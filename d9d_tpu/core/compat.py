@@ -1,17 +1,9 @@
-"""Version-compat shims for the ambient-mesh JAX API.
+"""One spelling for the ambient-mesh and compiled-analysis calls.
 
-The framework targets the current JAX surface — ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.sharding.AxisType`` — which
-older runtimes (0.4.x, e.g. a CPU rig whose JAX is pinned by an
-accelerator plugin) predate. On a current JAX every helper here
-degenerates to the native call; on an old one they fall back to a
-process-local current-mesh slot with the same resolution semantics
-("most recently set mesh wins; a scoped set restores the previous one
-on exit"), so the mesh-dependent stack stays importable and testable
-everywhere.
-
-Only the AMBIENT-MESH bookkeeping is emulated — collectives, shard_map
-and NamedSharding go through the public API on both sides.
+The repository runs on one installation (jax 0.9, ``pyproject.toml``),
+so every helper here is the native call; they exist so that callers
+share one keyword surface (``shard_map``'s optional arguments) and one
+normalized shape for what a backend returns from its analyses.
 """
 
 import jax
@@ -27,67 +19,21 @@ __all__ = [
     "shard_map",
 ]
 
-# True on a runtime with the native ambient-mesh API. The SPMD
-# training/e2e test tier keys off this: the fallbacks below keep
-# single-process serving/decode/bench paths working on old runtimes,
-# but full mesh-training e2e there is uncertified (tests skip it).
-HAS_MODERN_JAX = hasattr(jax, "set_mesh")
+# Constant since the 0.4.x emulation went; the test skips that still read
+# it go with the Design item ``jax-0.4-compat`` (ROADMAP.md).
+HAS_MODERN_JAX = True
 
-# fallback ambient mesh (single slot, matching jax.set_mesh semantics:
-# a statement-form set replaces the current mesh; a scoped set restores
-# the previous one on exit). Single-controller: the executor and all
-# mesh builds run on the main thread; prefetch threads never set meshes.
-_AMBIENT: list = [None]
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
 
 
 def mesh_axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,) * n`` where the runtime understands it."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {
-            "axis_types": (jax.sharding.AxisType.Auto,) * n_axes
-        }
-    return {}
-
-
-class _FallbackSetMesh:
-    """Matches ``jax.set_mesh``'s dual use: called as a statement the
-    mesh stays ambient process-wide; used as a context manager the
-    previously ambient mesh is restored at block exit."""
-
-    def __init__(self, mesh):
-        self._prev = _AMBIENT[0]
-        _AMBIENT[0] = mesh
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        _AMBIENT[0] = self._prev
-        return False
-
-
-def set_mesh(mesh):
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return _FallbackSetMesh(mesh)
-
-
-def get_abstract_mesh():
-    """The ambient mesh, or None. The fallback returns the concrete
-    ``Mesh`` most recently set — its ``.shape`` mapping is what every
-    caller consumes, so the two paths are interchangeable."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    return _AMBIENT[0]
+    """``axis_types=(Auto,) * n``: ``jax.make_mesh`` defaults to Explicit
+    (sharding-in-types), which rejects plain ``jit`` use."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 # -- compiled-executable introspection (telemetry/introspect.py) --------
-#
-# The AOT surface is stable (`Lowered.compile()` → `Compiled`), but what
-# the *backend* returns from cost/memory analysis varies: lists vs dicts
-# across jax versions, None on backends without the C++ implementation,
-# and attribute-less stubs on some plugins. Normalize here so the
-# introspection layer never has to version-switch.
 
 _MEMORY_FIELDS = (
     "argument_size_in_bytes",
@@ -99,52 +45,29 @@ _MEMORY_FIELDS = (
 
 
 def compiled_cost_analysis(compiled) -> dict | None:
-    """``Compiled.cost_analysis()`` normalized to one flat dict (or None
-    when the backend declines). Older runtimes return a one-element list
-    of dicts; newer ones return the dict directly."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backend without the analysis
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
+    """``Compiled.cost_analysis()`` as one flat ``{str: float}`` dict, or
+    None when the backend returns nothing."""
+    ca = compiled.cost_analysis()
     if not ca:
         return None
-    try:
-        return {str(k): float(v) for k, v in dict(ca).items()}
-    except Exception:  # noqa: BLE001 — unexpected shape: treat as absent
-        return None
+    return {str(k): float(v) for k, v in ca.items()}
 
 
 def compiled_memory_analysis(compiled) -> dict | None:
     """``Compiled.memory_analysis()`` as ``{field: int bytes}`` over the
     standard CompiledMemoryStats size fields, or None when the backend
-    returns nothing useful (all-absent attrs count as nothing)."""
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:  # noqa: BLE001 — backend without the analysis
-        return None
+    returns nothing."""
+    ma = compiled.memory_analysis()
     if ma is None:
         return None
-    out = {}
-    for field in _MEMORY_FIELDS:
-        v = getattr(ma, field, None)
-        if v is not None:
-            try:
-                out[field] = int(v)
-            except (TypeError, ValueError):
-                continue
-    return out or None
+    return {field: int(getattr(ma, field)) for field in _MEMORY_FIELDS}
 
 
 def device_hbm_capacity() -> int | None:
     """Per-chip accelerator memory capacity in bytes (``bytes_limit``
     from the device's memory stats), or None where the backend exposes
     none (CPU rigs) — callers skip the budget gauge then."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # noqa: BLE001 — backend not initialized/available
-        return None
+    stats = jax.local_devices()[0].memory_stats()
     if not stats:
         return None
     limit = stats.get("bytes_limit")
@@ -153,29 +76,13 @@ def device_hbm_capacity() -> int | None:
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
               axis_names=None):
-    """``jax.shard_map`` with the keyword surface this repo uses.
-
-    The fallback maps onto ``jax.experimental.shard_map.shard_map``:
-    ``check_vma`` → ``check_rep`` (the older name for the same
-    replication-inference toggle) and ``axis_names`` (the subset of mesh
-    axes that go manual) → ``auto`` (its complement).
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
+    """``jax.shard_map`` with the keyword surface this repo uses: the two
+    optional arguments are passed only when given."""
     kwargs = {}
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )

@@ -20,7 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from d9d_tpu.core.mesh import MeshContext
 from d9d_tpu.core.offload import SleepTag, offload_tree, onload_tree
-from d9d_tpu.core.tree_sharding import replicate_uncommitted
 from d9d_tpu.core.types import PyTree
 from d9d_tpu.loop import event as ev
 from d9d_tpu.loop.components.batch_maths import BatchMaths
@@ -158,15 +157,27 @@ class Trainer:
                 self.task = task = PeftTask(task, peft_method, self.base_params)
             self.events.emit(ev.EVENT_MODEL_READY, trainer=self)
 
-            # normalize placement: a fresh jit(init) leaves constraint-free
-            # scalars (step counters) uncommitted on one device, which
-            # round-trips through a checkpoint as a committed placement
-            # that conflicts with the mesh-placed params at the first
-            # post-restore step (core/tree_sharding.replicate_uncommitted)
-            self.opt_state = replicate_uncommitted(
-                # d9d-lint: disable=D9D001 — one-shot optimizer-state init
-                jax.jit(self.optimizer.init)(self.params), ctx.mesh
+            # Place the state where the step will keep it: every
+            # param-shaped leaf (the moments) on its parameter's sharding,
+            # the riders (step counters, RNG keys) replicated on the mesh.
+            # Left to the compiler, the moments — constant zeros to it —
+            # come out replicated: N full copies per chip at step 0, and a
+            # second compile of the train step when they come back out of
+            # it sharded (seen on the FSDP x EP mesh, PR 21). Committing
+            # the riders to the mesh also keeps a checkpoint round trip
+            # from pinning them to one device.
+            replicated = NamedSharding(ctx.mesh, P())
+            state_shardings = optax.tree_utils.tree_map_params(
+                self.optimizer,
+                lambda _, sharding: sharding,
+                jax.eval_shape(self.optimizer.init, self.params),
+                jax.tree.map(lambda x: x.sharding, self.params),
+                transform_non_params=lambda _: replicated,
             )
+            # d9d-lint: disable=D9D001 — one-shot optimizer-state init
+            self.opt_state = jax.jit(
+                self.optimizer.init, out_shardings=state_shardings
+            )(self.params)
             self.zero = None
             if config.zero_sharding:
                 # ZeRO optimizer-state sharding (parallel/zero.py): move
@@ -285,8 +296,13 @@ class Trainer:
         )
         # tok_s is whole-mesh throughput, so MFU normalizes by the whole
         # mesh's peak (per-chip peak x mesh size), matching bench.py's
-        # single-chip convention at mesh size 1
-        self._peak_flops = device_peak_flops() * int(ctx.mesh.devices.size)
+        # single-chip convention at mesh size 1. None off the TPU: the
+        # CPU rig has no peak, so it emits no train/mfu gauge
+        chip_peak = device_peak_flops()
+        self._peak_flops = (
+            chip_peak * int(ctx.mesh.devices.size)
+            if chip_peak is not None else None
+        )
         # per-chip optimizer-state footprint (docs/design/zero_sharding.md):
         # under ZeRO this reads ~1/dp_replicate of the replicated value —
         # the executable claim the bench column mirrors
@@ -876,10 +892,11 @@ class Trainer:
                                 / window
                             )
                             tele.gauge("train/tokens_per_s").set(tok_s)
-                            tele.gauge("train/mfu").set(
-                                tok_s * self._flops_per_token
-                                / self._peak_flops
-                            )
+                            if self._peak_flops is not None:
+                                tele.gauge("train/mfu").set(
+                                    tok_s * self._flops_per_token
+                                    / self._peak_flops
+                                )
                         tele_sync_t0 = now
                         steps_since_sync = 0
                         self._note_flops_divergence()

@@ -213,7 +213,7 @@ def grouped_swiglu_apply(
     bf16), but tools/roofline.py predicts the copy INVERTS at µBS=1 with
     fp32 master weights (the concat becomes the largest single HBM term);
     ``D9D_TPU_MOE_FUSED_GATE_UP=0`` switches to two grouped matmuls for
-    the on-chip A/B (run_tpu_benches.sh).
+    the on-chip A/B (ROADMAP ``env-selected-kernels``; not run yet).
     """
     x = permuted_x.astype(dtype)
     g, u = gate_up_grouped_matmul(

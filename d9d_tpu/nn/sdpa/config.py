@@ -26,6 +26,11 @@ class SdpaPallasFlashConfig(pydantic.BaseModel):
     # one-pass backward (see ops/attention/pallas_flash._bwd_fused_kernel);
     # None = env D9D_TPU_FLASH_BWD ("fused"/"split"), default split
     fused_bwd: bool | None = None
+    # mesh axes the batch / head dims are split over: on a multi-device
+    # mesh the kernel shard_maps itself over them (Mosaic kernels are not
+    # auto-partitionable). Sequence-sharded runs use SdpaRingConfig.
+    batch_axes: tuple[str, ...] = ("dp_r", "dp_s")
+    head_axes: tuple[str, ...] = ("tp",)
 
 
 class SdpaRingConfig(pydantic.BaseModel):

@@ -30,12 +30,7 @@ def _auto_config() -> SdpaBackendConfig:
     if os.environ.get(ENV_OVERRIDE):
         return _adapter.validate_python(json.loads(os.environ[ENV_OVERRIDE]))
     if jax.default_backend() == "tpu":
-        try:  # auto mode degrades gracefully if the kernel is unavailable
-            import d9d_tpu.ops.attention.pallas_flash  # noqa: F401
-
-            return SdpaPallasFlashConfig()
-        except ImportError:
-            return SdpaEagerConfig()
+        return SdpaPallasFlashConfig()
     return SdpaEagerConfig()
 
 
@@ -53,6 +48,7 @@ def build_sdpa_backend(config: SdpaBackendConfig | None = None) -> SdpaBackend:
         return make_pallas_flash_sdpa(
             block_q=config.block_q, block_kv=config.block_kv,
             fused_bwd=config.fused_bwd,
+            batch_axes=config.batch_axes, head_axes=config.head_axes,
         )
     if isinstance(config, SdpaRingConfig):
         from d9d_tpu.core.mesh import resolve_ambient_mesh

@@ -765,12 +765,50 @@ def flash_attention_block(
     return _flash_ol(cfg, q, k, v, offsets, q_segments, kv_segments)
 
 
+def _shard_over_mesh(fn, mesh, batch_axes, head_axes, *, has_segments):
+    """Run ``fn(q, k, v, sinks, q_seg, kv_seg)`` per shard of ``mesh``:
+    batch over ``batch_axes``, heads over ``head_axes``, sequence whole.
+
+    Mosaic kernels cannot be partitioned by the SPMD partitioner (the TPU
+    lowering raises "Mosaic kernels cannot be automatically partitioned"
+    as soon as the mesh has more than one device), so under FSDP/TP the
+    kernel has to name its own partitioning. GQA grouping survives the
+    head split because q and kv heads shard in the same contiguous order.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    qkv = P(batch_axes or None, None, head_axes or None, None)
+    seg = P(batch_axes or None, None)
+    in_specs = (qkv, qkv, qkv, P(head_axes or None))
+    if has_segments:
+        in_specs += (seg, seg)
+
+    def per_shard(q, k, v, sinks, *segs):
+        return fn(q, k, v, sinks, *(segs or (None, None)))
+
+    run = jax.shard_map(
+        per_shard, mesh=mesh, in_specs=in_specs, out_specs=qkv,
+        check_vma=False,
+    )
+    return lambda q, k, v, sinks, q_seg, kv_seg: run(
+        q, k, v, sinks, *((q_seg, kv_seg) if has_segments else ())
+    )
+
+
 def make_pallas_flash_sdpa(
     block_q: int = 1024,
     block_kv: int = 512,
     fused_bwd: bool | None = None,
+    batch_axes: tuple[str, ...] = (),
+    head_axes: tuple[str, ...] = (),
 ):
     """Build an SdpaBackend backed by the Pallas flash kernel.
+
+    ``batch_axes`` / ``head_axes`` name the mesh axes the batch and head
+    dims are split over. Where the ambient mesh gives any of them more
+    than one device, the kernel runs inside a ``shard_map`` over them
+    (:func:`_shard_over_mesh`); on a one-device mesh, or with no axes
+    named, it is called directly.
 
     Default block sizes follow the r3 on-chip sweep (tools/bench_kernels.py,
     BASELINE.md): 1024x512 won fwd+bwd at every swept shape (t=2048/8192
@@ -829,6 +867,15 @@ def make_pallas_flash_sdpa(
         sinks_arr = (
             sinks if sinks is not None else jnp.zeros((q.shape[2],), jnp.float32)
         )
-        return _flash(cfg, q, k, v, sinks_arr, q_segments, kv_segments)
+        call = functools.partial(_flash, cfg)
+        mesh = jax.sharding.get_abstract_mesh()
+        b_axes = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1)
+        h_axes = tuple(a for a in head_axes if mesh.shape.get(a, 1) > 1)
+        if b_axes or h_axes:
+            call = _shard_over_mesh(
+                call, mesh, b_axes, h_axes,
+                has_segments=q_segments is not None,
+            )
+        return call(q, k, v, sinks_arr, q_segments, kv_segments)
 
     return sdpa

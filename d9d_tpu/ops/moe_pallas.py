@@ -147,6 +147,34 @@ def _ffn_kernel(gid_ref, a_ref, probs_ref, wg_ref, wu_ref, wd_ref, out_ref):
     out_ref[...] = (y * probs_ref[...]).astype(out_ref.dtype)
 
 
+_GATHER_UNROLL = 8  # rows gathered per loop trip; block_m % 8 == 0 is gated
+
+
+def _gather_rows(
+    ps_ref, x_ref, probs_ref, a_scr, p_scr, *, block_m: int, top_k: int
+):
+    """Fill this tile's activation/prob scratch from the resident x/probs
+    by the scalar-prefetched ``pair_src`` map; pad rows (pair_src < 0)
+    load row 0 and are zeroed. Unrolled by hand: Mosaic lowers a
+    ``fori_loop`` only rolled or fully unrolled ("Only unroll=num_steps
+    and unroll=1 supported", the v5e compiler on ``unroll=8``)."""
+    base = pl.program_id(0) * block_m
+
+    def body(j, _):
+        for u in range(_GATHER_UNROLL):
+            i = j * _GATHER_UNROLL + u
+            src = ps_ref[base + i]
+            valid = src >= 0
+            src0 = jnp.maximum(src, 0)
+            row = x_ref[pl.ds(src0 // top_k, 1), :]
+            a_scr[pl.ds(i, 1), :] = jnp.where(valid, row, 0)
+            pr = probs_ref[pl.ds(src0, 1), :]
+            p_scr[pl.ds(i, 1), :] = jnp.where(valid, pr, 0)
+        return 0
+
+    jax.lax.fori_loop(0, block_m // _GATHER_UNROLL, body, 0)
+
+
 def _ffn_gather_kernel(
     gid_ref, ps_ref, x_ref, probs_ref, wg_ref, wu_ref, wd_ref, out_ref,
     a_scr, p_scr, *, block_m: int, top_k: int,
@@ -161,19 +189,9 @@ def _ffn_gather_kernel(
     pass, the top residual HBM term in tools/roofline.py's post-µBS4
     attribution). Pad rows (pair_src < 0) load row 0 and are zeroed.
     """
-    t = pl.program_id(0)
-
-    def body(i, _):
-        src = ps_ref[t * block_m + i]
-        valid = src >= 0
-        src0 = jnp.maximum(src, 0)
-        row = x_ref[pl.ds(src0 // top_k, 1), :]
-        a_scr[pl.ds(i, 1), :] = jnp.where(valid, row, 0)
-        pr = probs_ref[pl.ds(src0, 1), :]
-        p_scr[pl.ds(i, 1), :] = jnp.where(valid, pr, 0)
-        return 0
-
-    jax.lax.fori_loop(0, block_m, body, 0, unroll=8)
+    _gather_rows(
+        ps_ref, x_ref, probs_ref, a_scr, p_scr, block_m=block_m, top_k=top_k
+    )
     a = a_scr[...]
     g = jnp.dot(a, wg_ref[0], preferred_element_type=jnp.float32)
     u = jnp.dot(a, wu_ref[0], preferred_element_type=jnp.float32)
@@ -208,17 +226,9 @@ def _ffn_gather_combine_kernel(
     def _init():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    def gather(i, _):
-        src = ps_ref[t * block_m + i]
-        valid = src >= 0
-        src0 = jnp.maximum(src, 0)
-        row = x_ref[pl.ds(src0 // top_k, 1), :]
-        a_scr[pl.ds(i, 1), :] = jnp.where(valid, row, 0)
-        pr = probs_ref[pl.ds(src0, 1), :]
-        p_scr[pl.ds(i, 1), :] = jnp.where(valid, pr, 0)
-        return 0
-
-    jax.lax.fori_loop(0, block_m, gather, 0, unroll=8)
+    _gather_rows(
+        ps_ref, x_ref, probs_ref, a_scr, p_scr, block_m=block_m, top_k=top_k
+    )
     a = a_scr[...]
     g = jnp.dot(a, wg_ref[0], preferred_element_type=jnp.float32)
     u = jnp.dot(a, wu_ref[0], preferred_element_type=jnp.float32)
@@ -264,6 +274,17 @@ def _vmem_budget() -> int:
     return int(
         os.environ.get("D9D_TPU_MOE_FFN_VMEM_BUDGET", 96 * 1024 * 1024)
     )
+
+
+def _compiler_params(interpret: bool):
+    """Grant the kernels the budget the eligibility gates promise them:
+    Mosaic's default scoped-VMEM limit is 16 MiB, and the plain kernel at
+    Qwen3-30B-A3B expert shapes (h2048, i768, block_m 128) needs 20 MiB
+    ("Scoped allocation with size 20.00M and limit 16.00M exceeded scoped
+    vmem limit", the v5e compiler without this)."""
+    if interpret:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_budget())
 
 
 # scalar-prefetch budget for the gather variant's SMEM riders (gid +
@@ -372,6 +393,7 @@ def _fused_ffn_call(
         _ffn_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, h), aligned_x.dtype),
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(gid, aligned_x, aligned_probs, gate_w, up_w, down_w)
 
@@ -441,6 +463,7 @@ def _fused_gather_call(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, h), x.dtype),
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(gid, pair_src, x, probs_flat, gate_w, up_w, down_w)
 
@@ -478,6 +501,7 @@ def _fused_gather_combine_call(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(gid, pair_src, x, probs_flat, gate_w, up_w, down_w)
 

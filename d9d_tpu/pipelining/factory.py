@@ -78,7 +78,7 @@ class Interleaved1F1BScheduleConfig(_RuntimeChoice):
 # simulation (tools/pp_makespan.py, BASELINE.md r4 table) shows it strictly
 # dominating both other policies at every multi-rank config (−12.6% vs 1F1B
 # at pp=8/µB=8); it stays opt-in until the residual write+read tax between
-# the I and W jits is measured on chip (queued in run_tpu_benches.sh).
+# the I and W jits is measured on chip (ROADMAP S4; not measured yet).
 
 
 class ZeroBubble1PScheduleConfig(_RuntimeChoice):

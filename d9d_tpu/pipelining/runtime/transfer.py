@@ -2,10 +2,15 @@
 
 Pipeline stages live on different submeshes, and the executor/optimizer
 move activations, grad-norm scalars, and clip factors between them with
-``jax.device_put``. Runtimes differ in what they accept for a
-device->device copy between *different device sets*: TPU (TFRT) supports
-it (experimentally) — the fast path — while CPU multi-controller rejects
-it. ``put_compat`` falls back to reassembling from addressable shards:
+``jax.device_put``. A single process (one host, however many chips) may
+copy between different device sets directly. Multi-controller jax may not:
+jax 0.9.0's ``device_put`` still raises "For a cross-host reshard in
+multi-controller JAX, input and target sharding should have the same set
+of devices" for it (``jax/_src/dispatch.py``; re-tested in PR 21 with two
+CPU processes, the runtime ``tests/core/test_multiprocess_e2e.py`` drives),
+with only experimental support in the TFRT TPU runtime. So the multi-process
+runtimes are the ones that still take ``put_compat``'s fallback, which
+reassembles from addressable shards:
 for every destination device this process owns, the matching global slice
 must already live on a source device this process owns, which holds for
 replicated values (every process has a local copy) and for pipeline
@@ -50,7 +55,7 @@ def _shardwise_put(x: jax.Array, sharding) -> jax.Array:
 
 
 # Whether this runtime accepts a direct device_put between different
-# device sets (TPU/TFRT: yes; CPU multi-controller: no). Classified once:
+# device sets (single process: yes; multi-controller: no). Classified once:
 # when the first cross-set payload put raises ValueError, a tiny dedicated
 # probe REPLICATING the failure mode (an array on a source device moved
 # onto the destination sharding's device set) decides whether that was a

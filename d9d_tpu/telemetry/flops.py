@@ -16,7 +16,10 @@ __all__ = [
     "device_peak_flops",
 ]
 
-# Peak bf16 FLOPs per chip by device-kind substring (bench.py table).
+# Peak bf16 FLOPs per chip by device-kind substring (Google Cloud TPU
+# documentation, per-generation system pages). The one table bench.py,
+# chip_smoke.py and the trainer's MFU gauge read; a TPU that is not in it
+# is an error, never a default.
 PEAK_FLOPS = {
     "v5 lite": 197e12,
     "v5e": 197e12,
@@ -24,7 +27,6 @@ PEAK_FLOPS = {
     "v4": 275e12,
     "v6": 918e12,
 }
-DEFAULT_PEAK = 197e12  # unknown device (CPU rigs): v5e yardstick
 
 
 def model_flops_per_token(
@@ -95,16 +97,23 @@ def active_param_count(trees, config: Any | None = None) -> float:
     return total
 
 
-def device_peak_flops() -> float:
-    """Peak bf16 FLOPs of the first local device (DEFAULT_PEAK when the
-    device kind is unrecognized — live MFU is a trend signal, and on CPU
-    rigs an arbitrary-but-fixed yardstick keeps the gauge plottable)."""
+def device_peak_flops() -> float | None:
+    """Peak bf16 FLOPs of the first local device, from :data:`PEAK_FLOPS`.
+
+    None on a backend that is not a TPU (the CPU rig): there is no peak
+    to divide by, so callers emit no utilisation there. A TPU whose kind
+    is missing from the table raises — add the kind with its source
+    rather than measure against another chip's peak."""
     import jax  # deferred: the telemetry package core stays jax-free
 
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — backend not initialized/available
-        return DEFAULT_PEAK
-    return next(
-        (v for k, v in PEAK_FLOPS.items() if k in kind), DEFAULT_PEAK
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
+    for key, peak in PEAK_FLOPS.items():
+        if key in kind:
+            return peak
+    raise ValueError(
+        f"no peak FLOP/s recorded for TPU kind {device.device_kind!r}; "
+        "add it to d9d_tpu.telemetry.flops.PEAK_FLOPS with its source"
     )

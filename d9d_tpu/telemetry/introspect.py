@@ -44,6 +44,7 @@ import hashlib
 import logging
 import threading
 import time
+import weakref
 from typing import Any, Callable
 
 from d9d_tpu.telemetry import audit_capture  # stdlib-only at import
@@ -52,6 +53,7 @@ __all__ = [
     "ExecutableRecord",
     "RecompileGuard",
     "TrackedJit",
+    "compiled_hlo",
     "executable_flops",
     "inventory",
     "recompile_guard",
@@ -174,6 +176,24 @@ def executable_flops(name: str) -> float | None:
             if rec.name == name and rec.flops is not None:
                 return rec.flops
     return None
+
+
+# live wrappers, so a caller can read back the programs that really ran
+_WRAPPERS: "weakref.WeakSet[TrackedJit]" = weakref.WeakSet()
+
+
+def compiled_hlo(name: str) -> list[str]:
+    """Optimized HLO text of every executable that live ``tracked_jit``
+    wrappers named ``name`` hold — the programs that were dispatched,
+    not a re-lowering of them. ``chip_smoke.py`` reads it to show which
+    kernels and collectives a chip run took."""
+    with _INVENTORY_LOCK:
+        wrappers = [w for w in _WRAPPERS if w.name == name]
+    return [
+        compiled.as_text()
+        for w in wrappers
+        for compiled in list(w._compiled.values())
+    ]
 
 
 # -- recompile guard ----------------------------------------------------
@@ -323,6 +343,8 @@ class TrackedJit:
         self._records: dict[Any, ExecutableRecord] = {}
         self._fallback = False
         self._lock = threading.Lock()
+        with _INVENTORY_LOCK:
+            _WRAPPERS.add(self)
 
     # the plain jitted function, for callers that need jit attributes
     @property

@@ -24,14 +24,11 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent.parent))
 
-import jax
-
-import os
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from d9d_tpu.core.compile_cache import enable_compile_cache
 from d9d_tpu.loop.generate import generate
 from d9d_tpu.model_state import load_params
 from d9d_tpu.nn.sdpa import build_sdpa_backend
@@ -52,6 +49,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ProjectConfig.model_validate(
         json.loads(Path(args.config).read_text())

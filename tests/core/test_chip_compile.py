@@ -1,0 +1,308 @@
+"""Compile-only rehearsal for the TPU v5e, kept as tests.
+
+The chip's compiler is installed on the CPU rig and compiles for a
+described, unattached ``v5e:2x2`` topology
+(/opt/skills/guides/on-chip-measurement/SKILL.md §2). A compile that
+passes here is a compile, never a run: it shows that the kernel fits the
+chip's tiling, VMEM and partitioning rules at the real widths
+(Qwen3-30B-A3B: H32/4 d128, 128 experts x 768, h2048) and that the
+kernel or collective is really in the program; ``chip_smoke.py`` is what
+executes them. Kernels that had passed every interpret-mode test were
+refused here first (``ops/moe_pallas.py``, PR 21).
+
+``jax.default_backend`` is steered by ``monkeypatch`` in the tests that
+need the TPU branch of the code; the program grows no option for it.
+"""
+
+import dataclasses
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tests.conftest import load_repo_module
+
+# one definition of "a Pallas kernel is in this HLO" for these compiles
+# and for the chip run that executes them
+_smoke_pallas_calls = load_repo_module("chip_smoke", "chip_smoke.py").pallas_calls
+
+BF16 = jnp.bfloat16
+# Qwen3-30B-A3B attention and expert geometry (models/qwen3/moe.py)
+HQ, HKV, D, T = 32, 4, 128, 4096
+E, H, INTER, TOP_K = 128, 2048, 768, 8
+PAGE = 64  # chip_smoke.py's serving page size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this rig
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """An entry compiled for a described chip is written to the
+    persistent cache but cannot be read back without the chip (the next
+    compile warns and recompiles): keep the cache off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` takes its TPU branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _pallas_calls(compiled, scope: str = "") -> int:
+    return _smoke_pallas_calls([compiled.as_text()], scope)
+
+
+def _on(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+# -- attention kernels -------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "fused_bwd,t",
+    [(False, T), (True, T), (True, 1024)],
+    ids=["split-t4096", "fused-t4096", "fused-t1024"],
+)
+def test_flash_fwd_bwd(topo, as_tpu, fused_bwd, t):
+    from d9d_tpu.ops.attention.pallas_flash import (
+        fused_bwd_applies,
+        make_pallas_flash_sdpa,
+    )
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    flash = make_pallas_flash_sdpa(fused_bwd=fused_bwd)
+
+    def loss(q, k, v):
+        return flash(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds((1, t, HQ, D), BF16), sds((1, t, HKV, D), BF16),
+        sds((1, t, HKV, D), BF16),
+    ).compile()
+    # The one-pass backward keeps a [g, T, d] dq state in VMEM: at the
+    # 30B-A3B group size (g = 8) it fits only up to T ~ 1700, so asking
+    # for it at T 4096 still takes the split kernels.
+    one_pass = fused_bwd and fused_bwd_applies(
+        t=t, num_heads=HQ, num_kv_heads=HKV, head_dim=D, itemsize=2
+    )
+    assert one_pass == (fused_bwd and t == 1024)
+    # forward + one-pass backward, or forward + (dq, dk/dv)
+    assert _pallas_calls(compiled) == (2 if one_pass else 3)
+
+
+def test_flash_shards_itself_over_a_mesh(topo, as_tpu):
+    """Mosaic kernels cannot be auto-partitioned: on a multi-device mesh
+    the SDPA factory's flash backend has to shard_map itself (PR 21: the
+    FSDP x EP train step failed to lower without it)."""
+    from d9d_tpu.nn.sdpa import SdpaPallasFlashConfig, build_sdpa_backend
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("dp_s", "tp"))
+    sds = _on(NamedSharding(mesh, P("dp_s")))
+    flash = build_sdpa_backend(SdpaPallasFlashConfig())
+
+    def loss(q, k, v):
+        return flash(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            sds((4, 1024, HQ, D), BF16), sds((4, 1024, HKV, D), BF16),
+            sds((4, 1024, HKV, D), BF16),
+        ).compile()
+    assert _pallas_calls(compiled) == 3
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged_bf16", "paged_int8"])
+def test_flash_decode(topo, layout):
+    from d9d_tpu.ops.attention.pallas_decode import flash_decode_attention
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    b, s = 4, 128
+    q, start = sds((b, 1, HQ, D), BF16), sds((b,), jnp.int32)
+    if layout == "contiguous":
+        kv = sds((b, HKV, s, D), BF16)
+        args, kwargs = (q, kv, kv), {}
+    else:
+        quant = layout == "paged_int8"
+        n_pages = b * (s // PAGE) + 1
+        pool = sds((n_pages, HKV, PAGE, D), jnp.int8 if quant else BF16)
+        kwargs = {"page_table": sds((b, s // PAGE), jnp.int32)}
+        if quant:
+            scale = sds((n_pages, HKV, PAGE), jnp.float32)
+            kwargs |= {"k_scale": scale, "v_scale": scale}
+        args = (q, pool, pool)
+    compiled = jax.jit(
+        lambda q, k, v, start, **kw: flash_decode_attention(
+            q, k, v, start=start, interpret=False, **kw
+        )
+    ).lower(*args, start, **kwargs).compile()
+    assert _pallas_calls(compiled) == 1
+
+
+# -- the model ---------------------------------------------------------------
+
+
+def test_one_layer_30b_a3b_value_and_grad(topo, as_tpu):
+    """The whole model at published widths, one layer deep, through the
+    factory's default SDPA: the flash kernels are in the program."""
+    import flax.linen as nn
+
+    from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
+    from d9d_tpu.nn.sdpa import build_sdpa_backend
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    cfg = dataclasses.replace(Qwen3MoeConfig.qwen3_30b_a3b(), num_layers=1)
+    model = Qwen3MoeCausalLM(
+        config=cfg, sdpa=build_sdpa_backend(), dtype=BF16, param_dtype=BF16
+    )
+    ids = jax.ShapeDtypeStruct((1, 8), jnp.int32)
+    abstract = nn.unbox(jax.eval_shape(
+        lambda z: model.init(jax.random.PRNGKey(0), z, z, z)["params"], ids
+    ))
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), abstract)
+    n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    assert n_params > 1_200_000_000
+
+    def loss(params, tokens):
+        return model.apply({"params": params}, tokens, tokens, tokens).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        params, sds((1, T), jnp.int32)
+    ).compile()
+    assert _pallas_calls(compiled, "self_attn") >= 3, (
+        "flash forward/backward kernels not in the HLO"
+    )
+    ma = compiled.memory_analysis()
+    # bf16 params in, bf16 grads out: 2.5 GB each; the step fits one chip
+    assert ma.argument_size_in_bytes + ma.output_size_in_bytes < 6e9
+
+
+@pytest.mark.parametrize("mode", ["forward", "grad"])
+def test_ep_dispatch_combine_on_four_chips(topo, as_tpu, mode):
+    """The EP flow at 30B-A3B expert shapes over four described chips:
+    ``lax.ragged_all_to_all`` itself, not the CPU emulation under it."""
+    from d9d_tpu.nn.moe import grouped_swiglu_apply
+    from d9d_tpu.ops.ep_dispatch import ep_dispatch_compute_combine
+
+    world, n_loc = 4, 4096
+    mesh = Mesh(np.array(topo.devices), ("ep",))
+    rows = _on(NamedSharding(mesh, P("ep")))
+    e_loc = E // world
+
+    def body(x, ids, probs, gate_w, up_w, down_w):
+        def expert_fn(rows, group_sizes):
+            return grouped_swiglu_apply(
+                rows, jnp.ones((rows.shape[0],), jnp.float32), group_sizes,
+                gate_w, up_w, down_w, BF16,
+            )
+
+        return ep_dispatch_compute_combine(
+            x, ids, probs, expert_fn, ep_axes=("ep",), e_loc=e_loc,
+            ep_world=world, capacity_factor=None,
+        )
+
+    run = jax.shard_map(
+        body, mesh=mesh, in_specs=(P("ep"),) * 6, out_specs=P("ep"),
+        check_vma=False,
+    )
+    fn = run
+    if mode == "grad":
+        def fn(x, *rest):
+            return jax.grad(
+                lambda x, *r: run(x, *r).astype(jnp.float32).sum(),
+                argnums=(0, 3, 4, 5),
+            )(x, *rest)
+
+    compiled = jax.jit(fn).lower(
+        rows((world * n_loc, H), BF16),
+        rows((world * n_loc, TOP_K), jnp.int32),
+        rows((world * n_loc, TOP_K), jnp.float32),
+        rows((E, H, INTER), BF16), rows((E, H, INTER), BF16),
+        rows((E, INTER, H), BF16),
+    ).compile()
+    n = compiled.as_text().count(" ragged-all-to-all(")
+    # dispatch + combine, and the transposes of both in the backward
+    assert n >= (2 if mode == "forward" else 3), n
+
+
+# -- the env-selected fused expert FFN (default stays ``xla``) ---------------
+
+# What the v5e compiler says to the gather variants once the ``unroll=8``
+# it refused first ("Only unroll=num_steps and unroll=1 supported") is
+# unrolled by hand: the in-kernel row gather reads one bf16 row at a
+# dynamic sublane offset. Not a line or two to repair; see ROADMAP
+# ``env-selected-kernels``.
+_GATHER_REFUSAL = "cannot statically prove that index in dimension 0"
+
+
+@pytest.mark.parametrize(
+    "variant",
+    [
+        "pallas",
+        pytest.param("pallas_gather", marks=pytest.mark.xfail(
+            strict=True, raises=Exception, reason=_GATHER_REFUSAL)),
+        pytest.param("pallas_gather_combine", marks=pytest.mark.xfail(
+            strict=True, raises=Exception, reason=_GATHER_REFUSAL)),
+    ],
+)
+def test_moe_pallas_ffn(topo, variant):
+    from d9d_tpu.ops import moe_pallas
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    block_m, n = 128, 4096
+    m = n * TOP_K
+    m_pad = (-(-m // block_m) + E) * block_m
+    weights = (
+        sds((E, H, INTER), BF16), sds((E, H, INTER), BF16),
+        sds((E, INTER, H), BF16),
+    )
+    gid = sds((m_pad // block_m,), jnp.int32)
+    # the eligibility gates pass these shapes, so the kernels are taken
+    assert moe_pallas._tpu_shapes_ok(H, INTER, block_m, 2)
+    assert moe_pallas._combine_fits(n, m, H, INTER, block_m, 2, E)
+    if variant == "pallas":
+        lowered = moe_pallas._fused_ffn_call.lower(
+            sds((m_pad, H), BF16), sds((m_pad, 1), jnp.float32), gid,
+            *weights, block_m=block_m, interpret=False,
+        )
+    else:
+        call = (
+            moe_pallas._fused_gather_call if variant == "pallas_gather"
+            else moe_pallas._fused_gather_combine_call
+        )
+        lowered = call.lower(
+            sds((n, H), BF16), sds((m, 1), jnp.float32), gid,
+            sds((m_pad,), jnp.int32), *weights,
+            block_m=block_m, top_k=TOP_K, interpret=False,
+        )
+    try:
+        compiled = lowered.compile()
+    except Exception as e:
+        assert _GATHER_REFUSAL in str(e), e  # another refusal is news
+        raise
+    assert _pallas_calls(compiled) == 1

@@ -126,7 +126,9 @@ def test_phase_timeline_covers_wall_and_reports_throughput(tmp_path):
     assert last["counters"]["train/tokens"] == STEPS * BATCH * SEQ
     assert last["counters"]["train/steps"] == STEPS
     assert last["gauges"]["train/tokens_per_s"] > 0
-    assert last["gauges"]["train/mfu"] >= 0
+    # the CPU rig has no peak FLOP/s to divide by, so no utilisation
+    # gauge (telemetry/flops.device_peak_flops returns None off the TPU)
+    assert "train/mfu" not in last["gauges"]
     # io spans from the data loader side are absent (generator dataset),
     # but the histogram summaries must be well-formed where present
     for name, h in last["histograms"].items():

@@ -31,7 +31,8 @@ def test_bench_tiny_runs(devices, tmp_path, monkeypatch):
     assert result["value"] > 0
     assert result["unit"] == "tokens/s"
     assert "vs_baseline" in result
-    assert result["detail"]["mfu"] >= 0
+    # no peak FLOP/s off the TPU: utilisation is not measured on this rig
+    assert result["detail"]["mfu"] is None
     from d9d_tpu.telemetry import iter_events
 
     (jsonl,) = tmp_path.glob("*.jsonl")
@@ -102,7 +103,7 @@ def test_bench_moe_tiny_runs(devices):
     assert result["metric"] == "qwen3_moe_tokens_per_sec_per_chip"
     assert result["value"] > 0
     assert result["detail"]["active_params"] < result["detail"]["total_params"]
-    assert 0 <= result["detail"]["mfu"] <= result["detail"]["hfu"] + 1e-9
+    assert result["detail"]["mfu"] is None and result["detail"]["hfu"] is None
 
 
 @pytest.mark.slow  # compile-bound on the 2-core rig; e2e tier covers it
@@ -133,8 +134,7 @@ def test_bench_input_pipeline_tiny_runs(devices):
     result = bench.run_bench_input_pipeline(tiny=True)
     assert result["metric"] == "input_pipeline_step_ms"
     for key in ("synthetic_ms", "sync_ms", "prefetch_ms"):
-        # None = benchtime.timeit deemed the case unmeasurable (RTT jitter)
-        assert result[key] is None or result[key] > 0
+        assert result[key] > 0
 
 
 def test_bench_generate_tiny_runs(devices):
@@ -155,7 +155,7 @@ def test_bench_hybrid_tiny_runs(devices):
     result = bench.run_bench_moe(tiny=True, hybrid=True)
     assert result["metric"] == "qwen3_next_hybrid_tokens_per_sec_per_chip"
     assert result["value"] > 0
-    assert result["detail"]["mfu"] >= 0
+    assert result["detail"]["mfu"] is None
 
 
 def test_bench_serving_tiny_runs(devices):

@@ -11,9 +11,9 @@ being checked), stale baseline entries reported so the file shrinks as
 debt is paid.
 
 ``--facts`` audits an existing telemetry JSONL capture instead of
-running the harness — the flow for the queued TPU bench legs, whose
-``run_tpu_benches.sh`` runs export ``D9D_AUDIT_CAPTURE=1`` so the
-``executable`` events carry ``audit`` blocks.
+running the harness — the flow for a chip run made with
+``D9D_AUDIT_CAPTURE=1`` in its environment, so that the ``executable``
+events carry ``audit`` blocks.
 """
 
 import argparse

@@ -164,6 +164,10 @@ def main():
 
         jax.config.update("jax_platforms", "cpu")
 
+    from d9d_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax.numpy as jnp
 
     from d9d_tpu.models.qwen3 import Qwen3DenseConfig
