@@ -1,0 +1,150 @@
+"""Operations and bytes the algorithm needs, from a cell's shapes.
+
+The arithmetic behind ``train.mfu_pct`` and the ``*_roofline`` metrics.
+It reads the configuration file's Hugging Face keys and the traffic
+mix's sizes; nothing is taken from the program, and recomputed
+(rematerialised) operations are never counted.
+"""
+
+BF16 = 2  # bytes
+
+
+def is_mla(cfg: dict) -> bool:
+    return "kv_lora_rank" in cfg
+
+
+def n_routed_experts(cfg: dict) -> int:
+    return cfg.get("num_experts") or cfg["n_routed_experts"]
+
+
+def n_dense_layers(cfg: dict) -> int:
+    return min(cfg.get("first_k_dense_replace", 0), cfg["num_hidden_layers"])
+
+
+def attention_matmul_params(cfg: dict) -> int:
+    """Projection parameters of one attention layer."""
+    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
+    if is_mla(cfg):
+        d_qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+        rank = cfg["kv_lora_rank"]
+        return (
+            d * h * d_qk
+            + d * (rank + cfg["qk_rope_head_dim"])
+            + rank * h * (cfg["qk_nope_head_dim"] + cfg["v_head_dim"])
+            + h * cfg["v_head_dim"] * d
+        )
+    hd, hkv = cfg["head_dim"], cfg["num_key_value_heads"]
+    return d * h * hd + 2 * d * hkv * hd + h * hd * d
+
+
+def active_matmul_params(cfg: dict) -> int:
+    """Weights one token is multiplied by: attention projections, router,
+    its top-k experts and the shared ones, dense layers, and the output
+    head. The embedding is a lookup and is not counted."""
+    d = cfg["hidden_size"]
+    layers, dense = cfg["num_hidden_layers"], n_dense_layers(cfg)
+    per_expert = 3 * d * cfg["moe_intermediate_size"]
+    sparse = (
+        d * n_routed_experts(cfg)
+        + (cfg["num_experts_per_tok"] + cfg.get("n_shared_experts", 0))
+        * per_expert
+    )
+    return (
+        layers * attention_matmul_params(cfg)
+        + dense * 3 * d * cfg["intermediate_size"]
+        + (layers - dense) * sparse
+        + d * cfg["vocab_size"]
+    )
+
+
+def attention_score_flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Forward FLOPs per token of QK^T and PV under a causal mask (a token
+    attends to half the sequence on average), all layers."""
+    h = cfg["num_attention_heads"]
+    if is_mla(cfg):
+        d_qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+        d_v = cfg["v_head_dim"]
+    else:
+        d_qk = d_v = cfg["head_dim"]
+    return cfg["num_hidden_layers"] * h * seq_len * (d_qk + d_v)
+
+
+def train_flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Forward + backward = 3 x forward; forward = 2 FLOPs a weight."""
+    return 3.0 * (
+        2.0 * active_matmul_params(cfg)
+        + attention_score_flops_per_token(cfg, seq_len)
+    )
+
+
+def expert_mm_train(cfg: dict, tokens: int) -> dict:
+    """Expert matmuls of ONE sparse layer in one training step, forward
+    and backward: gate, up and down, each once forward and twice
+    backward (to the input and to the weight). Bytes: every expert's
+    weights read forward and backward and their gradient written, rows
+    in and out of each matmul."""
+    d, f = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    rows = tokens * cfg["num_experts_per_tok"]
+    weights = n_routed_experts(cfg) * 3 * d * f
+    flops = 3 * 2.0 * rows * 3 * d * f
+    row_bytes = rows * (2 * d + 4 * f) * BF16  # x, g, u, h, y
+    return {
+        "flops": flops,
+        "bytes": 3.0 * weights * BF16 + 3.0 * row_bytes,
+    }
+
+
+def expert_mm_decode(cfg: dict, slots: int, experts_touched: float) -> dict:
+    """Expert matmuls of ONE sparse layer in one decode step over
+    ``slots`` tokens. Bytes count only the experts the step's routing
+    touched (their three matrices once) plus the rows."""
+    d, f = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    rows = slots * cfg["num_experts_per_tok"]
+    return {
+        "flops": 2.0 * rows * 3 * d * f,
+        "bytes": experts_touched * 3 * d * f * BF16
+        + rows * (2 * d + 4 * f) * BF16,
+    }
+
+
+def expected_experts_touched(cfg: dict, slots: int) -> float:
+    """Distinct experts hit by ``slots`` tokens x top-k draws when the
+    router is near uniform, as it is at seeded init."""
+    n = n_routed_experts(cfg)
+    return n * (1.0 - (1.0 - cfg["num_experts_per_tok"] / n) ** slots)
+
+
+def flash_train(cfg: dict, sequences: int, seq_len: int) -> dict:
+    """The attention kernels of ONE layer in one step. Forward is
+    QK^T and PV over the causal half; the backward kernels recompute the
+    scores and form dQ, dK, dV: 2.5 x the forward's matmul work (the
+    recomputation is part of the algorithm FlashAttention defines, not
+    rematerialisation of the model). Bytes: q, k, v, o read or written
+    forward; q, k, v, o, do read and dq, dk, dv written backward."""
+    h = cfg["num_attention_heads"]
+    if is_mla(cfg):
+        d_qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+        d_v = cfg["v_head_dim"]
+        hkv = h
+    else:
+        d_qk = d_v = cfg["head_dim"]
+        hkv = cfg["num_key_value_heads"]
+    tokens = sequences * seq_len
+    fwd_flops = 2.0 * tokens * h * (seq_len / 2) * (d_qk + d_v)
+    q_bytes = tokens * h * d_qk * BF16
+    k_bytes = tokens * hkv * d_qk * BF16
+    v_bytes = tokens * hkv * d_v * BF16
+    o_bytes = tokens * h * d_v * BF16
+    fwd_bytes = q_bytes + k_bytes + v_bytes + o_bytes
+    bwd_bytes = 2 * (q_bytes + k_bytes + v_bytes) + 2 * o_bytes
+    return {
+        "flops": 3.5 * fwd_flops,
+        "bytes": float(fwd_bytes + bwd_bytes),
+    }
+
+
+def roofline_seconds(cost: dict, peak) -> tuple[float, str]:
+    """Least time the chip could take, and which bound binds."""
+    compute = cost["flops"] / peak.bf16_flops
+    memory = cost["bytes"] / peak.hbm_bytes_per_s
+    return (compute, "compute") if compute >= memory else (memory, "memory")

@@ -1,0 +1,240 @@
+"""The generic readers: how a metric file's ``reader`` becomes a number.
+
+A metric is ``benchmarks/metrics/<name>.json``. Its ``reader`` is either
+``{"use": "<a function below>", "args": {...}}`` or ``{"file": true}``,
+which loads ``benchmarks/metrics/<name>.py`` and calls its
+``read(run)``. A reader that finds nothing to read returns ``None`` and
+the harness leaves the metric out of the line. Readers never touch the
+program: they see what the cell observed (``run.observed``), the
+normalised trace of a traced run (``run.trace``) and the cell's files.
+"""
+
+import dataclasses
+import importlib.util
+import statistics
+
+import numpy as np
+
+from . import costs, manifest
+from . import trace as tr
+
+
+@dataclasses.dataclass
+class Run:
+    cell: object
+    observed: object
+    setup_s: float
+    inventory: tuple
+    device_kind: str
+    trace: dict | None = None
+    scopes: dict | None = None
+    notes: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def hf(self) -> dict:
+        return self.cell.config
+
+    @property
+    def peak(self):
+        from . import peaks
+
+        return peaks.peak_for(self.device_kind)
+
+
+def read(run: Run, name: str):
+    own = manifest.metric_file(name)
+    reader = own["reader"]
+    if reader.get("file"):
+        path = manifest.BENCH_DIR / "metrics" / f"{name}.py"
+        spec = importlib.util.spec_from_file_location(
+            "bench_metric_" + name.replace(".", "_").replace("-", "_"), path
+        )
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module.read(run)
+    return globals()[reader["use"]](run, **reader.get("args", {}))
+
+
+# -- end to end ---------------------------------------------------------------
+
+
+def setup_seconds(run: Run):
+    return run.setup_s
+
+
+def train_rate(run: Run):
+    o = run.observed
+    return o.steps_in_window * o.tokens_per_step / o.window_s / o.chips
+
+
+def serve_rate(run: Run):
+    """Tokens emitted after the first chunk boundary inside the window up
+    to the last one, over the time between the two."""
+    b = run.observed.boundaries
+    if len(b) < 2:
+        return None
+    return sum(tokens for _, tokens in b[1:]) / (b[-1][0] - b[0][0])
+
+
+def request_percentile(run: Run, field: str, q: float, scale: float = 1.0):
+    values = [getattr(r, field) for r in run.observed.requests]
+    return float(np.percentile(values, q)) * scale if values else None
+
+
+# -- entry points -------------------------------------------------------------
+
+
+def compile_seconds(run: Run):
+    return sum(r.lower_s + r.compile_s for r in run.inventory)
+
+
+def compiles_in_window(run: Run):
+    return float(run.observed.compiles_in_window)
+
+
+# -- training loop ------------------------------------------------------------
+
+
+def train_mfu(run: Run):
+    """Model FLOPs per token x tokens per second per chip over the peak;
+    recomputed operations are not counted."""
+    per_token = costs.train_flops_per_token(run.hf, run.observed.seq_len)
+    return 100.0 * per_token * train_rate(run) / run.peak.bf16_flops
+
+
+def phase_share(run: Run, phases, decile: int | None = None):
+    """The host's time in ``phases`` as a share of the window. Without
+    ``decile``: every step's time in them, summed, over the window's
+    seconds. The Trainer dispatches without waiting, so in most steps
+    the host is throttled by the device somewhere inside these phases
+    and that sum holds the wait. With ``decile``: that decile over the
+    window's steps of a step's sum, over the seconds per step; right
+    after each metric fetch the host runs free for a step or two, and
+    the lower decile is such a step: what the loop costs the host when
+    nothing holds it back."""
+    o = run.observed
+    names = {f"train/phase/{p}" for p in phases}
+    per_step: dict[int, float] = {}
+    for s in o.spans:
+        if s.name in names and s.step is not None and s.step >= o.first_step:
+            per_step[s.step] = per_step.get(s.step, 0.0) + s.dur_s
+    if decile is None:
+        return 100.0 * sum(per_step.values()) / o.window_s if per_step else None
+    if len(per_step) < 10:
+        return None
+    own = statistics.quantiles(per_step.values(), n=10)[decile - 1]
+    return 100.0 * own / (o.window_s / o.steps_in_window)
+
+
+def hbm_claim_gb(run: Run):
+    claim = run.observed.step_hbm_bytes
+    return claim / 1e9 if claim else None
+
+
+# -- serving loop -------------------------------------------------------------
+
+
+def stats_ratio(run: Run, numerator: str, denominator: str,
+                scale: float = 1.0):
+    stats = run.observed.stats_window
+    if not stats[denominator]:
+        return None
+    return scale * stats[numerator] / stats[denominator]
+
+
+def prompt_step_share(run: Run):
+    o = run.observed
+    busy = o.stats_window["slot_steps_busy"]
+    return 100.0 * o.prompt_steps_window / busy if busy else None
+
+
+# -- from the trace -----------------------------------------------------------
+
+
+def _traced(run: Run) -> bool:
+    return run.trace is not None and bool(run.trace["devices"])
+
+
+def module_median_ms(run: Run, pattern: str):
+    if not _traced(run):
+        return None
+    runs = tr.module_seconds(run.trace, pattern)
+    return 1e3 * statistics.median(runs) if runs else None
+
+
+def device_idle(run: Run):
+    return 100.0 * tr.idle_share(run.trace) if _traced(run) else None
+
+
+def collective_exposed(run: Run):
+    if not _traced(run):
+        return None
+    return 100.0 * tr.collective_exposed_share(run.trace)
+
+
+def host_gap_per_chunk(run: Run, spans, module_pattern: str):
+    """Device-idle milliseconds inside the named host spans, per chunk."""
+    if not _traced(run):
+        return None
+    chunks = len(tr.module_seconds(run.trace, module_pattern))
+    if not chunks:
+        return None
+    program = tr.program_spans(run.trace)
+    idle = tr.idle_seconds_in(run.trace, program, set(spans))
+    return 1e3 * idle / chunks / max(len(run.trace["devices"]), 1)
+
+
+def kernel_roofline(run: Run, pattern: str, cost: str,
+                    module_pattern: str):
+    """A kernel's share of its roofline: the least time the chip could
+    take for the work the cell's shapes define (per execution of the
+    step program, per device) over the kernel's device time."""
+    if not _traced(run):
+        return None
+    measured = tr.op_seconds(run.trace, pattern, run.scopes)
+    executions = len(tr.module_seconds(run.trace, module_pattern))
+    executions /= max(len(run.trace["devices"]), 1)
+    if not measured["events"] or not executions:
+        return None
+    work = KERNEL_COSTS[cost](run)
+    least, bound = costs.roofline_seconds(work, run.peak)
+    share = tr.roofline_share(least * executions, measured["seconds"])
+    run.notes[f"{cost}.bound"] = bound
+    return 100.0 * share
+
+
+def _sparse_layers(hf: dict) -> int:
+    return hf["num_hidden_layers"] - costs.n_dense_layers(hf)
+
+
+def _scaled(work: dict, factor: float) -> dict:
+    return {k: v * factor for k, v in work.items()}
+
+
+def _expert_mm_train(run: Run) -> dict:
+    o = run.observed
+    one = costs.expert_mm_train(run.hf, o.tokens_per_step)
+    return _scaled(one, _sparse_layers(run.hf) / o.chips)
+
+
+def _flash_train(run: Run) -> dict:
+    o = run.observed
+    one = costs.flash_train(
+        run.hf, o.tokens_per_step // o.seq_len, o.seq_len
+    )
+    return _scaled(one, run.hf["num_hidden_layers"] / o.chips)
+
+
+def _expert_mm_decode(run: Run) -> dict:
+    """Per execution of the fused chunk: ``chunk_k`` decode steps."""
+    o = run.observed
+    touched = costs.expected_experts_touched(run.hf, o.slots)
+    one = costs.expert_mm_decode(run.hf, o.slots, touched)
+    return _scaled(one, _sparse_layers(run.hf) * o.chunk_k)
+
+
+KERNEL_COSTS = {
+    "expert_mm_train": _expert_mm_train,
+    "flash_train": _flash_train,
+    "expert_mm_decode": _expert_mm_decode,
+}
