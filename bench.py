@@ -99,7 +99,9 @@ def _measure(trainer, data_iter, *, warmup, steps, batch, seq_len,
     # feed the capture to tools/trace_summary.py
     profile_root = os.environ.get("D9D_BENCH_PROFILE_DIR")
     if profile_root and profile_tag:
-        with jax.profiler.trace(os.path.join(profile_root, profile_tag)):
+        from d9d_tpu.core.tracing import trace
+
+        with trace(os.path.join(profile_root, profile_tag)):
             for _ in range(2):
                 m = trainer.run_step(next(data_iter))
             jax.block_until_ready(m)

@@ -17,7 +17,6 @@ An op's ``text`` is the HLO instruction as the trace names it
 (``%fusion.3 = bf16[..] fusion(..)``), cut to ``TEXT_LIMIT`` characters.
 """
 
-import contextlib
 import glob
 import os
 import re
@@ -43,25 +42,13 @@ SPAN_PREFIXES = ("serve.", "bench/", "loop.", "train/", "pp")
 # -- capture (needs jax) ----------------------------------------------------
 
 
-@contextlib.contextmanager
 def capture(logdir: str):
-    """Profile the body into ``logdir``; the Python tracer stays off (it
-    records every Python call and slows the host it is measuring)."""
-    import jax
+    """Profile the body into ``logdir`` through the program's one control
+    for the profiler (``core/tracing.trace``: Python tracer off, host
+    tracer level 2, annotations on, a clock anchor at both ends)."""
+    from d9d_tpu.core.tracing import trace
 
-    from d9d_tpu.core.tracing import set_trace_annotations
-
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 2
-    os.makedirs(logdir, exist_ok=True)
-    set_trace_annotations(True)
-    jax.profiler.start_trace(logdir, profiler_options=options)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        set_trace_annotations(False)
+    return trace(logdir)
 
 
 def span(name: str):

@@ -12,10 +12,17 @@ steady state), for ``--seconds`` seconds inside it, and on after it
 closes until every request submitted inside has finished. Latencies are
 of the requests submitted inside the window; throughput is of the tokens
 emitted between the first and the last chunk boundary inside it.
+
+A traced run (``--trace 2``) is that run and then ``TRACE_SECONDS`` more
+of the same traffic under the profiler, once the drain is over and every
+number of the window is taken: the closed loop keeps refilling, so these
+are more ``boundary()`` calls. ``--trace 1`` records them before the
+window opens.
 """
 
 import dataclasses
 import itertools
+import os
 import time
 
 import jax
@@ -25,7 +32,7 @@ from d9d_tpu.telemetry import introspect
 from benchmarks.harness import build, correct, traffic
 from benchmarks.harness import trace as tr
 
-# seconds of traffic the traced run records before its window opens
+# seconds of traffic a traced run records
 TRACE_SECONDS = 4.0
 
 
@@ -65,6 +72,7 @@ class ServeObserved:
     marks: dict
     hlo_texts: list
     checks: dict
+    traced: tuple | None = None  # (start, end) of the capture, host clock
 
 
 def stats_snapshot(stats) -> dict:
@@ -72,7 +80,7 @@ def stats_snapshot(stats) -> dict:
 
 
 def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
-        devices) -> ServeObserved:
+        devices, trace_after: bool = False) -> ServeObserved:
     config = cell.config
     mix = traffic.sized(cell.traffic, tiny)
     serving = (config["tiny"] if tiny else config)["serving"]
@@ -142,14 +150,21 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
     )
     marks["compared"] = time.perf_counter()
 
-    # a traced run records TRACE_SECONDS of the same traffic first and
-    # opens the window once the capture has been written: stopping the
-    # profiler stalls the loop for seconds, which no metric may include
-    if trace_dir is not None:
+    def trace_chunks():
+        """(start, end) of TRACE_SECONDS of the same traffic under the
+        profiler. Stopping it stalls the loop for seconds, which no
+        metric may include: the capture lies wholly before the window
+        (--trace 1) or wholly after its drain (--trace 2)."""
         with tr.capture(trace_dir):
-            until = time.perf_counter() + TRACE_SECONDS
+            start = time.perf_counter()
+            until = start + TRACE_SECONDS
             while time.perf_counter() < until:
                 boundary()
+            return start, time.perf_counter()
+
+    traced = None
+    if trace_dir is not None and not trace_after:
+        traced = trace_chunks()
         marks["traced"] = time.perf_counter()
 
     mark = len(introspect.inventory())
@@ -176,11 +191,18 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
     while wanted & live.keys() and time.perf_counter() < give_up:
         boundary()
     requests_in = [f for f in finished if inside(f.submit_t)]
+    unfinished = len(wanted & live.keys())
+    if trace_dir is not None and trace_after:
+        # the profiler's first start costs seconds: it falls into a
+        # capture of nothing, which is thrown away
+        with tr.capture(os.path.join(trace_dir, "first_start")):
+            pass
+        traced = trace_chunks()
     observed = ServeObserved(
         kind=mix["kind"], chips=len(devices), slots=slots,
         chunk_k=build.CHUNK_K,
         opened_at=opened_at, closed_at=closed_at, boundaries=boundaries,
-        requests=requests_in, unfinished=len(wanted & live.keys()),
+        requests=requests_in, unfinished=unfinished,
         stats_window={k: stats1[k] - stats0[k] for k in stats0},
         # the step that consumes a prompt's last token emits a token, so
         # a request spends n_prompt - 1 steps only consuming
@@ -193,7 +215,7 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
             }) for t in introspect.compiled_hlo(name)]
             if trace_dir is not None else []
         ),
-        checks=checks,
+        checks=checks, traced=traced,
     )
     batcher.close()
     return observed

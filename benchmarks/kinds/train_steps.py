@@ -5,15 +5,21 @@ dispatched without waiting for the device and the metrics are fetched
 every ``log_every`` steps. The benchmark only feeds it batches through a
 ``DatasetProvider`` and listens to its spans. When the last warm-up step
 has been dispatched the benchmark waits for the device
-(``block_until_ready``) and opens the window; the batches stop once
-``--seconds`` have passed; when ``train()`` has returned it waits for
-the device again and closes the window. Tokens per second is every step
-dispatched in between over that whole interval: all the work and all
-the time of the window.
+(``block_until_ready``) and opens the window; at the first step that
+ends after ``--seconds`` have passed it waits for the device again and
+closes the window, inside ``train()``, and the batches stop. Tokens per
+second is every step dispatched in between over that whole interval: all
+the work and all the time of the window.
+
+A traced run (``--trace 2``) is that run and then ``TRACE_STEPS`` more
+steps of the same ``train()`` under the profiler, started once the
+window has closed and its numbers are taken. ``--trace 1`` records them
+first, from the window's start, and opens the window anew afterwards.
 """
 
 import dataclasses
 import math
+import os
 import time
 
 import jax
@@ -31,28 +37,41 @@ from d9d_tpu.telemetry.sinks import TelemetrySink
 from benchmarks.harness import build, correct, traffic
 from benchmarks.harness import trace as tr
 
-# steps the traced run records from the window's start
+# steps a traced run records
 TRACE_STEPS = 6
 
 
 class WindowSink(TelemetrySink):
-    """Collects the Trainer's spans and opens the window when the last
-    warm-up step has been dispatched and has finished on the device. In
-    a traced run it records the first ``TRACE_STEPS`` steps after that
-    and opens the window anew once the capture has been written, so that
-    no rate or share includes the profiler's own stop."""
+    """Collects the Trainer's spans, opens the window when the last
+    warm-up step has been dispatched and has finished on the device, and
+    closes it, in the loop's own thread, at the first step that ends
+    past the deadline: it waits for the device, takes the time and the
+    last step counted, and the batches stop (``windowed``).
 
-    def __init__(self, warmup_steps: int, seconds: float, trace_dir):
+    ``--trace 1`` (``trace_dir`` alone) records the first ``TRACE_STEPS``
+    steps after the window's start and opens the window anew once the
+    capture has been written. ``--trace 2`` (``trace_after``) starts the
+    capture when the window has closed and lets ``TRACE_STEPS`` more
+    steps through. Either way no rate or share includes the profiler's
+    own start or stop."""
+
+    def __init__(self, warmup_steps: int, seconds: float, trace_dir,
+                 trace_after: bool = False):
         self.warmup_steps = warmup_steps
         self.seconds = seconds
         self.trace_dir = trace_dir
+        self.trace_after = trace_after
         self.trainer = None
         self.spans = []
         self.opened_at = None
         self.first_step = None  # first step counted in the window
         self.deadline = math.inf
         self.inventory_mark = None
-        self._capture = None
+        self.closed_at = None
+        self.compiles_in_window = None
+        self.stop_after = None  # no batch is handed out for a later step
+        self.traced = None  # (start, end) of the capture, host clock
+        self._capture = self._capture_from = None
 
     def _open(self, next_step: int) -> None:
         jax.block_until_ready(self.trainer.params)
@@ -61,33 +80,59 @@ class WindowSink(TelemetrySink):
         self.deadline = self.opened_at + self.seconds
         self.inventory_mark = len(introspect.inventory())
 
+    def _close(self, step: int) -> None:
+        jax.block_until_ready(self.trainer.params)
+        self.closed_at = time.perf_counter()
+        self.stop_after = step  # the last step counted
+        self.compiles_in_window = (
+            len(introspect.inventory()) - self.inventory_mark
+        )
+        if self.trace_after:
+            # the profiler's first start costs seconds: it falls into a
+            # capture of nothing, which is thrown away
+            with tr.capture(os.path.join(self.trace_dir, "first_start")):
+                pass
+            self.stop_after = step + TRACE_STEPS
+            self._start_capture()
+
+    def _start_capture(self) -> None:
+        self._capture = tr.capture(self.trace_dir)
+        self._capture.__enter__()
+        self._capture_from = time.perf_counter()
+
     def on_span(self, span) -> None:
+        if self.closed_at is not None:
+            return  # the window's spans are taken
         self.spans.append(span)
         if span.name != "train/step":
             return
         if span.step == self.warmup_steps - 1:
             self._open(span.step + 1)
-            if self.trace_dir is not None:
-                self._capture = tr.capture(self.trace_dir)
-                self._capture.__enter__()
-        elif (
-            self._capture is not None
-            and span.step == self.warmup_steps - 1 + TRACE_STEPS
-        ):
-            jax.block_until_ready(self.trainer.params)
-            self.stop_capture()
-            self._open(span.step + 1)
+            if self.trace_dir is not None and not self.trace_after:
+                self._start_capture()
+        elif self._capture is not None:
+            if span.step == self.warmup_steps - 1 + TRACE_STEPS:
+                jax.block_until_ready(self.trainer.params)
+                self.stop_capture()
+                self._open(span.step + 1)
+        elif span.t0 + span.dur_s >= self.deadline:
+            self._close(span.step)
 
     def stop_capture(self) -> None:
         if self._capture is not None:
             self._capture.__exit__(None, None, None)
             self._capture = None
+            self.traced = (self._capture_from, time.perf_counter())
 
 
 def windowed(batches, sink: WindowSink):
-    """The generator's batches until the window's deadline has passed."""
-    for batch in batches:
-        if time.perf_counter() >= sink.deadline:
+    """The generator's batches, one per step, until the sink has closed
+    the window (and, in a ``--trace 2`` run, ``TRACE_STEPS`` more). It
+    may run in the prefetch thread, ahead of the loop: it never waits,
+    and the few batches it has handed out beyond the last step run as
+    steps that nothing counts."""
+    for step, batch in enumerate(batches):
+        if sink.stop_after is not None and step > sink.stop_after:
             return
         yield batch
 
@@ -109,6 +154,7 @@ class TrainObserved:
     losses: list
     hlo_texts: list
     checks: dict
+    traced: tuple | None = None  # (start, end) of the capture, host clock
 
     @property
     def window_s(self) -> float:
@@ -152,7 +198,7 @@ def one_chip_loss(config, mix, tiny, seed, batch, device):
 
 
 def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
-        devices) -> TrainObserved:
+        devices, trace_after: bool = False) -> TrainObserved:
     config = cell.config
     mix = traffic.sized(cell.traffic, tiny)
     local_cfg, hf = build.sizes(config, tiny)
@@ -182,7 +228,7 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
 
     ctx = build.mesh_context(config, devices)
     cfg = build.sharded_model_config(config, ctx, tiny)
-    sink = WindowSink(mix["warmup_steps"], seconds, trace_dir)
+    sink = WindowSink(mix["warmup_steps"], seconds, trace_dir, trace_after)
 
     def stream():
         yield first_batch
@@ -212,14 +258,13 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
     tele.add_sink(sink)
     try:
         history = trainer.train()
+        jax.block_until_ready(trainer.params)
     finally:
         sink.stop_capture()
         tele.remove_sink(sink, close=False)
-    jax.block_until_ready(trainer.params)
-    closed_at = time.perf_counter()
 
-    if sink.opened_at is None:
-        raise RuntimeError("the window never opened: warm-up never ended")
+    if sink.closed_at is None:
+        raise RuntimeError("the window never opened, or never closed")
     steps = [
         s for s in sink.spans
         if s.name == "train/step" and s.step >= sink.first_step
@@ -230,16 +275,16 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
         steps_in_window=len(steps),
         tokens_per_step=mix["sequences"] * mix["seq_len"],
         seq_len=mix["seq_len"], spans=sink.spans,
-        opened_at=sink.opened_at, closed_at=closed_at,
+        opened_at=sink.opened_at, closed_at=sink.closed_at,
         first_step=sink.first_step, marks=marks,
-        compiles_in_window=len(introspect.inventory()) - sink.inventory_mark,
+        compiles_in_window=sink.compiles_in_window,
         step_hbm_bytes=int(records[-1].hbm_peak_bytes) if records else 0,
         losses=[h["loss"] for h in history],
         hlo_texts=(
             list(introspect.compiled_hlo("train_step"))
             if trace_dir is not None else []
         ),
-        checks=checks,
+        checks=checks, traced=sink.traced,
     )
     trainer.close()
     return observed

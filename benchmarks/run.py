@@ -1,7 +1,7 @@
 """One run of one cell of the benchmark.
 
     python3 benchmarks/run.py --workload <config>.<traffic> --seed N \\
-        --seconds S --trace 0|1
+        --seconds S --trace 0|1|2
 
 A new process each time. It builds the cell's system from its files
 (``benchmarks/configs``, ``benchmarks/traffic``), warms up, compares the
@@ -10,6 +10,15 @@ for ``--seconds`` seconds and prints one JSON object as its last line:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, in a
 traced run, ``breakdown``. With ``--trace 0`` the metrics are the cell's
 end-to-end metrics, with ``--trace 1`` its per-layer metrics.
+
+``--trace 2`` is a ``--trace 0`` run followed by a short traced window in
+the same process. Until the measured window has closed and its numbers
+are taken it does what ``--trace 0`` does and nothing of the profiler is
+started; then it starts and stops the profiler once for nothing (the
+first start's cost falls into no number), traces a few seconds of the
+same traffic and prints one line with both kinds of metric: end to end,
+``program_span`` and ``program_counter`` metrics from the measured
+window, ``device_trace`` metrics from the traced seconds.
 
 It needs a TPU with as many chips as the cell asks for and exits 4
 without a result when there is none: there is no fallback. ``--tiny``
@@ -39,7 +48,7 @@ def parse(argv):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--tiny", action="store_true",
                     help="toy widths, any backend, counts only (tests)")
     return ap.parse_args(argv)
@@ -75,7 +84,7 @@ def main(argv=None) -> int:
 
     import importlib
 
-    from benchmarks.harness import readers
+    from benchmarks.harness import layers, readers
     from benchmarks.harness import trace as tr
     from d9d_tpu.telemetry import introspect
 
@@ -88,7 +97,8 @@ def main(argv=None) -> int:
     )
     try:
         observed = kind.run(
-            cell, args.seed, args.seconds, trace_dir, args.tiny, devices
+            cell, args.seed, args.seconds, trace_dir, args.tiny, devices,
+            trace_after=args.trace == 2,
         )
         run = readers.Run(
             cell=cell, observed=observed,
@@ -98,13 +108,19 @@ def main(argv=None) -> int:
         )
         if trace_dir is not None and platform == "tpu":
             run.trace = tr.load_xplane(tr.newest_xplane(trace_dir))
-            run.scopes = tr.scopes_from_hlo(observed.hlo_texts)
+            # the expert matmuls' custom calls carry no scope of their own
+            run.scopes = layers.with_expert_matmuls(
+                tr.scopes_from_hlo(observed.hlo_texts), observed.hlo_texts
+            )
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
     verdict = kind.verdict(observed)
-    entries = cell.per_layer if args.trace else cell.end_to_end
+    entries = {
+        0: cell.end_to_end, 1: cell.per_layer,
+        2: cell.end_to_end + cell.per_layer,
+    }[args.trace]
     metrics = {}
     for entry in entries:
         # a CPU run gives counts only: nothing timed is reported there
@@ -134,6 +150,15 @@ def main(argv=None) -> int:
             "device_ops": tr.top_ops(run.trace, run.scopes, n=10),
             "idle_gaps": tr.idle_gaps(run.trace, spans, n=10),
         }
+        if args.trace == 2:
+            # the same idle time by the program's own spans of the traced
+            # seconds (the always-on phase clocks, host/gc), placed on
+            # the trace's clock by the program's clock anchor
+            line["breakdown"]["idle_gaps_by_program_span"] = tr.idle_gaps(
+                run.trace,
+                layers.phase_spans_on_trace(run.trace, *observed.traced),
+                n=10,
+            )
     print(json.dumps({
         "workload": cell.name, "seed": args.seed, "seconds": args.seconds,
         "samples": kind.samples(observed),

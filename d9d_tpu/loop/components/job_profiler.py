@@ -6,8 +6,11 @@ schedule, per-rank chrome traces. TPU equivalent: ``jax.profiler`` traces
 (viewable in XProf/TensorBoard, incl. device HLO timelines); one trace dir
 per cycle, named by step and process index.
 
-Two capture modes share one profiler (jax allows exactly one live trace
-per process, so they are mutually exclusive and lock-guarded):
+Two capture modes share one profiler. jax allows exactly one live trace
+per process: ``core/tracing.start_trace`` / ``stop_trace`` own that rule
+(and the profiler's options, the annotation flag and the clock anchor);
+both modes start and stop through them, so they exclude each other and
+any other capture in the process (a benchmark's, a tool's):
 
 - the original step-cadence schedule (``every_steps``/``active_steps``);
 - :meth:`capture` — a wall-clock one-shot for operator-driven captures
@@ -26,7 +29,7 @@ from pathlib import Path
 
 import jax
 
-from d9d_tpu.core.tracing import set_trace_annotations
+from d9d_tpu.core import tracing
 from d9d_tpu.telemetry import get_telemetry
 from d9d_tpu.telemetry.host_sampler import HostSampler
 
@@ -86,6 +89,18 @@ class JobProfiler:
             # take down the trace stop path
             logger.warning("host-stacks emission failed", exc_info=True)
 
+    def _start(self, out: Path) -> None:
+        """Profiler, then sampler: the profiler's first-use initialization
+        can take seconds and must not pollute the host-stacks window."""
+        tracing.start_trace(out)
+        self._start_sampler()
+
+    def _stop(self) -> None:
+        """Sampler, then profiler: stopping serializes the xplane (can
+        take seconds) and must not pollute the host-stacks window."""
+        self._stop_sampler()
+        tracing.stop_trace()
+
     def step_begin(self, step: int) -> None:
         if self._tracing_until is None and self._should_start(step):
             with self._lock:
@@ -95,28 +110,18 @@ class JobProfiler:
                     self.trace_dir
                     / f"step_{step}_proc_{jax.process_index()}"
                 )
-                out.mkdir(parents=True, exist_ok=True)
                 logger.info("profiler: tracing steps %d..%d -> %s",
                             step, step + self.active_steps - 1, out)
-                # host-side action/staging annotations only exist inside
-                # capture windows — zero cost on unprofiled steps
-                set_trace_annotations(True)
-                jax.profiler.start_trace(str(out))
-                # sampler last: start_trace's first-use initialization can
-                # take seconds and must not pollute the host-stacks window
-                # (mirror of the stop ordering in step_end)
-                self._start_sampler()
+                try:
+                    self._start(out)
+                except tracing.TraceBusyError:
+                    return  # someone else's capture: skip this cycle
                 self._tracing_until = step + self.active_steps
 
     def step_end(self, step: int) -> None:
         if self._tracing_until is not None and step + 1 >= self._tracing_until:
             with self._lock:
-                # sampler first: stop_trace serializes the xplane (can
-                # take seconds) and that teardown must not pollute the
-                # host-stacks window
-                self._stop_sampler()
-                jax.profiler.stop_trace()
-                set_trace_annotations(False)
+                self._stop()
                 self._tracing_until = None
 
     # -- on-demand one-shot capture ------------------------------------
@@ -128,7 +133,8 @@ class JobProfiler:
         return the capture directory immediately (the trace stops on a
         timer thread after ``duration_s``). Returns ``None`` — never
         raises to its caller's caller — when the profiler is already
-        busy (a cadence window or another capture is live)."""
+        busy (a cadence window, another capture, or any other trace
+        started through ``core/tracing`` is live)."""
         with self._lock:
             if self._capture_dir is not None or self._tracing_until is not None:
                 return None
@@ -137,21 +143,14 @@ class JobProfiler:
                 Path(out_dir)
                 / f"ondemand_{stamp}_proc{jax.process_index()}"
             )
-            out.mkdir(parents=True, exist_ok=True)
             logger.info(
                 "profiler: on-demand capture (%.1fs) -> %s",
                 duration_s, out,
             )
-            set_trace_annotations(True)
             try:
-                jax.profiler.start_trace(str(out))
-            except Exception:
-                set_trace_annotations(False)
-                raise
-            # sampler after start_trace: first-use profiler init can take
-            # seconds and must not pollute the host-stacks window (the
-            # stop side mirrors this — sampler stops before stop_trace)
-            self._start_sampler()
+                self._start(out)
+            except tracing.TraceBusyError:
+                return None
             self._capture_dir = out
             tele = get_telemetry()
             tele.counter("profile/captures").add(1)
@@ -168,13 +167,11 @@ class JobProfiler:
         with self._lock:
             if self._capture_dir is None:
                 return
-            self._stop_sampler()  # before stop_trace: see step_end
             try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — a stop race (close()
-                # already stopped it) must not kill the timer thread
+                self._stop()
+            except Exception:  # noqa: BLE001 — a failing stop must not
+                # kill the timer thread
                 logger.warning("capture stop failed", exc_info=True)
-            set_trace_annotations(False)
             logger.info(
                 "profiler: on-demand capture done -> %s", self._capture_dir
             )
@@ -188,7 +185,5 @@ class JobProfiler:
         self._finish_capture()  # no-op when no capture is live
         with self._lock:
             if self._tracing_until is not None:
-                self._stop_sampler()
-                jax.profiler.stop_trace()
-                set_trace_annotations(False)
+                self._stop()
                 self._tracing_until = None

@@ -61,9 +61,14 @@ queue-wait and per-chunk slot-occupancy histograms are derived from the
 host clock at the SAME boundaries the token readbacks already happen at
 — the fused path's host-interaction contract (one dispatch + one
 readback per chunk) is untouched; ``tests/telemetry`` pins
-``stats.readbacks`` against it. Host dispatch/readback/admission
-regions carry ``serve.*`` ``core/tracing.annotate`` labels inside
-profiler capture windows (``tools/trace_summary.py`` groups them).
+``stats.readbacks`` against it. Every fused chunk is partitioned
+gap-free by an always-on phase clock, the serving twin of the Trainer's
+(``serve/phase/{admit,plan,dispatch,readback,commit}`` closed by
+``serve/step``: host clock only, on whether or not a profiler is), so a
+stall inside a chunk names its phase with no capture live. Host
+dispatch/readback/admission regions additionally carry ``serve.*``
+``core/tracing.annotate`` labels inside profiler capture windows
+(``benchmarks/harness/trace.py`` attributes device-idle gaps to them).
 The monitoring plane rides the same boundaries: every request carries
 a fleet-stable trace id (``request_trace`` JSONL milestones),
 ``replica_label`` namespaces the serve instruments per replica
@@ -106,6 +111,7 @@ finished, so those requests complete wholly on the old generation.
 
 import _thread
 import collections
+import contextlib
 import dataclasses
 import inspect
 import itertools
@@ -213,6 +219,7 @@ class _ChunkPlan:
     rids: list            # rid per slot at dispatch (-1 = idle)
     emit_from: list       # first step index (within the chunk) that emits
     version: int = 0      # weights generation this chunk dispatched with
+    index: int = 0        # chunk index since the last stats reset
 
 
 # default per-transfer staging bound for KV page shipments: the same
@@ -324,7 +331,10 @@ class ServeStats:
     fused loop divides by K); ``readbacks`` counts device→host token
     fetches; ``device_steps`` counts single-token decode steps executed
     on device; ``slot_steps_busy / slot_steps_total`` give slot
-    occupancy (busy includes prompt-consumption steps).
+    occupancy (busy includes prompt-consumption steps);
+    ``slot_steps_prompt`` is the part of busy in which a row only
+    consumed a prompt token and emitted nothing, so busy less prompt is
+    the generation steps, exactly.
     """
 
     host_dispatches: int = 0
@@ -333,6 +343,7 @@ class ServeStats:
     device_steps: int = 0
     emitted_tokens: int = 0
     slot_steps_busy: int = 0
+    slot_steps_prompt: int = 0
     slot_steps_total: int = 0
     # degraded-mode counters: submits rejected by the bounded queue,
     # requests expired by their deadline (queued or running), requests
@@ -692,6 +703,11 @@ class ContinuousBatcher:
         self._rem_d = jnp.zeros((batch_size,), jnp.int32)
         # dispatched-but-unharvested fused chunks, FIFO
         self._pending: collections.deque[tuple] = collections.deque()
+        # the open chunk's phase clock (step_chunk / step); None inside
+        # the overlapped drain, where one chunk's harvest overlaps the
+        # next one's compute: each dispatch and each harvest then times
+        # its own phases and no ``serve/step`` partition is emitted
+        self._clock = None
 
         # opt-in live metrics endpoint (telemetry/export.py); weakrefs so
         # the endpoint can never pin a discarded batcher's device cache
@@ -1816,6 +1832,7 @@ class ContinuousBatcher:
                 self._kv.mark_filled(slot.rid)
             if slot.pending:  # still consuming the prompt
                 self._tokens[i] = slot.pending.pop(0)
+                self.stats.slot_steps_prompt += 1
                 continue
             tok = int(nxt[i])  # sampled from the row's latest position
             emitted[slot.rid] = tok
@@ -1867,7 +1884,17 @@ class ContinuousBatcher:
         dispatch with ``admit=False`` and a plan that is deterministic
         given the previous dispatch (prompt feeding advances host-side,
         everything else is a device carry).
+
+        Phases (``serve/phase/*``, host clock, always on): ``admit`` is
+        a pending weight swap, expiry, page allocation, slot filling and
+        the page-table push; ``plan`` builds the forced tokens, splits
+        the RNG and stages the arguments on the device; ``dispatch`` is
+        the call into the fused program (enqueue only) and the record
+        of the plan.
         """
+        clock = self._clock or self._tele.phases(
+            "serve", step=self.stats.chunks
+        )
         self._apply_pending_weights()
         admit_mask = np.zeros((self._b,), bool)
         admit_budget = np.zeros((self._b,), np.int32)
@@ -1909,6 +1936,7 @@ class ContinuousBatcher:
                 if self._paged:
                     self._push_page_table()
                 self._note_pages()
+        clock.mark("admit")
 
         forced = np.zeros((self._b, k), np.int32)
         n_forced = np.zeros((self._b,), np.int32)
@@ -1939,14 +1967,16 @@ class ContinuousBatcher:
             if self._paged:
                 admit_args += (jnp.asarray(admit_pos),)
         with annotate("serve.dispatch"):
+            # forced_t: scan xs layout [K, B]
+            plan_args = (
+                jnp.asarray(forced.T), jnp.asarray(n_forced),
+                jnp.asarray(emit_from),
+            )
+            clock.mark("plan")
             (self._cache, self._tok_d, self._pos_d, self._live_d,
              self._rem_d, toks) = fused(
                 self._params, self._cache, self._tok_d, self._pos_d,
-                self._live_d, self._rem_d, sub,
-                # forced_t: scan xs layout [K, B]
-                jnp.asarray(forced.T), jnp.asarray(n_forced),
-                jnp.asarray(emit_from),
-                *admit_args,
+                self._live_d, self._rem_d, sub, *plan_args, *admit_args,
             )
         if self._paged:
             for slot in self._slots:
@@ -1959,20 +1989,26 @@ class ContinuousBatcher:
         self._pending.append(
             (toks,
              _ChunkPlan(k=k, rids=rids, emit_from=emit_from.tolist(),
-                        version=self.weights_version))
+                        version=self.weights_version,
+                        index=self.stats.chunks))
         )
         self.stats.host_dispatches += 1
         self.stats.chunks += 1
         self.stats.device_steps += k
         self._progress_t = time.perf_counter()
+        clock.mark("dispatch")
 
     def _harvest_one(self) -> dict[int, list[int]]:
         """Fetch the oldest in-flight chunk (ONE readback) and replay the
         device's emission/stop logic on it to commit host state."""
         toks_d, plan = self._pending.popleft()
+        clock = self._clock or self._tele.phases("serve", step=plan.index)
         with annotate("serve.readback"):
             # d9d-lint: disable=D9D003 — the single [B, K] readback per chunk
             toks = np.asarray(toks_d)
+        # the wait for the device plus the transfer; what follows replays
+        # emission and stop logic on the host: serve/phase/commit
+        clock.mark("readback")
         now = time.perf_counter()
         self._progress_t = now
         if self._first_readback_t is None:
@@ -2014,6 +2050,10 @@ class ContinuousBatcher:
                         self._release_row_pages(i, device_dead=True)
                     break
             self.stats.slot_steps_busy += busy_steps
+            # steps in which the row only consumed a prompt token, from
+            # the plan the chunk was dispatched with; a row emits before
+            # it dies, so these never pass the step it died on
+            self.stats.slot_steps_prompt += min(plan.emit_from[i], plan.k)
             chunk_busy += busy_steps
             if rid in emitted:
                 self._note_tokens(rid, len(emitted[rid]), now)
@@ -2023,6 +2063,11 @@ class ContinuousBatcher:
             "serve/slot_util", chunk_busy / (self._b * plan.k), _UTIL_EDGES
         )
         self._note_throughput(chunk_tokens, now)
+        if self._clock is None:
+            # the overlapped drain: this harvest's own clock, phases only
+            # (inside step_chunk / step the chunk's clock ends ``commit``
+            # when it closes and emits ``serve/step``)
+            clock.mark("commit")
         return emitted
 
     def _sync(self) -> dict[int, list[int]]:
@@ -2065,8 +2110,27 @@ class ContinuousBatcher:
         self._sync()
         if not self._busy() and not self._queue:
             return {}
-        self._dispatch_chunk(self._k, admit=True)
-        return self._sync()
+        with self._chunk_clock():
+            self._dispatch_chunk(self._k, admit=True)
+            return self._sync()
+
+    @contextlib.contextmanager
+    def _chunk_clock(self):
+        """One dispatch and one harvest under one phase clock: the
+        ``serve/phase/*`` spans partition the chunk gap-free and
+        ``serve/step`` closes it (see ``_dispatch_chunk`` and
+        ``_harvest_one`` for what each phase holds)."""
+        clock = self._clock = self._tele.phases(
+            "serve", step=self.stats.chunks
+        )
+        try:
+            yield
+        except BaseException:
+            clock.cancel()  # not a chunk: no spans for it
+            raise
+        finally:
+            self._clock = None
+            clock.close(tail_phase="commit")
 
     def step(self) -> dict[int, int]:
         """Admit waiting requests, advance every slot one token; returns
@@ -2081,10 +2145,10 @@ class ContinuousBatcher:
         self._sync()
         if not self._busy() and not self._queue:
             return {}
-        self._dispatch_chunk(1, admit=True)
-        return {
-            rid: toks[0] for rid, toks in self._sync().items() if toks
-        }
+        with self._chunk_clock():
+            self._dispatch_chunk(1, admit=True)
+            emitted = self._sync()
+        return {rid: toks[0] for rid, toks in emitted.items() if toks}
 
     def drain(self, max_steps: int = 100_000) -> dict[int, list[int]]:
         """Run until every submitted request has finished.

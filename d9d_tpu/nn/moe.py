@@ -214,14 +214,26 @@ def grouped_swiglu_apply(
     fp32 master weights (the concat becomes the largest single HBM term);
     ``D9D_TPU_MOE_FUSED_GATE_UP=0`` switches to two grouped matmuls for
     the on-chip A/B (ROADMAP ``env-selected-kernels``; not run yet).
+
+    The ``moe/experts/{gate_up,act,down}`` scopes are HLO metadata only:
+    in a trace they say which grouped matmul (forward or transposed) an
+    op belongs to. The TPU compiler rewrites ``ragged_dot`` into a custom
+    call named ``ragged-dot-none`` and drops its ``op_name``: the
+    scopes stay on what stands around the call (the weight concatenation,
+    the slices, the probability weighting) and in the lowered HLO, and
+    a trace's reader takes the calls themselves by their name
+    (``benchmarks/harness/layers.py``).
     """
     x = permuted_x.astype(dtype)
-    g, u = gate_up_grouped_matmul(
-        x, gate_w.astype(dtype), up_w.astype(dtype), group_sizes
-    )
-    hidden = silu_mul(g, u)
-    out = grouped_matmul(hidden, down_w.astype(dtype), group_sizes)
-    return out * permuted_probs[:, None].astype(dtype)
+    with jax.named_scope("moe/experts/gate_up"):
+        g, u = gate_up_grouped_matmul(
+            x, gate_w.astype(dtype), up_w.astype(dtype), group_sizes
+        )
+    with jax.named_scope("moe/experts/act"):
+        hidden = silu_mul(g, u)
+    with jax.named_scope("moe/experts/down"):
+        out = grouped_matmul(hidden, down_w.astype(dtype), group_sizes)
+        return out * permuted_probs[:, None].astype(dtype)
 
 
 class SharedSwiGLU(nn.Module):
@@ -385,7 +397,8 @@ class MoELayer(nn.Module):
     def _forward_local(
         self, x: Array, topk_ids: Array, topk_probs: Array
     ) -> Array:
-        sort = sort_tokens_by_expert(topk_ids, self.num_grouped_experts)
+        with jax.named_scope("moe/permute"):
+            sort = sort_tokens_by_expert(topk_ids, self.num_grouped_experts)
         if moe_ffn_backend() in ("pallas", "pallas_gather"):
             # one fused Pallas kernel over the group-aligned layout: the
             # [M, 2*inter]/[M, inter] intermediates and the gate+up weight
@@ -397,17 +410,20 @@ class MoELayer(nn.Module):
             # scatter-accumulates token-major [N, D] output in VMEM, so
             # the expert-sorted y rows never hit HBM either
             # (D9D_TPU_MOE_COMBINE=unfused for the A/B)
-            return fused_moe_ffn_apply(
-                x, topk_probs, sort,
-                self.grouped_experts.gate_weight,
-                self.grouped_experts.up_weight,
-                self.grouped_experts.down_weight,
-                self.dtype,
-                num_experts=self.num_grouped_experts,
-            )
-        permuted_x, permuted_probs = permute_tokens(x, topk_probs, sort)
+            with jax.named_scope("moe/experts/fused_ffn"):
+                return fused_moe_ffn_apply(
+                    x, topk_probs, sort,
+                    self.grouped_experts.gate_weight,
+                    self.grouped_experts.up_weight,
+                    self.grouped_experts.down_weight,
+                    self.dtype,
+                    num_experts=self.num_grouped_experts,
+                )
+        with jax.named_scope("moe/permute"):
+            permuted_x, permuted_probs = permute_tokens(x, topk_probs, sort)
         y = self.grouped_experts(permuted_x, permuted_probs, sort.group_sizes)
-        return unpermute_combine(y, sort, x.shape[0]).astype(x.dtype)
+        with jax.named_scope("moe/combine"):
+            return unpermute_combine(y, sort, x.shape[0]).astype(x.dtype)
 
     # --- EP path (reference communications/deepep.py, re-designed) -------
 

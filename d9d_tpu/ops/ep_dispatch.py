@@ -122,9 +122,12 @@ def ep_dispatch_compute_combine(
     # permutation — see ops/moe.py stable_expert_order; TPU sorts are
     # bitonic and this runs per MoE layer per microbatch)
     ids_flat = ids_loc.reshape(-1)
-    order, pair_dest, counts = stable_expert_order(ids_flat, e_loc * ep_world)
-    token_of = order // k
-    x_rows = jnp.take(x_loc, token_of, axis=0)  # [m, D]
+    with jax.named_scope("moe/permute"):
+        order, pair_dest, counts = stable_expert_order(
+            ids_flat, e_loc * ep_world
+        )
+        token_of = order // k
+        x_rows = jnp.take(x_loc, token_of, axis=0)  # [m, D]
 
     # 2. tiny count exchange: S[s, e] = rows shard s routes to expert e
     S = lax.all_gather(counts, ep_axes, axis=0)  # [W, E]
@@ -180,15 +183,17 @@ def ep_dispatch_compute_combine(
     labels = (q[:, None] >= jnp.take(incl, src_of, axis=0)).sum(axis=1)
     labels = jnp.clip(labels, 0, e_loc - 1)  # padding rows → last group
 
-    by_expert, dest, group_sizes = stable_expert_order(labels, e_loc)
-    rows_sorted = jnp.take(recv, by_expert, axis=0)
+    with jax.named_scope("moe/permute"):
+        by_expert, dest, group_sizes = stable_expert_order(labels, e_loc)
+        rows_sorted = jnp.take(recv, by_expert, axis=0)
 
     with jax.named_scope("ep/expert_compute"):
         y_sorted = expert_fn(rows_sorted, group_sizes)
     # un-sort via the inverse permutation as a gather (dest[by_expert[r]]
     # == r) — cheaper than a zeros+scatter on TPU, same as ops/moe.py's
     # unpermute_combine
-    y_buf = jnp.take(y_sorted, dest, axis=0)
+    with jax.named_scope("moe/combine"):
+        y_buf = jnp.take(y_sorted, dest, axis=0)
 
     # 5. mirrored return trip (swap send/recv roles). My slice for source s
     # must land where s's sorted rows for me begin: s's own block layout.
@@ -207,6 +212,7 @@ def ep_dispatch_compute_combine(
 
     # 6. weight by router probs, fold the k assignments per token
     # (collision-free gather form — see ops/moe.py combine_pairs)
-    probs_rows = jnp.take(probs_loc.reshape(-1), order)
-    weighted = home * probs_rows[:, None].astype(home.dtype)
-    return combine_pairs(weighted, pair_dest, n)
+    with jax.named_scope("moe/combine"):
+        probs_rows = jnp.take(probs_loc.reshape(-1), order)
+        weighted = home * probs_rows[:, None].astype(home.dtype)
+        return combine_pairs(weighted, pair_dest, n)

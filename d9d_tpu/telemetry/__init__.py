@@ -15,10 +15,13 @@ Usage shape (see docs/design/observability.md):
 
 Metric namespace (enforced by convention, documented in the design doc):
 ``train/*`` trainer loop, ``pp/*`` pipeline executor, ``serve/*``
-continuous batching, ``io/*`` checkpoint + data IO.
+continuous batching, ``io/*`` checkpoint + data IO, ``host/*`` the
+process itself (``host/gc``: the cyclic collector's pauses).
 """
 
+import collections
 import contextlib
+import gc
 import logging
 import threading
 import time as _time
@@ -115,6 +118,9 @@ class Telemetry:
         # verdict of the moment things went wrong
         self.last_numerics = None
         self._slo_eval_warned_t = -float("inf")
+        self._gc_t0: float | None = None
+        # pauses the collector's hook has seen and no span records yet
+        self._gc_pauses: collections.deque = collections.deque()
 
     # -- instrument passthrough (the API components actually use) ------
 
@@ -172,6 +178,8 @@ class Telemetry:
             return tuple(self._sinks)
 
     def _on_span(self, span: Span) -> None:
+        if self._gc_pauses and span.name != "host/gc":
+            self._record_gc_pauses()
         for sink in self.sinks:
             sink.on_span(span)
 
@@ -212,6 +220,7 @@ class Telemetry:
         Each flush also (a) evaluates the attached SLO monitor first, so
         slo/* instruments in the snapshot are current, and (b) appends
         the snapshot to the registry's flight-recorder ring."""
+        self._record_gc_pauses()
         if self.slo_monitor is not None:
             try:
                 self.slo_monitor.evaluate()
@@ -269,7 +278,59 @@ class Telemetry:
         self.flight_recorder = FlightRecorder(directory, **kwargs)
         return self.flight_recorder
 
+    # -- the cyclic collector's pauses ---------------------------------
+
+    # a young-generation collection takes microseconds and comes every
+    # few hundred allocations: only a pause worth seeing is recorded
+    GC_SPAN_MIN_S = 1e-3
+
+    def watch_gc(self) -> None:
+        """Record a ``host/gc`` span (meta: generation, collected) for
+        every collection of generation 2 and every collection longer
+        than ``GC_SPAN_MIN_S``: a pause of the whole interpreter that no
+        loop's own phases can name. One ``gc.callbacks`` hook, installed
+        for the process hub (:func:`get_telemetry`, :func:`set_telemetry`)
+        and removed by :meth:`close`; nothing runs on a step path.
+
+        A collection starts wherever an allocation happens, also while
+        this thread holds the registry's or a sink's lock, so the hook
+        itself only reads the clock and appends to a deque; the span is
+        recorded with the next span of any kind and at every flush."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def unwatch_gc(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        now = _time.perf_counter()
+        if phase == "start":
+            self._gc_t0 = now
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:
+            return
+        generation = info.get("generation")
+        if generation == 2 or now - t0 > self.GC_SPAN_MIN_S:
+            self._gc_pauses.append(
+                (t0, now - t0, generation, info.get("collected"))
+            )
+
+    def _record_gc_pauses(self) -> None:
+        while self._gc_pauses:
+            try:
+                t0, dur_s, generation, collected = self._gc_pauses.popleft()
+            except IndexError:  # another thread took the last one
+                return
+            self.registry.record_span(
+                "host/gc", t0, dur_s,
+                meta={"generation": generation, "collected": collected},
+            )
+
     def close(self) -> None:
+        self.unwatch_gc()
+        self._record_gc_pauses()
         for sink in self.sinks:
             self.remove_sink(sink)
 
@@ -285,6 +346,7 @@ def get_telemetry() -> Telemetry:
         with _default_lock:
             if _default is None:
                 _default = Telemetry()
+                _default.watch_gc()
     return _default
 
 
@@ -292,7 +354,10 @@ def set_telemetry(hub: Telemetry) -> Telemetry:
     """Replace the process hub (tests, embedders); returns the new hub."""
     global _default
     with _default_lock:
+        if _default is not None and _default is not hub:
+            _default.unwatch_gc()  # one hook per process
         _default = hub
+        hub.watch_gc()
     return hub
 
 

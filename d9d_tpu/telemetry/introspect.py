@@ -24,7 +24,9 @@ observable for free:
   return None) are harvested into a process-wide inventory: FLOPs,
   bytes-accessed, and the args/outputs/temps/generated-code HBM
   breakdown per executable, streamed to sinks as schema-v2
-  ``executable`` events and summarized by ``tools/trace_summary.py``.
+  ``executable`` events and read back through :func:`inventory` by the
+  benchmark's ``entry.compile_s`` / ``entry.lower_s``
+  (``benchmarks/harness/readers.py``).
 
 The happy path costs one extra host-side tuple build per call (the
 signature key — the same work ``jax.jit``'s own cache-key computation

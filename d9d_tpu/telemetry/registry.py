@@ -391,7 +391,7 @@ class PhaseTimeline:
     def mark(self, phase: str) -> None:
         now = time.perf_counter()
         self._registry.record_span(
-            # d9d-lint: disable=D9D006 — caller-prefixed ({train,bench}/phase/*, documented)
+            # d9d-lint: disable=D9D006 — caller-prefixed ({train,serve,bench}/phase/*, documented)
             f"{self._prefix}/phase/{phase}", self._last, now - self._last,
             step=self._step,
         )
@@ -416,13 +416,13 @@ class PhaseTimeline:
         now = time.perf_counter()
         if now > self._last:
             self._registry.record_span(
-                # d9d-lint: disable=D9D006 — caller-prefixed ({train,bench}/phase/*, documented)
+                # d9d-lint: disable=D9D006 — caller-prefixed ({train,serve,bench}/phase/*, documented)
                 f"{self._prefix}/phase/{tail_phase}", self._last,
                 now - self._last, step=self._step,
             )
         total = now - self._t0
         self._registry.record_span(
-            # d9d-lint: disable=D9D006 — caller-prefixed ({train,bench}/step, documented)
+            # d9d-lint: disable=D9D006 — caller-prefixed ({train,serve,bench}/step, documented)
             f"{self._prefix}/step", self._t0, total, step=self._step
         )
         return total

@@ -27,7 +27,9 @@ def one_line(text: str, limit: int = 200) -> bool:
 
 def test_top_level_keys_and_sizes():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert BENCH["trace_in_run"] is True  # a traced run is --trace 2
     assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
     assert isinstance(BENCH["run_seconds"], int)
     assert 1 <= BENCH["run_seconds"] <= 51

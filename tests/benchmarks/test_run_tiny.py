@@ -2,6 +2,7 @@
 process per run, as the driver starts it. It must print the contract's
 last line, and no device metric may be in it."""
 
+import functools
 import json
 import os
 import subprocess
@@ -33,6 +34,15 @@ def last_line(proc) -> dict:
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
+@functools.lru_cache(maxsize=None)
+def tiny_line(workload: str, trace: int, devices: int) -> dict:
+    """The last line of one tiny run; a run is made once per module."""
+    return last_line(run_py(
+        "--workload", workload, "--seed", str(2**31 + 5), "--seconds", "1",
+        "--trace", str(trace), "--tiny", devices=devices,
+    ))
+
+
 CASES = [
     ("qwen3-30b-a3b-l1.train-16k", 1, 1),
     ("qwen3-30b-a3b-decode.serve-rollout-closed", 0, 1),
@@ -44,10 +54,7 @@ CASES = [
 
 @pytest.mark.parametrize("workload,trace,devices", CASES)
 def test_tiny_run_prints_the_contracts_last_line(workload, trace, devices):
-    line = last_line(run_py(
-        "--workload", workload, "--seed", str(2**31 + 5), "--seconds", "1",
-        "--trace", str(trace), "--tiny", devices=devices,
-    ))
+    line = tiny_line(workload, trace, devices)
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["correct"] is True
     assert line["attempted"] > 0 and line["failed"] == 0
@@ -62,6 +69,43 @@ def test_tiny_run_prints_the_contracts_last_line(workload, trace, devices):
     if trace:
         compiles = [k for k in line["metrics"] if "compiles_in_window" in k]
         assert compiles and line["metrics"][compiles[0]]["value"] == 0.0
+
+
+@pytest.mark.parametrize("workload,devices", [
+    ("qwen3-30b-a3b-l1.train-16k", 1),
+    ("qwen3-30b-a3b-decode.serve-rollout-closed", 1),
+    ("qwen3-30b-a3b-ep4.train-16k", 4),
+])
+def test_trace_2_measures_then_traces_in_one_run(workload, devices):
+    """``--trace 2``: one line with both kinds of metric. On the CPU rig
+    only counters are reported, so: every per-layer counter of the cell,
+    and of the end-to-end kind exactly what ``--trace 0`` reports with
+    the same seed."""
+    line = tiny_line(workload, 2, devices)
+    plain = tiny_line(workload, 0, devices)
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+    assert line["correct"] is True
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert line["device"] == plain["device"]
+    assert set(line["metrics"]) <= COUNTERS
+    assert "busy_s" not in line["device"] and "breakdown" not in line
+    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
+    assert {k: v for k, v in line["metrics"].items() if k in end_to_end} \
+        == plain["metrics"]
+    per_layer = {
+        m["name"] for m in BENCH["per_layer"]
+        if m["source"] == "program_counter"
+        and workload in m.get("workloads", [workload])
+    }
+    assert per_layer and per_layer <= set(line["metrics"])
+    compiles = [k for k in line["metrics"] if "compiles_in_window" in k]
+    assert compiles and line["metrics"][compiles[0]]["value"] == 0.0
+    if "serve" in workload:
+        # the program's own count of prompt-only slot-steps agrees with
+        # the benchmark's reckoning from its request table
+        own = line["metrics"]["serve.prompt_slot_steps_pct"]["value"]
+        reckoned = line["metrics"]["serve.prompt_step_share_pct"]["value"]
+        assert own == pytest.approx(reckoned, abs=2.0)
 
 
 def test_no_tpu_no_result():

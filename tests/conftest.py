@@ -35,6 +35,19 @@ def devices():
     return devs
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _ambient_mesh_stays_in_its_module():
+    """``MeshParameters.build`` makes its mesh ambient (``jax.set_mesh``)
+    and nothing unsets it, so a module that built a one- or four-device
+    mesh broke whichever module the worker ran next ("incompatible
+    devices ... jit's context mesh"): which tests failed depended on how
+    the files fell to the workers. Each module ends with the ambient
+    mesh it started with."""
+    before = jax.sharding.get_mesh()
+    yield
+    jax.set_mesh(before)
+
+
 @pytest.fixture(autouse=True)
 def _fixed_seed():
     import random

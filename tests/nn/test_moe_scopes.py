@@ -1,0 +1,105 @@
+"""Scopes on the expert matmuls: in the lowered HLO of a tiny train step
+every ``ragged-dot``, forward and both transposes, carries an ``op_name``
+that says which matmul it is, on the local and on the EP path, for both
+model families. The scopes are metadata only."""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from d9d_tpu.core import MeshParameters
+from d9d_tpu.models.deepseek import DeepseekCausalLM, deepseek_v2_tiny
+from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
+from d9d_tpu.ops.attention.eager import eager_sdpa
+
+B, T, VOCAB = 4, 16, 256
+MATMUL = re.compile(r"moe/experts/(gate_up|down)")
+
+
+def qwen3(ep_axes=None):
+    return Qwen3MoeCausalLM(
+        config=Qwen3MoeConfig.tiny(ep_axes=ep_axes), sdpa=eager_sdpa,
+        dtype=jnp.float32,
+    )
+
+
+def deepseek(ep_axes=None):
+    assert ep_axes is None
+    return DeepseekCausalLM(
+        config=deepseek_v2_tiny(VOCAB), sdpa=eager_sdpa, dtype=jnp.float32
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def lowered_train_step(family, ep_axes=None):
+    """The loss and its gradient with respect to every parameter, lowered
+    for the TPU (the CPU's lowering expands ``ragged_dot`` into plain
+    dots; lowering for another platform needs no such device) and not yet
+    compiled: StableHLO text with the locations that become ``op_name``."""
+    model = family(ep_axes)
+    rng = np.random.default_rng(0)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (B, T)), jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    params = model.clone().init(
+        jax.random.PRNGKey(0), tokens, positions, tokens
+    )["params"]
+
+    def loss(p):
+        return model.apply({"params": p}, tokens, positions, tokens).sum()
+
+    traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def ragged_dots(text):
+    """``op_name`` of every ragged-dot in lowered StableHLO text."""
+    locations = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    return [
+        locations.get(m[1], "") for m in re.finditer(
+            r'"?chlo\.ragged_dot"?.* loc\((#loc\d+)\)', text
+        )
+    ]
+
+
+@pytest.fixture(scope="module")
+def ep_axes():
+    ctx = MeshParameters(dp_shard=4, ep_shard=4).build(jax.devices()[:4])
+    return tuple(ctx.ep_shard_axes)
+
+
+@pytest.mark.parametrize("family,path", [
+    (qwen3, "local"), (qwen3, "ep"), (deepseek, "local"),
+], ids=["qwen3-local", "qwen3-ep", "deepseek-local"])
+def test_every_ragged_dot_says_which_matmul_it_is(family, path, ep_axes):
+    names = ragged_dots(
+        lowered_train_step(family, ep_axes if path == "ep" else None)
+    )
+    # per expert layer: gate|up and down, each forward and two transposes
+    assert len(names) >= 6 and len(names) % 6 == 0, names
+    assert all(MATMUL.search(n) for n in names), names
+    by = {
+        which: [n for n in names if MATMUL.search(n)[1] == which]
+        for which in ("gate_up", "down")
+    }
+    assert len(by["gate_up"]) == len(by["down"]) == len(names) // 2
+    for group in by.values():
+        backward = [n for n in group if "transpose(" in n]
+        if path == "local":
+            assert len(backward) == 2 * (len(group) - len(backward))
+    if path == "ep":
+        # inside shard_map a location is relative to the mapped body; the
+        # differentiation wrappers join it when the program is compiled
+        assert all("ep/expert_compute/moe/experts/" in n for n in names)
+
+
+def test_permute_and_combine_are_scoped_too(ep_axes):
+    for axes, marks in ((None, ["moe/permute", "moe/combine"]),
+                        (ep_axes, ["moe/permute", "moe/combine",
+                                   "ep/dispatch_a2a"])):
+        text = lowered_train_step(qwen3, axes)
+        for mark in marks:
+            assert f"{mark}/" in text, mark

@@ -120,16 +120,12 @@ def measure(engine, *, batch, microbatch, seq_len, vocab, warmup, steps,
         # separate short traced pass: steady-state dispatch gaps only,
         # with per-action host annotations on (tools/trace_summary.py
         # groups by them)
-        from d9d_tpu.core.tracing import set_trace_annotations
+        from d9d_tpu.core.tracing import trace
 
-        set_trace_annotations(True)
-        try:
-            with jax.profiler.trace(trace_dir):
-                for _ in range(min(steps, 3)):
-                    m = engine.step(make_microbatches())
-                drain(m)
-        finally:
-            set_trace_annotations(False)
+        with trace(trace_dir):
+            for _ in range(min(steps, 3)):
+                m = engine.step(make_microbatches())
+            drain(m)
     return dt / steps
 
 
