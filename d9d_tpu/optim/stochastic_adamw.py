@@ -8,6 +8,14 @@ training — no fp32 master copy needed. The RNG key is part of the optimizer
 state (reference keeps its own RNG in state_dict), so checkpoints resume the
 exact noise stream.
 
+What a draw is: per step and leaf, ``fold_in(fold_in(key, count), leaf_index)``
+keys ONE Threefry-2x32 block per element (``ops.stochastic.rounding_fields``),
+and its 64 bits are carved into the 16-bit fields that round what is stored in
+bf16: field 0 (low half of word 0) the parameter, field 1 (high half of word
+0) ``mu``, field 2 (low half of word 1) ``nu``. With fp32 moments only field 0
+is used. The generator, not the memory traffic, is what the pass costs on a
+TPU, so the three roundings share a block instead of drawing one each.
+
 The object satisfies the trainer's optimizer protocol (``init`` /
 ``update``) and additionally exposes ``apply_updates`` so the train step can
 let the optimizer own the parameter write (required: ``optax.apply_updates``
@@ -22,7 +30,7 @@ import jax.numpy as jnp
 import optax
 
 from d9d_tpu.core.types import PyTree
-from d9d_tpu.ops.stochastic import stochastic_round_to_bf16
+from d9d_tpu.ops.stochastic import rounding_fields, stochastic_round_with_field
 
 
 class StochasticAdamWState(NamedTuple):
@@ -37,7 +45,7 @@ class StochasticAdamW:
 
     ``learning_rate`` may be a float or an optax schedule. Moments default
     to fp32; pass ``moment_dtype=jnp.bfloat16`` to store them rounded too
-    (stochastically, sharing the step's noise stream).
+    (stochastically, from other bits of the parameter's own Threefry block).
     """
 
     # the train step must NOT down-cast fp32 grads to param dtype for us
@@ -104,10 +112,10 @@ class StochasticAdamW:
             upd = m_hat / (jnp.sqrt(v_hat) + self.eps) + self.weight_decay * p32
             new_p32 = p32 - lr * upd
 
-            k_p, k_mu, k_nu = jax.random.split(key, 3)
-            new_p = self._round(new_p32, p.dtype, k_p)
-            new_mu = self._round(mu32, self.moment_dtype, k_mu)
-            new_nu = self._round(nu32, self.moment_dtype, k_nu)
+            f_p, f_mu, f_nu = rounding_fields(key, p.shape)
+            new_p = self._round(new_p32, p.dtype, f_p)
+            new_mu = self._round(mu32, self.moment_dtype, f_mu)
+            new_nu = self._round(nu32, self.moment_dtype, f_nu)
             return new_p, new_mu, new_nu
 
         # work on flat leaf lists so tuple-structured param pytrees are safe
@@ -140,7 +148,7 @@ class StochasticAdamW:
     # -- helpers -------------------------------------------------------
 
     @staticmethod
-    def _round(x32: jax.Array, dtype: Any, key: jax.Array) -> jax.Array:
+    def _round(x32: jax.Array, dtype: Any, field: jax.Array) -> jax.Array:
         if dtype == jnp.bfloat16:
-            return stochastic_round_to_bf16(x32, key)
+            return stochastic_round_with_field(x32, field)
         return x32.astype(dtype)
