@@ -178,7 +178,7 @@ class _Slot:
     rid: int = -1            # active request id, -1 = idle
     # legacy (per-token) mode: prompt tokens after the one in _tokens
     pending: list = dataclasses.field(default_factory=list)
-    pos: int = 0             # legacy mode: next rope position for this row
+    pos: int = 0             # next cache position this row writes
     # fused mode: prompt tokens not yet dispatched as step inputs
     feed: list = dataclasses.field(default_factory=list)
     emitted: int = 0         # committed (harvested) emissions
@@ -218,6 +218,7 @@ class _ChunkPlan:
     k: int
     rids: list            # rid per slot at dispatch (-1 = idle)
     emit_from: list       # first step index (within the chunk) that emits
+    pos: list             # cache position each row writes at the first step
     version: int = 0      # weights generation this chunk dispatched with
     index: int = 0        # chunk index since the last stats reset
 
@@ -334,7 +335,13 @@ class ServeStats:
     occupancy (busy includes prompt-consumption steps);
     ``slot_steps_prompt`` is the part of busy in which a row only
     consumed a prompt token and emitted nothing, so busy less prompt is
-    the generation steps, exactly.
+    the generation steps, exactly. ``positions_attended`` sums, over the
+    busy slot-steps, the cache positions the step attends (the row's own
+    new token included), so over ``slot_steps_busy`` it is the mean
+    context a step reads. ``pool_pages_total`` sums the page pool's
+    pages in use at each chunk boundary (over ``chunks``: the mean) and
+    ``pool_pages_peak`` is the most a boundary saw; both stay 0 without
+    paging. All of it is host arithmetic on the plan: no readback.
     """
 
     host_dispatches: int = 0
@@ -345,6 +352,9 @@ class ServeStats:
     slot_steps_busy: int = 0
     slot_steps_prompt: int = 0
     slot_steps_total: int = 0
+    positions_attended: int = 0
+    pool_pages_total: int = 0
+    pool_pages_peak: int = 0
     # degraded-mode counters: submits rejected by the bounded queue,
     # requests expired by their deadline (queued or running), requests
     # shed by the autopilot's burn-driven admission tiering
@@ -1567,6 +1577,14 @@ class ContinuousBatcher:
             in_use * self._page_bytes / max(1, running),
         )
 
+    def _count_pool_pages(self) -> int:
+        """One chunk boundary's pages in use into ``ServeStats`` (paged
+        mode only)."""
+        in_use = self._kv.pages_in_use
+        self.stats.pool_pages_total += in_use
+        self.stats.pool_pages_peak = max(self.stats.pool_pages_peak, in_use)
+        return in_use
+
     def hbm_bytes_per_request(self) -> float:
         """Peak resident KV bytes over peak concurrent running requests
         for the current measurement window — deterministic given the
@@ -1818,6 +1836,9 @@ class ContinuousBatcher:
         self.stats.device_steps += 1
         self.stats.slot_steps_total += self._b
         self.stats.slot_steps_busy += int(live.sum())
+        self.stats.positions_attended += int((pos[live] + 1).sum())
+        if self._paged:
+            self._count_pool_pages()
         self._observe("serve/slot_util", live.sum() / self._b, _UTIL_EDGES)
 
         emitted: dict[int, int] = {}
@@ -1925,6 +1946,7 @@ class ContinuousBatcher:
                         # a prefix-cache hit skips the cached tokens:
                         # feeding resumes at the first un-cached one
                         feed=list(req.prompt[start_pos:]),
+                        pos=start_pos,
                         emitted=0,
                         budget=req.max_new_tokens,
                         deadline_t=req.deadline_t,
@@ -1941,11 +1963,13 @@ class ContinuousBatcher:
         forced = np.zeros((self._b, k), np.int32)
         n_forced = np.zeros((self._b,), np.int32)
         emit_from = np.full((self._b,), k, np.int32)
-        rids = []
+        rids, pos = [], []
         for i, slot in enumerate(self._slots):
             rids.append(slot.rid)
+            pos.append(slot.pos)
             if slot.rid < 0:
                 continue
+            slot.pos += k
             m = len(slot.feed)
             nf = min(m, k)
             if nf:
@@ -1989,12 +2013,19 @@ class ContinuousBatcher:
         self._pending.append(
             (toks,
              _ChunkPlan(k=k, rids=rids, emit_from=emit_from.tolist(),
-                        version=self.weights_version,
+                        pos=pos, version=self.weights_version,
                         index=self.stats.chunks))
         )
         self.stats.host_dispatches += 1
         self.stats.chunks += 1
         self.stats.device_steps += k
+        if self._paged:
+            # on the closing serve/step span too: ServeStats gives a
+            # caller totals, the span timeline any window's peak
+            clock.meta.update(
+                pool_pages=self._count_pool_pages(),
+                pool_pages_free=self._kv.pages_free,
+            )
         self._progress_t = time.perf_counter()
         clock.mark("dispatch")
 
@@ -2016,6 +2047,7 @@ class ContinuousBatcher:
         self.stats.readbacks += 1
         self.stats.slot_steps_total += self._b * plan.k
         chunk_busy = 0
+        chunk_positions = 0
         chunk_tokens = 0
         emitted: dict[int, list[int]] = {}
         for i, rid in enumerate(plan.rids):
@@ -2050,6 +2082,11 @@ class ContinuousBatcher:
                         self._release_row_pages(i, device_dead=True)
                     break
             self.stats.slot_steps_busy += busy_steps
+            # step j of the row writes position pos + j and attends
+            # positions 0..pos + j
+            chunk_positions += (
+                busy_steps * plan.pos[i] + busy_steps * (busy_steps + 1) // 2
+            )
             # steps in which the row only consumed a prompt token, from
             # the plan the chunk was dispatched with; a row emits before
             # it dies, so these never pass the step it died on
@@ -2059,6 +2096,12 @@ class ContinuousBatcher:
                 self._note_tokens(rid, len(emitted[rid]), now)
                 if rid in self.done:
                     self._note_finish(rid, now, version=plan.version)
+        self.stats.positions_attended += chunk_positions
+        # this chunk's share of the two counters, for a reader of the
+        # span timeline (a traced window's chunks, say)
+        clock.meta.update(
+            slot_steps_busy=chunk_busy, positions_attended=chunk_positions
+        )
         self._observe(
             "serve/slot_util", chunk_busy / (self._b * plan.k), _UTIL_EDGES
         )

@@ -21,6 +21,14 @@ mscale attention temperature are configured per the published configs
 blocks — but no HF weight mapper or logits-parity test exists yet, so
 treat checkpoint loading as future work (the Qwen3/Llama/Next families
 are the logits-parity-tested interop surface).
+
+GLM-4.7-Flash (``glm4_moe_lite``) rides the same backbone with the
+DeepSeek-V3 layer equations: q compression in MLA, d_qk == d_v, and the
+sigmoid ``noaux_tc`` router with its selection bias
+(``router_score_function``, ``router_expert_bias``); held to
+``benchmarks/references/glm4_moe_lite.py`` in
+``tests/models/test_glm4_moe_lite.py`` and, at published widths on the
+chip, in the benchmark's ``glm-4.7-flash-decode`` cell.
 """
 
 from d9d_tpu.models.qwen3.moe import (
@@ -162,4 +170,82 @@ def deepseek_v2(vocab_size: int = 102_400) -> Qwen3MoeConfig:
         qk_norm=False,
         rope_theta=10_000.0,
         rope_scaling=_deepseek_yarn(),
+    )
+
+
+def glm4_moe_lite_tiny(vocab_size: int = 256) -> Qwen3MoeConfig:
+    """CPU-runnable GLM-4.7-Flash-shaped config (tests, ``--tiny``
+    benchmark runs): one dense and one expert layer, q compression on,
+    d_qk == d_v, sigmoid ``noaux_tc`` router with its selection bias,
+    renormalised weights times a routed scale."""
+    return Qwen3MoeConfig(
+        vocab_ranges=(("default", vocab_size),),
+        hidden_size=64,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,  # unused by MLA; kept for config invariants
+        head_dim=32,
+        moe_intermediate_size=32,
+        num_experts=8,
+        num_experts_per_tok=2,
+        intermediate_size=128,
+        mlp_only_layers=(0,),
+        shared_expert=SharedExpertParameters(
+            intermediate_size=32, enable_gate=False
+        ),
+        mla=MLAParameters(
+            kv_lora_rank=32,
+            qk_nope_head_dim=24,
+            qk_rope_head_dim=8,
+            v_head_dim=32,
+            q_lora_rank=24,
+        ),
+        routed_scaling_factor=1.8,
+        norm_topk_prob=True,
+        router_score_function="sigmoid",
+        router_expert_bias=True,
+        qk_norm=False,
+        rope_theta=1_000_000.0,
+        norm_eps=1e-5,
+        remat=False,
+    )
+
+
+def glm_4_7_flash(vocab_size: int = 154_880) -> Qwen3MoeConfig:
+    """GLM-4.7-Flash geometry (``glm4_moe_lite``, 30B total / 3B active):
+    47 layers, the first dense (10,240 wide), then 64 routed experts x
+    1,536 top-4 plus one shared; the DeepSeek-V3 layer equations: MLA
+    with q compression (rank 768), rank-512 latents, 192 + 64 query/key
+    and 256 value dims a head over 20 heads, no rope scaling; sigmoid
+    ``noaux_tc`` routing, renormalised weights times 1.8. The
+    multi-token-prediction layer is not built."""
+    return Qwen3MoeConfig(
+        vocab_ranges=(("default", vocab_size),),
+        hidden_size=2048,
+        num_layers=47,
+        num_heads=20,
+        num_kv_heads=20,
+        head_dim=256,
+        moe_intermediate_size=1536,
+        num_experts=64,
+        num_experts_per_tok=4,
+        intermediate_size=10_240,
+        mlp_only_layers=(0,),
+        shared_expert=SharedExpertParameters(
+            intermediate_size=1536, enable_gate=False
+        ),
+        mla=MLAParameters(
+            kv_lora_rank=512,
+            qk_nope_head_dim=192,
+            qk_rope_head_dim=64,
+            v_head_dim=256,
+            q_lora_rank=768,
+        ),
+        routed_scaling_factor=1.8,
+        norm_topk_prob=True,
+        router_score_function="sigmoid",
+        router_expert_bias=True,
+        qk_norm=False,
+        rope_theta=1_000_000.0,
+        norm_eps=1e-5,
     )

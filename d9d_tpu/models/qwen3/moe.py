@@ -117,6 +117,11 @@ class Qwen3MoeConfig:
     # TopKRouter.n_group / topk_group); 1 = plain top-k
     router_n_group: int = 1
     router_topk_group: int = 1
+    # the gate's score function and its selection bias (DeepSeek-V3
+    # ``noaux_tc``: sigmoid scores, ``e_score_correction_bias``); see
+    # TopKRouter
+    router_score_function: str = "softmax"
+    router_expert_bias: bool = False
 
     @property
     def vocab_size(self) -> int:
@@ -307,6 +312,8 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 num_grouped_experts=cfg.num_experts,
                 top_k=cfg.num_experts_per_tok,
                 router_renormalize_probabilities=cfg.norm_topk_prob,
+                router_enable_expert_bias=cfg.router_expert_bias,
+                router_score_function=cfg.router_score_function,
                 shared_expert=cfg.shared_expert,
                 ep_axes=cfg.ep_axes,
                 token_axes=cfg.moe_token_axes,

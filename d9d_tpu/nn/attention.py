@@ -718,10 +718,14 @@ class LowRankProjection(nn.Module):
                 ),
             )
 
-        x = proj(self.bottleneck, "down_proj", (la.EMBED, None))(x)
-        x = RMSNorm(self.bottleneck, eps=self.norm_eps, name="norm",
-                    param_dtype=self.param_dtype)(x)
-        return proj(self.features, "up_proj", (None, la.HEADS))(x)
+        # HLO metadata only: which half of MLA's query bottleneck (its one
+        # user) a traced op belongs to
+        with jax.named_scope("mla/q_down"):
+            x = proj(self.bottleneck, "down_proj", (la.EMBED, None))(x)
+            x = RMSNorm(self.bottleneck, eps=self.norm_eps, name="norm",
+                        param_dtype=self.param_dtype)(x)
+        with jax.named_scope("mla/q_up"):
+            return proj(self.features, "up_proj", (None, la.HEADS))(x)
 
 
 class MultiHeadLatentAttention(nn.Module):
@@ -808,7 +812,8 @@ class MultiHeadLatentAttention(nn.Module):
                 param_dtype=self.param_dtype,
             )(x)
         else:
-            q = proj(h * d_qk, "q_proj", (la.EMBED, la.HEADS))(x)
+            with jax.named_scope("mla/q_up"):
+                q = proj(h * d_qk, "q_proj", (la.EMBED, la.HEADS))(x)
         q = q.reshape(b, t, h, d_qk)
         q_nope, q_rope = q[..., :d_nope], q[..., d_nope:]
         q_rope = apply_rope(q_rope, cos[..., : d_rope // 2],
@@ -816,10 +821,16 @@ class MultiHeadLatentAttention(nn.Module):
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
         # --- KV latent + decoupled shared rope key ---
-        kv = proj(self.kv_lora_rank + d_rope, "kv_down_proj", (la.EMBED, None))(x)
-        c_kv, k_rope = kv[..., : self.kv_lora_rank], kv[..., self.kv_lora_rank:]
-        c_kv = RMSNorm(self.kv_lora_rank, eps=self.norm_eps,
-                       name="kv_down_norm", param_dtype=self.param_dtype)(c_kv)
+        with jax.named_scope("mla/kv_down"):
+            kv = proj(
+                self.kv_lora_rank + d_rope, "kv_down_proj", (la.EMBED, None)
+            )(x)
+            c_kv = kv[..., : self.kv_lora_rank]
+            k_rope = kv[..., self.kv_lora_rank:]
+            c_kv = RMSNorm(
+                self.kv_lora_rank, eps=self.norm_eps, name="kv_down_norm",
+                param_dtype=self.param_dtype,
+            )(c_kv)
         # rotate the shared rope key at ITS OWN positions before any
         # caching (write-time rope, like the KV cache's rotated keys)
         k_rope = apply_rope(
@@ -851,14 +862,17 @@ class MultiHeadLatentAttention(nn.Module):
                 # buffer anyway, so they run unchanged on it (masks are
                 # built over the gathered length)
                 _paged_write_checks(start, t, mask)
-            cached_c = _decode_cache_append(
-                self, c_kv.astype(self.dtype), "cached_latent", s_max,
-                start, page_table=page_table,
-            )
-            cached_r = _decode_cache_append(
-                self, k_rope.astype(self.dtype), "cached_rope_key", s_max,
-                start, page_table=page_table,
-            )
+            # the scatter of the new token and, paged, the gather of the
+            # row's view: where a step reads its cache rows from HBM
+            with jax.named_scope("mla/cache_append"):
+                cached_c = _decode_cache_append(
+                    self, c_kv.astype(self.dtype), "cached_latent", s_max,
+                    start, page_table=page_table,
+                )
+                cached_r = _decode_cache_append(
+                    self, k_rope.astype(self.dtype), "cached_rope_key",
+                    s_max, start, page_table=page_table,
+                )
             idx.value = start + t
             from d9d_tpu.nn.decode_flags import in_continuation_chunk
 
@@ -897,7 +911,10 @@ class MultiHeadLatentAttention(nn.Module):
             # GroupedQueryAttention._decode_attend; continuation chunks
             # took the slot path above)
             prefill_segs = _prefill_segments(mask, t, s_max)
-        k, v = _decompress_kv(c_kv, k_rope, kv_up_w, h, d_nope, self.dtype)
+        with jax.named_scope("mla/decompress"):
+            k, v = _decompress_kv(
+                c_kv, k_rope, kv_up_w, h, d_nope, self.dtype
+            )
 
         # pad V: softmax(QKᵀ)·[V|0] = [out|0] (reference :199-207)
         pad = d_qk - d_v
@@ -929,9 +946,10 @@ class MultiHeadLatentAttention(nn.Module):
         """
         from d9d_tpu.ops.attention.eager import eager_sdpa
 
-        k, v = _decompress_kv(
-            c, k_rope, w, self.num_heads, d_nope, self.dtype
-        )
+        with jax.named_scope("mla/decompress"):
+            k, v = _decompress_kv(
+                c, k_rope, w, self.num_heads, d_nope, self.dtype
+            )
         scale = (
             self.softmax_scale if self.softmax_scale is not None
             else d_qk**-0.5
@@ -951,30 +969,35 @@ class MultiHeadLatentAttention(nn.Module):
         """
         h = self.num_heads
         r = self.kv_lora_rank
-        wk = w.astype(jnp.float32).reshape(r, h, d_nope + d_v)
-        wv = wk[..., d_nope:]
-        wk = wk[..., :d_nope]
-        qn = q_nope.astype(jnp.float32)
-        qr = q_rope.astype(jnp.float32)
-        cf = c.astype(jnp.float32)
-        rf = k_rope.astype(jnp.float32)
-        q_abs = jnp.einsum("bthd,rhd->bthr", qn, wk)
         scale = (
             self.softmax_scale if self.softmax_scale is not None
             else d_qk**-0.5
         )
-        scores = (
-            jnp.einsum("bthr,bsr->bhts", q_abs, cf)
-            + jnp.einsum("bthd,bsd->bhts", qr, rf)
-        ) * scale
-        neg_big = jnp.asarray(-1e30, scores.dtype)
-        scores = jnp.where(dec_mask, scores, neg_big)
-        # finite mask sentinel (not -inf): a fully-masked row must produce
-        # zeros like eager_sdpa's guarded softmax, not NaN
-        p = jax.nn.softmax(scores, axis=-1)
-        p = jnp.where(
-            jnp.any(dec_mask, axis=-1, keepdims=True), p, 0.0
-        )
-        out_lat = jnp.einsum("bhts,bsr->bthr", p, cf)
-        out = jnp.einsum("bthr,rhd->bthd", out_lat, wv)
-        return out.astype(self.dtype)
+        # the three scopes are what a trace tells the stages apart by
+        # (benchmarks/metrics/kernel.mla_decode_roofline)
+        with jax.named_scope("mla/absorb_q"):
+            wk = w.astype(jnp.float32).reshape(r, h, d_nope + d_v)
+            wv = wk[..., d_nope:]
+            wk = wk[..., :d_nope]
+            qn = q_nope.astype(jnp.float32)
+            qr = q_rope.astype(jnp.float32)
+            q_abs = jnp.einsum("bthd,rhd->bthr", qn, wk)
+        with jax.named_scope("mla/latent_attend"):
+            cf = c.astype(jnp.float32)
+            rf = k_rope.astype(jnp.float32)
+            scores = (
+                jnp.einsum("bthr,bsr->bhts", q_abs, cf)
+                + jnp.einsum("bthd,bsd->bhts", qr, rf)
+            ) * scale
+            neg_big = jnp.asarray(-1e30, scores.dtype)
+            scores = jnp.where(dec_mask, scores, neg_big)
+            # finite mask sentinel (not -inf): a fully-masked row must
+            # produce zeros like eager_sdpa's guarded softmax, not NaN
+            p = jax.nn.softmax(scores, axis=-1)
+            p = jnp.where(
+                jnp.any(dec_mask, axis=-1, keepdims=True), p, 0.0
+            )
+            out_lat = jnp.einsum("bhts,bsr->bthr", p, cf)
+        with jax.named_scope("mla/fold_v"):
+            out = jnp.einsum("bthr,rhd->bthd", out_lat, wv)
+            return out.astype(self.dtype)

@@ -54,11 +54,19 @@ class SharedExpertParameters:
 
 
 class TopKRouter(nn.Module):
-    """Softmax gate → optional expert bias → top-k → optional renorm.
+    """Gate scores → optional selection bias → top-k → optional renorm.
 
-    Reference router.py:23. The expert bias (loss-free load balancing) is a
-    non-trainable variable in the ``moe_buffers`` collection, updated
-    outside the gradient path.
+    Reference router.py:23. ``score_function`` is the family's and is set
+    by the model preset: ``"softmax"`` over all experts (Qwen3,
+    DeepSeek-V2) or an independent ``"sigmoid"`` per expert (the
+    DeepSeek-V3 line's ``noaux_tc``: GLM-4.7-Flash, Moonlight, Kimi-K2).
+    The selection bias (loss-free load balancing, HF
+    ``e_score_correction_bias``) joins the selection only, never the
+    returned weights. It is a float32 leaf of the ``params`` collection,
+    zero at init and behind ``stop_gradient``: whatever carries ``params``
+    (``Trainer``, ``generate``, ``ContinuousBatcher``, a checkpoint)
+    carries it, its gradient is exactly zero, and a balancing controller
+    writes it outside the gradient path.
     """
 
     dim: int
@@ -66,6 +74,7 @@ class TopKRouter(nn.Module):
     top_k: int
     renormalize_probabilities: bool = True
     enable_expert_bias: bool = False
+    score_function: str = "softmax"
     # group-limited routing (DeepSeek ``group_limited_greedy``): experts
     # partition into ``n_group`` groups, each scored by its best expert;
     # only experts in the top ``topk_group`` groups are eligible for the
@@ -78,53 +87,68 @@ class TopKRouter(nn.Module):
     @nn.compact
     def __call__(self, hidden: Array) -> tuple[Array, Array]:
         """hidden [..., D] → (indices [..., K] int32, probs [..., K] fp32)."""
-        scores = nn.Dense(
-            self.num_experts,
-            use_bias=False,
-            name="gate",
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), (la.EMBED, None)
-            ),
-        )(hidden)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        if self.score_function not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"score_function {self.score_function!r}: softmax or sigmoid"
+            )
+        with jax.named_scope("moe/router/score"):
+            scores = nn.Dense(
+                self.num_experts,
+                use_bias=False,
+                name="gate",
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), (la.EMBED, None)
+                ),
+            )(hidden)
+            if self.score_function == "softmax":
+                probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            else:
+                probs = jax.nn.sigmoid(scores.astype(jnp.float32))
 
-        # selection scores may differ from the returned probs (bias joins
-        # selection only; group-limited routing masks ineligible groups)
-        sel = probs
-        if self.enable_expert_bias:
-            bias = self.variable(
-                "moe_buffers",
-                "expert_bias",
-                lambda: jnp.zeros((self.num_experts,), jnp.float32),
-            ).value
-            sel = sel + bias
-        if self.n_group > 1:
-            if self.num_experts % self.n_group != 0:
-                raise ValueError(
-                    f"num_experts {self.num_experts} not divisible by "
-                    f"n_group {self.n_group}"
+        with jax.named_scope("moe/router/select"):
+            # selection scores may differ from the returned probs (bias
+            # joins selection only; group-limited routing masks
+            # ineligible groups)
+            sel = probs
+            if self.enable_expert_bias:
+                bias = self.param(
+                    "e_score_correction_bias",
+                    nn.with_logical_partitioning(
+                        nn.initializers.zeros, (None,)
+                    ),
+                    (self.num_experts,),
+                    jnp.float32,
                 )
-            per = self.num_experts // self.n_group
-            group_score = sel.reshape(
-                *sel.shape[:-1], self.n_group, per
-            ).max(axis=-1)
-            _, top_g = lax.top_k(group_score, self.topk_group)
-            gmask = (
-                jax.nn.one_hot(top_g, self.n_group, dtype=jnp.bool_)
-                .any(axis=-2)
+                sel = sel + lax.stop_gradient(bias)
+            if self.n_group > 1:
+                if self.num_experts % self.n_group != 0:
+                    raise ValueError(
+                        f"num_experts {self.num_experts} not divisible by "
+                        f"n_group {self.n_group}"
+                    )
+                per = self.num_experts // self.n_group
+                group_score = sel.reshape(
+                    *sel.shape[:-1], self.n_group, per
+                ).max(axis=-1)
+                _, top_g = lax.top_k(group_score, self.topk_group)
+                gmask = (
+                    jax.nn.one_hot(top_g, self.n_group, dtype=jnp.bool_)
+                    .any(axis=-2)
+                )
+                emask = jnp.repeat(gmask, per, axis=-1)
+                sel = jnp.where(emask, sel, -jnp.inf)
+            _, selected_idx = lax.top_k(sel, self.top_k)
+            selected_probs = jnp.take_along_axis(
+                probs, selected_idx, axis=-1
             )
-            emask = jnp.repeat(gmask, per, axis=-1)
-            sel = jnp.where(emask, sel, -jnp.inf)
-        _, selected_idx = lax.top_k(sel, self.top_k)
-        selected_probs = jnp.take_along_axis(probs, selected_idx, axis=-1)
 
-        if self.renormalize_probabilities:
-            selected_probs = selected_probs / (
-                selected_probs.sum(axis=-1, keepdims=True) + 1e-20
-            )
-        return selected_idx.astype(jnp.int32), selected_probs
+            if self.renormalize_probabilities:
+                selected_probs = selected_probs / (
+                    selected_probs.sum(axis=-1, keepdims=True) + 1e-20
+                )
+            return selected_idx.astype(jnp.int32), selected_probs
 
 
 class GroupedSwiGLU(nn.Module):
@@ -298,6 +322,8 @@ class MoELayer(nn.Module):
     top_k: int
     router_renormalize_probabilities: bool = True
     router_enable_expert_bias: bool = False
+    # the family's gate score: see TopKRouter.score_function
+    router_score_function: str = "softmax"
     # group-limited routing (see TopKRouter.n_group / topk_group)
     router_n_group: int = 1
     router_topk_group: int = 1
@@ -327,6 +353,7 @@ class MoELayer(nn.Module):
             top_k=self.top_k,
             renormalize_probabilities=self.router_renormalize_probabilities,
             enable_expert_bias=self.router_enable_expert_bias,
+            score_function=self.router_score_function,
             n_group=self.router_n_group,
             topk_group=self.router_topk_group,
             dtype=self.dtype,

@@ -377,7 +377,8 @@ class PhaseTimeline:
 
     ``mark(phase)`` closes the currently open phase at *now* and opens
     the next; ``close()`` ends the last phase and emits the enclosing
-    ``{prefix}/step`` span.
+    ``{prefix}/step`` span, which carries ``meta`` (fill it any time
+    before ``close()``: counts that belong to the interval as a whole).
     """
 
     def __init__(self, registry: MetricRegistry, prefix: str, *, step=None):
@@ -387,6 +388,7 @@ class PhaseTimeline:
         self._t0 = time.perf_counter()
         self._last = self._t0
         self._closed = False
+        self.meta: dict[str, Any] = {}
 
     def mark(self, phase: str) -> None:
         now = time.perf_counter()
@@ -423,6 +425,7 @@ class PhaseTimeline:
         total = now - self._t0
         self._registry.record_span(
             # d9d-lint: disable=D9D006 — caller-prefixed ({train,serve,bench}/step, documented)
-            f"{self._prefix}/step", self._t0, total, step=self._step
+            f"{self._prefix}/step", self._t0, total, step=self._step,
+            meta=self.meta or None,
         )
         return total

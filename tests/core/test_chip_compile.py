@@ -164,6 +164,64 @@ def test_flash_decode(topo, layout):
     assert _pallas_calls(compiled) == 1
 
 
+@pytest.mark.parametrize("phase", ["prefill", "absorbed_step"])
+def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
+    """``MultiHeadLatentAttention`` at the published GLM-4.7-Flash
+    geometry (q compression 768, rank 512, 192 + 64 query/key and 256
+    value dims, 20 heads): the decode-mode prefill takes d_qk = d_v = 256
+    through the Pallas flash kernel, and the absorbed single-token step
+    compiles against paged latent and rope-key pools of 64 slots x 1,152
+    positions (the benchmark's ``glm-4.7-flash-decode`` cell)."""
+    import flax.linen as nn
+
+    from d9d_tpu.nn.attention import MultiHeadLatentAttention
+    from d9d_tpu.nn.decode_flags import PAGE_TABLE_LEAF
+    from d9d_tpu.nn.sdpa import build_sdpa_backend
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    s_max, rank, d_rope = 1152, 512, 64
+    module = MultiHeadLatentAttention(
+        hidden_size=H, num_heads=20, qk_nope_head_dim=192,
+        qk_rope_head_dim=d_rope, v_head_dim=256, kv_lora_rank=rank,
+        q_lora_rank=768, sdpa=build_sdpa_backend(), norm_eps=1e-5,
+        decode_max_length=s_max, dtype=BF16, param_dtype=BF16,
+    )
+    b, t = (4, 128) if phase == "prefill" else (64, 1)
+    x = sds((b, t, H), BF16)
+    rope = sds((b, t, d_rope // 2), jnp.float32)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        nn.unbox(jax.eval_shape(
+            lambda: module.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 1, H), BF16),
+                jnp.zeros((1, 1, d_rope // 2)), jnp.zeros((1, 1, d_rope // 2)),
+            )["params"]
+        )),
+    )
+    if phase == "prefill":
+        cache = {
+            "cache_index": sds((), jnp.int32),
+            "cached_latent": sds((b, s_max, rank), BF16),
+            "cached_rope_key": sds((b, s_max, d_rope), BF16),
+        }
+    else:
+        pages = b * (s_max // PAGE) + 1
+        cache = {
+            "cache_index": sds((b,), jnp.int32),
+            "cached_latent": sds((pages, PAGE, rank), BF16),
+            "cached_rope_key": sds((pages, PAGE, d_rope), BF16),
+            PAGE_TABLE_LEAF: sds((b, s_max // PAGE), jnp.int32),
+        }
+    compiled = jax.jit(
+        lambda p, c, x, cos, sin: module.apply(
+            {"params": p, "cache": c}, x, cos, sin, mutable=["cache"]
+        )
+    ).lower(params, cache, x, rope, rope).compile()
+    assert _pallas_calls(compiled) == (1 if phase == "prefill" else 0)
+    # the gathered view and its float32 copy are the step's temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
 # -- the model ---------------------------------------------------------------
 
 

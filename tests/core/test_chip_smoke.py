@@ -108,8 +108,12 @@ def test_script_refuses_to_measure_without_a_tpu(script):
 def cache_config():
     """Whatever the helper sets is put back."""
     before = jax.config.jax_compilation_cache_dir
+    metadata = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update(
+        "jax_compilation_cache_include_metadata_in_key", metadata
+    )
 
 
 def test_cache_helper_honours_the_environment(monkeypatch, tmp_path, cache_config):
@@ -128,6 +132,42 @@ def test_cache_helper_defaults_inside_the_checkout(monkeypatch, cache_config):
     assert jax.config.jax_compilation_cache_dir == str(chosen)
     ignored = (ROOT / ".gitignore").read_text().split()
     assert chosen.name + "/" in ignored
+
+
+def test_a_programs_scopes_are_part_of_its_cache_key(tmp_path, cache_config):
+    """Two programs that differ only in a ``jax.named_scope`` are two
+    cache entries once the helper has run: a trace is read by scope, so a
+    hit must never hand back another program's names."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.sin(x) * 2.0
+        return f
+
+    was_on = jax.config.jax_enable_compilation_cache
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    compile_cache.enable_compile_cache()
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        compilation_cache.reset_cache()
+        x = jnp.arange(8.0)
+        entries = []
+        # one call site: the caller's line is metadata too
+        for name in ("stage/a", "stage/a", "stage/b"):
+            jax.jit(scoped(name))(x).block_until_ready()
+            entries.append({p.name for p in tmp_path.iterdir()})
+        first, again, other = entries
+        assert first and again == first  # the same program: a hit
+        assert len(other) > len(first)  # another scope: another key
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+        compilation_cache.reset_cache()
 
 
 def test_cache_helper_agrees_across_processes(tmp_path):
