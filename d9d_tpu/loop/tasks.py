@@ -7,6 +7,7 @@ import numpy as np
 
 from d9d_tpu.core.types import Array, PyTree
 from d9d_tpu.loop.control.task import PipelineTrainTask, TrainTask
+from d9d_tpu.nn.moe import EP_BUFFER_STATS
 from d9d_tpu.ops import LM_IGNORE_INDEX
 
 
@@ -18,20 +19,26 @@ def _moe_load_metrics(updates: PyTree) -> dict[str, Array]:
     so the engine's microbatch scan sums it exactly; the max/total ratio
     is taken host-side in ``metrics_postprocess`` — taking max per
     microbatch first would bias the share upward with small microbatches.
-    Covers the logged step (not the whole log window). Single-program path
-    only: under pipeline parallelism the executor's metric channel carries
-    last-stage loss statistics and this metric is absent. Empty dict for
-    dense models."""
+    Under expert parallelism the receive buffer's use rides along the same
+    way: rows taken, rows needed, fallbacks and dispatches, summed over
+    layers here and over microbatches by the scan, turned into shares
+    host-side. Covers the logged step (not the whole log window).
+    Single-program path only: under pipeline parallelism the executor's
+    metric channel carries last-stage loss statistics and this metric is
+    absent. Empty dict for dense models."""
     stats = updates.get("moe_stats") if updates else None
     if not stats:
         return {}
-    counts = [
-        (leaf[0] if isinstance(leaf, tuple) else leaf).astype(jnp.float32)
-        for leaf in jax.tree.leaves(
-            stats, is_leaf=lambda x: isinstance(x, tuple)
+    by_name: dict[str, list[Array]] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        stats, is_leaf=lambda x: isinstance(x, tuple)
+    ):
+        leaf = leaf[0] if isinstance(leaf, tuple) else leaf
+        name = getattr(path[-1], "key", path[-1])
+        by_name.setdefault(f"moe_{name}", []).append(
+            leaf.astype(jnp.float32)
         )
-    ]
-    return {"moe_tokens_per_expert": sum(counts)}
+    return {name: sum(leaves) for name, leaves in by_name.items()}
 
 
 class CausalLMTask(PipelineTrainTask):
@@ -79,6 +86,14 @@ class CausalLMTask(PipelineTrainTask):
             metrics["task/moe_load_max_frac"] = float(
                 counts.max() / max(counts.sum(), 1.0)
             )
+        taken, needed, fallbacks, dispatches = (
+            metrics.pop(f"task/moe_{name}", None) for name in EP_BUFFER_STATS
+        )
+        if dispatches:
+            # dropless EP: the share of layer-steps whose receive buffer was
+            # the worst case, and how full the buffers taken were
+            metrics["moe/ep_fallback_share"] = float(fallbacks / dispatches)
+            metrics["moe/ep_buffer_fill"] = float(needed / max(taken, 1.0))
         return metrics
 
     # -- pipeline surface (PipelineTrainTask) --------------------------

@@ -106,7 +106,8 @@ class Qwen3MoeConfig:
     gdn_conv_size: int = 4
     # EP dispatch buffer sizing (see MoELayer.ep_capacity_factor): a factor
     # like 2.0 gives N·k/ep per-shard compute with deterministic drops;
-    # None = dropless worst-case buffer
+    # None = dropless and exact, the buffer a rung chosen per call from the
+    # exchanged counts, the worst case N·k only as the fallback
     ep_capacity_factor: Optional[float] = None
     # MLA attention on every (non-GDN) layer when set — the DeepSeek-V2
     # family rides this backbone (models/deepseek/)

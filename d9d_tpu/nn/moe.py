@@ -290,6 +290,14 @@ class SharedSwiGLU(nn.Module):
         return out
 
 
+# what the EP path sows into ``moe_stats`` beside ``tokens_per_expert``, each
+# a scalar that sums over layers and microbatches: receive-buffer rows taken,
+# rows needed, fallbacks to the worst-case rung, and dispatches to share over
+EP_BUFFER_STATS = (
+    "ep_buffer_rows", "ep_rows_needed", "ep_fallbacks", "ep_dispatches",
+)
+
+
 class MoELayer(nn.Module):
     """Router + dispatch + grouped experts + combine (+ shared expert).
 
@@ -336,9 +344,15 @@ class MoELayer(nn.Module):
     # this is also the per-shard grouped-GEMM row count, so a factor like
     # 2.0 gives the N·k/ep compute scaling; overflow drops assignment tails
     # deterministically, contributing exact zeros (DeepSeek capacity style).
-    # None = dropless worst-case buffer (n_loc·k·ep rows): exact results,
-    # but memory AND compute back at all-gather scale — use it for parity
-    # testing or tiny EP degrees, set a factor for production
+    # None = dropless, exact results: the buffer is the smallest rung of a
+    # fixed ladder (about 1.25 × n_loc·k, from ep = 8 one between,
+    # n_loc·k·ep) that holds the largest intake of any shard, chosen per
+    # call from the exchanged counts (ops/ep_dispatch.py ep_buffer_ladder).
+    # Compute follows the rows that arrive; only routing that sends one
+    # shard more than a quarter above its even share pays more, up to the
+    # all-gather scale of the last rung, and moe_stats /
+    # moe/ep_fallback_share say when. Memory is claimed for the last rung
+    # either way: set a factor where that does not fit
     ep_capacity_factor: Optional[float] = None
     # DeepSeek routed_scaling_factor: multiplies the routed experts'
     # combined output (not the shared expert)
@@ -481,39 +495,42 @@ class MoELayer(nn.Module):
                 self.grouped_experts.down_weight,
             )
 
-        def dispatch_local(x_loc, ids_loc, probs_loc, gate_w, up_w, down_w):
-            def expert_fn(rows, group_sizes):
-                return grouped_swiglu_apply(
-                    rows,
-                    jnp.ones((rows.shape[0],), jnp.float32),
-                    group_sizes,
-                    gate_w,
-                    up_w,
-                    down_w,
-                    dtype,
-                )
+        def expert_fn(rows, group_sizes, gate_w, up_w, down_w):
+            return grouped_swiglu_apply(
+                rows,
+                jnp.ones((rows.shape[0],), jnp.float32),
+                group_sizes,
+                gate_w,
+                up_w,
+                down_w,
+                dtype,
+            )
 
-            return ep_dispatch_compute_combine(
+        def dispatch_local(x_loc, ids_loc, probs_loc, gate_w, up_w, down_w):
+            out, use = ep_dispatch_compute_combine(
                 x_loc,
                 ids_loc,
                 probs_loc,
                 expert_fn,
+                (gate_w, up_w, down_w),
                 ep_axes=ep_axes,
                 e_loc=e_loc,
                 ep_world=ep_size,
                 capacity_factor=capacity,
             )
+            # one row a device: what its EP group's buffer took and needed
+            return out, jnp.stack(use).astype(jnp.float32)[None]
 
         if self.token_axes is None:
             # legacy flow: flatten tokens globally, reshard over ep_axes
             d = hidden.shape[-1]
             k = topk_ids.shape[-1]
-            out = compat.shard_map(
+            out, use = compat.shard_map(
                 dispatch_local,
                 mesh=mesh,
                 in_specs=(P(ep_axes, None),) * 3
                 + (P(ep_axes, None, None),) * 3,
-                out_specs=P(ep_axes, None),
+                out_specs=(P(ep_axes, None), P(ep_axes, None)),
                 axis_names=set(ep_axes),
             )(
                 hidden.reshape(-1, d),
@@ -521,6 +538,7 @@ class MoELayer(nn.Module):
                 topk_probs.reshape(-1, k),
                 *expert_weights(),
             )
+            self._sow_ep_buffer_use(use)
             return out.reshape(hidden.shape).astype(hidden.dtype)
 
         # token-layout flow: ride the residual sharding, no boundary reshard
@@ -555,7 +573,7 @@ class MoELayer(nn.Module):
                 ids_flat = lax.dynamic_slice_in_dim(ids_flat, start, n_own)
                 probs_flat = lax.dynamic_slice_in_dim(probs_flat, start, n_own)
 
-            out = dispatch_local(
+            out, use = dispatch_local(
                 x_flat, ids_flat, probs_flat, gate_w, up_w, down_w
             )
 
@@ -563,15 +581,34 @@ class MoELayer(nn.Module):
                 # restore the full local block (and with it, replication
                 # over the non-token ep axes the out_spec declares)
                 out = lax.all_gather(out, dup_axes, axis=0, tiled=True)
-            return out.reshape(b_loc, t_loc, d)
+            return out.reshape(b_loc, t_loc, d), use
 
-        out = compat.shard_map(
+        out, use = compat.shard_map(
             ep_body,
             mesh=mesh,
             in_specs=(tok_spec,) * 3 + (P(ep_axes, None, None),) * 3,
-            out_specs=tok_spec,
+            # EP groups route their own tokens and may take different
+            # rungs: every device of the mesh reports its group's
+            out_specs=(tok_spec, P(tuple(mesh.axis_names), None)),
             # the tiled all_gather over dup_axes makes the output invariant
             # there, which vma inference cannot see statically
             check_vma=False,
         )(hidden, topk_ids, topk_probs, *expert_weights())
+        self._sow_ep_buffer_use(use)
         return out.astype(hidden.dtype)
+
+    def _sow_ep_buffer_use(self, use: Array) -> None:
+        """``use [devices, 3]``: what each device's EP group took, needed
+        and whether it fell back (``ops/ep_dispatch.py EpBufferUse``).
+        Sown as means over the devices, beside ``tokens_per_expert``, with
+        a count of dispatches to take shares over: a run whose routing has
+        drifted out of the snug rung says so in its metrics."""
+        values = (*use.mean(axis=0), jnp.ones((), jnp.float32))
+        for name, value in zip(EP_BUFFER_STATS, values):
+            self.sow(
+                "moe_stats",
+                name,
+                value,
+                reduce_fn=lambda a, b: a + b,
+                init_fn=lambda: jnp.zeros((), jnp.float32),
+            )

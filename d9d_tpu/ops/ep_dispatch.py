@@ -4,10 +4,16 @@ TPU-native replacement for DeepEP's NVSHMEM all-to-all buffer (reference
 d9d/module/block/moe/communications/deepep.py:55-150): tokens travel to the
 shard that owns their expert, compute runs only on owned assignments, and
 results ride a mirrored ragged all-to-all home. Per-shard grouped-GEMM row
-count is the static receive buffer size: ``capacity_factor × N_global·k/ep``
-with a capacity factor set (the compute scaling the all-gather flow lacked),
-or the dropless worst case ``N_global·k`` with ``capacity_factor=None``
-(exact results; only the communication is reduced to the ragged rows).
+count is the static receive buffer size. With a capacity factor set it is
+``capacity_factor × N_global·k/ep`` and overflow is dropped. Dropless
+(``capacity_factor=None``) it is one rung of a short fixed ladder
+(:func:`ep_buffer_ladder`: near ``1.25·m``, from eight shards one between,
+``m·W``), chosen at run time per call from the counts the shards have
+exchanged anyway: the smallest rung that holds the largest intake of any
+shard. The worst case ``N_global·k`` is only the fallback, so exact results
+cost the compute and traffic of the rows that arrive plus a quarter, until
+routing is so uneven that one shard takes more than the snug rung holds;
+memory is still claimed for the last rung.
 
 Flow inside one ``shard_map`` shard over the ep axes (W shards, each
 owning ``e_loc = E/W`` experts):
@@ -16,24 +22,31 @@ owning ``e_loc = E/W`` experts):
    rows become contiguous per destination shard;
 2. all-gather the tiny per-expert count vector → the full [W, E] count
    matrix ``S``, from which *every* shard derives identical send/recv
-   sizes, offsets, and (under capacity) identical deterministic clamping;
+   sizes, offsets, the rung to run and (under capacity) identical
+   deterministic clamping;
 3. ragged all-to-all the hidden rows (only real rows move);
 4. re-sort received rows by local expert (they arrive grouped by source),
    grouped-GEMM through this shard's experts;
-5. inverse-permute and ragged all-to-all the results back;
-6. owner side: weight by router probs and scatter-add per token.
+5. inverse-permute and ragged all-to-all the results back, weight them
+   by the router probs;
+6. owner side: fold the k rows per token.
+
+Steps 3 to 5 are :func:`_exchange`; dropless, they run under a
+``lax.switch`` over the ladder (:func:`_laddered_exchange`). Every shard of
+a group takes the same branch, so the collectives inside stay matched.
 
 Differentiable end to end: ``ragged_all_to_all`` carries JVP/transpose
 rules, so the backward re-crosses the network exactly like DeepEP's
-dispatch/combine backward pair (deepep.py:91-150). Capacity overflow drops
-the tail rows of a (source, destination) slice deterministically; dropped
-assignments contribute exactly zero (their return slot is never written),
-matching capacity-style MoE semantics. ``capacity_factor=None`` is
-dropless with a ``m·W``-row buffer.
+dispatch/combine backward pair (deepep.py:91-150); the laddered exchange
+has its own VJP that makes the same choice again in the backward.
+Capacity overflow drops the tail rows of a (source, destination) slice
+deterministically; dropped assignments contribute exactly zero (their
+return slot is never written), matching capacity-style MoE semantics.
 """
 
+import functools
 import math
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +55,12 @@ from jax import lax
 from d9d_tpu.core.types import Array
 from d9d_tpu.ops.moe import combine_pairs, stable_expert_order
 
-__all__ = ["ep_buffer_rows", "ep_dispatch_compute_combine"]
+__all__ = [
+    "EpBufferUse",
+    "ep_buffer_ladder",
+    "ep_buffer_rows",
+    "ep_dispatch_compute_combine",
+]
 
 
 def _ragged_a2a(
@@ -85,63 +103,81 @@ def _ragged_a2a(
 def ep_buffer_rows(
     rows_per_shard: int, ep_world: int, capacity_factor: Optional[float]
 ) -> int:
-    """Static receive-buffer row count (the per-shard grouped-GEMM size)."""
+    """Static receive-buffer row count (the per-shard grouped-GEMM size).
+
+    ``capacity_factor=None`` gives the dropless worst case ``m·W``: the
+    last rung of :func:`ep_buffer_ladder`, the only one that always fits.
+    """
     if capacity_factor is None:
         return rows_per_shard * ep_world  # dropless worst case
     # round up to a sublane multiple for friendly tiling
     return ((math.ceil(rows_per_shard * capacity_factor) + 7) // 8) * 8
 
 
+# the snug rung's headroom over a perfectly even split of the group's rows
+_SNUG_FACTOR = 1.25
+
+
+def ep_buffer_ladder(rows_per_shard: int, ep_world: int) -> tuple[int, ...]:
+    """Ascending receive-buffer sizes the dropless path chooses from.
+
+    A snug rung near ``1.25·m`` and the worst case ``m·W``, which any
+    routing fits; from ``W = 8``, where the two are more than six times
+    apart, one rung between at their geometric mean. Every rung is traced,
+    lowered and loaded, forward and backward, in every MoE layer (about
+    a second of set-up a rung for four layers on the v5e host), so there
+    are at most three. ``W = 1`` has one rung.
+    """
+    full = ep_buffer_rows(rows_per_shard, ep_world, None)
+    factors = [_SNUG_FACTOR]
+    if ep_world >= 8:
+        factors.append(math.sqrt(_SNUG_FACTOR * ep_world))
+    rungs = {ep_buffer_rows(rows_per_shard, ep_world, f) for f in factors}
+    return (*sorted(r for r in rungs if r < full), full)
+
+
+class EpBufferUse(NamedTuple):
+    """What one dispatch took and needed; identical on every shard of the
+    EP group (both derive from the all-gathered count matrix)."""
+
+    rows_taken: Array  # int32 []: receive-buffer rows this call ran with
+    rows_needed: Array  # int32 []: the largest intake of any shard
+    fell_back: Array  # int32 []: 1 when a ladder's last rung had to run
+
+
 def _excl_cumsum(x: Array, axis: int = 0) -> Array:
     return jnp.cumsum(x, axis=axis) - x
 
 
-def ep_dispatch_compute_combine(
-    x_loc: Array,
-    ids_loc: Array,
-    probs_loc: Array,
-    expert_fn,
-    *,
-    ep_axes: tuple[str, ...],
-    e_loc: int,
-    ep_world: int,
-    capacity_factor: Optional[float],
+class _Route(NamedTuple):
+    """The static half of one exchange (hashable: a ``custom_vjp``
+    non-differentiable argument)."""
+
+    expert_fn: Callable
+    ep_axes: tuple[str, ...]
+    e_loc: int
+    ep_world: int
+
+
+def _exchange(
+    route: _Route, buf_rows: int, x_rows: Array, probs_rows: Array,
+    expert_weights, S: Array,
 ) -> Array:
-    """Inside-shard_map body: route rows to expert owners, compute, return.
+    """Steps 3 to 5 of the module docstring through a ``buf_rows``-row
+    receive buffer: ``x_rows [m, D]`` (sorted by global expert) → the
+    experts' outputs for the same rows, weighted by ``probs_rows [m]``.
 
-    ``expert_fn(rows [M, D], group_sizes [e_loc]) -> [M, D]`` runs this
-    shard's experts over expert-sorted rows (probabilities are applied on
-    the owner side, after the results come home).
+    A receiver's intake beyond ``buf_rows`` is cut deterministically,
+    identically on every shard: earlier sources keep their rows. With
+    ``buf_rows`` at or above the group's largest intake nothing is cut.
     """
-    n, k = ids_loc.shape
-    m = n * k
-    d_model = x_loc.shape[-1]
+    expert_fn, ep_axes, e_loc, ep_world = route
+    m, d_model = x_rows.shape
     me = lax.axis_index(ep_axes)
-
-    # 1. group assignment rows by global expert id (sort-free stable
-    # permutation — see ops/moe.py stable_expert_order; TPU sorts are
-    # bitonic and this runs per MoE layer per microbatch)
-    ids_flat = ids_loc.reshape(-1)
-    with jax.named_scope("moe/permute"):
-        order, pair_dest, counts = stable_expert_order(
-            ids_flat, e_loc * ep_world
-        )
-        token_of = order // k
-        x_rows = jnp.take(x_loc, token_of, axis=0)  # [m, D]
-
-    # 2. tiny count exchange: S[s, e] = rows shard s routes to expert e
-    S = lax.all_gather(counts, ep_axes, axis=0)  # [W, E]
     # rows shard s sends to shard d
     R = S.reshape(ep_world, ep_world, e_loc).sum(axis=-1)  # [W(src), W(dst)]
-
-    buf_rows = ep_buffer_rows(m, ep_world, capacity_factor)
-    if capacity_factor is None:
-        A = R
-    else:
-        # deterministic clamp, identical on every shard: earlier sources
-        # keep their rows, the tail of a receiver's intake is cut
-        room = jnp.maximum(buf_rows - _excl_cumsum(R, axis=0), 0)
-        A = jnp.minimum(R, room)
+    room = jnp.maximum(buf_rows - _excl_cumsum(R, axis=0), 0)
+    A = jnp.minimum(R, room)
 
     send_sizes = A[me]  # [W] rows I send to each dst
     input_offsets = _excl_cumsum(R[me])  # my sorted rows: blocks sized R[me]
@@ -188,7 +224,7 @@ def ep_dispatch_compute_combine(
         rows_sorted = jnp.take(recv, by_expert, axis=0)
 
     with jax.named_scope("ep/expert_compute"):
-        y_sorted = expert_fn(rows_sorted, group_sizes)
+        y_sorted = expert_fn(rows_sorted, group_sizes, *expert_weights)
     # un-sort via the inverse permutation as a gather (dest[by_expert[r]]
     # == r) — cheaper than a zeros+scatter on TPU, same as ops/moe.py's
     # unpermute_combine
@@ -209,10 +245,137 @@ def ep_dispatch_compute_combine(
             ep_axes=ep_axes,
             ep_world=ep_world,
         )
+    with jax.named_scope("moe/combine"):
+        return home * probs_rows[:, None].astype(home.dtype)
 
-    # 6. weight by router probs, fold the k assignments per token
-    # (collision-free gather form — see ops/moe.py combine_pairs)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _laddered_exchange(
+    route: _Route, ladder: tuple[int, ...], rung: Array,
+    x_rows: Array, probs_rows: Array, expert_weights, S: Array,
+) -> Array:
+    """:func:`_exchange` through rung ``rung`` of ``ladder``.
+
+    ``rung`` is the same on every shard of the group, so the ragged
+    all-to-alls inside the taken branch stay matched. The VJP is its own
+    rule because differentiating through ``lax.switch`` makes every
+    branch return the union of all branches' residuals, the others' as
+    zeros: the snug branch would still allocate and zero worst-case-sized
+    residuals. Here the forward keeps only its inputs and the backward
+    makes the choice again, each branch differentiating its own exchange.
+    Nothing after the exchange needs its output to differentiate (the
+    probabilities are applied inside, the fold per token is linear), so
+    under a layer's remat the recomputed forward is dead code and the
+    backward's own recomputation is the one the remat would have done.
+    """
+    return lax.switch(
+        rung,
+        [functools.partial(_exchange, route, rows) for rows in ladder],
+        x_rows, probs_rows, expert_weights, S,
+    )
+
+
+def _laddered_fwd(route, ladder, rung, *operands):
+    out = _laddered_exchange(route, ladder, rung, *operands)
+    return out, (rung, *operands)
+
+
+def _laddered_bwd(route, ladder, residuals, g):
+    rung, x_rows, probs_rows, expert_weights, S = residuals
+
+    def pull_back(rows):
+        def branch(x_rows, probs_rows, expert_weights, S, g):
+            _, vjp = jax.vjp(
+                lambda x, p, w: _exchange(route, rows, x, p, w, S),
+                x_rows, probs_rows, expert_weights,
+            )
+            return vjp(g)
+
+        return branch
+
+    d_rows, d_probs, d_weights = lax.switch(
+        rung, [pull_back(rows) for rows in ladder],
+        x_rows, probs_rows, expert_weights, S, g,
+    )
+    return None, d_rows, d_probs, d_weights, None
+
+
+_laddered_exchange.defvjp(_laddered_fwd, _laddered_bwd)
+
+
+def ep_dispatch_compute_combine(
+    x_loc: Array,
+    ids_loc: Array,
+    probs_loc: Array,
+    expert_fn,
+    expert_weights: tuple = (),
+    *,
+    ep_axes: tuple[str, ...],
+    e_loc: int,
+    ep_world: int,
+    capacity_factor: Optional[float],
+) -> tuple[Array, EpBufferUse]:
+    """Inside-shard_map body: route rows to expert owners, compute, return.
+
+    ``expert_fn(rows [M, D], group_sizes [e_loc], *expert_weights) ->
+    [M, D]`` runs this shard's experts over expert-sorted rows
+    (probabilities are applied on the owner side, after the results come
+    home). Weights that take gradients are passed through
+    ``expert_weights``, not closed over: the dropless exchange is a
+    ``custom_vjp``. ``M`` is the receive buffer's row count: one static
+    size under a capacity factor, one rung of :func:`ep_buffer_ladder`
+    per call when dropless.
+
+    Returns the combined rows ``[n, D]`` and the buffer's use.
+    """
+    n, k = ids_loc.shape
+    m = n * k
+
+    # 1. group assignment rows by global expert id (sort-free stable
+    # permutation — see ops/moe.py stable_expert_order; TPU sorts are
+    # bitonic and this runs per MoE layer per microbatch)
+    ids_flat = ids_loc.reshape(-1)
+    with jax.named_scope("moe/permute"):
+        order, pair_dest, counts = stable_expert_order(
+            ids_flat, e_loc * ep_world
+        )
+        token_of = order // k
+        x_rows = jnp.take(x_loc, token_of, axis=0)  # [m, D]
+
+    # 2. tiny count exchange: S[s, e] = rows shard s routes to expert e
+    S = lax.all_gather(counts, ep_axes, axis=0)  # [W, E]
+    # the largest intake of any shard: known everywhere before a row moves
+    need = S.reshape(ep_world, ep_world, e_loc).sum(axis=(0, 2)).max()
+
     with jax.named_scope("moe/combine"):
         probs_rows = jnp.take(probs_loc.reshape(-1), order)
-        weighted = home * probs_rows[:, None].astype(home.dtype)
-        return combine_pairs(weighted, pair_dest, n)
+
+    route = _Route(expert_fn, tuple(ep_axes), e_loc, ep_world)
+    if capacity_factor is None:
+        ladder = ep_buffer_ladder(m, ep_world)
+    else:
+        ladder = (ep_buffer_rows(m, ep_world, capacity_factor),)
+    if len(ladder) == 1:
+        weighted = _exchange(
+            route, ladder[0], x_rows, probs_rows, expert_weights, S
+        )
+        rung = 0
+    else:
+        # the smallest rung that holds the largest intake; the last always
+        # does, so the path stays dropless whatever the routing
+        rung = sum((need > rows).astype(jnp.int32) for rows in ladder[:-1])
+        weighted = _laddered_exchange(
+            route, ladder, rung, x_rows, probs_rows, tuple(expert_weights), S
+        )
+
+    # 6. fold the k assignments per token, already weighted by the router
+    # probs (collision-free gather form — see ops/moe.py combine_pairs)
+    with jax.named_scope("moe/combine"):
+        out = combine_pairs(weighted, pair_dest, n)
+    return out, EpBufferUse(
+        rows_taken=jnp.take(jnp.asarray(ladder, jnp.int32), rung),
+        rows_needed=need.astype(jnp.int32),
+        fell_back=jnp.asarray(
+            (rung == len(ladder) - 1) & (len(ladder) > 1), jnp.int32
+        ),
+    )

@@ -272,17 +272,17 @@ def test_ep_dispatch_combine_on_four_chips(topo, as_tpu, mode):
     rows = _on(NamedSharding(mesh, P("ep")))
     e_loc = E // world
 
-    def body(x, ids, probs, gate_w, up_w, down_w):
-        def expert_fn(rows, group_sizes):
-            return grouped_swiglu_apply(
-                rows, jnp.ones((rows.shape[0],), jnp.float32), group_sizes,
-                gate_w, up_w, down_w, BF16,
-            )
-
-        return ep_dispatch_compute_combine(
-            x, ids, probs, expert_fn, ep_axes=("ep",), e_loc=e_loc,
-            ep_world=world, capacity_factor=None,
+    def expert_fn(rows, group_sizes, gate_w, up_w, down_w):
+        return grouped_swiglu_apply(
+            rows, jnp.ones((rows.shape[0],), jnp.float32), group_sizes,
+            gate_w, up_w, down_w, BF16,
         )
+
+    def body(x, ids, probs, *weights):
+        return ep_dispatch_compute_combine(
+            x, ids, probs, expert_fn, weights, ep_axes=("ep",), e_loc=e_loc,
+            ep_world=world, capacity_factor=None,
+        )[0]
 
     run = jax.shard_map(
         body, mesh=mesh, in_specs=(P("ep"),) * 6, out_specs=P("ep"),

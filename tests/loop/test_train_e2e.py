@@ -142,25 +142,42 @@ def test_weighted_loss_ignores_masked_tokens():
     assert history[-1]["loss_weight"] == 24.0
 
 
-def test_moe_training_reports_expert_load_balance(devices):
+@pytest.mark.parametrize("expert_parallel", [False, True], ids=["local", "ep"])
+def test_moe_training_reports_expert_load_balance(devices, expert_parallel):
     """MoE runs surface the tokens_per_expert load statistic (reference
     buffer, module/block/moe/layer.py:16) as task/moe_load_max_frac —
-    the heaviest expert's share of routed assignments."""
+    the heaviest expert's share of routed assignments. Under dropless
+    expert parallelism the metric flush also says how the receive
+    buffers were used: the share of layer-steps that fell back to the
+    worst-case rung, and the fill of the buffers taken."""
+    import dataclasses
+
     import jax.numpy as jnp
 
     from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
+    from d9d_tpu.parallel import fsdp_ep_plan
+
+    mesh_kw = {"ep_shard": 4} if expert_parallel else {}
+    ctx = MeshParameters(dp_shard=4, **mesh_kw).build(devices[:4])
+    cfg = Qwen3MoeConfig.tiny(vocab_size=VOCAB)
+    if expert_parallel:
+        cfg = dataclasses.replace(
+            cfg, ep_axes=ctx.ep_shard_axes,
+            moe_token_axes=(ctx.batch_axes, ctx.sequence_axes),
+        )
 
     class MoEProvider(ModelProvider):
         def build_module(self, stage):
             return Qwen3MoeCausalLM(
-                config=Qwen3MoeConfig.tiny(vocab_size=VOCAB),
+                config=cfg,
                 sdpa=eager_sdpa,
                 stage=stage,
+                act_sharding=ctx.batch_sharding() if expert_parallel else None,
                 dtype=jnp.float32,
             )
 
         def build_plan(self, ctx):
-            return replicate_plan(ctx)
+            return fsdp_ep_plan(ctx) if expert_parallel else replicate_plan(ctx)
 
         def sample_inputs(self, batch_size, seq_len):
             z = np.zeros((batch_size, seq_len), np.int32)
@@ -172,7 +189,6 @@ def test_moe_training_reports_expert_load_balance(devices):
             for _ in range(2):
                 yield {"input_ids": rng.randint(0, VOCAB, size=(8, 17))}
 
-    ctx = MeshParameters(dp_shard=4).build(devices[:4])
     trainer = Trainer(
         ctx=ctx,
         config=TrainerConfig(
@@ -190,6 +206,16 @@ def test_moe_training_reports_expert_load_balance(devices):
     assert 1.0 / 8 - 1e-6 <= frac <= 1.0
     # dense runs must NOT carry the metric
     assert "task/moe_load_max_frac" not in _dense_history(devices)[-1]
+    # the raw sums never reach the history, the shares only under EP
+    assert not [k for k in hist[-1] if k.startswith("task/moe_ep_")]
+    if expert_parallel:
+        for entry in hist:
+            # two microbatches x the tiny preset's expert layers a step
+            assert entry["moe/ep_fallback_share"] in (0.0, 0.25, 0.5, 0.75, 1.0)
+            assert 0.0 < entry["moe/ep_buffer_fill"] <= 1.0
+    else:
+        assert "moe/ep_fallback_share" not in hist[-1]
+        assert "moe/ep_buffer_fill" not in hist[-1]
 
 
 def _dense_history(devices):

@@ -125,6 +125,96 @@ def test_moe_layer_tokens_per_expert_stats(ctx):
     assert int(np.asarray(tpe).sum()) == 2 * 8 * 2
 
 
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_local_path_has_no_buffer_choice(ctx):
+    """Without ``ep_axes`` the layer is the local permute path and nothing
+    of the EP path's run-time buffer choice is in its program: no
+    conditional and no custom VJP forward or backward, and the only
+    statistic sown is ``tokens_per_expert``. The one-chip programs are
+    what they were."""
+    layer = MoELayer(
+        hidden_dim=16, intermediate_dim_grouped=32, num_grouped_experts=8,
+        top_k=2, dtype=jnp.float32,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+
+    def loss(p, x):
+        out, stats = layer.apply({"params": p}, x, mutable=["moe_stats"])
+        return (out ** 2).sum(), stats
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(params, x)
+    names = set(_primitives(jaxpr.jaxpr))
+    assert "ragged_dot_general" in names  # the scan reaches the experts
+    assert not names & {"cond", "custom_vjp_call", "custom_vjp_call_jaxpr"}
+    assert not names & {"all_gather", "ragged_all_to_all", "shard_map"}
+    _, stats = loss(params, x)
+    assert set(stats["moe_stats"]) == {"tokens_per_expert"}
+
+
+@pytest.mark.parametrize("token_layout", [False, True], ids=["legacy", "layout"])
+@pytest.mark.parametrize("routing", ["seeded", "one_shard"])
+def test_ep_layer_sows_buffer_use(ctx, token_layout, routing):
+    """The EP flows report the receive buffer taken, the rows needed and a
+    fallback beside ``tokens_per_expert``; with every token forced onto
+    one shard's experts the last rung runs and the layer still equals the
+    local path."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from d9d_tpu.ops.ep_dispatch import ep_buffer_ladder
+
+    kw = dict(
+        hidden_dim=16, intermediate_dim_grouped=32, num_grouped_experts=16,
+        top_k=2, router_enable_expert_bias=True, dtype=jnp.float32,
+    )
+    local = MoELayer(**kw)
+    ep = MoELayer(
+        ep_axes=ctx.ep_shard_axes,
+        token_axes=(ctx.batch_axes, ctx.sequence_axes) if token_layout else None,
+        **kw,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16))
+    params = local.init(jax.random.PRNGKey(0), x)["params"]
+    if routing == "one_shard":
+        # the selection bias sends every token to experts 0 and 1
+        bias = jnp.zeros((16,)).at[:2].set(10.0)
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: bias if "e_score_correction_bias" in str(path)
+            else leaf, params,
+        )
+    if token_layout:
+        x = jax.device_put(
+            x, NamedSharding(ctx.mesh, P(ctx.batch_axes, ctx.sequence_axes))
+        )
+    want = local.apply({"params": params}, x)
+    got, stats = jax.jit(
+        lambda p, x: ep.apply({"params": p}, x, mutable=["moe_stats"])
+    )(params, x)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
+    )
+
+    stats = {k: float(np.asarray(v).sum()) if k != "tokens_per_expert"
+             else np.asarray(v) for k, v in stats["moe_stats"].items()}
+    world = 8
+    rows = 8 * 16 * 2  # the one EP group's assignment rows
+    ladder = ep_buffer_ladder(rows // world, world)
+    needed = stats["tokens_per_expert"].reshape(world, -1).sum(axis=1).max()
+    assert stats["ep_dispatches"] == 1.0
+    assert stats["ep_rows_needed"] == needed
+    assert stats["ep_buffer_rows"] == min(r for r in ladder if r >= needed)
+    assert stats["ep_fallbacks"] == float(stats["ep_buffer_rows"] == ladder[-1])
+    if routing == "one_shard":
+        assert needed == rows and stats["ep_fallbacks"] == 1.0
+
+
 @pytest.mark.parametrize(
     "mesh_kw",
     [

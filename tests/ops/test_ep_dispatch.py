@@ -26,7 +26,12 @@ pytestmark = [pytest.mark.e2e, requires_modern_jax]
 from jax.sharding import Mesh, PartitionSpec as P
 
 from d9d_tpu.core import compat
-from d9d_tpu.ops.ep_dispatch import ep_buffer_rows, ep_dispatch_compute_combine
+from d9d_tpu.ops import ep_dispatch
+from d9d_tpu.ops.ep_dispatch import (
+    ep_buffer_ladder,
+    ep_buffer_rows,
+    ep_dispatch_compute_combine,
+)
 
 W = 4  # ep world
 E = 8  # global experts
@@ -40,11 +45,12 @@ def _mesh(devices):
     return Mesh(np.array(devices[:W]), ("ep",))
 
 
-def _expert_fn_factory(shard_offset, seen_rows):
+def _expert_fn_factory(seen_rows):
     """Expert e transforms rows as x * (2 + global_e). Records GEMM size."""
 
     def fn(rows, group_sizes):
         seen_rows.append(rows.shape[0])
+        shard_offset = jax.lax.axis_index(("ep",)) * E_LOC
         # build per-row scale from group membership
         bounds = jnp.cumsum(group_sizes)
         local_e = (jnp.arange(rows.shape[0])[:, None] >= bounds[None, :]).sum(1)
@@ -59,17 +65,16 @@ def _run_dispatch(devices, x, ids, probs, capacity_factor):
     seen: list[int] = []
 
     def body(x_loc, ids_loc, probs_loc):
-        shard_offset = jax.lax.axis_index(("ep",)) * E_LOC
         return ep_dispatch_compute_combine(
             x_loc,
             ids_loc,
             probs_loc,
-            _expert_fn_factory(shard_offset, seen),
+            _expert_fn_factory(seen),
             ep_axes=("ep",),
             e_loc=E_LOC,
             ep_world=W,
             capacity_factor=capacity_factor,
-        )
+        )[0]
 
     run = jax.jit(
         compat.shard_map(
@@ -120,8 +125,10 @@ def test_gemm_rows_follow_capacity_contract(devices):
     assert all(s == expected for s in seen)
     assert expected < m * W  # strictly below the all-gather row count
 
+    # dropless: one program per rung of the ladder, the worst case last
     _, seen_dropless = _run_dispatch(devices, x, ids, probs, None)
-    assert all(s == ep_buffer_rows(m, W, None) for s in seen_dropless)
+    assert set(seen_dropless) == set(ep_buffer_ladder(m, W))
+    assert max(seen_dropless) == ep_buffer_rows(m, W, None)
 
 
 def test_generous_capacity_matches_oracle(devices):
@@ -164,13 +171,12 @@ def test_dispatch_is_differentiable(devices):
 
     def loss(x, probs):
         def body(x_loc, ids_loc, probs_loc):
-            shard_offset = jax.lax.axis_index(("ep",)) * E_LOC
             return ep_dispatch_compute_combine(
                 x_loc, ids_loc, probs_loc,
-                _expert_fn_factory(shard_offset, []),
+                _expert_fn_factory([]),
                 ep_axes=("ep",), e_loc=E_LOC, ep_world=W,
                 capacity_factor=None,
-            )
+            )[0]
 
         out = compat.shard_map(
             body, mesh=mesh, in_specs=(P("ep"), P("ep"), P("ep")),
@@ -193,3 +199,167 @@ def test_dispatch_is_differentiable(devices):
     )
     np.testing.assert_allclose(gx, egx, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(gp, egp, rtol=1e-4, atol=1e-4)
+
+
+# -- the dropless ladder: a rung chosen from the exchanged counts -------------
+
+INTER = 8  # expert FFN width of the real-experts tests
+M = N_LOC * K  # assignment rows a shard
+
+
+def _routing(kind, world):
+    """``ids [N, K]`` over ``E`` experts on ``world`` shards whose largest
+    per-shard intake is ``M`` ("uniform"), 30 rows on shard 0 ("skewed",
+    eight shards: between the snug rung and the worst case) or every row
+    ("one_shard")."""
+    t = np.arange(world * N_LOC)
+    if kind == "uniform":
+        cols = [t % E, (t + E // 2) % E]
+    elif kind == "skewed":
+        assert world == E
+        cols = [np.where(t < 30, 0, 1 + t % (E - 1)), 1 + t % (E - 1)]
+    else:
+        assert kind == "one_shard"
+        cols = [0 * t, 0 * t + E // world - 1]
+    return np.stack(cols, axis=1).astype(np.int32)
+
+
+def _needed(ids, world):
+    """The largest intake of any shard, reckoned from the ids alone."""
+    return int(np.bincount(ids.reshape(-1) // (E // world), minlength=world).max())
+
+
+def _swiglu_experts(rows, group_sizes, gate_w, up_w, down_w):
+    from d9d_tpu.nn.moe import grouped_swiglu_apply
+
+    return grouped_swiglu_apply(
+        rows, jnp.ones((rows.shape[0],), jnp.float32), group_sizes,
+        gate_w, up_w, down_w, jnp.float32,
+    )
+
+
+def _expert_weights(seed=7):
+    rng = np.random.RandomState(seed)
+    return tuple(
+        jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.3)
+        for shape in ((E, D, INTER), (E, D, INTER), (E, INTER, D))
+    )
+
+
+def _loss_grads_and_use(devices, world, x, ids, probs, weights):
+    """``(out, grads of x, probs and the three expert weights, use
+    [world, 3])`` through real grouped-SwiGLU experts sharded over the ep
+    axis of ``world`` shards, dropless."""
+    mesh = Mesh(np.array(devices[:world]), ("ep",))
+
+    def body(x_loc, ids_loc, probs_loc, *w_loc):
+        out, use = ep_dispatch_compute_combine(
+            x_loc, ids_loc, probs_loc, _swiglu_experts, w_loc,
+            ep_axes=("ep",), e_loc=E // world, ep_world=world,
+            capacity_factor=None,
+        )
+        return out, jnp.stack(use)[None]
+
+    run = compat.shard_map(
+        body, mesh=mesh, in_specs=(P("ep"),) * 6,
+        out_specs=(P("ep"), P("ep")), check_vma=False,
+    )
+
+    def loss(x, probs, *w):
+        out, use = run(x, ids, probs, *w)
+        return (out ** 2).sum(), (out, use)
+
+    with jax.set_mesh(mesh):
+        (_, (out, use)), grads = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        )(jnp.asarray(x), jnp.asarray(probs), *weights)
+    return np.asarray(out), [np.asarray(g) for g in grads], np.asarray(use)
+
+
+@pytest.mark.parametrize("world,kind,rung", [
+    (4, "uniform", 0), (4, "one_shard", 1),
+    (8, "uniform", 0), (8, "skewed", 1), (8, "one_shard", 2),
+])
+def test_ladder_matches_the_worst_case_buffer(
+    devices, monkeypatch, world, kind, rung
+):
+    """Whatever rung the counts select (the snug one, the middle one eight
+    shards have, the worst case), outputs are bit-equal to the single
+    ``m·W`` buffer's and every gradient equal; the rung and the rows
+    needed are reported identically by every shard."""
+    rng = np.random.RandomState(11)
+    n = world * N_LOC
+    x = rng.randn(n, D).astype(np.float32)
+    probs = rng.rand(n, K).astype(np.float32)
+    ids = _routing(kind, world)
+    weights = _expert_weights()
+    ladder = ep_buffer_ladder(M, world)
+    assert ladder == {4: (16, 48), 8: (16, 40, 96)}[world]
+    needed = _needed(ids, world)
+    assert ladder[rung] >= needed > (ladder[rung - 1] if rung else 0)
+
+    out, grads, use = _loss_grads_and_use(devices, world, x, ids, probs, weights)
+    assert np.all(use == use[0])  # the same on every shard
+    assert use[0].tolist() == [
+        ladder[rung], needed, int(rung == len(ladder) - 1)
+    ]
+
+    # the parent's dropless path: one worst-case buffer, plain autodiff
+    monkeypatch.setattr(
+        ep_dispatch, "ep_buffer_ladder", lambda rows, w: (rows * w,)
+    )
+    ref_out, ref_grads, ref_use = _loss_grads_and_use(
+        devices, world, x, ids, probs, weights
+    )
+    assert ref_use[0].tolist() == [M * world, needed, 0]
+    np.testing.assert_array_equal(out, ref_out)
+    assert np.abs(out).max() > 0
+    for name, g, ref in zip(
+        ("x", "probs", "gate", "up", "down"), grads, ref_grads
+    ):
+        np.testing.assert_allclose(g, ref, rtol=1e-6, atol=1e-6, err_msg=name)
+        assert np.abs(ref).max() > 0, name
+
+
+@pytest.mark.parametrize(
+    "m,world", [(12, 4), (32768, 4), (8, 2), (100, 8), (5, 4), (12, 1), (4096, 64)]
+)
+def test_ladder_is_a_function_of_rows_and_world(m, world):
+    ladder = ep_buffer_ladder(m, world)
+    assert ladder == ep_buffer_ladder(m, world)
+    assert ladder[-1] == m * world == ep_buffer_rows(m, world, None)
+    assert list(ladder) == sorted(set(ladder))  # strictly ascending
+    assert all(r % 8 == 0 and r >= m for r in ladder[:-1])
+    # the worst case is far enough from the snug rung for one between
+    assert len(ladder) <= (3 if world >= 8 else 2)
+    if world == 1:
+        assert ladder == (m,)
+    elif m >= 64:
+        # the snug rung holds a near-even split with a quarter to spare
+        assert 1.25 * m <= ladder[0] < 1.25 * m + 8
+
+
+@pytest.mark.parametrize("capacity_factor", [0.5, 1.0, 2.0, 4.0])
+def test_capacity_factor_drops_the_same_tail_rows(devices, capacity_factor):
+    """One static buffer, as before the ladder: with every assignment on
+    shard 0 the first ``cap`` rows of its intake (sources in order, each
+    source's block expert-sorted) are kept and the rest contribute zero."""
+    rng = np.random.RandomState(2)
+    n = W * N_LOC
+    x = rng.randn(n, D).astype(np.float32)
+    ids = _routing("one_shard", W)
+    probs = rng.rand(n, K).astype(np.float32)
+    m = N_LOC * K
+    cap = ep_buffer_rows(m, W, capacity_factor)
+
+    out, seen = _run_dispatch(devices, x, ids, probs, capacity_factor)
+    assert set(seen) == {cap}  # one program, one buffer
+
+    expected = np.zeros_like(x)
+    for src in range(W):
+        for pos in range(m):  # the source's rows, sorted by expert then token
+            expert, token = divmod(pos, N_LOC)
+            if src * m + pos < cap:
+                t = src * N_LOC + token
+                expected[t] += x[t] * (2.0 + expert) * probs[t, expert]
+    np.testing.assert_allclose(out, expected, rtol=1e-5, atol=1e-5)
