@@ -12,8 +12,8 @@ cut; weights and data made from ``--seed``):
 - ``sync``: a chain of large bf16 matmuls timed around
   ``block_until_ready`` comes out at or below the chip's peak FLOP/s,
   i.e. the call waits for the device;
-- ``train``: a ``Trainer`` built from providers as ``bench.py`` and
-  ``example/qwen3_moe/pretrain.py`` build theirs (bf16 params,
+- ``train``: a ``Trainer`` built from providers as
+  ``example/qwen3_moe/pretrain.py`` builds its own (bf16 params,
   ``StochasticAdamW``) takes a few steps on a repeated batch: losses
   finite and falling, the step-0 loss within a tolerance of the same
   forward recomputed with eager attention, the Pallas flash kernels in
@@ -228,7 +228,7 @@ def program_hbm_bytes(name: str, since: int = 0) -> int:
     return int(records[-1].hbm_peak_bytes)
 
 
-# -- providers: the same shapes bench.py and the example hand the Trainer --
+# -- providers: the same shapes the example hands the Trainer --
 
 
 class SmokeModel(ModelProvider):

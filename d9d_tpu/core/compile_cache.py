@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-Script entry points (``chip_smoke.py``, ``bench.py``, the examples, the
-``tools/bench_*.py`` mains) call :func:`enable_compile_cache` first
+Script entry points (``chip_smoke.py``, ``benchmarks/run.py``, the
+examples, the ``tools/bench_*.py`` mains) call :func:`enable_compile_cache` first
 thing; ``import d9d_tpu`` never does, and the tests keep the cache off
 (``tests/conftest.py``).
 

@@ -2,7 +2,7 @@
 
 Reference: d9d/loop/component/data_loader_factory.py:102 — torchdata's
 worker-backed ``StatefulDataLoader`` keeps batch N+1's host work off the
-step path. TPU equivalent (VERDICT r3 item 4): a producer thread runs the
+step path. TPU equivalent: a producer thread runs the
 host input pipeline — raw fetch from the loader and task
 ``prepare_batch`` (numpy), plus device staging whenever that is
 collective-free — ``depth`` batches ahead of the consuming train loop,

@@ -295,8 +295,8 @@ class Trainer:
             config=self._model_config(),
         )
         # tok_s is whole-mesh throughput, so MFU normalizes by the whole
-        # mesh's peak (per-chip peak x mesh size), matching bench.py's
-        # single-chip convention at mesh size 1. None off the TPU: the
+        # mesh's peak (per-chip peak x mesh size), the single-chip
+        # convention at mesh size 1. None off the TPU: the
         # CPU rig has no peak, so it emits no train/mfu gauge
         chip_peak = device_peak_flops()
         self._peak_flops = (

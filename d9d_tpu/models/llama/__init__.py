@@ -1,4 +1,4 @@
-"""Llama-3 model family (BASELINE.md config 4: Llama-3-70B-class 4D runs).
+"""Llama-3 model family (Llama-3-70B-class 4D runs).
 
 Llama-3's decoder is architecturally the Qwen3-dense stack minus the
 q/k RMSNorms (and with Llama's rope theta / vocab): HF even uses the

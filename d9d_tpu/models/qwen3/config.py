@@ -32,7 +32,7 @@ class Qwen3DenseConfig:
     # "full" recomputes everything in backward (minimum memory, ~8N HFU);
     # "dots_no_batch" saves matmul outputs with no batch dims (XLA's
     # checkpoint_dots_with_no_batch_dims policy) — fewer recomputed FLOPs
-    # for more activation memory. Measured via bench.py on chip.
+    # for more activation memory. No cell sets it yet (ROADMAP S6).
     remat_policy: str = "full"
 
     @property
@@ -41,7 +41,7 @@ class Qwen3DenseConfig:
 
     @staticmethod
     def tiny(vocab_size: int = 256) -> "Qwen3DenseConfig":
-        """2-layer CPU-runnable config (BASELINE.md config 1)."""
+        """2-layer CPU-runnable config."""
         return Qwen3DenseConfig(
             vocab_ranges=(("default", vocab_size),),
             hidden_size=64,

@@ -65,7 +65,7 @@ class Qwen3DenseBackbone(nn.Module):
     # residual-stream [B, T, E] sharding pin: anchors SPMD propagation at
     # every layer boundary so activation layouts can't drift into fused
     # batch shardings that force replicate-reshard at attention (the ring
-    # SDPA wants [b@dp, t@cp_s, h@tp]) — see VERDICT r2 Weak #2
+    # SDPA wants [b@dp, t@cp_s, h@tp])
     act_sharding: Optional[NamedSharding] = None
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32

@@ -21,8 +21,8 @@ from d9d_tpu.ops import RopeStyle, apply_rope
 
 
 def _decode_contract_checks(start, t: int, s_max: int):
-    """Functionalized assertions for the two traced decode contracts
-    (ADVICE r4): the multi-token prefill FAST PATH is only valid on an
+    """Functionalized assertions for the two traced decode contracts:
+    the multi-token prefill FAST PATH is only valid on an
     empty cache (continuation chunks — ``in_continuation_chunk()`` —
     take the slot-cache path instead and are valid at any index), and
     the cache must never overflow (past capacity,
@@ -385,7 +385,7 @@ class GroupedQueryAttention(nn.Module):
     # parameter pytree — q_proj/k_proj/v_proj kernels stay separate for
     # checkpoints/HF/PEFT/plans). Off by default: under tensor parallelism
     # the concat crosses the tp-sharded head dim and XLA must reshard the
-    # kernels; single-chip benches enable it (D9D_BENCH_FUSED_QKV).
+    # kernels.
     fused_qkv: bool = False
     # Autoregressive decode mode (loop/generate.py), on when > 0:
     # maintains KV-cache variables in the "cache" collection
@@ -761,8 +761,8 @@ class MultiHeadLatentAttention(nn.Module):
     # False: single-token steps instead decompress EVERY cache slot
     # through kv_up and attend over the slot cache — the cost the
     # absorbed trick avoids. Kept as the absorbed form's correctness
-    # oracle and the honest half of the bench A/B (ADVICE r4: timing a
-    # t=2 prefill on a warm cache measures neither).
+    # oracle and the honest half of an A/B (timing a t=2 prefill on a
+    # warm cache measures neither).
     decode_absorbed: bool = True
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
@@ -941,8 +941,7 @@ class MultiHeadLatentAttention(nn.Module):
         """Non-absorbed decode: decompress every cache slot through kv_up
         each step (O(s_max·r·h·(d_nope+d_v)) per token — the traffic the
         absorbed form avoids) and attend over the slot cache. Serves as
-        the absorbed path's correctness oracle and the honest
-        'decompressed' leg of tools/bench_kernels.py mla_decode.
+        the absorbed path's correctness oracle.
         """
         from d9d_tpu.ops.attention.eager import eager_sdpa
 

@@ -230,12 +230,12 @@ def grouped_swiglu_apply(
     checkpoints, HF mappers, PEFT and sharding plans) stay separate
     gate/up tensors.
 
-    Caveat (ADVICE r3): because ragged_dot is an opaque custom call, XLA
+    Caveat: because ragged_dot is an opaque custom call, XLA
     materializes the concatenated weight copy each forward (again in the
     backward under remat) — one extra full-weight write+read per MoE layer
-    per microbatch. Measured a net win at the r3-swept config (64E × i256,
-    bf16), but tools/roofline.py predicts the copy INVERTS at µBS=1 with
-    fp32 master weights (the concat becomes the largest single HBM term);
+    per microbatch. In decode the copy is 768 MB a layer and 0.235 s of
+    4.12 s traced on ``qwen3-30b-a3b-decode.serve-rollout-closed``
+    (ledger, PR 24, ``breakdown``; ROADMAP S3);
     ``D9D_TPU_MOE_FUSED_GATE_UP=0`` switches to two grouped matmuls for
     the on-chip A/B (ROADMAP ``env-selected-kernels``; not run yet).
 

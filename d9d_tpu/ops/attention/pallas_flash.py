@@ -810,11 +810,11 @@ def make_pallas_flash_sdpa(
     (:func:`_shard_over_mesh`); on a one-device mesh, or with no axes
     named, it is called directly.
 
-    Default block sizes follow the r3 on-chip sweep (tools/bench_kernels.py,
-    BASELINE.md): 1024x512 won fwd+bwd at every swept shape (t=2048/8192
-    d=64, t=4096 d=128) over 512x512 and the smaller tilings; blocks are
-    clamped to the padded sequence length below, so small inputs are
-    unaffected.
+    Default block sizes 1024x512 come from a toy-width sweep (t=2048/8192
+    d=64, t=4096 d=128); no cell has re-swept them, and
+    ``kernel.flash_train_roofline`` reads 26 to 34 % (ledger, PR 24;
+    ROADMAP S9). Blocks are clamped to the padded sequence length below,
+    so small inputs are unaffected.
 
     ``fused_bwd`` selects the one-pass backward (dq+dk+dv from a single
     logit recompute, ~20% fewer backward matmul FLOPs at the cost of a

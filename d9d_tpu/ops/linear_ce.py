@@ -32,9 +32,8 @@ def _chunk_loss(
     ``matmul_dtype="bf16"`` runs the [C,D]x[D,V] einsum — the largest
     matmul in an LM step — with bf16 inputs and fp32 accumulation
     (``preferred_element_type``), the full-throughput MXU path; "fp32"
-    keeps fp32 inputs (half-rate MXU) for exact math. Measured on chip by
-    tools/bench_kernels.py (VERDICT r2 Weak #6); the softmax/LSE math is
-    fp32 either way.
+    keeps fp32 inputs (half-rate MXU) for exact math; the softmax/LSE
+    math is fp32 either way.
     """
     if matmul_dtype == "bf16":
         logits = jnp.einsum(
@@ -87,13 +86,14 @@ def linear_cross_entropy(
     path, anything else stays exact fp32 — so fp32 callers never lose
     precision silently.
 
-    ``chunk_size`` follows the r3 on-chip sweeps (tools/bench_kernels.py,
-    BASELINE.md): at n=16384 d=1024 v=32768 chunk 512 beat 2048/8192 by
-    ~20% fwd while holding the smallest live logit slab, but at n=2048 a
-    SINGLE chunk beat 512 (25.3k vs 24.5k tok/s end-to-end, the µBS=1 MoE
-    row). ``"auto"`` (default) encodes that sweep: one chunk up to n=2048
-    AND a logit slab no bigger than the swept 2048×32768, 512 beyond —
-    pass an int to pin it.
+    ``chunk_size`` trades the live logit slab against per-chunk
+    overhead: 512 holds the smallest slab on long inputs, a single chunk
+    avoids the loop on short ones. ``"auto"`` (default): one chunk up to
+    n=2048 AND a logit slab no bigger than 2048×32768, 512 beyond — pass
+    an int to pin it. The thresholds come from toy-width sweeps and no
+    cell has re-measured them; the head and loss are 53.2 % of device time
+    on ``qwen3-30b-a3b-l1.train-16k`` (builder's chip run, PR 25; PERF.md
+    §5; ROADMAP S7, B3).
     """
     if matmul_dtype is None:
         matmul_dtype = "bf16" if hidden.dtype == jnp.bfloat16 else "fp32"

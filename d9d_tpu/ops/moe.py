@@ -60,7 +60,7 @@ def stable_expert_order(
 
     The one-hot costs O(M·E) HBM traffic (recomputed again under remat):
     a win at swept shapes (M≤128k, E≤64: ≤33 MB) but inverting for very
-    large M·E (ADVICE r3: E=256, M=131k → 134 MB ×2 per MoE layer per
+    large M·E (E=256, M=131k → 134 MB ×2 per MoE layer per
     microbatch pressures HBM), so past a threshold this falls back to the
     stable argsort instead.
     """
@@ -132,8 +132,7 @@ def fused_combine_enabled() -> bool:
     down-projection's combine (ragged gather → grouped matmul → K-sum)
     runs INSIDE the fused kernel, accumulating token-major [N, D]
     outputs in VMEM — the expert-sorted y rows and the pair-gathered
-    copy never exist in HBM (tools/roofline.py's 79 ms/step
-    permute+combine residual is half combine-side). Read at call time
+    copy never exist in HBM. Read at call time
     like the file's other env knobs; ops/moe_pallas.py consults it and
     its VMEM-fit gate can still veto per shape."""
     return os.environ.get("D9D_TPU_MOE_COMBINE", "fused") != "unfused"
@@ -175,8 +174,8 @@ def gate_up_grouped_matmul(
     expert-sorted rows stream from HBM once; off: two grouped matmuls,
     no weight-concat materialization — see nn/moe.py grouped_swiglu_apply
     for the trade-off). Shared by the XLA MoE chain AND the Pallas
-    backend's fallback/backward reference (ADVICE r4: the env switch must
-    cover every path or the perf A/B is inconsistent). Weights must
+    backend's fallback/backward reference (the env switch must cover
+    every path or the perf A/B is inconsistent). Weights must
     already be in the compute dtype.
     """
     if os.environ.get("D9D_TPU_MOE_FUSED_GATE_UP", "1") == "1":

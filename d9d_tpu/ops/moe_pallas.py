@@ -6,13 +6,10 @@ Replaces the local MoE compute chain
 nv-grouped-gemm + Triton permute/silu kernels, d9d/kernel/gmm/function.py,
 d9d/kernel/moe/) with ONE Pallas kernel per layer call.
 
-Why (tools/roofline.py attribution of the 0.136-MFU north star): the XLA
-chain round-trips ``[M, 2*inter]`` gate+up activations and ``[M, inter]``
-hidden through HBM between the grouped matmuls, and the fused gate+up
-single-ragged_dot trick additionally materializes a runtime
-``[E, in, 2*inter]`` weight concat every call (ADVICE r3). At the bench
-geometry that is ~150 MB of avoidable HBM traffic per layer pass. This
-kernel keeps those intermediates in VMEM: each grid step loads one
+Why: the XLA chain round-trips ``[M, 2*inter]`` gate+up activations and
+``[M, inter]`` hidden through HBM between the grouped matmuls, and the
+fused gate+up single-ragged_dot trick additionally materializes a runtime
+``[E, in, 2*inter]`` weight concat every call. This kernel keeps those intermediates in VMEM: each grid step loads one
 ``[block_m, h]`` activation tile plus its expert's three weight blocks,
 runs gate/up/down matmuls + silu + prob-scale on-chip, and writes only
 the ``[block_m, h]`` output tile.
@@ -39,8 +36,7 @@ VMEM budget. ``D9D_TPU_MOE_FFN=pallas_gather`` additionally fuses the
 permute gather into the kernel: the whole token matrix ``x [N, h]``
 (and flat probs) sits resident in VMEM and each M-tile gathers its rows
 in-kernel via the scalar-prefetched ``pair_src`` map, so the aligned
-activation buffer never exists in HBM — tools/roofline.py's top
-residual HBM term after the µBS/bf16 levers. The gather variant
+activation buffer never exists in HBM. The gather variant
 auto-falls back to plain ``pallas`` when the residency or SMEM index
 maps don't fit (:func:`_gather_fits`).
 
@@ -185,9 +181,8 @@ def _ffn_gather_kernel(
     resident in VMEM (eligibility gates on the fit); each grid step
     gathers its tile's rows by the scalar-prefetched ``pair_src`` map —
     so the aligned activation buffer of the two-step path never exists
-    in HBM (that buffer cost a full [m_pad, h] write + read per layer
-    pass, the top residual HBM term in tools/roofline.py's post-µBS4
-    attribution). Pad rows (pair_src < 0) load row 0 and are zeroed.
+    in HBM (that buffer costs a full [m_pad, h] write + read per layer
+    pass). Pad rows (pair_src < 0) load row 0 and are zeroed.
     """
     _gather_rows(
         ps_ref, x_ref, probs_ref, a_scr, p_scr, block_m=block_m, top_k=top_k
@@ -253,7 +248,7 @@ def _ffn_gather_combine_kernel(
 def _vmem_bytes_estimate(
     h: int, inter: int, block_m: int, itemsize: int
 ) -> int:
-    """Per-grid-step VMEM bytes the fused kernel needs (ADVICE r4).
+    """Per-grid-step VMEM bytes the fused kernel needs.
 
     Pallas double-buffers every streamed input block: three expert weight
     blocks (``2*h*inter`` gate+up plus ``inter*h`` down) dominate; the
@@ -351,7 +346,7 @@ def _tpu_shapes_ok(
     h: int, inter: int, block_m: int, itemsize: int = 2
 ) -> bool:
     """Lane alignment AND VMEM fit — large h/inter geometries would fail
-    at Mosaic compile instead of falling back (ADVICE r4), so estimate
+    at Mosaic compile instead of falling back, so estimate
     the footprint and route oversized shapes to the XLA chain
     (budget: :func:`_vmem_budget`)."""
     if not (h % LANES == 0 and inter % LANES == 0 and block_m % 8 == 0):
@@ -511,7 +506,7 @@ def _reference_apply(x, probs, sort, gate_w, up_w, down_w, dtype):
     single source of truth for the custom_vjp backward AND the fallback.
     Uses the shared env-switched gate+up helper so the
     ``D9D_TPU_MOE_FUSED_GATE_UP`` A/B also covers the fallback and the
-    custom_vjp backward under this backend (ADVICE r4)."""
+    custom_vjp backward under this backend."""
     from d9d_tpu.ops.moe import (
         gate_up_grouped_matmul, permute_tokens, unpermute_combine,
     )

@@ -22,16 +22,10 @@ to economise on.
   fuses into the surrounding optimizer arithmetic.
 - :func:`stochastic_round_to_bf16` — the same with field 0 of a block
   drawn from ``key``.
-- :func:`stochastic_round_to_bf16_pallas` — Pallas TPU kernel using the
-  on-chip PRNG (``pltpu.prng_random_bits``) instead of Threefry.
 """
-
-import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 from jax.extend.random import threefry2x32_p
 
 from d9d_tpu.core.types import Array
@@ -92,49 +86,3 @@ def stochastic_round_to_bf16(x: Array, key: jax.Array) -> Array:
     """:func:`stochastic_round_with_field` with field 0 of the block that
     ``key`` gives each element of ``x``."""
     return stochastic_round_with_field(x, rounding_fields(key, x.shape)[0])
-
-
-_LANES = 128
-_BLOCK_ROWS = 256
-
-
-def _sr_kernel(seed_ref, x_ref, out_ref):
-    # distinct stream per grid block: hash the block id into the seed
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0))
-    xf = x_ref[...]
-    bits = pltpu.bitcast(xf, jnp.uint32)
-    rand = pltpu.bitcast(pltpu.prng_random_bits(xf.shape), jnp.uint32)
-    out = pltpu.bitcast(_sr_bits(bits, rand), jnp.float32)
-    out_ref[...] = jnp.where(jnp.isfinite(xf), out, xf).astype(jnp.bfloat16)
-
-
-# d9d-lint: disable=D9D001 — standalone-use decorator; the optimizer traces this inside its tracked update program
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def stochastic_round_to_bf16_pallas(
-    x: Array, seed: Array, *, interpret: bool = False
-) -> Array:
-    """Pallas TPU stochastic rounding driven by the on-chip PRNG.
-
-    ``seed`` is a scalar int32; reuse across calls yields identical noise,
-    so callers should fold the step counter in. The input is processed as
-    (rows, 128) VMEM blocks over a 1-D grid.
-    """
-    n = x.size
-    cols = _LANES
-    rows = -(-n // cols)
-    pad_rows = -(-rows // _BLOCK_ROWS) * _BLOCK_ROWS
-    flat = jnp.pad(x.astype(jnp.float32).reshape(-1), (0, pad_rows * cols - n))
-    tiled = flat.reshape(pad_rows, cols)
-
-    out = pl.pallas_call(
-        _sr_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(pad_rows // _BLOCK_ROWS,),
-            in_specs=[pl.BlockSpec((_BLOCK_ROWS, cols), lambda i, seed: (i, 0))],
-            out_specs=pl.BlockSpec((_BLOCK_ROWS, cols), lambda i, seed: (i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((pad_rows, cols), jnp.bfloat16),
-        interpret=interpret,
-    )(seed.reshape(1).astype(jnp.int32), tiled)
-    return out.reshape(-1)[:n].reshape(x.shape)

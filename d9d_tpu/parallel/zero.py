@@ -3,9 +3,8 @@ mesh axis (PAPERS.md, arxiv 2004.13336 — ZeRO stage 1/2).
 
 The data-parallel replicate axis keeps a full copy of the fp32 masters
 and Adam moments on every chip, and the optimizer step streams all of it
-through HBM: BASELINE.md's roofline attributes a large slice of the MoE
-north-star's HBM-bound step to exactly this traffic (the fp32
-master/optimizer stream plus the 66 ms/step fp32 grad accumulator).
+through HBM (the fp32 master/optimizer stream plus the fp32 grad
+accumulator). ZeRO has never run on the chip (ROADMAP R-L).
 ZeRO's observation is that the *update* is elementwise, so each replica
 only needs 1/N of the state:
 

@@ -67,18 +67,18 @@ class Interleaved1F1BScheduleConfig(_RuntimeChoice):
     stages_per_rank: int = 1
 
 
-# Zero-bubble schedules default to cache_full per the r3 on-chip
-# microbench (tools/bench_pp.py, BASELINE.md): with 2 virtual stages on one
-# chip, zb1p/remat ran 30% slower than 1F1B (each dI and dW phase recomputes
-# the stage forward) while zb1p/cache_full tied it. remat remains available
-# for memory-bound real-PP runs where filling bubbles with W-compute pays.
+# Zero-bubble schedules default to cache_full: under remat each dI and dW
+# phase recomputes the stage forward (two extra forwards a microbatch
+# against 1F1B's one), cache_full runs the fused backward once. remat
+# remains available for memory-bound real-PP runs where filling bubbles
+# with W-compute pays.
 #
-# r4 adds "cache_acts" — the true zero-bubble split (dW at the W slot from
-# saved residuals, 1F1B FLOPs; see runtime/stage.py). The dependency-level
-# simulation (tools/pp_makespan.py, BASELINE.md r4 table) shows it strictly
-# dominating both other policies at every multi-rank config (−12.6% vs 1F1B
-# at pp=8/µB=8); it stays opt-in until the residual write+read tax between
-# the I and W jits is measured on chip (ROADMAP S4; not measured yet).
+# "cache_acts" is the true zero-bubble split (dW at the W slot from saved
+# residuals, 1F1B FLOPs; see runtime/stage.py). The dependency-level
+# simulation (tools/pp_makespan.py) has it dominating both other policies
+# at every multi-rank config; it stays opt-in until the residual
+# write+read tax between the I and W jits is measured on chip (ROADMAP
+# R-P, `zb-residual-policies`; not measured yet).
 
 
 class ZeroBubble1PScheduleConfig(_RuntimeChoice):

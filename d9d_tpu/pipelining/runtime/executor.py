@@ -11,8 +11,7 @@ dispatch provides the overlap the reference gets from per-process
 execution — the host races ahead enqueuing work for all stage device
 groups while earlier computations are still running.
 
-Because every action costs host dispatch time (BASELINE.md measured ≈9%
-at pp=2/µB=8 with zero real communication), the interpretation loop is
+Because every action costs host dispatch time, the interpretation loop is
 pre-compiled at construction: the program is flattened once into a list of
 (bound handler, action, trace label) triples — no isinstance chains or
 label formatting on the step path — microbatch kwargs are staged onto

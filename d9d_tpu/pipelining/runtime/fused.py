@@ -2,8 +2,9 @@
 
 The legacy ``PipelineScheduleExecutor`` interprets the validated global
 linearization one tiny ``tracked_jit`` per action — O(microbatches ×
-actions) host dispatches per step, the ≈9% single-controller tax
-BASELINE.md measured at a zero-comm pp=2/µB=8 config. This module is
+actions) host dispatches per step, the single-controller tax (39
+dispatches a step against 1 at the tiny 1F1B config: ``pp_micro.*`` in
+``BENCH_BASELINE.json``; no time measured on the chip, ROADMAP R-P). This module is
 the compile-the-schedule answer (the MPMD pipeline-compilation lineage,
 arxiv 2412.14374): a schedule compiler partitions the SAME linearization
 into maximal *fusable runs* per rank, traces each run's actions —

@@ -98,7 +98,7 @@ class PipelineStageRuntime:
     # mesh (jax.set_mesh in MeshParameters.build) never conflicts with this
     # stage's device group, and shard_map-based modules resolve it
     mesh: Any | None = None
-    # How zero-bubble schedules pay for the dI/dW split (VERDICT r2 Weak #4):
+    # How zero-bubble schedules pay for the dI/dW split:
     # - "remat": dI and dW are independent vjps, each recomputing the stage
     #   forward (2 extra forwards per microbatch vs 1F1B's one). Memory-
     #   minimal: only the input carry persists between I and W actions.
@@ -118,7 +118,8 @@ class PipelineStageRuntime:
     #   keeps dW alone. Costs residual memory between the I and W actions
     #   (what the ZB schedules' memory model budgets for).
     # The better default is workload-dependent — tools/bench_pp.py measures
-    # all three; see BASELINE.md.
+    # all three; none measured on the chip yet (ROADMAP R-P,
+    # `zb-residual-policies`).
     residual_policy: str = "remat"
 
     def __post_init__(self) -> None:

@@ -60,7 +60,7 @@ def _shardwise_put(x: jax.Array, sharding) -> jax.Array:
 # probe REPLICATING the failure mode (an array on a source device moved
 # onto the destination sharding's device set) decides whether that was a
 # capability limit (→ shard-wise fallback forever) or a real error in the
-# payload itself (→ re-raised, never masked) (ADVICE r3).
+# payload itself (→ re-raised, never masked).
 _cross_set_direct: bool | None = None
 
 

@@ -280,7 +280,7 @@ class PipelinedOptimizer:
                 with self._scoped(s):
                     sq_local.append(self._stage_sq_norm(s)(stage_grads[s]))
             # batched hop: all per-stage scalars move to the anchor stage
-            # from one call site (VERDICT r3 item 3)
+            # from one call site
             sq_norms = put_compat(sq_local, anchor)
         with annotate("pp_opt.combine"), self._scoped(last):
             norm, factor = self._combine(sq_norms, weight_sum)

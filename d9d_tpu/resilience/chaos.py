@@ -37,8 +37,7 @@ timing races — so a chaos test asserts exact recovery behavior, not
   (steps, arrivals-per-step) compiled into an exact arrival schedule.
   Arrival *times* carry zero randomness (fractional rates are spread
   by an error accumulator), so an overload ramp reproduces the same
-  queue depths, rejections and autopilot decisions on every run; the
-  same builder shapes ``tools/bench_serve.py`` ramp workloads.
+  queue depths, rejections and autopilot decisions on every run.
 
 Queue overflow needs no injector: submit past ``max_queue`` and assert
 :class:`~d9d_tpu.loop.serve.QueueFullError`.
@@ -296,9 +295,8 @@ def ramp_arrivals(
     (fractional rates are spread deterministically by an error
     accumulator — rate 0.5 lands one arrival every second step, never a
     random draw). Returns ``[(arrival_step, prompt, max_new_tokens)]``
-    in the exact tuple shape ``tools/bench_serve.py`` workloads use, so
-    one builder drives both the autopilot chaos tests and the bench
-    harness ramp legs. Prompt contents and budgets come from the
+    in the exact tuple shape ``tools/bench_serve.py`` workloads use.
+    Prompt contents and budgets come from the
     seeded RNG (``prompt_hi``/``gen_hi`` exclusive, matching
     ``make_workload``); arrival *times* carry no randomness at all.
     """

@@ -214,7 +214,18 @@ def _chunked_place(
     round-trip each through the host, and write it into a
     target-sharded accumulator via a donated dynamic_update_slice.
     Peak transient footprint per device: the target shard (required)
-    plus one replicated ≤ budget chunk. Returns (placed, n_chunks)."""
+    plus one replicated ≤ budget chunk. Returns (placed, n_chunks).
+
+    An eager op refuses an operand whose devices are not the ambient
+    mesh's, and on a resume under another layout the source lives on
+    one device set, the target on another and the ambient mesh is the
+    caller's: each side runs under its own devices' mesh."""
+    src = leaf.sharding
+    if isinstance(src, NamedSharding):
+        src_mesh = src.mesh
+    else:
+        devs = sorted(leaf.devices(), key=lambda d: d.id)
+        src_mesh = Mesh(np.array(devs), ("src",))
     rows = leaf.shape[0]
     row_bytes = max(1, _leaf_nbytes(leaf) // max(rows, 1))
     chunk_rows = max(1, int(budget // row_bytes))
@@ -229,9 +240,11 @@ def _chunked_place(
     n = 0
     for a in range(0, rows, chunk_rows):
         b = min(rows, a + chunk_rows)
-        host_chunk = np.asarray(leaf[a:b])  # gather: ≤ budget bytes
+        with jax.set_mesh(src_mesh):
+            host_chunk = np.asarray(leaf[a:b])  # gather: ≤ budget bytes
         dev_chunk = jax.device_put(host_chunk, repl)
-        out = write_j(out, dev_chunk, jnp.int32(a))
+        with jax.set_mesh(target.mesh):
+            out = write_j(out, dev_chunk, jnp.int32(a))
         n += 1
     return out, n
 

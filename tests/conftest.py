@@ -60,7 +60,7 @@ def _fixed_seed():
 
 
 def load_repo_module(name, relpath):
-    """Load a repo-root script (bench.py, tools/*.py) by path — shared by
+    """Load a repo script (chip_smoke.py, tools/*.py) by path — shared by
     the harness tests so the spec/exec boilerplate lives once."""
     import importlib.util
     import pathlib

@@ -19,7 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from d9d_tpu.ops.stochastic import (
     rounding_fields,
     stochastic_round_to_bf16,
-    stochastic_round_to_bf16_pallas,
     stochastic_round_with_field,
 )
 from d9d_tpu.optim import StochasticAdamW
@@ -58,17 +57,6 @@ class TestStochasticRounding:
         out = stochastic_round_to_bf16(x, jax.random.PRNGKey(3))
         o = np.asarray(out.astype(jnp.float32))
         assert np.isposinf(o[0]) and np.isneginf(o[1]) and np.isnan(o[2])
-
-    def test_pallas_kernel_matches_semantics(self):
-        try:
-            x = jnp.full((8, 128), 1.0 + 1 / 512.0, jnp.float32)
-            out = stochastic_round_to_bf16_pallas(
-                x, jnp.int32(42), interpret=True
-            )
-        except Exception as e:  # pragma: no cover - interpret-mode gaps
-            pytest.skip(f"pallas interpret mode unavailable for prng: {e}")
-        vals = set(np.unique(np.asarray(out.astype(jnp.float32))))
-        assert vals <= {1.0, 1.0 + 1 / 128.0}
 
 
 def _tree_close(a, b, tol):

@@ -149,11 +149,16 @@ def test_topology_mismatch_detection():
 # memory-bounded redistribution
 
 
-def test_redistribute_tree_chunks_under_budget():
-    ctx_src = MeshParameters(dp_replicate=2).build(jax.devices()[:2])
-    src_mesh = ctx_src.mesh
-    ctx_dst = MeshParameters(dp_replicate=4).build(jax.devices()[:4])
-    dst_mesh = ctx_dst.mesh
+@pytest.mark.parametrize("ambient", ["target", "source", "other"])
+def test_redistribute_tree_chunks_under_budget(ambient):
+    """The chunked path slices under the source's devices and writes
+    under the target's, whichever mesh the caller left ambient."""
+    src_mesh = MeshParameters(dp_replicate=2).build(jax.devices()[:2]).mesh
+    dst_mesh = MeshParameters(dp_replicate=4).build(jax.devices()[:4]).mesh
+    if ambient == "source":
+        jax.set_mesh(src_mesh)
+    elif ambient == "other":
+        MeshParameters(dp_replicate=2).build(jax.devices()[4:6])
     data = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
     leaf = jax.device_put(jnp.asarray(data), NamedSharding(src_mesh, P()))
     target = NamedSharding(dst_mesh, P())

@@ -487,7 +487,7 @@ class TestRealArtifacts:
 
         from d9d_tpu.telemetry.audit_capture import _jaxpr_census
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def body(c, _):
                 return c * np.float64(1.5), None
 

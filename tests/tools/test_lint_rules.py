@@ -382,7 +382,7 @@ def _one_finding_repo(tmp_path):
     })
 
 
-def test_baseline_diff_new_vs_baselined_vs_stale(tmp_path):
+def test_baseline_diff_new_baselined_stale(tmp_path):
     root = _one_finding_repo(tmp_path)
     findings = run(root, [RULES_BY_ID["D9D001"]])
     assert len(findings) == 1

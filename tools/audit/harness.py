@@ -151,7 +151,7 @@ def leg_serve() -> None:
     from d9d_tpu.loop.serve import ContinuousBatcher
     from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM
 
-    model, params, cfg = build_model(tiny=True)
+    model, params, cfg = build_model()
     prompt = [1, 2, 3]
     fused = ContinuousBatcher(
         model, params, batch_size=2, chunk_size=4, overlap=True
@@ -189,7 +189,7 @@ def leg_serve_quant() -> None:
     from d9d_tpu.loop.quantize import quantize_for_serving
     from d9d_tpu.loop.serve import ContinuousBatcher
 
-    model, params, cfg = build_model(tiny=True)
+    model, params, cfg = build_model()
     qparams = quantize_for_serving(params)
     fused = ContinuousBatcher(
         model, qparams, batch_size=2, chunk_size=4,
@@ -224,7 +224,7 @@ def leg_serve_disagg() -> None:
     from d9d_tpu.resilience import ServingFleet
     from d9d_tpu.telemetry import get_telemetry, introspect
 
-    model, params, cfg = build_model(tiny=True)
+    model, params, cfg = build_model()
 
     def make() -> ContinuousBatcher:
         return ContinuousBatcher(
@@ -348,8 +348,8 @@ def leg_pp_fused() -> None:
     """The fused MPMD pipeline runtime (pipelining/runtime/fused.py):
     every compiled run (``pp_fused/r{R}/run{K}``) certified for the
     zero-collective contract and donation coverage. Two partitions:
-    the tiny single-program 1F1B config (the bench.py / bench_compare
-    acceptance row) and the zero-bubble cache_acts pp=2 schedule,
+    the tiny single-program 1F1B config (the bench_compare acceptance
+    row) and the zero-bubble cache_acts pp=2 schedule,
     whose dI/dW split plus cross-rank run boundaries produce the
     richest run structure the partitioner emits."""
     import flax.linen as nn

@@ -1,27 +1,23 @@
-"""Perf-regression gate: diff a bench/telemetry summary against the
+"""The CPU count gate: diff a summary of structural counts against the
 committed baseline snapshot, exit nonzero on regression.
 
-Every PR runs tier-1; none of them, until now, ran anything that would
-notice a 10x perf collapse. This tool closes that gap with a cheap
-tripwire that needs no chip:
+A tripwire that needs no chip and states no speed (a speed is a row of
+``benchmarks/run.py`` on the chip; ``BENCHMARK.json``, ``PERF.md``):
 
 - ``--run-micro`` drives a tiny ``ContinuousBatcher`` workload on CPU
-  (seconds, deterministic seed) and collects the metrics that are
-  *structurally* meaningful on any backend: host dispatches per 1k
-  tokens, readbacks, emitted tokens, compile counts and recompiles
-  (from the ``telemetry/introspect.py`` inventory), peak executable HBM
-  claim — plus wall-clock tokens/s as a loose catastrophic-collapse
-  floor. Mid-bench the workload PUBLISHES the model's own weights back
-  into the live batcher (``install_weights`` — the elastic train→serve
-  handoff, docs/design/elasticity.md): a publish must add zero
+  (seconds, deterministic seed) and collects the counts that mean the
+  same on any backend: host dispatches per 1k tokens, readbacks,
+  emitted tokens, pages, compile counts and recompiles (from the
+  ``telemetry/introspect.py`` inventory). No wall clock is read: a CPU
+  timing says nothing about the device and is never written under a
+  device metric's name. Mid-bench the workload PUBLISHES the model's
+  own weights back into the live batcher (``install_weights`` — the
+  elastic train→serve handoff, docs/design/elasticity.md): a publish
+  must add zero
   steady-state compiles and zero dispatches, so the same exact-count
   gates that catch a dispatch regression also catch a publish-induced
   recompile.
 - ``--current FILE`` compares an existing summary instead of running.
-- ``--from-bench-jsonl FILE`` extracts the comparable metrics from a
-  ``bench_results/bench.jsonl`` row (the on-chip ``bench.py`` output)
-  so a chip run can emit a compare summary; without a ``tpu`` section
-  in the baseline it reports without gating.
 
 Baseline format (``BENCH_BASELINE.json`` at the repo root, committed):
 
@@ -30,15 +26,13 @@ Baseline format (``BENCH_BASELINE.json`` at the repo root, committed):
 
 ``direction: higher`` fails when ``current < value * (1 - rel_tol)``;
 ``direction: lower`` fails when ``current > value * (1 + rel_tol)``.
-Structural counts carry ``rel_tol 0`` (they are deterministic — any
-increase is a real regression); wall-clock metrics carry wide
-tolerances (CI boxes are noisy; the gate is for collapses, not 3%
-jitter). A metric present in the baseline but missing from the current
-summary fails (a deleted metric is how a regression hides).
+Every metric is a deterministic count at ``rel_tol 0``: any increase is
+a real regression. A metric present in the baseline but missing from
+the current summary fails (a deleted metric is how a regression hides).
 
 Exit codes: 0 ok, 1 regression, 2 usage/baseline error.
 
-Refresh the baseline after an intentional perf change with:
+Refresh the baseline after an intended change of a count with:
     python tools/bench_compare.py --run-micro --write-baseline
 """
 
@@ -67,9 +61,8 @@ def _drive_micro(
     *,
     front=None,
     publish_fn=None,
-) -> float:
-    """Drive the deterministic micro workload (after warmup/reset);
-    returns the timed-window wall seconds.
+) -> None:
+    """Drive the deterministic micro workload (after warmup/reset).
 
     ONE loop serves every leg, so the byte-identical structural gates
     always compare the same arrival/clock/drain semantics: ``front``
@@ -84,14 +77,11 @@ def _drive_micro(
     prefix leg uses it because a publish correctly INVALIDATES the
     prefix cache (cached KV is weights-dependent), and that leg gates
     steady-state hit economics, not publish cost."""
-    import time
-
     if front is None:
         front = batcher
     pending = list(workload)
     clock = 0
     publishes = 0 if publish else 1
-    t0 = time.perf_counter()
     while pending:
         while pending and pending[0][0] <= clock:
             _, prompt, gen = pending.pop(0)
@@ -117,7 +107,6 @@ def _drive_micro(
         elif pending:
             clock = pending[0][0]
     front.drain()
-    return time.perf_counter() - t0
 
 
 def _scrape_and_check(server) -> tuple[int, str]:
@@ -146,8 +135,7 @@ def run_micro() -> dict:
 
     Deterministic given the seed: the arrival schedule is released
     against the batcher's own device-step clock, sampling is greedy,
-    and compile counts come from the introspection inventory — only
-    ``tok_per_s`` carries wall-clock noise.
+    and compile counts come from the introspection inventory.
 
     Five legs: **plain** (the historical gate), **exporter-enabled** —
     a replica-labeled batcher with the live /metrics endpoint up, an
@@ -166,10 +154,8 @@ def run_micro() -> dict:
     loop acts only at round boundaries). The exporter leg's
     structural counts must be IDENTICAL to the plain leg's (the
     monitoring plane adds zero dispatches, zero readbacks, zero
-    steady-state compiles — the overhead contract's exact half) and
-    its wall-clock overhead is reported as ``exporter_overhead_frac``
-    against the 2% budget (gated loosely on the noisy CI rig — the
-    strict number is a chip run's job, not measured yet).
+    steady-state compiles). What the plane costs in wall clock is a
+    chip run's to say (not measured yet).
     """
     import os
 
@@ -188,7 +174,7 @@ def run_micro() -> dict:
         introspect,
     )
 
-    model, params, cfg = build_model(tiny=True)
+    model, params, cfg = build_model()
     workload = make_workload(
         vocab=cfg.vocab_size, requests=MICRO["requests"], seed=0,
         prompt_lo=2, prompt_hi=6, gen_lo=MICRO["gen_lo"],
@@ -210,7 +196,7 @@ def run_micro() -> dict:
     batcher.drain()
     batcher.reset_measurement()
     mark_window = len(introspect.inventory())
-    dt = _drive_micro(batcher, workload, params)
+    _drive_micro(batcher, workload, params)
     st = batcher.stats
     # snapshot the plain leg's inventory slices BEFORE the exporter leg
     # warms its own batcher (whose warmup compiles must not read as the
@@ -241,12 +227,9 @@ def run_micro() -> dict:
         exp.drain()
         exp.reset_measurement()
         mark_exp = len(introspect.inventory())
-        # the timed window prices the ALWAYS-ON cost (labels, SLO
-        # observers, endpoint thread); the scrape itself lands right
-        # after it — a production scrape amortizes over seconds of
-        # serving, so timing one inside a ~30ms window would gate
-        # scrape latency, not serving overhead
-        dt_exp = _drive_micro(exp, workload, params)
+        # the counted window carries the ALWAYS-ON plane (labels, SLO
+        # observers, endpoint thread); the scrape lands right after it
+        _drive_micro(exp, workload, params)
         mid_scrape()
     finally:
         server.close()
@@ -385,9 +368,6 @@ def run_micro() -> dict:
         - promotes_before
     )
     ap_exact = int(ap_b.outputs == batcher.outputs)
-    peaks = [
-        r.hbm_peak_bytes for r in bench_records if r.hbm_peak_bytes
-    ]
     return {
         "schema": 1,
         "workload": dict(MICRO),
@@ -405,23 +385,14 @@ def run_micro() -> dict:
             "serve_micro.recompiles": sum(
                 1 for r in bench_records if r.recompile
             ),
-            # per-executable HBM claim of the biggest serving executable
-            # (None on backends without memory analysis → omitted)
-            **(
-                {"serve_micro.peak_hbm_bytes": max(peaks)}
-                if peaks else {}
-            ),
             # the mid-bench publish actually applied (weights generation
             # advanced); its dispatch/compile cost is gated by the
             # exact-count metrics above
             "serve_micro.weight_publishes": batcher.weights_version,
-            # wall clock — wide-tolerance collapse floor only
-            "serve_micro.tok_per_s": round(st.emitted_tokens / dt, 2),
             # exporter leg: same workload with the monitoring plane UP
             # (live /metrics endpoint + replica labels + SLO monitor +
-            # one mid-run scrape). Exact halves of the overhead
-            # contract: identical structural counts — zero added
-            # dispatches/readbacks/compiles with the exporter enabled
+            # one mid-run scrape): identical structural counts — zero
+            # added dispatches/readbacks/compiles with the exporter enabled
             "serve_micro.exporter_emitted_tokens": exp.stats.emitted_tokens,
             "serve_micro.exporter_host_dispatches": (
                 exp.stats.host_dispatches
@@ -434,13 +405,6 @@ def run_micro() -> dict:
             # counters (a broken exporter must fail the gate, not
             # silently stop exporting)
             "serve_micro.exporter_scrape_ok": scrape["ok"],
-            # wall-clock overhead vs the plain leg: the 2% budget. On
-            # the noisy CI rig this is gated as a collapse floor only
-            # (rel_tol in the baseline); the chip leg reports the
-            # strict number
-            "serve_micro.exporter_overhead_frac": round(
-                (dt_exp - dt) / dt, 4
-            ),
             # paged leg: byte-identical structural counts + exact
             # tokens vs the plain (contiguous) leg — paging must add
             # zero host interactions per token
@@ -533,103 +497,6 @@ def run_micro() -> dict:
     }
 
 
-EXPORTER_CONTENTION_CAVEAT = (
-    "note serve_micro.exporter_overhead_frac breached: on the 2-core CI "
-    "rig the exporter's endpoint thread contends with the serving loop "
-    "for the same cores, so this wall-clock leg is flaky-by-construction "
-    "under load — re-running the plain+exporter timing legs once in "
-    "isolation before failing the gate"
-)
-
-
-def rerun_exporter_overhead() -> float:
-    """Isolated re-measure of ``serve_micro.exporter_overhead_frac``:
-    the plain and exporter timing legs only, back to back, with nothing
-    else from the microbench running. ``main`` calls this exactly once
-    when the full-run gate fails on this metric ALONE — by the time it
-    runs, every other leg's batchers/servers/threads are closed, so the
-    contention that makes the in-run number flaky is gone. Structural
-    exporter metrics are NOT re-derived (they are deterministic and not
-    contention-sensitive; a structural failure is real)."""
-    from tools.bench_serve import build_model, make_workload
-
-    from d9d_tpu.loop.serve import ContinuousBatcher
-    from d9d_tpu.telemetry import (
-        MetricsServer,
-        SloMonitor,
-        SloPolicy,
-        get_telemetry,
-    )
-
-    model, params, cfg = build_model(tiny=True)
-    workload = make_workload(
-        vocab=cfg.vocab_size, requests=MICRO["requests"], seed=0,
-        prompt_lo=2, prompt_hi=6, gen_lo=MICRO["gen_lo"],
-        gen_hi=MICRO["gen_hi"],
-        mean_interarrival=MICRO["gen_hi"] / MICRO["batch_size"],
-    )
-    k = MICRO["chunk_k"]
-    batcher = ContinuousBatcher(
-        model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True,
-    )
-    batcher.submit(workload[0][1], max_new_tokens=2 * k + 2)
-    batcher.drain()
-    batcher.reset_measurement()
-    dt = _drive_micro(batcher, workload, params)
-
-    # same always-on monitoring plane as the in-run exporter leg (labels,
-    # SLO observers, live endpoint thread); the mid-run scrape lands
-    # outside the timed window there, so it is not replicated here
-    exp = ContinuousBatcher(
-        model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True, replica_label="r0",
-    )
-    monitor = SloMonitor([
-        SloPolicy(name="bench_ttft_p99", metric="serve/ttft_s",
-                  quantile=0.99, target=60.0, window_s=60.0),
-        SloPolicy(name="bench_miss_rate", kind="rate",
-                  bad="serve/expired", good=("serve/requests_finished",),
-                  target=0.01, window_s=60.0),
-    ]).attach(get_telemetry())
-    server = MetricsServer(port=0).start()
-    try:
-        exp.submit(workload[0][1], max_new_tokens=2 * k + 2)
-        exp.drain()
-        exp.reset_measurement()
-        dt_exp = _drive_micro(exp, workload, params)
-    finally:
-        server.close()
-        monitor.detach()
-        exp.close()
-    return round((dt_exp - dt) / dt, 4)
-
-
-def gate_with_exporter_rescue(current: dict, baseline: dict):
-    """``compare`` plus the one sanctioned retry: when
-    ``serve_micro.exporter_overhead_frac`` is the SOLE failing metric,
-    re-measure that leg once in isolation (``rerun_exporter_overhead``)
-    and compare again. Every other failure — and any failure that rides
-    alongside it — stays fatal on the first pass. Shared by the
-    ``--run-micro`` CLI gate and the in-suite tripwire test so both
-    paths carry identical flake semantics. Returns
-    ``(ok, lines, exporter_rerun)``; ``current`` is updated in place
-    with the re-measured value when the rescue fires."""
-    ok, lines = compare(current, baseline)
-    if ok:
-        return ok, lines, False
-    failing = [ln for ln in lines if ln.startswith("FAIL")]
-    if not failing or not all(
-        "serve_micro.exporter_overhead_frac" in ln for ln in failing
-    ):
-        return ok, lines, False
-    current["metrics"]["serve_micro.exporter_overhead_frac"] = (
-        rerun_exporter_overhead()
-    )
-    ok, lines = compare(current, baseline)
-    return ok, lines, True
-
-
 def run_disagg_micro() -> dict:
     """The disaggregated-serving leg (docs/design/elasticity.md
     "Disaggregated serving"): the SAME shared-prefix workload through a
@@ -645,7 +512,7 @@ def run_disagg_micro() -> dict:
         run_fleet,
     )
 
-    model, params, cfg = build_model(tiny=True)
+    model, params, cfg = build_model()
     shared = make_shared_prefix_workload(
         vocab=cfg.vocab_size, requests=MICRO["requests"], seed=0,
         prefix_len=2 * 16 + 2, tail_lo=2, tail_hi=6,
@@ -972,39 +839,6 @@ def run_pp_micro() -> dict:
     }
 
 
-def extract_bench_jsonl(path: str) -> dict:
-    """Comparable metrics from the newest parseable ``bench.py`` row in
-    a bench_results jsonl capture (rows may be error lines — skip)."""
-    metrics = {}
-    with open(path) as fh:
-        for line in fh:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                row = json.loads(line)
-            except ValueError:
-                continue
-            if row.get("metric") and "value" in row:
-                metrics[f"tpu.{row['metric']}"] = row["value"]
-                detail = row.get("detail", {})
-                for block in ("moe", "hybrid", "serving", "pp"):
-                    sub = detail.get(block)
-                    if isinstance(sub, dict) and "value" in sub:
-                        metrics[f"tpu.{sub.get('metric', block)}"] = (
-                            sub["value"]
-                        )
-                if isinstance(detail.get("serving"), dict):
-                    d = detail["serving"].get("dispatches_per_1k_tokens")
-                    if d is not None:
-                        metrics["tpu.serving_dispatches_per_1k_tokens"] = d
-                if isinstance(detail.get("pp"), dict):
-                    f = detail["pp"].get("pp/fused_programs")
-                    if f is not None:
-                        metrics["tpu.pp/fused_programs"] = f
-    return {"schema": 1, "metrics": metrics}
-
-
 def compare(current: dict, baseline: dict) -> tuple[bool, list[str]]:
     """→ (ok, report lines). Gates every baseline metric against the
     current summary with its direction + relative tolerance."""
@@ -1047,7 +881,7 @@ def compare(current: dict, baseline: dict) -> tuple[bool, list[str]]:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="Perf-regression gate vs the committed baseline"
+        description="CPU count gate vs the committed baseline"
     )
     ap.add_argument(
         "--baseline", default=str(DEFAULT_BASELINE),
@@ -1060,12 +894,6 @@ def main(argv=None) -> int:
     )
     src.add_argument(
         "--current", help="compare an existing summary JSON file"
-    )
-    src.add_argument(
-        "--from-bench-jsonl",
-        help="extract metrics from a bench_results bench.jsonl capture "
-        "(TPU legs); reports without gating when the baseline has no "
-        "matching tpu.* metrics",
     )
     ap.add_argument(
         "--write-baseline", action="store_true",
@@ -1083,11 +911,9 @@ def main(argv=None) -> int:
 
         enable_compile_cache()
         current = run_micro()
-    elif args.current:
+    else:
         with open(args.current) as fh:
             current = json.load(fh)
-    else:
-        current = extract_bench_jsonl(args.from_bench_jsonl)
 
     if args.write_current:
         with open(args.write_current, "w") as fh:
@@ -1098,10 +924,10 @@ def main(argv=None) -> int:
             print("--write-baseline requires --run-micro", file=sys.stderr)
             return 2
         baseline = {
-            "comment": "perf-regression gate baseline "
+            "comment": "CPU count-gate baseline "
                        "(tools/bench_compare.py); refresh with "
-                       "--run-micro --write-baseline after intentional "
-                       "perf changes",
+                       "--run-micro --write-baseline after an intended "
+                       "change of a count",
             "metrics": default_thresholds(current["metrics"]),
         }
         with open(args.baseline, "w") as fh:
@@ -1117,20 +943,7 @@ def main(argv=None) -> int:
         print(f"cannot read baseline {args.baseline}: {e}", file=sys.stderr)
         return 2
 
-    exporter_rerun = False
-    if args.run_micro:
-        # the one known-flaky wall-clock leg: when it is the ONLY
-        # failure, re-measure it once in isolation instead of failing
-        # (docs/design/observability.md "Perf-regression gate").
-        # --current snapshots never re-run — their rc must stay a pure
-        # function of the file's contents.
-        ok, lines, exporter_rerun = gate_with_exporter_rescue(
-            current, baseline
-        )
-        if exporter_rerun:
-            print(EXPORTER_CONTENTION_CAVEAT)
-    else:
-        ok, lines = compare(current, baseline)
+    ok, lines = compare(current, baseline)
     for line in lines:
         print(line)
     print(json.dumps({
@@ -1138,36 +951,18 @@ def main(argv=None) -> int:
             "ok": ok,
             "baseline": str(args.baseline),
             "gated_metrics": len(baseline.get("metrics", {})),
-            "exporter_rerun": exporter_rerun,
         }
     }))
     return 0 if ok else 1
 
 
 def default_thresholds(metrics: dict) -> dict:
-    """Per-metric gate specs for a fresh baseline: structural counts are
-    exact (any extra dispatch/compile/byte is a real regression),
-    wall-clock rates get a wide collapse-only floor."""
+    """Per-metric gate specs for a fresh baseline: every metric is an
+    exact count (any extra dispatch/compile/byte is a real regression);
+    only the direction and two contract values differ."""
     specs = {}
     for name, value in metrics.items():
-        if name.endswith(".tok_per_s"):
-            # CI wall clock is noisy: gate only a catastrophic collapse
-            specs[name] = {
-                "value": value, "direction": "higher", "rel_tol": 0.9,
-            }
-        elif name.endswith(".exporter_overhead_frac"):
-            # the 2% monitoring-plane budget is the CONTRACT value, not
-            # the measured one (CI noise can even make it negative); the
-            # wide rel_tol makes the CI gate a 20% collapse floor — the
-            # strict 2% check is the chip leg's job. A breach under
-            # --run-micro triggers ONE automatic isolated re-measure
-            # (rerun_exporter_overhead) before the gate fails: the
-            # 2-core-contention flake is the tool's problem, not the
-            # operator's
-            specs[name] = {
-                "value": 0.02, "direction": "lower", "rel_tol": 9.0,
-            }
-        elif name.endswith(".quant_kv_hbm_frac_vs_paged"):
+        if name.endswith(".quant_kv_hbm_frac_vs_paged"):
             # the CONTRACT value (int8+scales must at least halve the
             # per-request KV bytes), not the measured one — robust to
             # head-dim drift in the tiny model config
@@ -1211,11 +1006,6 @@ def default_thresholds(metrics: dict) -> dict:
             # publish would let a publish-induced recompile hide)
             specs[name] = {
                 "value": value, "direction": "higher", "rel_tol": 0.0,
-            }
-        elif name.endswith(".peak_hbm_bytes"):
-            # layout/codegen details may drift a little across jaxlib
-            specs[name] = {
-                "value": value, "direction": "lower", "rel_tol": 0.25,
             }
         else:
             specs[name] = {

@@ -1,6 +1,6 @@
 """Pipeline-schedule microbenchmark: 1F1B vs ZB1P × residual policies.
 
-VERDICT r2 Weak #4/#5: zero-bubble schedules pay forward recomputes for the
+Zero-bubble schedules pay forward recomputes for the
 dI/dW split ("remat" policy) or give up the deferred-W bubble filler
 ("cache_full"); whether either beats plain 1F1B is an empirical question,
 and the single-controller executor's per-action dispatch cost needs a

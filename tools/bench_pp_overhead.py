@@ -1,11 +1,10 @@
 """PP executor dispatch-overhead microbench: mitigations vs naive VM.
 
-VERDICT r5 Weak #3: the single-controller executor's ≈9% per-action
-dispatch tax got real mitigations — the pre-compiled dispatch plan (no
+The single-controller executor's per-action dispatch tax got real
+mitigations — the pre-compiled dispatch plan (no
 isinstance chains or label formatting on the step path), windowed
 first-use kwargs staging, and the fused end-of-step loss-stat jit
-(``pipelining/runtime/executor.py``) — but no before/after number ever
-existed, even on the CPU rig. This harness produces one: it runs the
+(``pipelining/runtime/executor.py``). This harness runs the
 SAME schedule program through (a) the production executor and (b) a
 ``NaiveExecutor`` subclass that deliberately re-creates the
 pre-mitigation interpretation loop — per-action type dispatch + label
@@ -26,8 +25,9 @@ Smoke on CPU mesh:  JAX_PLATFORMS=cpu python tools/bench_pp_overhead.py --tiny
 CPU rig number:     python tools/bench_pp_overhead.py --cpu
 TPU chip:           python tools/bench_pp_overhead.py
 
-Prints one JSON line per executor plus a "summary" line; BASELINE.md
-records the measured numbers.
+Prints one JSON line per executor plus a "summary" line. No pipeline
+runtime has a benchmark cell yet, so nothing here is in the ledger
+(ROADMAP R-P, `pp-tools-without-a-cell`).
 """
 
 import argparse
@@ -188,7 +188,7 @@ def main():
         dtype = jnp.float32
     elif args.cpu:
         # big enough that compute dominates: the overhead shows as a
-        # few-percent delta like the ≈9% executor tax BASELINE.md records
+        # few-percent delta
         cfg = Qwen3DenseConfig(
             vocab_ranges=(("default", 4096),), hidden_size=256,
             num_layers=4, num_heads=8, num_kv_heads=4, head_dim=32,

@@ -30,8 +30,8 @@ schedulable W half (shorter I slots shrink the inter-stage critical path).
 
 Usage: python tools/pp_makespan.py [--pp 4] [--microbatches 8] [--comm 0.1]
 Prints one JSON line per (schedule, policy): makespan, bubble fraction
-(idle device-time share), and total compute — the evidence base for the
-residual-policy defaults recorded in BASELINE.md.
+(idle device-time share), and total compute — the evidence behind the
+residual-policy defaults in ``pipelining/factory.py``.
 """
 
 import argparse

@@ -26,7 +26,7 @@ PP stage busy/bubble and serve admission become one inspectable
 timeline at https://ui.perfetto.dev.
 
 Two attribution tables ride the repo's own instrumentation
-(core/tracing.py — VERDICT r3 item 3, the ``record_function`` analogue):
+(core/tracing.py, the ``record_function`` analogue):
 
 - **host regions**: TraceAnnotation events named ``pp.*`` (one per pipeline
   action, by kind/stage/microbatch), ``pp_opt.*`` (optimizer phases),
