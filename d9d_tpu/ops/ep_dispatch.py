@@ -38,7 +38,12 @@ a group takes the same branch, so the collectives inside stay matched.
 Differentiable end to end: ``ragged_all_to_all`` carries JVP/transpose
 rules, so the backward re-crosses the network exactly like DeepEP's
 dispatch/combine backward pair (deepep.py:91-150); the laddered exchange
-has its own VJP that makes the same choice again in the backward.
+has its own VJP that makes the same choice again in the backward. Rows
+move only through ``ops/moe.py``'s ``permute_rows``, ``spread_to_pairs``
+and ``combine_pairs``, whose transposes are gathers by the inverse
+permutation ``stable_expert_order`` returns beside each index (over all
+``buf_rows`` rows, padding included): the backward of steps 1, 4, 5 and 6
+gathers as the forward does and holds no scatter-add of hidden-width rows.
 Capacity overflow drops the tail rows of a (source, destination) slice
 deterministically; dropped assignments contribute exactly zero (their
 return slot is never written), matching capacity-style MoE semantics.
@@ -53,7 +58,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from d9d_tpu.core.types import Array
-from d9d_tpu.ops.moe import combine_pairs, stable_expert_order
+from d9d_tpu.ops.moe import (
+    combine_pairs,
+    permute_rows,
+    spread_to_pairs,
+    stable_expert_order,
+)
 
 __all__ = [
     "EpBufferUse",
@@ -221,7 +231,7 @@ def _exchange(
 
     with jax.named_scope("moe/permute"):
         by_expert, dest, group_sizes = stable_expert_order(labels, e_loc)
-        rows_sorted = jnp.take(recv, by_expert, axis=0)
+        rows_sorted = permute_rows(recv, by_expert, dest)
 
     with jax.named_scope("ep/expert_compute"):
         y_sorted = expert_fn(rows_sorted, group_sizes, *expert_weights)
@@ -229,7 +239,7 @@ def _exchange(
     # == r) — cheaper than a zeros+scatter on TPU, same as ops/moe.py's
     # unpermute_combine
     with jax.named_scope("moe/combine"):
-        y_buf = jnp.take(y_sorted, dest, axis=0)
+        y_buf = permute_rows(y_sorted, dest, by_expert)
 
     # 5. mirrored return trip (swap send/recv roles). My slice for source s
     # must land where s's sorted rows for me begin: s's own block layout.
@@ -340,7 +350,7 @@ def ep_dispatch_compute_combine(
             ids_flat, e_loc * ep_world
         )
         token_of = order // k
-        x_rows = jnp.take(x_loc, token_of, axis=0)  # [m, D]
+        x_rows = spread_to_pairs(x_loc, token_of, pair_dest)  # [m, D]
 
     # 2. tiny count exchange: S[s, e] = rows shard s routes to expert e
     S = lax.all_gather(counts, ep_axes, axis=0)  # [W, E]
@@ -348,7 +358,7 @@ def ep_dispatch_compute_combine(
     need = S.reshape(ep_world, ep_world, e_loc).sum(axis=(0, 2)).max()
 
     with jax.named_scope("moe/combine"):
-        probs_rows = jnp.take(probs_loc.reshape(-1), order)
+        probs_rows = permute_rows(probs_loc.reshape(-1), order, pair_dest)
 
     route = _Route(expert_fn, tuple(ep_axes), e_loc, ep_world)
     if capacity_factor is None:
@@ -371,7 +381,7 @@ def ep_dispatch_compute_combine(
     # 6. fold the k assignments per token, already weighted by the router
     # probs (collision-free gather form — see ops/moe.py combine_pairs)
     with jax.named_scope("moe/combine"):
-        out = combine_pairs(weighted, pair_dest, n)
+        out = combine_pairs(weighted, token_of, pair_dest, n)
     return out, EpBufferUse(
         rows_taken=jnp.take(jnp.asarray(ladder, jnp.int32), rung),
         rows_needed=need.astype(jnp.int32),

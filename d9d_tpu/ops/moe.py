@@ -8,14 +8,19 @@ Replaces the reference kernel layer for MoE (SURVEY §2.2):
 - Triton permute/unpermute kernels (d9d/kernel/moe/permute_with_probs.py:711,
   indices_to_multihot.py:263) → a stable argsort over expert ids + gather;
   XLA fuses the gather into the surrounding computation, and every shape is
-  static (N·K rows) as TPU compilation demands.
+  static (N·K rows) as TPU compilation demands. Every row movement is one of
+  three linear maps (:func:`permute_rows`, :func:`spread_to_pairs`,
+  :func:`combine_pairs`) whose transposes are given as gathers, so the
+  backward holds no scatter-add of hidden-width rows either.
 
 All functions operate on a flat token dim; callers reshape [B,T,D]→[N,D].
 """
 
+import functools
 import os
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -107,6 +112,62 @@ def sort_tokens_by_expert(topk_ids: Array, num_experts: int) -> TokenSort:
     )
 
 
+@jax.custom_vjp
+def permute_rows(x: Array, idx: Array, inv_idx: Array) -> Array:
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv_idx``.
+
+    The pair comes from one :func:`stable_expert_order` call (``sort_idx``
+    and ``dest``, either way round). The transpose of a gather by a
+    permutation is the gather by its inverse: no row collides and nothing
+    is added. Autodiff cannot know ``idx`` is a permutation and would write
+    a general scatter-add, several times slower than a gather on TPU, so
+    the transpose is given here.
+    """
+    return jnp.take(x, idx, axis=0)
+
+
+def _permute_rows_fwd(x, idx, inv_idx):
+    return permute_rows(x, idx, inv_idx), (idx, inv_idx)
+
+
+def _permute_rows_bwd(residuals, g):
+    idx, inv_idx = residuals
+    return permute_rows(g, inv_idx, idx), None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def spread_to_pairs(x: Array, token_idx: Array, dest: Array) -> Array:
+    """A token's row to each of its K expert-sorted pair rows.
+
+    x: [N, D] → [N*K, D], row r a copy of token ``token_idx[r]``;
+    ``token_idx = sort_idx // K`` and ``dest`` the inverse of ``sort_idx``
+    (:func:`stable_expert_order`). The mirror of :func:`combine_pairs`,
+    which is its transpose: autodiff's own would be a scatter-add
+    colliding K ways on every token.
+    """
+    return _spread_to_pairs(x, token_idx, dest, x.shape[0])
+
+
+# N is static beside the operands: the transpose needs it and sees only g
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread_to_pairs(x, token_idx, dest, num_tokens):
+    return jnp.take(x, token_idx, axis=0)
+
+
+def _spread_to_pairs_fwd(x, token_idx, dest, num_tokens):
+    return _spread_to_pairs(x, token_idx, dest, num_tokens), (token_idx, dest)
+
+
+def _spread_to_pairs_bwd(num_tokens, residuals, g):
+    token_idx, dest = residuals
+    return combine_pairs(g, token_idx, dest, num_tokens), None, None
+
+
+_spread_to_pairs.defvjp(_spread_to_pairs_fwd, _spread_to_pairs_bwd)
+
+
 def permute_tokens(
     x: Array, probs: Array, sort: TokenSort
 ) -> tuple[Array, Array]:
@@ -120,9 +181,9 @@ def permute_tokens(
     # backward needs these rows (dW), and recomputing them means redoing
     # the gather under remat
     permuted_x = checkpoint_name(
-        jnp.take(x, sort.token_idx, axis=0), "moe_permuted_rows"
+        spread_to_pairs(x, sort.token_idx, sort.dest), "moe_permuted_rows"
     )
-    permuted_probs = jnp.take(probs.reshape(-1), sort.sort_idx, axis=0)
+    permuted_probs = permute_rows(probs.reshape(-1), sort.sort_idx, sort.dest)
     return permuted_x, permuted_probs
 
 
@@ -138,21 +199,39 @@ def fused_combine_enabled() -> bool:
     return os.environ.get("D9D_TPU_MOE_COMBINE", "fused") != "unfused"
 
 
-def combine_pairs(y: Array, dest: Array, num_tokens: int) -> Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine_pairs(
+    y: Array, token_idx: Array, dest: Array, num_tokens: int
+) -> Array:
     """Fold expert-sorted pair rows back to their owning tokens.
 
     y: [N*K, D] expert-sorted rows (already prob-weighted); ``dest`` the
-    inverse permutation from :func:`stable_expert_order` → [N, D].
+    inverse permutation from :func:`stable_expert_order` and ``token_idx``
+    the owning token of each sorted row → [N, D].
     Formulated as a duplicate-free gather by ``dest`` followed by a K-row
     sum instead of ``zeros.at[token_idx].add(y)``: the scatter-add
     collides K ways on every token (each token owns K expert rows) while
-    ``dest`` is a permutation, so both this gather and its VJP (a scatter
-    at unique indices) are collision-free on TPU. Shared by the local MoE
-    path and the EP shard_map combine.
+    ``dest`` is a permutation, so the gather is collision-free on TPU. Its
+    transpose is :func:`spread_to_pairs`, a gather by ``token_idx`` (the
+    K-fold broadcast and the inverse permutation in one), where autodiff
+    would scatter at ``dest``. Shared by the local MoE path and the EP
+    shard_map combine.
     """
     k = dest.shape[0] // num_tokens
     pair_y = jnp.take(y, dest, axis=0)  # token-major pair rows
     return pair_y.reshape(num_tokens, k, y.shape[-1]).sum(axis=1)
+
+
+def _combine_pairs_fwd(y, token_idx, dest, num_tokens):
+    return combine_pairs(y, token_idx, dest, num_tokens), (token_idx, dest)
+
+
+def _combine_pairs_bwd(num_tokens, residuals, g):
+    token_idx, dest = residuals
+    return spread_to_pairs(g, token_idx, dest), None, None
+
+
+combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
 
 
 def unpermute_combine(y: Array, sort: TokenSort, num_tokens: int) -> Array:
@@ -161,7 +240,7 @@ def unpermute_combine(y: Array, sort: TokenSort, num_tokens: int) -> Array:
     y: [N*K, D] (already prob-weighted) → [N, D]. The reverse of
     ``permute_tokens``; see :func:`combine_pairs` for the formulation.
     """
-    return combine_pairs(y, sort.dest, num_tokens)
+    return combine_pairs(y, sort.token_idx, sort.dest, num_tokens)
 
 
 def gate_up_grouped_matmul(

@@ -125,20 +125,19 @@ def test_moe_layer_tokens_per_expert_stats(ctx):
     assert int(np.asarray(tpe).sum()) == 2 * 8 * 2
 
 
-def _primitives(jaxpr):
-    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+def _equations(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs included."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _primitives(sub)
+            yield from _equations(sub)
 
 
 def test_local_path_has_no_buffer_choice(ctx):
     """Without ``ep_axes`` the layer is the local permute path and nothing
     of the EP path's run-time buffer choice is in its program: no
-    conditional and no custom VJP forward or backward, and the only
-    statistic sown is ``tokens_per_expert``. The one-chip programs are
-    what they were."""
+    conditional and no custom VJP of the exchange, forward or backward,
+    and the only statistic sown is ``tokens_per_expert``."""
     layer = MoELayer(
         hidden_dim=16, intermediate_dim_grouped=32, num_grouped_experts=8,
         top_k=2, dtype=jnp.float32,
@@ -151,9 +150,16 @@ def test_local_path_has_no_buffer_choice(ctx):
         return (out ** 2).sum(), stats
 
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(params, x)
-    names = set(_primitives(jaxpr.jaxpr))
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = {eqn.primitive.name for eqn in eqns}
     assert "ragged_dot_general" in names  # the scan reaches the experts
-    assert not names & {"cond", "custom_vjp_call", "custom_vjp_call_jaxpr"}
+    assert not names & {"cond", "custom_vjp_call_jaxpr"}
+    # the only custom VJPs are the row movements' given transposes, each
+    # calling its mirror in the backward (ops/moe.py), never the exchange's
+    assert {
+        eqn.params["bwd"].__name__ for eqn in eqns
+        if eqn.primitive.name == "custom_vjp_call"
+    } <= {"_permute_rows_bwd", "_spread_to_pairs_bwd", "_combine_pairs_bwd"}
     assert not names & {"all_gather", "ragged_all_to_all", "shard_map"}
     _, stats = loss(params, x)
     assert set(stats["moe_stats"]) == {"tokens_per_expert"}

@@ -35,11 +35,9 @@ def deepseek(ep_axes=None):
 
 
 @functools.lru_cache(maxsize=None)
-def lowered_train_step(family, ep_axes=None):
-    """The loss and its gradient with respect to every parameter, lowered
-    for the TPU (the CPU's lowering expands ``ragged_dot`` into plain
-    dots; lowering for another platform needs no such device) and not yet
-    compiled: StableHLO text with the locations that become ``op_name``."""
+def traced_train_step(family, ep_axes=None):
+    """The loss and its gradient with respect to every parameter of a tiny
+    model, traced and not yet lowered."""
     model = family(ep_axes)
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, VOCAB, (B, T)), jnp.int32)
@@ -51,7 +49,16 @@ def lowered_train_step(family, ep_axes=None):
     def loss(p):
         return model.apply({"params": p}, tokens, positions, tokens).sum()
 
-    traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+    return jax.jit(jax.value_and_grad(loss)).trace(params)
+
+
+@functools.lru_cache(maxsize=None)
+def lowered_train_step(family, ep_axes=None):
+    """That step lowered for the TPU (the CPU's lowering expands
+    ``ragged_dot`` into plain dots; lowering for another platform needs no
+    such device) and not yet compiled: StableHLO text with the locations
+    that become ``op_name``."""
+    traced = traced_train_step(family, ep_axes)
     return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
 
 
@@ -109,3 +116,46 @@ def test_permute_and_combine_are_scoped_too(ep_axes):
         text = lowered_train_step(qwen3, axes)
         for mark in marks:
             assert f"{mark}/" in text, mark
+
+
+def row_ops(jaxpr, primitives, scope=""):
+    """``(primitive, scope, operand aval)`` of every equation of
+    ``primitives`` in ``jaxpr`` and the jaxprs nested in it; the scope is
+    the name stack the lowering joins into the op's ``op_name``."""
+    for eqn in jaxpr.eqns:
+        inner = f"{scope}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name in primitives:
+            yield eqn.primitive.name, inner, eqn.invars[0].aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from row_ops(sub, primitives, inner)
+
+
+@pytest.mark.parametrize("family,path", [
+    (qwen3, "local"), (qwen3, "ep"), (deepseek, "local"),
+], ids=["qwen3-local", "qwen3-ep", "deepseek-local"])
+def test_moe_backward_scatters_no_rows(family, path, ep_axes):
+    """No scatter of float rows is left in the program that is lowered:
+    the transposes of the MoE layer's row movements are gathers by the
+    inverse permutation (``ops/moe.py permute_rows``, ``spread_to_pairs``,
+    ``combine_pairs``). What stays: the int32 index scatters of
+    ``stable_expert_order``, the router's ``[B, T, E]`` top-k transpose,
+    and on this rig the transposes of the gather-based all-to-all
+    emulation (``ep/dispatch_a2a``, ``ep/combine_a2a``; a collective on
+    the TPU)."""
+    jaxpr = traced_train_step(family, ep_axes if path == "ep" else None).jaxpr
+    moved = [
+        (name, scope, aval) for name, scope, aval in row_ops(
+            jaxpr.jaxpr, ("scatter", "scatter-add", "gather")
+        ) if re.search(r"moe/(permute|combine)", scope)
+    ]
+    floats = [
+        (name, scope, aval.str_short()) for name, scope, aval in moved
+        if jnp.issubdtype(aval.dtype, jnp.floating)
+    ]
+    assert [op for op in floats if op[0] != "gather"] == []
+    # the mechanism engaged: the backward moves its rows by gathers
+    assert any("transpose(" in scope for _, scope, _ in floats), floats
+    index_scatters = [aval for name, _, aval in moved if name == "scatter"]
+    assert index_scatters and all(
+        aval.dtype == jnp.int32 and aval.ndim == 1 for aval in index_scatters
+    )

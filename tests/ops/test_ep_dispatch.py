@@ -246,22 +246,26 @@ def _expert_weights(seed=7):
     )
 
 
-def _loss_grads_and_use(devices, world, x, ids, probs, weights):
+def _loss_grads_and_use(
+    devices, world, x, ids, probs, weights, capacity_factor=None, remat=False
+):
     """``(out, grads of x, probs and the three expert weights, use
     [world, 3])`` through real grouped-SwiGLU experts sharded over the ep
-    axis of ``world`` shards, dropless."""
+    axis of ``world`` shards: dropless, or under ``capacity_factor``;
+    with ``remat`` the whole dispatch sits under ``jax.checkpoint``."""
     mesh = Mesh(np.array(devices[:world]), ("ep",))
 
     def body(x_loc, ids_loc, probs_loc, *w_loc):
         out, use = ep_dispatch_compute_combine(
             x_loc, ids_loc, probs_loc, _swiglu_experts, w_loc,
             ep_axes=("ep",), e_loc=E // world, ep_world=world,
-            capacity_factor=None,
+            capacity_factor=capacity_factor,
         )
         return out, jnp.stack(use)[None]
 
     run = compat.shard_map(
-        body, mesh=mesh, in_specs=(P("ep"),) * 6,
+        jax.checkpoint(body) if remat else body,
+        mesh=mesh, in_specs=(P("ep"),) * 6,
         out_specs=(P("ep"), P("ep")), check_vma=False,
     )
 
@@ -363,3 +367,68 @@ def test_capacity_factor_drops_the_same_tail_rows(devices, capacity_factor):
                 t = src * N_LOC + token
                 expected[t] += x[t] * (2.0 + expert) * probs[t, expert]
     np.testing.assert_allclose(out, expected, rtol=1e-5, atol=1e-5)
+
+
+# -- row movements whose transposes are gathers -------------------------------
+
+
+def _plain_autodiff_rows(monkeypatch):
+    """The three row movements as the plain ``jnp.take`` they were, whose
+    transposes autodiff writes as scatter-adds."""
+
+    def take_rows(x, idx, *unused):
+        return jnp.take(x, idx, axis=0)
+
+    def take_and_fold(y, token_idx, dest, num_tokens):
+        pair_y = jnp.take(y, dest, axis=0)
+        return pair_y.reshape(num_tokens, -1, y.shape[-1]).sum(axis=1)
+
+    monkeypatch.setattr(ep_dispatch, "permute_rows", take_rows)
+    monkeypatch.setattr(ep_dispatch, "spread_to_pairs", take_rows)
+    monkeypatch.setattr(ep_dispatch, "combine_pairs", take_and_fold)
+
+
+@pytest.mark.parametrize("world,kind,capacity_factor,remat,grouping,use", [
+    (4, "uniform", None, False, "one_hot", [16, 12, 0]),  # snug, padding rows
+    (4, "uniform", None, True, "argsort", [16, 12, 0]),
+    (4, "one_shard", None, False, "one_hot", [48, 48, 1]),  # fallback rung
+    (4, "one_shard", None, True, "argsort", [48, 48, 1]),
+    (8, "skewed", None, False, "one_hot", [40, 30, 0]),  # the rung between
+    (4, "one_shard", 1.0, False, "one_hot", [16, 48, 0]),  # 32 rows dropped
+    (4, "one_shard", 1.0, True, "argsort", [16, 48, 0]),
+    (4, "uniform", 0.5, False, "argsort", [8, 12, 0]),  # every shard drops
+])
+def test_gather_transposes_match_plain_autodiff(
+    devices, monkeypatch, world, kind, capacity_factor, remat, grouping, use
+):
+    """Gradients through the dispatch with the given transposes (gathers by
+    the inverse permutation, the k-row fold) against the same dispatch
+    under plain ``jnp.take`` autodiff (scatter-adds): on both branches of
+    ``stable_expert_order``, with padding rows in the buffer, on the
+    ladder's fallback rung under forced skew, under a capacity factor that
+    drops rows, and under ``jax.checkpoint``."""
+    from d9d_tpu.ops import moe as moe_ops
+
+    if grouping == "argsort":
+        monkeypatch.setattr(moe_ops, "_ONE_HOT_GROUPING_LIMIT", 0)
+    rng = np.random.RandomState(13)
+    n = world * N_LOC
+    x = rng.randn(n, D).astype(np.float32)
+    probs = rng.rand(n, K).astype(np.float32)
+    ids = _routing(kind, world)
+    weights = _expert_weights()
+    args = (devices, world, x, ids, probs, weights, capacity_factor, remat)
+
+    out, grads, got_use = _loss_grads_and_use(*args)
+    assert got_use[0].tolist() == use
+    _plain_autodiff_rows(monkeypatch)
+    ref_out, ref_grads, ref_use = _loss_grads_and_use(*args)
+    assert ref_use[0].tolist() == use
+
+    np.testing.assert_array_equal(out, ref_out)  # the forward is the same
+    assert np.abs(out).max() > 0
+    for name, g, ref in zip(
+        ("x", "probs", "gate", "up", "down"), grads, ref_grads
+    ):
+        np.testing.assert_allclose(g, ref, rtol=1e-6, atol=1e-6, err_msg=name)
+        assert np.abs(ref).max() > 0, name

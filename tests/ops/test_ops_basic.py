@@ -307,3 +307,127 @@ def test_stable_expert_order_argsort_fallback_matches(monkeypatch):
     slow = moe_ops.stable_expert_order(ids, 13)
     for a, b in zip(fast, slow):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _take_rows(x, idx, *unused):
+    """``permute_rows`` and ``spread_to_pairs`` as the plain gather."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _take_and_fold(y, token_idx, dest, num_tokens):
+    """``combine_pairs`` as the plain gather and k-row sum."""
+    return jnp.take(y, dest, axis=0).reshape(
+        num_tokens, -1, y.shape[-1]
+    ).sum(axis=1)
+
+
+class TestRowMovementTransposes:
+    """``permute_rows``, ``spread_to_pairs`` and ``combine_pairs`` carry
+    their transposes as gathers (ops/moe.py): the same gradients as
+    ``jnp.take`` autodiff, whose transposes are scatter-adds, on both
+    branches of ``stable_expert_order``, with and without remat."""
+
+    N, K, E, D = 37, 4, 8, 16
+
+    def _sort(self, monkeypatch, grouping, routing):
+        import d9d_tpu.ops.moe as moe_ops
+
+        if grouping == "argsort":
+            monkeypatch.setattr(moe_ops, "_ONE_HOT_GROUPING_LIMIT", 0)
+        r = np.random.RandomState(4)
+        if routing == "padded":
+            # a receive buffer's labels: a fifth real, the padding rows
+            # all clipped onto the last expert
+            ids = np.full((self.N, self.K), self.E - 1)
+            ids[: self.N // 5] = r.randint(0, self.E, (self.N // 5, self.K))
+        else:
+            ids = r.randint(0, self.E, (self.N, self.K))
+        sort = moe_ops.sort_tokens_by_expert(jnp.asarray(ids, jnp.int32), self.E)
+        # the precondition of every transpose below: a full permutation
+        # and its inverse, whichever branch made them
+        rows = np.arange(self.N * self.K)
+        np.testing.assert_array_equal(np.asarray(sort.dest)[sort.sort_idx], rows)
+        np.testing.assert_array_equal(np.asarray(sort.sort_idx)[sort.dest], rows)
+        return moe_ops, sort
+
+    @staticmethod
+    def _grad(fn, operand, remat):
+        weights = rng(*jax.eval_shape(fn, operand).shape, seed=9)
+        fn = jax.checkpoint(fn) if remat else fn
+        loss = lambda v: (jnp.tanh(fn(v)) * weights).sum()
+        return jax.jit(jax.grad(loss))(operand)
+
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    @pytest.mark.parametrize("routing", ["random", "padded"])
+    @pytest.mark.parametrize("grouping", ["one_hot", "argsort"])
+    def test_gradients_match_take_autodiff(
+        self, monkeypatch, grouping, routing, remat
+    ):
+        moe_ops, sort = self._sort(monkeypatch, grouping, routing)
+        n = self.N
+        pairs, tokens = rng(n * self.K, self.D, seed=1), rng(n, self.D, seed=2)
+
+        cases = [  # (given transpose, plain take, indices, operand, bit-equal)
+            (moe_ops.permute_rows, _take_rows,
+             (sort.sort_idx, sort.dest), pairs, True),
+            (moe_ops.permute_rows, _take_rows,
+             (sort.dest, sort.sort_idx), pairs, True),
+            (moe_ops.permute_rows, _take_rows,
+             (sort.sort_idx, sort.dest), pairs[:, 0], True),
+            (moe_ops.combine_pairs, _take_and_fold,
+             (sort.token_idx, sort.dest, n), pairs, True),
+            # the k-row fold adds a token's k cotangents in pair order,
+            # the scatter-add in sorted order
+            (moe_ops.spread_to_pairs, _take_rows,
+             (sort.token_idx, sort.dest), tokens, False),
+        ]
+        for given_fn, plain_fn, indices, operand, bit_equal in cases:
+            given = lambda v: given_fn(v, *indices)
+            plain = lambda v: plain_fn(v, *indices)
+            np.testing.assert_array_equal(given(operand), plain(operand))
+            got = self._grad(given, operand, remat)
+            want = self._grad(plain, operand, False)
+            assert np.abs(want).max() > 0
+            if bit_equal:
+                np.testing.assert_array_equal(got, want)
+            else:
+                np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("grouping", ["one_hot", "argsort"])
+    def test_local_path_backward_holds_no_scatter_add(
+        self, monkeypatch, grouping
+    ):
+        moe_ops, sort = self._sort(monkeypatch, grouping, "random")
+        x, probs = rng(self.N, self.D), rng(self.N, self.K, seed=3)
+
+        def loss(x, probs):
+            rows, row_probs = moe_ops.permute_tokens(x, probs, sort)
+            y = jnp.tanh(rows) * row_probs[:, None]
+            return (moe_ops.unpermute_combine(y, sort, self.N) ** 2).sum()
+
+        jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, probs))
+        assert "scatter" not in jaxpr
+        assert jaxpr.count("gather") >= 6  # three forward, three transposed
+
+    def test_forward_only_program_is_the_plain_take(self, monkeypatch):
+        """Without a gradient the given transposes leave no mark: serving
+        and ``generate`` lower to the program plain ``jnp.take`` gives."""
+        moe_ops, sort = self._sort(monkeypatch, "one_hot", "random")
+        n = self.N
+        x, probs = rng(n, self.D), rng(n, self.K, seed=3)
+
+        def lowered(spread, permute, combine):
+            def forward(x, probs, token_idx, sort_idx, dest):
+                pair_probs = permute(probs.reshape(-1), sort_idx, dest)
+                rows = spread(x, token_idx, dest) * pair_probs[:, None]
+                return combine(permute(rows, sort_idx, dest), token_idx, dest, n)
+
+            return jax.jit(forward).lower(
+                x, probs, sort.token_idx, sort.sort_idx, sort.dest
+            ).as_text()
+
+        given = lowered(
+            moe_ops.spread_to_pairs, moe_ops.permute_rows, moe_ops.combine_pairs
+        )
+        plain = lowered(_take_rows, _take_rows, _take_and_fold)
+        assert "gather" in plain and given == plain
