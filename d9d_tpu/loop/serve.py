@@ -46,7 +46,22 @@ that row's cache slice (every cache leaf leads with the batch dim).
 Idle and dead rows have their ``cache_index`` pinned to 0 inside the
 jitted step, so a slot left idle for arbitrarily many steps can never
 overflow the capacity contract or defeat the flash-decode block skip.
-GDN layers need nothing: their recurrent state is per-row already.
+
+Two kinds of cache live in one manager. Recurrent layers (GDN
+``delta_state``, Mamba ``ssm_state``, the short convolutions'
+``conv_tail``) keep state that is per-row already and is never paged:
+the rule is ``nn/decode_flags.recurrent_leaves`` (any per-row leaf that
+is neither pageable sequence content nor a write index), never a
+model's name. Admission zeroes a row's leaves in the same dispatch that
+starts it, so whatever an idle or dead row wrote there (it keeps
+stepping on token 0 under static shapes) cannot reach the next
+request; a model with such leaves serves with the prefix cache off
+(its state summarizes the whole prefix and cannot be rebuilt from
+shared KV pages) and ``prefix_cache=True`` raises. The zeroing is a
+masked pass over every per-row leaf, so its cost follows
+``ServeStats.recurrent_state_bytes`` (on the ``serve/step`` span and
+the ``serve/recurrent_state_bytes`` gauge too) and ``rows_reset``
+counts the rows it cleared.
 
 Parity contract: greedy serving of any admission schedule must emit,
 per request, exactly the tokens ``generate(model, params, prompt)``
@@ -341,7 +356,11 @@ class ServeStats:
     context a step reads. ``pool_pages_total`` sums the page pool's
     pages in use at each chunk boundary (over ``chunks``: the mean) and
     ``pool_pages_peak`` is the most a boundary saw; both stay 0 without
-    paging. All of it is host arithmetic on the plan: no readback.
+    paging. ``recurrent_state_bytes`` is a level, not a sum: the bytes
+    of the per-row recurrent leaves the batcher's cache holds (0 for an
+    attention-only model), as of the last chunk; ``rows_reset`` counts
+    the rows whose per-row leaves an admission zeroed. All of it is
+    host arithmetic on the plan: no readback.
     """
 
     host_dispatches: int = 0
@@ -355,6 +374,8 @@ class ServeStats:
     positions_attended: int = 0
     pool_pages_total: int = 0
     pool_pages_peak: int = 0
+    recurrent_state_bytes: int = 0
+    rows_reset: int = 0
     # degraded-mode counters: submits rejected by the bounded queue,
     # requests expired by their deadline (queued or running), requests
     # shed by the autopilot's burn-driven admission tiering
@@ -669,6 +690,11 @@ class ContinuousBatcher:
                 _zero_row, name="serve/reset_row", donate_argnums=0
             )
         self._cache = self._init_cache()
+        # static per-batcher fact: what of the cache is per-row recurrent
+        # state, beside the serve/kv_* gauges of the paged part
+        self._gauge_set(
+            "serve/recurrent_state_bytes", self._recurrent_state_bytes
+        )
         if self._paged:
             # static per-batcher fact, but exported so dashboards (and
             # the bench accounting) can tell quantized pools apart
@@ -847,6 +873,7 @@ class ContinuousBatcher:
             PAGE_TABLE_LEAF,
             PAGED_CACHE_LEAVES,
             PAGED_SCALE_SUFFIX,
+            recurrent_leaves,
         )
 
         z = jnp.zeros((self._b, 1), jnp.int32)
@@ -863,14 +890,17 @@ class ContinuousBatcher:
             math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
             for p, s in flat.items() if p[-1] in PAGED_CACHE_LEAVES
         )
-        # per-row cache leaves that are NOT pageable (GDN recurrent
-        # state, conv tails, toy memories): paging leaves them per-row;
-        # their presence auto-disables the prefix cache (their state
-        # can't be rebuilt from shared KV pages)
-        self._unpageable_leaves = sorted({
-            p[-1] for p in flat
-            if p[-1] not in PAGED_CACHE_LEAVES and p[-1] != "cache_index"
-        })
+        # per-row cache leaves that are NOT pageable (GDN and Mamba
+        # recurrent state, conv tails, toy memories): paging leaves them
+        # per-row; their presence auto-disables the prefix cache (their
+        # state can't be rebuilt from shared KV pages). Their bytes are
+        # what every admission's row reset passes over.
+        recurrent = recurrent_leaves(shapes["cache"])
+        self._unpageable_leaves = sorted({p[-1] for p in recurrent})
+        self._recurrent_state_bytes = sum(
+            math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+            for s in recurrent.values()
+        )
         self._page_bytes = 0
         out = {}
         for p, s in flat.items():
@@ -1794,6 +1824,7 @@ class ContinuousBatcher:
                 reset_mask[i] = True
                 admit_pos[i] = start_pos
                 self._note_admit(req.rid)
+                self.stats.rows_reset += 1
             if reset_mask.any():
                 if self._paged:
                     self._cache = self._reset(
@@ -1837,6 +1868,7 @@ class ContinuousBatcher:
         self.stats.slot_steps_total += self._b
         self.stats.slot_steps_busy += int(live.sum())
         self.stats.positions_attended += int((pos[live] + 1).sum())
+        self.stats.recurrent_state_bytes = self._recurrent_state_bytes
         if self._paged:
             self._count_pool_pages()
         self._observe("serve/slot_util", live.sum() / self._b, _UTIL_EDGES)
@@ -2019,6 +2051,13 @@ class ContinuousBatcher:
         self.stats.host_dispatches += 1
         self.stats.chunks += 1
         self.stats.device_steps += k
+        rows_reset = int(admit_mask.sum())
+        self.stats.rows_reset += rows_reset
+        self.stats.recurrent_state_bytes = self._recurrent_state_bytes
+        clock.meta.update(
+            recurrent_state_bytes=self._recurrent_state_bytes,
+            rows_reset=rows_reset,
+        )
         if self._paged:
             # on the closing serve/step span too: ServeStats gives a
             # caller totals, the span timeline any window's peak

@@ -25,12 +25,15 @@ Cache mechanics (why this needs no new module support):
   index), so keys written for rejected proposals become invisible the
   moment ``cache_index`` rewinds — no buffer surgery. Rows rewind
   independently (per-row ``[B]`` indices).
-- GatedDeltaNet layers are REJECTED by contract
-  (``NotImplementedError``): their recurrent state advances
-  irreversibly through every token, so rejected proposals would need
-  per-position state checkpoints the layer does not keep. Speculate
-  with attention-family models (dense GQA, Llama, MLA); hybrids decode
-  through ``generate``/``ContinuousBatcher``.
+- Recurrent layers (GatedDeltaNet, Mamba) are REJECTED by contract
+  (``NotImplementedError``): their state advances irreversibly through
+  every token, so rejected proposals would need per-position state
+  checkpoints the layers do not keep. The rule is the one
+  ``ContinuousBatcher`` keeps its prefix cache off by
+  (``nn/decode_flags.recurrent_leaves``: any per-row cache leaf that is
+  neither pageable nor a write index), not a list of leaf names.
+  Speculate with attention-family models (dense GQA, Llama, MLA);
+  hybrids decode through ``generate``/``ContinuousBatcher``.
 """
 
 from typing import Any, Optional
@@ -45,17 +48,17 @@ from d9d_tpu.telemetry import tracked_jit
 
 
 def _assert_rewindable(cache) -> None:
-    from flax.traverse_util import flatten_dict
+    from d9d_tpu.nn.decode_flags import recurrent_leaves
 
-    for path in flatten_dict(cache):
-        if path[-1] in ("delta_state", "conv_tail"):
-            raise NotImplementedError(
-                "speculative decoding requires rewindable decode state; "
-                "GatedDeltaNet layers advance a recurrent state that "
-                "cannot roll back past rejected proposals "
-                f"(cache leaf {'/'.join(path)}). Use generate() or "
-                "ContinuousBatcher for hybrid models."
-            )
+    stateful = recurrent_leaves(cache)
+    if stateful:
+        raise NotImplementedError(
+            "speculative decoding requires rewindable decode state; "
+            "recurrent layers (GatedDeltaNet, Mamba) advance a recurrent "
+            "state that cannot roll back past rejected proposals "
+            f"(cache leaf {'/'.join(next(iter(stateful)))}). Use "
+            "generate() or ContinuousBatcher for hybrid models."
+        )
 
 
 def _set_indices(cache, new_index: Array):

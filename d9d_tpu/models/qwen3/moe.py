@@ -25,6 +25,7 @@ from d9d_tpu.nn.heads import (
 from d9d_tpu.nn.mlp import SwiGLU
 from d9d_tpu.nn.moe import MoELayer, SharedExpertParameters
 from d9d_tpu.nn.norm import RMSNorm
+from d9d_tpu.nn.vocab_ranges import concat_vocab_ranges
 from d9d_tpu.nn.sdpa.protocol import SdpaBackend
 from d9d_tpu.ops import (
     RopeScaling,
@@ -123,6 +124,19 @@ class Qwen3MoeConfig:
     # TopKRouter
     router_score_function: str = "softmax"
     router_expert_bias: bool = False
+    # State-space layers (models/jamba/): listed layers swap attention for
+    # a Mamba-1 mixer (nn/mamba.py); a stack with any adds its residual
+    # stream in float32 (Mamba's residual_in_fp32). dt_rank 0 = hidden/16.
+    mamba_layers: tuple[int, ...] = ()
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # the output head reads the embedding table (no head parameters);
+    # the table is then drawn at embedding_init_std, the family's
+    # initializer_range, so that logits are of order 1 at init
+    tie_word_embeddings: bool = False
+    embedding_init_std: float = 1.0
 
     @property
     def vocab_size(self) -> int:
@@ -226,8 +240,8 @@ class Qwen3MoeDecoderLayer(nn.Module):
     def __call__(
         self,
         x: Array,
-        cos: Array,
-        sin: Array,
+        cos: Optional[Array],
+        sin: Optional[Array],
         mask: Optional[Array] = None,
         padding_mask: Optional[Array] = None,
     ) -> Array:
@@ -237,7 +251,24 @@ class Qwen3MoeDecoderLayer(nn.Module):
             cfg.hidden_size, eps=cfg.norm_eps, zero_centered=zc,
             name="input_layernorm",
         )(x)
-        if self.layer_idx in cfg.linear_attention_layers:
+        if self.layer_idx in cfg.mamba_layers:
+            from d9d_tpu.nn.mamba import MambaMixer
+
+            # like GDN below, the mixer zeroes padded positions itself
+            # and takes the [B, T] ``padding_mask``
+            attn_out = MambaMixer(
+                hidden_size=cfg.hidden_size,
+                d_state=cfg.mamba_d_state,
+                d_conv=cfg.mamba_d_conv,
+                expand=cfg.mamba_expand,
+                dt_rank=cfg.mamba_dt_rank,
+                norm_eps=cfg.norm_eps,
+                decode=self.decode_max_length > 0,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                name="mamba",
+            )(normed, padding_mask)
+        elif self.layer_idx in cfg.linear_attention_layers:
             from d9d_tpu.nn.linear_attention import GatedDeltaNet
 
             # GDN zeroes padded positions before the conv/recurrence (HF
@@ -354,16 +385,18 @@ class Qwen3MoeBackbone(nn.Module):
         padding_mask: Optional[Array] = None,
     ) -> Array:
         cfg = self.config
+        stream = jnp.float32 if cfg.mamba_layers else self.dtype
         if self.stage.is_first:
             x = TokenEmbedding(
                 vocab_ranges=cfg.vocab_ranges,
                 hidden_size=cfg.hidden_size,
-                dtype=self.dtype,
+                init_std=cfg.embedding_init_std,
+                dtype=stream,
                 param_dtype=self.param_dtype,
                 name="embed_tokens",
             )(x)
         else:
-            x = x.astype(self.dtype)
+            x = x.astype(stream)
         x = self._pin(x)
 
         # partial rotary (rope_fraction < 1): frequencies are computed over
@@ -373,10 +406,14 @@ class Qwen3MoeBackbone(nn.Module):
             cfg.mla.qk_rope_head_dim if cfg.mla is not None
             else int(cfg.head_dim * cfg.rope_fraction)
         )
-        inv_freq, att_scale = compute_rope_frequencies(
-            rotary_dim, cfg.rope_theta, cfg.rope_scaling
-        )
-        cos, sin = make_rope_cos_sin(positions, inv_freq, att_scale)
+        # rope_fraction 0 (no positional encoding: the attention layers
+        # of a state-space hybrid) rotates nothing: no frequencies
+        cos = sin = None
+        if rotary_dim:
+            inv_freq, att_scale = compute_rope_frequencies(
+                rotary_dim, cfg.rope_theta, cfg.rope_scaling
+            )
+            cos, sin = make_rope_cos_sin(positions, inv_freq, att_scale)
 
         layer_cls = Qwen3MoeDecoderLayer
         # remat is a backward-pass tool; decode is forward-only and its
@@ -411,7 +448,7 @@ class Qwen3MoeBackbone(nn.Module):
             x = RMSNorm(
                 cfg.hidden_size, eps=cfg.norm_eps,
                 zero_centered=cfg.zero_centered_norms, name="norm",
-            )(x)
+            )(x).astype(self.dtype)
             numerics.tap("norm", x)
         return x
 
@@ -439,14 +476,31 @@ class Qwen3MoeCausalLM(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
         )
+        tied = self.config.tie_word_embeddings
+        if tied and not (self.stage.is_first and self.stage.is_last):
+            raise ValueError(
+                "tie_word_embeddings needs the embedding and the head on "
+                "one pipeline stage"
+            )
         if self.stage.is_last:
             self.lm_head = LanguageModellingHead(
                 vocab_ranges=self.config.vocab_ranges,
                 hidden_size=self.config.hidden_size,
                 ce_chunk_size=self.ce_chunk_size,
+                tied=tied,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
             )
+
+    def _head_table(self) -> Optional[Array]:
+        """The embedding table a tied head reads, once the backbone has
+        run (its parameters exist then, at ``init`` too); else None."""
+        if not self.config.tie_word_embeddings:
+            return None
+        tables = nn.meta.unbox(self.model.variables["params"]["embed_tokens"])
+        return concat_vocab_ranges(
+            [tables[f"embedding_{name}"] for name, _ in self.config.vocab_ranges]
+        )
 
     def __call__(
         self,
@@ -458,7 +512,7 @@ class Qwen3MoeCausalLM(nn.Module):
     ) -> Array:
         h = self.model(x, positions, mask, padding_mask)
         if self.stage.is_last and labels is not None:
-            return self.lm_head(h, labels)
+            return self.lm_head(h, labels, self._head_table())
         return h
 
     def logits(
@@ -471,7 +525,7 @@ class Qwen3MoeCausalLM(nn.Module):
         h = self.model(x, positions, mask, padding_mask)
         if not self.stage.is_last:
             return h
-        return self.lm_head.logits(h)
+        return self.lm_head.logits(h, self._head_table())
 
     def logits_last(
         self,
@@ -484,7 +538,7 @@ class Qwen3MoeCausalLM(nn.Module):
         h = self.model(x, positions, mask, padding_mask)
         if not self.stage.is_last:
             return h
-        return self.lm_head.logits(h[:, -1:])
+        return self.lm_head.logits(h[:, -1:], self._head_table())
 
 
 class Qwen3MoeForClassification(nn.Module):

@@ -400,8 +400,8 @@ class GroupedQueryAttention(nn.Module):
     def __call__(
         self,
         x: Array,
-        cos: Array,
-        sin: Array,
+        cos: Optional[Array],
+        sin: Optional[Array],
         mask: Optional[Array] = None,
     ) -> Array:
         b, t, _ = x.shape

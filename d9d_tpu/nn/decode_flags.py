@@ -85,6 +85,30 @@ PAGED_SCALE_LEAVES = {
     for name, axis in PAGED_CACHE_LEAVES.items()
 }
 
+# leaves of a paged cache that do not lead with the batch dimension:
+# pools shared by all rows, and the tables the host allocator writes
+_SHARED_LEAVES = (
+    set(PAGED_CACHE_LEAVES) | set(PAGED_SCALE_LEAVES) | {PAGE_TABLE_LEAF}
+)
+
+
+def recurrent_leaves(cache) -> dict:
+    """``{path: leaf}`` of the per-row cache leaves that hold neither
+    pageable sequence content nor a write index: recurrent state that
+    summarizes a row's whole prefix (GDN ``delta_state``, Mamba
+    ``ssm_state``, the short convolutions' ``conv_tail``). The ONE
+    statement of the rule: such a leaf cannot be rebuilt from shared KV
+    pages, so ``ContinuousBatcher`` keeps its prefix cache off for a
+    model that has one, and it cannot roll back past rejected
+    proposals, so ``speculative_generate`` refuses the model. Works on
+    arrays and on ``jax.eval_shape`` shapes alike."""
+    from flax.traverse_util import flatten_dict
+
+    return {
+        path: leaf for path, leaf in flatten_dict(cache).items()
+        if path[-1] not in _SHARED_LEAVES and path[-1] != "cache_index"
+    }
+
 
 def map_page_table(cache, fn):
     """Apply ``fn`` to every ``page_table`` leaf of a cache pytree (the
@@ -111,12 +135,9 @@ def zero_rows_skip_paged(cache, row_mask):
     import jax.numpy as jnp
     from flax.traverse_util import flatten_dict, unflatten_dict
 
-    skip = (
-        set(PAGED_CACHE_LEAVES) | set(PAGED_SCALE_LEAVES) | {PAGE_TABLE_LEAF}
-    )
     flat = flatten_dict(cache)
     for path, x in list(flat.items()):
-        if path[-1] in skip:
+        if path[-1] in _SHARED_LEAVES:
             continue
         m = row_mask.reshape((-1,) + (1,) * (x.ndim - 1))
         flat[path] = jnp.where(m, jnp.zeros_like(x), x)

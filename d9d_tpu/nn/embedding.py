@@ -22,6 +22,9 @@ class TokenEmbedding(nn.Module):
 
     vocab_ranges: tuple[tuple[str, int], ...]  # ordered (name, size)
     hidden_size: int
+    # a table that is also the output head (tied) wants the family's
+    # small initializer_range: logits are then of order 1 at init
+    init_std: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
@@ -37,7 +40,7 @@ class TokenEmbedding(nn.Module):
             self.vocab_ranges,
             self.hidden_size,
             self.param_dtype,
-            nn.initializers.normal(stddev=1.0),
+            nn.initializers.normal(stddev=self.init_std),
         )
         table = concat_vocab_ranges(tables)
         return jnp.take(table, token_ids, axis=0).astype(self.dtype)

@@ -20,17 +20,22 @@ class LanguageModellingHead(nn.Module):
 
     ``__call__`` returns per-token loss (never materializing full logits,
     reference language_modelling.py:14 via CCE); ``logits`` returns raw
-    logits for inference/eval paths.
+    logits for inference/eval paths. ``tied`` heads own no parameters:
+    both take the embedding table ``[V, D]`` the caller hands them
+    (``tie_word_embeddings``), through the same fused cross-entropy.
     """
 
     vocab_ranges: tuple[tuple[str, int], ...]
     hidden_size: int
     ce_chunk_size: "int | str" = "auto"
     logit_softcap: float | None = None
+    tied: bool = False
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
     def setup(self) -> None:
+        if self.tied:
+            return
         self._tables = make_vocab_range_params(
             self.param,
             "head",
@@ -40,12 +45,18 @@ class LanguageModellingHead(nn.Module):
             nn.initializers.lecun_normal(),
         )
 
-    def _weight(self) -> Array:
-        return concat_vocab_ranges(self._tables)
+    def _weight(self, table: Optional[Array]) -> Array:
+        if self.tied != (table is not None):
+            raise ValueError(
+                "a tied head, and only a tied head, is handed the table"
+            )
+        return table if self.tied else concat_vocab_ranges(self._tables)
 
-    def __call__(self, hidden: Array, labels: Array) -> Array:
+    def __call__(
+        self, hidden: Array, labels: Array, table: Optional[Array] = None
+    ) -> Array:
         """hidden [B,T,D], labels [B,T] → per-token loss [B,T] (fp32)."""
-        w = self._weight()
+        w = self._weight(table)
         b, t, d = hidden.shape
         # CE matmul policy follows the activation dtype (linear_ce default):
         # bf16 models take the full-rate MXU path, fp32 models stay exact
@@ -58,8 +69,8 @@ class LanguageModellingHead(nn.Module):
         )
         return loss.reshape(b, t)
 
-    def logits(self, hidden: Array) -> Array:
-        w = self._weight()
+    def logits(self, hidden: Array, table: Optional[Array] = None) -> Array:
+        w = self._weight(table)
         return hidden.astype(jnp.float32) @ w.astype(jnp.float32).T
 
 

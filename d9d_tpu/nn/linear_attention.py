@@ -28,18 +28,22 @@ from d9d_tpu.ops.swiglu import silu_mul
 
 class CausalShortConv1d(nn.Module):
     """Causal depthwise conv over time with SiLU (reference :17; fla's
-    causal_conv1d). Weight [channels, kernel]."""
+    causal_conv1d). Weight [channels, kernel]; ``use_bias`` adds a
+    per-channel bias before the SiLU (Mamba's conv1d). ``left_context``
+    ``[B, K-1, C]`` stands in for the zero left pad: the true previous
+    inputs of a decode-mode call."""
 
     channels: int
     kernel_size: int
+    use_bias: bool = False
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x: Array) -> Array:  # [B,T,C]
+    def __call__(self, x: Array, left_context: Optional[Array] = None) -> Array:  # [B,T,C]
         def conv_init(key, shape, dtype):
             # torch depthwise-conv default (kaiming_uniform a=√5):
             # U(-1/√K, 1/√K) with fan_in = kernel taps, NOT channels
-            bound = shape[-1] ** -0.5
+            bound = self.kernel_size ** -0.5
             return jax.random.uniform(key, shape, dtype, -bound, bound)
 
         w = self.param(
@@ -49,9 +53,20 @@ class CausalShortConv1d(nn.Module):
             self.param_dtype,
         )
         xf = x.astype(jnp.float32)
-        pad = self.kernel_size - 1
-        xp = jnp.pad(xf, ((0, 0), (pad, 0), (0, 0)))
+        if left_context is None:
+            pad = self.kernel_size - 1
+            xp = jnp.pad(xf, ((0, 0), (pad, 0), (0, 0)))
+        else:
+            xp = jnp.concatenate([left_context.astype(jnp.float32), xf], axis=1)
         out = _depthwise_causal_conv(xp, w.astype(jnp.float32))
+        if self.use_bias:
+            bias = self.param(
+                "bias",
+                nn.with_logical_partitioning(conv_init, (la.HEADS,)),
+                (self.channels,),
+                self.param_dtype,
+            )
+            out = out + bias.astype(jnp.float32)
         return jax.nn.silu(out).astype(x.dtype)
 
 

@@ -222,6 +222,59 @@ def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
+def test_flash_decode_multi_query_group_of_20(topo):
+    """Jamba2-3B's attention layers in the serving cell: 20 query heads
+    on ONE key/value head (24 padded rows a kernel instance) against a
+    paged pool of 256 slots x 1,152 positions."""
+    from d9d_tpu.ops.attention.pallas_decode import flash_decode_attention
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    b, s = 256, 1152
+    pool = sds((b * (s // PAGE) + 1, 1, PAGE, D), BF16)
+    compiled = jax.jit(
+        lambda q, k, v, start, page_table: flash_decode_attention(
+            q, k, v, start=start, page_table=page_table, interpret=False
+        )
+    ).lower(
+        sds((b, 1, 20, D), BF16), pool, pool, sds((b,), jnp.int32),
+        sds((b, s // PAGE), jnp.int32),
+    ).compile()
+    assert _pallas_calls(compiled) == 1
+
+
+def test_mamba_step_at_jamba2_3b_widths(topo):
+    """The Mamba-1 mixer's one-token step for 256 rows at the published
+    widths (d_inner 5,120, d_state 16, dt_rank 160): the float32 state
+    (84 MB) and the conv tail are updated in place when the cache is
+    donated, and the step's temporaries stay a fraction of the state."""
+    import flax.linen as nn
+
+    from d9d_tpu.nn.mamba import MambaMixer
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    mixer = MambaMixer(
+        hidden_size=2560, dt_rank=160, decode=True, dtype=BF16,
+        param_dtype=BF16,
+    )
+    abstract = nn.unbox(jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros((256, 1, 2560), BF16))
+    ))
+    variables = jax.tree.map(lambda a: sds(a.shape, a.dtype), abstract)
+    assert variables["cache"]["ssm_state"].shape == (256, 16, 5120)
+    state_bytes = 256 * 16 * 5120 * 4
+    compiled = jax.jit(
+        lambda cache, params, u: mixer.apply(
+            {"params": params, "cache": cache}, u, mutable=["cache"]
+        ),
+        donate_argnums=0,
+    ).lower(
+        variables["cache"], variables["params"], sds((256, 1, 2560), BF16)
+    ).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= state_bytes  # updated in place
+    assert ma.temp_size_in_bytes < state_bytes / 2
+
+
 # -- the model ---------------------------------------------------------------
 
 

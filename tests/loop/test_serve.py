@@ -9,6 +9,8 @@ token-identical to the legacy per-token path across K, including
 mid-chunk finishes (budget and EOS), mid-chunk admissions (requests
 submitted between chunk boundaries), and the double-buffered drain."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -316,3 +318,146 @@ def test_fused_dispatch_counters():
         >= 4 * fused.stats.dispatches_per_1k_tokens
     )
     assert fused.stats.readbacks == fused.stats.chunks
+
+
+# -- a state-space hybrid: two kinds of cache in one manager ------------------
+#
+# jamba_tiny: a Mamba-1 mixer (per-row ssm_state + conv_tail, never paged)
+# beside a multi-query attention layer (paged KV), tied table.
+
+
+def _jamba(decode_max_length=32):
+    from d9d_tpu.models.jamba import JambaCausalLM, jamba_tiny
+
+    return JambaCausalLM(
+        config=jamba_tiny(VOCAB), sdpa=eager_sdpa, dtype=jnp.float32,
+        decode_max_length=decode_max_length,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _jamba_setup():
+    """One model, its weights and its generate oracle for the cases below."""
+    model = _jamba()
+    params = _params(model)
+    oracle = jax.jit(lambda prm, ids: generate(
+        model, prm, ids, max_new_tokens=9
+    ))
+
+    def want(prompt):
+        ids = jnp.asarray([prompt], jnp.int32)
+        return np.asarray(oracle(params, ids))[0].tolist()
+
+    return model, params, want
+
+
+@pytest.mark.parametrize("page_size,chunk", [
+    (8, 4), (8, None), (None, 4),
+], ids=["paged-fused", "paged-per-token", "contiguous-fused"])
+def test_state_space_hybrid_staggered_admission_matches_generate(
+    page_size, chunk
+):
+    """Admission zeroes a row's ssm_state and conv_tail in the dispatch
+    that starts it: requests admitted mid-flight, queued behind a full
+    batch, and into slots other requests died in emit what generate
+    emits for each alone."""
+    model, params, want = _jamba_setup()
+    # five prompts of one length each: the oracle compiles once a length
+    prompts = [p[:n] for p, n in zip(_prompts(21, 5, lo=6, hi=7),
+                                     (3, 5, 3, 5, 3))]
+    batcher = ContinuousBatcher(
+        model, params, batch_size=2, page_size=page_size, chunk_size=chunk
+    )
+    rids = [batcher.submit(prompts[0], max_new_tokens=9)]
+    batcher.step()
+    batcher.step()
+    rids += [batcher.submit(p, max_new_tokens=9) for p in prompts[1:]]
+    outputs = batcher.drain()
+    for rid, prompt in zip(rids, prompts):
+        assert outputs[rid] == want(prompt), rid
+    assert batcher._unpageable_leaves == ["conv_tail", "ssm_state"]
+    if page_size:
+        assert batcher._kv.prefix_cache_enabled is False
+    batcher.close()
+
+
+def test_state_space_hybrid_reused_slot_serves_the_second_request():
+    """One slot, requests strictly one after another, and a dead row that
+    kept stepping on token 0 to the end of its chunk: each request gets
+    its own stream, not a continuation of its predecessor's state."""
+    model, params, want = _jamba_setup()
+    prompts = [p[:n] for p, n in zip(_prompts(22, 3, lo=6, hi=7), (5, 3, 5))]
+    batcher = ContinuousBatcher(
+        model, params, batch_size=1, page_size=8, chunk_size=8
+    )
+    rids = [batcher.submit(p, max_new_tokens=9) for p in prompts]
+    outputs = batcher.drain()
+    for rid, prompt in zip(rids, prompts):
+        assert outputs[rid] == want(prompt), rid
+    # and differ from each other: the check above is not vacuous
+    assert outputs[rids[0]] != outputs[rids[1]]
+    batcher.close()
+
+
+def test_state_space_hybrid_counts_its_recurrent_state():
+    """``recurrent_state_bytes`` from the batcher's own cache tree and
+    ``rows_reset`` per admission, in ServeStats, on each ``serve/step``
+    span and in the gauge; an attention-only model counts zero bytes."""
+    from d9d_tpu.telemetry import Telemetry
+
+    model, params, _ = _jamba_setup()
+    tele = Telemetry()
+    batcher = ContinuousBatcher(
+        model, params, batch_size=2, page_size=8, chunk_size=4,
+        telemetry=tele,
+    )
+    cfg = model.config
+    d_inner = cfg.mamba_expand * cfg.hidden_size
+    per_row = len(cfg.mamba_layers) * d_inner * 4 * (
+        cfg.mamba_d_state + cfg.mamba_d_conv - 1
+    )  # float32 state, and tails in the model's dtype: float32 here
+    for p in _prompts(23, 3, lo=3, hi=4):
+        batcher.submit(p, max_new_tokens=5)
+    while batcher.active:
+        batcher.step_chunk()
+    assert batcher.stats.recurrent_state_bytes == 2 * per_row
+    assert batcher.stats.rows_reset == 3
+    steps = [s for s in tele.registry.spans if s.name == "serve/step"]
+    assert steps and all(
+        s.meta["recurrent_state_bytes"] == 2 * per_row for s in steps
+    )
+    assert sum(s.meta["rows_reset"] for s in steps) == 3
+    assert tele.registry.gauge(
+        "serve/recurrent_state_bytes").value == 2 * per_row
+    batcher.close()
+    dense = _dense()
+    plain = ContinuousBatcher(dense, _params(dense), batch_size=2,
+                              telemetry=Telemetry())
+    plain.submit([1, 2], max_new_tokens=2)
+    plain.drain()
+    assert plain.stats.recurrent_state_bytes == 0
+    assert plain.stats.rows_reset == 1
+    plain.close()
+
+
+def test_state_space_hybrid_refuses_prefix_cache_and_speculation():
+    """One rule (``nn/decode_flags.recurrent_leaves``), two refusals: a
+    state that summarizes the whole prefix cannot be rebuilt from shared
+    KV pages, nor rolled back past a rejected proposal."""
+    from d9d_tpu.loop.speculative import speculative_generate
+    from d9d_tpu.nn.decode_flags import recurrent_leaves
+
+    model, params, _ = _jamba_setup()
+    with pytest.raises(ValueError, match="unsound"):
+        ContinuousBatcher(model, params, batch_size=2, page_size=8,
+                          prefix_cache=True)
+    with pytest.raises(NotImplementedError, match="ssm_state|conv_tail"):
+        speculative_generate(
+            model, params, model, params, jnp.asarray([[1, 2, 3]], jnp.int32),
+            max_new_tokens=4, speculate_k=2,
+        )
+    # the rule itself: by what a leaf is, not by its name
+    cache = {"attn": {"cached_key": 0, "cache_index": 0, "page_table": 0,
+                      "cached_key_scale": 0},
+             "mixer": {"some_new_state": 0}}
+    assert list(recurrent_leaves(cache)) == [("mixer", "some_new_state")]
