@@ -25,6 +25,13 @@ never materializes logits. Two layout decisions follow:
   online-softmax state (m, l, acc over g·T rows) persists in VMEM
   scratch across kv steps, exactly like the training forward.
 
+Paged mode (the serving loop's page pools, ``_paged_decode_call``) is
+the same arithmetic with the cache gathered through a page table: one
+grid step a batch row, the row's live pages copied a block of pages at
+a time into a double-buffered VMEM block (all kv heads of a page in one
+copy), one online-softmax update a block; pages past the row's last
+query are neither copied nor attended.
+
 Slot semantics ride positions: the cache write index ``start`` enters
 as a traced SMEM scalar, queries sit at global positions
 ``start + [0,T)``, keys at their slot index — so causal/window masking
@@ -89,14 +96,13 @@ class _DecodeConfig:
     # mode only; never combines with has_valid — the serving loop's
     # paged rows are never left-padded)
     quant: bool = False
+    # paged mode: pages a copied block holds (paged_decode_geometry)
+    pages_per_step: int = 1
 
 
 def _decode_kernel(*refs, cfg: _DecodeConfig):
-    ks_ref = vs_ref = valid_ref = None
-    if cfg.quant:
-        offs_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:6]
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[6:]
-    elif cfg.has_valid:
+    valid_ref = None
+    if cfg.has_valid:
         offs_ref, q_ref, k_ref, v_ref, valid_ref = refs[:5]
         o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[5:]
     else:
@@ -130,13 +136,6 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
         q = q_ref[0, 0, :, :].astype(jnp.float32)
         k = k_ref[0, 0, :, :].astype(jnp.float32)
         v = v_ref[0, 0, :, :].astype(jnp.float32)
-        if cfg.quant:
-            # int8 block × per-slot scale column [bkv, 1] broadcast
-            # over the feature dim: the dequant rides the same in-VMEM
-            # f32 math the kernel already does — HBM streamed the int8
-            # bytes, the rescale is free next to the MXU dot
-            k = k * ks_ref[0, 0, :, :]
-            v = v * vs_ref[0, 0, :, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -185,14 +184,197 @@ def _pad_to(n: int, m: int) -> int:
     return (-n) % m
 
 
-def _paged_decode_kernel(offs_ref, pt_ref, *refs, cfg: _DecodeConfig):
-    """Paged variant: identical math to :func:`_decode_kernel` — the
-    page table is consumed entirely by the kv BlockSpec index maps
-    (scalar-prefetch gather), so the kernel body only needs the write
-    offsets. Grid step ``ki`` is the row's LOGICAL block ki; its bytes
-    stream from pool page ``pt[bi, ki]``."""
-    del pt_ref  # consumed by the index maps
-    _decode_kernel(offs_ref, *refs, cfg=cfg)
+# Paged mode. A grid step is one batch row; inside it the row's LIVE
+# pages are copied from the pools (left in HBM) into a double-buffered
+# VMEM block of ``pages_per_step`` pages, and a block meets the online
+# softmax at once. On the chip (PERF.md, PR 33) a page operand of the
+# BlockSpec pipeline costs 0.05 us whether its page is live or not, a
+# grid step 0.2 us, and one online-softmax update 0.45 us whatever its
+# width: a step of one 16 KB page was bound by those, not by its bytes.
+# The contiguous path streams 512 positions a step too.
+PAGED_STEP_POSITIONS = 512
+# both buffers of the K and V blocks
+PAGED_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+@dataclasses.dataclass(frozen=True)
+class PagedDecodeGeometry:
+    """The tiling :func:`_paged_decode_call` runs for given shapes."""
+
+    pages_per_step: int      # pages a copied block holds
+    grid: tuple[int, ...]    # one step a batch row
+    vmem_bytes: int          # both buffers of the K and V blocks
+
+
+def paged_decode_geometry(
+    *, batch: int, kv_heads: int, n_pages: int, page_size: int,
+    head_dim: int, kv_itemsize: int,
+) -> PagedDecodeGeometry:
+    """``pages_per_step`` and the grid, from the shapes alone.
+
+    A copied page holds ALL kv heads (the pool is ``[P, Hkv, page, D]``:
+    a page's heads are one contiguous slab), so the grid has no kv head
+    dimension, and a row's blocks are a loop inside its one grid step,
+    over the live ones only. A block takes enough pages to cover
+    ``PAGED_STEP_POSITIONS`` key positions (8 pages of 64), at most the
+    row's pages, and fewer until both buffers of the K and V blocks fit
+    ``PAGED_VMEM_BUDGET`` (one page of every kv head is the least).
+    """
+    page_bytes = 2 * kv_heads * page_size * head_dim * kv_itemsize  # K and V
+    pps = min(n_pages, max(1, PAGED_STEP_POSITIONS // page_size))
+    while pps > 1 and 2 * pps * page_bytes > PAGED_VMEM_BUDGET:
+        pps -= 1
+    return PagedDecodeGeometry(
+        pages_per_step=pps, grid=(batch,), vmem_bytes=2 * pps * page_bytes,
+    )
+
+
+def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
+                         cfg: _DecodeConfig, n_pages: int):
+    """Grid step ``bi`` attends row ``bi``: all kv heads, the row's live
+    pages only (from the window's floor to the last query's page), a
+    block of ``cfg.pages_per_step`` pages at a time. While a block is
+    attended the next one (the row's, or the next row's first) is on
+    its way into the other buffer. Same arithmetic as
+    :func:`_decode_kernel`: one online-softmax update a block of keys,
+    in position order; a block's pages that were not copied are past
+    the last query and masked by position."""
+    ks_ref = vs_ref = None
+    if cfg.quant:
+        (ks_ref, vs_ref), refs = refs[:2], refs[2:]
+    pools, (o_ref, lse_ref), bufs = refs[:2], refs[2:4], refs[4:6]
+    sems, slot_ref, m_ref, l_ref, acc_ref = refs[6:]
+    page, pps = cfg.block_kv, cfg.pages_per_step
+    block = pps * page
+    bi = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+
+    def live_pages(row):
+        """(first live page, live pages) of a row: pages wholly past the
+        last query, or at or below the first query's window floor, are
+        never copied."""
+        start = offs_ref[row]
+        last = jnp.minimum((start + (cfg.t - 1)) // page, n_pages - 1)
+        first = 0
+        if cfg.window is not None:
+            # on a block's edge, so that every block starts on one
+            first = jnp.maximum(start - cfg.window + 1, 0) // block * pps
+        return first, last - first + 1
+
+    def page_copy(pool, buf, pid, j, slot, i):
+        return pltpu.make_async_copy(
+            pool.at[pid],
+            buf.at[slot, :, pl.ds(pl.multiple_of(j * page, page), page), :],
+            sems.at[slot, i],
+        )
+
+    def start_copies(row, first_page, count, slot):
+        """``count`` pages of ``row`` from ``first_page`` on, into
+        buffer ``slot``."""
+        def one_page(j, carry):
+            pid = pt_ref[row * n_pages + first_page + j]
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                page_copy(pool, buf, pid, j, slot, i).start()
+            return carry
+
+        jax.lax.fori_loop(0, count, one_page, None)
+
+    def wait_copies(count, slot):
+        def one_page(j, carry):
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                # a wait counts a page's bytes, whichever page they were
+                page_copy(pool, buf, 0, 0, slot, i).wait()
+            return carry
+
+        jax.lax.fori_loop(0, count, one_page, None)
+
+    first, n_live = live_pages(bi)
+    n_blocks = pl.cdiv(n_live, pps)
+    nxt_row = jnp.minimum(bi + 1, n_rows - 1)
+    nxt_row_first, nxt_row_live = live_pages(nxt_row)
+
+    @pl.when(bi == 0)
+    def _first_row():
+        slot_ref[0] = 0
+        # a buffer's unwritten tail is masked by position, but 0 x NaN
+        # in the value dot is NaN: no buffer starts with arbitrary bits
+        bufs[1][...] = jnp.zeros_like(bufs[1])
+        start_copies(0, first, jnp.minimum(n_live, pps), 0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_BIG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    start = offs_ref[bi]
+
+    def attend(slot, k_lo):
+        q = q_ref[0].astype(jnp.float32)       # [Hkv, rows_pad, D]
+        k = bufs[0][slot].astype(jnp.float32)  # [Hkv, block, D]
+        v = bufs[1][slot].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * cfg.scale  # [Hkv, rows_pad, block]
+        if cfg.quant:
+            # int8 keys: a slot's scale multiplies its column of
+            # scores (row [Hkv, 1, block] of the row's gathered scales),
+            # in float32 like the rest; the values' scales meet p below
+            s = s * ks_ref[0, :, pl.ds(k_lo // block, 1), :]
+
+        rp = s.shape[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (rp, block), 0)
+        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (rp, block), 1)
+        # row r = (head-in-group, token i) flattened as ig·T + i
+        q_pos = start + jax.lax.rem(row, cfg.t)
+        mask = (k_pos < cfg.s_len) & (k_pos <= q_pos) & (row < cfg.rows)
+        if cfg.window is not None:
+            mask &= k_pos > q_pos - cfg.window
+        mask = mask[None]
+        s = jnp.where(mask, s, NEG_BIG)
+
+        m_prev = m_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # gated by the mask, not just the sentinel (see _decode_kernel):
+        # fully masked rows keep l at 0 and finalize to exact zeros
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_ref[:, :, :1] + p.sum(axis=2, keepdims=True)
+        if cfg.quant:
+            p = p * vs_ref[0, :, pl.ds(k_lo // block, 1), :]
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    def attend_block(ib, carry):
+        slot = slot_ref[0]
+        block_first = first + ib * pps
+        count = jnp.minimum(n_live - ib * pps, pps)
+        # the block after this one: the row's next, else the next row's first
+        in_row = ib + 1 < n_blocks
+        nxt_first = jnp.where(in_row, block_first + pps, nxt_row_first)
+        nxt_count = jnp.minimum(
+            jnp.where(in_row, n_live - (ib + 1) * pps, nxt_row_live), pps
+        )
+
+        @pl.when(in_row | (bi + 1 < n_rows))
+        def _prefetch():
+            start_copies(jnp.where(in_row, bi, nxt_row), nxt_first,
+                         nxt_count, 1 - slot)
+
+        wait_copies(count, slot)
+
+        attend(slot, block_first * page)
+        slot_ref[0] = 1 - slot
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, attend_block, None)
+
+    m = m_ref[:, :, :1]
+    l = l_ref[:, :, :1]
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 # d9d-lint: disable=D9D001 — standalone-use decorator; serving traces this inside the tracked serve/step program (a TrackedJit cannot be called under a trace)
@@ -202,67 +384,76 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     """``q_rows [B, Hkv, rows_pad, D]`` vs page pools
     ``k/v [P, Hkv, page_size, D]`` gathered through
     ``page_table [B, n_pages]`` → same outputs as :func:`_decode_call`
-    on the contiguous equivalent. The kv-block index map generalizes
-    from ``block = ki`` to ``block = page_table[bi, ki]`` — the paging
-    claim in one line: the kernel needs a different INDEX, not a
-    different algorithm. ``block_kv == page_size`` by construction.
+    on the contiguous equivalent: a different INDEX, not a different
+    algorithm. The pools stay in HBM; the kernel copies a row's live
+    pages itself, ``cfg.pages_per_step`` a block
+    (:func:`paged_decode_geometry`), so a page costs a copy only while
+    it is live and a grid step moves a block of pages, not one.
+    ``cfg.block_kv`` is the page size.
 
     ``cfg.quant``: k/v pools are int8 and ``k/v_scale [P, Hkv, ps]``
-    carry the per-slot dequantization scales — reshaped to a trailing
-    unit lane and streamed through the SAME gathering index map as
-    their pools (a scale page is just a narrower page), rescaled in
-    the kernel's existing in-VMEM f32 accumulation."""
+    carry the per-slot dequantization scales. A row's scales are
+    gathered here through the same table into lane-dense rows, one a
+    block of keys, and meet the scores and the probabilities in the
+    kernel's float32 math."""
     b, hkv, rp, d = q_rows.shape
     n_pages = page_table.shape[1]
+    block = cfg.pages_per_step * cfg.block_kv
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, cfg.block_kv, d),
-        lambda bi, hi, ki, offs, pt: (pt[bi, ki], hi, 0, 0),
-    )
-    scale_specs, scale_bufs = (), ()
+    scale_specs, scale_rows = [], ()
     if cfg.quant:
-        scale_specs = (
-            pl.BlockSpec((1, 1, cfg.block_kv, 1),
-                         lambda bi, hi, ki, offs, pt: (pt[bi, ki], hi, 0, 0)),
-        ) * 2
-        scale_bufs = (k_scale[..., None], v_scale[..., None])
+        # a row's scales, gathered here: [B, Hkv, blocks, block], one
+        # lane-dense row a block of keys (3 % of the int8 bytes)
+        pps = cfg.pages_per_step
+        n_blocks = pl.cdiv(n_pages, pps)
+        table = jnp.pad(page_table, ((0, 0), (0, n_blocks * pps - n_pages)))
+
+        def rows(scale):  # [P, Hkv, page] -> [B, Hkv, blocks, block]
+            g = scale[table].reshape(b, n_blocks, pps, hkv, cfg.block_kv)
+            return g.transpose(0, 3, 1, 2, 4).reshape(b, hkv, n_blocks, block)
+
+        scale_rows = (rows(k_scale), rows(v_scale))
+        scale_specs = [pl.BlockSpec(
+            (1, hkv, n_blocks, block), lambda bi, offs, pt: (bi, 0, 0, 0),
+        )] * 2
+
+    def row_spec(width):
+        return pl.BlockSpec((1, hkv, rp, width),
+                            lambda bi, offs, pt: (bi, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # offsets, page_table
-        grid=(b, hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, rp, d),
-                         lambda bi, hi, ki, offs, pt: (bi, hi, 0, 0)),
-            kv_spec,
-            kv_spec,
-            *scale_specs,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, rp, d),
-                         lambda bi, hi, ki, offs, pt: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, rp, 1),
-                         lambda bi, hi, ki, offs, pt: (bi, hi, 0, 0)),
-        ],
+        num_scalar_prefetch=2,  # offsets, the page table (flat: SMEM pads rows)
+        grid=(b,),
+        in_specs=[row_spec(d), *scale_specs]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_specs=[row_spec(d), row_spec(1)],
         scratch_shapes=[
-            pltpu.VMEM((rp, LANES), jnp.float32),
-            pltpu.VMEM((rp, LANES), jnp.float32),
-            pltpu.VMEM((rp, d), jnp.float32),
+            pltpu.VMEM((2, hkv, block, d), k_pool.dtype),
+            pltpu.VMEM((2, hkv, block, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),  # the buffer the next block waits on
+            pltpu.VMEM((hkv, rp, LANES), jnp.float32),
+            pltpu.VMEM((hkv, rp, LANES), jnp.float32),
+            pltpu.VMEM((hkv, rp, d), jnp.float32),
         ],
     )
     o, lse = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, cfg=cfg),
+        functools.partial(_paged_decode_kernel, cfg=cfg, n_pages=n_pages),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rp, d), q_rows.dtype),
             jax.ShapeDtypeStruct((b, hkv, rp, 1), jnp.float32),
         ],
+        # rows in order: a row starts the next row's first copies
         compiler_params=(
             None if cfg.interpret else pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
+                dimension_semantics=("arbitrary",)
             )
         ),
         interpret=cfg.interpret,
-    )(offsets, page_table, q_rows, k_pool, v_pool, *scale_bufs)
+        # the name holds the geometry: a trace says which tiling ran
+        name=f"paged_decode_p{cfg.pages_per_step}",
+    )(offsets, page_table.reshape(-1), q_rows, *scale_rows, k_pool, v_pool)
     return o, lse[..., 0]
 
 
@@ -361,20 +552,21 @@ def flash_decode_attention(
     passing custom validity get the guarded-softmax behavior.
 
     PAGED mode (``page_table [B, n_pages]`` set): ``k/v`` are page
-    POOLS ``[P, Hkv, page_size, D]`` and row ``b``'s logical kv block
-    ``ki`` streams from pool page ``page_table[b, ki]`` — the block
-    index map gathers page ids instead of assuming ``page == ki``
-    (``block_kv`` is forced to the page size). Everything else —
-    per-row ``start``, whole-block skip, windows, sinks, the online
-    softmax — is unchanged, which is exactly why paging is an indexing
-    generalization of this kernel rather than a new one. ``kv_valid``
-    does not compose with paging (the serving loop never passes it).
+    POOLS ``[P, Hkv, page_size, D]`` and row ``b``'s logical page ``p``
+    lives in pool page ``page_table[b, p]``. A grid step is one row:
+    it copies the row's live pages, a block of pages at a time
+    (:func:`paged_decode_geometry`; ``block_kv`` does not apply), all
+    kv heads of a page in one copy, one online-softmax update a block.
+    Everything else — per-row ``start``, pages past the row's last
+    query never touched, windows, sinks, the online softmax — is
+    unchanged. ``kv_valid`` does not compose with paging (the serving
+    loop never passes it).
 
     QUANTIZED paged mode (``k_scale``/``v_scale [P, Hkv, page_size]``
     set): the pools are int8 and each slot's feature vector carries a
-    f32 scale; the scale pools stream through the same gathering index
-    map (one narrow block per page) and the kernel widens
-    ``int8 * scale`` inside its existing f32 accumulation — HBM
+    f32 scale; a row's scales are gathered through the same page table
+    and the kernel applies them in its float32 math (a key's scale to
+    its column of scores, a value's to its probabilities) — HBM
     traffic per slot drops to D int8 bytes + one f32 scale. Note int8
     TPU tiles are (32, 128): on-chip (non-interpret) runs need
     ``page_size >= 32``; the CPU interpret tier has no such floor.
@@ -414,6 +606,10 @@ def flash_decode_attention(
             raise ValueError("k_scale and v_scale must be set together")
         page_size = k_cache.shape[2]
         n_pages = page_table.shape[1]
+        geo = paged_decode_geometry(
+            batch=b, kv_heads=hkv, n_pages=n_pages, page_size=page_size,
+            head_dim=d, kv_itemsize=k_cache.dtype.itemsize,
+        )
         cfg = _DecodeConfig(
             scale=softmax_scale if softmax_scale is not None else d**-0.5,
             window=window_size,
@@ -425,6 +621,7 @@ def flash_decode_attention(
             has_valid=False,
             interpret=interpret,
             quant=k_scale is not None,
+            pages_per_step=geo.pages_per_step,
         )
         o, lse = _paged_decode_call(
             cfg, q_rows, k_cache, v_cache, offsets,

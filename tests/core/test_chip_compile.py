@@ -222,24 +222,53 @@ def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
-def test_flash_decode_multi_query_group_of_20(topo):
-    """Jamba2-3B's attention layers in the serving cell: 20 query heads
-    on ONE key/value head (24 padded rows a kernel instance) against a
-    paged pool of 256 slots x 1,152 positions."""
-    from d9d_tpu.ops.attention.pallas_decode import flash_decode_attention
+@pytest.mark.parametrize("pools", ["bf16", "int8"])
+@pytest.mark.parametrize(
+    "slots,hq,hkv,s",
+    [(64, HQ, HKV, 576), (256, 20, 1, 1152)],
+    ids=["qwen3-64x4x9-group8", "jamba-256x1x18-group20"],
+)
+def test_paged_decode_at_the_serving_cells(topo, slots, hq, hkv, s, pools):
+    """The paged decode kernel at the two serving cells' geometries:
+    Qwen3-30B-A3B (64 slots, 32 query heads on 4, 9 pages of 64 a row)
+    and Jamba2-3B's attention layers (256 slots, 20 query heads on ONE
+    key/value head, 24 padded rows, 18 pages a row). One grid step a
+    row attends blocks of several pages, all kv heads of a page in one
+    copy."""
+    from d9d_tpu.ops.attention.pallas_decode import (
+        flash_decode_attention,
+        paged_decode_geometry,
+    )
 
     sds = _on(SingleDeviceSharding(topo.devices[0]))
-    b, s = 256, 1152
-    pool = sds((b * (s // PAGE) + 1, 1, PAGE, D), BF16)
+    quant = pools == "int8"
+    n_pages = s // PAGE
+    geo = paged_decode_geometry(
+        batch=slots, kv_heads=hkv, n_pages=n_pages, page_size=PAGE,
+        head_dim=D, kv_itemsize=1 if quant else 2,
+    )
+    assert geo.pages_per_step > 1
+    assert int(np.prod(geo.grid)) <= slots * hkv * -(
+        -n_pages // geo.pages_per_step
+    )
+    pool = sds((slots * n_pages + 1, hkv, PAGE, D), jnp.int8 if quant else BF16)
+    kwargs = {"page_table": sds((slots, n_pages), jnp.int32)}
+    if quant:
+        scale = sds((slots * n_pages + 1, hkv, PAGE), jnp.float32)
+        kwargs |= {"k_scale": scale, "v_scale": scale}
     compiled = jax.jit(
-        lambda q, k, v, start, page_table: flash_decode_attention(
-            q, k, v, start=start, page_table=page_table, interpret=False
+        lambda q, k, v, start, **kw: flash_decode_attention(
+            q, k, v, start=start, interpret=False, **kw
         )
     ).lower(
-        sds((b, 1, 20, D), BF16), pool, pool, sds((b,), jnp.int32),
-        sds((b, s // PAGE), jnp.int32),
+        sds((slots, 1, hq, D), BF16), pool, pool, sds((slots,), jnp.int32),
+        **kwargs,
     ).compile()
     assert _pallas_calls(compiled) == 1
+    # the kernel's name holds the geometry, for the traces
+    assert f"paged_decode_p{geo.pages_per_step}/pallas_call" in compiled.as_text()
+    # the int8 row scales are gathered, not the whole scale pool relaid
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
 def test_mamba_step_at_jamba2_3b_widths(topo):

@@ -7,6 +7,8 @@ positions, windows, sinks, GQA grouping, ragged key validity, and
 non-lane-aligned cache lengths. Runs in Pallas interpret mode on CPU.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +16,11 @@ import pytest
 
 from d9d_tpu.nn.attention import _decode_slot_mask
 from d9d_tpu.ops.attention.eager import eager_sdpa
-from d9d_tpu.ops.attention.pallas_decode import flash_decode_attention
+from d9d_tpu.ops.attention import pallas_decode
+from d9d_tpu.ops.attention.pallas_decode import (
+    flash_decode_attention,
+    paged_decode_geometry,
+)
 
 
 def _mk(b, t, hq, hkv, d, s, seed=0):
@@ -192,6 +198,163 @@ def test_paged_parity_sinks_and_gqa():
             kv_valid=jnp.ones((b, pt.shape[1] * ps), jnp.int32),
             interpret=True,
         )
+
+
+# (page_size, n_pages, key positions a block) -> pages a block
+_BLOCK_GEOMETRIES = {
+    "even-4+4": ((8, 8, 32), 4),
+    "tail-4+3": ((8, 7, 32), 4),        # n_pages no multiple of the block
+    "tail-2+2+1": ((16, 5, 32), 2),     # 3 blocks, the last of one page
+    "tail-6+3": ((8, 9, 48), 6),
+    "one-block": ((16, 4, 512), 4),     # every page in one block
+    "qwen3-9x64": ((64, 9, 512), 8),    # the Qwen3 serving cell: 8 + 1
+    "jamba-18x64": ((64, 18, 512), 8),  # the Jamba serving cell: 8 + 8 + 2
+}
+_GROUPS = {"g8-on-4": (32, 4), "g20-on-1": (20, 1)}
+
+
+def _block_case(monkeypatch, geometry, heads, t, d=16, seed=0):
+    """q, a contiguous cache and five rows' starts that meet every edge
+    of the block geometry: position 0, a row that ends inside a page
+    with fewer live pages than one block, the last position of block 0,
+    the first of block 1 (a block's edge), and every page live."""
+    (ps, n_pages, block), want = _BLOCK_GEOMETRIES[geometry]
+    monkeypatch.setattr(pallas_decode, "PAGED_STEP_POSITIONS", block)
+    hq, hkv = _GROUPS[heads]
+    s = ps * n_pages
+    geo = paged_decode_geometry(
+        batch=5, kv_heads=hkv, n_pages=n_pages, page_size=ps, head_dim=d,
+        kv_itemsize=4,
+    )
+    pps = geo.pages_per_step
+    assert pps == want
+    assert geo.grid == (5,)
+    edge = min(pps * ps, s - t)
+    starts = jnp.asarray(
+        [0, ps + 3, max(edge - t, 0), edge, s - t], jnp.int32
+    )
+    q, k, v = _mk(5, t, hq, hkv, d, s, seed=seed)
+    return q, k, v, starts, ps
+
+
+def _assert_paged_equals_contiguous(q, k, v, starts, ps, **kwargs):
+    want = flash_decode_attention(
+        q, k, v, start=starts, interpret=True, block_kv=ps, **kwargs
+    )
+    pool_k, pool_v, pt = _paginate(k, v, ps, seed=4)
+    got = flash_decode_attention(
+        q, pool_k, pool_v, start=starts, page_table=pt, interpret=True,
+        **kwargs,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("window", [None, 7])
+@pytest.mark.parametrize("heads", list(_GROUPS))
+@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
+def test_paged_block_parity(monkeypatch, geometry, heads, window, t):
+    """A grid step attends a block of pages: the paged result equals the
+    contiguous call's on the gathered view, over the geometries the rule
+    produces and the rows that meet their edges."""
+    q, k, v, starts, ps = _block_case(monkeypatch, geometry, heads, t)
+    _assert_paged_equals_contiguous(q, k, v, starts, ps, window_size=window)
+
+
+@pytest.mark.parametrize("heads", list(_GROUPS))
+@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
+def test_paged_block_parity_sinks(monkeypatch, geometry, heads):
+    q, k, v, starts, ps = _block_case(monkeypatch, geometry, heads, 1, seed=2)
+    sinks = jnp.asarray(np.random.RandomState(5).randn(q.shape[2]), jnp.float32)
+    _assert_paged_equals_contiguous(q, k, v, starts, ps, sinks=sinks)
+
+
+@pytest.mark.parametrize("window", [None, 7])
+@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
+def test_paged_block_parity_int8(monkeypatch, geometry, window):
+    """int8 pools with scale pages gathered by the same rule: the
+    kernel's in-VMEM ``int8 * scale`` equals the contiguous call on the
+    widened view."""
+    from d9d_tpu.nn.attention import _quantize_rows
+
+    q, k, v, starts, ps = _block_case(
+        monkeypatch, geometry, "g8-on-4", 1, seed=3
+    )
+    (k8, ks), (v8, vs) = _quantize_rows(k), _quantize_rows(v)
+    wide_k = k8.astype(jnp.float32) * ks[..., None]
+    wide_v = v8.astype(jnp.float32) * vs[..., None]
+    want = flash_decode_attention(
+        q, wide_k, wide_v, start=starts, window_size=window,
+        interpret=True, block_kv=ps,
+    )
+    pool_k, pool_v, pt = _paginate(k8, v8, ps, seed=8)
+    # a scale pool is a pool of [Hkv, page, 1] pages under the same table
+    pool_ks, pool_vs, _ = _paginate(ks[..., None], vs[..., None], ps, seed=8)
+    got = flash_decode_attention(
+        q, pool_k.astype(jnp.int8), pool_v.astype(jnp.int8), start=starts,
+        window_size=window, page_table=pt, k_scale=pool_ks[..., 0],
+        v_scale=pool_vs[..., 0], interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_paged_dead_tail_on_the_garbage_page():
+    """The serving loop maps a row's unallocated tail to page 0: those
+    pages are skipped by position, whatever page 0 holds."""
+    b, t, hq, hkv, d, ps, n_pages = 2, 1, 8, 2, 16, 64, 9
+    q, k, v = _mk(b, t, hq, hkv, d, ps * n_pages, seed=31)
+    starts = jnp.asarray([70, 200], jnp.int32)
+    pool_k, pool_v, pt = _paginate(k, v, ps, seed=9)
+    pool_k = pool_k.at[0].set(1e4)
+    pool_v = pool_v.at[0].set(jnp.nan)
+    pt = pt.at[0, 2:].set(0).at[1, 4:].set(0)
+    want = flash_decode_attention(
+        q, k, v, start=starts, interpret=True, block_kv=ps
+    )
+    got = flash_decode_attention(
+        q, pool_k, pool_v, start=starts, page_table=pt, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "shapes,pages_per_step",
+    [
+        # the two serving cells: blocks of 8 pages of 64
+        (dict(batch=64, kv_heads=4, n_pages=9, page_size=64), 8),
+        (dict(batch=256, kv_heads=1, n_pages=18, page_size=64), 8),
+        # tiny pages still cover 512 positions a block
+        (dict(batch=4, kv_heads=2, n_pages=6, page_size=8), 6),
+        (dict(batch=4, kv_heads=2, n_pages=100, page_size=16), 32),
+        # a long row; 8 kv heads are 4 MiB exactly
+        (dict(batch=8, kv_heads=4, n_pages=512, page_size=64), 8),
+        (dict(batch=8, kv_heads=8, n_pages=512, page_size=64), 8),
+        # float32 pools: the VMEM budget cuts the block
+        (dict(batch=8, kv_heads=8, n_pages=64, page_size=64,
+              kv_itemsize=4), 4),
+        (dict(batch=8, kv_heads=32, n_pages=64, page_size=64,
+              kv_itemsize=4), 1),
+        # pages of 256: two a block
+        (dict(batch=8, kv_heads=2, n_pages=16, page_size=256), 2),
+    ],
+)
+def test_paged_geometry_from_shapes(shapes, pages_per_step):
+    shapes = {"head_dim": 128, "kv_itemsize": 2, **shapes}
+    geo = paged_decode_geometry(**shapes)
+    assert geo.pages_per_step == pages_per_step
+    # one grid step a row, whatever its pages: never more than a step a
+    # block of every kv head
+    assert geo.grid == (shapes["batch"],)
+    assert math.prod(geo.grid) <= shapes["batch"] * shapes["kv_heads"] * -(
+        -shapes["n_pages"] // geo.pages_per_step
+    )
+    assert geo.vmem_bytes <= pallas_decode.PAGED_VMEM_BUDGET
 
 
 def test_parity_under_jit_traced_start():
