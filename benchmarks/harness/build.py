@@ -168,9 +168,13 @@ def dtype_of(name: str):
 
 
 def reference_module(config: dict):
-    return importlib.import_module(
+    """The family's plain reference: ``logits(params, hf, tokens)`` and
+    the ``loss(params, hf, tokens, labels)`` it would be trained on."""
+    reference = importlib.import_module(
         f"benchmarks.references.{config['reference']}"
     )
+    assert callable(reference.logits) and callable(reference.loss)
+    return reference
 
 
 def unboxed(params):

@@ -19,12 +19,12 @@ step 2^-4 against bf16's 2^-8) reads sixteen times the bf16 figure,
 above 0.04; a wrong mask, scale, position or expert moves logits by
 their own size and reads near 1.
 
-``LOSS_TOL``: the sample's mean next-token loss, program (through its
-fused cross-entropy) against reference. At seeded init the predictions
-are near uniform (loss about ln(vocab)), per-token errors are of order
-1e-2 and average out over the sample; the v5e gave at most 5e-5. 3e-3
-leaves room for a seed and a deeper stack and is far below what a
-mis-wired label or shift does (order 1).
+``LOSS_TOL``: the sample's loss, program (through the Trainer's task
+and its fused cross-entropy) against the reference's own ``loss``. At
+seeded init the predictions are near uniform (loss about ln(vocab)),
+per-token errors are of order 1e-2 and average out over the sample; the
+v5e gave at most 5e-5. 3e-3 leaves room for a seed and a deeper stack
+and is far below what a mis-wired label or shift does (order 1).
 
 ``MULTICHIP_LOSS_TOL``: the four-chip forward loss against the one-chip
 forward loss of the same weights and batch. Only the order of sums
@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from d9d_tpu.loop import CausalLMTask
+
 from . import build
 
 LOGITS_REL_RMS_TOL = 0.015
@@ -59,15 +61,6 @@ def rel_rms(got: np.ndarray, want: np.ndarray) -> float:
     return float(np.sqrt(error / np.mean(np.square(want), dtype=np.float64)))
 
 
-def mean_loss(logits: np.ndarray, labels: np.ndarray) -> float:
-    """Mean next-token cross-entropy from logits, on the host."""
-    lg = np.asarray(logits, np.float32)
-    lg = lg - lg.max(axis=-1, keepdims=True)
-    log_norm = np.log(np.exp(lg).sum(axis=-1, dtype=np.float64))
-    picked = np.take_along_axis(lg, labels[..., None], -1)[..., 0]
-    return float(np.mean(log_norm - picked, dtype=np.float64))
-
-
 def reference_logits(reference, variables, hf: dict, tokens) -> np.ndarray:
     params = build.unboxed(variables["params"])
     fn = jax.jit(lambda p, t: reference.logits(p, hf, t))
@@ -75,28 +68,37 @@ def reference_logits(reference, variables, hf: dict, tokens) -> np.ndarray:
 
 
 def training_reference(reference, variables, hf: dict, sample) -> dict:
-    """What the reference says of the sample (``[rows, n + 1]`` ids)."""
+    """What the reference says of the sample (``[rows, n + 1]`` ids). Its
+    loss is the reference's own ``loss(params, hf, tokens, labels)``, so
+    that a family that trains on more than the next-token loss (a
+    multi-token-prediction term) is held to all of it. Logits and loss
+    come from one program: the compiler keeps one of the two forward
+    passes it is given."""
     tokens, labels = sample[:, :-1], sample[:, 1:]
-    logits = reference_logits(reference, variables, hf, tokens)
-    return {"logits": logits, "loss": mean_loss(logits, labels)}
+    logits, loss = jax.jit(lambda p, t, l: (
+        reference.logits(p, hf, t), reference.loss(p, hf, t, l)
+    ))(build.unboxed(variables["params"]), jnp.asarray(tokens),
+       jnp.asarray(labels))
+    return {"logits": np.asarray(logits), "loss": float(loss)}
 
 
-def training_system(module, variables, sample) -> dict:
+def training_system(module, variables, sample, task=None) -> dict:
     """The program on the same sample: logits through its ``logits``
-    method, and the loss through the path it trains with (the fused
-    cross-entropy inside ``__call__``)."""
-    tokens, labels = sample[:, :-1], sample[:, 1:]
-    rows, n = tokens.shape
-    positions = np.broadcast_to(np.arange(n, dtype=np.int32), (rows, n))
+    method, and the loss through ``task``, the one the Trainer trains
+    with (the next-token task where there is no Trainer): ``loss_sum /
+    weight`` of its ``loss_fn`` on the sample, so that whatever the task
+    adds to the next-token loss is compared."""
+    task = task or CausalLMTask()
+    mb = task.prepare_batch({"input_ids": sample})
     logits = jax.jit(
         lambda v, t, p: module.apply(v, t, p, method="logits")
-    )(variables, tokens, positions)
-    per_token = jax.jit(
-        lambda v, t, p, l: module.apply(v, t, p, l, mutable=["moe_stats"])[0]
-    )(variables, tokens, positions, labels)
+    )(variables, mb["tokens"], mb["positions"])
+    loss_sum, weight, _ = jax.jit(
+        lambda v, mb: task.loss_fn(module, v, mb, jax.random.PRNGKey(0))
+    )(variables, mb)  # no dropout: the key is unused
     return {
         "logits": np.asarray(logits, np.float32),
-        "loss": float(np.asarray(per_token, np.float64).mean()),
+        "loss": float(loss_sum) / float(weight),
     }
 
 

@@ -14,11 +14,54 @@ def is_mla(cfg: dict) -> bool:
 
 
 def n_routed_experts(cfg: dict) -> int:
+    """Routed experts held here: the file's own count."""
     return cfg.get("num_experts") or cfg["n_routed_experts"]
+
+
+def published_experts(cfg: dict) -> int:
+    """Routed experts of the deployment, which the router scores: the
+    ``share`` block's published count where this chip holds a share of
+    a layer (``reduced`` lists the key), else the count held."""
+    published = cfg.get("share", {}).get("published", {})
+    return (
+        published.get("num_experts") or published.get("n_routed_experts")
+        or n_routed_experts(cfg)
+    )
+
+
+def routed_per_token(cfg: dict) -> float:
+    """Of a token's top-k experts, how many are held here on average:
+    ``top_k x held / published``. The rest would be computed on the
+    chips that share the layer, and neither program nor reference
+    computes them."""
+    held = cfg["num_experts_per_tok"] * n_routed_experts(cfg)
+    return held / published_experts(cfg)
 
 
 def n_dense_layers(cfg: dict) -> int:
     return min(cfg.get("first_k_dense_replace", 0), cfg["num_hidden_layers"])
+
+
+def n_sparse_layers(cfg: dict) -> int:
+    return cfg["num_hidden_layers"] - n_dense_layers(cfg)
+
+
+def n_mtp_modules(cfg: dict) -> int:
+    """Multi-token-prediction modules (DeepSeek-V3, arXiv:2412.19437
+    section 2.2): each is one more block of the kind that follows the
+    dense layers, a ``2d x d`` merge of the hidden state with the next
+    token's embedding, and one more pass through the shared head. They
+    are trained, so only the training costs count them: a decode chunk
+    runs the stack alone, whatever the file states."""
+    return cfg.get("num_nextn_predict_layers") or 0
+
+
+def n_trained_attention_layers(cfg: dict) -> int:
+    return cfg["num_hidden_layers"] + n_mtp_modules(cfg)
+
+
+def n_trained_sparse_layers(cfg: dict) -> int:
+    return n_sparse_layers(cfg) + n_mtp_modules(cfg)
 
 
 def attention_matmul_params(cfg: dict) -> int:
@@ -26,9 +69,11 @@ def attention_matmul_params(cfg: dict) -> int:
     d, h = cfg["hidden_size"], cfg["num_attention_heads"]
     if is_mla(cfg):
         d_qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
-        rank = cfg["kv_lora_rank"]
+        rank, q_rank = cfg["kv_lora_rank"], cfg.get("q_lora_rank")
+        # q compression multiplies d x r_q and r_q x (h x d_qk) weights
+        query = d * q_rank + q_rank * h * d_qk if q_rank else d * h * d_qk
         return (
-            d * h * d_qk
+            query
             + d * (rank + cfg["qk_rope_head_dim"])
             + rank * h * (cfg["qk_nope_head_dim"] + cfg["v_head_dim"])
             + h * cfg["v_head_dim"] * d
@@ -37,36 +82,40 @@ def attention_matmul_params(cfg: dict) -> int:
     return d * h * hd + 2 * d * hkv * hd + h * hd * d
 
 
-def active_matmul_params(cfg: dict) -> int:
-    """Weights one token is multiplied by: attention projections, router,
-    its top-k experts and the shared ones, dense layers, and the output
-    head. The embedding is a lookup and is not counted."""
+def active_matmul_params(cfg: dict) -> float:
+    """Weights one token is multiplied by on this chip in a training
+    step: attention projections, the router at its published width,
+    those of its top-k experts that are held here and the shared ones,
+    dense layers, the output head over the vocabulary held, and per MTP
+    module a block, its merge and a second pass through the head. The
+    embedding is a lookup and is not counted."""
     d = cfg["hidden_size"]
-    layers, dense = cfg["num_hidden_layers"], n_dense_layers(cfg)
+    dense, mtp = n_dense_layers(cfg), n_mtp_modules(cfg)
     per_expert = 3 * d * cfg["moe_intermediate_size"]
     sparse = (
-        d * n_routed_experts(cfg)
-        + (cfg["num_experts_per_tok"] + cfg.get("n_shared_experts", 0))
+        d * published_experts(cfg)
+        + (routed_per_token(cfg) + cfg.get("n_shared_experts", 0))
         * per_expert
     )
     return (
-        layers * attention_matmul_params(cfg)
+        n_trained_attention_layers(cfg) * attention_matmul_params(cfg)
         + dense * 3 * d * cfg["intermediate_size"]
-        + (layers - dense) * sparse
-        + d * cfg["vocab_size"]
+        + n_trained_sparse_layers(cfg) * sparse
+        + mtp * 2 * d * d
+        + (1 + mtp) * d * cfg["vocab_size"]
     )
 
 
 def attention_score_flops_per_token(cfg: dict, seq_len: int) -> float:
     """Forward FLOPs per token of QK^T and PV under a causal mask (a token
-    attends to half the sequence on average), all layers."""
+    attends to half the sequence on average), all trained layers."""
     h = cfg["num_attention_heads"]
     if is_mla(cfg):
         d_qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
         d_v = cfg["v_head_dim"]
     else:
         d_qk = d_v = cfg["head_dim"]
-    return cfg["num_hidden_layers"] * h * seq_len * (d_qk + d_v)
+    return n_trained_attention_layers(cfg) * h * seq_len * (d_qk + d_v)
 
 
 def train_flops_per_token(cfg: dict, seq_len: int) -> float:
@@ -80,11 +129,12 @@ def train_flops_per_token(cfg: dict, seq_len: int) -> float:
 def expert_mm_train(cfg: dict, tokens: int) -> dict:
     """Expert matmuls of ONE sparse layer in one training step, forward
     and backward: gate, up and down, each once forward and twice
-    backward (to the input and to the weight). Bytes: every expert's
-    weights read forward and backward and their gradient written, rows
-    in and out of each matmul."""
+    backward (to the input and to the weight). Rows: the routed pairs
+    that land on the experts held. Bytes: every held expert's weights
+    read forward and backward and their gradient written, rows in and
+    out of each matmul."""
     d, f = cfg["hidden_size"], cfg["moe_intermediate_size"]
-    rows = tokens * cfg["num_experts_per_tok"]
+    rows = tokens * routed_per_token(cfg)
     weights = n_routed_experts(cfg) * 3 * d * f
     flops = 3 * 2.0 * rows * 3 * d * f
     row_bytes = rows * (2 * d + 4 * f) * BF16  # x, g, u, h, y
@@ -97,9 +147,10 @@ def expert_mm_train(cfg: dict, tokens: int) -> dict:
 def expert_mm_decode(cfg: dict, slots: int, experts_touched: float) -> dict:
     """Expert matmuls of ONE sparse layer in one decode step over
     ``slots`` tokens. Bytes count only the experts the step's routing
-    touched (their three matrices once) plus the rows."""
+    touched (their three matrices once) plus the rows that land on the
+    experts held."""
     d, f = cfg["hidden_size"], cfg["moe_intermediate_size"]
-    rows = slots * cfg["num_experts_per_tok"]
+    rows = slots * routed_per_token(cfg)
     return {
         "flops": 2.0 * rows * 3 * d * f,
         "bytes": experts_touched * 3 * d * f * BF16
@@ -108,10 +159,11 @@ def expert_mm_decode(cfg: dict, slots: int, experts_touched: float) -> dict:
 
 
 def expected_experts_touched(cfg: dict, slots: int) -> float:
-    """Distinct experts hit by ``slots`` tokens x top-k draws when the
-    router is near uniform, as it is at seeded init."""
-    n = n_routed_experts(cfg)
-    return n * (1.0 - (1.0 - cfg["num_experts_per_tok"] / n) ** slots)
+    """Distinct held experts hit by ``slots`` tokens x top-k draws over
+    the published experts when the router is near uniform, as it is at
+    seeded init."""
+    miss = 1.0 - cfg["num_experts_per_tok"] / published_experts(cfg)
+    return n_routed_experts(cfg) * (1.0 - miss ** slots)
 
 
 def flash_train(cfg: dict, sequences: int, seq_len: int) -> dict:

@@ -1,6 +1,8 @@
 """What the per-layer readers of PR 25 share: the program's span timeline
 inside a window, registry spans placed on a trace by the clock anchor,
-device time by scope, and one scope for the expert matmuls' custom calls.
+device time by scope, one scope for the expert matmuls' custom calls,
+and which events of a trace are a kernel's: each by its own instruction
+in the compiled program that ran it.
 
 Arithmetic on plain data, like ``trace.py``: the program is only asked
 for its span timeline and for its own reading of the clock anchor, so
@@ -8,6 +10,7 @@ all of it is tested on hand-made and recorded data with no TPU.
 """
 
 import re
+import typing
 
 from . import trace as tr
 
@@ -26,6 +29,8 @@ PHASE_SPANS = ("serve/phase/", "train/phase/", "host/gc")
 # and gives them no op_name but their own
 RAGGED_CALL = re.compile(r"ragged-dot")
 RAGGED_SCOPE = "moe/experts/ragged_dot"
+# what the TPU compiler makes of a dot_general
+MATRIX_PRODUCTS = ("dot", "convolution")
 
 
 # -- the program's span timeline ----------------------------------------------
@@ -147,3 +152,137 @@ def with_expert_matmuls(scopes: dict, hlo_texts) -> dict:
         r"^\s*(?:ROOT )?%?(ragged-dot[\w.\-]*) = ", "\n".join(hlo_texts), re.M
     )
     return {**scopes, **dict.fromkeys(calls, RAGGED_SCOPE)}
+
+
+# -- a kernel's events, each by its own instruction -----------------------------
+
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?(%?[\w.\-]+ = .*)")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+RESULT_LIMIT = 160  # of a result type: a trace cuts an event's text short
+
+
+class Program(typing.NamedTuple):
+    """What one compiled program's text says of its instructions, by
+    name (a name is the program's own: ``fusion.9`` is another
+    instruction in every program)."""
+
+    module: str  # the HloModule's name: ``jit_step``
+    results: dict  # instruction -> its result type, cut to RESULT_LIMIT
+    scopes: dict  # instruction -> the ``op_name`` of its metadata
+    products: dict  # instruction -> scopes of the matrix products it holds
+
+
+def compiled_program(text: str) -> Program:
+    """One compiled program's instructions. A matrix product is a
+    ``dot`` or a ``convolution`` under its own ``op_name``, and a fusion
+    whose computation holds some (fusions nested in it included), under
+    each such product's ``op_name`` (a fusion's own is its root's, which
+    may be an activation fused in behind the product, or one of three
+    projections fused together) or else its own. A copy, a concatenation
+    or any other fusion is not one."""
+    module, results, scopes = "", {}, {}
+    inside: dict[str, list] = {}  # computation -> (name, opcode, scope, calls)
+    body = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            body = inside.setdefault(head[1], [])
+            continue
+        found = _INSTRUCTION.match(line) if body is not None else None
+        if not found:
+            named = _MODULE.match(line)
+            module = named[1] if named else module
+            continue
+        name, result, opcode = tr.instruction(found[1])
+        scope, calls = _OP_NAME.search(line), _CALLS.search(line)
+        results[name] = result[:RESULT_LIMIT]
+        if scope:
+            scopes[name] = scope[1]
+        if opcode == "fusion" or opcode in MATRIX_PRODUCTS:
+            body.append((name, opcode, scope[1] if scope else "",
+                         calls[1] if calls else None))
+
+    def products_of(opcode, scope, calls, seen=()) -> tuple:
+        if opcode in MATRIX_PRODUCTS:
+            return (scope,)
+        if not calls or calls in seen:
+            return ()
+        return tuple(
+            held or scope for _, *inner in inside.get(calls, ())
+            for held in products_of(*inner, seen=(*seen, calls))
+        )
+
+    products = {}
+    for body in inside.values():
+        for name, *rest in body:
+            held = products_of(*rest)
+            if held:
+                products[name] = held
+    return Program(module, results, scopes, products)
+
+
+def programs_that_ran(trace: dict, programs) -> dict[str, list]:
+    """Module of the trace (``jit_step(1444..)``: a name with its
+    fingerprint) -> the compiled programs it may be: those of that name
+    in which most of the module's events are found, each by its
+    instruction's name and result type. Two programs made from one
+    function (the serving chunk with and without admission) share a name
+    and number their fusions apart, so the name alone does not say which
+    ran; the events do, unless the two agree wherever it matters."""
+    seen: dict[str, set] = {}
+    for _, module, text, _ in tr.events_in_modules(trace):
+        if module is not None:
+            name, result, _ = tr.instruction(text)
+            seen.setdefault(module, set()).add((name, result[:RESULT_LIMIT]))
+    out = {}
+    for module, events in seen.items():
+        named = [p for p in programs if p.module == module.partition("(")[0]]
+        found = [sum(p.results.get(n) == r for n, r in events) for p in named]
+        out[module] = [p for p, n in zip(named, found) if n == max(found)]
+    return out
+
+
+class Ambiguous(ValueError):
+    """Two programs that a module may be disagree on an instruction."""
+
+
+def own_instruction(ran: dict, module_pattern: str, call=None, scope=None,
+                    product_scope=None):
+    """``take(text, module)`` for ``trace.event_seconds``: is this event
+    one of a kernel's? Only events of the modules matching
+    ``module_pattern`` are, and of those the ones whose own instruction
+    is named like ``call`` (a custom call the compiler names itself), or
+    carries a scope matching ``scope``, or is or holds a matrix product
+    (``compiled_program``) scoped ``product_scope``; the last two as the
+    program that ran the module (``programs_that_ran``) says. Never by
+    its operands' names: the activation that reads a ``ragged-dot``'s
+    result is not the kernel. Never a copy or a concatenation under a
+    product's scope: the serving cells concatenate ``gate|up`` every
+    chunk."""
+    module_rx = re.compile(module_pattern)
+    call_rx = re.compile(call) if call else None
+
+    def said_by(program: Program, name: str) -> bool:
+        return bool(
+            scope and re.search(scope, program.scopes.get(name, ""))
+            or product_scope and any(
+                re.search(product_scope, held)
+                for held in program.products.get(name, ())
+            )
+        )
+
+    def take(text: str, module) -> bool:
+        if module is None or not module_rx.search(module):
+            return False
+        name = tr.parse_op(text)[0]
+        if call_rx and call_rx.match(name):
+            return True
+        said = {said_by(p, name) for p in ran.get(module, ())}
+        if len(said) > 1:
+            raise Ambiguous(f"{name} of {module}")
+        return said == {True}
+
+    return take

@@ -15,7 +15,7 @@ import statistics
 
 import numpy as np
 
-from . import costs, manifest
+from . import costs, layers, manifest
 from . import trace as tr
 
 
@@ -28,6 +28,7 @@ class Run:
     device_kind: str
     trace: dict | None = None
     scopes: dict | None = None
+    programs: tuple = ()  # layers.compiled_program of each program traced
     notes: dict = dataclasses.field(default_factory=dict)
 
     @property
@@ -102,24 +103,20 @@ def train_mfu(run: Run):
     return 100.0 * per_token * train_rate(run) / run.peak.bf16_flops
 
 
-def phase_share(run: Run, phases, decile: int | None = None):
-    """The host's time in ``phases`` as a share of the window. Without
-    ``decile``: every step's time in them, summed, over the window's
-    seconds. The Trainer dispatches without waiting, so in most steps
-    the host is throttled by the device somewhere inside these phases
-    and that sum holds the wait. With ``decile``: that decile over the
-    window's steps of a step's sum, over the seconds per step; right
-    after each metric fetch the host runs free for a step or two, and
-    the lower decile is such a step: what the loop costs the host when
-    nothing holds it back."""
+def unthrottled_phase_share(run: Run, phases, decile: int):
+    """What the loop costs the host in ``phases`` when nothing holds it
+    back: that decile, over the window's steps, of a step's time in
+    them, over the window's seconds per step. The Trainer dispatches
+    without waiting, so in most steps the host is throttled by the
+    device somewhere inside these phases (their plain sum over the
+    window is pinned at 100 %); right after each metric fetch it runs
+    free for a step or two, and the lower decile is such a step."""
     o = run.observed
     names = {f"train/phase/{p}" for p in phases}
     per_step: dict[int, float] = {}
     for s in o.spans:
         if s.name in names and s.step is not None and s.step >= o.first_step:
             per_step[s.step] = per_step.get(s.step, 0.0) + s.dur_s
-    if decile is None:
-        return 100.0 * sum(per_step.values()) / o.window_s if per_step else None
     if len(per_step) < 10:
         return None
     own = statistics.quantiles(per_step.values(), n=10)[decile - 1]
@@ -184,14 +181,27 @@ def host_gap_per_chunk(run: Run, spans, module_pattern: str):
     return 1e3 * idle / chunks / max(len(run.trace["devices"]), 1)
 
 
-def kernel_roofline(run: Run, pattern: str, cost: str,
-                    module_pattern: str):
+def kernel_roofline(run: Run, cost: str, module_pattern: str, call=None,
+                    scope=None, product_scope=None):
     """A kernel's share of its roofline: the least time the chip could
     take for the work the cell's shapes define (per execution of the
-    step program, per device) over the kernel's device time."""
+    step program, per device) over the kernel's device time. The
+    metric's file says which events of the step program are the
+    kernel's, each taken by its own instruction
+    (``layers.own_instruction``): named like ``call``, under ``scope``,
+    or a matrix product under ``product_scope``. Nothing where two
+    programs the trace cannot tell apart disagree on one."""
     if not _traced(run):
         return None
-    measured = tr.op_seconds(run.trace, pattern, run.scopes)
+    take = layers.own_instruction(
+        layers.programs_that_ran(run.trace, run.programs),
+        module_pattern, call, scope, product_scope,
+    )
+    try:
+        measured = tr.event_seconds(run.trace, take)
+    except layers.Ambiguous as which:
+        run.notes[f"{cost}.ambiguous"] = str(which)
+        return None
     executions = len(tr.module_seconds(run.trace, module_pattern))
     executions /= max(len(run.trace["devices"]), 1)
     if not measured["events"] or not executions:
@@ -203,10 +213,6 @@ def kernel_roofline(run: Run, pattern: str, cost: str,
     return 100.0 * share
 
 
-def _sparse_layers(hf: dict) -> int:
-    return hf["num_hidden_layers"] - costs.n_dense_layers(hf)
-
-
 def _scaled(work: dict, factor: float) -> dict:
     return {k: v * factor for k, v in work.items()}
 
@@ -214,7 +220,7 @@ def _scaled(work: dict, factor: float) -> dict:
 def _expert_mm_train(run: Run) -> dict:
     o = run.observed
     one = costs.expert_mm_train(run.hf, o.tokens_per_step)
-    return _scaled(one, _sparse_layers(run.hf) / o.chips)
+    return _scaled(one, costs.n_trained_sparse_layers(run.hf) / o.chips)
 
 
 def _flash_train(run: Run) -> dict:
@@ -222,15 +228,16 @@ def _flash_train(run: Run) -> dict:
     one = costs.flash_train(
         run.hf, o.tokens_per_step // o.seq_len, o.seq_len
     )
-    return _scaled(one, run.hf["num_hidden_layers"] / o.chips)
+    return _scaled(one, costs.n_trained_attention_layers(run.hf) / o.chips)
 
 
 def _expert_mm_decode(run: Run) -> dict:
-    """Per execution of the fused chunk: ``chunk_k`` decode steps."""
+    """Per execution of the fused chunk: ``chunk_k`` decode steps through
+    the stack, and through no multi-token-prediction module."""
     o = run.observed
     touched = costs.expected_experts_touched(run.hf, o.slots)
     one = costs.expert_mm_decode(run.hf, o.slots, touched)
-    return _scaled(one, _sparse_layers(run.hf) * o.chunk_k)
+    return _scaled(one, costs.n_sparse_layers(run.hf) * o.chunk_k)
 
 
 KERNEL_COSTS = {

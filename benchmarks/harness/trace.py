@@ -17,6 +17,7 @@ An op's ``text`` is the HLO instruction as the trace names it
 (``%fusion.3 = bf16[..] fusion(..)``), cut to ``TEXT_LIMIT`` characters.
 """
 
+import bisect
 import glob
 import os
 import re
@@ -117,24 +118,32 @@ def load_xplane(path: str) -> dict:
 # -- names ------------------------------------------------------------------
 
 
-def parse_op(text: str) -> tuple[str, str]:
-    """``(instruction name, opcode)`` of an HLO instruction's text."""
+def instruction(text: str) -> tuple[str, str, str]:
+    """``(instruction name, result type, opcode)`` of an HLO
+    instruction's text, as a trace or a compiled program prints it."""
     m = re.match(r"%?(\S+) = (.*)", text, re.S)
     if not m:
-        return text.strip().lstrip("%"), ""
+        return text.strip().lstrip("%"), "", ""
     name, rest = m[1], m[2]
-    if rest.startswith("("):  # tuple-shaped result: skip to its close
-        depth = 0
+    if rest.startswith("("):  # tuple-shaped result: up to its close
+        depth, close = 0, len(rest)
         for i, ch in enumerate(rest):
             depth += ch == "("
             depth -= ch == ")"
             if depth == 0:
-                rest = rest[i + 1:]
+                close = i + 1
                 break
+        result, rest = rest[:close], rest[close:]
     else:
-        rest = rest.partition(" ")[2]
+        result, _, rest = rest.partition(" ")
     opcode = re.match(r"\s*([\w\-]+)\(", rest)
-    return name, opcode[1] if opcode else ""
+    return name, result, opcode[1] if opcode else ""
+
+
+def parse_op(text: str) -> tuple[str, str]:
+    """``(instruction name, opcode)`` of an HLO instruction's text."""
+    name, _, opcode = instruction(text)
+    return name, opcode
 
 
 def scopes_from_hlo(hlo_texts) -> dict[str, str]:
@@ -254,44 +263,57 @@ def idle_share(trace: dict, window=None) -> float:
 # -- per-operation time -----------------------------------------------------
 
 
-def self_times(events) -> list[tuple[str, float]]:
-    """``(text, self seconds)`` per event of one device lane: an event's
-    duration less what the events nested inside it cover."""
+def self_times_at(events) -> list[tuple[str, float, float]]:
+    """``(text, start, self seconds)`` per event of one device lane: an
+    event's duration less what the events nested inside it cover."""
     order = sorted(events, key=lambda e: (e[1], -e[2]))
-    out, stack = [], []  # stack of [text, end, self]
+    out, stack = [], []  # stack of [text, start, end, self]
     for text, start, dur in order:
-        while stack and stack[-1][1] <= start + 1e-12:
+        while stack and stack[-1][2] <= start + 1e-12:
             done = stack.pop()
-            out.append((done[0], max(done[2], 0.0)))
+            out.append((done[0], done[1], max(done[3], 0.0)))
         if stack:
-            stack[-1][2] -= dur
-        stack.append([text, start + dur, dur])
+            stack[-1][3] -= dur
+        stack.append([text, start, start + dur, dur])
     while stack:
         done = stack.pop()
-        out.append((done[0], max(done[2], 0.0)))
+        out.append((done[0], done[1], max(done[3], 0.0)))
     return out
 
 
-def op_seconds(trace: dict, pattern: str, scopes=None,
-               window=None) -> dict:
-    """Self time of the device ops whose text or scope matches the regex,
-    averaged over devices; with the event count per device."""
-    rx = re.compile(pattern)
+def self_times(events) -> list[tuple[str, float]]:
+    """``(text, self seconds)`` per event of one device lane."""
+    return [(text, seconds) for text, _, seconds in self_times_at(events)]
+
+
+def events_in_modules(trace: dict, window=None):
+    """``(device, module, text, self seconds)`` for every device op of
+    the window. ``module`` is the execution the op started in, as the
+    trace names it (the program's name with its fingerprint:
+    ``jit_step(1444..)``), or ``None`` outside any: a device runs one
+    program at a time, so an op belongs to the execution that covers
+    its start."""
     lo, hi = window or window_of(trace)
-    per_device, counts = [], []
-    for lanes in trace["devices"].values():
+    for ordinal, lanes in trace["devices"].items():
+        runs = sorted((m[1], m[1] + m[2], m[0]) for m in lanes["modules"])
+        starts = [r[0] for r in runs]
         inside = [e for e in lanes["ops"] if lo <= e[1] < hi]
-        total, n = 0.0, 0
-        for text, seconds in self_times(inside):
-            name = parse_op(text)[0]
-            if rx.search(text) or rx.search((scopes or {}).get(name, "")):
-                total += seconds
-                n += 1
-        per_device.append(total)
-        counts.append(n)
-    n_dev = max(len(per_device), 1)
-    return {"seconds": sum(per_device) / n_dev,
-            "events": sum(counts) / n_dev}
+        for text, start, seconds in self_times_at(inside):
+            i = bisect.bisect_right(starts, start) - 1
+            covered = i >= 0 and start < runs[i][1]
+            yield ordinal, runs[i][2] if covered else None, text, seconds
+
+
+def event_seconds(trace: dict, take, window=None) -> dict:
+    """Self time of the device ops that ``take(text, module)`` accepts
+    (``events_in_modules``), averaged over devices; with the event count
+    per device."""
+    seconds, events = 0.0, 0
+    for _, module, text, own in events_in_modules(trace, window):
+        if take(text, module):
+            seconds, events = seconds + own, events + 1
+    n_dev = max(len(trace["devices"]), 1)
+    return {"seconds": seconds / n_dev, "events": events / n_dev}
 
 
 def top_ops(trace: dict, scopes=None, n: int = 10, window=None):

@@ -245,7 +245,9 @@ def run(cell, seed: int, seconds: float, trace_dir, tiny: bool,
             reference, trainer.params, hf, sample
         )
     checks.update(correct.compare_training(
-        correct.training_system(trainer.module, trainer.params, sample),
+        correct.training_system(
+            trainer.module, trainer.params, sample, trainer.task
+        ),
         expected,
     ))
     del expected

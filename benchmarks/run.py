@@ -112,6 +112,9 @@ def main(argv=None) -> int:
             run.scopes = layers.with_expert_matmuls(
                 tr.scopes_from_hlo(observed.hlo_texts), observed.hlo_texts
             )
+            run.programs = tuple(
+                map(layers.compiled_program, observed.hlo_texts)
+            )
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)

@@ -49,14 +49,32 @@ def test_model_shares_on_the_recorded_trace(recorded):
     assert 5.4 < share["attention"] < 10.0
     assert 60.0 < sum(share.values()) < 100.0
     # a reader looks at an op's own name and scope, never at its operands:
-    # the accepted kernel metric's pattern also takes the fusions that
-    # read a ragged-dot's result (0.1549 s), this takes the calls alone
+    # a pattern on the whole text also takes the fusions that read a
+    # ragged-dot's result (0.1549 s: what kernel.expert_mm_train_roofline
+    # summed until PR 34), this takes the calls alone (0.0906 s, 58 %)
     calls = layers.scope_seconds(
         recorded["trace"], {}, r"never", layers.RAGGED_CALL
     )
     busy = recorded["expected"]["busy_s"]
     assert 100 * calls / busy == pytest.approx(share["experts"])
     assert calls < recorded["expected"]["ragged_dot_s"]
+    # and so do the expert rooflines: 6 calls and 2 of metadata a step
+    program = layers.Program("jit_step", {}, recorded["scopes"], {})
+    ran = layers.programs_that_ran(recorded["trace"], [program])
+    assert ran["jit_step(14448973072706519740)"] == [program]
+    assert ran["jit__threefry_fold_in(15899896716144297254)"] == []
+    taken = tr.event_seconds(recorded["trace"], layers.own_instruction(
+        ran, "train_step|jit_step", call="ragged-dot",
+        product_scope="moe/experts/(gate_up|down)/"))
+    assert taken == {"seconds": pytest.approx(calls), "events": 16}
+    assert calls == pytest.approx(0.0906, abs=1e-4)
+    # the flash kernels are the custom calls under a self_attn scope
+    flash = tr.event_seconds(recorded["trace"], layers.own_instruction(
+        ran, "train_step|jit_step", scope="self_attn.*pallas_call"))
+    assert flash["events"] == recorded["expected"]["flash_events"]
+    # a kernel's events are those of the step program alone
+    other = layers.own_instruction(ran, "no_such_module", call="ragged-dot")
+    assert tr.event_seconds(recorded["trace"], other)["events"] == 0
 
 
 def test_model_shares_need_a_trace():
@@ -127,6 +145,221 @@ def test_every_ragged_dot_call_gets_the_experts_scope():
     assert tr.label(text, scopes) == "custom-call:moe/experts/ragged_dot"
     # the accepted roofline readers find these calls by name, not by scope
     assert not layers.RAGGED_CALL.search(layers.RAGGED_SCOPE)
+
+
+# -- which instructions are matrix products -------------------------------------
+
+# what the v5e's compiler made of two einsums, a ragged_dot and a
+# concatenation under the experts' scopes (compiled for a described chip,
+# PR 34; layouts, backend configs and most operands left out): a
+# dot_general is a convolution inside a kOutput fusion, the activation is
+# fused into the down product as its producer, the fusion's own op_name is
+# its root's
+PRODUCT_HLO = """
+HloModule jit_f, is_scheduled=true
+
+%fused_computation (param_0.1: bf16[8,1024,512]) -> bf16[8,1024,1024] {
+  %param_0.1 = bf16[8,1024,512]{2,1,0} parameter(0)
+  %pad.3 = bf16[8,1024,1024]{2,1,0} pad(%param_0.1, %constant.11), padding=0_0x0_0x0_512, metadata={op_name="jit(f)/moe/experts/gate_up/concatenate" stack_frame_id=7}
+  ROOT %convert.2 = bf16[8,1024,1024]{2,1,0} convert(%maximum.1)
+}
+
+%bitcast_fusion (bitcast_input: bf16[8,1024,512]) -> bf16[8,1024,512] {
+  %bitcast_input = bf16[8,1024,512]{2,1,0} parameter(0)
+  ROOT %bitcast.7 = bf16[8,1024,512]{2,1,0} bitcast(%bitcast_input)
+}
+
+%fused_computation.1 (param_0.3: bf16[8,1024,512], param_1.18: bf16[512,1024]) -> bf16[8,512,512] {
+  %param_0.3 = bf16[8,1024,512]{2,1,0} parameter(0)
+  %fusion.11 = bf16[8,1024,512]{2,1,0} fusion(%param_0.3), kind=kLoop, calls=%bitcast_fusion
+  %convolution.2 = bf16[8,512,512]{2,1,0} convolution(%fusion.11, %fusion.7), window={size=1}, dim_labels=0fb_oi0->0bf, metadata={op_name="jit(f)/moe/experts/gate_up/td,edf->etf/dot_general" stack_frame_id=2}
+  ROOT %bitcast.3 = bf16[8,512,512]{1,2,0} bitcast(%convolution.2), metadata={op_name="jit(f)/moe/experts/gate_up/td,edf->etf/transpose" stack_frame_id=2}
+}
+
+%fused_computation.12 (param_0.29: bf16[8,256,1024], param_1.24: bf16[8,512,512]) -> bf16[512,1024] {
+  %slice_multiply_fusion.3 = bf16[8,512,256]{1,2,0} fusion(%param_1.24), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(f)/moe/experts/act/mul" stack_frame_id=4}
+  %convolution.5 = bf16[512,1024,1]{1,0,2} convolution(%slice_multiply_fusion.3, %fusion.12), window={size=8}, dim_labels=0bf_0io->bf0, metadata={op_name="jit(f)/moe/experts/down/etf,efd->td/dot_general" stack_frame_id=5}
+  ROOT %multiply.6 = bf16[512,1024]{1,0} multiply(%convolution.5, %scale), metadata={op_name="jit(f)/moe/combine/mul" stack_frame_id=5}
+}
+
+%fused_computation.20 (param_0.40: bf16[512,1024]) -> bf16[512,1024] {
+  %fusion.21 = bf16[512,1024]{1,0} fusion(%param_0.40), kind=kOutput, calls=%fused_computation.12
+  ROOT %add.1 = bf16[512,1024]{1,0} add(%fusion.21, %param_0.40)
+}
+
+ENTRY %main.2 (x.1: bf16[512,1024], wg.1: bf16[8,1024,512]) -> (bf16[512,1024], bf16[512,512]) {
+  %ragged-dot-none = bf16[512,512]{1,0} custom-call(%get-tuple-element, %x.1, %wg.1), custom_call_target="tpu_custom_call"
+  %convolution_bitcast_fusion = bf16[8,512,512]{1,2,0} fusion(%copy-done.1, %copy-done.2), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(f)/moe/experts/gate_up/td,edf->etf/dot_general" stack_frame_id=2}
+  %fusion.9 = bf16[512,1024]{1,0} fusion(%copy-done, %convolution_bitcast_fusion), kind=kOutput, calls=%fused_computation.12, metadata={op_name="jit(f)/moe/combine/mul" stack_frame_id=5}
+  %fusion.30 = bf16[512,1024]{1,0} fusion(%fusion.9), kind=kLoop, calls=%fused_computation.20
+  %pad_maximum_fusion = bf16[8,1024,1024]{2,1,0} fusion(%copy-done.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/moe/experts/gate_up/concatenate" stack_frame_id=7}
+  %dot.4 = f32[512,64]{1,0} dot(%x.1, %router), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/moe/router/score/dot_general"}
+  ROOT %tuple.1 = (bf16[512,1024]{1,0}, bf16[512,512]{1,0}) tuple(%fusion.30, %ragged-dot-none)
+}
+"""
+
+
+def test_matrix_products_are_found_in_the_compiled_text():
+    program = layers.compiled_program(PRODUCT_HLO)
+    gate_up = "jit(f)/moe/experts/gate_up/td,edf->etf/dot_general"
+    down = "jit(f)/moe/experts/down/etf,efd->td/dot_general"
+    assert program.module == "jit_f"
+    assert program.products == {
+        # the products themselves, and the fusions that hold one: under
+        # the product's scope, not the root's (fusion.9's own op_name is
+        # the multiply fused in behind the product)
+        "convolution.2": (gate_up,), "convolution_bitcast_fusion": (gate_up,),
+        "convolution.5": (down,), "fusion.9": (down,),
+        "fusion.21": (down,), "fusion.30": (down,),  # a fusion nested in one
+        "dot.4": ("jit(f)/moe/router/score/dot_general",),
+    }
+    # the concatenation under gate_up, the bitcast fusion and the custom
+    # call are not matrix products
+    assert not {"pad_maximum_fusion", "fusion.11", "ragged-dot-none"} \
+        & set(program.products)
+    # every instruction's result type and own scope, by its name
+    assert program.results["fusion.9"] == "bf16[512,1024]{1,0}"
+    assert program.results["tuple.1"] == "(bf16[512,1024]{1,0}, bf16[512,512]{1,0})"
+    assert program.scopes["fusion.9"] == "jit(f)/moe/combine/mul"
+    assert "ragged-dot-none" in program.results
+    assert "ragged-dot-none" not in program.scopes
+    take = layers.own_instruction(
+        {"jit_f(1)": [program]}, "jit_f", call="ragged-dot",
+        product_scope="moe/experts/(gate_up|down)/")
+    event = lambda name: f"%{name} = bf16[8,8]{{1,0}} fusion(bf16[8,8]{{1,0}} %p)"  # noqa: E731
+    assert take(event("fusion.9"), "jit_f(1)")
+    assert take(event("convolution_bitcast_fusion"), "jit_f(1)")
+    assert take("%ragged-dot-none = bf16[512,512]{1,0} custom-call(s32[1]{0} %m)",
+                "jit_f(1)")
+    assert not take(event("pad_maximum_fusion"), "jit_f(1)")  # gate_up, a copy
+    assert not take(event("dot.4"), "jit_f(1)")  # a product, the router's
+    # a consumer names the call among its operands only
+    assert not take("%act.1 = bf16[8,8]{1,0} fusion(bf16[8,8]{1,0} %ragged-dot-none)",
+                    "jit_f(1)")
+    # and nothing outside the step program's executions
+    assert not take(event("fusion.9"), None)
+    assert not take(event("fusion.9"), "jit_other(2)")
+    empty = layers.compiled_program("")
+    assert empty == layers.Program("", {}, {}, {})
+
+
+def test_a_fusion_that_holds_several_products_is_under_each_ones_scope():
+    """The dense serving cell's SwiGLU is one fusion of three matmuls
+    whose own scope is the last one's (my chip run, PR 34: the trace
+    shows 0.69 s under ``mlp/down_proj`` and none under the others)."""
+    program = layers.compiled_program("""
+HloModule jit_fused_fn, is_scheduled=true
+
+%fused_computation.5 (x: bf16[256,2560]) -> bf16[256,2560] {
+  %convolution.1 = bf16[256,8192]{1,0} convolution(%x, %wg), dim_labels=bf_io->bf, metadata={op_name="jit(fused_fn)/mlp/gate_proj/dot_general"}
+  %convolution.2 = bf16[256,8192]{1,0} convolution(%x, %wu), dim_labels=bf_io->bf, metadata={op_name="jit(fused_fn)/mlp/up_proj/dot_general"}
+  %multiply.3 = bf16[256,8192]{1,0} multiply(%convolution.1, %convolution.2)
+  ROOT %convolution.4 = bf16[256,2560]{1,0} convolution(%multiply.3, %wd), dim_labels=bf_io->bf, metadata={op_name="jit(fused_fn)/mlp/down_proj/dot_general"}
+}
+
+ENTRY %main (x: bf16[256,2560]) -> bf16[256,2560] {
+  ROOT %fusion.7 = bf16[256,2560]{1,0} fusion(%x), kind=kOutput, calls=%fused_computation.5, metadata={op_name="jit(fused_fn)/mlp/down_proj/dot_general"}
+}
+""")
+    assert program.products["fusion.7"] == tuple(
+        f"jit(fused_fn)/mlp/{name}_proj/dot_general"
+        for name in ("gate", "up", "down"))
+    event = "%fusion.7 = bf16[256,2560]{1,0} fusion(bf16[256,2560]{1,0} %x)"
+    for held in ("gate_proj", "up_proj", "down_proj"):
+        take = layers.own_instruction(
+            {"jit_fused_fn(5)": [program]}, "fused", product_scope=held)
+        assert take(event, "jit_fused_fn(5)")
+    other = layers.own_instruction(
+        {"jit_fused_fn(5)": [program]}, "fused", product_scope="o_proj")
+    assert not other(event, "jit_fused_fn(5)")
+
+
+# two programs of one process name their instructions alike: ``fusion.9``
+# is the down product of the decode chunk and the attention output of the
+# prompt step, and both call a ``fused_computation.1`` (REVIEW, PR 34)
+def two_programs():
+    chunk = """
+HloModule jit_fused_fn, is_scheduled=true
+
+%fused_computation.1 (p: bf16[64,768]) -> bf16[64,2048] {
+  ROOT %convolution.1 = bf16[64,2048]{1,0} convolution(%p, %w), dim_labels=bf_io->bf, metadata={op_name="jit(fused_fn)/moe/experts/down/dot_general"}
+}
+
+ENTRY %main (x: bf16[64,768]) -> bf16[64,2048] {
+  ROOT %fusion.9 = bf16[64,2048]{1,0} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(fused_fn)/moe/experts/down/dot_general"}
+}
+"""
+    step = """
+HloModule jit_step_fn, is_scheduled=true
+
+%fused_computation.1 (p: bf16[64,4096]) -> bf16[64,2048] {
+  ROOT %convolution.1 = bf16[64,2048]{1,0} convolution(%p, %w), dim_labels=bf_io->bf, metadata={op_name="jit(step_fn)/self_attn/o_proj/dot_general"}
+}
+
+%fused_computation.2 (p: bf16[64,2048]) -> bf16[64,2048] {
+  ROOT %add.1 = bf16[64,2048]{1,0} add(%p, %p)
+}
+
+ENTRY %main (x: bf16[64,4096]) -> bf16[64,2048] {
+  %fusion.9 = bf16[64,2048]{1,0} fusion(%x), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step_fn)/self_attn/o_proj/dot_general"}
+  ROOT %fusion.10 = bf16[64,2048]{1,0} fusion(%fusion.9), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(step_fn)/moe/experts/down/add"}
+}
+"""
+    # the chunk again as the batcher compiles it with admission: the same
+    # function, so the same module name, with its fusions numbered apart
+    admit = chunk.replace("fusion.9", "fusion.12").replace(
+        "ENTRY %main (x: bf16[64,768]) -> bf16[64,2048] {",
+        "ENTRY %main (x: bf16[64,768]) -> bf16[64,2048] {\n"
+        "  %fusion.9 = s32[64]{0} fusion(%slots), kind=kLoop, "
+        'calls=%fused_computation.3, metadata={op_name="jit(fused_fn)/admit/select_n"}')
+    return [layers.compiled_program(t) for t in (chunk, step, admit)]
+
+
+DOWN_EVENT = ("%fusion.9 = bf16[64,2048]{1,0} fusion(bf16[64,768]{1,0} %x), "
+              "kind=kOutput, calls=%fused_computation.1")
+ADMIT_EVENT = ("%fusion.9 = s32[64]{0} fusion(s32[64]{0} %slots), kind=kLoop, "
+               "calls=%fused_computation.3")
+ADMIT_DOWN = DOWN_EVENT.replace("fusion.9", "fusion.12")
+EXPERTS = "moe/experts/(gate_up|down)/"
+
+
+def test_an_instruction_is_looked_up_in_the_program_that_ran_it():
+    chunk, step, admit = two_programs()
+    # each text is resolved within itself: both call a fused_computation.1
+    assert chunk.products["fusion.9"] == (
+        "jit(fused_fn)/moe/experts/down/dot_general",)
+    assert step.products["fusion.9"] == (
+        "jit(step_fn)/self_attn/o_proj/dot_general",)
+    assert "fusion.10" not in step.products  # an add under the experts' scope
+    assert "fusion.9" not in admit.products
+    trace = {"devices": {"0": {"async": [], "ops": [
+        [DOWN_EVENT, 0.0, 1.0],   # the chunk's down product
+        [DOWN_EVENT, 2.0, 0.25],  # the prompt step's attention output
+        [ADMIT_EVENT, 4.0, 0.5], [ADMIT_DOWN, 5.0, 1.5],
+    ], "modules": [
+        ["jit_fused_fn(11)", 0.0, 1.0, 1], ["jit_step_fn(22)", 2.0, 1.0, 2],
+        ["jit_fused_fn(33)", 4.0, 3.0, 3],
+    ]}}, "host": []}
+    ran = layers.programs_that_ran(trace, [chunk, step, admit])
+    # the name says which function, the events which of its two programs
+    assert ran == {"jit_fused_fn(11)": [chunk], "jit_step_fn(22)": [step],
+                   "jit_fused_fn(33)": [admit]}
+    take = layers.own_instruction(ran, "fused", product_scope=EXPERTS)
+    assert tr.event_seconds(trace, take) == {"seconds": 2.5, "events": 2}
+    # with the step program asked for too, its fusion.9 is still attention
+    both = layers.own_instruction(ran, "fused|step", product_scope=EXPERTS)
+    assert tr.event_seconds(trace, both) == {"seconds": 2.5, "events": 2}
+    # where the events do not tell two programs of one name apart and the
+    # two disagree on an instruction, nothing is read rather than a guess
+    blind = [p._replace(results={}) for p in (chunk, step, admit)]
+    unsure = layers.programs_that_ran(trace, blind)
+    assert unsure["jit_fused_fn(11)"] == [blind[0], blind[2]]
+    with pytest.raises(layers.Ambiguous, match="fusion.9"):
+        tr.event_seconds(trace, layers.own_instruction(
+            unsure, "fused", product_scope=EXPERTS))
+    # where they agree (a call the compiler names itself), it is read
+    calls = layers.own_instruction(unsure, "fused", call="fusion.12")
+    assert tr.event_seconds(trace, calls) == {"seconds": 1.5, "events": 1}
 
 
 # -- the serving loop's phase readers ------------------------------------------

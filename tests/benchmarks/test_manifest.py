@@ -20,6 +20,14 @@ CATALOG = Path("/opt/skills/guides/model-configs/architectures.jsonl")
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
+# what ``reduced`` may never name: a width. The vocabulary's rows end in
+# ``_size`` and are a count: a chip may hold a slice of them
+WIDTH = re.compile(r"(_dim|_rank|_size)$|head|experts_per_tok")
+COUNTS = ("vocab_size",)
+# the counts a chip holds a share of, where a layer is divided over chips
+EXPERT_COUNTS = ("n_routed_experts", "num_experts")
+SHARED = (*COUNTS, *EXPERT_COUNTS)
+
 
 def one_line(text: str, limit: int = 200) -> bool:
     return 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
@@ -43,26 +51,74 @@ def test_top_level_keys_and_sizes():
     assert (ROOT / script).is_file()
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_entry_and_file(config):
+def check_reduced(body: dict, published: dict | None = None) -> None:
+    """The rule for a configuration file's ``reduced``: no width may be
+    cut, and a chip's share of a stated deployment (the ``model-configs``
+    guide, section 4) is stated and kept to the guide's floors. A file
+    that lists the vocabulary or the routed experts carries
+
+        "share": {"published": {key: count}}
+
+    with the source's count of each such key (``published``, where the
+    catalog has the model, pins it to the source): the held count
+    divides it, the experts held are at least 8, the vocabulary held at
+    least an eighth. How many chips share a layer is the published
+    experts over those held, and is written nowhere. A file that lists
+    neither carries no such block: the cost functions read it from any
+    file that has one."""
+    for key in body["reduced"]:
+        assert key in COUNTS or not WIDTH.search(key), key
+    shared = [key for key in body["reduced"] if key in SHARED]
+    if not shared:
+        assert "share" not in body
+        return
+    assert set(body.get("share", ())) == {"published"}, shared
+    whole_of = body["share"]["published"]
+    assert set(whole_of) == set(shared)
+    for key in shared:
+        held, whole = body[key], whole_of[key]
+        assert 0 < held < whole and whole % held == 0, key
+        if key in EXPERT_COUNTS:
+            assert held >= 8, key
+        else:
+            assert 8 * held >= whole, key
+        if published is not None:
+            assert whole == published[key], key
+
+
+def check_config(config: dict, bench: dict, root: Path) -> None:
+    """A ``configs`` entry of ``bench`` and its file under ``root``."""
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(config["name"])
     assert one_line(config["source"]) and one_line(config["why"])
     assert len(config["reduced"]) <= 16
     assert all(NAME.match(k) for k in config["reduced"])
-    assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
-    body = json.loads((ROOT / config["file"]).read_text())
+    assert any(config["file"].startswith(p + "/") for p in bench["paths"])
+    body = json.loads((root / config["file"]).read_text())
     assert body["source"] == config["source"]
     assert body["reduced"] == config["reduced"]
-    # no width may be cut
-    for key in config["reduced"]:
-        assert not re.search(r"(_dim|_rank|_size)$|head|experts_per_tok", key)
+    check_reduced(body)
     for key in ("preset", "model_class", "reference", "mode", "chips",
                 "assumed", "deployment", "tiny"):
         assert key in body, key
     reference = ROOT / "benchmarks" / "references" / f"{body['reference']}.py"
     assert reference.is_file()
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    assert any(w["config"] == config["name"] for w in bench["workloads"])
+
+
+def check_against_catalog(config: dict, body: dict, entry: dict) -> None:
+    """Every number of the catalog's entry is in the file under the same
+    key, but for the keys in ``reduced``; of those, a count this chip
+    holds a share of is still pinned to the source by ``share``."""
+    for key, value in entry.items():
+        if key not in config["reduced"]:
+            assert body.get(key, "absent") == value, (config["name"], key)
+    check_reduced(body, published=entry)
+
+
+@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_entry_and_file(config):
+    check_config(config, BENCH, ROOT)
 
 
 def test_config_files_are_distinct():
@@ -72,24 +128,135 @@ def test_config_files_are_distinct():
     assert len(set(names)) == len(names)
 
 
+def catalog() -> dict:
+    rows = map(json.loads, CATALOG.read_text().splitlines())
+    return {row["source_url"]: row["config"] for row in rows}
+
+
 @pytest.mark.skipif(not CATALOG.exists(), reason="no catalog here")
 def test_catalog_models_hold_every_number_of_their_entry():
-    catalog = {}
-    for line in CATALOG.read_text().splitlines():
-        row = json.loads(line)
-        catalog[row["source_url"]] = row["config"]
-    checked = 0
+    checked, entries = 0, catalog()
     for config in BENCH["configs"]:
-        entry = catalog.get(config["source"])
+        entry = entries.get(config["source"])
         if entry is None:
             continue  # Qwen3-30B-A3B is not in the catalog
         body = json.loads((ROOT / config["file"]).read_text())
-        for key, value in entry.items():
-            if key in config["reduced"]:
-                continue
-            assert body.get(key, "absent") == value, (config["name"], key)
+        check_against_catalog(config, body, entry)
         checked += 1
     assert checked >= 1  # DeepSeek-V2-Lite is in it
+
+
+# -- a chip's share of a stated deployment -------------------------------------
+
+DEEPSEEK = "deepseek-v2-lite-l2"
+
+
+def a_share_cut(tmp_path, **changes):
+    """A manifest and a configuration file as a later PR would add them:
+    one chip's share of a DeepSeek-V2-Lite training job whose layers are
+    each divided over eight chips. Depth, the routed experts held (64 ->
+    8) and the vocabulary's slice (102,400 -> 12,800 rows) are reduced,
+    all else is the catalog's; ``changes`` then alter the file
+    (``share=None`` takes the block out)."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == DEEPSEEK)
+    body = json.loads((ROOT / entry["file"]).read_text())
+    body.update(n_routed_experts=8, vocab_size=12_800,
+                reduced=with_reduced(), share=share_of())
+    body.update(changes)
+    if body["share"] is None:
+        del body["share"]
+    config = dict(entry, name=DEEPSEEK + "-share8", reduced=body["reduced"],
+                  file="benchmarks/configs/" + DEEPSEEK + "-share8.json")
+    (tmp_path / "benchmarks/configs").mkdir(parents=True)
+    (tmp_path / config["file"]).write_text(json.dumps(body))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append(config)
+    bench["workloads"].append({
+        "name": config["name"] + ".train-16k", "config": config["name"],
+        "traffic": "train-16k", "chips": 1, "why": "a test",
+    })
+    return config, bench, body
+
+
+def with_reduced(*more):
+    return ["num_hidden_layers", "n_routed_experts", "vocab_size", *more]
+
+
+def share_of(**published):
+    return {"published": {"n_routed_experts": 64, "vocab_size": 102_400,
+                          **published}}
+
+
+SHARE_CUTS = {
+    "at_the_floors": ({}, True),
+    "four_experts": ({"n_routed_experts": 4}, False),
+    "a_sixteenth_of_the_vocabulary": ({"vocab_size": 6_400}, False),
+    "no_share_block": ({"share": None}, False),
+    "a_cut_expert_width": (
+        {"moe_intermediate_size": 704,
+         "reduced": with_reduced("moe_intermediate_size")}, False),
+    "a_cut_hidden_width": (
+        {"hidden_size": 1024, "reduced": with_reduced("hidden_size")}, False),
+    "experts_per_token_cut": (
+        {"num_experts_per_tok": 2,
+         "reduced": with_reduced("num_experts_per_tok")}, False),
+    "a_slice_that_does_not_divide": ({"vocab_size": 13_000}, False),
+    "a_key_beside_the_published_counts": (
+        {"share": dict(share_of(), chips_sharing_a_layer=8)}, False),
+    "a_share_block_where_nothing_is_shared": (
+        {"n_routed_experts": 64, "vocab_size": 102_400,
+         "reduced": ["num_hidden_layers"]}, False),
+    "nothing_shared_and_no_block": (
+        {"n_routed_experts": 64, "vocab_size": 102_400,
+         "reduced": ["num_hidden_layers"], "share": None}, True),
+    "a_published_count_that_is_not_the_sources": (
+        {"n_routed_experts": 16, "share": share_of(n_routed_experts=128)},
+        False),
+    "a_share_of_a_key_that_is_not_reduced": (
+        {"reduced": ["num_hidden_layers", "vocab_size"]}, False),
+}
+
+
+@pytest.mark.parametrize("case", SHARE_CUTS)
+def test_a_share_cut_configuration_is_held_to_the_guides_floors(
+        tmp_path, case):
+    """``vocab_size`` and the experts held may be listed in ``reduced``
+    beside a ``share`` block at the guide's floors; below a floor,
+    without the block or with a width cut the file is refused, by the
+    same checks the committed configurations pass."""
+    changes, passes = SHARE_CUTS[case]
+    config, bench, body = a_share_cut(tmp_path, **changes)
+    entry = catalog()[config["source"]] if CATALOG.exists() else None
+
+    def check():
+        check_config(config, bench, tmp_path)
+        if entry is not None:
+            check_against_catalog(config, body, entry)
+
+    if case == "a_published_count_that_is_not_the_sources" and entry is None:
+        pytest.skip("only the catalog can say that 128 is not the source's")
+    if passes:
+        check()
+    else:
+        with pytest.raises(AssertionError):
+            check()
+
+
+def test_the_share_cut_is_what_the_cost_functions_read(tmp_path):
+    """The file's ``share`` block is the one thing the harness reads to
+    know a chip holds a share: an eighth of the routed rows, the router
+    at its published width, the head over the slice."""
+    _, _, cut = a_share_cut(tmp_path)
+    whole = json.loads(
+        (ROOT / "benchmarks/configs" / f"{DEEPSEEK}.json").read_text())
+    assert costs.published_experts(cut) == 64 == costs.published_experts(whole)
+    assert costs.routed_per_token(cut) == 6 * 8 / 64
+    assert costs.routed_per_token(whole) == 6
+    rows = lambda cfg: costs.expert_mm_train(cfg, 16_384)["flops"]  # noqa: E731
+    assert rows(cut) * 8 == rows(whole)
+    d, per_expert = 2048, 3 * 2048 * 1408
+    assert costs.active_matmul_params(whole) - costs.active_matmul_params(cut) \
+        == d * (102_400 - 12_800) + (6 - 0.75) * per_expert
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
