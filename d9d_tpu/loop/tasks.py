@@ -94,6 +94,19 @@ class CausalLMTask(PipelineTrainTask):
             # the worst case, and how full the buffers taken were
             metrics["moe/ep_fallback_share"] = float(fallbacks / dispatches)
             metrics["moe/ep_buffer_fill"] = float(needed / max(taken, 1.0))
+        # a held range of a wider router's experts (MoELayer): the routed
+        # pairs that landed on the experts held, and all routed pairs
+        for name in ("rows_held", "rows_routed"):
+            count = metrics.pop(f"task/moe_{name}", None)
+            if count is not None:
+                metrics[f"moe/{name}"] = float(count)
+        # a model that trains on more than the next-token loss sows the
+        # terms' sums beside the experts' counts: per-token means here
+        tokens = metrics.get("task/tokens")
+        for name in ("next_token", "mtp"):
+            total = metrics.pop(f"task/moe_loss_{name}", None)
+            if total is not None and tokens:
+                metrics[f"loss/{name}"] = float(total / tokens)
         return metrics
 
     # -- pipeline surface (PipelineTrainTask) --------------------------

@@ -835,6 +835,13 @@ class Trainer:
                             for k, v in host_metrics.items()
                             if np.ndim(v) == 0
                         }
+                        # the step's routing counts ride its span too
+                        # (``train/step`` meta): a listener of spans reads
+                        # them without the metric history
+                        clock.meta.update({
+                            k: v for k, v in host_metrics.items()
+                            if k.startswith("moe/")
+                        })
                         host_metrics.update(
                             self.metric_collector.flush(self.run, step)
                         )

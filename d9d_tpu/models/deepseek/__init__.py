@@ -29,7 +29,25 @@ sigmoid ``noaux_tc`` router with its selection bias
 ``benchmarks/references/glm4_moe_lite.py`` in
 ``tests/models/test_glm4_moe_lite.py`` and, at published widths on the
 chip, in the benchmark's ``glm-4.7-flash-decode`` cell.
+
+Xing4.0-29B-A4B (``xing4_0``) is that block again (MLA with q
+compression, the sigmoid ``noaux_tc`` router, one shared expert, two
+leading dense layers) on a four-stream residual path mixed by Sinkhorn
+iterations (``hc_mult``; nn/hyper_connections.py) with one trained
+multi-token-prediction module (``num_mtp_modules``;
+models/qwen3/moe.py MultiTokenPrediction), on the training path. A
+preset may hold a chip's share of an expert-parallel job: a range of the
+routed experts (``num_routed_experts``, ``first_held_expert``) and a
+slice of the vocabulary. Logits, the module's logits, the whole loss and
+its gradients are held to ``benchmarks/references/xing4_0.py`` in
+``tests/models/test_xing4_0.py`` (with the shares adding up to the uncut
+layer), the stream's mix in ``tests/nn/test_hyper_connections.py``, the
+held range in ``tests/nn/test_moe_held_range.py``; at published widths
+on the chip, logits and loss in the benchmark's
+``xing4.0-29b-a4b-share8`` cell.
 """
+
+import dataclasses
 
 from d9d_tpu.models.qwen3.moe import (
     MLAParameters,
@@ -53,12 +71,12 @@ def _yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def _deepseek_yarn() -> RopeScalingYarn:
+def _deepseek_yarn(factor: float = 40.0) -> RopeScalingYarn:
     """The yarn scaling both published DeepSeek-V2 configs ship
     (factor 40 over a 4096 original context; attention_factor 1.0
     because the temperature rides the softmax scale instead)."""
     return RopeScalingYarn(
-        factor=40.0,
+        factor=factor,
         original_max_position=4096,
         beta_fast=32.0,
         beta_slow=1.0,
@@ -248,4 +266,121 @@ def glm_4_7_flash(vocab_size: int = 154_880) -> Qwen3MoeConfig:
         qk_norm=False,
         rope_theta=1_000_000.0,
         norm_eps=1e-5,
+    )
+
+
+def xing4_0_tiny(vocab_size: int = 256) -> Qwen3MoeConfig:
+    """CPU-runnable Xing4.0-shaped config (tests, ``--tiny`` benchmark
+    runs): one dense and one expert layer, 4 of 16 routed experts held
+    (the first four: what ``build.hf_view`` cannot say, the benchmark's
+    reference takes as 0), four residual streams at the published
+    Sinkhorn rounds, clamp and eps, one multi-token-prediction module."""
+    return Qwen3MoeConfig(
+        vocab_ranges=(("default", vocab_size),),
+        hidden_size=64,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,  # unused by MLA; kept for config invariants
+        head_dim=16,
+        moe_intermediate_size=32,
+        num_experts=4,
+        num_routed_experts=16,
+        first_held_expert=0,
+        num_experts_per_tok=2,
+        intermediate_size=128,
+        mlp_only_layers=(0,),
+        shared_expert=SharedExpertParameters(
+            intermediate_size=32, enable_gate=False
+        ),
+        mla=MLAParameters(
+            kv_lora_rank=32,
+            qk_nope_head_dim=16,
+            qk_rope_head_dim=8,
+            v_head_dim=16,
+            q_lora_rank=24,
+        ),
+        routed_scaling_factor=2.0,
+        norm_topk_prob=True,
+        router_score_function="sigmoid",
+        router_expert_bias=True,
+        qk_norm=False,
+        rope_theta=10_000.0,
+        hc_mult=4,
+        num_mtp_modules=1,
+        remat=False,
+    )
+
+
+def xing4_0_29b_a4b(
+    vocab_size: int = 131_072, num_experts: int = 64,
+    first_held_expert: int = 0,
+) -> Qwen3MoeConfig:
+    """Xing4.0-29B-A4B geometry (``xing4_0``): 40 layers at 3,584, the
+    first two dense (9,216 wide), then 64 routed experts x 1,024 top-4
+    plus one shared, sigmoid ``noaux_tc`` routing, renormalised weights
+    times 2; MLA with q compression (rank 768), rank-512 latents, 128 +
+    64 query/key and 128 value dims a head over 32 heads, yarn factor 64
+    with its temperature in the softmax scale; four residual streams
+    mixed by 20 Sinkhorn rounds; one trained multi-token-prediction
+    module; an untied 131,072-row vocabulary. ``num_experts`` below 64
+    and a smaller ``vocab_size`` give one chip's share of an
+    expert-parallel job: that many experts from ``first_held_expert`` on
+    under the 64-wide router, and the vocabulary's first rows."""
+    return Qwen3MoeConfig(
+        vocab_ranges=(("default", vocab_size),),
+        hidden_size=3584,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=128,
+        moe_intermediate_size=1024,
+        num_experts=num_experts,
+        num_routed_experts=64,
+        first_held_expert=first_held_expert,
+        num_experts_per_tok=4,
+        intermediate_size=9216,
+        mlp_only_layers=(0, 1),
+        shared_expert=SharedExpertParameters(
+            intermediate_size=1024, enable_gate=False
+        ),
+        mla=MLAParameters(
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            q_lora_rank=768,
+            softmax_scale=(128 + 64) ** -0.5
+            * _yarn_mscale(64.0, 1.0) ** 2,
+        ),
+        routed_scaling_factor=2.0,
+        norm_topk_prob=True,
+        router_score_function="sigmoid",
+        router_expert_bias=True,
+        qk_norm=False,
+        rope_theta=10_000.0,
+        rope_scaling=_deepseek_yarn(64.0),
+        norm_eps=1e-6,
+        hc_mult=4,
+        hc_sinkhorn_iters=20,
+        hc_eps=1e-6,
+        hc_res_clamp=(-30.0, 30.0),
+        num_mtp_modules=1,
+        mtp_loss_weight=0.3,
+        remat_prevent_cse=True,
+    )
+
+
+def xing4_0_29b_a4b_share8() -> Qwen3MoeConfig:
+    """One chip of the eight that share each layer of an 8-way
+    expert-parallel Xing4.0-29B-A4B training job: experts 0 to 7 under
+    the 64-wide router and vocabulary rows 0 to 16,383, every width as
+    published. Five layers of the forty (one leading dense layer, four
+    expert layers) beside the multi-token-prediction module, so that
+    weights, gradient and moments (913 M parameters) fit the chip; the
+    other layers are other pipeline stages' (the benchmark's
+    ``xing4.0-29b-a4b-share8`` configuration)."""
+    return dataclasses.replace(
+        xing4_0_29b_a4b(vocab_size=16_384, num_experts=8),
+        num_layers=5,
+        mlp_only_layers=(0,),
     )

@@ -17,7 +17,10 @@ from jax.sharding import NamedSharding
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn.attention import GroupedQueryAttention
 from d9d_tpu.nn.embedding import TokenEmbedding
+from d9d_tpu.nn.hyper_connections import expand_streams, sum_streams
+from d9d_tpu.nn import logical_axes as la
 from d9d_tpu.nn.heads import (
+    LM_IGNORE_INDEX,
     ClassificationHead,
     EmbeddingHead,
     LanguageModellingHead,
@@ -80,6 +83,13 @@ class Qwen3MoeConfig:
     remat: bool = True
     # see Qwen3DenseConfig.remat_policy
     remat_policy: str = "full"
+    # keep the compiler from merging a layer's rematerialised forward with
+    # the forward it repeats (``jax.checkpoint(prevent_cse=...)``). Off,
+    # the layers are unrolled and the compiler may keep what the remat
+    # meant to drop: five Xing4.0 layers and the MTP block claimed 12.9 GB
+    # of temporaries off and 8.1 GB on (described-v5e compile, PR 35). The
+    # shallower presets leave it off and keep the programs they had
+    remat_prevent_cse: bool = False
     # Qwen3-Next attention features: sigmoid output gate on attention
     # layers, partial rotary (frequencies computed over the rotary dim),
     # zero-centered RMSNorm weights (scale = 1 + w) on every norm except
@@ -137,6 +147,25 @@ class Qwen3MoeConfig:
     # initializer_range, so that logits are of order 1 at init
     tie_word_embeddings: bool = False
     embedding_init_std: float = 1.0
+    # A chip's share of an expert-parallel layer (MoELayer's held range):
+    # ``num_experts`` stays the count held, the weights' leading dimension;
+    # the router is ``num_routed_experts`` wide (0 = the count held) and
+    # the experts held are that many from ``first_held_expert`` on
+    num_routed_experts: int = 0
+    first_held_expert: int = 0
+    # manifold-constrained hyper-connections (nn/hyper_connections.py):
+    # the residual stream is ``hc_mult`` rows a token, mixed around every
+    # sublayer by coefficients of the token; 1 = the plain residual path
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple[float, float] = (-30.0, 30.0)
+    # trained multi-token-prediction modules (DeepSeek-V3 section 2.2;
+    # MultiTokenPrediction below): 0 or 1. With labels the per-token loss
+    # is next-token loss + ``mtp_loss_weight`` x the module's loss on the
+    # token after; ``logits`` and decode run the stack alone
+    num_mtp_modules: int = 0
+    mtp_loss_weight: float = 0.3
 
     @property
     def vocab_size(self) -> int:
@@ -247,10 +276,18 @@ class Qwen3MoeDecoderLayer(nn.Module):
     ) -> Array:
         cfg = self.config
         zc = cfg.zero_centered_norms
+        # hc_mult > 1: ``x`` is the n-stream ``[B, T, n, C]`` and each
+        # sublayer reads a mix of it and writes into all of it
+        hc = self._hyper_connection if cfg.hc_mult > 1 else None
+        if hc:
+            attn_hc = hc("attn_mhc")
+            attn_in, attn_mix = attn_hc.read(x)
+        else:
+            attn_in = x
         normed = RMSNorm(
             cfg.hidden_size, eps=cfg.norm_eps, zero_centered=zc,
             name="input_layernorm",
-        )(x)
+        )(attn_in)
         if self.layer_idx in cfg.mamba_layers:
             from d9d_tpu.nn.mamba import MambaMixer
 
@@ -324,11 +361,16 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="self_attn",
             )(normed, cos, sin, mask)
-        x = x + attn_out
+        if hc:
+            x = attn_hc.write(x, attn_out, attn_mix)
+            mlp_hc = hc("mlp_mhc")
+            mlp_in, mlp_mix = mlp_hc.read(x)
+        else:
+            x = mlp_in = x + attn_out
         h = RMSNorm(
             cfg.hidden_size, eps=cfg.norm_eps, zero_centered=zc,
             name="post_attention_layernorm",
-        )(x)
+        )(mlp_in)
         if self.layer_idx in cfg.mlp_only_layers:
             mlp_out = SwiGLU(
                 hidden_size=cfg.hidden_size,
@@ -353,11 +395,66 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 routed_scaling=cfg.routed_scaling_factor,
                 router_n_group=cfg.router_n_group,
                 router_topk_group=cfg.router_topk_group,
+                num_routed_experts=cfg.num_routed_experts,
+                first_held_expert=cfg.first_held_expert,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name="mlp",
             )(h)
+        if hc:
+            return mlp_hc.write(x, mlp_out, mlp_mix)
         return x + mlp_out
+
+    def _hyper_connection(self, name: str):
+        from d9d_tpu.nn.hyper_connections import HyperConnection
+
+        cfg = self.config
+        return HyperConnection(
+            hidden_size=cfg.hidden_size,
+            streams=cfg.hc_mult,
+            sinkhorn_iters=cfg.hc_sinkhorn_iters,
+            eps=cfg.hc_eps,
+            res_clamp=cfg.hc_res_clamp,
+            norm_eps=cfg.norm_eps,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            name=name,
+        )
+
+
+def rope_cos_sin(cfg: Qwen3MoeConfig, positions: Array):
+    """``(cos, sin)`` at ``positions`` for the config's rotary geometry,
+    or ``(None, None)`` where nothing is rotated."""
+    # partial rotary (rope_fraction < 1): frequencies are computed over
+    # the rotary dim, not head_dim (NeoX/Qwen3-Next semantics). MLA
+    # (DeepSeek) rotates only its decoupled rope sub-vector.
+    rotary_dim = (
+        cfg.mla.qk_rope_head_dim if cfg.mla is not None
+        else int(cfg.head_dim * cfg.rope_fraction)
+    )
+    # rope_fraction 0 (no positional encoding: the attention layers
+    # of a state-space hybrid) rotates nothing: no frequencies
+    if not rotary_dim:
+        return None, None
+    inv_freq, att_scale = compute_rope_frequencies(
+        rotary_dim, cfg.rope_theta, cfg.rope_scaling
+    )
+    return make_rope_cos_sin(positions, inv_freq, att_scale)
+
+
+def decoder_layer_class(cfg: Qwen3MoeConfig, decode_max_length: int):
+    """The decoder layer, rematerialised where the config asks for it."""
+    # remat is a backward-pass tool; decode is forward-only and its
+    # mutable cache variables don't compose with nn.remat
+    if cfg.remat and decode_max_length == 0:
+        from d9d_tpu.models.qwen3.dense import _remat_policy
+
+        return nn.remat(
+            Qwen3MoeDecoderLayer,
+            prevent_cse=cfg.remat_prevent_cse,
+            policy=_remat_policy(cfg.remat_policy),
+        )
+    return Qwen3MoeDecoderLayer
 
 
 class Qwen3MoeBackbone(nn.Module):
@@ -383,7 +480,12 @@ class Qwen3MoeBackbone(nn.Module):
         positions: Array,
         mask: Optional[Array] = None,
         padding_mask: Optional[Array] = None,
+        with_prenorm: bool = False,
     ) -> Array:
+        """Between pipeline stages the carry is the residual stream:
+        ``[B, T, C]``, or ``[B, T, n, C]`` under ``hc_mult`` n. On the last
+        stage ``with_prenorm`` also returns what the final norm was given
+        (the multi-token-prediction module's input)."""
         cfg = self.config
         stream = jnp.float32 if cfg.mamba_layers else self.dtype
         if self.stage.is_first:
@@ -395,37 +497,14 @@ class Qwen3MoeBackbone(nn.Module):
                 param_dtype=self.param_dtype,
                 name="embed_tokens",
             )(x)
+            if cfg.hc_mult > 1:
+                x = expand_streams(x, cfg.hc_mult)
         else:
             x = x.astype(stream)
         x = self._pin(x)
 
-        # partial rotary (rope_fraction < 1): frequencies are computed over
-        # the rotary dim, not head_dim (NeoX/Qwen3-Next semantics). MLA
-        # (DeepSeek) rotates only its decoupled rope sub-vector.
-        rotary_dim = (
-            cfg.mla.qk_rope_head_dim if cfg.mla is not None
-            else int(cfg.head_dim * cfg.rope_fraction)
-        )
-        # rope_fraction 0 (no positional encoding: the attention layers
-        # of a state-space hybrid) rotates nothing: no frequencies
-        cos = sin = None
-        if rotary_dim:
-            inv_freq, att_scale = compute_rope_frequencies(
-                rotary_dim, cfg.rope_theta, cfg.rope_scaling
-            )
-            cos, sin = make_rope_cos_sin(positions, inv_freq, att_scale)
-
-        layer_cls = Qwen3MoeDecoderLayer
-        # remat is a backward-pass tool; decode is forward-only and its
-        # mutable cache variables don't compose with nn.remat
-        if cfg.remat and self.decode_max_length == 0:
-            from d9d_tpu.models.qwen3.dense import _remat_policy
-
-            layer_cls = nn.remat(
-                Qwen3MoeDecoderLayer,
-                prevent_cse=False,
-                policy=_remat_policy(cfg.remat_policy),
-            )
+        cos, sin = rope_cos_sin(cfg, positions)
+        layer_cls = decoder_layer_class(cfg, self.decode_max_length)
 
         for gid in distribute_layers_for_pipeline_stage(cfg.num_layers, self.stage):
             x = layer_cls(
@@ -445,12 +524,84 @@ class Qwen3MoeBackbone(nn.Module):
             numerics.tap(f"layers_{gid}", x)
 
         if self.stage.is_last:
+            prenorm = sum_streams(x) if cfg.hc_mult > 1 else x
             x = RMSNorm(
                 cfg.hidden_size, eps=cfg.norm_eps,
                 zero_centered=cfg.zero_centered_norms, name="norm",
-            )(x).astype(self.dtype)
+            )(prenorm).astype(self.dtype)
             numerics.tap("norm", x)
+            if with_prenorm:
+                return x, prenorm
         return x
+
+
+class MultiTokenPrediction(nn.Module):
+    """One trained multi-token-prediction module (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2, depth 1).
+
+    ``h' = M [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]`` with ``h_i`` the
+    main stack's output before its final norm and ``M`` a ``2C x C``
+    merge; one more decoder block of the expert kind (under ``hc_mult``
+    n its own n streams, started as n copies of ``h'`` and read out by
+    the sum); its own final norm. The embedding table and the head are
+    the main model's: the caller looks up ``Emb(t_{i+1})`` and gives the
+    result here to the shared head with the labels shifted once more.
+    Trained only: ``logits`` and decode never build it.
+    """
+
+    config: Qwen3MoeConfig
+    sdpa: SdpaBackend
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self,
+        hidden: Array,
+        next_embedding: Array,
+        positions: Array,
+        mask: Optional[Array] = None,
+        padding_mask: Optional[Array] = None,
+    ) -> Array:
+        """``hidden``, ``next_embedding`` ``[B, T, C]`` → ``[B, T, C]``,
+        normed for the head."""
+        cfg = self.config
+
+        def norm(name: str):
+            return RMSNorm(
+                cfg.hidden_size, eps=cfg.norm_eps,
+                zero_centered=cfg.zero_centered_norms, name=name,
+            )
+
+        pair = jnp.concatenate(
+            [norm("hnorm")(hidden), norm("enorm")(next_embedding)], axis=-1
+        ).astype(self.dtype)
+        x = nn.Dense(
+            cfg.hidden_size,
+            use_bias=False,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), (la.EMBED, None)
+            ),
+            name="merge",
+        )(pair)
+        if cfg.hc_mult > 1:
+            x = expand_streams(x, cfg.hc_mult)
+        cos, sin = rope_cos_sin(cfg, positions)
+        # a layer index past the stack's: of the kind that follows the
+        # dense layers
+        x = decoder_layer_class(cfg, 0)(
+            config=cfg,
+            sdpa=self.sdpa,
+            layer_idx=cfg.num_layers,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            name="block",
+        )(x, cos, sin, mask, padding_mask)
+        if cfg.hc_mult > 1:
+            x = sum_streams(x)
+        return norm("norm")(x).astype(self.dtype)
 
 
 class Qwen3MoeCausalLM(nn.Module):
@@ -482,6 +633,21 @@ class Qwen3MoeCausalLM(nn.Module):
                 "tie_word_embeddings needs the embedding and the head on "
                 "one pipeline stage"
             )
+        mtp = self.config.num_mtp_modules
+        if mtp not in (0, 1):
+            raise ValueError(f"num_mtp_modules {mtp}: 0 or 1")
+        if mtp and not (self.stage.is_first and self.stage.is_last):
+            raise ValueError(
+                "a multi-token-prediction module needs the embedding and "
+                "the head on one pipeline stage"
+            )
+        if mtp:
+            self.mtp = MultiTokenPrediction(
+                config=self.config,
+                sdpa=self.sdpa,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+            )
         if self.stage.is_last:
             self.lm_head = LanguageModellingHead(
                 vocab_ranges=self.config.vocab_ranges,
@@ -492,15 +658,59 @@ class Qwen3MoeCausalLM(nn.Module):
                 param_dtype=self.param_dtype,
             )
 
-    def _head_table(self) -> Optional[Array]:
-        """The embedding table a tied head reads, once the backbone has
-        run (its parameters exist then, at ``init`` too); else None."""
-        if not self.config.tie_word_embeddings:
-            return None
+    def _embedding_table(self) -> Array:
+        """The backbone's embedding table ``[V, C]``, once the backbone
+        has run (its parameters exist then, at ``init`` too)."""
         tables = nn.meta.unbox(self.model.variables["params"]["embed_tokens"])
         return concat_vocab_ranges(
             [tables[f"embedding_{name}"] for name, _ in self.config.vocab_ranges]
         )
+
+    def _head_table(self) -> Optional[Array]:
+        """The embedding table a tied head reads; else None."""
+        if not self.config.tie_word_embeddings:
+            return None
+        return self._embedding_table()
+
+    def _next_embedding(self, tokens: Array) -> Array:
+        """``Emb(t_{i+1})`` at position i. The last position is given the
+        first token: causal, so only its own output sees it, and its label
+        is ignored."""
+        return jnp.take(
+            self._embedding_table(), jnp.roll(tokens, -1, axis=1), axis=0
+        ).astype(self.dtype)
+
+    def _loss_with_mtp(
+        self, tokens: Array, positions: Array, labels: Array,
+        mask: Optional[Array], padding_mask: Optional[Array],
+    ) -> Array:
+        """Per-token next-token loss + ``mtp_loss_weight`` x the module's
+        loss on the token after: position i merges ``h_i`` with the
+        embedding of ``t_{i+1}`` and is held to ``t_{i+2}`` (the labels
+        shifted once more; the last position and ignored labels give 0).
+        The two terms are sown into ``moe_stats`` as sums, which the task
+        turns into ``loss/next_token`` and ``loss/mtp``."""
+        h, prenorm = self.model(
+            tokens, positions, mask, padding_mask, with_prenorm=True
+        )
+        table = self._head_table()
+        next_token = self.lm_head(h, labels, table)
+        next_embedding = self._next_embedding(tokens)
+        after = jnp.concatenate(
+            [labels[:, 1:], jnp.full_like(labels[:, :1], LM_IGNORE_INDEX)],
+            axis=1,
+        )
+        mtp = self.lm_head(
+            self.mtp(prenorm, next_embedding, positions, mask, padding_mask),
+            after, table,
+        )
+        for name, term in (("loss_next_token", next_token), ("loss_mtp", mtp)):
+            self.sow(
+                "moe_stats", name, term.sum(),
+                reduce_fn=lambda a, b: a + b,
+                init_fn=lambda: jnp.zeros((), jnp.float32),
+            )
+        return next_token + self.config.mtp_loss_weight * mtp
 
     def __call__(
         self,
@@ -510,6 +720,8 @@ class Qwen3MoeCausalLM(nn.Module):
         mask: Optional[Array] = None,
         padding_mask: Optional[Array] = None,
     ) -> Array:
+        if self.config.num_mtp_modules and labels is not None:
+            return self._loss_with_mtp(x, positions, labels, mask, padding_mask)
         h = self.model(x, positions, mask, padding_mask)
         if self.stage.is_last and labels is not None:
             return self.lm_head(h, labels, self._head_table())
@@ -539,6 +751,24 @@ class Qwen3MoeCausalLM(nn.Module):
         if not self.stage.is_last:
             return h
         return self.lm_head.logits(h[:, -1:], self._head_table())
+
+    def mtp_logits(
+        self,
+        x: Array,
+        positions: Array,
+        mask: Optional[Array] = None,
+        padding_mask: Optional[Array] = None,
+    ) -> Array:
+        """The multi-token-prediction module's logits ``[B, T, V]``:
+        position i predicts ``t_{i+2}`` (the last position's are of no
+        use). For comparisons; training goes through ``__call__``."""
+        _, prenorm = self.model(
+            x, positions, mask, padding_mask, with_prenorm=True
+        )
+        h = self.mtp(
+            prenorm, self._next_embedding(x), positions, mask, padding_mask
+        )
+        return self.lm_head.logits(h, self._head_table())
 
 
 class Qwen3MoeForClassification(nn.Module):

@@ -21,7 +21,8 @@ TPU-native design:
 """
 
 import dataclasses
-from typing import Optional
+import functools
+from typing import NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -33,12 +34,18 @@ from d9d_tpu.core import compat
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn import logical_axes as la
 from d9d_tpu.nn.mlp import SwiGLU
-from d9d_tpu.ops.ep_dispatch import ep_dispatch_compute_combine
+from d9d_tpu.ops.ep_dispatch import (
+    ep_buffer_rows,
+    ep_dispatch_compute_combine,
+)
 from d9d_tpu.ops.moe import (
+    fold_held,
     gate_up_grouped_matmul,
     grouped_matmul,
     permute_tokens,
+    sort_held_pairs,
     sort_tokens_by_expert,
+    spread_held,
     unpermute_combine,
 )
 from d9d_tpu.ops.moe_pallas import fused_moe_ffn_apply, moe_ffn_backend
@@ -260,6 +267,176 @@ def grouped_swiglu_apply(
         return out * permuted_probs[:, None].astype(dtype)
 
 
+# --- a held range of the router's experts -------------------------------------
+
+
+class _HeldLadder(NamedTuple):
+    """The static half of the held-range path (hashable: a ``custom_vjp``
+    non-differentiable argument)."""
+
+    dtype: object
+    top_k: int
+    buffers: tuple[int, ...]  # ascending one-pass buffer sizes, in rows
+    passes: int  # token chunks of the fallback, each with room for all its pairs
+
+
+# Each one-pass buffer is a quarter above the one below, from a quarter above
+# an even router's share up to this multiple of it. A held range is a few
+# experts and nothing balances the router here, so one layer's share wanders:
+# the Xing4.0 cell's MTP block went from 4,340 rows (of 32,768; even: 4,096)
+# to 5,904 and back within 40 steps (my chip run, PR 35). The EP path's two
+# rungs below its worst case (``ep_buffer_ladder``: 1.25 and 3.16 at eight
+# shards) would have run that layer at 12,952 rows; here its cost follows its
+# rows within a quarter
+_HELD_RUNG_RATIO = 1.25
+_HELD_ONE_PASS_LIMIT = 3.2
+
+
+def held_ladder(
+    num_tokens: int, top_k: int, num_held: int, num_routed: int
+) -> tuple[tuple[int, ...], int]:
+    """``(buffers, passes)`` for ``num_held`` of ``num_routed`` experts.
+
+    The one-pass buffers are chosen from the count of pairs that land
+    here, the way the dropless EP path chooses its rung
+    (``ops/ep_dispatch.py``), on a finer ladder: see ``_HELD_RUNG_RATIO``.
+    Routing so uneven that the last of them overflows is computed in
+    ``passes`` chunks of tokens, the fewest whose every pair fits that last
+    buffer: dropless whatever the routing, and never ``num_tokens * top_k``
+    rows in one buffer. A call of so few tokens (a decode step) that the
+    smallest rung would hold every pair gets one buffer of all its pairs
+    and ``passes`` 1.
+    """
+    share = num_routed // num_held
+    pairs = num_tokens * top_k
+    even = -(-pairs // share)
+    buffers, factor = [], _HELD_RUNG_RATIO
+    while factor <= _HELD_ONE_PASS_LIMIT:
+        rows = ep_buffer_rows(even, share, factor)
+        if rows >= pairs:
+            break
+        buffers.append(rows)
+        factor *= _HELD_RUNG_RATIO
+    if not buffers:
+        return (pairs,), 1
+    passes = next(
+        p for p in range(2, num_tokens + 1)
+        if num_tokens % p == 0 and num_tokens // p * top_k <= buffers[-1]
+    )
+    return tuple(buffers), passes
+
+
+def _held_pass(dtype, top_k, buf_rows, x, local_ids, probs, weights):
+    """The held experts over ``x [n, D]`` through one ``buf_rows``-row
+    buffer: gather the rows of the pairs that land here, grouped SwiGLU,
+    fold them back to their tokens. ``local_ids [n, K]`` is
+    ``weights[0].shape[0]`` for a pair routed elsewhere."""
+    with jax.named_scope("moe/permute"):
+        held = sort_held_pairs(local_ids, weights[0].shape[0], buf_rows)
+        rows = spread_held(x, held, top_k)
+        live = jnp.arange(buf_rows) < held.rows_held
+        row_probs = jnp.where(
+            live, jnp.take(probs.reshape(-1), held.pair_of_row), 0
+        )
+    y = grouped_swiglu_apply(
+        rows, row_probs, held.group_sizes, *weights, dtype
+    )
+    with jax.named_scope("moe/combine"):
+        return fold_held(y, held, x.shape[0], top_k).astype(x.dtype)
+
+
+def _held_branches(ladder: _HeldLadder):
+    dtype, top_k, buffers, passes = ladder
+
+    def in_chunks(x, local_ids, probs, weights):
+        n, d = x.shape
+        chunk = n // passes
+
+        def one(_, part):
+            return None, _held_pass(
+                dtype, top_k, chunk * top_k, *part, weights
+            )
+
+        _, out = lax.scan(one, None, (
+            x.reshape(passes, chunk, d),
+            local_ids.reshape(passes, chunk, top_k),
+            probs.reshape(passes, chunk, top_k),
+        ))
+        return out.reshape(n, d)
+
+    return [
+        *(functools.partial(_held_pass, dtype, top_k, rows)
+          for rows in buffers),
+        in_chunks,
+    ]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _laddered_held(ladder: _HeldLadder, rung, x, local_ids, probs, weights):
+    """The held experts through rung ``rung`` of ``ladder`` (the last is
+    the chunked fallback). Its own VJP for the reason
+    ``ops/ep_dispatch.py _laddered_exchange`` gives: differentiating
+    through ``lax.switch`` would have the snug branch allocate the other
+    branches' residuals as zeros. The forward keeps its inputs and the
+    backward makes the choice again."""
+    return lax.switch(rung, _held_branches(ladder), x, local_ids, probs, weights)
+
+
+def _laddered_held_fwd(ladder, rung, x, local_ids, probs, weights):
+    out = _laddered_held(ladder, rung, x, local_ids, probs, weights)
+    return out, (rung, x, local_ids, probs, weights)
+
+
+def _laddered_held_bwd(ladder, residuals, g):
+    rung, x, local_ids, probs, weights = residuals
+
+    def pull_back(branch):
+        def pulled(x, local_ids, probs, weights, g):
+            _, vjp = jax.vjp(
+                lambda x, p, w: branch(x, local_ids, p, w), x, probs, weights
+            )
+            return vjp(g)
+
+        return pulled
+
+    d_x, d_probs, d_weights = lax.switch(
+        rung, [pull_back(b) for b in _held_branches(ladder)],
+        x, local_ids, probs, weights, g,
+    )
+    return None, d_x, None, d_probs, d_weights
+
+
+_laddered_held.defvjp(_laddered_held_fwd, _laddered_held_bwd)
+
+
+def held_experts_apply(
+    x: Array, local_ids: Array, probs: Array, weights: tuple,
+    *, num_routed: int, dtype: jnp.dtype,
+) -> Array:
+    """Routed output of the experts held here, for tokens routed over
+    ``num_routed`` experts of which ``weights`` hold a contiguous range.
+
+    x: [N, D]; local_ids: [N, K] the chosen expert less the first held
+    one, ``E`` (the count held) where that falls outside ``[0, E)``;
+    probs: [N, K]. Only the pairs that land here are gathered, multiplied
+    and folded: in the smallest buffer of :func:`held_ladder` that holds
+    them, chosen per call from their count. What the other experts would
+    add is computed by the chips that hold them, and nowhere here.
+    """
+    n, k = local_ids.shape
+    num_held = weights[0].shape[0]
+    buffers, passes = held_ladder(n, k, num_held, num_routed)
+    if passes == 1:
+        return _held_pass(dtype, k, buffers[0], x, local_ids, probs, weights)
+    rows_held = (local_ids < num_held).sum()
+    rung = sum((rows_held > rows).astype(jnp.int32) for rows in buffers)
+    return _laddered_held(
+        _HeldLadder(dtype, k, buffers, passes), rung,
+        x, local_ids, probs, tuple(weights),
+    )
+
+
+
 class SharedSwiGLU(nn.Module):
     """Always-on expert with optional sigmoid gate (reference
     shared_expert.py:21)."""
@@ -357,13 +534,43 @@ class MoELayer(nn.Module):
     # DeepSeek routed_scaling_factor: multiplies the routed experts'
     # combined output (not the shared expert)
     routed_scaling: float = 1.0
+    # A held range of a wider router's experts: one chip's share of a
+    # layer that expert-parallel chips divide between them, seen from that
+    # chip alone. ``num_routed_experts`` is the router's width (0 = the
+    # count held: every expert is here) and ``first_held_expert`` the first
+    # of the ``num_grouped_experts`` held. The router scores, biases,
+    # selects and renormalises over all of them; the pairs routed outside
+    # the range drop out before the permutation and only the rows that
+    # land here are gathered, multiplied and combined
+    # (``held_experts_apply``). What the other experts would add is theirs
+    # to compute: nothing here stands in for them.
+    num_routed_experts: int = 0
+    first_held_expert: int = 0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
+    @property
+    def router_width(self) -> int:
+        return self.num_routed_experts or self.num_grouped_experts
+
     def setup(self) -> None:
+        held, routed = self.num_grouped_experts, self.router_width
+        if routed != held:
+            if routed % held or not (
+                0 <= self.first_held_expert <= routed - held
+            ):
+                raise ValueError(
+                    f"{held} experts from {self.first_held_expert} on are "
+                    f"no share of {routed} routed experts"
+                )
+            if self.ep_axes is not None:
+                raise ValueError(
+                    "a held range is one chip's view of an expert-parallel "
+                    "layer; with ep_axes the mesh holds every expert"
+                )
         self.router = TopKRouter(
             dim=self.hidden_dim,
-            num_experts=self.num_grouped_experts,
+            num_experts=routed,
             top_k=self.top_k,
             renormalize_probabilities=self.router_renormalize_probabilities,
             enable_expert_bias=self.router_enable_expert_bias,
@@ -406,17 +613,19 @@ class MoELayer(nn.Module):
         self.sow(
             "moe_stats",
             "tokens_per_expert",
-            jnp.bincount(
-                topk_ids.reshape(-1), length=self.num_grouped_experts
-            ),
+            jnp.bincount(topk_ids.reshape(-1), length=self.router_width),
             reduce_fn=lambda a, b: a + b,
-            init_fn=lambda: jnp.zeros(
-                (self.num_grouped_experts,), jnp.int32
-            ),
+            init_fn=lambda: jnp.zeros((self.router_width,), jnp.int32),
         )
 
-        if self.ep_axes is None:
-            k = topk_ids.shape[-1]
+        k = topk_ids.shape[-1]
+        if self.router_width != self.num_grouped_experts:
+            out = self._forward_held(
+                hidden.reshape(-1, orig_shape[-1]),
+                topk_ids.reshape(-1, k),
+                topk_probs.reshape(-1, k),
+            ).reshape(orig_shape)
+        elif self.ep_axes is None:
             out = self._forward_local(
                 hidden.reshape(-1, orig_shape[-1]),
                 topk_ids.reshape(-1, k),
@@ -465,6 +674,35 @@ class MoELayer(nn.Module):
         y = self.grouped_experts(permuted_x, permuted_probs, sort.group_sizes)
         with jax.named_scope("moe/combine"):
             return unpermute_combine(y, sort, x.shape[0]).astype(x.dtype)
+
+    # --- a held range of the router's experts ----------------------------
+
+    def _forward_held(
+        self, x: Array, topk_ids: Array, topk_probs: Array
+    ) -> Array:
+        held = self.num_grouped_experts
+        with jax.named_scope("moe/permute"):
+            local = topk_ids - self.first_held_expert
+            local = jnp.where((local >= 0) & (local < held), local, held)
+        # routed pairs that landed here, and all of them: the share says
+        # how far the rows computed are from an even router's
+        for name, value in (
+            ("rows_held", (local < held).sum()), ("rows_routed", local.size),
+        ):
+            self.sow(
+                "moe_stats", name, jnp.asarray(value, jnp.float32),
+                reduce_fn=lambda a, b: a + b,
+                init_fn=lambda: jnp.zeros((), jnp.float32),
+            )
+        return held_experts_apply(
+            x, local, topk_probs,
+            (
+                self.grouped_experts.gate_weight,
+                self.grouped_experts.up_weight,
+                self.grouped_experts.down_weight,
+            ),
+            num_routed=self.router_width, dtype=self.dtype,
+        )
 
     # --- EP path (reference communications/deepep.py, re-designed) -------
 

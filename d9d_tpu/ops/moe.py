@@ -290,3 +290,137 @@ def grouped_matmul(x: Array, weight: Array, group_sizes: Array) -> Array:
         ),
         "moe_grouped_dot",
     )
+
+
+# --- a held range of a wider router's experts --------------------------------
+
+
+class HeldSort(NamedTuple):
+    """The (token, choice) pairs that land on the experts held here, laid
+    into a buffer of ``M`` rows (:func:`sort_held_pairs`).
+
+    Two orders over the same pairs: *rows* are expert-sorted (what the
+    grouped matmul wants), *slots* are token-major (a token's pairs are
+    neighbours, so folding them is a few shifted adds and no scatter).
+    Rows and slots from ``rows_held`` on are padding.
+
+    pair_of_row: [M] flattened (token-major) pair of each row.
+    token_of_row: [M] owning token of each row.
+    row_of_slot: [M] the row of each slot's pair.
+    token_of_slot: [M] owning token of each slot.
+    slot_start: [N] a token's first slot.
+    slot_count: [N] pairs of a token that are held (at most K).
+    rows_held: [] pairs held, at most M.
+    group_sizes: [E] rows per held expert; the padding rows ride in the
+        last group (as zero rows: :func:`spread_held`).
+    """
+
+    pair_of_row: Array
+    token_of_row: Array
+    row_of_slot: Array
+    token_of_slot: Array
+    slot_start: Array
+    slot_count: Array
+    rows_held: Array
+    group_sizes: Array
+
+
+def sort_held_pairs(
+    local_ids: Array, num_held: int, buf_rows: int
+) -> HeldSort:
+    """Index vectors for the pairs routed to ``num_held`` local experts.
+
+    local_ids: [N, K] int32 local expert of each pair, ``num_held`` for a
+    pair routed to an expert held elsewhere. The caller picks ``buf_rows``
+    at or above the number of held pairs. Only index vectors are made
+    here, ``N*K`` integers at most: no row of hidden width moves.
+    """
+    n, k = local_ids.shape
+    flat = local_ids.reshape(n * k)
+    # pairs routed elsewhere form one more group, sorted last
+    sort_idx, dest, sizes = stable_expert_order(flat, num_held + 1)
+    rows_held = n * k - sizes[num_held]
+    pair_of_row = sort_idx[:buf_rows]
+
+    held = flat < num_held
+    seen = jnp.cumsum(held.astype(jnp.int32))
+    pairs = jnp.arange(n * k, dtype=jnp.int32)
+    # slot of a held pair: how many held pairs stand before it; the others
+    # aim past the buffer, each at an index of its own, and are dropped
+    slot = jnp.where(held, seen - 1, buf_rows + pairs - seen)
+    pair_of_slot = jnp.zeros((buf_rows,), jnp.int32).at[slot].set(
+        pairs, mode="drop", unique_indices=True
+    )
+    count = held.reshape(n, k).sum(axis=1).astype(jnp.int32)
+    group_sizes = sizes[:num_held].at[num_held - 1].add(buf_rows - rows_held)
+    return HeldSort(
+        pair_of_row=pair_of_row,
+        token_of_row=pair_of_row // k,
+        row_of_slot=jnp.take(dest, pair_of_slot),
+        token_of_slot=pair_of_slot // k,
+        slot_start=jnp.cumsum(count) - count,
+        slot_count=count,
+        rows_held=rows_held,
+        group_sizes=group_sizes,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def spread_held(x: Array, held: HeldSort, top_k: int) -> Array:
+    """A token's row to each of its held pairs' buffer rows.
+
+    x: [N, D] → [M, D], row r a copy of token ``held.token_of_row[r]``,
+    zeros from ``held.rows_held`` on. Its transpose is :func:`fold_held`.
+    """
+    rows = jnp.take(x, held.token_of_row, axis=0)
+    live = jnp.arange(rows.shape[0]) < held.rows_held
+    return jnp.where(live[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+def _spread_held_fwd(x, held, top_k):
+    return spread_held(x, held, top_k), (held, x.shape[0])
+
+
+def _spread_held_bwd(top_k, residuals, g):
+    held, num_tokens = residuals
+    return fold_held(g, held, num_tokens, top_k), None
+
+
+spread_held.defvjp(_spread_held_fwd, _spread_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def fold_held(y: Array, held: HeldSort, num_tokens: int, top_k: int) -> Array:
+    """Fold the held pairs' buffer rows back to their owning tokens.
+
+    y: [M, D] expert-sorted rows → [N, D]; a token with no held pair gets
+    zeros. One gather brings the rows into slot order, where a token's (at
+    most K) pairs are neighbours: K - 1 shifted adds leave each token's
+    sum in its first slot, and one N-row gather picks those up. No
+    scatter-add, and never ``N*K`` rows. Its transpose is
+    :func:`spread_held`.
+    """
+    m = y.shape[0]
+    live = jnp.arange(m) < held.rows_held
+    by_slot = jnp.where(
+        live[:, None], jnp.take(y, held.row_of_slot, axis=0), 0
+    ).astype(jnp.float32)
+    token = jnp.where(live, held.token_of_slot, -1)
+    total = by_slot
+    for j in range(1, top_k):
+        same = jnp.pad(token[j:], (0, j), constant_values=-2) == token
+        later = jnp.pad(by_slot[j:], ((0, j), (0, 0)))
+        total = total + jnp.where(same[:, None], later, 0)
+    out = jnp.take(total, jnp.minimum(held.slot_start, m - 1), axis=0)
+    return jnp.where((held.slot_count > 0)[:, None], out, 0).astype(y.dtype)
+
+
+def _fold_held_fwd(y, held, num_tokens, top_k):
+    return fold_held(y, held, num_tokens, top_k), held
+
+
+def _fold_held_bwd(num_tokens, top_k, held, g):
+    return spread_held(g, held, top_k), None
+
+
+fold_held.defvjp(_fold_held_fwd, _fold_held_bwd)

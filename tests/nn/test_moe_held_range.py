@@ -1,0 +1,258 @@
+"""A held range of a wider router's experts (``MoELayer.num_routed_experts``,
+``first_held_expert``): one chip's share of an expert-parallel layer, seen
+from that chip alone. The shares add up to the uncut layer, forward and in
+the gradients; only the rows that land here are gathered, multiplied and
+folded (a count over the jaxpr); routing however uneven drops no pair."""
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from d9d_tpu.nn.moe import (
+    MoELayer,
+    SharedExpertParameters,
+    held_experts_apply,
+    held_ladder,
+)
+from d9d_tpu.ops.moe import fold_held, sort_held_pairs, spread_held
+
+D, F = 32, 16
+SHARED = SharedExpertParameters(intermediate_size=16, enable_gate=False)
+
+
+def layer(routed: int, held: int, first: int, top_k: int, **extra):
+    return MoELayer(
+        hidden_dim=D, intermediate_dim_grouped=F, num_grouped_experts=held,
+        top_k=top_k, router_score_function="sigmoid",
+        router_enable_expert_bias=True, routed_scaling=2.0,
+        num_routed_experts=routed if held != routed else 0,
+        first_held_expert=first, dtype=jnp.float32, param_dtype=jnp.float32,
+        **extra,
+    )
+
+
+def whole_layer_params(routed: int, top_k: int, x, shared=None, seed=1):
+    params = nn.meta.unbox(
+        layer(routed, routed, 0, top_k, shared_expert=shared)
+        .init(jax.random.PRNGKey(seed), x)["params"]
+    )
+    params["router"]["e_score_correction_bias"] = jnp.asarray(
+        np.random.RandomState(seed).uniform(-0.3, 0.3, routed), jnp.float32
+    )
+    return params
+
+
+def share_of(params, first: int, held: int):
+    cut = dict(params)
+    cut["grouped_experts"] = {
+        k: v[first:first + held] for k, v in params["grouped_experts"].items()
+    }
+    return cut
+
+
+@pytest.mark.parametrize("routed,held,top_k", [
+    (16, 4, 3), (16, 8, 2), (8, 2, 4), (64, 8, 4),
+], ids=lambda v: str(v))
+def test_the_shares_add_up_to_the_uncut_layer(routed, held, top_k):
+    """Over all shares of a layer the routed parts, with the shared expert
+    counted once, sum to the uncut layer's output; so do the gradients to
+    the input, the router and (share by share) the experts."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, D))
+    whole = layer(routed, routed, 0, top_k, shared_expert=SHARED)
+    params = whole_layer_params(routed, top_k, x, SHARED)
+    probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def uncut(p, x):
+        return (whole.apply({"params": p}, x) * probe).sum()
+
+    want = whole.apply({"params": params}, x)
+    want_dp, want_dx = jax.grad(uncut, argnums=(0, 1))(params, x)
+
+    total = 0.0
+    total_dx = 0.0
+    gate_grad = 0.0
+    held_rows = 0.0
+    for first in range(0, routed, held):
+        # the shared expert rides with the first share only
+        shared = SHARED if first == 0 else None
+        part = layer(routed, held, first, top_k, shared_expert=shared)
+        p = share_of(params, first, held)
+        if shared is None:
+            p.pop("shared_expert_module")
+
+        def cut(p, x, part=part):
+            return (part.apply({"params": p}, x) * probe).sum()
+
+        out, stats = part.apply({"params": p}, x, mutable=["moe_stats"])
+        dp, dx = jax.grad(cut, argnums=(0, 1))(p, x)
+        total, total_dx = total + out, total_dx + dx
+        gate_grad = gate_grad + dp["router"]["gate"]["kernel"]
+        held_rows += float(stats["moe_stats"]["rows_held"])
+        assert float(stats["moe_stats"]["rows_routed"]) == 128 * top_k
+        assert stats["moe_stats"]["tokens_per_expert"].shape == (routed,)
+        for name, g in dp["grouped_experts"].items():
+            np.testing.assert_allclose(
+                g, want_dp["grouped_experts"][name][first:first + held],
+                rtol=1e-4, atol=1e-6,
+            )
+    assert held_rows == 128 * top_k  # every pair lands on exactly one share
+    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(total_dx, want_dx, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(
+        gate_grad, want_dp["router"]["gate"]["kernel"], rtol=1e-4, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("skew", ["even", "all_here", "none_here"])
+def test_no_pair_is_dropped_however_uneven_the_routing(skew):
+    """The buffer is chosen from the count of pairs that land here: the
+    snug one for an even router, the chunked fallback when every pair
+    does, and a layer nobody routes to gives zeros."""
+    n, k, routed, held = 64, 2, 16, 4
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.normal(size=(n, D)), jnp.float32)
+    probs = jnp.asarray(rng.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    ids = {
+        "even": rng.randint(0, routed, size=(n, k)),
+        "all_here": rng.randint(0, held, size=(n, k)),
+        "none_here": rng.randint(held, routed, size=(n, k)),
+    }[skew]
+    local = jnp.asarray(np.where(ids < held, ids, held), jnp.int32)
+    weights = tuple(
+        jnp.asarray(rng.normal(size=s) * 0.2, jnp.float32)
+        for s in ((held, D, F), (held, D, F), (held, F, D))
+    )
+    buffers, passes = held_ladder(n, k, held, routed)
+    # a quarter above the even 32 rows, then a quarter above that, ...;
+    # beyond the last, two chunks of 32 tokens x 2
+    assert buffers == (40, 56, 64, 80, 104) and passes == 2
+    assert skew != "even" or 20 < (ids < held).sum() <= 40
+
+    def run(x, probs, weights):
+        return held_experts_apply(
+            x, local, probs, weights, num_routed=routed, dtype=jnp.float32
+        )
+
+    def dense(x, probs, weights):
+        gate, up, down = weights
+        out = jnp.zeros_like(x)
+        for j in range(k):
+            e = jnp.minimum(local[:, j], held - 1)
+            h = jax.nn.silu(jnp.einsum("nd,ndf->nf", x, gate[e])) * jnp.einsum(
+                "nd,ndf->nf", x, up[e])
+            y = jnp.einsum("nf,nfd->nd", h, down[e]) * probs[:, j:j + 1]
+            out = out + jnp.where((local[:, j] < held)[:, None], y, 0.0)
+        return out
+
+    np.testing.assert_allclose(
+        run(x, probs, weights), dense(x, probs, weights), rtol=1e-4, atol=1e-6
+    )
+    probe = jnp.asarray(rng.normal(size=(n, D)), jnp.float32)
+    got = jax.grad(lambda *a: (run(*a) * probe).sum(), argnums=(0, 1, 2))(
+        x, probs, weights)
+    want = jax.grad(lambda *a: (dense(*a) * probe).sum(), argnums=(0, 1, 2))(
+        x, probs, weights)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+    if skew == "none_here":
+        assert not np.asarray(run(x, probs, weights)).any()
+
+
+def test_spread_and_fold_are_each_others_transpose():
+    n, k, held, buf = 24, 3, 4, 48
+    rng = np.random.RandomState(1)
+    local = jnp.asarray(rng.randint(0, held + 3, size=(n, k)).clip(max=held))
+    sort = sort_held_pairs(local, held, buf)
+    rows = int(sort.rows_held)
+    assert rows == int((local < held).sum()) <= buf
+    assert int(sort.group_sizes.sum()) == buf  # padding rides in the last
+    x = jnp.asarray(rng.normal(size=(n, D)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(buf, D)), jnp.float32)
+    spread = spread_held(x, sort, k)
+    assert not np.asarray(spread[rows:]).any()
+    np.testing.assert_allclose(
+        (spread * y).sum(), (x * fold_held(y, sort, n, k)).sum(), rtol=1e-5
+    )
+    # the fold is the plain scatter-add of the live rows
+    want = jnp.zeros((n, D)).at[sort.token_of_row[:rows]].add(y[:rows])
+    np.testing.assert_allclose(fold_held(y, sort, n, k), want, atol=1e-5)
+
+
+def wide_rows(jaxpr, rows: int, width: int, found=None):
+    """Equations anywhere in ``jaxpr`` with an operand or result of
+    ``rows`` rows and ``width`` or more columns, by primitive."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            shape = getattr(getattr(var, "aval", None), "shape", ())
+            if len(shape) >= 2 and shape[-2] == rows and shape[-1] >= width:
+                found[eqn.primitive.name] = found.get(eqn.primitive.name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            wide_rows(sub, rows, width, found)
+    return found
+
+
+def grouped_matmuls(found: dict) -> int:
+    return sum(n for name, n in found.items() if name.startswith("ragged_dot"))
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_no_row_buffer_is_as_long_as_all_routed_pairs(what):
+    """With a range held no gather, matmul or any other op under the layer
+    touches an ``N x k``-row array of hidden or expert width, forward or
+    backward, in any branch of the ladder; without one the local path
+    gathers exactly that."""
+    n, k, routed, held = 64, 4, 16, 2
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, n, D))
+    params = whole_layer_params(routed, k, x)
+
+    def program(module, p):
+        def fn(p, x):
+            return module.apply({"params": p}, x).sum()
+
+        return jax.make_jaxpr(jax.grad(fn) if what == "gradient" else fn)(
+            p, x).jaxpr
+
+    uncut = wide_rows(program(layer(routed, routed, 0, k), params), n * k, F)
+    assert grouped_matmuls(uncut) >= 2 and uncut.get("gather", 0) >= 1
+    cut = wide_rows(
+        program(layer(routed, held, 4, k), share_of(params, 4, held)),
+        n * k, F,
+    )
+    assert cut == {}
+    # and the rows it does move are the ladder's
+    buffers, passes = held_ladder(n, k, held, routed)
+    assert buffers == (40, 56, 64, 80, 104) and n // passes * k == 64
+    jaxpr = program(layer(routed, held, 4, k), share_of(params, 4, held))
+    for rows in (*buffers, n // passes * k):
+        assert grouped_matmuls(wide_rows(jaxpr, rows, F)) >= 2, rows
+
+
+def test_without_a_range_the_layer_is_the_layer_it_was():
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, D))
+    plain = layer(8, 8, 0, 2)
+    same = MoELayer(
+        hidden_dim=D, intermediate_dim_grouped=F, num_grouped_experts=8,
+        top_k=2, router_score_function="sigmoid",
+        router_enable_expert_bias=True, routed_scaling=2.0,
+        num_routed_experts=8, dtype=jnp.float32, param_dtype=jnp.float32,
+    )
+    params = plain.init(jax.random.PRNGKey(1), x)
+    assert str(jax.make_jaxpr(lambda p: plain.apply(p, x))(params)) == str(
+        jax.make_jaxpr(lambda p: same.apply(p, x))(params))
+    assert "rows_held" not in plain.apply(
+        params, x, mutable=["moe_stats"])[1]["moe_stats"]
+
+
+@pytest.mark.parametrize("kwargs,why", [
+    (dict(routed=16, held=5, first=0), "no share"),
+    (dict(routed=16, held=4, first=13), "no share"),
+    (dict(routed=16, held=4, first=0, ep_axes=("ep",)), "ep_axes"),
+])
+def test_a_range_that_is_no_share_is_refused(kwargs, why):
+    x = jnp.zeros((1, 8, D))
+    module = layer(top_k=2, **kwargs)
+    with pytest.raises(ValueError, match=why):
+        module.init(jax.random.PRNGKey(0), x)
