@@ -39,6 +39,8 @@ from d9d_tpu.ops.ep_dispatch import (
     ep_dispatch_compute_combine,
 )
 from d9d_tpu.ops.moe import (
+    all_experts_swiglu,
+    few_rows_touch_all_experts,
     fold_held,
     gate_up_grouped_matmul,
     grouped_matmul,
@@ -240,20 +242,25 @@ def grouped_swiglu_apply(
     Caveat: because ragged_dot is an opaque custom call, XLA
     materializes the concatenated weight copy each forward (again in the
     backward under remat) — one extra full-weight write+read per MoE layer
-    per microbatch. In decode the copy is 768 MB a layer and 0.235 s of
-    4.12 s traced on ``qwen3-30b-a3b-decode.serve-rollout-closed``
-    (ledger, PR 24, ``breakdown``; ROADMAP S3);
-    ``D9D_TPU_MOE_FUSED_GATE_UP=0`` switches to two grouped matmuls for
-    the on-chip A/B (ROADMAP ``env-selected-kernels``; not run yet).
+    per microbatch; ``D9D_TPU_MOE_FUSED_GATE_UP=0`` switches to two
+    grouped matmuls (a training question: ROADMAP S6,
+    ``env-selected-kernels``). A decode step's 64 rows do not come here
+    since PR 36: the local path sends a call of few rows that reaches
+    nearly every expert through ``ops/moe.py all_experts_swiglu``, which
+    has no concatenation and no ``ragged_dot`` (a fused chunk used to
+    hoist the copy, 768 MB a layer, and keep it). What still does: the
+    training calls, the EP shard body, a held range, and a one-row
+    ``generate`` step, which reads 8 experts of 128.
 
-    The ``moe/experts/{gate_up,act,down}`` scopes are HLO metadata only:
-    in a trace they say which grouped matmul (forward or transposed) an
-    op belongs to. The TPU compiler rewrites ``ragged_dot`` into a custom
-    call named ``ragged-dot-none`` and drops its ``op_name``: the
-    scopes stay on what stands around the call (the weight concatenation,
-    the slices, the probability weighting) and in the lowered HLO, and
-    a trace's reader takes the calls themselves by their name
-    (``benchmarks/harness/layers.py``).
+    The ``moe/experts/{gate_up,act,down}`` scopes are HLO metadata only
+    (the all-expert form carries the same, with ``all_experts`` under
+    the two products'): in a trace they say which grouped matmul
+    (forward or transposed) an op belongs to. The TPU compiler rewrites
+    ``ragged_dot`` into a custom call named ``ragged-dot-none`` and drops
+    its ``op_name``: the scopes stay on what stands around the call (the
+    weight concatenation, the slices, the probability weighting) and in
+    the lowered HLO, and a trace's reader takes the calls themselves by
+    their name (``benchmarks/harness/layers.py``).
     """
     x = permuted_x.astype(dtype)
     with jax.named_scope("moe/experts/gate_up"):
@@ -647,6 +654,17 @@ class MoELayer(nn.Module):
     def _forward_local(
         self, x: Array, topk_ids: Array, topk_probs: Array
     ) -> Array:
+        if moe_ffn_backend() == "xla" and few_rows_touch_all_experts(
+            *topk_ids.shape, self.num_grouped_experts
+        ):
+            # a decode step's few rows reach nearly every expert: plain
+            # products over all of them, nothing sorted, moved or folded
+            experts = self.grouped_experts
+            return all_experts_swiglu(
+                x, topk_ids, topk_probs,
+                experts.gate_weight, experts.up_weight, experts.down_weight,
+                self.dtype,
+            ).astype(x.dtype)
         with jax.named_scope("moe/permute"):
             sort = sort_tokens_by_expert(topk_ids, self.num_grouped_experts)
         if moe_ffn_backend() in ("pallas", "pallas_gather"):

@@ -13,6 +13,12 @@ Replaces the reference kernel layer for MoE (SURVEY §2.2):
   :func:`combine_pairs`) whose transposes are given as gathers, so the
   backward holds no scatter-add of hidden-width rows either.
 
+- A call of few rows that reaches nearly every expert anyway (a decode
+  step: 64 rows x top-8 over 128) skips all of that: plain products over
+  ALL experts, the sum over experts inside the down product's contraction
+  (:func:`all_experts_swiglu`, chosen from static shapes by
+  :func:`few_rows_touch_all_experts`).
+
 All functions operate on a flat token dim; callers reshape [B,T,D]→[N,D].
 """
 
@@ -25,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from d9d_tpu.core.types import Array
+from d9d_tpu.ops.swiglu import silu_mul
 
 
 class TokenSort(NamedTuple):
@@ -254,7 +261,9 @@ def gate_up_grouped_matmul(
     no weight-concat materialization — see nn/moe.py grouped_swiglu_apply
     for the trade-off). Shared by the XLA MoE chain AND the Pallas
     backend's fallback/backward reference (the env switch must cover
-    every path or the perf A/B is inconsistent). Weights must
+    every path or the perf A/B is inconsistent). It covers the grouped
+    matmuls only: :func:`all_experts_swiglu`, which a call of few rows
+    takes, never concatenates and does not consult it. Weights must
     already be in the compute dtype.
     """
     if os.environ.get("D9D_TPU_MOE_FUSED_GATE_UP", "1") == "1":
@@ -290,6 +299,97 @@ def grouped_matmul(x: Array, weight: Array, group_sizes: Array) -> Array:
         ),
         "moe_grouped_dot",
     )
+
+
+# --- few rows: every expert's weights once, through plain products -----------
+
+# A call of N rows that multiplies every expert's weights does 2·N FLOP per
+# bf16 weight, N FLOP per weight byte. The v5e's ridge is 197 TFLOP/s over
+# 819 GB/s = 240 FLOP a byte, so with N well under it the products stay
+# bound by the weights' bytes, which ``ragged_dot`` would stream too: it
+# reads every expert some row chose, and N·K draws over E experts leave
+# (1 - 1/E)^(N·K) ≈ exp(-N·K/E) of them untouched, 14 % at N·K = 2·E and
+# 2 % at a decode step's 64 rows x top-8 over 128. What ``ragged_dot``
+# pays beside the bytes is 7 to 9 us a group (PERF.md §6, PR 36), which
+# a few rows a group cannot hide.
+FEW_ROWS_LIMIT = 128
+
+
+def few_rows_touch_all_experts(
+    num_rows: int, top_k: int, num_experts: int
+) -> bool:
+    """Does a call of these static shapes take :func:`all_experts_swiglu`?
+
+    Only where the all-expert products stay memory-bound (``num_rows`` at
+    most :data:`FEW_ROWS_LIMIT`) and the routing reads nearly every expert
+    anyway (``num_rows * top_k >= 2 * num_experts``: 86 % expected and
+    more). A one-row ``generate`` step (8 draws over 128 experts) keeps
+    ``ragged_dot`` and reads a sixteenth of the bytes; a training call has
+    thousands of rows and keeps it too.
+    """
+    return (
+        num_rows <= FEW_ROWS_LIMIT and num_rows * top_k >= 2 * num_experts
+    )
+
+
+def all_experts_swiglu(
+    x: Array,
+    topk_ids: Array,
+    topk_probs: Array,
+    gate_w: Array,
+    up_w: Array,
+    down_w: Array,
+    dtype: jnp.dtype,
+) -> Array:
+    """The routed SwiGLU of a few rows as plain products over ALL experts.
+
+    x: [N, D]; topk_ids, topk_probs: [N, K]; gate_w, up_w: [E, D, F] and
+    down_w: [E, F, D] as stored → [N, D]. Every row meets every expert:
+    gate and up are two products of the same [N, D] rows (256 KB at a
+    decode step: read twice for nothing, so no ``[E, D, 2F]`` concatenation
+    exists here), and the sum over experts is part of the down product's
+    contraction, ``n (e f), (e f) d -> n d``, accumulated in float32 like
+    any matmul. The router's probabilities enter as an ``[N, E]`` weight on
+    the hidden rows; where a pair was not selected the hidden row is
+    *selected* to zero, not multiplied by it, so what an expert makes of a
+    row it was never routed contributes an exact zero whatever it is.
+
+    No sort, no permute, no combine and no custom call: the compiler's own
+    matmul streams the weights once (the output head's product at the same
+    64 rows reads 85 % of the memory roofline where ``ragged_dot``'s 256
+    groups a layer read 38 %). Plain ops, so autodiff is the backward.
+    Weights are cast to ``dtype`` here as on the grouped path.
+    """
+    n, d = x.shape
+    e, _, f = gate_w.shape
+    x = x.astype(dtype)
+    hit = topk_ids[:, :, None] == jnp.arange(e, dtype=topk_ids.dtype)
+    selected = hit.any(axis=1)  # [N, E]
+    # a token's K choices are distinct, so the sum has one term; drawn
+    # twice, an expert weighs in with both probabilities as on the
+    # grouped path
+    weight = jnp.where(hit, topk_probs[:, :, None], 0).sum(axis=1)
+    rows_by_all = (((1,), (1,)), ((), ()))  # n d, e d f -> n e f
+    with jax.named_scope("moe/experts/gate_up/all_experts"):
+        g = lax.dot_general(
+            x, gate_w.astype(dtype), rows_by_all, preferred_element_type=dtype
+        )
+        u = lax.dot_general(
+            x, up_w.astype(dtype), rows_by_all, preferred_element_type=dtype
+        )
+    with jax.named_scope("moe/experts/act"):
+        hidden = silu_mul(g, u)
+        hidden = jnp.where(
+            selected[:, :, None],
+            hidden * weight[:, :, None].astype(dtype),
+            jnp.zeros((), dtype),
+        )
+    with jax.named_scope("moe/experts/down/all_experts"):
+        return jnp.dot(
+            hidden.reshape(n, e * f),
+            down_w.astype(dtype).reshape(e * f, d),
+            preferred_element_type=dtype,
+        )
 
 
 # --- a held range of a wider router's experts --------------------------------

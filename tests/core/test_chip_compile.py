@@ -16,9 +16,11 @@ need the TPU branch of the code; the program grows no option for it.
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep compiler logs out of /tmp
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -302,6 +304,80 @@ def test_mamba_step_at_jamba2_3b_widths(topo):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= state_bytes  # updated in place
     assert ma.temp_size_in_bytes < state_bytes / 2
+
+
+# -- the local expert path in a decode chunk -----------------------------------
+
+
+def _standalone_results(text: str):
+    """``(opcode, bytes)`` of every array-valued instruction of compiled
+    text that runs as an op of its own: the entry computation and loop
+    bodies, not the insides of a fusion (where a weight-sized ``bitcast``
+    fusion is an operand's view and writes nothing)."""
+    size = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1}
+    fused = set(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head[1]
+            continue
+        found = inside not in fused and re.match(
+            r"^\s+(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+            line,
+        )
+        if found:
+            dtype, dims, opcode = found.groups()
+            count = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            yield opcode, count * size.get(dtype, 4)
+
+
+@pytest.mark.parametrize(
+    "e,inter,top_k,parent_temp",
+    [(E, INTER, TOP_K, 768e6), (64, 1536, 4, 0.81e9)],
+    ids=["qwen3-128x768-top8", "glm-64x1536-top4"],
+)
+def test_expert_block_of_a_decode_chunk(topo, e, inter, top_k, parent_temp):
+    """``MoELayer`` at the two MoE serving cells' expert shapes for the 64
+    rows of a decode step, inside a loop of a chunk's 8 steps: the call
+    takes the all-expert products (``ops/moe.py
+    few_rows_touch_all_experts``), so no ``ragged-dot`` call is in the
+    program, nothing the size of a weight is copied, transposed or
+    concatenated (a chunk hoists such a thing out of its loop and keeps
+    it: the parent held a ``gate|up`` copy of 768 MB / 0.81 GB a layer),
+    and the temporaries are a small part of that."""
+    from d9d_tpu.nn.moe import MoELayer
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    layer = MoELayer(
+        hidden_dim=H, intermediate_dim_grouped=inter, num_grouped_experts=e,
+        top_k=top_k, dtype=BF16, param_dtype=BF16,
+    )
+    x = sds((64, 1, H), BF16)
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        nn.unbox(jax.eval_shape(
+            lambda x: layer.init(jax.random.PRNGKey(0), x)["params"], x
+        )),
+    )
+
+    def chunk(params, x):
+        return jax.lax.fori_loop(
+            0, 8, lambda _, x: x + layer.apply({"params": params}, x), x
+        )
+
+    compiled = jax.jit(chunk).lower(params, x).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert len(re.findall(r"moe/experts/(?:gate_up|down)/all_experts", text)) >= 3
+    one_weight = e * H * inter * 2
+    relaid = [
+        (opcode, size) for opcode, size in _standalone_results(text)
+        if size >= one_weight and opcode in (
+            "copy", "transpose", "concatenate", "fusion", "pad", "reshape")
+    ]
+    assert relaid == []
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temp / 10
 
 
 # -- the model ---------------------------------------------------------------

@@ -142,7 +142,8 @@ def test_local_path_has_no_buffer_choice(ctx):
         hidden_dim=16, intermediate_dim_grouped=32, num_grouped_experts=8,
         top_k=2, dtype=jnp.float32,
     )
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
+    # 256 rows: a call of 128 or fewer takes the all-expert products
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 16))
     params = layer.init(jax.random.PRNGKey(0), x)["params"]
 
     def loss(p, x):

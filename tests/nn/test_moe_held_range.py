@@ -208,14 +208,18 @@ def test_no_row_buffer_is_as_long_as_all_routed_pairs(what):
     x = jax.random.normal(jax.random.PRNGKey(0), (1, n, D))
     params = whole_layer_params(routed, k, x)
 
-    def program(module, p):
+    def program(module, p, x=x):
         def fn(p, x):
             return module.apply({"params": p}, x).sum()
 
         return jax.make_jaxpr(jax.grad(fn) if what == "gradient" else fn)(
             p, x).jaxpr
 
-    uncut = wide_rows(program(layer(routed, routed, 0, k), params), n * k, F)
+    # (at four times the rows: a call of 128 or fewer meets every expert
+    # through plain products and moves no row at all)
+    many = jnp.tile(x, (1, 4, 1))
+    uncut = wide_rows(
+        program(layer(routed, routed, 0, k), params, many), 4 * n * k, F)
     assert grouped_matmuls(uncut) >= 2 and uncut.get("gather", 0) >= 1
     cut = wide_rows(
         program(layer(routed, held, 4, k), share_of(params, 4, held)),

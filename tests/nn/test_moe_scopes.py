@@ -16,7 +16,9 @@ from d9d_tpu.models.deepseek import DeepseekCausalLM, deepseek_v2_tiny
 from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.ops.attention.eager import eager_sdpa
 
-B, T, VOCAB = 4, 16, 256
+# 256 rows a call: past ``ops/moe.py FEW_ROWS_LIMIT``, so the local path is
+# the grouped one these tests read (tests/nn/test_moe_few_rows.py has the other)
+B, T, VOCAB = 4, 64, 256
 MATMUL = re.compile(r"moe/experts/(gate_up|down)")
 
 
