@@ -80,10 +80,14 @@ readback per chunk) is untouched; ``tests/telemetry`` pins
 gap-free by an always-on phase clock, the serving twin of the Trainer's
 (``serve/phase/{admit,plan,dispatch,readback,commit}`` closed by
 ``serve/step``: host clock only, on whether or not a profiler is), so a
-stall inside a chunk names its phase with no capture live. Host
-dispatch/readback/admission regions additionally carry ``serve.*``
-``core/tracing.annotate`` labels inside profiler capture windows
-(``benchmarks/harness/trace.py`` attributes device-idle gaps to them).
+stall inside a chunk names its phase with no capture live. The
+admission, plan, dispatch, readback and commit regions additionally
+carry ``serve.*`` ``core/tracing.annotate`` labels inside profiler
+capture windows (``benchmarks/harness/trace.py`` attributes device-idle
+gaps to them), and each chunk's ``serve/step`` span says what its
+dispatch cost the host: the wrapper's signature walk, the enqueue and
+the argument leaves (``TrackedJit.last_call``), the stagings' seconds
+and count.
 The monitoring plane rides the same boundaries: every request carries
 a fleet-stable trace id (``request_trace`` JSONL milestones),
 ``replica_label`` namespaces the serve instruments per replica
@@ -1560,24 +1564,31 @@ class ContinuousBatcher:
                 self._count("serve/prefix_cache_misses")
         return alloc
 
-    def _push_page_table(self) -> None:
+    def _push_page_table(self) -> int:
         """Sync the device page tables from the host mirror (a tiny
         host→device transfer between dispatches — NOT a tracked
         dispatch). Only ever called at clean boundaries (no chunks in
         flight), so a zeroed row reroutes any still-live zombie row's
-        writes to the garbage page before its next chunk."""
+        writes to the garbage page before its next chunk. Returns the
+        transfers made: one per page-table leaf, none while clean."""
         if not self._kv_table_dirty:
-            return
+            return 0
         self._kv_table_dirty = False
         from d9d_tpu.nn.decode_flags import map_page_table
 
         table = self._kv.table
-        # one fresh buffer PER leaf: the cache is donated into the
-        # fused dispatch, and donating one shared buffer through N
-        # layer scopes trips XLA's double-donation check
-        self._cache = map_page_table(
-            self._cache, lambda _pt: jnp.asarray(table)
-        )
+        pushed = 0
+
+        def push(_pt):
+            # one fresh buffer PER leaf: the cache is donated into the
+            # fused dispatch, and donating one shared buffer through N
+            # layer scopes trips XLA's double-donation check
+            nonlocal pushed
+            pushed += 1
+            return jnp.asarray(table)
+
+        self._cache = map_page_table(self._cache, push)
+        return pushed
 
     def _release_row_pages(self, row: int, *, device_dead: bool) -> None:
         """Drop a retired row's page references. ``device_dead`` rows
@@ -1944,7 +1955,17 @@ class ContinuousBatcher:
         the page-table push; ``plan`` builds the forced tokens, splits
         the RNG and stages the arguments on the device; ``dispatch`` is
         the call into the fused program (enqueue only) and the record
-        of the plan.
+        of the plan. The profiler's annotations (on during a capture)
+        cut the same code a little differently: ``serve.plan`` ends
+        before the three plan arrays are staged and ``serve.dispatch``
+        holds that staging and the call, but not the record of the plan.
+
+        The chunk's closing ``serve/step`` span also says what the
+        dispatch cost the host, whichever phase it fell in:
+        ``dispatch_key_s``, ``dispatch_enqueue_s``, ``dispatch_arg_leaves``
+        (the fused program's ``TrackedJit.last_call``) and ``stage_s``,
+        ``stage_transfers``: the page-table pushes, the RNG split, the
+        admission arrays and the three plan arrays.
         """
         clock = self._clock or self._tele.phases(
             "serve", step=self.stats.chunks
@@ -1953,6 +1974,9 @@ class ContinuousBatcher:
         admit_mask = np.zeros((self._b,), bool)
         admit_budget = np.zeros((self._b,), np.int32)
         admit_pos = np.zeros((self._b,), np.int32)
+        # seconds and count of this chunk's host-to-device stagings,
+        # wherever they sit: two clock reads a site
+        stage_s, stage_n = 0.0, 0
         if admit:
             with annotate("serve.admit"):
                 now = time.perf_counter()
@@ -1989,46 +2013,57 @@ class ContinuousBatcher:
                     admit_pos[i] = start_pos
                     self._note_admit(req.rid)
                 if self._paged:
-                    self._push_page_table()
+                    t = time.perf_counter()
+                    stage_n += self._push_page_table()
+                    stage_s += time.perf_counter() - t
                 self._note_pages()
         clock.mark("admit")
 
-        forced = np.zeros((self._b, k), np.int32)
-        n_forced = np.zeros((self._b,), np.int32)
-        emit_from = np.full((self._b,), k, np.int32)
-        rids, pos = [], []
-        for i, slot in enumerate(self._slots):
-            rids.append(slot.rid)
-            pos.append(slot.pos)
-            if slot.rid < 0:
-                continue
-            slot.pos += k
-            m = len(slot.feed)
-            nf = min(m, k)
-            if nf:
-                forced[i, :nf] = slot.feed[:nf]
-            n_forced[i] = nf
-            emit_from[i] = max(m - 1, 0)
-            slot.feed = slot.feed[k:]
+        with annotate("serve.plan"):
+            forced = np.zeros((self._b, k), np.int32)
+            n_forced = np.zeros((self._b,), np.int32)
+            emit_from = np.full((self._b,), k, np.int32)
+            rids, pos = [], []
+            for i, slot in enumerate(self._slots):
+                rids.append(slot.rid)
+                pos.append(slot.pos)
+                if slot.rid < 0:
+                    continue
+                slot.pos += k
+                m = len(slot.feed)
+                nf = min(m, k)
+                if nf:
+                    forced[i, :nf] = slot.feed[:nf]
+                n_forced[i] = nf
+                emit_from[i] = max(m - 1, 0)
+                slot.feed = slot.feed[k:]
 
-        self._rng, sub = jax.random.split(self._rng)
-        with_admit = bool(admit_mask.any())
-        fused = self._fused.get((k, with_admit))
-        if fused is None:
-            fused = self._fused[(k, with_admit)] = self._build_fused(
-                k, with_admit
-            )
-        admit_args = ()
-        if with_admit:
-            admit_args = (jnp.asarray(admit_mask), jnp.asarray(admit_budget))
-            if self._paged:
-                admit_args += (jnp.asarray(admit_pos),)
+            with_admit = bool(admit_mask.any())
+            fused = self._fused.get((k, with_admit))
+            if fused is None:
+                fused = self._fused[(k, with_admit)] = self._build_fused(
+                    k, with_admit
+                )
+            t = time.perf_counter()
+            self._rng, sub = jax.random.split(self._rng)
+            admit_args = ()
+            if with_admit:
+                admit_args = (
+                    jnp.asarray(admit_mask), jnp.asarray(admit_budget)
+                )
+                if self._paged:
+                    admit_args += (jnp.asarray(admit_pos),)
+            stage_n += 1 + len(admit_args)
+            stage_s += time.perf_counter() - t
         with annotate("serve.dispatch"):
+            t = time.perf_counter()
             # forced_t: scan xs layout [K, B]
             plan_args = (
                 jnp.asarray(forced.T), jnp.asarray(n_forced),
                 jnp.asarray(emit_from),
             )
+            stage_n += len(plan_args)
+            stage_s += time.perf_counter() - t
             clock.mark("plan")
             (self._cache, self._tok_d, self._pos_d, self._live_d,
              self._rem_d, toks) = fused(
@@ -2055,9 +2090,17 @@ class ContinuousBatcher:
         rows_reset = int(admit_mask.sum())
         self.stats.rows_reset += rows_reset
         self.stats.recurrent_state_bytes = self._recurrent_state_bytes
+        # what the wrapper's own Python and the enqueue cost this chunk
+        # (TrackedJit.last_call), beside the stagings counted above
+        cost = fused.last_call
         clock.meta.update(
             recurrent_state_bytes=self._recurrent_state_bytes,
             rows_reset=rows_reset,
+            dispatch_key_s=cost.key_s,
+            dispatch_enqueue_s=cost.enqueue_s,
+            dispatch_arg_leaves=cost.arg_leaves,
+            stage_s=stage_s,
+            stage_transfers=stage_n,
         )
         if self._paged:
             # on the closing serve/step span too: ServeStats gives a
@@ -2080,77 +2123,82 @@ class ContinuousBatcher:
         # the wait for the device plus the transfer; what follows replays
         # emission and stop logic on the host: serve/phase/commit
         clock.mark("readback")
-        now = time.perf_counter()
-        self._progress_t = now
-        if self._first_readback_t is None:
-            self._first_readback_t = now
-        self.stats.readbacks += 1
-        self.stats.slot_steps_total += self._b * plan.k
-        chunk_busy = 0
-        chunk_positions = 0
-        chunk_tokens = 0
-        emitted: dict[int, list[int]] = {}
-        for i, rid in enumerate(plan.rids):
-            if rid < 0 or rid in self.done:
-                # idle at dispatch, or finished in an earlier chunk that
-                # was harvested after this one was (speculatively)
-                # dispatched — the device masked it dead already
-                continue
-            slot = self._slots[i]
-            # exact occupancy, replayed like the device's stop masks: a
-            # row is busy through the step it dies on, idle after
-            busy_steps = plan.k
-            for j in range(min(plan.emit_from[i], plan.k), plan.k):
-                tok = int(toks[i, j])
-                emitted.setdefault(rid, []).append(tok)
-                self.outputs[rid].append(tok)
-                slot.emitted += 1
-                self.stats.emitted_tokens += 1
-                chunk_tokens += 1
-                if slot.emitted >= slot.budget or (
-                    self._eos is not None and tok == self._eos
-                ):
-                    self.done.add(rid)
-                    self._slots[i] = _Slot()
-                    busy_steps = j + 1
-                    if self._paged:
-                        # the device row died IN-DEVICE at this same
-                        # step (its later writes are pinned to the
-                        # garbage page), so the pages free immediately;
-                        # reuse waits for the next admit boundary,
-                        # which pushes the zeroed table row first
-                        self._release_row_pages(i, device_dead=True)
-                    break
-            self.stats.slot_steps_busy += busy_steps
-            # step j of the row writes position pos + j and attends
-            # positions 0..pos + j
-            chunk_positions += (
-                busy_steps * plan.pos[i] + busy_steps * (busy_steps + 1) // 2
+        with annotate("serve.commit"):
+            now = time.perf_counter()
+            self._progress_t = now
+            if self._first_readback_t is None:
+                self._first_readback_t = now
+            self.stats.readbacks += 1
+            self.stats.slot_steps_total += self._b * plan.k
+            chunk_busy = 0
+            chunk_positions = 0
+            chunk_tokens = 0
+            emitted: dict[int, list[int]] = {}
+            for i, rid in enumerate(plan.rids):
+                if rid < 0 or rid in self.done:
+                    # idle at dispatch, or finished in an earlier chunk
+                    # that was harvested after this one was (speculatively)
+                    # dispatched — the device masked it dead already
+                    continue
+                slot = self._slots[i]
+                # exact occupancy, replayed like the device's stop masks:
+                # a row is busy through the step it dies on, idle after
+                busy_steps = plan.k
+                for j in range(min(plan.emit_from[i], plan.k), plan.k):
+                    tok = int(toks[i, j])
+                    emitted.setdefault(rid, []).append(tok)
+                    self.outputs[rid].append(tok)
+                    slot.emitted += 1
+                    self.stats.emitted_tokens += 1
+                    chunk_tokens += 1
+                    if slot.emitted >= slot.budget or (
+                        self._eos is not None and tok == self._eos
+                    ):
+                        self.done.add(rid)
+                        self._slots[i] = _Slot()
+                        busy_steps = j + 1
+                        if self._paged:
+                            # the device row died IN-DEVICE at this same
+                            # step (its later writes are pinned to the
+                            # garbage page), so the pages free
+                            # immediately; reuse waits for the next admit
+                            # boundary, which pushes the zeroed table row
+                            self._release_row_pages(i, device_dead=True)
+                        break
+                self.stats.slot_steps_busy += busy_steps
+                # step j of the row writes position pos + j and attends
+                # positions 0..pos + j
+                chunk_positions += (
+                    busy_steps * plan.pos[i]
+                    + busy_steps * (busy_steps + 1) // 2
+                )
+                # steps in which the row only consumed a prompt token,
+                # from the plan the chunk was dispatched with; a row emits
+                # before it dies, so these never pass the step it died on
+                self.stats.slot_steps_prompt += min(
+                    plan.emit_from[i], plan.k
+                )
+                chunk_busy += busy_steps
+                if rid in emitted:
+                    self._note_tokens(rid, len(emitted[rid]), now)
+                    if rid in self.done:
+                        self._note_finish(rid, now, version=plan.version)
+            self.stats.positions_attended += chunk_positions
+            # this chunk's share of the two counters, for a reader of the
+            # span timeline (a traced window's chunks, say)
+            clock.meta.update(
+                slot_steps_busy=chunk_busy, positions_attended=chunk_positions
             )
-            # steps in which the row only consumed a prompt token, from
-            # the plan the chunk was dispatched with; a row emits before
-            # it dies, so these never pass the step it died on
-            self.stats.slot_steps_prompt += min(plan.emit_from[i], plan.k)
-            chunk_busy += busy_steps
-            if rid in emitted:
-                self._note_tokens(rid, len(emitted[rid]), now)
-                if rid in self.done:
-                    self._note_finish(rid, now, version=plan.version)
-        self.stats.positions_attended += chunk_positions
-        # this chunk's share of the two counters, for a reader of the
-        # span timeline (a traced window's chunks, say)
-        clock.meta.update(
-            slot_steps_busy=chunk_busy, positions_attended=chunk_positions
-        )
-        self._observe(
-            "serve/slot_util", chunk_busy / (self._b * plan.k), _UTIL_EDGES
-        )
-        self._note_throughput(chunk_tokens, now)
-        if self._clock is None:
-            # the overlapped drain: this harvest's own clock, phases only
-            # (inside step_chunk / step the chunk's clock ends ``commit``
-            # when it closes and emits ``serve/step``)
-            clock.mark("commit")
+            self._observe(
+                "serve/slot_util", chunk_busy / (self._b * plan.k),
+                _UTIL_EDGES,
+            )
+            self._note_throughput(chunk_tokens, now)
+            if self._clock is None:
+                # the overlapped drain: this harvest's own clock, phases
+                # only (inside step_chunk / step the chunk's clock ends
+                # ``commit`` when it closes and emits ``serve/step``)
+                clock.mark("commit")
         return emitted
 
     def _sync(self) -> dict[int, list[int]]:

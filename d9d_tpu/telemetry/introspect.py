@@ -47,11 +47,12 @@ import logging
 import threading
 import time
 import weakref
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 from d9d_tpu.telemetry import audit_capture  # stdlib-only at import
 
 __all__ = [
+    "CallCost",
     "ExecutableRecord",
     "RecompileGuard",
     "TrackedJit",
@@ -64,6 +65,17 @@ __all__ = [
 ]
 
 logger = logging.getLogger("d9d_tpu.telemetry.introspect")
+
+
+class CallCost(NamedTuple):
+    """What one call through a :class:`TrackedJit` cost the host: the
+    seconds before the compiled call (flatten, signature, lookup; a
+    compile's own seconds are left out, its span has them), the seconds
+    inside ``compiled(*args)`` (the enqueue), and the argument leaves."""
+
+    key_s: float
+    enqueue_s: float
+    arg_leaves: int
 
 
 @dataclasses.dataclass
@@ -85,6 +97,11 @@ class ExecutableRecord:
     generated_code_bytes: int | None = None
     alias_bytes: int | None = None
     calls: int = 0
+    # what the wrapper itself cost over those calls (host clock, no
+    # sync): finding the executable, and inside ``compiled(*args)``
+    key_s: float = 0.0
+    enqueue_s: float = 0.0
+    arg_leaves: int = 0  # argument leaves of this signature
     # compile-time artifact facts (telemetry/audit_capture.py): only
     # populated when audit capture is opted in — collective census,
     # donation coverage, baked consts, dtype census, host callbacks
@@ -344,6 +361,10 @@ class TrackedJit:
         self._compiled: dict[Any, Any] = {}
         self._records: dict[Any, ExecutableRecord] = {}
         self._fallback = False
+        # the newest call's own cost on the host (None before any): the
+        # caller that owns the call's span puts it there; the running
+        # totals are on the signature's ExecutableRecord
+        self.last_call: CallCost | None = None
         self._lock = threading.Lock()
         with _INVENTORY_LOCK:
             _WRAPPERS.add(self)
@@ -488,11 +509,14 @@ class TrackedJit:
         return compiled
 
     def __call__(self, *args, **kwargs):
+        t0 = time.perf_counter()
         if self._fallback:
-            return self._jit(*args, **kwargs)
+            return self._call_plain(t0, args, kwargs)
         key = self._signature_key(args, kwargs)
         compiled = self._compiled.get(key)
+        compile_s = 0.0
         if compiled is None:
+            tc = time.perf_counter()
             with self._lock:
                 compiled = self._compiled.get(key)
                 if compiled is None and not self._fallback:
@@ -500,11 +524,29 @@ class TrackedJit:
                     if compiled is not None:
                         self._compiled[key] = compiled
             if compiled is None:  # degraded inside _compile
-                return self._jit(*args, **kwargs)
+                return self._call_plain(t0, args, kwargs)
+            compile_s = time.perf_counter() - tc
         record = self._records.get(key)
         if record is not None:
             record.calls += 1
-        return compiled(*args, **kwargs)
+        t1 = time.perf_counter()
+        out = compiled(*args, **kwargs)
+        t2 = time.perf_counter()
+        cost = self.last_call = CallCost(
+            t1 - t0 - compile_s, t2 - t1, len(key[0])
+        )
+        if record is not None:
+            record.key_s += cost.key_s
+            record.enqueue_s += cost.enqueue_s
+            record.arg_leaves = cost.arg_leaves
+        return out
+
+    def _call_plain(self, t0, args, kwargs):
+        """The degraded site: plain ``jax.jit``, whose own cache finds
+        the executable inside the call."""
+        out = self._jit(*args, **kwargs)
+        self.last_call = CallCost(0.0, time.perf_counter() - t0, 0)
+        return out
 
 
 def tracked_jit(fn: Callable, *, name: str, **jit_kwargs: Any) -> TrackedJit:
