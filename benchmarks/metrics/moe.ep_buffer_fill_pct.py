@@ -4,13 +4,8 @@ window's ``train/step`` spans (the Trainer puts a fetched step's
 ``moe/*`` scalars there). Mean over the fetched steps; spans without it
 (a program with no expert-parallel exchange) give nothing to read."""
 
-from benchmarks.harness import layers
+from benchmarks.metrics import span_meta
 
 
 def read(run):
-    fills = [
-        s.meta["moe/ep_buffer_fill"]
-        for s in layers.window_spans(run, {"train/step"})
-        if s.meta and "moe/ep_buffer_fill" in s.meta
-    ]
-    return 100.0 * sum(fills) / len(fills) if fills else None
+    return span_meta.mean(run, "train/step", "moe/ep_buffer_fill", 100.0)

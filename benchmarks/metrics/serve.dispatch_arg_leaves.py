@@ -3,13 +3,9 @@
 spans (the program with admission takes two or three arrays more than
 the one without). Spans without the key give nothing to read."""
 
-from benchmarks.harness import layers
+from benchmarks.metrics import span_meta
 
 
 def read(run):
-    leaves = [
-        s.meta["dispatch_arg_leaves"]
-        for s in layers.window_spans(run, {"serve/step"})
-        if s.meta and "dispatch_arg_leaves" in s.meta
-    ]
+    leaves = span_meta.values(run, "serve/step", "dispatch_arg_leaves")
     return max(leaves) if leaves else None

@@ -3,13 +3,8 @@
 ``serve/step`` span as ``dispatch_enqueue_s``. Mean over the measured
 window's chunks; spans without the key give nothing to read."""
 
-from benchmarks.harness import layers
+from benchmarks.metrics import span_meta
 
 
 def read(run):
-    seconds = [
-        s.meta["dispatch_enqueue_s"]
-        for s in layers.window_spans(run, {"serve/step"})
-        if s.meta and "dispatch_enqueue_s" in s.meta
-    ]
-    return 1e3 * sum(seconds) / len(seconds) if seconds else None
+    return span_meta.mean(run, "serve/step", "dispatch_enqueue_s", 1e3)

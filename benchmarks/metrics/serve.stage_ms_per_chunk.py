@@ -4,13 +4,8 @@ in ``plan``, the three plan arrays): ``stage_s`` on each chunk's
 ``serve/step`` span. Mean over the measured window's chunks; spans
 without the key give nothing to read."""
 
-from benchmarks.harness import layers
+from benchmarks.metrics import span_meta
 
 
 def read(run):
-    seconds = [
-        s.meta["stage_s"]
-        for s in layers.window_spans(run, {"serve/step"})
-        if s.meta and "stage_s" in s.meta
-    ]
-    return 1e3 * sum(seconds) / len(seconds) if seconds else None
+    return span_meta.mean(run, "serve/step", "stage_s", 1e3)

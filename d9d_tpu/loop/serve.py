@@ -967,15 +967,21 @@ class ContinuousBatcher:
         stream halves while the compiled signature stays a pure
         function of the (quantized) tree's shapes/dtypes. On an
         unquantized tree this is a structural no-op."""
+        from d9d_tpu.nn.decode_flags import caller_holds_bounds
+
         params = dequantize_params(params)
         kwargs = {"mask": None}
         if self._step_pad is not None:
             kwargs["padding_mask"] = self._step_pad
-        logits, state = self._model.apply(
-            {"params": params, "cache": cache},
-            tok[:, None], pos[:, None],
-            method=self._method, mutable=["cache"], **kwargs,
-        )
+        # submit() refuses what would pass decode_max_length and a step
+        # takes one token, so the modules trace no debug check: its
+        # effect would cost the program jax's C++ dispatch
+        with caller_holds_bounds():
+            logits, state = self._model.apply(
+                {"params": params, "cache": cache},
+                tok[:, None], pos[:, None],
+                method=self._method, mutable=["cache"], **kwargs,
+            )
         return state["cache"], logits[:, -1].astype(jnp.float32)
 
     def _sample(self, row_logits, key):

@@ -36,6 +36,33 @@ def in_continuation_chunk() -> bool:
     return _continuation.get()
 
 
+_bounds_held = contextvars.ContextVar(
+    "d9d_tpu_decode_bounds_held", default=False
+)
+
+
+@contextlib.contextmanager
+def caller_holds_bounds():
+    """Mark model calls in this block as made by a caller that enforces
+    the decode contracts itself, on the host, before it dispatches (the
+    serving loop: ``submit()`` refuses a request that would pass
+    ``decode_max_length`` and a step takes one token): the attention
+    modules then trace no ``checkify.debug_check``. The check is a
+    no-op in plain jit, but its ``ErrorEffect`` stays on the jaxpr, and
+    a compiled program with an unordered effect gets no C++ dispatch
+    from jax: every call flattens, shards and wraps its arguments in
+    Python (12 to 14 us a leaf on the chip's host; PERF.md, PR 37)."""
+    token = _bounds_held.set(True)
+    try:
+        yield
+    finally:
+        _bounds_held.reset(token)
+
+
+def bounds_held_by_caller() -> bool:
+    return _bounds_held.get()
+
+
 def map_cache_index(cache, fn):
     """Apply ``fn`` to every decode write-index leaf of a cache pytree.
 

@@ -20,7 +20,6 @@ process itself (``host/gc``: the cyclic collector's pauses).
 """
 
 import collections
-import contextlib
 import gc
 import logging
 import threading
@@ -67,7 +66,6 @@ __all__ = [
     "exp_edges",
     "get_telemetry",
     "set_telemetry",
-    "attached_jsonl_sink",
     "iter_events",
     "validate_event",
     "model_flops_per_token",
@@ -386,25 +384,3 @@ from d9d_tpu.telemetry.numerics import (  # noqa: E402
     TrainDriftMonitor,
     default_drift_policies,
 )
-
-
-@contextlib.contextmanager
-def attached_jsonl_sink(directory, *, run_name: str):
-    """Attach a :class:`JsonlSink` for ``directory`` to the process hub
-    for the duration and remove it on exit; flush cadence stays with the
-    caller. Yields ``(hub, sink)`` — ``sink`` is ``None`` and nothing is
-    attached when ``directory`` is falsy, so env-gated bench harnesses
-    share one code path either way."""
-    hub = get_telemetry()
-    if not directory:
-        yield hub, None
-        return
-    import jax  # deferred (process_index): the package core stays jax-free
-
-    sink = hub.add_sink(JsonlSink(
-        directory, run_name=run_name, process_index=jax.process_index(),
-    ))
-    try:
-        yield hub, sink
-    finally:
-        hub.remove_sink(sink)

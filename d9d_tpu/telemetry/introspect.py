@@ -28,14 +28,18 @@ observable for free:
   benchmark's ``entry.compile_s`` / ``entry.lower_s``
   (``benchmarks/harness/readers.py``).
 
-The happy path costs one extra host-side tuple build per call (the
-signature key — the same work ``jax.jit``'s own cache-key computation
-does) and **zero** extra device dispatches or readbacks: the AOT call
-is the very dispatch ``jax.jit`` would have made. If the AOT machinery
-raises during lower/compile (exotic argument types, plugin quirks), the
-wrapper permanently degrades to the plain jitted function for that
-site, logs once, and keeps the program running — introspection must
-never take down training.
+The happy path costs **zero** extra device dispatches or readbacks: the
+AOT call is the very dispatch ``jax.jit`` would have made. The
+signature key is a Python walk over every argument leaf; it runs when a
+signature is first seen and when the C++ dispatch of the last call's
+executable does not recognise a call (``TrackedJit._steady_call``), not
+on every call: a steady loop pays three clock reads and a tuple, which
+each call also reports (``TrackedJit.last_call``, totals on the
+``ExecutableRecord``). If the AOT machinery raises during lower/compile
+(exotic argument types, plugin quirks), the wrapper permanently
+degrades to the plain jitted function for that site, logs once, and
+keeps the program running — introspection must never take down
+training.
 
 No jax import at module load: the telemetry package core stays
 jax-free; ``tracked_jit`` defers the import to first use.
@@ -333,6 +337,14 @@ def _leaf_sig(x) -> Any:
     return repr(x)
 
 
+class _OtherSignature(Exception):
+    """A steady call met arguments of another signature: ``key``."""
+
+    def __init__(self, key):
+        super().__init__()
+        self.key = key
+
+
 class TrackedJit:
     """``jax.jit`` with compile/recompile/cost/HBM accounting.
 
@@ -365,6 +377,11 @@ class TrackedJit:
         # caller that owns the call's span puts it there; the running
         # totals are on the signature's ExecutableRecord
         self.last_call: CallCost | None = None
+        # the newest signature's C++-dispatched call (_steady_call), and
+        # every one made so far
+        self._steady: tuple | None = None
+        self._walked_s = 0.0  # of a walk inside the newest steady call
+        self._steady_calls: dict[Any, tuple] = {}
         self._lock = threading.Lock()
         with _INVENTORY_LOCK:
             _WRAPPERS.add(self)
@@ -512,7 +529,22 @@ class TrackedJit:
         t0 = time.perf_counter()
         if self._fallback:
             return self._call_plain(t0, args, kwargs)
-        key = self._signature_key(args, kwargs)
+        key = None
+        steady = self._steady
+        if steady is not None:
+            # the signature of the call before: the C++ dispatch of its
+            # executable recognises it with no Python walk over the leaves
+            call, record, leaves = steady
+            t1 = time.perf_counter()
+            try:
+                out = call(*args, **kwargs)
+            except _OtherSignature as other:
+                key = other.key
+            else:
+                self._account(record, t0, t1, 0.0, leaves)
+                return out
+        if key is None:
+            key = self._signature_key(args, kwargs)
         compiled = self._compiled.get(key)
         compile_s = 0.0
         if compiled is None:
@@ -527,19 +559,73 @@ class TrackedJit:
                 return self._call_plain(t0, args, kwargs)
             compile_s = time.perf_counter() - tc
         record = self._records.get(key)
-        if record is not None:
-            record.calls += 1
         t1 = time.perf_counter()
         out = compiled(*args, **kwargs)
-        t2 = time.perf_counter()
+        self._account(record, t0, t1, compile_s, len(key[0]))
+        self._steady = self._steady_call(key, compiled, record)
+        return out
+
+    def _account(self, record, t0, t1, compile_s, leaves):
+        """File what the call that just returned cost the host: as
+        ``last_call`` for the caller, and onto the signature's record."""
+        # a walk the C++ dispatch asked for ran inside the call: it is
+        # the key's, not the enqueue's
+        walked, self._walked_s = self._walked_s, 0.0
         cost = self.last_call = CallCost(
-            t1 - t0 - compile_s, t2 - t1, len(key[0])
+            t1 - t0 - compile_s + walked,
+            time.perf_counter() - t1 - walked, leaves,
         )
         if record is not None:
+            record.calls += 1
             record.key_s += cost.key_s
             record.enqueue_s += cost.enqueue_s
-            record.arg_leaves = cost.arg_leaves
-        return out
+            record.arg_leaves = leaves
+
+    def _steady_call(self, key, compiled, record):
+        """``(call, record, leaves)`` for the steady state of ``key``:
+        ``call`` dispatches ``compiled`` from C++ for every argument
+        signature it has seen, as ``jax.jit`` and ``Compiled.__call__``
+        do, and asks Python only about one it has not. There the walk
+        over the leaves decides: this executable's signature goes on to
+        jax's own AOT call (which C++ then remembers), any other raises
+        :class:`_OtherSignature` with the key the walk made. ``None``
+        where this jax does not offer the pieces (the walk then runs on
+        every call, as before), or the record is gone."""
+        if record is None:
+            return None
+        made = self._steady_calls.get(key)
+        if made is None:
+            aot_miss = getattr(
+                getattr(compiled, "_call", None), "_cache_miss", None
+            )
+            if aot_miss is None:  # no C++ call for this executable
+                return None
+
+            me = weakref.ref(self)  # the call hangs off self: no cycle
+
+            def cache_miss(*args, **kwargs):
+                wrapper = me()
+                t = time.perf_counter()
+                seen = wrapper._signature_key(args, kwargs)
+                if seen != key:
+                    raise _OtherSignature(seen)
+                wrapper._walked_s = time.perf_counter() - t
+                return aot_miss(*args, **kwargs)
+
+            try:
+                from jax._src import tree_util
+                from jax._src.interpreters import pxla
+                from jax._src.lib import xla_client
+
+                call = xla_client._xla.pjit(
+                    self.name, None, cache_miss, [], [],
+                    pxla.JitGlobalCppCacheKeys(),
+                    tree_util.dispatch_registry, pxla.cc_shard_arg,
+                )
+            except Exception:  # noqa: BLE001 — another jax: keep walking
+                return None
+            made = self._steady_calls[key] = (call, record, len(key[0]))
+        return made
 
     def _call_plain(self, t0, args, kwargs):
         """The degraded site: plain ``jax.jit``, whose own cache finds
