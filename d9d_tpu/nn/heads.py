@@ -23,6 +23,12 @@ class LanguageModellingHead(nn.Module):
     logits for inference/eval paths. ``tied`` heads own no parameters:
     both take the embedding table ``[V, D]`` the caller hands them
     (``tie_word_embeddings``), through the same fused cross-entropy.
+
+    ``ce_chunk_size`` is ``linear_cross_entropy``'s ``chunk_size``:
+    ``"auto"`` (what every model passes) is one slab for a short call and,
+    beyond the single-slab size, a scan over blocks of the vocabulary with
+    the tokens whole; an int pins the older loop over token chunks of that
+    size with the vocabulary whole.
     """
 
     vocab_ranges: tuple[tuple[str, int], ...]

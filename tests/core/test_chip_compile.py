@@ -306,6 +306,42 @@ def test_mamba_step_at_jamba2_3b_widths(topo):
     assert ma.temp_size_in_bytes < state_bytes / 2
 
 
+# -- the output head's fused cross-entropy -----------------------------------
+
+
+@pytest.mark.parametrize("v", [151936, 102400], ids=["qwen3", "deepseek"])
+def test_linear_ce_block_loop_at_a_step_of_16k_tokens(topo, v):
+    """Loss and both gradients of the fused cross-entropy at the one-chip
+    training cells' sizes (16,384 tokens x 2,048, Qwen3's vocabulary with
+    its ragged rest and DeepSeek's 25 even blocks): the compiled loops
+    hold a ``[blocks, 4096, 2048]`` stack of the weight and of its
+    gradient and the hidden state's float32 gradient, never the weight's
+    whole gradient as loop state beside them, and no ``[N, V]`` array."""
+    from d9d_tpu.ops.linear_ce import linear_cross_entropy
+
+    n, d = 16384, 2048
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+
+    def total(h, w, labels, cot):
+        return (linear_cross_entropy(h, w, labels) * cot).sum()
+
+    compiled = jax.jit(jax.value_and_grad(total, argnums=(0, 1))).lower(
+        sds((n, d), BF16), sds((v, d), BF16), sds((n,), jnp.int32),
+        sds((n,), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    whiles = [line for line in text.splitlines() if " while(" in line]
+    assert len(whiles) == 2  # the forward scan and the backward scan
+    blocks = v // 4096
+    for line in whiles:
+        assert f"bf16[{blocks},4096,{d}]" in line
+        assert f"[{v},{d}]" not in line and f"[{d},{v}]" not in line
+    assert f"f32[{n},{d}]" in whiles[1]  # the carry, rounded once after
+    assert f"[{n},{v}]" not in text and f"[{v},{n}]" not in text
+    # slab, stacked gradient, a copy of the blocks where V is ragged
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 # -- the local expert path in a decode chunk -----------------------------------
 
 

@@ -82,9 +82,20 @@ def test_fused_qkv_rejects_tp_mesh():
         m.init(jax.random.PRNGKey(0), tokens, pos, labels)
 
 
-def test_cce_auto_respects_vocab_budget():
-    """auto must keep chunking when n*V exceeds the swept slab even at
-    small n (large-vocab models never materialize [N, V])."""
+@pytest.fixture
+def no_ambient_mesh():
+    """The tests above leave their mesh ambient, and under a mesh of
+    several devices "auto" keeps the token-chunk loop."""
+    import numpy as np
+
+    with jax.set_mesh(jax.sharding.Mesh(np.empty((), dtype=object), ())):
+        yield
+
+
+def test_cce_auto_respects_vocab_budget(no_ambient_mesh):
+    """auto must keep looping when n*V exceeds the swept slab even at
+    small n (large-vocab models never materialize [N, V]): every slab is
+    a block of the vocabulary inside the budget, the tokens whole."""
     from unittest import mock
 
     import d9d_tpu.ops.linear_ce as lce
@@ -93,11 +104,12 @@ def test_cce_auto_respects_vocab_budget():
     w = jnp.ones((131072, 8), jnp.float32)  # n*V = 2^27 >> swept budget
     labels = jnp.zeros((1024,), jnp.int32)
     with mock.patch.object(
-        lce, "_chunk_loss", wraps=lce._chunk_loss
-    ) as spy:
+        lce, "_block_stats", wraps=lce._block_stats
+    ) as spy, mock.patch.object(lce, "_chunk_loss") as chunk:
         lce.linear_cross_entropy(h, w, labels)
-    # chunked path: _chunk_loss is called via lax.map body trace, with a
-    # [512, ...] chunk — never the full 1024-token slab
-    assert spy.called
+    # block loop: _block_stats is called via the scan's body trace, with
+    # all 1024 tokens against a [65536, 8] block — never the whole table
+    assert spy.called and not chunk.called
     for call in spy.call_args_list:
-        assert call.args[0].shape[0] == 512
+        assert call.args[0].shape[0] == 1024
+        assert call.args[2].shape[0] * 1024 <= lce._AUTO_SINGLE_CHUNK_MAX_LOGITS

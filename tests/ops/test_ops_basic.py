@@ -241,6 +241,339 @@ class TestLinearCrossEntropy:
         assert np.isfinite(np.asarray(out)).all()
 
 
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr, through scans, remats and calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(inner)
+
+
+def _loop_carries(jaxpr):
+    """Shape and dtype of every scan's loop state (its carry, not what
+    it scans)."""
+    shapes = []
+    for eqn in _all_eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
+            shapes += [
+                (v.aval.shape, v.aval.dtype)
+                for v in eqn.invars[consts : consts + carry]
+            ]
+    return shapes
+
+
+def _product_shapes(jaxpr):
+    return [
+        eqn.outvars[0].aval.shape
+        for eqn in _all_eqns(jaxpr)
+        if eqn.primitive.name == "dot_general"
+    ]
+
+
+# (tokens, vocabulary, single-slab tokens, slab budget in logits,
+# logit_softcap): the module's two thresholds shrunk until toy shapes
+# take the block loop
+_BLOCK_CASES = {
+    # V = 2^5 x 11: two blocks of 128 columns and a rest of 96
+    "ragged-vocabulary": (48, 352, 8, 48 * 128, None),
+    # 102 = 3 tiles of 34 tokens for 100: the last tile is padded
+    "token-tiles-softcap": (100, 352, 8, 40 * 128, 5.0),
+    "even-vocabulary-softcap": (48, 512, 8, 48 * 128, 5.0),
+    # over the token threshold, under the slab's: one block, no rest
+    "one-block": (48, 96, 8, 48 * 128, None),
+}
+
+
+class TestLinearCrossEntropyVocabBlocks:
+    """``chunk_size="auto"`` above the single-slab size: a scan over
+    blocks of the vocabulary, held to the dense float32 computation and
+    to the token-chunk loop that ``chunk_size=<int>`` keeps."""
+
+    D = 16
+
+    @staticmethod
+    def _shrink(monkeypatch, single_tokens, budget):
+        import d9d_tpu.ops.linear_ce as lce
+
+        monkeypatch.setattr(lce, "_AUTO_SINGLE_CHUNK_MAX", single_tokens)
+        monkeypatch.setattr(lce, "_AUTO_SINGLE_CHUNK_MAX_LOGITS", budget)
+        return lce
+
+    def _inputs(self, n, v, dtype):
+        h = rng(n, self.D, dtype=dtype)
+        w = rng(v, self.D, seed=1, dtype=dtype)
+        labels = jax.random.randint(jax.random.PRNGKey(2), (n,), 0, v)
+        labels = labels.at[jnp.array([3, n - 1])].set(LM_IGNORE_INDEX)
+        labels = labels.at[0].set(v - 1).at[1].set(0)
+        # a per-token cotangent that is not uniform: the task's weights
+        cot = jnp.asarray(
+            np.random.RandomState(0).choice([0.0, 1.0, 2.5], n), jnp.float32
+        )
+        return h, w, labels, cot
+
+    @staticmethod
+    def _dense(h, w, labels, cot, softcap):
+        logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
+        if softcap is not None:
+            logits = softcap * jnp.tanh(logits / softcap)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(labels, 0)[:, None], axis=-1
+        )[:, 0]
+        loss = jax.nn.logsumexp(logits, axis=-1) - picked
+        return (jnp.where(labels == LM_IGNORE_INDEX, 0.0, loss) * cot).sum()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    @pytest.mark.parametrize("case", list(_BLOCK_CASES))
+    def test_loss_and_gradients_match_dense_and_token_chunks(
+        self, monkeypatch, case, dtype
+    ):
+        n, v, single_tokens, budget, softcap = _BLOCK_CASES[case]
+        lce = self._shrink(monkeypatch, single_tokens, budget)
+        h, w, labels, cot = self._inputs(n, v, dtype)
+
+        def fused(chunk):
+            def total(h, w):
+                loss = linear_cross_entropy(
+                    h, w, labels, chunk_size=chunk, logit_softcap=softcap
+                )
+                assert loss.shape == (n,) and loss.dtype == jnp.float32
+                return (loss * cot).sum()
+            return jax.value_and_grad(total, argnums=(0, 1))(h, w)
+
+        from unittest import mock
+
+        with mock.patch.object(
+            lce, "_block_stats", wraps=lce._block_stats
+        ) as blocks, mock.patch.object(
+            lce, "_chunk_loss", wraps=lce._chunk_loss
+        ) as chunks:
+            got, got_grads = fused("auto")
+            assert blocks.called and not chunks.called
+            slab = max(
+                c.args[0].shape[0] * c.args[2].shape[0]
+                for c in blocks.call_args_list
+            )
+            assert slab <= budget
+            blocks.reset_mock()
+            chunked, chunked_grads = fused(16)
+            assert chunks.called and not blocks.called
+        dense, dense_grads = jax.value_and_grad(self._dense, argnums=(0, 1))(
+            h, w, labels, cot, softcap
+        )
+
+        # bf16: the logits carry the operands' rounding (the policy), the
+        # gradients one rounding to the parameter's dtype on top
+        loss_tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(got, dense, rtol=loss_tol)
+        np.testing.assert_allclose(got, chunked, rtol=1e-5)
+        for mine, ref, loop in zip(got_grads, dense_grads, chunked_grads):
+            assert mine.dtype == dtype and mine.shape == ref.shape
+            scale = float(jnp.abs(ref).max())
+            tol = (1e-5 if dtype == jnp.float32 else 2e-2) * scale
+            np.testing.assert_allclose(
+                mine.astype(jnp.float32), ref, atol=tol, rtol=0
+            )
+            np.testing.assert_allclose(
+                mine.astype(jnp.float32), loop.astype(jnp.float32),
+                atol=tol, rtol=0,
+            )
+        # rows of LM_IGNORE_INDEX and of weight 0 leave the hidden state's
+        # gradient at exactly zero
+        dead = (np.asarray(labels) == LM_IGNORE_INDEX) | (np.asarray(cot) == 0)
+        assert dead.any()
+        assert not np.asarray(got_grads[0].astype(jnp.float32))[dead].any()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["fp32", "bf16"])
+    def test_weight_gradient_is_nearer_dense_than_the_chunk_loop(
+        self, monkeypatch, dtype
+    ):
+        """One float32-accumulated product and one rounding a block where
+        the token-chunk loop adds a chunk's product to a carry in the
+        parameter's dtype once a chunk: never further from the dense
+        gradient. The hidden state's gradient, summed over the blocks in
+        float32 and rounded once, stays within one rounding of dense."""
+        self._shrink(monkeypatch, 8, 64 * 128)
+        n, v = 64, 512
+        h, w, labels, cot = self._inputs(n, v, dtype)
+
+        def grads(chunk):
+            return jax.grad(
+                lambda h, w: (
+                    linear_cross_entropy(h, w, labels, chunk_size=chunk) * cot
+                ).sum(),
+                argnums=(0, 1),
+            )(h, w)
+
+        dense = jax.grad(self._dense, argnums=(0, 1))(h, w, labels, cot, None)
+        err = lambda got, ref: float(
+            jnp.abs(got.astype(jnp.float32) - ref).mean()
+        )
+        blocks, chunks = grads("auto"), grads(4)  # 16 additions to the carry
+        # 1e-6: float32 summation order, where the chunk loop is exact
+        assert err(blocks[1], dense[1]) <= err(chunks[1], dense[1]) + 1e-6
+        # one rounding of the largest entries (bf16); float32 sums 512
+        # columns in another order
+        one_rounding = 1e-5 if dtype == jnp.float32 else 2.0**-7
+        np.testing.assert_allclose(
+            blocks[0].astype(jnp.float32), dense[0].astype(jnp.float32),
+            rtol=0, atol=one_rounding * float(jnp.abs(dense[0]).max()),
+        )
+
+    def test_frozen_head_pays_for_no_weight_gradient(self, monkeypatch):
+        """LoRA and every frozen-head PEFT stack differentiate the hidden
+        state alone: the backward then holds no product whose output is a
+        block of the weight (plain autodiff, no hand-written rule)."""
+        n, v, single_tokens, budget, _ = _BLOCK_CASES["ragged-vocabulary"]
+        self._shrink(monkeypatch, single_tokens, budget)
+        h, w, labels, cot = self._inputs(n, v, jnp.float32)
+        total = lambda h, w: (linear_cross_entropy(h, w, labels) * cot).sum()
+        weight_blocks = {(128, self.D), (self.D, 128), (96, self.D),
+                         (self.D, 96)}
+
+        frozen = jax.make_jaxpr(jax.grad(total, argnums=0))(h, w).jaxpr
+        assert not weight_blocks & set(_product_shapes(frozen))
+        assert (n, self.D) in _product_shapes(frozen)
+        trained = jax.make_jaxpr(jax.grad(total, argnums=(0, 1)))(h, w).jaxpr
+        assert weight_blocks & set(_product_shapes(trained))
+        np.testing.assert_allclose(
+            jax.grad(total, argnums=0)(h, w),
+            jax.grad(self._dense, argnums=0)(h, w, labels, cot, None),
+            rtol=1e-4, atol=1e-6,
+        )
+
+    @pytest.mark.parametrize("v", [32768, 16384 + 128 * 3],
+                             ids=["even", "ragged"])
+    def test_backward_carries_no_weight_and_forward_no_logits(self, v):
+        """At the module's own thresholds (nothing shrunk; a jaxpr runs
+        nothing): above the single-slab size the backward's loop state
+        is the hidden state's gradient in float32, never an array of the
+        weight's shape, and no ``[N, V]`` array exists in either pass.
+        The token-chunk loop carries the weight's whole gradient."""
+        n, d = 4096, 8
+        h = jax.ShapeDtypeStruct((n, d), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((v, d), jnp.bfloat16)
+        labels = jnp.zeros((n,), jnp.int32)
+
+        def backward(chunk):
+            return jax.make_jaxpr(jax.grad(
+                lambda h, w: linear_cross_entropy(
+                    h, w, labels, chunk_size=chunk
+                ).sum(),
+                argnums=(0, 1),
+            ))(h, w).jaxpr
+
+        whole = {(v, d), (d, v)}
+        blocks = backward("auto")
+        carries = _loop_carries(blocks)
+        assert ((n, d), jnp.float32) in carries  # rounded once, at the end
+        assert not whole & {shape for shape, _ in carries}
+        assert all(
+            var.aval.shape != (n, v) and var.aval.shape != (v, n)
+            for eqn in _all_eqns(blocks) for var in eqn.outvars
+        )
+        # every block of the weight's gradient is one product over all
+        # the tokens: its contraction is n long
+        block_products = [
+            eqn for eqn in _all_eqns(blocks)
+            if eqn.primitive.name == "dot_general"
+            and eqn.outvars[0].aval.shape[-1] == d
+            and eqn.outvars[0].aval.shape != (n, d)
+        ]
+        assert block_products
+        for eqn in block_products:
+            (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+            assert eqn.invars[0].aval.shape[lhs_contract[0]] == n
+        assert whole & {shape for shape, _ in _loop_carries(backward(512))}
+
+    def test_tied_table_through_the_head(self, monkeypatch):
+        """A tied head is handed the embedding table: through the block
+        loop the gradient lands in the table, and in the hidden state."""
+        from d9d_tpu.nn.heads import LanguageModellingHead
+
+        n, v, single_tokens, budget, _ = _BLOCK_CASES["ragged-vocabulary"]
+        lce = self._shrink(monkeypatch, single_tokens, budget)
+        h, table, labels, cot = self._inputs(n, v, jnp.float32)
+        head = LanguageModellingHead(
+            vocab_ranges=(("default", v),), hidden_size=self.D, tied=True,
+            dtype=jnp.float32,
+        )
+
+        def total(h, table):
+            loss = head.apply(
+                {}, h.reshape(2, n // 2, self.D), labels.reshape(2, n // 2),
+                table,
+            )
+            return (loss.reshape(-1) * cot).sum()
+
+        from unittest import mock
+
+        with mock.patch.object(
+            lce, "_block_stats", wraps=lce._block_stats
+        ) as blocks:
+            got = jax.grad(total, argnums=(0, 1))(h, table)
+            assert blocks.called
+        want = jax.grad(self._dense, argnums=(0, 1))(h, table, labels, cot, None)
+        for mine, ref in zip(got, want):
+            assert np.abs(ref).max() > 0
+            np.testing.assert_allclose(mine, ref, rtol=1e-4, atol=1e-6)
+
+    def test_row_sharded_head_on_a_mesh_keeps_the_token_chunk_program(
+        self, monkeypatch, devices
+    ):
+        """Four devices, tokens and the head's rows sharded over
+        ``dp_shard``: under the ambient mesh ``"auto"`` lowers to the
+        token-chunk loop's program, so the head is gathered no more than
+        before, and loss and gradients equal the unsharded call's."""
+        from unittest import mock
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from d9d_tpu.core import compat
+        from d9d_tpu.core.mesh import AXIS_DP_SHARD, MESH_AXIS_NAMES
+
+        lce = self._shrink(monkeypatch, 8, 64 * 128)
+        n, v = 64, 512
+        h, w, labels, cot = self._inputs(n, v, jnp.float32)
+
+        def step(chunk):
+            def total(h, w, labels, cot):
+                loss = linear_cross_entropy(h, w, labels, chunk_size=chunk)
+                return (loss * cot).sum()
+            return jax.value_and_grad(total, argnums=(0, 1))
+
+        alone = jax.jit(step("auto"))(h, w, labels, cot)  # the block loop
+
+        # MeshParameters(dp_shard=4).build(...)'s mesh, ambient for this
+        # test alone (build() leaves its mesh set)
+        mesh = jax.sharding.Mesh(
+            np.asarray(devices[:4]).reshape(1, 1, 4, 1, 1, 1),
+            MESH_AXIS_NAMES,
+            **compat.mesh_axis_types_kwargs(len(MESH_AXIS_NAMES)),
+        )
+        rows = NamedSharding(mesh, P(AXIS_DP_SHARD))
+        placed = [jax.device_put(x, rows) for x in (h, w, labels, cot)]
+        with compat.set_mesh(mesh), mock.patch.object(
+            lce, "_chunk_loss", wraps=lce._chunk_loss
+        ) as chunks, mock.patch.object(
+            lce, "_block_stats", wraps=lce._block_stats
+        ) as blocks:
+            compiled, parent = (
+                jax.jit(step(chunk), out_shardings=(None, (rows, rows)))
+                .lower(*placed).compile()
+                for chunk in ("auto", 512)
+            )
+            assert chunks.called and not blocks.called
+        sharded = compiled(*placed)
+        gathers = lambda c: c.as_text().count("all-gather")
+        assert gathers(compiled) <= gathers(parent)
+        np.testing.assert_allclose(sharded[0], alone[0], rtol=1e-5)
+        for mine, ref in zip(sharded[1], alone[1]):
+            np.testing.assert_allclose(mine, ref, rtol=1e-4, atol=1e-6)
+
+
 class TestPartialRope:
     def test_gqa_partial_rope_runs_and_passes_through(self):
         """rope_fraction=0.5: second half of head dims must be untouched by rotation."""
