@@ -63,6 +63,18 @@ masked pass over every per-row leaf, so its cost follows
 the ``serve/recurrent_state_bytes`` gauge too) and ``rows_reset``
 counts the rows it cleared.
 
+A third kind under paging: an attention layer that reads a window of
+positions keeps a ring of pages a row (``nn/attention.py
+_ring_page_table``; the leaves ``decode_flags.RING_CACHE_LEAVES``), the
+window and one page of positions however long the context, beside the
+full layers' pools: no allocator, no table leaf, no garbage page, no
+zeroing at admission (what a ring still holds is behind the position
+masks). ``ServeStats.window_cache_bytes`` is what they hold. A ring has
+dropped what a shared prefix page would stand for, so such a model
+serves with the prefix cache off by the rule that covers recurrent
+state (``decode_flags.window_leaves``), ``prefix_cache=True`` raises,
+and so does ``kv_quant``.
+
 Parity contract: greedy serving of any admission schedule must emit,
 per request, exactly the tokens ``generate(model, params, prompt)``
 produces — ``tests/loop/test_serve.py`` drives staggered schedules
@@ -364,8 +376,19 @@ class ServeStats:
     paging. ``recurrent_state_bytes`` is a level, not a sum: the bytes
     of the per-row recurrent leaves the batcher's cache holds (0 for an
     attention-only model), as of the last chunk; ``rows_reset`` counts
-    the rows whose per-row leaves an admission zeroed. All of it is
-    host arithmetic on the plan: no readback.
+    the rows whose per-row leaves an admission zeroed.
+    ``window_cache_bytes`` is a level too: the bytes of the window
+    layers' rings of pages (0 without such layers, or unpaged), and
+    ``window_positions_attended`` is ``positions_attended`` for those
+    layers: each busy slot-step's context or the layer's window, the
+    smaller, summed over the layers that keep a ring. All of that is
+    host arithmetic on the plan: no readback. ``moe_rows_held``
+    and ``moe_rows_routed`` sum, over the fused chunks' steps and the
+    expert layers that hold a range of their router's experts, the
+    routed (token, expert) pairs that landed on the held ones and all of
+    them, dead rows' included (they step on token 0): the layers' own
+    counts, carried out in the chunk's one token readback; 0 for a model
+    whose layers hold every expert.
     """
 
     host_dispatches: int = 0
@@ -381,6 +404,10 @@ class ServeStats:
     pool_pages_peak: int = 0
     recurrent_state_bytes: int = 0
     rows_reset: int = 0
+    window_cache_bytes: int = 0
+    window_positions_attended: int = 0
+    moe_rows_held: int = 0
+    moe_rows_routed: int = 0
     # degraded-mode counters: submits rejected by the bounded queue,
     # requests expired by their deadline (queued or running), requests
     # shed by the autopilot's burn-driven admission tiering
@@ -414,6 +441,20 @@ def _zero_row(cache, row_mask: Array):
         return jnp.where(m, jnp.zeros_like(x), x)
 
     return jax.tree.map(z, cache)
+
+
+def _positions_under(row_spans, window: int) -> int:
+    """Positions a window layer attends over ``row_spans``, ``(first
+    position, busy steps)`` a row: step ``j`` of a row at ``pos`` sees a
+    context of ``pos + j`` (its own token included, ``j`` from 1) and
+    the layer reads that or its window, the smaller."""
+    if not row_spans:
+        return 0
+    pos, steps = np.asarray(row_spans, np.int64).T
+    whole = np.clip(window - pos, 0, steps)  # steps whose context fits
+    return int(np.sum(
+        whole * pos + whole * (whole + 1) // 2 + (steps - whole) * window
+    ))
 
 
 def _normalize_params(params):
@@ -700,6 +741,7 @@ class ContinuousBatcher:
         self._gauge_set(
             "serve/recurrent_state_bytes", self._recurrent_state_bytes
         )
+        self._gauge_set("serve/window_cache_bytes", self._window_cache_bytes)
         if self._paged:
             # static per-batcher fact, but exported so dashboards (and
             # the bench accounting) can tell quantized pools apart
@@ -717,8 +759,9 @@ class ContinuousBatcher:
                 raise ValueError(
                     "prefix_cache=True is unsound for this model: cache "
                     f"leaves {self._unpageable_leaves} hold per-row "
-                    "recurrent state that summarizes the whole prefix "
-                    "and cannot be restored from KV pages"
+                    "recurrent state that summarizes the whole prefix, "
+                    "or a window layer's ring that has dropped it, and "
+                    "cannot be restored from KV pages"
                 )
             self._kv = PagedKVAllocator(
                 num_pages=self._num_pages,
@@ -879,21 +922,43 @@ class ContinuousBatcher:
             PAGED_CACHE_LEAVES,
             PAGED_SCALE_SUFFIX,
             recurrent_leaves,
+            ring_caches,
+            window_leaves,
         )
 
         z = jnp.zeros((self._b, 1), jnp.int32)
         # eval_shape: cache SHAPES only — model.init would materialize
-        # (and immediately discard) a full second copy of the parameters
-        shapes = jax.eval_shape(
-            self._model.init, jax.random.PRNGKey(0), z, z, z
-        )
+        # (and immediately discard) a full second copy of the parameters.
+        # Paged, a window layer declares a ring of pages a row in place
+        # of a context's worth of cache (nn/attention.py)
+        with (
+            ring_caches(self._page_size) if self._paged
+            else contextlib.nullcontext(())
+        ) as windows:
+            shapes = jax.eval_shape(
+                self._model.init, jax.random.PRNGKey(0), z, z, z
+            )
+        # window -> how many layers keep a ring of it
+        self._ring_windows = collections.Counter(windows)
         flat = flatten_dict(shapes["cache"])
+        # layers that hold a range of their router's experts count the
+        # routed pairs that land here (nn/moe.py): the fused chunk
+        # carries the counts out with its tokens
+        self._counts_held_rows = any(
+            p[-1] == "rows_held"
+            for p in flatten_dict(shapes.get("moe_stats", {}))
+        )
         # dense-layout byte total of the sequence caches: the paged
         # mode's savings denominator, and the contiguous mode's (static)
         # KV residency for the hbm-bytes-per-request accounting
-        self._kv_bytes_static = sum(
-            math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-            for p, s in flat.items() if p[-1] in PAGED_CACHE_LEAVES
+        def nbytes(leaves) -> int:
+            return sum(
+                math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+                for s in leaves
+            )
+
+        self._kv_bytes_static = nbytes(
+            s for p, s in flat.items() if p[-1] in PAGED_CACHE_LEAVES
         )
         # per-row cache leaves that are NOT pageable (GDN and Mamba
         # recurrent state, conv tails, toy memories): paging leaves them
@@ -901,11 +966,17 @@ class ContinuousBatcher:
         # state can't be rebuilt from shared KV pages). Their bytes are
         # what every admission's row reset passes over.
         recurrent = recurrent_leaves(shapes["cache"])
-        self._unpageable_leaves = sorted({p[-1] for p in recurrent})
-        self._recurrent_state_bytes = sum(
-            math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-            for s in recurrent.values()
+        rings = window_leaves(shapes["cache"])
+        if rings and self._kv_quant is not None:
+            raise ValueError(
+                "kv_quant does not cover a window layer's ring of pages "
+                f"({sorted({p[-1] for p in rings})})"
+            )
+        self._unpageable_leaves = sorted(
+            {p[-1] for p in recurrent} | {p[-1] for p in rings}
         )
+        self._recurrent_state_bytes = nbytes(recurrent.values())
+        self._window_cache_bytes = nbytes(rings.values())
         self._page_bytes = 0
         out = {}
         for p, s in flat.items():
@@ -952,9 +1023,13 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     # jitted executables
 
-    def _model_step(self, params, cache, tok, pos):
+    def _model_step(self, params, cache, tok, pos, held_rows=None):
         """One single-token decode call (trace-time helper shared by the
-        per-token and fused executables). ``params`` is a TRACED
+        per-token and fused executables). ``held_rows`` (the fused
+        chunk's ``[2]`` int32 running sums, for a model that counts
+        them) comes back with this step's ``rows_held`` and
+        ``rows_routed`` added, summed over the layers that sow them.
+        ``params`` is a TRACED
         argument, never a closure constant: that is what lets
         :meth:`install_weights` swap trees without retracing — the
         executable's signature (shapes/dtypes/placements) is identical
@@ -976,13 +1051,26 @@ class ContinuousBatcher:
         # submit() refuses what would pass decode_max_length and a step
         # takes one token, so the modules trace no debug check: its
         # effect would cost the program jax's C++ dispatch
+        counted = held_rows is not None
         with caller_holds_bounds():
             logits, state = self._model.apply(
                 {"params": params, "cache": cache},
                 tok[:, None], pos[:, None],
-                method=self._method, mutable=["cache"], **kwargs,
+                method=self._method,
+                mutable=["cache", "moe_stats"] if counted else ["cache"],
+                **kwargs,
             )
-        return state["cache"], logits[:, -1].astype(jnp.float32)
+        row_logits = logits[:, -1].astype(jnp.float32)
+        if not counted:
+            return state["cache"], row_logits
+        from flax.traverse_util import flatten_dict
+
+        sown = flatten_dict(state["moe_stats"])
+        step_rows = jnp.stack([
+            sum(v for p, v in sown.items() if p[-1] == name)
+            for name in ("rows_held", "rows_routed")
+        ]).astype(jnp.int32)
+        return state["cache"], row_logits, held_rows + step_rows
 
     def _sample(self, row_logits, key):
         if self._temp == 0.0:
@@ -1028,6 +1116,7 @@ class ContinuousBatcher:
         tables to the garbage page (see :func:`_pin_page_table`)."""
         eos = self._eos
         paged = self._paged
+        counted = self._counts_held_rows
         if paged:
             from d9d_tpu.nn.decode_flags import (
                 map_cache_index,
@@ -1055,15 +1144,15 @@ class ContinuousBatcher:
             keys = jax.random.split(key, k)
 
             def body(carry, xs):
-                cache, tok, pos, live, rem = carry
+                (cache, tok, pos, live, rem), held_rows = carry[:5], carry[5:]
                 j, kj, fj = xs
                 # input: host-forced prompt token while any remain for
                 # this row, else the previous step's sampled token
                 inp = jnp.where((j < n_forced) & live, fj, tok)
                 inp = jnp.where(live, inp, 0)
                 pos_in = jnp.where(live, pos, 0)
-                cache, row_logits = self._model_step(
-                    params, cache, inp, pos_in
+                cache, row_logits, *held_rows = self._model_step(
+                    params, cache, inp, pos_in, *held_rows
                 )
                 nxt = self._sample(row_logits, kj)
                 emit = live & (j >= emit_from)
@@ -1081,15 +1170,24 @@ class ContinuousBatcher:
                 cache = _pin_cache_index(cache, live)
                 if paged:
                     cache = _pin_page_table(cache, live)
-                return (cache, tok, pos, live, rem), out
+                return (cache, tok, pos, live, rem, *held_rows), out
 
-            (cache, tok, pos, live, rem), toks = jax.lax.scan(
-                body, (cache, tok, pos, live, rem),
+            counts = (jnp.zeros((2,), jnp.int32),) if counted else ()
+            (cache, tok, pos, live, rem, *counts), toks = jax.lax.scan(
+                body, (cache, tok, pos, live, rem, *counts),
                 (jnp.arange(k, dtype=jnp.int32), keys, forced_t),
             )
             # toks [K, B] → the [B, K] device-side emission buffer the
             # host fetches in ONE readback per chunk
-            return cache, tok, pos, live, rem, jnp.moveaxis(toks, 0, 1)
+            toks = jnp.moveaxis(toks, 0, 1)
+            if counted:
+                # the held-rows counts ride the same buffer: two more
+                # rows, each count in its first column
+                toks = jnp.concatenate([
+                    toks,
+                    jnp.zeros((2, k), jnp.int32).at[:, 0].set(counts[0]),
+                ])
+            return cache, tok, pos, live, rem, toks
 
         return tracked_jit(
             fused_fn,
@@ -1641,7 +1739,10 @@ class ContinuousBatcher:
         decode_max_length is resident whether used or not); paged mode
         charges pages actually mapped."""
         if self._paged:
-            resident = self._kv.peak_pages_in_use * self._page_bytes
+            resident = (
+                self._kv.peak_pages_in_use * self._page_bytes
+                + self._window_cache_bytes
+            )
         else:
             resident = self._kv_bytes_static
         return resident / max(1, self._peak_running)
@@ -1887,6 +1988,7 @@ class ContinuousBatcher:
         self.stats.slot_steps_busy += int(live.sum())
         self.stats.positions_attended += int((pos[live] + 1).sum())
         self.stats.recurrent_state_bytes = self._recurrent_state_bytes
+        self.stats.window_cache_bytes = self._window_cache_bytes
         if self._paged:
             self._count_pool_pages()
         self._observe("serve/slot_util", live.sum() / self._b, _UTIL_EDGES)
@@ -2096,11 +2198,13 @@ class ContinuousBatcher:
         rows_reset = int(admit_mask.sum())
         self.stats.rows_reset += rows_reset
         self.stats.recurrent_state_bytes = self._recurrent_state_bytes
+        self.stats.window_cache_bytes = self._window_cache_bytes
         # what the wrapper's own Python and the enqueue cost this chunk
         # (TrackedJit.last_call), beside the stagings counted above
         cost = fused.last_call
         clock.meta.update(
             recurrent_state_bytes=self._recurrent_state_bytes,
+            window_cache_bytes=self._window_cache_bytes,
             rows_reset=rows_reset,
             dispatch_key_s=cost.key_s,
             dispatch_enqueue_s=cost.enqueue_s,
@@ -2130,6 +2234,12 @@ class ContinuousBatcher:
         # emission and stop logic on the host: serve/phase/commit
         clock.mark("readback")
         with annotate("serve.commit"):
+            if self._counts_held_rows:
+                # the two rows below the slots' (``_build_fused``)
+                held, routed = (int(n) for n in toks[self._b:, 0])
+                self.stats.moe_rows_held += held
+                self.stats.moe_rows_routed += routed
+                toks = toks[:self._b]
             now = time.perf_counter()
             self._progress_t = now
             if self._first_readback_t is None:
@@ -2139,6 +2249,7 @@ class ContinuousBatcher:
             chunk_busy = 0
             chunk_positions = 0
             chunk_tokens = 0
+            row_spans = []  # (first position, busy steps) of each busy row
             emitted: dict[int, list[int]] = {}
             for i, rid in enumerate(plan.rids):
                 if rid < 0 or rid in self.done:
@@ -2178,6 +2289,8 @@ class ContinuousBatcher:
                     busy_steps * plan.pos[i]
                     + busy_steps * (busy_steps + 1) // 2
                 )
+                if self._ring_windows:
+                    row_spans.append((plan.pos[i], busy_steps))
                 # steps in which the row only consumed a prompt token,
                 # from the plan the chunk was dispatched with; a row emits
                 # before it dies, so these never pass the step it died on
@@ -2195,6 +2308,13 @@ class ContinuousBatcher:
             clock.meta.update(
                 slot_steps_busy=chunk_busy, positions_attended=chunk_positions
             )
+            if self._ring_windows:
+                chunk_window = sum(
+                    layers * _positions_under(row_spans, window)
+                    for window, layers in self._ring_windows.items()
+                )
+                self.stats.window_positions_attended += chunk_window
+                clock.meta.update(window_positions_attended=chunk_window)
             self._observe(
                 "serve/slot_util", chunk_busy / (self._b * plan.k),
                 _UTIL_EDGES,

@@ -6,10 +6,12 @@ by an MoE layer (all layers sparse by default; ``mlp_only_layers`` keeps
 specific layers dense, matching HF Qwen3MoE semantics).
 """
 
+import contextlib
 import dataclasses
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding
@@ -58,6 +60,29 @@ class MLAParameters:
     # override the default d_qk**-0.5 (DeepSeek yarn mscale: the
     # checkpoint's softmax scale carries a yarn temperature factor)
     softmax_scale: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One more grouped-query attention kind of a stack that mixes them
+    (``Qwen3MoeConfig.attention_kinds``), by what it changes from the
+    config's plain fields, which stay the settings of the kind named
+    ``"attention"``: ``None`` keeps the plain field. A window makes the
+    kind's layers attend the last ``window_size`` positions only, and the
+    serving loop then keeps no more of them (``nn/attention.py``, "ring of
+    pages"); ``use_sinks`` gives each query head a learned logit that
+    joins the softmax's denominator alone."""
+
+    num_kv_heads: Optional[int] = None
+    rope_theta: Optional[float] = None
+    window_size: Optional[int] = None
+    use_sinks: bool = False
+
+
+# the token-mixer kinds a layer can be without an entry in
+# ``attention_kinds``: grouped-query attention with the plain fields,
+# latent attention (``mla``), a Mamba-1 mixer, a GatedDeltaNet block
+LAYER_KINDS = ("attention", "mla", "mamba", "gdn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,10 +191,58 @@ class Qwen3MoeConfig:
     # token after; ``logits`` and decode run the stack alone
     num_mtp_modules: int = 0
     mtp_loss_weight: float = 0.3
+    # The per-layer pattern of token-mixer kinds: ``layer_kinds[i]`` names
+    # layer i's, one of ``LAYER_KINDS`` or a name ``attention_kinds``
+    # defines. Empty, the pattern is what ``mamba_layers``,
+    # ``linear_attention_layers`` and ``mla`` say, in that order (the
+    # presets that predate it): ``layer_kind`` is the one reading of both
+    layer_kinds: tuple[str, ...] = ()
+    attention_kinds: tuple[tuple[str, AttentionKind], ...] = ()
+    # a value head narrower than the query/key head (0 = ``head_dim``) and
+    # a constant on the value projection's output, in every GQA kind
+    v_head_dim: int = 0
+    attention_value_scale: float = 1.0
+
+    def __post_init__(self):
+        named = dict(self.attention_kinds)
+        if set(named) & set(LAYER_KINDS):
+            raise ValueError(
+                f"attention_kinds may not redefine {LAYER_KINDS}: {set(named)}"
+            )
+        unknown = set(self.layer_kinds) - set(LAYER_KINDS) - set(named)
+        if unknown:
+            raise ValueError(f"layer_kinds names no kind: {sorted(unknown)}")
+        if self.layer_kinds and len(self.layer_kinds) != self.num_layers:
+            raise ValueError(
+                f"{len(self.layer_kinds)} layer_kinds for {self.num_layers} "
+                "layers"
+            )
 
     @property
     def vocab_size(self) -> int:
         return sum(s for _, s in self.vocab_ranges)
+
+    def layer_kind(self, layer_idx: int) -> str:
+        """The token-mixer kind of layer ``layer_idx``. A layer past the
+        stack's (the multi-token-prediction block) is of the kind a
+        stack without a pattern would give it."""
+        if layer_idx < len(self.layer_kinds):
+            return self.layer_kinds[layer_idx]
+        if layer_idx in self.mamba_layers:
+            return "mamba"
+        if layer_idx in self.linear_attention_layers:
+            return "gdn"
+        return "attention" if self.mla is None else "mla"
+
+    def attention_kind(self, kind: str) -> AttentionKind:
+        """The settings of GQA kind ``kind``, every field filled in."""
+        own = dict(self.attention_kinds).get(kind, AttentionKind())
+        return AttentionKind(
+            num_kv_heads=own.num_kv_heads or self.num_kv_heads,
+            rope_theta=own.rope_theta or self.rope_theta,
+            window_size=own.window_size,
+            use_sinks=own.use_sinks,
+        )
 
     @staticmethod
     def tiny(vocab_size: int = 256, ep_axes=None) -> "Qwen3MoeConfig":
@@ -288,7 +361,8 @@ class Qwen3MoeDecoderLayer(nn.Module):
             cfg.hidden_size, eps=cfg.norm_eps, zero_centered=zc,
             name="input_layernorm",
         )(attn_in)
-        if self.layer_idx in cfg.mamba_layers:
+        kind = cfg.layer_kind(self.layer_idx)
+        if kind == "mamba":
             from d9d_tpu.nn.mamba import MambaMixer
 
             # like GDN below, the mixer zeroes padded positions itself
@@ -305,7 +379,7 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="mamba",
             )(normed, padding_mask)
-        elif self.layer_idx in cfg.linear_attention_layers:
+        elif kind == "gdn":
             from d9d_tpu.nn.linear_attention import GatedDeltaNet
 
             # GDN zeroes padded positions before the conv/recurrence (HF
@@ -325,7 +399,7 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="linear_attn",
             )(normed, padding_mask)
-        elif cfg.mla is not None:
+        elif kind == "mla":
             from d9d_tpu.nn.attention import MultiHeadLatentAttention
 
             attn_out = MultiHeadLatentAttention(
@@ -345,22 +419,35 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 name="self_attn",
             )(normed, cos, sin, mask)
         else:
-            attn_out = GroupedQueryAttention(
-                hidden_size=cfg.hidden_size,
-                num_heads=cfg.num_heads,
-                num_kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.head_dim,
-                sdpa=self.sdpa,
-                qk_norm=cfg.qk_norm,
-                qk_norm_zero_centered=zc,
-                use_output_gate=cfg.use_output_gate,
-                fused_qkv=cfg.fused_qkv,
-                rope_fraction=cfg.rope_fraction,
-                decode_max_length=self.decode_max_length,
-                dtype=self.dtype,
-                param_dtype=self.param_dtype,
-                name="self_attn",
-            )(normed, cos, sin, mask)
+            own = cfg.attention_kind(kind)
+            # a kind beside the plain one says so in its ops' scope
+            # (``layers_3/attn_window/self_attn/...``): a trace tells a
+            # window layer's attention from a full one's
+            scope = (
+                contextlib.nullcontext() if kind == "attention"
+                else jax.named_scope(f"attn_{kind}")
+            )
+            with scope:
+                attn_out = GroupedQueryAttention(
+                    hidden_size=cfg.hidden_size,
+                    num_heads=cfg.num_heads,
+                    num_kv_heads=own.num_kv_heads,
+                    head_dim=cfg.head_dim,
+                    v_head_dim=cfg.v_head_dim,
+                    value_scale=cfg.attention_value_scale,
+                    sdpa=self.sdpa,
+                    qk_norm=cfg.qk_norm,
+                    qk_norm_zero_centered=zc,
+                    use_output_gate=cfg.use_output_gate,
+                    fused_qkv=cfg.fused_qkv,
+                    rope_fraction=cfg.rope_fraction,
+                    window_size=own.window_size,
+                    use_sinks=own.use_sinks,
+                    decode_max_length=self.decode_max_length,
+                    dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                    name="self_attn",
+                )(normed, cos, sin, mask)
         if hc:
             x = attn_hc.write(x, attn_out, attn_mix)
             mlp_hc = hc("mlp_mhc")
@@ -422,9 +509,12 @@ class Qwen3MoeDecoderLayer(nn.Module):
         )
 
 
-def rope_cos_sin(cfg: Qwen3MoeConfig, positions: Array):
-    """``(cos, sin)`` at ``positions`` for the config's rotary geometry,
-    or ``(None, None)`` where nothing is rotated."""
+def rope_cos_sin(
+    cfg: Qwen3MoeConfig, positions: Array, theta: Optional[float] = None
+):
+    """``(cos, sin)`` at ``positions`` for the config's rotary geometry
+    (at base ``theta`` where an attention kind has its own), or ``(None,
+    None)`` where nothing is rotated."""
     # partial rotary (rope_fraction < 1): frequencies are computed over
     # the rotary dim, not head_dim (NeoX/Qwen3-Next semantics). MLA
     # (DeepSeek) rotates only its decoupled rope sub-vector.
@@ -437,7 +527,7 @@ def rope_cos_sin(cfg: Qwen3MoeConfig, positions: Array):
     if not rotary_dim:
         return None, None
     inv_freq, att_scale = compute_rope_frequencies(
-        rotary_dim, cfg.rope_theta, cfg.rope_scaling
+        rotary_dim, theta or cfg.rope_theta, cfg.rope_scaling
     )
     return make_rope_cos_sin(positions, inv_freq, att_scale)
 
@@ -503,10 +593,15 @@ class Qwen3MoeBackbone(nn.Module):
             x = x.astype(stream)
         x = self._pin(x)
 
-        cos, sin = rope_cos_sin(cfg, positions)
+        # one table a rotary base: a stack of one kind builds one
+        rope: dict = {}
         layer_cls = decoder_layer_class(cfg, self.decode_max_length)
 
         for gid in distribute_layers_for_pipeline_stage(cfg.num_layers, self.stage):
+            theta = cfg.attention_kind(cfg.layer_kind(gid)).rope_theta
+            if theta not in rope:
+                rope[theta] = rope_cos_sin(cfg, positions, theta)
+            cos, sin = rope[theta]
             x = layer_cls(
                 config=cfg,
                 sdpa=self.sdpa,

@@ -208,6 +208,26 @@ def _decode_cache_append(module: nn.Module, value, name: str, s_max: int,
     return ref.value
 
 
+def _scatter_head_rows(pool, page, off, rows):
+    """``pool [P, H, ps, D]`` with ``rows [B, H, D]`` written at
+    ``(page[b], h, off[b])``: a scatter of whole ``D``-rows into the pool
+    seen as ``[P·H·ps, D]`` (a free view: ``ps`` is whole sublane tiles).
+    Written as ``pool.at[page, :, off, :].set(rows)`` the TPU compiler
+    gives the scatter a layout of its own (heads inside positions) and
+    copies the WHOLE pool into it and back to the decode kernel's every
+    step: 3.9 ms a layer a step against 0.56 at 256 slots x 18 pages x 4
+    heads x (256 + 128) numbers (my chip run, PR 41; PERF.md §6)."""
+    p, h, ps, d = pool.shape
+    flat = (
+        (page[:, None] * h + jnp.arange(h, dtype=page.dtype)[None, :]) * ps
+        + off[:, None]
+    ).reshape(-1)
+    return (
+        pool.reshape(p * h * ps, d).at[flat].set(rows.reshape(-1, d))
+        .reshape(pool.shape)
+    )
+
+
 def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
                                      s_max: int, start, page_table=None):
     """Append ``value [B, T, H, D]`` at cache slot ``start`` of a
@@ -243,10 +263,10 @@ def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
             # (the flash kernel's scale BlockSpec / the quantized eager
             # gather) dequantize — the raw int8 pool is returned
             qv, sc = _quantize_rows(value[:, 0])  # [B,H,D] i8, [B,H] f32
-            ref.value = pool.at[page, :, off, :].set(qv)
+            ref.value = _scatter_head_rows(pool, page, off, qv)
             sref.value = sref.value.at[page, :, off].set(sc)
             return ref.value
-        ref.value = pool.at[page, :, off, :].set(value[:, 0])
+        ref.value = _scatter_head_rows(pool, page, off, value[:, 0])
         return ref.value
     ref = module.variable(
         "cache", name,
@@ -262,6 +282,74 @@ def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
             lambda c, v, s: lax.dynamic_update_slice(c, v, (0, s, 0))
         )(ref.value, vt, start)
     return ref.value
+
+
+def _cache_row_pad(width: int) -> int:
+    """Numbers to add to a cached row of ``width``, so that a row wider
+    than the TPU's 128-lane tile ends on a tile's edge: the chip stores
+    such a row in whole tiles whatever its logical width (192 numbers
+    take 256), and the paged decode kernel, which copies pages out of
+    the pools itself, can only cut a pool on those edges. A row of one
+    tile or less is stored, and left, as it is."""
+    from d9d_tpu.ops.attention.pallas_decode import LANES
+
+    return (-width) % LANES if width > LANES else 0
+
+
+def _ring_page_table(module: nn.Module, b: int, s_max: int,
+                     window_size, kv_shapes, dtype):
+    """The page table of a window layer's RING OF PAGES, or None where
+    the layer keeps a whole context (no window; ``generate``'s contiguous
+    cache; an unpaged serving loop).
+
+    A query at position ``i`` of a window layer reads positions
+    ``(i - window, i]`` and nothing older, so the serving loop's paged
+    mode (``decode_flags.ring_caches`` around its ``init``) has such a
+    layer declare, in place of ``cached_key`` / ``cached_value``, the two
+    ``RING_CACHE_LEAVES``: ``[B * ring_pages, H, page_size, D]``, laid
+    out as a page pool so the paged decode kernel and the eager gather
+    read them as they read one, with row ``b`` the sole owner of pages
+    ``b * ring_pages ..``. Logical page ``p`` of a row lives in its ring
+    page ``p % ring_pages``: the window and the page being written never
+    span more (``pallas_decode.window_pages``), so a page is overwritten
+    only once every position in it has left the window. The table is
+    that rule written out, ``[B, ceil(s_max / page_size)]``: a constant
+    of the program, no leaf, nothing for the host to push. Whatever a
+    ring page still holds of an older pass or an earlier request sits at
+    logical positions after the row's write index or before its window,
+    which the position masks hide, so admission zeroes nothing here, and
+    a dead row (write index pinned to 0) scribbles into its own ring:
+    no garbage page. Presence of the leaves is the mode flag, as with
+    ``page_table``."""
+    from d9d_tpu.nn.decode_flags import (
+        RING_CACHE_LEAVES,
+        note_ring,
+        ring_page_size,
+    )
+    from d9d_tpu.ops.attention.pallas_decode import window_pages
+
+    if window_size is None:
+        return None
+    page_size = ring_page_size()
+    if page_size is not None and module.is_initializing():
+        note_ring(window_size)
+        pages = b * window_pages(window_size, page_size)
+        for name, (heads, width) in zip(RING_CACHE_LEAVES, kv_shapes):
+            # put, not declared: the append below declares each leaf
+            # once, as it does a pool the serving loop seeded
+            module.put_variable(
+                "cache", name,
+                jnp.zeros((pages, heads, page_size, width), dtype),
+            )
+    if not module.has_variable("cache", RING_CACHE_LEAVES[0]):
+        return None
+    ring = module.get_variable("cache", RING_CACHE_LEAVES[0])
+    per_row, page_size = ring.shape[0] // b, ring.shape[2]
+    logical = jnp.arange(-(-s_max // page_size), dtype=jnp.int32)
+    return (
+        jnp.arange(b, dtype=jnp.int32)[:, None] * per_row
+        + logical[None, :] % per_row
+    )
 
 
 def _gather_pages_heads_major(pool, page_table):
@@ -385,6 +473,13 @@ class GroupedQueryAttention(nn.Module):
     use_output_gate: bool = False
     window_size: int | None = None
     softmax_scale: float | None = None
+    # value heads of their own width (0 = ``head_dim``; never wider): the
+    # caches, the decode kernels and ``o_proj`` take it as it is, the
+    # training SDPA is given V zero-padded to ``head_dim``
+    # (softmax(QKᵀ)·[V|0] = [out|0], as the latent module does)
+    v_head_dim: int = 0
+    # a constant on the value projection's output
+    value_scale: float = 1.0
     # One matmul for q/k/v over a runtime kernel concat (the activation
     # rows stream from HBM once instead of three times; same math, same
     # parameter pytree — q_proj/k_proj/v_proj kernels stay separate for
@@ -411,8 +506,11 @@ class GroupedQueryAttention(nn.Module):
     ) -> Array:
         b, t, _ = x.shape
         h, hkv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        dv = self.v_head_dim or d
         if h % hkv != 0:
             raise ValueError(f"num_heads {h} not divisible by num_kv_heads {hkv}")
+        if dv > d:
+            raise ValueError(f"v_head_dim ({dv}) must not exceed head_dim ({d})")
 
         def proj(features, name, axes):
             return nn.Dense(
@@ -456,18 +554,20 @@ class GroupedQueryAttention(nn.Module):
                 [
                     kernel(h * d, "q_proj", (la.EMBED, la.HEADS)),
                     kernel(hkv * d, "k_proj", (la.EMBED, la.KV_HEADS)),
-                    kernel(hkv * d, "v_proj", (la.EMBED, la.KV_HEADS)),
+                    kernel(hkv * dv, "v_proj", (la.EMBED, la.KV_HEADS)),
                 ],
                 axis=-1,
             ).astype(self.dtype)
             qkv = x.astype(self.dtype) @ w
             q = qkv[..., : h * d].reshape(b, t, h, d)
             k = qkv[..., h * d : (h + hkv) * d].reshape(b, t, hkv, d)
-            v = qkv[..., (h + hkv) * d :].reshape(b, t, hkv, d)
+            v = qkv[..., (h + hkv) * d :].reshape(b, t, hkv, dv)
         else:
             q = proj(h * d, "q_proj", (la.EMBED, la.HEADS))(x).reshape(b, t, h, d)
             k = proj(hkv * d, "k_proj", (la.EMBED, la.KV_HEADS))(x).reshape(b, t, hkv, d)
-            v = proj(hkv * d, "v_proj", (la.EMBED, la.KV_HEADS))(x).reshape(b, t, hkv, d)
+            v = proj(hkv * dv, "v_proj", (la.EMBED, la.KV_HEADS))(x).reshape(b, t, hkv, dv)
+        if self.value_scale != 1.0:
+            v = v * jnp.asarray(self.value_scale, v.dtype)
 
         if self.qk_norm:
             q = RMSNorm(d, eps=self.qk_norm_eps, name="q_norm",
@@ -514,10 +614,8 @@ class GroupedQueryAttention(nn.Module):
         if self.decode_max_length > 0:
             attn = self._decode_attend(q, k, v, sinks, mask, b, t)
         else:
-            attn = self.sdpa(
-                q,
-                k,
-                v,
+            attn = self._sdpa_padded(
+                q, k, v,
                 causal=True,
                 softmax_scale=self.softmax_scale,
                 window_size=self.window_size,
@@ -528,11 +626,21 @@ class GroupedQueryAttention(nn.Module):
         # kernel's output instead of re-running it in the backward pass
         attn = checkpoint_name(attn, "sdpa_out")
 
-        out = attn.reshape(b, t, h * d)
+        out = attn.reshape(b, t, h * dv)
         if self.use_output_gate:
-            gate = proj(h * d, "gate_proj", (la.EMBED, la.HEADS))(x)
+            gate = proj(h * dv, "gate_proj", (la.EMBED, la.HEADS))(x)
             out = out * nn.sigmoid(gate)
         return proj(self.hidden_size, "o_proj", (la.HEADS, la.EMBED))(out)
+
+    def _sdpa_padded(self, q, k, v, **kwargs):
+        """The SDPA backend on value heads zero-padded to the query/key
+        width, the padding cut off its output: every backend (flash and
+        ring included) takes one head width."""
+        pad = q.shape[-1] - v.shape[-1]
+        if not pad:
+            return self.sdpa(q, k, v, **kwargs)
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
+        return self.sdpa(q, k, v, **kwargs)[..., : v.shape[-1] - pad]
 
     def _decode_attend(self, q, k, v, sinks, mask, b, t):
         """KV-cache attention: write the new k/v at the cache index, then
@@ -560,22 +668,52 @@ class GroupedQueryAttention(nn.Module):
         idx = _decode_cache_index(self)
         start = idx.value
         _decode_contract_checks(start, t, s_max)
+        # a cached key row ends on a lane tile's edge (_cache_row_pad):
+        # new keys are zero-padded on their way into a cache and so are
+        # the queries that meet cached keys (zeros add nothing to a
+        # score; the scale stays the head's own). The prefill fast path
+        # below attends the new tokens as they are.
+        scale = (
+            self.softmax_scale if self.softmax_scale is not None
+            else q.shape[-1] ** -0.5
+        )
+        k_new, q_new = k, q
+        pad = _cache_row_pad(q.shape[-1])
+        if pad:
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad)))
         page_table = _decode_page_table(self)
+        key_leaf, value_leaf = "cached_key", "cached_value"
+        ring_table = _ring_page_table(
+            self, b, s_max, self.window_size,
+            (k.shape[2:], v.shape[2:]), self.dtype,
+        )
+        if ring_table is not None:
+            # a window layer under the paged serving loop: the paged
+            # path below on the row's own ring of pages
+            from d9d_tpu.nn.decode_flags import RING_CACHE_LEAVES
+
+            page_table, (key_leaf, value_leaf) = ring_table, RING_CACHE_LEAVES
         if page_table is not None:
             # paged serving mode (loop/serve.py): one token per row per
             # step through page pools; the flash path streams the pool
             # through the gathering block index map, the eager oracle
             # gathers a contiguous per-row view
+            index = start
+            if ring_table is not None and self.is_initializing():
+                # the serving loop's shape-only init: the index is
+                # still the scalar it seeds per row
+                start = jnp.broadcast_to(start, (b,))
             _paged_write_checks(start, t, mask)
             k_pool = _decode_cache_append_heads_major(
-                self, k.astype(self.dtype), "cached_key", s_max, start,
+                self, k.astype(self.dtype), key_leaf, s_max, start,
                 page_table=page_table,
             )
             v_pool = _decode_cache_append_heads_major(
-                self, v.astype(self.dtype), "cached_value", s_max, start,
+                self, v.astype(self.dtype), value_leaf, s_max, start,
                 page_table=page_table,
             )
-            idx.value = start + t
+            idx.value = index + t
             # kv_quant mode (loop/serve.py): the appends above wrote
             # int8 + per-slot scales; both read paths dequantize
             k_scale = v_scale = None
@@ -590,7 +728,7 @@ class GroupedQueryAttention(nn.Module):
                 return flash_decode_attention(
                     q, k_pool, v_pool,
                     start=start,
-                    softmax_scale=self.softmax_scale,
+                    softmax_scale=scale,
                     window_size=self.window_size,
                     sinks=sinks,
                     page_table=page_table,
@@ -613,7 +751,7 @@ class GroupedQueryAttention(nn.Module):
                 jnp.transpose(keys, (0, 2, 1, 3)),
                 jnp.transpose(values, (0, 2, 1, 3)),
                 causal=False,
-                softmax_scale=self.softmax_scale,
+                softmax_scale=scale,
                 sinks=sinks,
                 mask=_decode_slot_mask(
                     start, t, s_virt, self.window_size, None
@@ -640,8 +778,8 @@ class GroupedQueryAttention(nn.Module):
             # generate(). Continuation prefill chunks (chunked prefill,
             # loop/generate.py prefill_chunk_size) fall through to the
             # slot-cache path below, which is valid at any cache index.
-            return self.sdpa(
-                q, k, v,
+            return self._sdpa_padded(
+                q_new, k_new, v,
                 causal=True,
                 softmax_scale=self.softmax_scale,
                 window_size=self.window_size,
@@ -661,7 +799,7 @@ class GroupedQueryAttention(nn.Module):
             return flash_decode_attention(
                 q, keys, values,
                 start=start,
-                softmax_scale=self.softmax_scale,
+                softmax_scale=scale,
                 window_size=self.window_size,
                 sinks=sinks,
                 kv_valid=None if mask is None else mask[:, 0, 0, :],
@@ -671,7 +809,7 @@ class GroupedQueryAttention(nn.Module):
             jnp.transpose(keys, (0, 2, 1, 3)),
             jnp.transpose(values, (0, 2, 1, 3)),
             causal=False,
-            softmax_scale=self.softmax_scale,
+            softmax_scale=scale,
             sinks=sinks,
             mask=_decode_slot_mask(start, t, s_max, self.window_size, mask),
         )

@@ -112,10 +112,63 @@ PAGED_SCALE_LEAVES = {
     for name, axis in PAGED_CACHE_LEAVES.items()
 }
 
+# -- window layers' rings of pages (nn/attention.py _ring_page_table) ---
+
+# the second cache kind of the paged serving loop: the key and value
+# leaves of an attention layer that reads a window of positions only,
+# ``[B * ring_pages, Hkv, page_size, D]``. Each row owns its ring, the
+# page table is a constant of the program, and what a layer holds does
+# not grow with the context.
+RING_CACHE_LEAVES = ("ring_key", "ring_value")
+
+_rings = contextvars.ContextVar("d9d_tpu_decode_rings", default=None)
+
+
+@contextlib.contextmanager
+def ring_caches(page_size: int):
+    """Have a model's ``init`` in this block declare its window layers'
+    caches as rings of ``page_size``-position pages: the paged serving
+    loop's shape-only init. The leaves that result are the mode flag for
+    every later ``apply``, which needs no context. Yields the list the
+    layers that did so append their windows to, in call order: the
+    serving loop counts the positions such layers read from it."""
+    windows: list[int] = []
+    token = _rings.set((int(page_size), windows))
+    try:
+        yield windows
+    finally:
+        _rings.reset(token)
+
+
+def ring_page_size():
+    rings = _rings.get()
+    return rings[0] if rings else None
+
+
+def note_ring(window_size: int) -> None:
+    """A window layer declared its ring (inside :func:`ring_caches`)."""
+    _rings.get()[1].append(int(window_size))
+
+
+def window_leaves(cache) -> dict:
+    """``{path: leaf}`` of the window layers' ring leaves. Beside
+    :func:`recurrent_leaves`, the other half of the rule that keeps the
+    prefix cache off: a ring has dropped the positions a shared prefix
+    page would stand for, so a hit could not give them back."""
+    from flax.traverse_util import flatten_dict
+
+    return {
+        path: leaf for path, leaf in flatten_dict(cache).items()
+        if path[-1] in RING_CACHE_LEAVES
+    }
+
+
 # leaves of a paged cache that do not lead with the batch dimension:
-# pools shared by all rows, and the tables the host allocator writes
+# pools shared by all rows, the tables the host allocator writes, and
+# the rows' rings of pages
 _SHARED_LEAVES = (
     set(PAGED_CACHE_LEAVES) | set(PAGED_SCALE_LEAVES) | {PAGE_TABLE_LEAF}
+    | set(RING_CACHE_LEAVES)
 )
 
 
@@ -155,9 +208,10 @@ def map_page_table(cache, fn):
 
 def zero_rows_skip_paged(cache, row_mask):
     """Zero ``row_mask``-selected batch rows of every PER-ROW cache leaf,
-    skipping page pools and page tables (which have no batch-leading
-    dim — pools are shared across rows, and admitted rows' table rows
-    are written by the host allocator, not zeroed). The paged-mode
+    skipping page pools, page tables and rings of pages (which have no
+    batch-leading dim — pools are shared across rows, admitted rows'
+    table rows are written by the host allocator, not zeroed, and what a
+    ring still holds is behind the position masks). The paged-mode
     sibling of ``loop/serve.py``'s ``_zero_row``; trace-safe."""
     import jax.numpy as jnp
     from flax.traverse_util import flatten_dict, unflatten_dict

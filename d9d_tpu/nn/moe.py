@@ -39,6 +39,7 @@ from d9d_tpu.ops.ep_dispatch import (
     ep_dispatch_compute_combine,
 )
 from d9d_tpu.ops.moe import (
+    HELD_FEW_ROWS_LIMIT,
     all_experts_swiglu,
     few_rows_touch_all_experts,
     fold_held,
@@ -248,9 +249,12 @@ def grouped_swiglu_apply(
     since PR 36: the local path sends a call of few rows that reaches
     nearly every expert through ``ops/moe.py all_experts_swiglu``, which
     has no concatenation and no ``ragged_dot`` (a fused chunk used to
-    hoist the copy, 768 MB a layer, and keep it). What still does: the
-    training calls, the EP shard body, a held range, and a one-row
-    ``generate`` step, which reads 8 experts of 128.
+    hoist the copy, 768 MB a layer, and keep it), nor does a decode
+    step through a held range since PR 41 (``_forward_held``). What
+    still comes here: the training calls, the EP shard body, a held
+    range's calls of more than ``HELD_FEW_ROWS_LIMIT`` rows or of too few
+    to reach the router's width, and a one-row ``generate`` step, which
+    reads 8 experts of 128.
 
     The ``moe/experts/{gate_up,act,down}`` scopes are HLO metadata only
     (the all-expert form carries the same, with ``all_experts`` under
@@ -659,12 +663,7 @@ class MoELayer(nn.Module):
         ):
             # a decode step's few rows reach nearly every expert: plain
             # products over all of them, nothing sorted, moved or folded
-            experts = self.grouped_experts
-            return all_experts_swiglu(
-                x, topk_ids, topk_probs,
-                experts.gate_weight, experts.up_weight, experts.down_weight,
-                self.dtype,
-            ).astype(x.dtype)
+            return self._all_experts(x, topk_ids, topk_probs)
         with jax.named_scope("moe/permute"):
             sort = sort_tokens_by_expert(topk_ids, self.num_grouped_experts)
         if moe_ffn_backend() in ("pallas", "pallas_gather"):
@@ -712,15 +711,26 @@ class MoELayer(nn.Module):
                 reduce_fn=lambda a, b: a + b,
                 init_fn=lambda: jnp.zeros((), jnp.float32),
             )
+        if moe_ffn_backend() == "xla" and few_rows_touch_all_experts(
+            *local.shape, self.router_width, HELD_FEW_ROWS_LIMIT
+        ):
+            # a decode step: every held expert's weights once through
+            # plain products, whatever the routing (an id of ``held``, a
+            # pair routed elsewhere, matches no expert and adds nothing)
+            return self._all_experts(x, local, topk_probs)
         return held_experts_apply(
-            x, local, topk_probs,
-            (
-                self.grouped_experts.gate_weight,
-                self.grouped_experts.up_weight,
-                self.grouped_experts.down_weight,
-            ),
+            x, local, topk_probs, self._expert_weights(),
             num_routed=self.router_width, dtype=self.dtype,
         )
+
+    def _expert_weights(self):
+        experts = self.grouped_experts
+        return experts.gate_weight, experts.up_weight, experts.down_weight
+
+    def _all_experts(self, x: Array, ids: Array, probs: Array) -> Array:
+        return all_experts_swiglu(
+            x, ids, probs, *self._expert_weights(), self.dtype
+        ).astype(x.dtype)
 
     # --- EP path (reference communications/deepep.py, re-designed) -------
 
@@ -743,13 +753,6 @@ class MoELayer(nn.Module):
         e_loc = num_experts // ep_size
         dtype = self.dtype
         capacity = self.ep_capacity_factor
-
-        def expert_weights():
-            return (
-                self.grouped_experts.gate_weight,
-                self.grouped_experts.up_weight,
-                self.grouped_experts.down_weight,
-            )
 
         def expert_fn(rows, group_sizes, gate_w, up_w, down_w):
             return grouped_swiglu_apply(
@@ -792,7 +795,7 @@ class MoELayer(nn.Module):
                 hidden.reshape(-1, d),
                 topk_ids.reshape(-1, k),
                 topk_probs.reshape(-1, k),
-                *expert_weights(),
+                *self._expert_weights(),
             )
             self._sow_ep_buffer_use(use)
             return out.reshape(hidden.shape).astype(hidden.dtype)
@@ -849,7 +852,7 @@ class MoELayer(nn.Module):
             # the tiled all_gather over dup_axes makes the output invariant
             # there, which vma inference cannot see statically
             check_vma=False,
-        )(hidden, topk_ids, topk_probs, *expert_weights())
+        )(hidden, topk_ids, topk_probs, *self._expert_weights())
         self._sow_ep_buffer_use(use)
         return out.astype(hidden.dtype)
 

@@ -197,6 +197,15 @@ PAGED_STEP_POSITIONS = 512
 PAGED_VMEM_BUDGET = 4 * 1024 * 1024
 
 
+def window_pages(window: int, page_size: int) -> int:
+    """Pages a window of ``window`` positions can span: a query reads
+    ``window - 1`` positions behind its own, which end at most that many
+    pages back (rounded up), and its own page. What a window layer's ring
+    of pages holds a row (``nn/attention.py``) and the most a row has
+    live in the paged kernel."""
+    return -(-(window - 1) // page_size) + 1
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedDecodeGeometry:
     """The tiling :func:`_paged_decode_call` runs for given shapes."""
@@ -208,7 +217,8 @@ class PagedDecodeGeometry:
 
 def paged_decode_geometry(
     *, batch: int, kv_heads: int, n_pages: int, page_size: int,
-    head_dim: int, kv_itemsize: int,
+    head_dim: int, kv_itemsize: int, v_head_dim: int | None = None,
+    window: int | None = None,
 ) -> PagedDecodeGeometry:
     """``pages_per_step`` and the grid, from the shapes alone.
 
@@ -219,9 +229,14 @@ def paged_decode_geometry(
     ``PAGED_STEP_POSITIONS`` key positions (8 pages of 64), at most the
     row's pages, and fewer until both buffers of the K and V blocks fit
     ``PAGED_VMEM_BUDGET`` (one page of every kv head is the least).
+    Under a ``window`` a row never has more live pages than the window
+    and the query's own page span, and a block takes no more.
     """
-    page_bytes = 2 * kv_heads * page_size * head_dim * kv_itemsize  # K and V
+    width = head_dim + (head_dim if v_head_dim is None else v_head_dim)
+    page_bytes = kv_heads * page_size * width * kv_itemsize  # K and V
     pps = min(n_pages, max(1, PAGED_STEP_POSITIONS // page_size))
+    if window is not None:
+        pps = min(pps, window_pages(window, page_size))
     while pps > 1 and 2 * pps * page_bytes > PAGED_VMEM_BUDGET:
         pps -= 1
     return PagedDecodeGeometry(
@@ -257,8 +272,10 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
         last = jnp.minimum((start + (cfg.t - 1)) // page, n_pages - 1)
         first = 0
         if cfg.window is not None:
-            # on a block's edge, so that every block starts on one
-            first = jnp.maximum(start - cfg.window + 1, 0) // block * pps
+            floor = jnp.maximum(start - cfg.window + 1, 0)
+            # the floor's own page; with int8 pools its block's first
+            # page, since the gathered scale rows are cut on block edges
+            first = floor // block * pps if cfg.quant else floor // page
         return first, last - first + 1
 
     def page_copy(pool, buf, pid, j, slot, i):
@@ -397,6 +414,7 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     block of keys, and meet the scores and the probabilities in the
     kernel's float32 math."""
     b, hkv, rp, d = q_rows.shape
+    dv = v_pool.shape[-1]
     n_pages = page_table.shape[1]
     block = cfg.pages_per_step * cfg.block_kv
 
@@ -426,22 +444,22 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
         grid=(b,),
         in_specs=[row_spec(d), *scale_specs]
         + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
-        out_specs=[row_spec(d), row_spec(1)],
+        out_specs=[row_spec(dv), row_spec(1)],
         scratch_shapes=[
             pltpu.VMEM((2, hkv, block, d), k_pool.dtype),
-            pltpu.VMEM((2, hkv, block, d), v_pool.dtype),
+            pltpu.VMEM((2, hkv, block, dv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),  # the buffer the next block waits on
             pltpu.VMEM((hkv, rp, LANES), jnp.float32),
             pltpu.VMEM((hkv, rp, LANES), jnp.float32),
-            pltpu.VMEM((hkv, rp, d), jnp.float32),
+            pltpu.VMEM((hkv, rp, dv), jnp.float32),
         ],
     )
     o, lse = pl.pallas_call(
         functools.partial(_paged_decode_kernel, cfg=cfg, n_pages=n_pages),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rp, d), q_rows.dtype),
+            jax.ShapeDtypeStruct((b, hkv, rp, dv), q_rows.dtype),
             jax.ShapeDtypeStruct((b, hkv, rp, 1), jnp.float32),
         ],
         # rows in order: a row starts the next row's first copies
@@ -467,6 +485,7 @@ def _decode_call(cfg: _DecodeConfig, q_rows, kp, vp, valid, offsets):
     (heads-major — the caller's cache layout, streamed with no relayout)
     → ``(o [B, Hkv, rows_pad, D], lse [B, Hkv, rows_pad])``."""
     b, hkv, rp, d = q_rows.shape
+    dv = vp.shape[-1]
     s_pad = kp.shape[2]
     n_kv = s_pad // cfg.block_kv
 
@@ -486,22 +505,22 @@ def _decode_call(cfg: _DecodeConfig, q_rows, kp, vp, valid, offsets):
             pl.BlockSpec((1, 1, rp, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, cfg.block_kv, d),
                          lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, cfg.block_kv, d),
+            pl.BlockSpec((1, 1, cfg.block_kv, dv),
                          lambda bi, hi, ki: (bi, hi, ki, 0)),
             *valid_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rp, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, rp, dv), lambda bi, hi, ki: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, rp, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rp, d), q_rows.dtype),
+            jax.ShapeDtypeStruct((b, hkv, rp, dv), q_rows.dtype),
             jax.ShapeDtypeStruct((b, hkv, rp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((rp, LANES), jnp.float32),
             pltpu.VMEM((rp, LANES), jnp.float32),
-            pltpu.VMEM((rp, d), jnp.float32),
+            pltpu.VMEM((rp, dv), jnp.float32),
         ],
         compiler_params=(
             None if cfg.interpret else pltpu.CompilerParams(
@@ -531,9 +550,9 @@ def flash_decode_attention(
 ) -> Array:
     """Decode-step attention: ``q [B,T,Hq,D]`` (new tokens at cache
     positions ``start + [0,T)``) against the full slot cache
-    ``k/v [B,Hkv,S,D]`` (HEADS-MAJOR — the layout
+    ``k [B,Hkv,S,D]``, ``v [B,Hkv,S,Dv]`` (HEADS-MAJOR — the layout
     ``_decode_cache_append_heads_major`` maintains, so the cache streams
-    into the kernel with zero per-step relayout) → ``[B,T,Hq,D]``.
+    into the kernel with zero per-step relayout) → ``[B,T,Hq,Dv]``.
 
     Slot-causal + optional sliding window over global positions;
     ``start`` may be a scalar (one shared write index — the closed-batch
@@ -573,6 +592,7 @@ def flash_decode_attention(
     """
     b, t, hq, d = q.shape
     _, hkv, s, _ = k_cache.shape
+    dv = v_cache.shape[-1]  # value heads may be narrower than the keys'
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     g = hq // hkv
@@ -609,6 +629,7 @@ def flash_decode_attention(
         geo = paged_decode_geometry(
             batch=b, kv_heads=hkv, n_pages=n_pages, page_size=page_size,
             head_dim=d, kv_itemsize=k_cache.dtype.itemsize,
+            v_head_dim=dv, window=window_size,
         )
         cfg = _DecodeConfig(
             scale=softmax_scale if softmax_scale is not None else d**-0.5,
@@ -668,9 +689,9 @@ def flash_decode_attention(
         z = jnp.clip(sink_rows - lse[..., None], max=60.0)
         o = (o.astype(jnp.float32) / (1.0 + jnp.exp(z))).astype(o.dtype)
 
-    # [B,Hkv,g·T,D] → [B,T,Hq,D]
+    # [B,Hkv,g·T,Dv] → [B,T,Hq,Dv]
     return (
-        o.reshape(b, hkv, g, t, d)
+        o.reshape(b, hkv, g, t, dv)
         .transpose(0, 3, 1, 2, 4)
-        .reshape(b, t, hq, d)
+        .reshape(b, t, hq, dv)
     )

@@ -313,23 +313,31 @@ def grouped_matmul(x: Array, weight: Array, group_sizes: Array) -> Array:
 # pays beside the bytes is 7 to 9 us a group (PERF.md §6, PR 36), which
 # a few rows a group cannot hide.
 FEW_ROWS_LIMIT = 128
+# A held range of a wider router's experts (``nn/moe.py _forward_held``)
+# takes the same products up to the ridge itself. What it would take
+# otherwise is a buffer chosen per call from the count of pairs that land
+# here, and in passes over chunks of tokens where routing is uneven: each
+# pass streams every held expert's weights again, so a decode step's time
+# followed the seed's router (the MiMo cell's six runs spread by 10 %, one
+# layer in some seeds at three times its even share; PERF.md §6, PR 41).
+# The plain products cost the same whatever the routing.
+HELD_FEW_ROWS_LIMIT = 256
 
 
 def few_rows_touch_all_experts(
-    num_rows: int, top_k: int, num_experts: int
+    num_rows: int, top_k: int, num_experts: int,
+    row_limit: int = FEW_ROWS_LIMIT,
 ) -> bool:
     """Does a call of these static shapes take :func:`all_experts_swiglu`?
 
     Only where the all-expert products stay memory-bound (``num_rows`` at
-    most :data:`FEW_ROWS_LIMIT`) and the routing reads nearly every expert
-    anyway (``num_rows * top_k >= 2 * num_experts``: 86 % expected and
-    more). A one-row ``generate`` step (8 draws over 128 experts) keeps
-    ``ragged_dot`` and reads a sixteenth of the bytes; a training call has
-    thousands of rows and keeps it too.
+    most ``row_limit``) and the routing reads nearly every expert
+    anyway (``num_rows * top_k >= 2 * num_experts`` over the router's
+    ``num_experts``: 86 % expected and more). A one-row ``generate`` step
+    (8 draws over 128 experts) keeps ``ragged_dot`` and reads a sixteenth
+    of the bytes; a training call has thousands of rows and keeps it too.
     """
-    return (
-        num_rows <= FEW_ROWS_LIMIT and num_rows * top_k >= 2 * num_experts
-    )
+    return num_rows <= row_limit and num_rows * top_k >= 2 * num_experts
 
 
 def all_experts_swiglu(

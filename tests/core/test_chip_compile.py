@@ -273,6 +273,93 @@ def test_paged_decode_at_the_serving_cells(topo, slots, hq, hkv, s, pools):
     assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
+@pytest.mark.parametrize(
+    "hkv,window", [(4, None), (8, 128)], ids=["full-4x192", "window-8x192"]
+)
+def test_paged_decode_at_mimo_v2_flash_widths(topo, hkv, window):
+    """The paged decode kernel at the MiMo-V2-Flash cell's two kinds of
+    layer (256 slots, 64 query heads, pages of 64): key rows of 192
+    cached as 256 (whole lane tiles, so the kernel's own page copies end
+    on a tile's edge) against value rows of 128. A full layer reads a
+    pool through the allocator's table, 18 pages a row, blocks of 8; a
+    window layer its row's ring of 3 pages, one block a row."""
+    from d9d_tpu.nn.attention import _cache_row_pad
+    from d9d_tpu.ops.attention.pallas_decode import (
+        flash_decode_attention,
+        paged_decode_geometry,
+        window_pages,
+    )
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    slots, hq, d, dv, n_pages = 256, 64, 192, 128, 18
+    dk = d + _cache_row_pad(d)
+    assert dk == 256
+    geo = paged_decode_geometry(
+        batch=slots, kv_heads=hkv, n_pages=n_pages, page_size=PAGE,
+        head_dim=dk, kv_itemsize=2, v_head_dim=dv, window=window,
+    )
+    pages = slots * (window_pages(window, PAGE) if window else n_pages) + 1
+    assert geo.pages_per_step == (3 if window else 8)
+    compiled = jax.jit(
+        lambda q, k, v, start, table: flash_decode_attention(
+            q, k, v, start=start, page_table=table, window_size=window,
+            softmax_scale=d ** -0.5, sinks=None, interpret=False,
+        )
+    ).lower(
+        sds((slots, 1, hq, dk), BF16), sds((pages, hkv, PAGE, dk), BF16),
+        sds((pages, hkv, PAGE, dv), BF16), sds((slots,), jnp.int32),
+        sds((slots, n_pages), jnp.int32),
+    ).compile()
+    assert _pallas_calls(compiled) == 1
+    assert f"paged_decode_p{geo.pages_per_step}/pallas_call" in compiled.as_text()
+    # the queries' relayout to [B, Hkv, g, 256] (8.4 MB) and the output's:
+    # no pool is copied or relaid
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6
+
+
+def test_a_scanned_cache_append_copies_no_pool(topo):
+    """A decode step in a ``lax.scan`` (the serving chunk's shape): the
+    new token's rows scattered into a heads-major pool, then the paged
+    kernel on it. Written as ``pool.at[page, :, off, :].set`` the compiler
+    gives the scatter another layout and copies the whole pool there and
+    back each step (3.9 ms a layer a step at these shapes on the chip,
+    PERF.md, PR 41); as a scatter of rows into the flat pool the compiled
+    program holds no copy of a pool."""
+    from d9d_tpu.nn.attention import _scatter_head_rows
+    from d9d_tpu.ops.attention.pallas_decode import flash_decode_attention
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    slots, hq, hkv, dk, dv, n_pages = 256, 64, 4, 256, 128, 18
+    pages = slots * n_pages + 1
+
+    def chunk(kpool, vpool, table, start, q, knew, vnew):
+        def step(carry, _):
+            kpool, vpool, start = carry
+            page = jnp.take_along_axis(
+                table, (start // PAGE)[:, None], axis=1)[:, 0]
+            kpool = _scatter_head_rows(kpool, page, start % PAGE, knew)
+            vpool = _scatter_head_rows(vpool, page, start % PAGE, vnew)
+            out = flash_decode_attention(
+                q, kpool, vpool, start=start, page_table=table,
+                softmax_scale=192 ** -0.5, interpret=False,
+            )
+            return (kpool, vpool, start + 1), out.astype(jnp.float32).sum()
+
+        (kpool, vpool, _), outs = jax.lax.scan(
+            step, (kpool, vpool, start), None, length=8)
+        return kpool, vpool, outs
+
+    text = jax.jit(chunk, donate_argnums=(0, 1)).lower(
+        sds((pages, hkv, PAGE, dk), BF16), sds((pages, hkv, PAGE, dv), BF16),
+        sds((slots, n_pages), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, 1, hq, dk), BF16), sds((slots, hkv, dk), BF16),
+        sds((slots, hkv, dv), BF16),
+    ).compile().as_text()
+    pool_copies = re.findall(
+        rf"= bf16\[{pages},{hkv},{PAGE},(?:{dk}|{dv})\][^=\n]*\bcopy\(", text)
+    assert not pool_copies
+
+
 def test_mamba_step_at_jamba2_3b_widths(topo):
     """The Mamba-1 mixer's one-token step for 256 rows at the published
     widths (d_inner 5,120, d_state 16, dt_rank 160): the float32 state

@@ -16,6 +16,7 @@ from d9d_tpu.core import MeshParameters
 from d9d_tpu.nn.moe import MoELayer, grouped_swiglu_apply
 from d9d_tpu.ops.moe import (
     FEW_ROWS_LIMIT,
+    HELD_FEW_ROWS_LIMIT,
     all_experts_swiglu,
     few_rows_touch_all_experts,
     permute_tokens,
@@ -231,6 +232,29 @@ def test_a_decode_step_holds_plain_scoped_products_and_moves_no_row(rows, k, e):
     assert not any(re.search(r"moe/(permute|combine)", s) for _, s in program)
 
 
+HELD = dict(
+    num_routed_experts=64, first_held_expert=8,
+    router_score_function="sigmoid",
+)
+
+
+@pytest.mark.parametrize("rows", [32, HELD_FEW_ROWS_LIMIT])
+def test_a_decode_step_through_a_held_range_takes_the_plain_products(rows):
+    """Since PR 41: up to the chip's ridge in rows, once the pairs would
+    reach the router's width twice over, every held expert's weights go
+    once through the plain products whatever the routing; the buffer ladder
+    and its grouped matmuls are for the calls beside those."""
+    program = layer_program(rows, 4, 8, **HELD)
+    names = {eqn.primitive.name for eqn, _ in program}
+    assert not any(name.startswith("ragged_dot") for name in names), names
+    assert "cond" not in names  # no ladder
+    scoped = re.compile(r"moe/experts/(gate_up|down)/all_experts")
+    assert [
+        scoped.search(s)[1] for eqn, s in program
+        if eqn.primitive.name == "dot_general" and "moe/router" not in s
+    ] == ["gate_up", "gate_up", "down"]
+
+
 @pytest.fixture(scope="module")
 def ep_axes():
     ctx = MeshParameters(dp_shard=4, ep_shard=4).build(jax.devices()[:4])
@@ -238,20 +262,20 @@ def ep_axes():
 
 
 @pytest.mark.parametrize("flow", [
-    "local-16384-rows", "local-one-row", "held-range", "ep-dropless",
-    "ep-capacity",
+    "local-16384-rows", "local-one-row", "held-range",
+    "held-range-few-pairs", "ep-dropless", "ep-capacity",
 ])
 def test_every_other_flow_keeps_the_grouped_matmuls(flow, ep_axes):
-    """A training call and a one-row step bypass by shape; a held range and
-    the two EP flows never reach the local path, at 64 rows either. (Their
-    jaxpr text was compared with the parent commit's once, by digest: PR
-    36's entry in CHANGES.md.)"""
+    """A training call and a one-row step bypass by shape, and so does a
+    held range past ``HELD_FEW_ROWS_LIMIT`` rows or with too few pairs for
+    the router's width; the two EP flows never reach the local path, at 64
+    rows either. (Their jaxpr text was compared with the parent commit's
+    once, by digest: PR 36's entry in CHANGES.md.)"""
     rows, k, e, extra = {
         "local-16384-rows": (16_384, 8, 128, {}),
         "local-one-row": (1, 8, 128, {}),
-        "held-range": (64, 4, 8, dict(
-            num_routed_experts=64, first_held_expert=8,
-            router_score_function="sigmoid")),
+        "held-range": (HELD_FEW_ROWS_LIMIT + 1, 4, 8, HELD),
+        "held-range-few-pairs": (16, 4, 8, HELD),
         "ep-dropless": (64, 2, 16, dict(ep_axes=ep_axes)),
         "ep-capacity": (64, 2, 16, dict(
             ep_axes=ep_axes, ep_capacity_factor=1.25)),

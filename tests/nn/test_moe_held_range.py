@@ -52,13 +52,22 @@ def share_of(params, first: int, held: int):
     return cut
 
 
-@pytest.mark.parametrize("routed,held,top_k", [
-    (16, 4, 3), (16, 8, 2), (8, 2, 4), (64, 8, 4),
+@pytest.mark.parametrize("routed,held,top_k,path", [
+    (16, 4, 3, "ladder"), (16, 8, 2, "ladder"), (8, 2, 4, "ladder"),
+    (64, 8, 4, "ladder"),
+    (16, 4, 3, "plain-products"), (64, 8, 4, "plain-products"),
 ], ids=lambda v: str(v))
-def test_the_shares_add_up_to_the_uncut_layer(routed, held, top_k):
+def test_the_shares_add_up_to_the_uncut_layer(
+    routed, held, top_k, path, monkeypatch
+):
     """Over all shares of a layer the routed parts, with the shared expert
     counted once, sum to the uncut layer's output; so do the gradients to
-    the input, the router and (share by share) the experts."""
+    the input, the router and (share by share) the experts. By both of a
+    held range's paths: these 128 rows take the plain products over the
+    held experts (their pairs reach the router's width twice over in
+    every case), and the buffer ladder with that rule off."""
+    if path == "ladder":
+        monkeypatch.setattr("d9d_tpu.nn.moe.HELD_FEW_ROWS_LIMIT", 0)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, D))
     whole = layer(routed, routed, 0, top_k, shared_expert=SHARED)
     params = whole_layer_params(routed, top_k, x, SHARED)
@@ -199,11 +208,15 @@ def grouped_matmuls(found: dict) -> int:
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
-def test_no_row_buffer_is_as_long_as_all_routed_pairs(what):
+def test_no_row_buffer_is_as_long_as_all_routed_pairs(what, monkeypatch):
     """With a range held no gather, matmul or any other op under the layer
     touches an ``N x k``-row array of hidden or expert width, forward or
     backward, in any branch of the ladder; without one the local path
     gathers exactly that."""
+    # the ladder is for calls past a decode step's rows (PR 41: up to
+    # HELD_FEW_ROWS_LIMIT a held range takes the plain products): held
+    # to it here at these 64 rows, which keep the buffers small
+    monkeypatch.setattr("d9d_tpu.nn.moe.HELD_FEW_ROWS_LIMIT", 0)
     n, k, routed, held = 64, 4, 16, 2
     x = jax.random.normal(jax.random.PRNGKey(0), (1, n, D))
     params = whole_layer_params(routed, k, x)
