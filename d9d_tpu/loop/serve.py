@@ -151,7 +151,7 @@ import os
 import threading
 import time
 import weakref
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -253,6 +253,26 @@ class _ChunkPlan:
     pos: list             # cache position each row writes at the first step
     version: int = 0      # weights generation this chunk dispatched with
     index: int = 0        # chunk index since the last stats reset
+
+
+class _ChunkColumns(NamedTuple):
+    """Columns of a fused chunk's one host-made argument, an int32
+    ``[B, K + 5 (+ pages a row)]`` array: ``_dispatch_chunk`` fills them
+    on the host, ``_build_fused``'s program slices them."""
+
+    forced: slice      # [B, K]: prompt tokens forced step by step
+    n_forced: int      # how many of them the row has this chunk
+    emit_from: int     # first step index that emits
+    admit_mask: int    # 1 on a row admitted with this chunk
+    admit_budget: int  # its max_new_tokens
+    admit_pos: int     # paged: first position past its prefix-cache hit
+    table: slice       # paged: the allocator's page table, to the end
+
+
+def _chunk_columns(k: int) -> _ChunkColumns:
+    return _ChunkColumns(
+        slice(0, k), k, k + 1, k + 2, k + 3, k + 4, slice(k + 5, None)
+    )
 
 
 # default per-transfer staging bound for KV page shipments: the same
@@ -602,7 +622,12 @@ class ContinuousBatcher:
         self._b = batch_size
         self._eos = eos_id
         self._temp = temperature
-        self._rng = rng if rng is not None else jax.random.PRNGKey(0)
+        # a copy of the caller's key: the fused chunk takes the key as a
+        # donated carry (it splits it in the program), and the caller's
+        # array must outlive that
+        self._rng = (
+            jnp.copy(rng) if rng is not None else jax.random.PRNGKey(0)
+        )
         self._k = chunk_size
         self._overlap = overlap and chunk_size is not None
         self._dml = int(getattr(model, "decode_max_length", 0))
@@ -1108,28 +1133,53 @@ class ContinuousBatcher:
         exactly the O(s_max) traffic class the fused loop exists to
         avoid paying per chunk.
 
+        Everything the host decided for the chunk arrives as ONE int32
+        array, ``packed`` (:func:`_chunk_columns`; built and staged by
+        ``_dispatch_chunk``), and the program takes it apart: the forced
+        prompt tokens (transposed to the scan's ``[K, B]``), how many of
+        them a row has, the step it emits from, the admission's mask,
+        budgets and first positions (read by the ``with_admit`` variant
+        only; both take the same array, so there are two programs a
+        ``K``), and in paged mode the host allocator's page table. The
+        RNG key is a carry: the program splits it as the host used to,
+        samples from the second half and hands the first back.
+
         Paged mode differences, same dispatch structure: admitted rows
         reset only their PER-ROW leaves (pools are shared; stale page
         bytes sit behind the slot mask) and jump their write index /
         position to ``admit_pos`` — the first token past their prefix-
-        cache hit; each step additionally pins dead/idle rows' page
-        tables to the garbage page (see :func:`_pin_page_table`)."""
+        cache hit; the host's table is written into every ``page_table``
+        leaf and pinned by the device's own ``live`` (after the
+        admission has set it) before the first step, so a row that died
+        in-device while the host's mirror still holds its pages (a
+        follow-up chunk dispatched with that death unread) goes on
+        writing into the garbage page, and a row the host zeroed
+        (released, or a zombie whose pages wait for a clean boundary)
+        is rerouted there; each step additionally pins dead/idle rows'
+        page tables to the garbage page (see :func:`_pin_page_table`)."""
         eos = self._eos
         paged = self._paged
         counted = self._counts_held_rows
+        cols = _chunk_columns(k)
         if paged:
             from d9d_tpu.nn.decode_flags import (
                 map_cache_index,
+                map_page_table,
                 zero_rows_skip_paged,
             )
 
-        def fused_fn(params, cache, tok, pos, live, rem, key,
-                     forced_t, n_forced, emit_from,
-                     admit_mask=None, admit_budget=None, admit_pos=None):
+        def fused_fn(params, cache, tok, pos, live, rem, key, packed):
+            # forced_t: scan xs layout [K, B]
+            forced_t = packed[:, cols.forced].T
+            n_forced = packed[:, cols.n_forced]
+            emit_from = packed[:, cols.emit_from]
             if with_admit:
+                admit_mask = packed[:, cols.admit_mask] != 0
+                admit_budget = packed[:, cols.admit_budget]
                 # boundary work, fused into the same dispatch: zero
                 # admitted rows' cache and reset their carries
                 if paged:
+                    admit_pos = packed[:, cols.admit_pos]
                     cache = zero_rows_skip_paged(cache, admit_mask)
                     cache = map_cache_index(
                         cache,
@@ -1141,7 +1191,16 @@ class ContinuousBatcher:
                     pos = jnp.where(admit_mask, 0, pos)
                 live = jnp.where(admit_mask, True, live)
                 rem = jnp.where(admit_mask, admit_budget, rem)
-            keys = jax.random.split(key, k)
+            if paged:
+                # the host's table into every leaf, THEN the pin by the
+                # device's live: the mirror still holds the pages of a
+                # row whose death the host has not read yet
+                table = packed[:, cols.table]
+                cache = map_page_table(cache, lambda _pt: table)
+                cache = _pin_page_table(cache, live)
+            # the split the host made before every chunk, same bits
+            key, sub = jax.random.split(key)
+            keys = jax.random.split(sub, k)
 
             def body(carry, xs):
                 (cache, tok, pos, live, rem), held_rows = carry[:5], carry[5:]
@@ -1187,7 +1246,7 @@ class ContinuousBatcher:
                     toks,
                     jnp.zeros((2, k), jnp.int32).at[:, 0].set(counts[0]),
                 ])
-            return cache, tok, pos, live, rem, toks
+            return cache, tok, pos, live, rem, key, toks
 
         return tracked_jit(
             fused_fn,
@@ -1195,7 +1254,11 @@ class ContinuousBatcher:
                 f"serve/fused_k{k}" + ("_paged" if paged else "")
                 + ("_admit" if with_admit else "")
             ),
-            donate_argnums=(1, 2, 3, 4, 5),
+            donate_argnums=(1, 2, 3, 4, 5, 6),
+            # the page-table leaves come in only to hand their buffers to
+            # the tables the program writes: unused, they would be pruned
+            # and their donation dropped
+            keep_unused=paged,
         )
 
     # ------------------------------------------------------------------
@@ -1668,31 +1731,28 @@ class ContinuousBatcher:
                 self._count("serve/prefix_cache_misses")
         return alloc
 
-    def _push_page_table(self) -> int:
+    def _push_page_table(self) -> None:
         """Sync the device page tables from the host mirror (a tiny
         host→device transfer between dispatches — NOT a tracked
-        dispatch). Only ever called at clean boundaries (no chunks in
-        flight), so a zeroed row reroutes any still-live zombie row's
-        writes to the garbage page before its next chunk. Returns the
-        transfers made: one per page-table leaf, none while clean."""
+        dispatch): the single-step path's way, one transfer per
+        page-table leaf while ``_kv_table_dirty``, none while clean.
+        Only ever called at clean boundaries, so a zeroed row reroutes
+        any still-live zombie row's writes to the garbage page before
+        its next step. A fused chunk makes no such transfer: the table
+        is columns of its one packed argument and the program writes
+        every leaf itself (``_build_fused``)."""
         if not self._kv_table_dirty:
-            return 0
+            return
         self._kv_table_dirty = False
         from d9d_tpu.nn.decode_flags import map_page_table
 
         table = self._kv.table
-        pushed = 0
-
-        def push(_pt):
-            # one fresh buffer PER leaf: the cache is donated into the
-            # fused dispatch, and donating one shared buffer through N
-            # layer scopes trips XLA's double-donation check
-            nonlocal pushed
-            pushed += 1
-            return jnp.asarray(table)
-
-        self._cache = map_page_table(self._cache, push)
-        return pushed
+        # one fresh buffer PER leaf: the cache is donated into the
+        # step, and donating one shared buffer through N layer scopes
+        # trips XLA's double-donation check
+        self._cache = map_page_table(
+            self._cache, lambda _pt: jnp.asarray(table)
+        )
 
     def _release_row_pages(self, row: int, *, device_dead: bool) -> None:
         """Drop a retired row's page references. ``device_dead`` rows
@@ -2058,43 +2118,56 @@ class ContinuousBatcher:
         given the previous dispatch (prompt feeding advances host-side,
         everything else is a device carry).
 
+        Everything the host decides crosses to the device in ONE
+        staging: the plan, the admission and (paged) the allocator's
+        page table are columns of one int32 array
+        (:func:`_chunk_columns`) that ``_build_fused``'s program takes
+        apart. The RNG key never comes back to the host: it is a
+        carry the program splits. The table goes with EVERY chunk, so
+        ``_kv_table_dirty`` decides nothing here (the single-step path's
+        ``_push_page_table`` still reads it): whatever admission,
+        release or a deferred release's zeroing did to the mirror is on
+        the device before the chunk's first step.
+
         Phases (``serve/phase/*``, host clock, always on): ``admit`` is
-        a pending weight swap, expiry, page allocation, slot filling and
-        the page-table push; ``plan`` builds the forced tokens, splits
-        the RNG and stages the arguments on the device; ``dispatch`` is
-        the call into the fused program (enqueue only) and the record
+        a pending weight swap, expiry, page allocation and slot filling;
+        ``plan`` fills the array's columns and stages it; ``dispatch``
+        is the call into the fused program (enqueue only) and the record
         of the plan. The profiler's annotations (on during a capture)
         cut the same code a little differently: ``serve.plan`` ends
-        before the three plan arrays are staged and ``serve.dispatch``
-        holds that staging and the call, but not the record of the plan.
+        before the staging and ``serve.dispatch`` holds it and the call,
+        but not the record of the plan.
 
         The chunk's closing ``serve/step`` span also says what the
         dispatch cost the host, whichever phase it fell in:
         ``dispatch_key_s``, ``dispatch_enqueue_s``, ``dispatch_arg_leaves``
         (the fused program's ``TrackedJit.last_call``) and ``stage_s``,
-        ``stage_transfers``: the page-table pushes, the RNG split, the
-        admission arrays and the three plan arrays.
+        ``stage_transfers``: the one staging of the packed array.
         """
         clock = self._clock or self._tele.phases(
             "serve", step=self.stats.chunks
         )
         self._apply_pending_weights()
-        admit_mask = np.zeros((self._b,), bool)
-        admit_budget = np.zeros((self._b,), np.int32)
-        admit_pos = np.zeros((self._b,), np.int32)
-        # seconds and count of this chunk's host-to-device stagings,
-        # wherever they sit: two clock reads a site
-        stage_s, stage_n = 0.0, 0
+        cols = _chunk_columns(k)
+        packed = np.zeros(
+            (self._b,
+             cols.table.start + (self._pages_per_row if self._paged else 0)),
+            np.int32,
+        )
+        # views: the loops below write the array's columns in place
+        admit_mask = packed[:, cols.admit_mask]
+        admit_budget = packed[:, cols.admit_budget]
+        admit_pos = packed[:, cols.admit_pos]
         if admit:
             with annotate("serve.admit"):
                 now = time.perf_counter()
                 self._expire_queued(now)
                 self._expire_running(now)
-                if self._paged and self._kv.flush_deferred():
+                if self._paged:
                     # admit=True ⇒ no chunks in flight: deferred zombie
-                    # pages free now; the zeroed table rows push below,
-                    # BEFORE this dispatch
-                    self._kv_table_dirty = True
+                    # pages free now; this chunk carries the zeroed
+                    # table rows
+                    self._kv.flush_deferred()
                 for i, slot in enumerate(self._slots):
                     if slot.rid >= 0 or not self._queue:
                         continue
@@ -2116,21 +2189,18 @@ class ContinuousBatcher:
                         budget=req.max_new_tokens,
                         deadline_t=req.deadline_t,
                     )
-                    admit_mask[i] = True
+                    admit_mask[i] = 1
                     admit_budget[i] = req.max_new_tokens
                     admit_pos[i] = start_pos
                     self._note_admit(req.rid)
-                if self._paged:
-                    t = time.perf_counter()
-                    stage_n += self._push_page_table()
-                    stage_s += time.perf_counter() - t
                 self._note_pages()
         clock.mark("admit")
 
         with annotate("serve.plan"):
-            forced = np.zeros((self._b, k), np.int32)
-            n_forced = np.zeros((self._b,), np.int32)
-            emit_from = np.full((self._b,), k, np.int32)
+            forced = packed[:, cols.forced]
+            n_forced = packed[:, cols.n_forced]
+            emit_from = packed[:, cols.emit_from]
+            emit_from[:] = k
             rids, pos = [], []
             for i, slot in enumerate(self._slots):
                 rids.append(slot.rid)
@@ -2145,6 +2215,8 @@ class ContinuousBatcher:
                 n_forced[i] = nf
                 emit_from[i] = max(m - 1, 0)
                 slot.feed = slot.feed[k:]
+            if self._paged:
+                packed[:, cols.table] = self._kv.table
 
             with_admit = bool(admit_mask.any())
             fused = self._fused.get((k, with_admit))
@@ -2152,31 +2224,16 @@ class ContinuousBatcher:
                 fused = self._fused[(k, with_admit)] = self._build_fused(
                     k, with_admit
                 )
-            t = time.perf_counter()
-            self._rng, sub = jax.random.split(self._rng)
-            admit_args = ()
-            if with_admit:
-                admit_args = (
-                    jnp.asarray(admit_mask), jnp.asarray(admit_budget)
-                )
-                if self._paged:
-                    admit_args += (jnp.asarray(admit_pos),)
-            stage_n += 1 + len(admit_args)
-            stage_s += time.perf_counter() - t
         with annotate("serve.dispatch"):
+            # the chunk's one host-to-device staging
             t = time.perf_counter()
-            # forced_t: scan xs layout [K, B]
-            plan_args = (
-                jnp.asarray(forced.T), jnp.asarray(n_forced),
-                jnp.asarray(emit_from),
-            )
-            stage_n += len(plan_args)
-            stage_s += time.perf_counter() - t
+            packed_d = jax.device_put(packed)
+            stage_s = time.perf_counter() - t
             clock.mark("plan")
             (self._cache, self._tok_d, self._pos_d, self._live_d,
-             self._rem_d, toks) = fused(
+             self._rem_d, self._rng, toks) = fused(
                 self._params, self._cache, self._tok_d, self._pos_d,
-                self._live_d, self._rem_d, sub, *plan_args, *admit_args,
+                self._live_d, self._rem_d, self._rng, packed_d,
             )
         if self._paged:
             for slot in self._slots:
@@ -2200,7 +2257,7 @@ class ContinuousBatcher:
         self.stats.recurrent_state_bytes = self._recurrent_state_bytes
         self.stats.window_cache_bytes = self._window_cache_bytes
         # what the wrapper's own Python and the enqueue cost this chunk
-        # (TrackedJit.last_call), beside the stagings counted above
+        # (TrackedJit.last_call), beside the one staging timed above
         cost = fused.last_call
         clock.meta.update(
             recurrent_state_bytes=self._recurrent_state_bytes,
@@ -2210,7 +2267,7 @@ class ContinuousBatcher:
             dispatch_enqueue_s=cost.enqueue_s,
             dispatch_arg_leaves=cost.arg_leaves,
             stage_s=stage_s,
-            stage_transfers=stage_n,
+            stage_transfers=1,
         )
         if self._paged:
             # on the closing serve/step span too: ServeStats gives a
@@ -2279,7 +2336,7 @@ class ContinuousBatcher:
                             # step (its later writes are pinned to the
                             # garbage page), so the pages free
                             # immediately; reuse waits for the next admit
-                            # boundary, which pushes the zeroed table row
+                            # boundary, whose chunk carries the new table
                             self._release_row_pages(i, device_dead=True)
                         break
                 self.stats.slot_steps_busy += busy_steps

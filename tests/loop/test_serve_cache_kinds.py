@@ -44,10 +44,11 @@ def tiny_batcher(cell_name: str):
 def chunk_output(batcher, slots: int, k: int = 8):
     """The abstract result of the fused chunk without admission."""
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    # the host-made argument: plan, admission and page table, packed
+    packed = i32(slots, k + 5 + batcher._pages_per_row)
     args = (
         batcher._params, batcher._cache, i32(slots), i32(slots),
-        jnp.zeros((slots,), bool), i32(slots), jax.random.PRNGKey(0),
-        i32(k, slots), i32(slots), i32(slots),
+        jnp.zeros((slots,), bool), i32(slots), jax.random.PRNGKey(0), packed,
     )
     return jax.eval_shape(batcher._build_fused(k, False).jitted, *args)
 
