@@ -13,9 +13,25 @@ def is_mla(cfg: dict) -> bool:
     return "kv_lora_rank" in cfg
 
 
+# the keys a source may count its routed experts under, in the order read
+# (of the catalog's rows the Qwen-MoE and Jamba families use the first, the
+# DeepSeek family the second, Granite and MiniMax the third, Yuan the last)
+EXPERT_COUNTS = (
+    "num_experts", "n_routed_experts", "num_local_experts", "moe_num_experts"
+)
+
+
+def _expert_count(counts: dict) -> int | None:
+    """The first of ``EXPERT_COUNTS`` that ``counts`` states."""
+    return next((counts[k] for k in EXPERT_COUNTS if counts.get(k)), None)
+
+
 def n_routed_experts(cfg: dict) -> int:
     """Routed experts held here: the file's own count."""
-    return cfg.get("num_experts") or cfg["n_routed_experts"]
+    held = _expert_count(cfg)
+    if held is None:
+        raise KeyError(f"none of {', '.join(EXPERT_COUNTS)}")
+    return held
 
 
 def published_experts(cfg: dict) -> int:
@@ -23,10 +39,7 @@ def published_experts(cfg: dict) -> int:
     ``share`` block's published count where this chip holds a share of
     a layer (``reduced`` lists the key), else the count held."""
     published = cfg.get("share", {}).get("published", {})
-    return (
-        published.get("num_experts") or published.get("n_routed_experts")
-        or n_routed_experts(cfg)
-    )
+    return _expert_count(published) or n_routed_experts(cfg)
 
 
 def routed_per_token(cfg: dict) -> float:

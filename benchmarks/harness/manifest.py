@@ -41,9 +41,9 @@ def manifest(root: Path = ROOT) -> dict:
     return load_json(root / "BENCHMARK.json")
 
 
-def metric_file(name: str) -> dict:
+def metric_file(name: str, root: Path = ROOT) -> dict:
     """The metric's own file: unit, layer, what it is and its reader."""
-    return load_json(BENCH_DIR / "metrics" / f"{name}.json")
+    return load_json(root / BENCH_DIR.name / "metrics" / f"{name}.json")
 
 
 def applies(entry: dict, cell_name: str) -> bool:

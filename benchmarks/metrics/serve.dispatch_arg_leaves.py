@@ -1,7 +1,8 @@
 """Argument leaves of the fused chunk program: the largest
 ``dispatch_arg_leaves`` among the measured window's ``serve/step``
-spans (the program with admission takes two or three arrays more than
-the one without). Spans without the key give nothing to read."""
+spans (since PR 42 the program with admission and the one without take
+the same arguments: plan, admission and page table are one packed
+array). Spans without the key give nothing to read."""
 
 from benchmarks.metrics import span_meta
 

@@ -1,6 +1,6 @@
-"""The chunk's host-to-device stagings, wherever they sit (the
-page-table pushes in ``admit``, the RNG split and the admission arrays
-in ``plan``, the three plan arrays): ``stage_s`` on each chunk's
+"""The chunk's host-to-device stagings, wherever they sit (since PR 42
+one a chunk: the packed array of plan, admission and page table, which
+carries the RNG key the program splits): ``stage_s`` on each chunk's
 ``serve/step`` span. Mean over the measured window's chunks; spans
 without the key give nothing to read."""
 

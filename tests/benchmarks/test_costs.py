@@ -149,12 +149,14 @@ def measured(cfg: dict) -> dict:
     }
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_a_committed_configuration_reads_what_it_read(config):
+@pytest.mark.parametrize("pinned", AT_PR_33)
+def test_a_committed_configuration_reads_what_it_read(pinned):
+    """The six configurations of PR 33; a later one pins its readings in
+    a test of its own (a share cut: ``test_mhc_train_cost.py``)."""
+    config = next(c for c in BENCH["configs"] if c["name"] == pinned)
     cfg = json.loads((ROOT / config["file"]).read_text())
-    assert "share" not in cfg  # nothing committed is a share cut yet
     for name, call in measured(cfg).items():
-        want = AT_PR_33[config["name"]][name]
+        want = AT_PR_33[pinned][name]
         if isinstance(want, type):
             with pytest.raises(want):
                 call()
@@ -279,3 +281,52 @@ def test_a_share_cut_serves_the_held_experts_only():
     assert costs.n_sparse_layers(SHARE_CUT) == 4
     assert costs.n_trained_sparse_layers(SHARE_CUT) == 5
     assert readers._expert_mm_decode(run)["flops"] == 4 * 8 * work["flops"]
+
+
+# -- the key a source counts its experts under ---------------------------------
+
+# ISSUE 43's share: 18 of 72 experts of 768 held, top 10, under the key
+# ``num_local_experts``; the expert width is stated as
+# ``moe_intermediate_size`` beside whatever the source calls it
+LOCAL_EXPERTS = {
+    "hidden_size": 4096, "num_experts_per_tok": 10, "num_local_experts": 18,
+    "moe_intermediate_size": 768, "vocab_size": 25_088,
+    "reduced": ["num_hidden_layers", "num_local_experts", "vocab_size"],
+    "share": {"published": {"num_local_experts": 72, "vocab_size": 100_352}},
+}
+
+
+def test_a_share_under_num_local_experts_reads_a_quarter_of_the_rows():
+    """Of a token's 10 experts 18 / 72 are held: 2.5 rows a token land
+    here, and 128 slots x 10 draws over 72 miss a held expert with
+    probability (62 / 72) ** 128 = 5e-9, so all 18 are touched."""
+    assert costs.n_routed_experts(LOCAL_EXPERTS) == 18
+    assert costs.published_experts(LOCAL_EXPERTS) == 72
+    assert costs.routed_per_token(LOCAL_EXPERTS) == 2.5
+    touched = costs.expected_experts_touched(LOCAL_EXPERTS, 128)
+    assert round(touched, 4) == 18.0 and touched < 18
+    work = costs.expert_mm_decode(LOCAL_EXPERTS, 128, touched)
+    assert work["flops"] == 2 * 128 * 2.5 * 3 * 4096 * 768
+    # without the block the file would read as the whole layer: 10 rows
+    alone = {k: v for k, v in LOCAL_EXPERTS.items() if k != "share"}
+    assert costs.routed_per_token(alone) == 10
+
+
+@pytest.mark.parametrize("key", costs.EXPERT_COUNTS)
+def test_an_expert_count_is_read_under_any_of_its_four_keys(key):
+    cfg = {key: 8, "num_experts_per_tok": 4,
+           "share": {"published": {key: 64}}}
+    assert costs.n_routed_experts(cfg) == 8
+    assert costs.published_experts(cfg) == 64
+    assert costs.routed_per_token(cfg) == 0.5
+    assert costs.published_experts({key: 8}) == 8
+
+
+def test_a_file_with_no_expert_count_names_the_four_keys_it_may_use():
+    with pytest.raises(KeyError) as raised:
+        costs.n_routed_experts({"hidden_size": 4096})
+    for key in ("num_experts", "n_routed_experts", "num_local_experts",
+                "moe_num_experts"):
+        assert key in str(raised.value)
+    with pytest.raises(KeyError):
+        costs.published_experts({"share": {"published": {"vocab_size": 8}}})

@@ -11,8 +11,10 @@ import pytest
 from benchmarks.harness import manifest, readers
 
 MS = 1e-3
-MOE_CELLS = ["qwen3-30b-a3b-decode.serve-rollout-closed",
-             "glm-4.7-flash-decode.serve-reason-closed"]
+SERVING_CELLS = ["qwen3-30b-a3b-decode.serve-rollout-closed",
+                 "glm-4.7-flash-decode.serve-reason-closed",
+                 "jamba2-3b-decode.serve-reason-closed",
+                 "mimo-v2-flash-share16-decode.serve-reason-closed"]
 SPLIT = ["serve.dispatch_key_ms_per_chunk",
          "serve.dispatch_enqueue_ms_per_chunk", "serve.stage_ms_per_chunk",
          "serve.dispatch_arg_leaves", "serve.phase_gap_ms_per_chunk"]
@@ -161,34 +163,44 @@ def test_a_program_with_no_exchange_reads_no_exchange_counter(hub, name):
     assert readers.read(a_run(), name) is None
 
 
-@pytest.mark.parametrize("name", SPLIT)
-def test_an_unlisted_metrics_own_file_is_ready_to_be_listed(name):
-    """The five serving metrics are files only: the driver takes new
-    entries at the end of ``per_layer`` alone, and there an entry that
-    lists a serving cell fails ``test_run_tiny_glm.py`` (the rollout
-    cell's names are the GLM cell's less its last three) or
-    ``test_run_tiny_jamba.py`` (``names == SHARED + OWN``). A
-    ``benchmark`` PR lists them with entries alone: each file holds what
-    its entry has to repeat, and a reader."""
-    bench = manifest.manifest()
-    assert name not in {m["name"] for m in bench["per_layer"]}
-    own = manifest.metric_file(name)
+def check_a_dispatch_metric_is_listed_for_the_serving_cells(
+        name, root=manifest.ROOT):
+    """Listed since PR 43, entries alone: each file holds what its entry
+    repeats, and a reader; every serving cell emits every key."""
+    by_name = {m["name"]: m for m in manifest.manifest(root)["per_layer"]}
+    entry = by_name[name]
+    # every serving cell of PR 43; a later serving cell adds its name
+    assert set(SERVING_CELLS) <= set(entry["workloads"])
+    own = manifest.metric_file(name, root=root)
     assert own["name"] == name and own["reader"] == {"file": True}
-    assert (manifest.BENCH_DIR / "metrics" / f"{name}.py").is_file()
+    assert (root / "benchmarks/metrics" / f"{name}.py").is_file()
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == own[key], key
     assert own["layer"] == "serving loop"
     assert own["moves"] == "serve_tokens_per_s"
     assert own["better"] == "lower"
     assert own["unit"] == ("leaves" if name.endswith("arg_leaves") else "ms")
-    # the one counter of the five is what a CPU run would print
+    # the one counter of the five is what a CPU run prints
     assert (own["source"] == "program_counter") == \
         (name == "serve.dispatch_arg_leaves")
     assert not {"kinds", "min_chips", "workloads"} & set(own)
 
 
-def test_the_manifest_lists_the_exchanges_counters_at_its_end():
-    bench = manifest.manifest()
-    by_name = {m["name"]: m for m in bench["per_layer"]}
+@pytest.mark.parametrize("name", SPLIT)
+def test_a_dispatch_metric_is_listed_for_the_serving_cells(name):
+    check_a_dispatch_metric_is_listed_for_the_serving_cells(name)
+
+
+def check_the_manifest_lists_the_exchanges_counters_fill_before_fallback(
+        root=manifest.ROOT):
+    per_layer = manifest.manifest(root)["per_layer"]
+    by_name = {m["name"]: m for m in per_layer}
     for name in EP:
         assert by_name[name]["workloads"] == ["qwen3-30b-a3b-ep4.train-16k"]
         assert by_name[name]["source"] == "program_counter"
-    assert [m["name"] for m in bench["per_layer"]][-2:] == EP
+    names = [m["name"] for m in per_layer]
+    assert names.index(EP[0]) < names.index(EP[1])
+
+
+def test_the_manifest_lists_the_exchanges_counters_fill_before_fallback():
+    check_the_manifest_lists_the_exchanges_counters_fill_before_fallback()

@@ -1,7 +1,7 @@
 """The paged grouped-query decode's cost function against hand arithmetic
 at MiMo-V2-Flash's published sizes, the serving costs the harness reads
-of this share-cut configuration pinned to the last digit
-(``tests/benchmarks/conftest.py`` says why here), and this PR's readers on
+of this share-cut configuration pinned to the last digit (``test_costs.py``
+pins the six configurations of PR 33 alone), and this PR's readers on
 hand-made observations: what they read, and that a program without the
 counters gives them nothing to read (the parent commit under these
 files)."""

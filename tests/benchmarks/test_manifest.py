@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` against the contract, and against the files it
 names. Nothing here needs a device."""
 
+import functools
 import json
 import re
 from pathlib import Path
@@ -24,8 +25,9 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 # ``_size`` and are a count: a chip may hold a slice of them
 WIDTH = re.compile(r"(_dim|_rank|_size)$|head|experts_per_tok")
 COUNTS = ("vocab_size",)
-# the counts a chip holds a share of, where a layer is divided over chips
-EXPERT_COUNTS = ("n_routed_experts", "num_experts")
+# the counts a chip holds a share of, where a layer is divided over chips:
+# the routed experts, under whichever of the four keys the source uses
+EXPERT_COUNTS = costs.EXPERT_COUNTS
 SHARED = (*COUNTS, *EXPERT_COUNTS)
 
 
@@ -55,7 +57,8 @@ def check_reduced(body: dict, published: dict | None = None) -> None:
     """The rule for a configuration file's ``reduced``: no width may be
     cut, and a chip's share of a stated deployment (the ``model-configs``
     guide, section 4) is stated and kept to the guide's floors. A file
-    that lists the vocabulary or the routed experts carries
+    that lists the vocabulary or the routed experts (under the source's
+    own key, one of ``EXPERT_COUNTS``) carries
 
         "share": {"published": {key: count}}
 
@@ -259,15 +262,57 @@ def test_the_share_cut_is_what_the_cost_functions_read(tmp_path):
         == d * (102_400 - 12_800) + (6 - 0.75) * per_expert
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
-def test_cell_entry_and_files(cell):
+# a share stated under another of the keys a source may count its experts
+# by: 18 of 72 experts and a quarter of a 100,352-row vocabulary, the
+# numbers ISSUE 43 reckons for a source that says ``num_local_experts``
+LOCAL_EXPERTS = {
+    "num_hidden_layers": 10, "num_local_experts": 18, "vocab_size": 25_088,
+    "reduced": ["num_hidden_layers", "num_local_experts", "vocab_size"],
+    "share": {"published": {"num_local_experts": 72, "vocab_size": 100_352}},
+}
+THE_SOURCE = {"num_hidden_layers": 40, "num_local_experts": 72,
+              "vocab_size": 100_352}
+
+
+@pytest.mark.parametrize("held,published,passes", [
+    (18, None, True), (18, THE_SOURCE, True),
+    (6, THE_SOURCE, False),  # under the floor of 8
+    (20, THE_SOURCE, False),  # 72 experts do not divide into twenties
+    (18, dict(THE_SOURCE, num_local_experts=144), False),
+], ids=["alone", "against_its_source", "six_held", "twenty_held",
+        "not_the_sources_count"])
+def test_a_share_is_stated_under_the_sources_own_key(held, published, passes):
+    """``check_against_catalog`` reads ``published[key]`` for every shared
+    key of ``reduced``, so the key has to be the source's own: an alias
+    would be a ``KeyError`` on the catalog's row."""
+    body = dict(LOCAL_EXPERTS, num_local_experts=held)
+    if passes:
+        check_reduced(body, published=published)
+    else:
+        with pytest.raises(AssertionError):
+            check_reduced(body, published=published)
+
+
+@pytest.mark.parametrize("key", EXPERT_COUNTS)
+def test_every_expert_count_key_is_held_to_the_share_rule(key):
+    body = {key: 8, "reduced": [key], "share": {"published": {key: 64}}}
+    check_reduced(body, published={key: 64})
+    with pytest.raises(AssertionError):
+        check_reduced(dict(body, **{key: 4}))
+    with pytest.raises(AssertionError):  # listed, and no block beside it
+        check_reduced({key: 8, "reduced": [key]})
+
+
+def check_cell(cell: dict, bench: dict, root: Path) -> None:
+    """A ``workloads`` entry of ``bench`` and what the manifest under
+    ``root`` gives the cell."""
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
     assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
     assert cell["name"] == f"{cell['config']}.{cell['traffic']}"
     assert cell["chips"] in (1, 4)
     assert one_line(cell["why"])
-    assert cell["config"] in {c["name"] for c in BENCH["configs"]}
-    loaded = manifest.cell(cell["name"])
+    assert cell["config"] in {c["name"] for c in bench["configs"]}
+    loaded = manifest.cell(cell["name"], root=root)
     assert loaded.config["chips"] == cell["chips"]
     assert loaded.traffic["kind"] in ("train_steps", "closed_loop")
     reported = {m["name"] for m in loaded.end_to_end}
@@ -278,16 +323,25 @@ def test_cell_entry_and_files(cell):
         assert metric["moves"] in reported, metric["name"]
 
 
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_cell_entry_and_files(cell):
+    check_cell(cell, BENCH, ROOT)
+
+
+def copy_the_data_files(tmp_path) -> None:
+    import shutil
+
+    for sub in ("configs", "traffic", "metrics"):
+        shutil.copytree(ROOT / "benchmarks" / sub, tmp_path / "benchmarks" / sub)
+
+
 def with_a_new_cell(tmp_path, kind: str):
     """A copy of the manifest and its data files with one more cell: the
     decode configuration under a traffic mix of a kind no cell has yet,
     added the way a later PR adds one (a traffic file, a ``workloads``
     entry, the cell's name on the serving metrics' lists)."""
-    import shutil
-
     name = "qwen3-30b-a3b-decode.serve-new-mix"
-    for sub in ("configs", "traffic"):
-        shutil.copytree(ROOT / "benchmarks" / sub, tmp_path / "benchmarks" / sub)
+    copy_the_data_files(tmp_path)
     closed = json.loads(
         (ROOT / "benchmarks/traffic/serve-rollout-closed.json").read_text())
     (tmp_path / "benchmarks/traffic/serve-new-mix.json").write_text(
@@ -341,9 +395,11 @@ def test_cells_are_distinct_and_few_take_four_chips():
     assert four <= max(1, len(pairs) // 4)
 
 
-@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
-def test_metric_entry_and_its_own_file(metric):
-    end_to_end = metric in BENCH["end_to_end"]
+def check_metric(metric: dict, bench: dict, root: Path) -> None:
+    """An ``end_to_end`` or ``per_layer`` entry of ``bench`` and the
+    metric's own file under ``root``."""
+    end_to_end = metric in bench["end_to_end"]
+    cells = [w["name"] for w in bench["workloads"]]
     allowed = {"name", "unit", "better", "source", "workloads"}
     allowed |= {"bound"} if end_to_end else {"layer", "moves"}
     assert set(metric) <= allowed
@@ -356,22 +412,39 @@ def test_metric_entry_and_its_own_file(metric):
         assert 0.01 <= metric["bound"] <= 0.1
     else:
         assert one_line(metric["layer"])
-        assert metric["moves"] in {m["name"] for m in BENCH["end_to_end"]}
-    assert set(metric.get("workloads", CELLS)) <= set(CELLS)
-    own = manifest.metric_file(metric["name"])
+        assert metric["moves"] in {m["name"] for m in bench["end_to_end"]}
+    assert set(metric.get("workloads", cells)) <= set(cells)
+    own = manifest.metric_file(metric["name"], root=root)
     for key in ("unit", "better", "source", "layer", "moves"):
         assert own.get(key) == metric.get(key), key
     # which cells report a metric is the manifest's to say, and only its
     assert not {"kinds", "min_chips", "workloads"} & set(own)
     reader = own["reader"]
     if reader.get("file"):
-        assert (ROOT / "benchmarks/metrics" / f"{metric['name']}.py").is_file()
+        assert (root / "benchmarks/metrics" / f"{metric['name']}.py").is_file()
     else:
         from benchmarks.harness import readers
 
         assert callable(getattr(readers, reader["use"]))
     if re.search(r"roofline", metric["name"]):
         assert metric["name"].endswith("_roofline") and metric["unit"] == "%"
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_entry_and_its_own_file(metric):
+    check_metric(metric, BENCH, ROOT)
+
+
+def check_files_and_entries(bench: dict, root: Path) -> None:
+    """No file under ``benchmarks/metrics/`` waits for an entry (55 of 55
+    since PR 43), so a reading that a run makes reaches the ledger."""
+    files = {p.stem for p in (root / "benchmarks/metrics").glob("*.json")}
+    listed = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert files == set(listed) and len(set(listed)) == len(listed)
+
+
+def test_every_metric_file_is_listed_and_every_entry_has_its_file():
+    check_files_and_entries(BENCH, ROOT)
 
 
 def test_metric_names_are_distinct_and_setup_is_there():
@@ -381,6 +454,99 @@ def test_metric_names_are_distinct_and_setup_is_there():
     assert 1 <= len(BENCH["per_layer"]) <= 128
     setup = next(m for m in BENCH["end_to_end"] if m["name"] == "setup_s")
     assert setup["bound"] <= 0.1
+
+
+# -- what a later PR may add with files and entries alone ----------------------
+
+def with_an_appended_entry(tmp_path, cell: str):
+    """A copy of the manifest and its data files with one more per-layer
+    metric, added the way a later PR adds one: the metric's two files, and
+    an entry at the end of ``per_layer`` that lists ``cell`` alone."""
+    name = "layer.appended_by_a_later_pr"
+    copy_the_data_files(tmp_path)
+    bench = json.loads(json.dumps(BENCH))
+    moves = next(m["name"] for m in bench["end_to_end"]
+                 if cell in m.get("workloads", ()))
+    entry = {"name": name, "unit": "count", "better": "lower",
+             "source": "program_counter", "layer": "model", "moves": moves}
+    metrics = tmp_path / "benchmarks/metrics"
+    (metrics / f"{name}.json").write_text(json.dumps(dict(
+        entry, what="a test", reader={"file": True})))
+    (metrics / f"{name}.py").write_text("def read(run):\n    return None\n")
+    bench["per_layer"].append(dict(entry, workloads=[cell]))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return name
+
+
+@functools.cache
+def the_other_files_checks() -> dict:
+    """The test files that check the manifest's or a cell's list of
+    metrics, loaded once: each has its check as a function of the root."""
+    from tests.conftest import load_repo_module
+
+    return {
+        stem: load_repo_module(f"bench_{stem}", f"tests/benchmarks/{stem}.py")
+        for stem in ("test_run_tiny_glm", "test_run_tiny_jamba",
+                     "test_run_tiny_mimo", "test_mhc_train_cost",
+                     "test_dispatch_split_readers")
+    }
+
+
+def check_the_whole_manifest(root: Path) -> None:
+    """Every check of this file that reads the manifest, and every check
+    that another file of ``tests/benchmarks/`` makes of the manifest's or
+    a cell's list of metrics, against the manifest under ``root``."""
+    bench = manifest.manifest(root)
+    entries = catalog() if CATALOG.exists() else {}
+    for config in bench["configs"]:
+        check_config(config, bench, root)
+        if config["source"] in entries:
+            body = json.loads((root / config["file"]).read_text())
+            check_against_catalog(config, body, entries[config["source"]])
+    for cell in bench["workloads"]:
+        check_cell(cell, bench, root)
+    for metric in bench["end_to_end"] + bench["per_layer"]:
+        check_metric(metric, bench, root)
+    check_files_and_entries(bench, root)
+    assert len(bench["per_layer"]) <= 128
+    others = the_other_files_checks()
+    for stem, module in others.items():
+        if stem != "test_dispatch_split_readers":
+            module.check_the_manifest_gives_the_cell_its_metrics(root)
+    mimo = others["test_run_tiny_mimo"]
+    split = others["test_dispatch_split_readers"]
+    for own in mimo.OWN_FILES:
+        mimo.check_an_own_metrics_file_is_listed_for_its_cell(*own, root=root)
+    for name in split.SPLIT:
+        split.check_a_dispatch_metric_is_listed_for_the_serving_cells(
+            name, root)
+    split.check_the_manifest_lists_the_exchanges_counters_fill_before_fallback(
+        root)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_appended_per_layer_entry_is_files_and_an_entry_only(
+        tmp_path, cell):
+    """What a ``model_config`` PR owes and PR 37 and PR 41 could not
+    deliver: a per-layer entry at the end of the list, for any cell,
+    passes every check of the manifest and of the cells; the cell's line
+    gains the metric after all it had, and no other cell's changes."""
+    name = with_an_appended_entry(tmp_path, cell)
+    check_the_whole_manifest(tmp_path)
+    listed = lambda c, root: [  # noqa: E731
+        m["name"] for m in manifest.cell(c, root=root).per_layer]
+    for other in CELLS:
+        grown = [name] if other == cell else []
+        assert listed(other, tmp_path) == listed(other, ROOT) + grown
+
+
+def test_a_new_serving_cell_on_the_lists_passes_every_check(tmp_path):
+    """The other addition a later PR makes: a cell's name appended to the
+    lists of the metrics it reports."""
+    name = with_a_new_cell(tmp_path, kind="closed_loop")
+    check_the_whole_manifest(tmp_path)
+    assert len(manifest.cell(name, root=tmp_path).per_layer) == len(
+        manifest.cell("qwen3-30b-a3b-decode.serve-rollout-closed").per_layer)
 
 
 def test_files_under_paths_have_legal_names():

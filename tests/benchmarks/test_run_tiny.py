@@ -19,6 +19,32 @@ COUNTERS = {
 }
 
 
+# What every serving cell reports since PR 43, in the manifest's order. A
+# cell's test holds its names to this and to its own: as a subsequence or a
+# subset, never as the whole list, so that a later PR can append an entry
+# or list a new cell with files and entries alone
+EVERY_SERVING_CELL = [
+    "entry.compile_s", "entry.serve_compiles_in_window",
+    "serve.slot_occupancy_pct", "serve.prompt_step_share_pct",
+    "serve.ttft_p50_ms", "serve.tpot_p50_ms", "serve.host_gap_ms_per_chunk",
+    "device.serve_idle_pct", "serve.phase_host_ms_per_chunk",
+    "serve.longest_chunk_ms", "serve.gc_pause_ms",
+    "serve.prompt_slot_steps_pct", "entry.lower_s",
+    "model.decode_attention_device_pct", "serve.dispatch_key_ms_per_chunk",
+    "serve.dispatch_enqueue_ms_per_chunk", "serve.dispatch_arg_leaves",
+    "serve.stage_ms_per_chunk", "serve.phase_gap_ms_per_chunk",
+]
+# and the two more of the serving cells whose layers hold experts
+EXPERT_SERVING_CELLS = ["kernel.expert_mm_decode_roofline",
+                        "model.decode_experts_device_pct"]
+
+
+def in_order(names, within) -> bool:
+    """``names`` are all among ``within``, in this order."""
+    rest = iter(within)
+    return all(name in rest for name in names)
+
+
 def run_py(*args, devices=1, timeout=600):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")

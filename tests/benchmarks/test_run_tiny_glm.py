@@ -10,7 +10,10 @@ from tests.conftest import load_repo_module
 
 # the helpers of the first tiny-run tests: one run per module and case
 _tiny = load_repo_module("bench_run_tiny", "tests/benchmarks/test_run_tiny.py")
-COUNTERS, tiny_line = _tiny.COUNTERS, _tiny.tiny_line
+COUNTERS, tiny_line, in_order = _tiny.COUNTERS, _tiny.tiny_line, _tiny.in_order
+ROOT, SHARED = _tiny.ROOT, _tiny.EVERY_SERVING_CELL + _tiny.EXPERT_SERVING_CELLS
+OWN = ["kernel.mla_decode_roofline", "serve.mean_context_tokens",
+       "serve.latent_pool_used_pct"]
 CELL = "glm-4.7-flash-decode.serve-reason-closed"
 
 
@@ -42,21 +45,26 @@ def test_the_cells_own_counters_are_read_from_the_program():
     assert "kernel.mla_decode_roofline" not in metrics
 
 
-def test_the_manifest_gives_the_cell_its_metrics():
+def check_the_manifest_gives_the_cell_its_metrics(root=ROOT):
     from benchmarks.harness import manifest
 
-    cell = manifest.cell(CELL)
-    rollout = manifest.cell("qwen3-30b-a3b-decode.serve-rollout-closed")
+    cell = manifest.cell(CELL, root=root)
+    rollout = manifest.cell(
+        "qwen3-30b-a3b-decode.serve-rollout-closed", root=root)
     names = [m["name"] for m in cell.per_layer]
     # every serving metric the rollout cell reports, and three of its own
-    assert [m["name"] for m in rollout.per_layer] == names[:-3]
-    assert names[-3:] == [
-        "kernel.mla_decode_roofline", "serve.mean_context_tokens",
-        "serve.latent_pool_used_pct",
-    ]
+    # in the manifest's order
+    assert set(SHARED) <= set(names)
+    assert set(SHARED) <= {m["name"] for m in rollout.per_layer}
+    assert in_order(OWN, names)
+    assert not {OWN[0], OWN[2]} & {m["name"] for m in rollout.per_layer}
     assert [m["name"] for m in cell.end_to_end] == \
         [m["name"] for m in rollout.end_to_end]
     assert cell.config["serving"] == {
         "slots": 64, "page_size": 64, "decode_max_length": 1152,
     }
     assert cell.traffic["kind"] == "closed_loop"
+
+
+def test_the_manifest_gives_the_cell_its_metrics():
+    check_the_manifest_gives_the_cell_its_metrics()

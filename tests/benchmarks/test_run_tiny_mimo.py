@@ -2,8 +2,7 @@
 rig, a new process per run as the driver starts it: the contract's last
 line, ``correct`` true against the family's reference (which reads the
 layer kinds from the tree there), counters only; the manifest's entry
-for the cell, and the cell's own four metrics as files ready to be
-listed."""
+for the cell, and the cell's own four metrics, listed for it alone."""
 
 import pytest
 
@@ -11,7 +10,8 @@ from tests.conftest import load_repo_module
 
 # the helpers of the first tiny-run tests: one run per module and case
 _tiny = load_repo_module("bench_run_tiny", "tests/benchmarks/test_run_tiny.py")
-COUNTERS, tiny_line = _tiny.COUNTERS, _tiny.tiny_line
+COUNTERS, tiny_line, in_order = _tiny.COUNTERS, _tiny.tiny_line, _tiny.in_order
+ROOT = _tiny.ROOT
 CELL = "mimo-v2-flash-share16-decode.serve-reason-closed"
 JAMBA = "jamba2-3b-decode.serve-reason-closed"
 
@@ -41,32 +41,43 @@ def test_the_cells_counters_are_read_from_the_program():
     # the tiny table: prompts 3 and 6, outputs 10 and 20
     context = metrics["serve.mean_context_tokens"]
     assert 6.5 <= context["value"] <= 13.0
-    # the cell's own four metrics are files only (below): never printed
-    assert not set(OWN) & set(metrics)
+    # of the cell's own four the two counters are printed. mimo_v2_flash_tiny:
+    # 2 window layers x 4 slots x a ring of 12 positions x 4 key/value
+    # heads x (24 + 16) bf16 numbers
+    rings = metrics["serve.window_cache_gb"]
+    assert rings["unit"] == "GB"
+    assert rings["value"] == pytest.approx(2 * 4 * 12 * 4 * (24 + 16) * 2 / 1e9)
+    # 4 of 64 routed experts held: 6.25 % at an even router
+    held = metrics["moe.decode_held_rows_pct"]
+    assert held["unit"] == "%" and 3.0 <= held["value"] <= 12.5
+    # shares of device time and of a roofline come from a device trace
+    assert "kernel.gqa_decode_roofline" not in metrics
+    assert "model.decode_window_attention_device_pct" not in metrics
 
 
-def test_the_manifest_gives_the_cell_its_metrics():
+def check_the_manifest_gives_the_cell_its_metrics(root=ROOT):
     """A later PR that drops the cell from a list fails here and not in
     the driver's check (a listed metric missing from the last line is
     ``output_malformed``, one never listed is never read)."""
     from benchmarks.harness import manifest
 
-    cell = manifest.cell(CELL)
-    jamba = manifest.cell(JAMBA)
-    glm = manifest.cell("glm-4.7-flash-decode.serve-reason-closed")
+    cell = manifest.cell(CELL, root=root)
+    jamba = manifest.cell(JAMBA, root=root)
+    glm = manifest.cell("glm-4.7-flash-decode.serve-reason-closed", root=root)
     names = [m["name"] for m in cell.per_layer]
-    # what every serving cell reports and the expert metrics of the MoE
-    # serving cells
-    shared = [m["name"] for m in jamba.per_layer][:-3]
-    experts = {"kernel.expert_mm_decode_roofline",
-               "model.decode_experts_device_pct"}
-    assert set(names) == set(shared) | experts
-    assert experts <= {m["name"] for m in glm.per_layer}
     # what the other serving cells report and this one has nothing to read for
     absent = {"kernel.mla_decode_roofline", "serve.latent_pool_used_pct",
               "model.decode_ssm_device_pct", "kernel.ssm_decode_roofline",
               "serve.recurrent_state_gb"}
     assert not absent & set(names)
+    # what every serving cell reports, the expert metrics of the MoE
+    # serving cells, the mean context, and four of its own
+    shared = _tiny.EVERY_SERVING_CELL + ["serve.mean_context_tokens"]
+    experts = _tiny.EXPERT_SERVING_CELLS
+    assert set(names) >= set(shared) | set(experts) | set(OWN)
+    assert set(shared) <= {m["name"] for m in jamba.per_layer}
+    assert in_order(OWN, names)
+    assert set(experts) <= {m["name"] for m in glm.per_layer}
     assert [m["name"] for m in cell.end_to_end] == [
         "serve_tokens_per_s", "serve_ttft_p95_ms", "serve_tpot_p95_ms",
         "setup_s",
@@ -88,7 +99,17 @@ def test_the_manifest_gives_the_cell_its_metrics():
     assert cell.traffic == jamba.traffic == glm.traffic
 
 
-@pytest.mark.parametrize("name,unit,source,layer,moves,better", [
+def test_the_manifest_gives_the_cell_its_metrics():
+    check_the_manifest_gives_the_cell_its_metrics()
+
+
+OTHERS_AT_PR_43 = [
+    "qwen3-30b-a3b-l1.train-16k", "qwen3-30b-a3b-decode.serve-rollout-closed",
+    "deepseek-v2-lite-l2.train-16k", "qwen3-30b-a3b-ep4.train-16k",
+    "glm-4.7-flash-decode.serve-reason-closed", JAMBA,
+    "xing4.0-29b-a4b-share8.train-8k",
+]
+OWN_FILES = [
     ("serve.window_cache_gb", "GB", "program_counter", "serving loop",
      "serve_tokens_per_s", "lower"),
     ("model.decode_window_attention_device_pct", "%", "device_trace",
@@ -97,24 +118,36 @@ def test_the_manifest_gives_the_cell_its_metrics():
      "serve_tokens_per_s", "higher"),
     ("moe.decode_held_rows_pct", "%", "program_counter", "model",
      "serve_tokens_per_s", "higher"),
-])
-def test_an_own_metrics_file_is_ready_to_be_listed(
-        name, unit, source, layer, moves, better):
-    """The cell's own four metrics are files only, as PR 37's five are:
-    the driver takes new entries at the end of ``per_layer`` alone, and
-    ``test_dispatch_split_readers.py`` pins the list's last two to the
-    exchange's counters, a file of the benchmark that only a
-    ``benchmark`` PR may edit. That PR lists them with entries alone,
-    ``"workloads": [CELL]`` each: a file holds what its entry has to
-    repeat, and a reader (``test_gqa_decode_cost.py`` has each over a
-    hand-made run)."""
+]
+
+
+def check_an_own_metrics_file_is_listed_for_its_cell(
+        name, unit, source, layer, moves, better, root=ROOT):
+    """Listed since PR 43 for this cell alone, entries alone: a file
+    holds what its entry repeats, and a reader
+    (``test_gqa_decode_cost.py`` has each over a hand-made run)."""
     from benchmarks.harness import manifest
 
     assert name in OWN
-    assert name not in {m["name"] for m in manifest.manifest()["per_layer"]}
-    own = manifest.metric_file(name)
+    by_name = {m["name"]: m for m in manifest.manifest(root)["per_layer"]}
+    entry = by_name[name]
+    # for this cell and for none of the other cells of PR 43: none of them
+    # decodes through a window layer or a held range of experts (a later
+    # cell that does may join)
+    assert CELL in entry["workloads"]
+    assert not set(OTHERS_AT_PR_43) & set(entry["workloads"])
+    own = manifest.metric_file(name, root=root)
     assert own["name"] == name and own["reader"] == {"file": True}
-    assert (manifest.BENCH_DIR / "metrics" / f"{name}.py").is_file()
+    assert (root / "benchmarks/metrics" / f"{name}.py").is_file()
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == own[key], key
     assert (own["unit"], own["source"], own["layer"], own["moves"],
             own["better"]) == (unit, source, layer, moves, better)
     assert not {"kinds", "min_chips", "workloads"} & set(own)
+
+
+@pytest.mark.parametrize("name,unit,source,layer,moves,better", OWN_FILES)
+def test_an_own_metrics_file_is_listed_for_its_cell(
+        name, unit, source, layer, moves, better):
+    check_an_own_metrics_file_is_listed_for_its_cell(
+        name, unit, source, layer, moves, better)
