@@ -842,6 +842,14 @@ class Trainer:
                             k: v for k, v in host_metrics.items()
                             if k.startswith("moe/")
                         })
+                        # and what the flash kernels' grids visit and
+                        # compute, noted where the step was traced
+                        # (ops/attention/pallas_flash.py _note_grid)
+                        clock.meta.update({
+                            k: g.value
+                            for k, g in list(tele.registry.gauges.items())
+                            if k.startswith("flash/")
+                        })
                         host_metrics.update(
                             self.metric_collector.flush(self.run, step)
                         )

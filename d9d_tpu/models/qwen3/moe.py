@@ -71,12 +71,19 @@ class AttentionKind:
     kind's layers attend the last ``window_size`` positions only, and the
     serving loop then keeps no more of them (``nn/attention.py``, "ring of
     pages"); ``use_sinks`` gives each query head a learned logit that
-    joins the softmax's denominator alone."""
+    joins the softmax's denominator alone. ``num_heads`` is the kind's
+    count of query heads (its ``q_proj``, gate and ``o_proj`` widths);
+    ``rope_fraction`` and ``rope_scaling`` are the share of a head the
+    kind rotates and the law its frequencies follow, so that one stack
+    holds a half-rotated YaRN kind beside a plainly, wholly rotated one."""
 
     num_kv_heads: Optional[int] = None
     rope_theta: Optional[float] = None
     window_size: Optional[int] = None
     use_sinks: bool = False
+    num_heads: Optional[int] = None
+    rope_fraction: Optional[float] = None
+    rope_scaling: Optional[RopeScaling] = None
 
 
 # the token-mixer kinds a layer can be without an entry in
@@ -120,6 +127,9 @@ class Qwen3MoeConfig:
     # zero-centered RMSNorm weights (scale = 1 + w) on every norm except
     # the GDN gated output norm
     use_output_gate: bool = False
+    # the gate is one logit a query head, broadcast over the head's
+    # numbers (``gate_proj`` of width ``h``), not one a number
+    output_gate_per_head: bool = False
     # single matmul for q/k/v (see nn/attention.py fused_qkv)
     fused_qkv: bool = False
     rope_fraction: float = 1.0
@@ -242,6 +252,12 @@ class Qwen3MoeConfig:
             rope_theta=own.rope_theta or self.rope_theta,
             window_size=own.window_size,
             use_sinks=own.use_sinks,
+            num_heads=own.num_heads or self.num_heads,
+            rope_fraction=(
+                self.rope_fraction if own.rope_fraction is None
+                else own.rope_fraction
+            ),
+            rope_scaling=own.rope_scaling or self.rope_scaling,
         )
 
     @staticmethod
@@ -430,7 +446,7 @@ class Qwen3MoeDecoderLayer(nn.Module):
             with scope:
                 attn_out = GroupedQueryAttention(
                     hidden_size=cfg.hidden_size,
-                    num_heads=cfg.num_heads,
+                    num_heads=own.num_heads,
                     num_kv_heads=own.num_kv_heads,
                     head_dim=cfg.head_dim,
                     v_head_dim=cfg.v_head_dim,
@@ -439,8 +455,9 @@ class Qwen3MoeDecoderLayer(nn.Module):
                     qk_norm=cfg.qk_norm,
                     qk_norm_zero_centered=zc,
                     use_output_gate=cfg.use_output_gate,
+                    gate_per_head=cfg.output_gate_per_head,
                     fused_qkv=cfg.fused_qkv,
-                    rope_fraction=cfg.rope_fraction,
+                    rope_fraction=own.rope_fraction,
                     window_size=own.window_size,
                     use_sinks=own.use_sinks,
                     decode_max_length=self.decode_max_length,
@@ -510,24 +527,26 @@ class Qwen3MoeDecoderLayer(nn.Module):
 
 
 def rope_cos_sin(
-    cfg: Qwen3MoeConfig, positions: Array, theta: Optional[float] = None
+    cfg: Qwen3MoeConfig, positions: Array, kind: str = "attention"
 ):
-    """``(cos, sin)`` at ``positions`` for the config's rotary geometry
-    (at base ``theta`` where an attention kind has its own), or ``(None,
-    None)`` where nothing is rotated."""
+    """``(cos, sin)`` at ``positions`` for the rotary geometry of the
+    config's attention kind ``kind`` (its base, the share of a head it
+    rotates, its scaling law), or ``(None, None)`` where nothing is
+    rotated."""
+    own = cfg.attention_kind(kind)
     # partial rotary (rope_fraction < 1): frequencies are computed over
     # the rotary dim, not head_dim (NeoX/Qwen3-Next semantics). MLA
     # (DeepSeek) rotates only its decoupled rope sub-vector.
     rotary_dim = (
         cfg.mla.qk_rope_head_dim if cfg.mla is not None
-        else int(cfg.head_dim * cfg.rope_fraction)
+        else int(cfg.head_dim * own.rope_fraction)
     )
     # rope_fraction 0 (no positional encoding: the attention layers
     # of a state-space hybrid) rotates nothing: no frequencies
     if not rotary_dim:
         return None, None
     inv_freq, att_scale = compute_rope_frequencies(
-        rotary_dim, theta or cfg.rope_theta, cfg.rope_scaling
+        rotary_dim, own.rope_theta, own.rope_scaling
     )
     return make_rope_cos_sin(positions, inv_freq, att_scale)
 
@@ -593,15 +612,18 @@ class Qwen3MoeBackbone(nn.Module):
             x = x.astype(stream)
         x = self._pin(x)
 
-        # one table a rotary base: a stack of one kind builds one
+        # one table a rotation (base, rotated share, scaling law): a
+        # stack of one kind builds one
         rope: dict = {}
         layer_cls = decoder_layer_class(cfg, self.decode_max_length)
 
         for gid in distribute_layers_for_pipeline_stage(cfg.num_layers, self.stage):
-            theta = cfg.attention_kind(cfg.layer_kind(gid)).rope_theta
-            if theta not in rope:
-                rope[theta] = rope_cos_sin(cfg, positions, theta)
-            cos, sin = rope[theta]
+            kind = cfg.layer_kind(gid)
+            own = cfg.attention_kind(kind)
+            rotation = (own.rope_theta, own.rope_fraction, own.rope_scaling)
+            if rotation not in rope:
+                rope[rotation] = rope_cos_sin(cfg, positions, kind)
+            cos, sin = rope[rotation]
             x = layer_cls(
                 config=cfg,
                 sdpa=self.sdpa,

@@ -471,6 +471,9 @@ class GroupedQueryAttention(nn.Module):
     rope_fraction: float = 1.0
     use_sinks: bool = False
     use_output_gate: bool = False
+    # the output gate is one logit a query head (``gate_proj`` of width
+    # ``h``), broadcast over the head's numbers, not one logit a number
+    gate_per_head: bool = False
     window_size: int | None = None
     softmax_scale: float | None = None
     # value heads of their own width (0 = ``head_dim``; never wider): the
@@ -589,18 +592,18 @@ class GroupedQueryAttention(nn.Module):
             )
         if rot:
             cos_r, sin_r = cos[..., : rot // 2], sin[..., : rot // 2]
-            if rot < d:
-                q = jnp.concatenate(
-                    [apply_rope(q[..., :rot], cos_r, sin_r, self.rope_style), q[..., rot:]],
+
+            def rotated(u):
+                if rot == d:
+                    return apply_rope(u, cos_r, sin_r, self.rope_style)
+                return jnp.concatenate(
+                    [apply_rope(u[..., :rot], cos_r, sin_r, self.rope_style),
+                     u[..., rot:]],
                     axis=-1,
                 )
-                k = jnp.concatenate(
-                    [apply_rope(k[..., :rot], cos_r, sin_r, self.rope_style), k[..., rot:]],
-                    axis=-1,
-                )
-            else:
-                q = apply_rope(q, cos_r, sin_r, self.rope_style)
-                k = apply_rope(k, cos_r, sin_r, self.rope_style)
+
+            with jax.named_scope("rope"):
+                q, k = rotated(q), rotated(k)
 
         sinks = None
         if self.use_sinks:
@@ -627,7 +630,11 @@ class GroupedQueryAttention(nn.Module):
         attn = checkpoint_name(attn, "sdpa_out")
 
         out = attn.reshape(b, t, h * dv)
-        if self.use_output_gate:
+        if self.use_output_gate and self.gate_per_head:
+            with jax.named_scope("head_gate"):
+                gate = proj(h, "gate_proj", (la.EMBED, la.HEADS))(x)
+                out = (attn * nn.sigmoid(gate)[..., None]).reshape(out.shape)
+        elif self.use_output_gate:
             gate = proj(h * dv, "gate_proj", (la.EMBED, la.HEADS))(x)
             out = out * nn.sigmoid(gate)
         return proj(self.hidden_size, "o_proj", (la.HEADS, la.EMBED))(out)

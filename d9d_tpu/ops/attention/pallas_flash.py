@@ -15,7 +15,8 @@ require (second-minor %8, minor %128-or-full).
 The kv-block grid dim is innermost, so per-(b, h, q-block) running max /
 denominator / output accumulators persist in scratch across kv steps (TPU
 grids execute sequentially). Causal and window block-skipping happens via
-``pl.when`` — skipped blocks cost a grid step but no MXU work.
+``pl.when`` — skipped blocks cost a grid step but no MXU work
+(:func:`grid_visits` counts both, and the wrapper records them).
 
 The sink joins only the softmax denominator, so it is folded in *outside*
 the kernel as an elementwise correction on (o, lse); the backward kernels
@@ -106,6 +107,52 @@ def _skip_block(cfg: _FlashConfig, iq, ik, qoff=None, koff=None):
     if cfg.window is not None:
         skip |= k_hi <= q_lo - cfg.window
     return skip
+
+
+def grid_visits(cfg: _FlashConfig, t: int, s: int) -> tuple[int, int]:
+    """``(visited, computing)`` for one (batch, head) of a kernel's grid
+    over ``t`` queries and ``s`` keys: the (q block, kv block) pairs the
+    grid visits, all of them, and those that :func:`_skip_block` lets
+    compute (its rule on plain integers, no offsets). The others cost a
+    grid step and the copy of their K/V blocks, and no MXU work."""
+    n_q = -(-t // cfg.block_q)
+    n_kv = -(-s // cfg.block_kv)
+    computing = 0
+    for iq in range(n_q):
+        q_lo, q_hi = iq * cfg.block_q, (iq + 1) * cfg.block_q - 1
+        for ik in range(n_kv):
+            k_lo, k_hi = ik * cfg.block_kv, (ik + 1) * cfg.block_kv - 1
+            skip = cfg.causal and k_lo > q_hi
+            if cfg.window is not None:
+                skip = skip or k_hi <= q_lo - cfg.window
+            computing += not skip
+    return n_q * n_kv, computing
+
+
+def _note_grid(cfg: _FlashConfig, q: Array, num_kv_heads: int) -> None:
+    """Record what one call's grids visit and compute, by kind (``window``
+    where the call has one, else ``full``) and pass, as gauges
+    ``flash/<kind>/<pass>/blocks_{visited,computed}`` of the process's
+    telemetry registry. It runs where the call is traced, on static
+    shapes: the gauges hold the last traced call of a kind, and the
+    Trainer puts them on a fetched step's span."""
+    from d9d_tpu.telemetry import get_telemetry
+
+    b, t, h, d = q.shape
+    kind = "full" if cfg.window is None else "window"
+    visited, computing = grid_visits(cfg, t, t)
+    one_pass_bwd = cfg.fused_bwd and _fused_bwd_fits(
+        h // num_kv_heads, t + _pad_len(t, cfg.block_q), d, q.dtype.itemsize
+    )
+    tele = get_telemetry()
+    # a pass's kernels run over the same pairs: the forward, and the
+    # backward's dq and dk/dv (one kernel when fused)
+    for name, kernels in (("fwd", 1), ("bwd", 1 if one_pass_bwd else 2)):
+        calls = b * h * kernels
+        tele.gauge(f"flash/{kind}/{name}/blocks_visited").set(calls * visited)
+        tele.gauge(f"flash/{kind}/{name}/blocks_computed").set(
+            calls * computing
+        )
 
 
 def _read_segs(cfg: _FlashConfig, qseg_ref, kseg_ref):
@@ -864,6 +911,7 @@ def make_pallas_flash_sdpa(
             interpret=jax.default_backend() != "tpu",
             fused_bwd=fused_bwd,
         )
+        _note_grid(cfg, q, k.shape[2])
         sinks_arr = (
             sinks if sinks is not None else jnp.zeros((q.shape[2],), jnp.float32)
         )

@@ -497,6 +497,15 @@ def _spread_held_bwd(top_k, residuals, g):
 spread_held.defvjp(_spread_held_fwd, _spread_held_bwd)
 
 
+# The compiler keeps every shifted float32 copy of :func:`fold_held`'s K - 1
+# adds alive at once (3.48 GB of temporaries for K = 8 over a 50,000-row
+# buffer of 2,048, compiled for a described v5e: 8.5 buffers; 0.82 GB as a
+# loop). Up to this many bytes of such copies they are written out, as every
+# program before PR 44 has them (K = 4 over 12,500 rows of 3,584: 0.54 GB);
+# past it the adds run in a loop
+_FOLD_UNROLLED_LIMIT = 1 << 30
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def fold_held(y: Array, held: HeldSort, num_tokens: int, top_k: int) -> Array:
     """Fold the held pairs' buffer rows back to their owning tokens.
@@ -508,17 +517,29 @@ def fold_held(y: Array, held: HeldSort, num_tokens: int, top_k: int) -> Array:
     scatter-add, and never ``N*K`` rows. Its transpose is
     :func:`spread_held`.
     """
-    m = y.shape[0]
+    m, d = y.shape
     live = jnp.arange(m) < held.rows_held
     by_slot = jnp.where(
         live[:, None], jnp.take(y, held.row_of_slot, axis=0), 0
     ).astype(jnp.float32)
     token = jnp.where(live, held.token_of_slot, -1)
-    total = by_slot
-    for j in range(1, top_k):
-        same = jnp.pad(token[j:], (0, j), constant_values=-2) == token
-        later = jnp.pad(by_slot[j:], ((0, j), (0, 0)))
-        total = total + jnp.where(same[:, None], later, 0)
+    if (top_k - 1) * m * d * 4 <= _FOLD_UNROLLED_LIMIT:
+        total = by_slot
+        for j in range(1, top_k):
+            same = jnp.pad(token[j:], (0, j), constant_values=-2) == token
+            later = jnp.pad(by_slot[j:], ((0, j), (0, 0)))
+            total = total + jnp.where(same[:, None], later, 0)
+    else:
+        # the same adds in the same order, one shifted copy alive at a time
+        padded = jnp.pad(by_slot, ((0, top_k - 1), (0, 0)))
+        tokens = jnp.pad(token, (0, top_k - 1), constant_values=-2)
+
+        def add_shifted(j, total):
+            same = lax.dynamic_slice_in_dim(tokens, j, m) == token
+            later = lax.dynamic_slice_in_dim(padded, j, m)
+            return total + jnp.where(same[:, None], later, 0)
+
+        total = lax.fori_loop(1, top_k, add_shifted, by_slot)
     out = jnp.take(total, jnp.minimum(held.slot_start, m - 1), axis=0)
     return jnp.where((held.slot_count > 0)[:, None], out, 0).astype(y.dtype)
 

@@ -11,6 +11,7 @@ from typing import Any
 __all__ = [
     "model_flops_per_token",
     "gdn_flops_per_token",
+    "keys_per_query",
     "active_param_count",
     "device_peak_flops",
 ]
@@ -39,10 +40,14 @@ def model_flops_per_token(
 
     When ``config`` exposes transformer geometry (``num_layers``,
     ``num_heads``, ``head_dim`` — the Qwen3/deepseek config shape) the
-    causal-attention term ``6 * L * H * D * T`` is added; hybrid stacks
-    restrict it to the quadratic layers via ``linear_attention_layers``.
-    Without a recognizable config the 6N term alone is reported (an
-    underestimate for long sequences — documented, not guessed at).
+    causal-attention term ``6 * H * D * T`` a layer is added; hybrid
+    stacks restrict it to the quadratic layers via
+    ``linear_attention_layers``, and a stack that mixes attention kinds
+    (``layer_kind`` / ``attention_kind``) counts each layer at its own
+    kind's query heads and, under a window, at the keys a query sees
+    (:func:`keys_per_query`). Without a recognizable config the 6N term
+    alone is reported (an underestimate for long sequences —
+    documented, not guessed at).
     """
     flops = 6.0 * active_param_count
     if config is not None:
@@ -51,10 +56,33 @@ def model_flops_per_token(
         head_dim = getattr(config, "head_dim", None)
         if layers and heads and head_dim:
             linear = getattr(config, "linear_attention_layers", None) or ()
-            n_attn = layers - len(linear)
-            flops += 6.0 * n_attn * heads * head_dim * seq_len
+            for layer in range(layers):
+                if layer in linear:
+                    continue
+                h, window = _layer_attention(config, layer, heads)
+                flops += (
+                    12.0 * h * head_dim * keys_per_query(seq_len, window)
+                )
             flops += gdn_flops_per_token(config)
     return flops
+
+
+def keys_per_query(seq_len: int, window: int | None = None) -> float:
+    """Keys a query attends on average over a causal sequence: half the
+    sequence, or under a window ``W`` shorter than it ``W - W (W - 1) /
+    2S`` (the first ``W`` queries see fewer)."""
+    if window is None or window >= seq_len:
+        return seq_len / 2
+    return window - window * (window - 1) / (2 * seq_len)
+
+
+def _layer_attention(config: Any, layer: int, heads: int):
+    """``(query heads, window)`` of one layer: its kind's where the
+    config names kinds, else the plain count and no window."""
+    if not hasattr(config, "attention_kind"):
+        return heads, None
+    kind = config.attention_kind(config.layer_kind(layer))
+    return kind.num_heads, kind.window_size
 
 
 def gdn_flops_per_token(config: Any, chunk: int = 64) -> float:
@@ -75,13 +103,19 @@ def gdn_flops_per_token(config: Any, chunk: int = 64) -> float:
 def active_param_count(trees, config: Any | None = None) -> float:
     """Parameters that compute per token, summed over ``trees`` (pytrees
     of arrays): MoE expert weights — any leaf whose path contains
-    ``grouped_experts`` — scaled by ``num_experts_per_tok / num_experts``
-    from ``config``, everything else counted once. The single accounting
+    ``grouped_experts`` — scaled by ``num_experts_per_tok`` over the
+    router's width (``num_routed_experts``, else ``num_experts``) from
+    ``config``, everything else counted once. The single accounting
     behind the trainer's live-MFU gauge."""
     import jax  # deferred: the telemetry package core stays jax-free
     import numpy as np
 
-    n_exp = getattr(config, "num_experts", None)
+    # a chip's share of a wider router holds ``num_experts`` of
+    # ``num_routed_experts``: a token's top-k fall on them in proportion
+    n_exp = (
+        getattr(config, "num_routed_experts", None)
+        or getattr(config, "num_experts", None)
+    )
     top_k = getattr(config, "num_experts_per_tok", None)
     expert_scale = (top_k / n_exp) if (n_exp and top_k) else 1.0
     total = 0.0

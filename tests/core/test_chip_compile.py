@@ -86,6 +86,30 @@ def _on(sharding):
 # -- attention kernels -------------------------------------------------------
 
 
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["window-512-64-heads", "full-48-heads"])
+def test_flash_fwd_bwd_at_the_window_layer_cells_kinds(
+        topo, as_tpu, heads, window):
+    """The two attention kinds of the window-layer training cell: 64
+    query heads under a window of 512 and 48 causal ones, on 8 key/value
+    heads of 128 at 4,096 positions (the windowed kernels had only run in
+    interpret mode before PR 44)."""
+    from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    flash = make_pallas_flash_sdpa()
+
+    def loss(q, k, v):
+        out = flash(q, k, v, causal=True, window_size=window)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds((1, T, heads, 128), BF16), sds((1, T, 8, 128), BF16),
+        sds((1, T, 8, 128), BF16),
+    ).compile()
+    assert _pallas_calls(compiled) == 3  # forward, dq, dk/dv
+
+
 @pytest.mark.parametrize(
     "fused_bwd,t",
     [(False, T), (True, T), (True, 1024)],

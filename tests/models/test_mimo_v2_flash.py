@@ -107,9 +107,13 @@ def test_presets_hold_the_published_sizes():
     assert int(full.head_dim * full.rope_fraction) == 64
     assert (full.rope_theta, full.attention_value_scale) == (5_000_000.0, 0.707)
     window = full.attention_kind("window")
-    assert window == AttentionKind(8, 10_000.0, 128, True)
+    # what the kinds state, and the plain fields where they state nothing
+    # (both kinds: 64 query heads, 0.334 of a head rotated, no scaling law)
+    shared = dict(num_heads=64, rope_fraction=0.334,
+                  rope_scaling=full.rope_scaling)
+    assert window == AttentionKind(8, 10_000.0, 128, True, **shared)
     assert full.attention_kind("attention") == AttentionKind(
-        4, 5_000_000.0, None, False)
+        4, 5_000_000.0, None, False, **shared)
     assert full.layer_kinds.count("attention") == 9
     assert [i for i, k in enumerate(full.layer_kinds) if k == "attention"] \
         == [0, 5, 11, 17, 23, 29, 35, 41, 47]

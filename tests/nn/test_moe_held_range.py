@@ -189,6 +189,29 @@ def test_spread_and_fold_are_each_others_transpose():
     np.testing.assert_allclose(fold_held(y, sort, n, k), want, atol=1e-5)
 
 
+def test_the_fold_past_its_unrolled_limit_adds_in_the_same_order(monkeypatch):
+    """Past ``_FOLD_UNROLLED_LIMIT`` bytes of shifted copies the K - 1
+    adds run in a loop, one copy alive at a time: the same adds in the
+    same order, so the same float32 sums to the bit, and no scatter."""
+    from d9d_tpu.ops import moe as moe_ops
+
+    n, k, held, buf = 40, 8, 4, 96
+    rng = np.random.RandomState(2)
+    local = jnp.asarray(rng.randint(0, held + 12, size=(n, k)).clip(max=held))
+    sort = sort_held_pairs(local, held, buf)
+    y = jnp.asarray(rng.normal(size=(buf, D)), jnp.float32)
+    unrolled = fold_held(y, sort, n, k)
+    assert "scan" not in str(jax.make_jaxpr(
+        lambda y: fold_held(y, sort, n, k))(y))
+    monkeypatch.setattr(moe_ops, "_FOLD_UNROLLED_LIMIT", 0)
+    looped = jax.make_jaxpr(lambda y: fold_held(y, sort, n, k))(y)
+    assert "scan" in str(looped) and "scatter" not in str(looped)
+    np.testing.assert_array_equal(fold_held(y, sort, n, k), unrolled)
+    rows = int(sort.rows_held)
+    want = jnp.zeros((n, D)).at[sort.token_of_row[:rows]].add(y[:rows])
+    np.testing.assert_allclose(unrolled, want, atol=1e-5)
+
+
 def wide_rows(jaxpr, rows: int, width: int, found=None):
     """Equations anywhere in ``jaxpr`` with an operand or result of
     ``rows`` rows and ``width`` or more columns, by primitive."""
