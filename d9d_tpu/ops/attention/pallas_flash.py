@@ -14,9 +14,17 @@ require (second-minor %8, minor %128-or-full).
 
 The kv-block grid dim is innermost, so per-(b, h, q-block) running max /
 denominator / output accumulators persist in scratch across kv steps (TPU
-grids execute sequentially). Causal and window block-skipping happens via
-``pl.when`` — skipped blocks cost a grid step but no MXU work
-(:func:`grid_visits` counts both, and the wrapper records them).
+grids execute sequentially). Block-skipping happens via ``pl.when``
+(:func:`_skip_block`): a skipped block costs a grid step and the copy of
+its K/V but no MXU work. Under a static window the grid is the window's
+*band* (:class:`_Band`): the sequential dimension is as long as the most
+blocks any outer block computes, the index maps start each row of it at
+the first block in the window's reach, and a visit past the last block is
+clamped to it and skipped, so only the bands' corners are visited in vain.
+A call with no window visits every pair, the ones above the diagonal
+included, and so does one with traced offsets (ring attention), whose
+reach is not static. :func:`grid_visits` counts what each kernel's grid
+visits and computes, and the wrapper records it.
 
 The sink joins only the softmax denominator, so it is folded in *outside*
 the kernel as an elementwise correction on (o, lse); the backward kernels
@@ -87,8 +95,9 @@ def _mask_block(s, cfg: _FlashConfig, iq, ik, q_seg, k_seg, qoff=None, koff=None
     return jnp.where(mask, s, NEG_BIG)
 
 
-def _skip_block(cfg: _FlashConfig, iq, ik, qoff=None, koff=None):
-    """True when the whole kv block is masked for the whole q block.
+def _skip_block(cfg: _FlashConfig, iq, ik, qoff=None, koff=None, clamped=None):
+    """True when the whole kv block is masked for the whole q block, or
+    the visit is a band's ``clamped`` one (:func:`_band_visit`).
 
     Static (python bool arithmetic) without offsets; with traced offsets it
     becomes a scalar predicate — ``pl.when`` accepts both, and on TPU the
@@ -106,27 +115,114 @@ def _skip_block(cfg: _FlashConfig, iq, ik, qoff=None, koff=None):
         skip |= k_lo > q_hi
     if cfg.window is not None:
         skip |= k_hi <= q_lo - cfg.window
+    if clamped is not None:
+        skip |= clamped
     return skip
 
 
-def grid_visits(cfg: _FlashConfig, t: int, s: int) -> tuple[int, int]:
-    """``(visited, computing)`` for one (batch, head) of a kernel's grid
-    over ``t`` queries and ``s`` keys: the (q block, kv block) pairs the
-    grid visits, all of them, and those that :func:`_skip_block` lets
-    compute (its rule on plain integers, no offsets). The others cost a
-    grid step and the copy of their K/V blocks, and no MXU work."""
+def _computes(cfg: _FlashConfig, iq: int, ik: int) -> bool:
+    """:func:`_skip_block`'s rule, negated, on plain integers (no offsets)."""
+    q_lo, q_hi = iq * cfg.block_q, (iq + 1) * cfg.block_q - 1
+    k_lo, k_hi = ik * cfg.block_kv, (ik + 1) * cfg.block_kv - 1
+    skip = cfg.causal and k_lo > q_hi
+    if cfg.window is not None:
+        skip = skip or k_hi <= q_lo - cfg.window
+    return not skip
+
+
+def _at_least_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Band:
+    """What a static window leaves of a kernel's sequential grid dimension:
+    outer block ``o`` visits the inner blocks ``first(o) + j`` for ``j`` in
+    ``range(reach)``, where ``first(o)`` is the first block that
+    :func:`_skip_block` lets compute beside ``o`` and ``reach`` the most
+    that compute beside any outer block (the grid stays rectangular). A
+    visit at or past ``n``, the number of inner blocks there are, is
+    *clamped*: its index map names block ``n - 1`` again, so nothing is
+    copied, and the kernel skips it on the unclamped index."""
+
+    step: int  # positions an outer block covers
+    back: int  # positions before an outer block's first that it reaches
+    block: int  # positions an inner block covers
+    n: int
+    reach: int
+
+    def first(self, outer):
+        """For a plain or a traced ``outer`` (index maps, kernels)."""
+        return _at_least_0(outer * self.step - self.back) // self.block
+
+
+def _band(cfg: _FlashConfig, n_q: int, n_kv: int, *, dkv: bool = False):
+    """The band of the forward and dq grids (outer q block, inner kv
+    blocks) or, with ``dkv``, of the dk/dv grid (outer kv block, inner q
+    blocks); ``None`` where the whole grid is visited: no window, or
+    traced offsets (ring attention), whose reach is not static."""
+    if cfg.window is None or cfg.has_positions:
+        return None
+    computes = [
+        [_computes(cfg, iq, ik) for ik in range(n_kv)] for iq in range(n_q)
+    ]
+    if dkv:
+        # below the diagonal a kv block's first q block holds its first
+        # key; without one every q block from the first may see it
+        back = 0 if cfg.causal else n_kv * cfg.block_kv
+        reach = max(sum(column) for column in zip(*computes))
+        return _Band(cfg.block_kv, back, cfg.block_q, n_q, reach)
+    reach = max(sum(row) for row in computes)
+    return _Band(cfg.block_q, cfg.window - 1, cfg.block_kv, n_kv, reach)
+
+
+def _band_visit(band: _Band | None, outer, j):
+    """``(inner block, clamped)`` of the ``j``-th sequential step beside
+    ``outer``: the step itself on a whole grid; in a band the unclamped
+    index, for :func:`_skip_block` and :func:`_mask_block`, and whether the
+    visit is a clamped one, which must add nothing."""
+    if band is None:
+        return j, None
+    inner = band.first(outer) + j
+    return inner, inner >= band.n
+
+
+def _inner_block(band: _Band | None):
+    """The index-map half of :func:`_band_visit`: ``(outer, j)`` to the
+    block the ``j``-th sequential step beside ``outer`` brings in."""
+    if band is None:
+        return lambda outer, j: j
+    return lambda outer, j: jnp.minimum(band.first(outer) + j, band.n - 1)
+
+
+def grid_visits(
+    cfg: _FlashConfig, t: int, s: int, kernel: str = "fwd"
+) -> tuple[int, int]:
+    """``(visited, computing)`` for one (batch, query head) of a kernel's
+    grid over ``t`` queries and ``s`` keys: the (q block, kv block) pairs
+    the grid visits and those that :func:`_skip_block` lets compute (its
+    rule on plain integers, no offsets). ``kernel`` is ``"fwd"`` (dq has
+    the same grid), ``"dkv"`` or ``"fused"`` (the one-pass backward, which
+    keeps the whole grid under a window too). A visit that does not
+    compute costs a grid step and no MXU work, and outside a band the
+    copy of its K/V blocks."""
     n_q = -(-t // cfg.block_q)
     n_kv = -(-s // cfg.block_kv)
-    computing = 0
-    for iq in range(n_q):
-        q_lo, q_hi = iq * cfg.block_q, (iq + 1) * cfg.block_q - 1
-        for ik in range(n_kv):
-            k_lo, k_hi = ik * cfg.block_kv, (ik + 1) * cfg.block_kv - 1
-            skip = cfg.causal and k_lo > q_hi
-            if cfg.window is not None:
-                skip = skip or k_hi <= q_lo - cfg.window
-            computing += not skip
-    return n_q * n_kv, computing
+    dkv = kernel == "dkv"
+    n_outer, n_inner = (n_kv, n_q) if dkv else (n_q, n_kv)
+    band = None if kernel == "fused" else _band(cfg, n_q, n_kv, dkv=dkv)
+    if band is None:
+        visits = [(o, i) for o in range(n_outer) for i in range(n_inner)]
+    else:
+        visits = [
+            (o, band.first(o) + j)
+            for o in range(n_outer) for j in range(band.reach)
+        ]
+    computing = sum(
+        i < n_inner and _computes(cfg, *((i, o) if dkv else (o, i)))
+        for o, i in visits
+    )
+    return len(visits), computing
 
 
 def _note_grid(cfg: _FlashConfig, q: Array, num_kv_heads: int) -> None:
@@ -140,18 +236,21 @@ def _note_grid(cfg: _FlashConfig, q: Array, num_kv_heads: int) -> None:
 
     b, t, h, d = q.shape
     kind = "full" if cfg.window is None else "window"
-    visited, computing = grid_visits(cfg, t, t)
     one_pass_bwd = cfg.fused_bwd and _fused_bwd_fits(
         h // num_kv_heads, t + _pad_len(t, cfg.block_q), d, q.dtype.itemsize
     )
     tele = get_telemetry()
-    # a pass's kernels run over the same pairs: the forward, and the
-    # backward's dq and dk/dv (one kernel when fused)
-    for name, kernels in (("fwd", 1), ("bwd", 1 if one_pass_bwd else 2)):
-        calls = b * h * kernels
-        tele.gauge(f"flash/{kind}/{name}/blocks_visited").set(calls * visited)
+    # each kernel's own grid: the forward's, and the backward's dq and
+    # dk/dv (one kernel over the whole grid when fused)
+    passes = {"fwd": ("fwd",),
+              "bwd": ("fused",) if one_pass_bwd else ("fwd", "dkv")}
+    for name, kernels in passes.items():
+        visited, computing = (
+            sum(n) for n in zip(*(grid_visits(cfg, t, t, k) for k in kernels))
+        )
+        tele.gauge(f"flash/{kind}/{name}/blocks_visited").set(b * h * visited)
         tele.gauge(f"flash/{kind}/{name}/blocks_computed").set(
-            calls * computing
+            b * h * computing
         )
 
 
@@ -173,7 +272,7 @@ def _read_offsets(cfg: _FlashConfig, refs):
     return offs_ref[0], offs_ref[1], tuple(rest)
 
 
-def _fwd_kernel(*refs, cfg: _FlashConfig):
+def _fwd_kernel(*refs, cfg: _FlashConfig, band: _Band | None):
     qoff, koff, refs = _read_offsets(cfg, refs)
     if cfg.has_segments:
         q_ref, k_ref, v_ref, qseg_ref, kseg_ref = refs[:5]
@@ -181,16 +280,17 @@ def _fwd_kernel(*refs, cfg: _FlashConfig):
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         qseg_ref = kseg_ref = None
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    n_kv = pl.num_programs(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ik, clamped = _band_visit(band, iq, step)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_BIG)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff)))
+    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff, clamped)))
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)
         k = k_ref[0, 0, :, :].astype(jnp.float32)
@@ -212,7 +312,7 @@ def _fwd_kernel(*refs, cfg: _FlashConfig):
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ik == n_kv - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         m = m_ref[:, :1]
         l = l_ref[:, :1]
@@ -222,7 +322,7 @@ def _fwd_kernel(*refs, cfg: _FlashConfig):
         lse_ref[0, 0, :, :] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
-def _bwd_dq_kernel(*refs, cfg: _FlashConfig):
+def _bwd_dq_kernel(*refs, cfg: _FlashConfig, band: _Band | None):
     qoff, koff, refs = _read_offsets(cfg, refs)
     if cfg.has_segments:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref, kseg_ref = refs[:8]
@@ -230,14 +330,15 @@ def _bwd_dq_kernel(*refs, cfg: _FlashConfig):
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
         qseg_ref = kseg_ref = None
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    n_kv = pl.num_programs(3)
+    iq, step = pl.program_id(2), pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    ik, clamped = _band_visit(band, iq, step)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff)))
+    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff, clamped)))
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)
         k = k_ref[0, 0, :, :].astype(jnp.float32)
@@ -258,12 +359,16 @@ def _bwd_dq_kernel(*refs, cfg: _FlashConfig):
         ds = p * (dp - delta) * cfg.scale
         dq_acc[:] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
-    @pl.when(ik == n_kv - 1)
+    @pl.when(step == n_steps - 1)
     def _finalize():
         dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, cfg: _FlashConfig, n_q_blocks: int):
+def _bwd_dkv_kernel(
+    *refs, cfg: _FlashConfig, n_q_blocks: int, band: _Band | None
+):
+    """``n_q_blocks`` is the q blocks a head visits beside one kv block:
+    all of them, or the band's reach."""
     qoff, koff, refs = _read_offsets(cfg, refs)
     if cfg.has_segments:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref, kseg_ref = refs[:8]
@@ -274,14 +379,14 @@ def _bwd_dkv_kernel(*refs, cfg: _FlashConfig, n_q_blocks: int):
         qseg_ref = kseg_ref = None
     ik, inner = pl.program_id(2), pl.program_id(3)
     n_inner = pl.num_programs(3)
-    iq = inner % n_q_blocks
+    iq, clamped = _band_visit(band, ik, inner % n_q_blocks)
 
     @pl.when(inner == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff)))
+    @pl.when(jnp.logical_not(_skip_block(cfg, iq, ik, qoff, koff, clamped)))
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)
         k = k_ref[0, 0, :, :].astype(jnp.float32)
@@ -479,8 +584,14 @@ def _fwd_call(cfg: _FlashConfig, q, k, v, offsets, q_seg, kv_seg):
     qp, kp, vp = _to_bhtd(q, pad_q), _to_bhtd(k, pad_k), _to_bhtd(v, pad_k)
     offs_specs, offs_bufs = _offs_args(cfg, offsets)
 
-    grid = (b, h, n_q, n_kv)
-    kernel = functools.partial(_fwd_kernel, cfg=cfg)
+    band = _band(cfg, n_q, n_kv)
+    kv_block = _inner_block(band)
+    kv_like = pl.BlockSpec(
+        (1, 1, cfg.block_kv, d),
+        lambda bi, hi, qi, ki, g=g: (bi, hi // g, kv_block(qi, ki), 0),
+    )
+    grid = (b, h, n_q, n_kv if band is None else band.reach)
+    kernel = functools.partial(_fwd_kernel, cfg=cfg, band=band)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -488,14 +599,12 @@ def _fwd_call(cfg: _FlashConfig, q, k, v, offsets, q_seg, kv_seg):
             *offs_specs,
             pl.BlockSpec((1, 1, cfg.block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, cfg.block_kv, d),
-                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((1, 1, cfg.block_kv, d),
-                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
+            kv_like,
+            kv_like,
             *_seg_specs(
                 cfg,
                 lambda bi, hi, qi, ki: (bi, qi, 0),
-                lambda bi, hi, qi, ki: (bi, 0, ki),
+                lambda bi, hi, qi, ki: (bi, 0, kv_block(qi, ki)),
             ),
         ],
         out_specs=[
@@ -567,25 +676,29 @@ def _bwd_call(cfg: _FlashConfig, q, k, v, do, lse, delta, offsets, q_seg, kv_seg
     lsep, deltap = col(lse, pad_q), col(delta, pad_q)
     segs = _seg_buffers(cfg, q_seg, kv_seg, pad_q, pad_k)
 
+    fused = cfg.fused_bwd and _fused_bwd_fits(g, tq, d, q.dtype.itemsize)
     # grid: (b, hkv, kv-block, g·q-block) — q heads and q blocks share the
-    # inner sequential dim so dk/dv accumulate across both
-    q_gather = pl.BlockSpec(
-        (1, 1, cfg.block_q, d),
-        lambda bi, hi, ki, t_, n=n_q, g=g: (bi, hi * g + t_ // n, t_ % n, 0),
-    )
-    col_gather = pl.BlockSpec(
-        (1, 1, cfg.block_q, 1),
-        lambda bi, hi, ki, t_, n=n_q, g=g: (bi, hi * g + t_ // n, t_ % n, 0),
-    )
+    # inner sequential dim so dk/dv accumulate across both; under a band a
+    # head's q blocks are the kv block's reach (the fused kernel's resident
+    # dq state is laid out over the whole grid, which it keeps)
+    q_band = None if fused else _band(cfg, n_q, n_kv, dkv=True)
+    n_qv = n_q if q_band is None else q_band.reach
+    q_block = _inner_block(q_band)
+
+    def q_gather_map(bi, hi, ki, t_):
+        return (bi, hi * g + t_ // n_qv, q_block(ki, t_ % n_qv), 0)
+
+    q_gather = pl.BlockSpec((1, 1, cfg.block_q, d), q_gather_map)
+    col_gather = pl.BlockSpec((1, 1, cfg.block_q, 1), q_gather_map)
     kv_self = pl.BlockSpec((1, 1, cfg.block_kv, d),
                            lambda bi, hi, ki, t_: (bi, hi, ki, 0))
     seg_specs_kv = _seg_specs(
         cfg,
-        lambda bi, hi, ki, t_, n=n_q: (bi, t_ % n, 0),
+        lambda bi, hi, ki, t_: (bi, q_block(ki, t_ % n_qv), 0),
         lambda bi, hi, ki, t_: (bi, 0, ki),
     )
 
-    if cfg.fused_bwd and _fused_bwd_fits(g, tq, d, q.dtype.itemsize):
+    if fused:
         # dq block (1, g, tq, d) at a fixed index per (b, hkv): stays
         # resident across the whole kv×q sweep while the scratch
         # accumulates, written once at the last step
@@ -620,23 +733,27 @@ def _bwd_call(cfg: _FlashConfig, q, k, v, do, lse, delta, offsets, q_seg, kv_seg
         return dq, dk, dv
 
 
+    kv_band = _band(cfg, n_q, n_kv)
+    kv_block = _inner_block(kv_band)
     q_like = pl.BlockSpec((1, 1, cfg.block_q, d),
                           lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_like = pl.BlockSpec((1, 1, cfg.block_kv, d),
-                           lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0))
+    kv_like = pl.BlockSpec(
+        (1, 1, cfg.block_kv, d),
+        lambda bi, hi, qi, ki, g=g: (bi, hi // g, kv_block(qi, ki), 0),
+    )
     col_like = pl.BlockSpec((1, 1, cfg.block_q, 1),
                             lambda bi, hi, qi, ki: (bi, hi, qi, 0))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, cfg=cfg),
-        grid=(b, h, n_q, n_kv),
+        functools.partial(_bwd_dq_kernel, cfg=cfg, band=kv_band),
+        grid=(b, h, n_q, n_kv if kv_band is None else kv_band.reach),
         in_specs=[
             *offs_specs,
             q_like, kv_like, kv_like, q_like, col_like, col_like,
             *_seg_specs(
                 cfg,
                 lambda bi, hi, qi, ki: (bi, qi, 0),
-                lambda bi, hi, qi, ki: (bi, 0, ki),
+                lambda bi, hi, qi, ki: (bi, 0, kv_block(qi, ki)),
             ),
         ],
         out_specs=q_like,
@@ -647,8 +764,10 @@ def _bwd_call(cfg: _FlashConfig, q, k, v, do, lse, delta, offsets, q_seg, kv_seg
     )(*offs_bufs, qp, kp, vp, dop, lsep, deltap, *segs)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, cfg=cfg, n_q_blocks=n_q),
-        grid=(b, hkv, n_kv, g * n_q),
+        functools.partial(
+            _bwd_dkv_kernel, cfg=cfg, n_q_blocks=n_qv, band=q_band
+        ),
+        grid=(b, hkv, n_kv, g * n_qv),
         in_specs=[
             *offs_specs,
             q_gather, kv_self, kv_self, q_gather, col_gather, col_gather,
@@ -737,6 +856,22 @@ _flash_ol.defvjp(_flash_ol_fwd, _flash_ol_bwd)
 
 def _clamp_block(block: int, n: int) -> int:
     return min(block, max(8, 2 ** math.ceil(math.log2(max(n, 1)))))
+
+
+def _window_blocks(window: int) -> tuple[int, int]:
+    """The largest ``(block_q, block_kv)`` a call under ``window`` takes.
+
+    A block pair computes whole, so blocks longer than the window fill the
+    band with masked-out pairs (2.93 times the window's own at 1,024 x 512
+    under 512, 2.0 at 512 x 512); but a block's cost hardly falls with the
+    pairs it holds under 512 x 512 (15 such blocks a head cost the same
+    under windows of 128, 256 and 512, and 31 of 256 x 256 cost more), so
+    the blocks follow the window down to 512 and no further: half the
+    window rounded up to a power of two, at least 512. Swept on the chip
+    at 4 x 4,096, 64 heads on 8 of 128, windows of 128 to 1,024 (PERF.md
+    section 6, PR 45)."""
+    block = max(512, 2 ** math.ceil(math.log2(window)) // 2)
+    return block, block
 
 
 def combine_attention_chunks(
@@ -858,10 +993,12 @@ def make_pallas_flash_sdpa(
     named, it is called directly.
 
     Default block sizes 1024x512 come from a toy-width sweep (t=2048/8192
-    d=64, t=4096 d=128); no cell has re-swept them, and
-    ``kernel.flash_train_roofline`` reads 26 to 34 % (ledger, PR 24;
-    ROADMAP S9). Blocks are clamped to the padded sequence length below,
-    so small inputs are unaffected.
+    d=64, t=4096 d=128); no cell has re-swept them for a call with no
+    window, and ``kernel.flash_train_roofline`` reads 26 to 34 % (ledger,
+    PR 24; ROADMAP S9). Under a window a call takes at most
+    :func:`_window_blocks` of it (swept on the chip at PR 45), and blocks
+    are clamped to the padded sequence length below, so small inputs are
+    unaffected.
 
     ``fused_bwd`` selects the one-pass backward (dq+dk+dv from a single
     logit recompute, ~20% fewer backward matmul FLOPs at the cost of a
@@ -899,14 +1036,18 @@ def make_pallas_flash_sdpa(
             )
         t = q.shape[1]
         d = q.shape[-1]
+        bq, bkv = block_q, block_kv
+        if window_size is not None:
+            cap_q, cap_kv = _window_blocks(window_size)
+            bq, bkv = min(bq, cap_q), min(bkv, cap_kv)
         cfg = _FlashConfig(
             causal=causal,
             scale=softmax_scale if softmax_scale is not None else d**-0.5,
             window=window_size,
             has_sinks=sinks is not None,
             has_segments=q_segments is not None,
-            block_q=_clamp_block(block_q, t),
-            block_kv=_clamp_block(block_kv, t),
+            block_q=_clamp_block(bq, t),
+            block_kv=_clamp_block(bkv, t),
             seq_len=t,
             interpret=jax.default_backend() != "tpu",
             fused_bwd=fused_bwd,

@@ -361,17 +361,32 @@ def test_the_flash_wrapper_counts_what_its_grid_visits_and_computes():
             seq_len=t, interpret=True,
         )
 
-    # the cell's shapes: 4 x 8 pairs, 20 under the diagonal, 11 in reach
-    # of a window of 512
+    # the cell's shapes at the configured blocks: a whole grid of 4 x 8
+    # pairs with 20 under the diagonal; under a window of 512 the 11 in
+    # reach inside bands of 4 x 3 (forward, dq) and 8 x 2 (dk/dv)
     assert pf.grid_visits(config(None), 4096, 4096) == (32, 20)
-    assert pf.grid_visits(config(512), 4096, 4096) == (32, 11)
+    assert pf.grid_visits(config(None), 4096, 4096, "dkv") == (32, 20)
+    assert pf.grid_visits(config(512), 4096, 4096) == (12, 11)
+    assert pf.grid_visits(config(512), 4096, 4096, "dkv") == (16, 11)
+    assert pf.grid_visits(config(512), 4096, 4096, "fused") == (32, 11)
+    # and at the blocks the wrapper takes from that window: 8 q blocks
+    # reach 2 kv blocks each, the first its own alone
+    bq, bkv = pf._window_blocks(512)
+    assert (bq, bkv) == (512, 512)
+    for kernel in ("fwd", "dkv"):
+        assert pf.grid_visits(config(512, bq, bkv), 4096, 4096, kernel) \
+            == (16, 15)
     for window in (None, 5, 16, 40):
         cfg = config(window, 16, 8, 64)
         computing = sum(
             not bool(pf._skip_block(cfg, iq, ik))
             for iq in range(4) for ik in range(8)
         )
-        assert pf.grid_visits(cfg, 64, 64) == (32, computing)
+        assert pf.grid_visits(cfg, 64, 64, "fused") == (32, computing)
+        for kernel in ("fwd", "dkv"):
+            visited, in_band = pf.grid_visits(cfg, 64, 64, kernel)
+            assert in_band == computing
+            assert visited == 32 if window is None else visited <= 32
 
     q = jnp.ones((2, 64, 4, 16), jnp.float32)
     kv = jnp.ones((2, 64, 2, 16), jnp.float32)
@@ -386,11 +401,13 @@ def test_the_flash_wrapper_counts_what_its_grid_visits_and_computes():
             "full/fwd/blocks_visited", "full/fwd/blocks_computed",
         )
     }
-    # 2 x 4 (batch, head) grids of 4 x 4 pairs; a window of 16 reaches
-    # its own block and the one before
+    # 2 x 4 (batch, head) grids: whole ones of 4 x 4 pairs with no window;
+    # a window of 16 reaches its own block and the one before, a band of
+    # 4 x 2 in each of the three kernels, the first block's cut by
+    # position 0 (the backward is dq and dk/dv)
     assert read == {
-        "window/fwd/blocks_visited": 128, "window/fwd/blocks_computed": 56,
-        "window/bwd/blocks_visited": 256, "window/bwd/blocks_computed": 112,
+        "window/fwd/blocks_visited": 64, "window/fwd/blocks_computed": 56,
+        "window/bwd/blocks_visited": 128, "window/bwd/blocks_computed": 112,
         "full/fwd/blocks_visited": 128, "full/fwd/blocks_computed": 80,
     }
 

@@ -304,3 +304,140 @@ class TestFusedBackward:
         g_fused = jax.grad(loss, (0, 1, 2))(q, k, v, True)
         for a, b in zip(g_fused, g_split):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _pallas_grids(fn, *args):
+    """The grids of the Pallas calls ``fn`` traces to, in program order."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+class TestWindowBand:
+    """Under a static window the sequential grid dimension is the window's
+    band (``pallas_flash._Band``): outputs and the three gradients against
+    the eager oracle wherever the band's edges fall, and the grids the
+    calls really have against ``grid_visits``."""
+
+    # t, block_q, block_kv, window, extras
+    CASES = {
+        "window_under_a_block": (64, 16, 16, 5, {}),
+        "window_is_a_block": (64, 16, 16, 16, {}),
+        "window_not_a_multiple": (64, 16, 16, 21, {}),
+        "window_over_the_sequence": (48, 16, 16, 100, {}),
+        "sequence_not_a_multiple": (50, 16, 16, 12, {}),
+        "longer_q_blocks": (64, 32, 8, 13, {}),
+        "longer_kv_blocks": (64, 8, 32, 13, {}),
+        "gqa": (64, 16, 8, 19, {"hq": 4}),
+        "segments": (64, 16, 16, 20, {"segments": True, "hq": 4}),
+        "sinks": (48, 16, 16, 17, {"sinks": True}),
+        "non_causal": (64, 16, 16, 20, {"causal": False}),
+        # 6 q blocks of 8 beside 3 kv blocks of 16 under a window of 30: a
+        # kv block computes 6 q blocks from its own first, so the last kv
+        # block's band runs four visits past the last q block, whose rows
+        # brought in again would pass the mask of the positions after them
+        "clamped_visits": (48, 8, 16, 30, {"clamped": True}),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_outputs_and_grads_match_eager(self, case):
+        from d9d_tpu.ops.attention import pallas_flash as pf
+
+        t, block_q, block_kv, window, extras = self.CASES[case]
+        hq, hkv = extras.get("hq", 2), 2
+        causal = extras.get("causal", True)
+        sdpa = make_pallas_flash_sdpa(block_q=block_q, block_kv=block_kv)
+        q = rng(2, t, hq, 16)
+        k, v = rng(2, t, hkv, 16, seed=1), rng(2, t, hkv, 16, seed=2)
+        kw = {"window_size": window, "causal": causal}
+        if extras.get("segments"):
+            kw["q_segments"] = kw["kv_segments"] = _packed_segments(2, t, 3)
+        sinks = jnp.array([0.3, -0.7]) if extras.get("sinks") else None
+
+        def out_and_grads(fn):
+            def loss(q, k, v, s):
+                o = fn(q, k, v, sinks=s, **kw)
+                return (o ** 2).sum(), o
+
+            argnums = (0, 1, 2, 3) if sinks is not None else (0, 1, 2)
+            (_, o), grads = jax.value_and_grad(
+                loss, argnums=argnums, has_aux=True)(q, k, v, sinks)
+            return o, grads
+
+        o_f, g_f = out_and_grads(sdpa)
+        o_e, g_e = out_and_grads(eager_sdpa)
+        np.testing.assert_allclose(o_f, o_e, rtol=2e-3, atol=2e-3)
+        for a, b in zip(g_f, g_e):
+            np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
+
+        # the band holds every pair the whole grid computes, and no more
+        # visits than the whole grid
+        cfg = pf._FlashConfig(
+            causal=causal, scale=1.0, window=window, has_sinks=False,
+            has_segments=False, block_q=block_q, block_kv=block_kv,
+            seq_len=t, interpret=True,
+        )
+        n_q, n_kv = -(-t // block_q), -(-t // block_kv)
+        whole = sum(
+            not bool(pf._skip_block(cfg, iq, ik))
+            for iq in range(n_q) for ik in range(n_kv)
+        )
+        for kernel in ("fwd", "dkv"):
+            visited, computing = pf.grid_visits(cfg, t, t, kernel)
+            assert computing == whole
+            assert computing <= visited <= n_q * n_kv
+        if extras.get("clamped"):
+            band = pf._band(cfg, n_q, n_kv, dkv=True)
+            assert band.first(n_kv - 1) + band.reach == band.n + 4
+
+    @pytest.mark.parametrize("kind", ["window", "no_window", "positions"])
+    def test_the_lowered_grids_are_what_grid_visits_counts(self, kind):
+        """A windowed call lowers to its bands; a call with no window, and
+        one with traced positions (ring attention) under a window, to the
+        whole grids they had."""
+        from d9d_tpu.ops.attention import pallas_flash as pf
+
+        b, t, hq, hkv, bq, bkv = 1, 64, 4, 2, 16, 8
+        window = None if kind == "no_window" else 12
+        q = rng(b, t, hq, 16)
+        k, v = rng(b, t, hkv, 16, seed=1), rng(b, t, hkv, 16, seed=2)
+        if kind == "positions":
+            def fn(q, k, v):
+                o, _ = pf.flash_attention_block(
+                    q, k, v, q_offset=jnp.int32(0), k_offset=jnp.int32(0),
+                    window_size=window, block_q=bq, block_kv=bkv,
+                    fused_bwd=False)
+                return (o ** 2).sum()
+        else:
+            sdpa = make_pallas_flash_sdpa(
+                block_q=bq, block_kv=bkv, fused_bwd=False)
+
+            def fn(q, k, v):
+                return (sdpa(q, k, v, window_size=window) ** 2).sum()
+
+        fwd, dq, dkv = _pallas_grids(jax.grad(fn, (0, 1, 2)), q, k, v)
+        cfg = pf._FlashConfig(
+            causal=True, scale=1.0, window=window, has_sinks=False,
+            has_segments=False, block_q=bq, block_kv=bkv, seq_len=t,
+            interpret=True, has_positions=kind == "positions",
+        )
+        n_q, n_kv, g = t // bq, t // bkv, hq // hkv
+        if kind == "window":
+            # 16 queries back 11 keys: at most 4 kv blocks of 8 a q block,
+            # and 2 q blocks a kv block
+            assert fwd == dq == (b, hq, n_q, 4)
+            assert dkv == (b, hkv, n_kv, g * 2)
+        else:
+            assert fwd == dq == (b, hq, n_q, n_kv)
+            assert dkv == (b, hkv, n_kv, g * n_q)
+        for grid, kernel in ((fwd, "fwd"), (dq, "fwd"), (dkv, "dkv")):
+            per_head = np.prod(grid) // (b * hq)
+            assert pf.grid_visits(cfg, t, t, kernel)[0] == per_head
