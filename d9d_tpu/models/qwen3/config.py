@@ -29,8 +29,10 @@ class Qwen3DenseConfig:
     # nn/attention.py fused_qkv — leave off for TP plans)
     fused_qkv: bool = False
     remat: bool = True
-    # "full" recomputes everything in backward (minimum memory, ~8N HFU);
-    # "dots_no_batch" saves matmul outputs with no batch dims (XLA's
+    # "full" recomputes a layer from its input in backward but for the
+    # flash call, whose output and log-sum-exp are kept under every policy
+    # (T x H x (2 D + 4) bytes a layer; dense._remat_policy has the rule);
+    # "dots_no_batch" also saves matmul outputs with no batch dims (XLA's
     # checkpoint_dots_with_no_batch_dims policy) — fewer recomputed FLOPs
     # for more activation memory. No cell sets it yet (ROADMAP S6).
     remat_policy: str = "full"

@@ -32,28 +32,44 @@ from d9d_tpu.telemetry import numerics
 
 
 def _remat_policy(name: str):
-    """Map a config string to a jax.checkpoint policy (None = save nothing).
+    """Map a config string to a jax.checkpoint policy.
 
-    ``save_expensive`` keeps every plain matmul output (dots-no-batch-dims)
-    PLUS the named expensive ops the stock dot policies can't see — the
-    Pallas flash output ("sdpa_out") and the MoE grouped-matmul outputs and
-    their permuted input rows ("moe_grouped_dot"/"moe_permuted_rows") —
-    so backward recomputes only cheap elementwise work. Costs activation
-    memory proportional to layer width; "full" remains the default for
+    One rule under every policy: a rematerialised layer is recomputed from
+    its input except the flash call, whose output and log-sum-exp
+    ("sdpa_out", "sdpa_lse": named on the kernel's own residuals inside
+    ``ops/attention/pallas_flash.py``'s forward rules) are kept. They cost
+    ``T x H x (2 D + 4)`` bytes a layer to keep and a whole forward kernel
+    to make again; q, k and v are still recomputed (the projections and the
+    rotation run again, as they must for their own gradients). Where the
+    compiler already merges the recomputed forward with the first
+    (``prevent_cse=False`` on an unrolled stack) no kernel stops running
+    and the kept log-sum-exp costs its trip through the dense ``[B, H, T]``
+    form (0.4 ms a layer of 32 heads at 4 x 4,096; PERF.md section 6,
+    PR 46).
+
+    ``full`` keeps nothing else. ``dots_no_batch`` also keeps every plain
+    matmul output. ``save_expensive`` keeps those and the MoE
+    grouped-matmul outputs and their permuted input rows
+    ("moe_grouped_dot" / "moe_permuted_rows"), at activation memory
+    proportional to the layer's width; "full" remains the default for
     memory-bound configs.
     """
+    also_named = {
+        "full": (),
+        "dots_no_batch": (),
+        "save_expensive": ("moe_grouped_dot", "moe_permuted_rows"),
+    }
+    if name not in also_named:
+        raise ValueError(f"unknown remat_policy {name!r}")
+    policies = jax.checkpoint_policies
+    kept = policies.save_only_these_names(
+        "sdpa_out", "sdpa_lse", *also_named[name]
+    )
     if name == "full":
-        return None
-    if name == "dots_no_batch":
-        return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-    if name == "save_expensive":
-        return jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            jax.checkpoint_policies.save_only_these_names(
-                "sdpa_out", "moe_grouped_dot", "moe_permuted_rows"
-            ),
-        )
-    raise ValueError(f"unknown remat_policy {name!r}")
+        return kept
+    return policies.save_from_both_policies(
+        policies.checkpoint_dots_with_no_batch_dims, kept
+    )
 
 
 class Qwen3DenseBackbone(nn.Module):

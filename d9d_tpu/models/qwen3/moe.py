@@ -120,7 +120,10 @@ class Qwen3MoeConfig:
     # the layers are unrolled and the compiler may keep what the remat
     # meant to drop: five Xing4.0 layers and the MTP block claimed 12.9 GB
     # of temporaries off and 8.1 GB on (described-v5e compile, PR 35). The
-    # shallower presets leave it off and keep the programs they had
+    # shallower presets leave it off and keep the programs they had. On,
+    # every layer's backward truly runs its forward again, all of it but
+    # the flash kernel (remat_policy keeps that call's output and
+    # log-sum-exp)
     remat_prevent_cse: bool = False
     # Qwen3-Next attention features: sigmoid output gate on attention
     # layers, partial rotary (frequencies computed over the rotary dim),

@@ -11,7 +11,6 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn import logical_axes as la
@@ -625,9 +624,11 @@ class GroupedQueryAttention(nn.Module):
                 sinks=sinks,
                 mask=mask,
             )
-        # named so the "save_expensive" remat policy can keep the flash
-        # kernel's output instead of re-running it in the backward pass
-        attn = checkpoint_name(attn, "sdpa_out")
+        # no checkpoint name here: what a rematerialised layer keeps of
+        # this call, the flash kernels name themselves on their own
+        # residuals (pallas_flash.py _name_kept). A second name on the
+        # result out here kept a second copy of it, a pass over it a
+        # layer, and never kept the kernel from running again
 
         out = attn.reshape(b, t, h * dv)
         if self.use_output_gate and self.gate_per_head:
@@ -1051,7 +1052,6 @@ class MultiHeadLatentAttention(nn.Module):
                         q, cached_c, cached_r, kv_up_w, dec_mask,
                         d_qk, d_nope,
                     )
-                out = checkpoint_name(out, "sdpa_out")
                 return proj(self.hidden_size, "o_proj",
                             (la.HEADS, la.EMBED))(out.reshape(b, t, h * d_v))
             # prefill (t > 1): decompress only the NEW tokens and attend
@@ -1080,7 +1080,6 @@ class MultiHeadLatentAttention(nn.Module):
             out = self.sdpa(
                 q, k, v, causal=True, softmax_scale=scale, mask=mask
             )
-        out = checkpoint_name(out, "sdpa_out")
         if pad > 0:
             out = out[..., :d_v]
         out = out.reshape(b, t, h * d_v)

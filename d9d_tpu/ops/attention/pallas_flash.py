@@ -41,6 +41,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -633,6 +634,20 @@ def _fwd_call(cfg: _FlashConfig, q, k, v, offsets, q_seg, kv_seg):
     return o, lse
 
 
+def _name_kept(o, lse):
+    """Name the two residuals only the forward kernel can make.
+
+    Called inside the forward rules, so the variables the backward kernels
+    read carry the names themselves: a ``jax.checkpoint`` policy that saves
+    ``"sdpa_out"`` and ``"sdpa_lse"`` (every policy of
+    ``models/qwen3/dense.py _remat_policy``) keeps them, and the
+    rematerialised layer does not run the forward kernel again. A name on a
+    copy of the output outside the ``custom_vjp`` saves the copy and the
+    kernel runs all the same. Outside a ``jax.checkpoint`` a name is the
+    identity."""
+    return checkpoint_name(o, "sdpa_out"), checkpoint_name(lse, "sdpa_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash(cfg: _FlashConfig, q, k, v, sinks, q_seg, kv_seg):
     o, _ = _flash_fwd(cfg, q, k, v, sinks, q_seg, kv_seg)
@@ -649,6 +664,7 @@ def _flash_fwd(cfg: _FlashConfig, q, k, v, sinks, q_seg, kv_seg):
         inv = (1.0 / (1.0 + corr)).transpose(0, 2, 1)[..., None]  # [B,T,H,1]
         o = (o.astype(jnp.float32) * inv).astype(o.dtype)
         lse = lse + jnp.log1p(corr)
+    o, lse = _name_kept(o, lse)
     return o, (q, k, v, sinks, q_seg, kv_seg, o, lse)
 
 
@@ -836,7 +852,7 @@ def _flash_ol(cfg: _FlashConfig, q, k, v, offsets, q_seg, kv_seg):
 
 
 def _flash_ol_fwd(cfg: _FlashConfig, q, k, v, offsets, q_seg, kv_seg):
-    o, lse = _fwd_call(cfg, q, k, v, offsets, q_seg, kv_seg)
+    o, lse = _name_kept(*_fwd_call(cfg, q, k, v, offsets, q_seg, kv_seg))
     return (o, lse), (q, k, v, offsets, q_seg, kv_seg, o, lse)
 
 

@@ -20,8 +20,8 @@ pytestmark = [pytest.mark.e2e, requires_modern_jax]
 from d9d_tpu.core import MeshParameters
 from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.nn.moe import MoELayer
-from d9d_tpu.nn.sdpa import build_sdpa_backend
 from d9d_tpu.ops.attention.eager import eager_sdpa
+from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
 
 B, T = 4, 16
 
@@ -367,7 +367,10 @@ def test_hybrid_padding_mask_blocks_contamination(ctx):
 class TestRematPolicies:
     """All remat policies must produce identical gradients — they differ
     only in what gets recomputed vs saved (models/qwen3/dense.py
-    _remat_policy; "save_expensive" keeps named flash/grouped-dot outputs)."""
+    _remat_policy; "save_expensive" keeps named grouped-dot outputs) — and
+    every one keeps the flash call's output and log-sum-exp: through the
+    Pallas backend the gradient's jaxpr holds three kernel calls a layer
+    (forward, dq, dk/dv), never a second forward."""
 
     def test_grad_parity_across_policies(self):
         toks = jnp.ones((2, 16), jnp.int32)
@@ -381,7 +384,9 @@ class TestRematPolicies:
                 num_experts_per_tok=2, remat=True, remat_policy=policy,
             )
             m = Qwen3MoeCausalLM(
-                config=cfg, sdpa=build_sdpa_backend(), dtype=jnp.float32
+                config=cfg,
+                sdpa=make_pallas_flash_sdpa(block_q=16, block_kv=16),
+                dtype=jnp.float32,
             )
             variables = m.init(jax.random.PRNGKey(0), toks, pos, toks)
             params = variables["params"]
@@ -397,7 +402,9 @@ class TestRematPolicies:
                     for leaf in jax.tree.leaves(out)
                 )
 
-            grads[policy] = jax.jit(jax.grad(loss))(params)
+            traced = jax.jit(jax.grad(loss)).trace(params)
+            assert str(traced.jaxpr).count("pallas_call") == 3 * 2, policy
+            grads[policy] = traced.lower().compile()(params)
 
         ref = jax.tree.leaves(grads["full"])
         for policy in ("dots_no_batch", "save_expensive"):

@@ -441,3 +441,73 @@ class TestWindowBand:
         for grid, kernel in ((fwd, "fwd"), (dq, "fwd"), (dkv, "dkv")):
             per_head = np.prod(grid) // (b * hq)
             assert pf.grid_visits(cfg, t, t, kernel)[0] == per_head
+
+
+class TestRematerialisedLayerKeepsTheCall:
+    """A ``jax.checkpoint`` that may not be merged with the forward it
+    repeats (``prevent_cse=True``) runs the forward kernel a second time
+    unless its policy keeps the kernel's own residuals: ``_flash_fwd`` and
+    ``_flash_ol_fwd`` name them ("sdpa_out", "sdpa_lse") and every policy
+    of ``_remat_policy`` saves the names. Forward, dq and dk/dv: three
+    calls, where the parent commit traced four; the gradients are the bits
+    of the layer without ``jax.checkpoint``."""
+
+    CASES = {
+        "causal": {},
+        "causal_window": {"window_size": 20},
+        "sinks": {"sinks": True},
+        "segments": {"segments": True},
+        "flash_attention_block": {"block": True},
+    }
+
+    @staticmethod
+    def layer(window_size=None, sinks=False, segments=False, block=False):
+        from d9d_tpu.ops.attention.pallas_flash import flash_attention_block
+
+        seg = _packed_segments(1, 32, 2) if segments else None
+        sink_logits = jnp.array([0.5, -1.0]) if sinks else None
+
+        def f(q, k, v):
+            # the scalings stand for what a layer computes before and
+            # after the call and has to recompute around the kept pair
+            q, k, v = q * 1.25, k * 0.75, v * 1.5
+            if block:
+                o, lse = flash_attention_block(
+                    q, k, v, q_offset=0, k_offset=0, block_q=16, block_kv=16)
+                return (o.astype(jnp.float32) ** 2).sum() + lse.sum()
+            o = flash(q, k, v, window_size=window_size, sinks=sink_logits,
+                      q_segments=seg, kv_segments=seg)
+            return (o.astype(jnp.float32) ** 2).sum()
+
+        return f
+
+    QKV = (rng(1, 32, 2, 16), rng(1, 32, 1, 16, seed=1),
+           rng(1, 32, 1, 16, seed=2))
+    _plain = {}
+
+    def plain_gradients(self, case):
+        """dq, dk, dv of the case's layer with no ``jax.checkpoint``, in a
+        program of their own: once a case, for its three policies; with
+        the count the parent commit had under every policy."""
+        if case not in self._plain:
+            f = self.layer(**self.CASES[case])
+            # with nothing kept the forward kernel is traced twice
+            bare = jax.grad(jax.checkpoint(f, prevent_cse=True), (0, 1, 2))
+            assert len(_pallas_grids(bare, *self.QKV)) == 4
+            self._plain[case] = jax.jit(jax.grad(f, (0, 1, 2)))(*self.QKV)
+        return self._plain[case]
+
+    @pytest.mark.parametrize("policy",
+                             ["full", "dots_no_batch", "save_expensive"])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_three_calls_and_the_same_gradients(self, case, policy):
+        from d9d_tpu.models.qwen3.dense import _remat_policy
+
+        f = self.layer(**self.CASES[case])
+        kept = jax.grad(
+            jax.checkpoint(f, prevent_cse=True, policy=_remat_policy(policy)),
+            (0, 1, 2))
+        assert len(_pallas_grids(kept, *self.QKV)) == 3
+        for got, want in zip(jax.jit(kept)(*self.QKV),
+                             self.plain_gradients(case)):
+            np.testing.assert_array_equal(got, want)
