@@ -59,6 +59,25 @@ def _fixed_seed():
     yield
 
 
+def pytest_terminal_summary(terminalreporter):
+    """The ten slowest test files of the run with their summed seconds
+    (set-up, call and teardown, over the workers), from the reports pytest
+    already holds: every builder's log shows what a PR added to the tier-1
+    clock without a second run."""
+    seconds = {}
+    for reports in terminalreporter.stats.values():
+        for report in reports:
+            if hasattr(report, "duration") and hasattr(report, "nodeid"):
+                path = report.nodeid.split("::")[0]
+                seconds[path] = seconds.get(path, 0.0) + report.duration
+    if not seconds:
+        return
+    terminalreporter.section("slowest test files")
+    for path in sorted(seconds, key=seconds.get, reverse=True)[:10]:
+        terminalreporter.write_line(f"{seconds[path]:8.1f}s {path}")
+    terminalreporter.write_line(f"{sum(seconds.values()):8.1f}s all files")
+
+
 def load_repo_module(name, relpath):
     """Load a repo script (chip_smoke.py, tools/*.py) by path — shared by
     the harness tests so the spec/exec boilerplate lives once."""

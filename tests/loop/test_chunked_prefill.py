@@ -50,7 +50,7 @@ def _init_params(model):
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     full = model.clone(decode_max_length=0)
-    return full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
+    return jax.jit(full.init)(jax.random.PRNGKey(0), z, pos, z)["params"]
 
 
 def _prompt(b, p, seed=0):
@@ -153,7 +153,7 @@ def test_hybrid_gdn_chunked_matches_unchunked():
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     full = dec.clone(decode_max_length=0)
-    params = full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
+    params = jax.jit(full.init)(jax.random.PRNGKey(0), z, pos, z)["params"]
     prompt = _prompt(2, 7, seed=4)
     want = np.asarray(generate(dec, params, prompt, max_new_tokens=6))
     got = np.asarray(generate(
@@ -185,14 +185,14 @@ def test_mla_chunked_matches_unchunked():
     dec = full.clone(decode_max_length=16)
     x = jax.random.normal(jax.random.PRNGKey(7), (b, p, 32))
     cos, sin = rope(0, p)
-    variables = full.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(full.init)(jax.random.PRNGKey(1), x, cos, sin)
     params = variables["params"]
     want = full.apply({"params": params}, x, cos, sin)
 
     cache = jax.tree.map(
         jnp.zeros_like,
-        dec.init(jax.random.PRNGKey(1), x[:, :1], cos[:, :1],
-                 sin[:, :1])["cache"],
+        jax.jit(dec.init)(
+            jax.random.PRNGKey(1), x[:, :1], cos[:, :1], sin[:, :1])["cache"],
     )
     outs = []
     chunk = 3

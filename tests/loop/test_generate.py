@@ -18,6 +18,8 @@ requires_modern_jax = pytest.mark.skipif(
 # tests/ops/test_decode_attention.py
 pytestmark = pytest.mark.e2e
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -51,14 +53,15 @@ def _models(decode_max_length):
     b, t = 2, 8
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    params = full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
+    params = jax.jit(full.init)(jax.random.PRNGKey(0), z, pos, z)["params"]
     return full, dec, params
 
 
 def _full_logits(full, params, ids):
     b, t = ids.shape
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    return full.apply({"params": params}, ids, pos, method=full.logits)
+    return jax.jit(lambda p, ids: full.apply(
+        {"params": p}, ids, pos, method=full.logits))(params, ids)
 
 
 class TestDecodeParity:
@@ -72,20 +75,19 @@ class TestDecodeParity:
 
         p = 8
         pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (2, p))
-        got, state = dec.apply(
-            {"params": params}, ids[:, :p], pos,
-            method=dec.logits, mutable=["cache"],
-        )
+        # prefill and the single-token step: one compiled program each
+        decode = jax.jit(functools.partial(
+            dec.apply, method=dec.logits, mutable=["cache"]))
+        got, state = decode({"params": params}, ids[:, :p], pos)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want[:, :p]), rtol=2e-5, atol=2e-5
         )
         cache = state["cache"]
         for i in range(p, 12):
             step_pos = jnp.full((2, 1), i, jnp.int32)
-            logits_i, state = dec.apply(
+            logits_i, state = decode(
                 {"params": params, "cache": cache},
                 ids[:, i : i + 1], step_pos,
-                method=dec.logits, mutable=["cache"],
             )
             cache = state["cache"]
             np.testing.assert_allclose(
@@ -152,7 +154,7 @@ class TestDecodeParity:
         b, t = 2, 8
         z = jnp.zeros((b, t), jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        params = full.init(jax.random.PRNGKey(2), z, pos, z)["params"]
+        params = jax.jit(full.init)(jax.random.PRNGKey(2), z, pos, z)["params"]
 
         rng = np.random.default_rng(3)
         ids = jnp.asarray(rng.integers(0, VOCAB, (b, 12)), jnp.int32)
@@ -196,7 +198,7 @@ class TestDecodeParity:
         b, t = 2, 8
         z = jnp.zeros((b, t), jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        params = full.init(jax.random.PRNGKey(4), z, pos, z)["params"]
+        params = jax.jit(full.init)(jax.random.PRNGKey(4), z, pos, z)["params"]
         prompt = jnp.ones((2, 5), jnp.int32)
         out = generate(dec, params, prompt, max_new_tokens=6)
         assert out.shape == (2, 6)
@@ -262,7 +264,7 @@ class TestDecodeParity:
         b, t = 2, 8
         z = jnp.zeros((b, t), jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        params = dec.init(jax.random.PRNGKey(9), z, pos, z)["params"]
+        params = jax.jit(dec.init)(jax.random.PRNGKey(9), z, pos, z)["params"]
 
         rng = np.random.default_rng(10)
         short = jnp.asarray(rng.integers(0, VOCAB, (1, 4)), jnp.int32)
@@ -295,7 +297,7 @@ class TestDecodeParity:
         b, t = 2, 8
         z = jnp.zeros((b, t), jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        params = dec.init(jax.random.PRNGKey(6), z, pos, z)["params"]
+        params = jax.jit(dec.init)(jax.random.PRNGKey(6), z, pos, z)["params"]
 
         rng = np.random.default_rng(8)
         short = jnp.asarray(rng.integers(0, VOCAB, (1, 3)), jnp.int32)
@@ -400,7 +402,7 @@ class TestDecodeParity:
         z = jnp.zeros((b, t), jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
         full = LlamaCausalLM(config=cfg, sdpa=eager_sdpa, dtype=jnp.float32)
-        params = full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
+        params = jax.jit(full.init)(jax.random.PRNGKey(0), z, pos, z)["params"]
         prompt = jnp.ones((2, 4), jnp.int32)
         out = generate(dec, params, prompt, max_new_tokens=8)
         assert out.shape == (2, 8)

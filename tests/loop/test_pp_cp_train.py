@@ -17,23 +17,20 @@ requires_modern_jax = pytest.mark.skipif(
 # slow tier: full training/IO flows
 pytestmark = [pytest.mark.e2e, requires_modern_jax]
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from d9d_tpu.core import MeshParameters
 from d9d_tpu.loop import (
     AdamWProvider,
     CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
     Trainer,
     TrainerConfig,
 )
 from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM, Qwen3DenseConfig
 from d9d_tpu.nn.sdpa import SdpaRingConfig, build_sdpa_backend
 from d9d_tpu.parallel import fsdp_plan
+from tests.loop.conftest import LMProvider, SeededBatches
 
 VOCAB = 64
 
@@ -56,31 +53,17 @@ def test_dense_ring_attention_trains_under_pp(devices):
         remat=False,
     )
 
-    class Provider(ModelProvider):
-        def build_module(self, stage):
-            return Qwen3DenseCausalLM(
-                config=cfg,
-                sdpa=ring,
-                stage=stage,
-                act_sharding=NamedSharding(
-                    ctx.stage_mesh(stage.stage_index),
-                    P(ctx.batch_axes, ctx.sequence_axes),
-                ),
-                dtype=jnp.float32,
-            )
-
-        def build_plan(self, c):
-            return fsdp_plan(c)
-
-        def sample_inputs(self, b, t):
-            z = jnp.zeros((b, t), jnp.int32)
-            return (z, z, z)
-
-    class Data(DatasetProvider):
-        def build(self):
-            base = np.random.RandomState(0).randint(0, VOCAB, size=(8, 33))
-            while True:
-                yield {"input_ids": base}
+    def build_module(stage):
+        return Qwen3DenseCausalLM(
+            config=cfg,
+            sdpa=ring,
+            stage=stage,
+            act_sharding=NamedSharding(
+                ctx.stage_mesh(stage.stage_index),
+                P(ctx.batch_axes, ctx.sequence_axes),
+            ),
+            dtype=jnp.float32,
+        )
 
     trainer = Trainer(
         ctx=ctx,
@@ -93,8 +76,8 @@ def test_dense_ring_attention_trains_under_pp(devices):
             learning_rate=3e-3,
             pipeline={"kind": "interleaved_1f1b"},
         ),
-        model_provider=Provider(),
-        dataset_provider=Data(),
+        model_provider=LMProvider(build_module, fsdp_plan),
+        dataset_provider=SeededBatches((8, 33), VOCAB, fresh=False),
         task=CausalLMTask(),
         optimizer_provider=AdamWProvider(),
     )

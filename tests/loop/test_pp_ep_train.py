@@ -5,7 +5,6 @@ pp=2 x dp_s=2 leg with ep=2 overlaying dp_s, and the full 8-device
 pp=2 x dp_s=2 x tp=2 leg with ep=4 overlaying dp_s x tp. The multichip
 dryrun covers EP and PP separately; these are the composed paths."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,14 +25,13 @@ from d9d_tpu.core import MeshParameters
 from d9d_tpu.loop import (
     AdamWProvider,
     CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
     Trainer,
     TrainerConfig,
 )
 from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.nn.sdpa import build_sdpa_backend
 from d9d_tpu.parallel import fsdp_ep_plan
+from tests.loop.conftest import LMProvider, SeededBatches
 
 VOCAB = 128
 
@@ -54,31 +52,17 @@ def _train_pp_ep(ctx, *, with_tp: bool, seed: int) -> list[dict]:
         moe_token_axes=(ctx.batch_axes, ctx.sequence_axes),
     )
 
-    class Provider(ModelProvider):
-        def build_module(self, stage):
-            return Qwen3MoeCausalLM(
-                config=cfg,
-                sdpa=build_sdpa_backend(),
-                stage=stage,
-                act_sharding=NamedSharding(
-                    ctx.stage_mesh(stage.stage_index),
-                    P(ctx.batch_axes, ctx.sequence_axes),
-                ),
-                dtype=jnp.float32,
-            )
-
-        def build_plan(self, c):
-            return fsdp_ep_plan(c, with_tp=with_tp)
-
-        def sample_inputs(self, b, t):
-            z = jnp.zeros((b, t), jnp.int32)
-            return (z, z, z)
-
-    class Data(DatasetProvider):
-        def build(self):
-            base = np.random.RandomState(seed).randint(0, VOCAB, size=(8, 33))
-            while True:
-                yield {"input_ids": base}
+    def build_module(stage):
+        return Qwen3MoeCausalLM(
+            config=cfg,
+            sdpa=build_sdpa_backend(),
+            stage=stage,
+            act_sharding=NamedSharding(
+                ctx.stage_mesh(stage.stage_index),
+                P(ctx.batch_axes, ctx.sequence_axes),
+            ),
+            dtype=jnp.float32,
+        )
 
     trainer = Trainer(
         ctx=ctx,
@@ -91,8 +75,9 @@ def _train_pp_ep(ctx, *, with_tp: bool, seed: int) -> list[dict]:
             learning_rate=3e-3,
             pipeline={"kind": "interleaved_1f1b"},
         ),
-        model_provider=Provider(),
-        dataset_provider=Data(),
+        model_provider=LMProvider(
+            build_module, lambda c: fsdp_ep_plan(c, with_tp=with_tp)),
+        dataset_provider=SeededBatches((8, 33), VOCAB, seed, fresh=False),
         task=CausalLMTask(),
         optimizer_provider=AdamWProvider(),
     )

@@ -26,14 +26,13 @@ from d9d_tpu.core import MeshParameters
 from d9d_tpu.loop import (
     AdamWProvider,
     CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
     Trainer,
     TrainerConfig,
 )
 from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM, Qwen3DenseConfig
 from d9d_tpu.nn.sdpa import build_sdpa_backend
-from d9d_tpu.parallel import replicate_plan
+from tests.loop.conftest import LMProvider, SeededBatches
+from tests.loop.conftest import sync_stage_params as _sync_stage_params
 
 VOCAB = 64
 CFG = Qwen3DenseConfig(
@@ -49,26 +48,10 @@ CFG = Qwen3DenseConfig(
 STEPS = 2
 
 
-class Provider(ModelProvider):
-    def build_module(self, stage):
-        return Qwen3DenseCausalLM(
-            config=CFG, sdpa=build_sdpa_backend(), stage=stage,
-            dtype=jnp.float32,
-        )
-
-    def build_plan(self, ctx):
-        return replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
-
-
-class Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(7)
-        for _ in range(STEPS):
-            yield {"input_ids": rng.randint(0, VOCAB, size=(16, 17))}
+def _dense(stage):
+    return Qwen3DenseCausalLM(
+        config=CFG, sdpa=build_sdpa_backend(), stage=stage, dtype=jnp.float32,
+    )
 
 
 def _make(ctx, pipeline=None):
@@ -84,24 +67,10 @@ def _make(ctx, pipeline=None):
             learning_rate=1e-2,
             numerics_every_steps=1,
         ),
-        model_provider=Provider(),
-        dataset_provider=Data(),
+        model_provider=LMProvider(_dense),
+        dataset_provider=SeededBatches((16, 17), VOCAB, seed=7, steps=STEPS),
         task=CausalLMTask(),
         optimizer_provider=AdamWProvider(),
-    )
-
-
-def _sync_stage_params(engine, full_params):
-    def pull(path, leaf):
-        src = full_params
-        for k in path:
-            src = src[k.key]
-        return jax.device_put(np.asarray(src), leaf.sharding)
-
-    for rt in engine.stages.values():
-        rt.params = jax.tree_util.tree_map_with_path(pull, rt.params)
-    engine.opt_states = engine.optimizer.init(
-        {s: rt.params for s, rt in engine.stages.items()}
     )
 
 

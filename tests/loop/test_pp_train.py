@@ -29,13 +29,14 @@ from d9d_tpu.loop import (
     AdamWProvider,
     CausalLMTask,
     DatasetProvider,
-    ModelProvider,
     Trainer,
     TrainerConfig,
 )
 from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM, Qwen3DenseConfig
 from d9d_tpu.nn.sdpa import build_sdpa_backend
 from d9d_tpu.parallel import fsdp_plan, replicate_plan
+from tests.loop.conftest import LMProvider, SeededBatches
+from tests.loop.conftest import sync_stage_params as _sync_stage_params
 
 VOCAB = 64
 CFG = Qwen3DenseConfig(
@@ -51,29 +52,18 @@ CFG = Qwen3DenseConfig(
 STEPS = 4
 
 
-class Provider(ModelProvider):
-    def __init__(self, fsdp: bool):
-        self.fsdp = fsdp
-
-    def build_module(self, stage):
-        return Qwen3DenseCausalLM(
-            config=CFG, sdpa=build_sdpa_backend(), stage=stage,
-            dtype=jnp.float32,
-        )
-
-    def build_plan(self, ctx):
-        return fsdp_plan(ctx) if self.fsdp else replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
+def _dense(stage):
+    return Qwen3DenseCausalLM(
+        config=CFG, sdpa=build_sdpa_backend(), stage=stage, dtype=jnp.float32,
+    )
 
 
-class Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(7)
-        for _ in range(STEPS):
-            yield {"input_ids": rng.randint(0, VOCAB, size=(16, 17))}
+def Provider(fsdp: bool, build=_dense):
+    return LMProvider(build, fsdp_plan if fsdp else replicate_plan)
+
+
+def Data():
+    return SeededBatches((16, 17), VOCAB, seed=7, steps=STEPS)
 
 
 def train_history(ctx, pipeline=None, fsdp=False, build_only=False):
@@ -96,26 +86,6 @@ def train_history(ctx, pipeline=None, fsdp=False, build_only=False):
     if build_only:
         return trainer
     return trainer, trainer.train()
-
-
-def _sync_stage_params(engine, full_params):
-    """Overwrite every stage's params with the same-path leaves of a full
-    model tree (host numpy), then re-init optimizer state to match."""
-
-    def pull(leaf_sharding):
-        def fn(path, leaf):
-            src = full_params
-            for k in path:
-                src = src[k.key]
-            return jax.device_put(np.asarray(src), leaf.sharding)
-
-        return fn
-
-    for rt in engine.stages.values():
-        rt.params = jax.tree_util.tree_map_with_path(pull(None), rt.params)
-    engine.opt_states = engine.optimizer.init(
-        {s: rt.params for s, rt in engine.stages.items()}
-    )
 
 
 @pytest.fixture(scope="module")
@@ -353,14 +323,13 @@ def test_pp_hybrid_linear_attention_trains(devices):
 
     ctx = MeshParameters(pp=2, dp_shard=2).build(devices[:4])
 
-    class HybridProvider(Provider):
-        def build_module(self, stage):
-            return Qwen3MoeCausalLM(
-                config=Qwen3MoeConfig.hybrid_tiny(vocab_size=VOCAB),
-                sdpa=build_sdpa_backend(),
-                stage=stage,
-                dtype=jnp.float32,
-            )
+    def hybrid(stage):
+        return Qwen3MoeCausalLM(
+            config=Qwen3MoeConfig.hybrid_tiny(vocab_size=VOCAB),
+            sdpa=build_sdpa_backend(),
+            stage=stage,
+            dtype=jnp.float32,
+        )
 
     trainer = Trainer(
         ctx=ctx,
@@ -372,7 +341,7 @@ def test_pp_hybrid_linear_attention_trains(devices):
             log_every=1,
             learning_rate=5e-3,
         ),
-        model_provider=HybridProvider(fsdp=True),
+        model_provider=Provider(fsdp=True, build=hybrid),
         dataset_provider=Data(),
         task=CausalLMTask(),
         optimizer_provider=AdamWProvider(),

@@ -48,12 +48,20 @@ def _dense(decode_max_length=24):
     )
 
 
-def _params(model):
+@functools.lru_cache(maxsize=None)
+def _drawn(full):
     b, t = 2, 8
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    full = model.clone(decode_max_length=0)
-    return full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
+    return jax.jit(full.init)(jax.random.PRNGKey(0), z, pos, z)["params"]
+
+
+def _params(model):
+    """Seeded weights: one jitted ``init`` a model a process (this file's
+    tests and the five files that import it ask some seventy times); the
+    containers are the caller's own, the arrays shared."""
+    return jax.tree.map(
+        lambda a: a, _drawn(model.clone(decode_max_length=0)))
 
 
 def _oracle(model, params, prompt, n):

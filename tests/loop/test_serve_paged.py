@@ -236,7 +236,7 @@ def test_paged_hybrid_gdn_token_identical_and_prefix_auto_disabled():
     )
     z = jnp.zeros((2, 8), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (2, 8))
-    params = model.clone(decode_max_length=0).init(
+    params = jax.jit(model.clone(decode_max_length=0).init)(
         jax.random.PRNGKey(0), z, pos, z
     )["params"]
     prompts = _prompts(3, 3)

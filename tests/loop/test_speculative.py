@@ -42,7 +42,7 @@ def _dense(layers=2, seed=0, dml=40):
     b, t = 2, 8
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    params = model.clone(decode_max_length=0).init(
+    params = jax.jit(model.clone(decode_max_length=0).init)(
         jax.random.PRNGKey(seed), z, pos, z
     )["params"]
     return model, params
@@ -129,7 +129,7 @@ def test_gdn_hybrid_rejected_by_contract():
     b, t = 1, 4
     z = jnp.zeros((b, t), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    params = model.clone(decode_max_length=0).init(
+    params = jax.jit(model.clone(decode_max_length=0).init)(
         jax.random.PRNGKey(0), z, pos, z
     )["params"]
     with pytest.raises(NotImplementedError, match="recurrent state"):

@@ -14,8 +14,8 @@ from d9d_tpu.loop.serve import ContinuousBatcher
 from d9d_tpu.loop.speculative import speculative_generate
 from d9d_tpu.models.deepseek import DeepseekCausalLM, deepseek_v2_tiny
 from d9d_tpu.ops.attention.eager import eager_sdpa
-
-VOCAB = 64
+from tests.models import tiny
+from tests.models.tiny import VOCAB
 
 
 def _models(dml=0):
@@ -24,12 +24,8 @@ def _models(dml=0):
         config=cfg, sdpa=eager_sdpa, dtype=jnp.float32,
         decode_max_length=dml,
     )
-    b, t = 2, 8
-    z = jnp.zeros((b, t), jnp.int32)
-    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     full = model.clone(decode_max_length=0)
-    params = full.init(jax.random.PRNGKey(0), z, pos, z)["params"]
-    return full, model, params
+    return full, model, tiny.seeded_params(full)
 
 
 @pytest.mark.slow  # compile-bound on the 2-core rig; e2e tier covers it
@@ -40,14 +36,14 @@ def test_forward_loss_and_grads():
         np.random.RandomState(0).randint(0, VOCAB, (b, t)), jnp.int32
     )
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-    loss = full.apply({"params": params}, ids, pos, ids)
+    loss = jax.jit(full.apply)({"params": params}, ids, pos, ids)
     assert np.isfinite(float(loss.sum()))
     # MLA params exist where GQA's would not
     layer = params["model"]["layers_1"]["self_attn"]
     assert "kv_down_proj" in layer and "kv_up_proj" in layer
-    g = jax.grad(
+    g = jax.jit(jax.grad(
         lambda xp: float_sum(full, xp, ids, pos)
-    )(params)
+    ))(params)
     assert all(
         np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g)
     )
@@ -68,20 +64,12 @@ def test_greedy_generate_matches_full_forward_argmax():
     prompt = jnp.asarray(
         np.random.RandomState(1).randint(0, VOCAB, (1, 5)), jnp.int32
     )
-    n = 6
+    n, width = 6, 16
     got = np.asarray(generate(dec, params, prompt, max_new_tokens=n))[0]
-
-    seq = list(np.asarray(prompt)[0])
-    for _ in range(n):
-        ids = jnp.asarray([seq], jnp.int32)
-        pos = jnp.broadcast_to(
-            jnp.arange(len(seq), dtype=jnp.int32), (1, len(seq))
-        )
-        logits = full.apply(
-            {"params": params}, ids, pos, method=full.logits
-        )
-        seq.append(int(jnp.argmax(logits[0, -1])))
-    want = seq[5:]
+    pos = jnp.arange(width, dtype=jnp.int32)[None]
+    want, = tiny.greedy_oracle(
+        lambda p, t: full.apply({"params": p}, t, pos, method=full.logits),
+        params, [np.asarray(prompt)[0].tolist()], n, width)
     assert got.tolist() == want
 
 

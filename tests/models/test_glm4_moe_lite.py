@@ -6,6 +6,7 @@ and through the caches, at the tiny preset on the CPU rig with seeded
 weights, and one gradient step through ``Trainer``."""
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -15,16 +16,6 @@ import pytest
 
 from benchmarks.harness import build, correct
 from benchmarks.references import glm4_moe_lite as reference
-from d9d_tpu.core import MeshParameters
-from d9d_tpu.loop import (
-    AdamWProvider,
-    CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
-    Trainer,
-    TrainerConfig,
-)
-from d9d_tpu.loop.generate import generate
 from d9d_tpu.loop.serve import ContinuousBatcher
 from d9d_tpu.models.deepseek import (
     DeepseekCausalLM,
@@ -33,16 +24,13 @@ from d9d_tpu.models.deepseek import (
 )
 from d9d_tpu.nn.attention import MultiHeadLatentAttention
 from d9d_tpu.ops.attention.eager import eager_sdpa
-from d9d_tpu.parallel import replicate_plan
+from tests.models import tiny
+from tests.models.tiny import F32_REL_RMS, VOCAB, count
+from tests.models.tiny import ids as _ids
 
-VOCAB = 64
 CFG = glm4_moe_lite_tiny(VOCAB)
 HF = build.hf_view(CFG)
 
-# Float32 program against the float32 reference: the same sums in another
-# order (the program sorts tokens by expert, the reference evaluates every
-# expert densely); the CPU gives 1e-8, 1e-5 leaves room for a backend.
-F32_REL_RMS = 1e-5
 # bf16 weights and activations against the float32 reference reading the
 # same bf16 weights: every activation is rounded to 8 bits of mantissa
 # (relative step 2^-8 = 0.0039) a few times a layer; two layers at these
@@ -60,25 +48,16 @@ def _model(dtype=jnp.float32, dml=0):
     )
 
 
+def _bias_the_router(params, rng):
+    router = params["model"]["layers_1"]["mlp"]["router"]
+    router["e_score_correction_bias"] = jnp.asarray(
+        rng.uniform(-0.3, 0.3, CFG.num_experts), jnp.float32)
+
+
 def _params(dtype=jnp.float32, seed=0):
     """Seeded weights with a NON-ZERO selection bias: zero at init as in
     the published code, so every comparison below sets one."""
-    z = jnp.zeros((2, 8), jnp.int32)
-    params = nn.unbox(
-        _model(dtype).init(jax.random.PRNGKey(seed), z, z, z)["params"]
-    )
-    router = params["model"]["layers_1"]["mlp"]["router"]
-    router["e_score_correction_bias"] = jnp.asarray(
-        np.random.RandomState(seed).uniform(-0.3, 0.3, CFG.num_experts),
-        jnp.float32,
-    )
-    return params
-
-
-def _ids(shape, seed=1):
-    return jnp.asarray(
-        np.random.RandomState(seed).randint(0, VOCAB, shape), jnp.int32
-    )
+    return tiny.seeded_params(_model(dtype), seed, _bias_the_router)
 
 
 def test_presets_hold_the_published_sizes():
@@ -102,9 +81,6 @@ def test_presets_hold_the_published_sizes():
     shapes = nn.unbox(jax.eval_shape(
         lambda: two.init(jax.random.PRNGKey(0), z, z, z)["params"]
     ))
-
-    def count(tree):
-        return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
 
     assert round(count(shapes["model"]["layers_0"]) / 1e6) == 85
     assert round(count(shapes["model"]["layers_1"]) / 1e6) == 635
@@ -132,11 +108,12 @@ def test_the_comparisons_depend_on_the_bias():
     is routed by it, so the comparisons here prove the program reads it."""
     params = _params()
     tokens = _ids((2, 16))
-    with_bias = reference.logits(params, HF, tokens)
+    logits = jax.jit(lambda p: reference.logits(p, HF, tokens))
+    with_bias = logits(params)
     unbiased = jax.tree.map(lambda a: a, params)
     unbiased["model"]["layers_1"]["mlp"]["router"][
         "e_score_correction_bias"] = jnp.zeros((CFG.num_experts,))
-    without = reference.logits(unbiased, HF, tokens)
+    without = logits(unbiased)
     assert correct.rel_rms(without, with_bias) > 1e-3
 
 
@@ -178,15 +155,15 @@ def test_absorbed_decode_matches_the_decompressed_oracle():
     outs = {}
     for absorbed in (True, False):
         module = MultiHeadLatentAttention(decode_absorbed=absorbed, **kwargs)
-        variables = module.init(
+        variables = jax.jit(module.init)(
             jax.random.PRNGKey(4), x[:, :1], cos[:, :1], sin[:, :1]
         )
+        step = jax.jit(functools.partial(module.apply, mutable=["cache"]))
         cache, steps = variables["cache"], []
         for i in range(t):
-            out, state = module.apply(
+            out, state = step(
                 {"params": variables["params"], "cache": cache},
                 x[:, i:i + 1], cos[:, i:i + 1], sin[:, i:i + 1],
-                mutable=["cache"],
             )
             cache = state["cache"]
             steps.append(out)
@@ -201,14 +178,8 @@ def test_served_streams_paged_and_contiguous_equal_generate():
     model, params = _model(dml=32), _params()
     prompts = [np.asarray(_ids((n,), seed=n)).tolist() for n in (3, 6, 4)]
     n_new = 9
-
-    def oracle(p):
-        out = generate(
-            model, params, jnp.asarray([p], jnp.int32), max_new_tokens=n_new
-        )
-        return np.asarray(out)[0].tolist()
-
-    want = [oracle(p) for p in prompts]
+    want = correct.generate_streams(
+        model, params, prompts, n_new, max(len(p) for p in prompts)).tolist()
     for page_size in (None, 8):
         batcher = ContinuousBatcher(
             model, params, batch_size=2, page_size=page_size
@@ -233,38 +204,11 @@ def test_served_streams_paged_and_contiguous_equal_generate():
         batcher.close()
 
 
-class _Provider(ModelProvider):
-    def build_module(self, stage):
-        return DeepseekCausalLM(
-            config=CFG, sdpa=eager_sdpa, stage=stage, dtype=jnp.float32
-        )
-
-    def build_plan(self, ctx):
-        return replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
-
-
-class _Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(0)
-        while True:
-            yield {"input_ids": rng.randint(0, VOCAB, size=(4, 17))}
-
-
 def test_a_gradient_step_through_trainer_leaves_the_bias_alone():
-    trainer = Trainer(
-        ctx=MeshParameters().build(jax.devices()[:1]),
-        config=TrainerConfig(
-            global_batch_size=4, microbatch_size=4, seq_len=16,
-            total_steps=2, log_every=1, prefetch_batches=0,
-            learning_rate=1e-2, telemetry_console=False,
-        ),
-        model_provider=_Provider(), dataset_provider=_Data(),
-        task=CausalLMTask(),
-        optimizer_provider=AdamWProvider(weight_decay=0.1),
+    trainer = tiny.trainer(
+        lambda stage: DeepseekCausalLM(
+            config=CFG, sdpa=eager_sdpa, stage=stage, dtype=jnp.float32),
+        total_steps=2, one_batch=False, weight_decay=0.1,
     )
     def router(p):
         return nn.unbox(p)["params"]["model"]["layers_1"]["mlp"]["router"]
@@ -289,7 +233,7 @@ def test_the_bias_has_no_gradient():
             {"params": p}, tokens, pos, tokens, mutable=["moe_stats"]
         )[0].mean()
 
-    grads = jax.grad(loss)(params)
+    grads = jax.jit(jax.grad(loss))(params)
     router = grads["model"]["layers_1"]["mlp"]["router"]
     assert not np.asarray(router["e_score_correction_bias"]).any()
     assert np.asarray(router["gate"]["kernel"]).any()

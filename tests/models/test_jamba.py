@@ -16,20 +16,12 @@ from flax.traverse_util import flatten_dict, unflatten_dict
 
 from benchmarks.harness import build, correct
 from benchmarks.references import jamba as reference
-from d9d_tpu.core import MeshParameters
-from d9d_tpu.loop import (
-    AdamWProvider,
-    CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
-    Trainer,
-    TrainerConfig,
-)
 from d9d_tpu.models.jamba import JambaCausalLM, jamba2_3b, jamba_tiny
 from d9d_tpu.ops.attention.eager import eager_sdpa
-from d9d_tpu.parallel import replicate_plan
+from tests.models import tiny
+from tests.models.tiny import VOCAB, count
+from tests.models.tiny import ids as _ids
 
-VOCAB = 64
 # one whole period and one layer more: Mamba x 7, attention, Mamba x 7
 CFG = jamba_tiny(VOCAB, num_layers=15, attn_layer_period=14,
                  attn_layer_offset=7)
@@ -41,7 +33,7 @@ HF = build.hf_view(CFG)
 
 # Float32 program against the float32 reference: the same sums in another
 # order (a chunked associative scan against a sequential one); the CPU
-# gives 1e-6.
+# gives 1e-6. The one family not held to tiny.F32_REL_RMS (1e-5).
 F32_REL_RMS = 1e-4
 # The benchmark's own bound. bf16 weights against the float32 reference
 # reading the same weights. XLA's CPU backend rounds a bf16 program after
@@ -63,16 +55,7 @@ def _model(cfg=CFG, dtype=jnp.float32, dml=0, param_dtype=None):
 
 
 def _params(dtype=jnp.float32, seed=0, cfg=CFG):
-    z = jnp.zeros((2, 8), jnp.int32)
-    return nn.unbox(
-        _model(cfg, param_dtype=dtype).init(jax.random.PRNGKey(seed), z, z, z)
-    )["params"]
-
-
-def _ids(shape, seed=1):
-    return jnp.asarray(
-        np.random.RandomState(seed).randint(0, VOCAB, shape), jnp.int32
-    )
+    return tiny.seeded_params(_model(cfg, param_dtype=dtype), seed)
 
 
 def test_presets_hold_the_published_sizes():
@@ -95,9 +78,6 @@ def test_presets_hold_the_published_sizes():
     shapes = nn.unbox(jax.eval_shape(
         lambda: whole.init(jax.random.PRNGKey(0), z, z, z)["params"]
     ))
-
-    def count(tree):
-        return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
 
     layers = shapes["model"]
     assert round(count(layers["layers_0"]["mamba"]) / 1e5) == 412
@@ -156,9 +136,9 @@ def test_a_state_space_stack_adds_its_residual_stream_in_float32():
     model, params = _model(PAIR, BF16), _params(BF16, cfg=PAIR)
     ids = _ids((1, 6))
     pos = jnp.arange(6, dtype=jnp.int32)[None]
-    hidden, state = model.apply(
-        {"params": params}, ids, pos, capture_intermediates=True
-    )
+    hidden, state = jax.jit(lambda p: model.apply(
+        {"params": p}, ids, pos, capture_intermediates=True
+    ))(params)
     seen = state["intermediates"]["model"]
     for layer in ("layers_0", "layers_1"):
         assert seen[layer]["__call__"][0].dtype == jnp.float32
@@ -167,20 +147,21 @@ def test_a_state_space_stack_adds_its_residual_stream_in_float32():
     assert hidden.dtype == BF16
 
 
-def _decode_with_state_in(model, params, ids, state_dtype):
-    """Logits of every position, one token a step through the cache,
-    with the SSM state rounded to ``state_dtype`` after every step."""
+def _decode_with_state_in(model, params, ids):
+    """Logits of every position, one token a step through the cache, with
+    the SSM state kept in float32 and with it rounded to bf16 after every
+    step: one compiled program, which of the two is an argument."""
 
-    def carried(cache):
+    def carried(cache, rounded):
         flat = flatten_dict(cache)
         return unflatten_dict({
-            p: v.astype(state_dtype).astype(v.dtype)
+            p: jnp.where(rounded, v.astype(BF16).astype(v.dtype), v)
             if p[-1] == "ssm_state" else v
             for p, v in flat.items()
         })
 
     @jax.jit
-    def run(params, ids):
+    def run(params, ids, rounded):
         pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
         first, state = model.apply(
             {"params": params}, ids[:, :1], pos[None, :1], method="logits",
@@ -193,14 +174,16 @@ def _decode_with_state_in(model, params, ids, state_dtype):
                 {"params": params, "cache": cache}, tok[None, None],
                 p[None, None], method="logits", mutable=["cache"],
             )
-            return carried(new["cache"]), out[0, 0]
+            return carried(new["cache"], rounded), out[0, 0]
 
         _, rest = jax.lax.scan(
-            step, carried(state["cache"]), (ids[0, 1:], pos[1:])
+            step, carried(state["cache"], rounded), (ids[0, 1:], pos[1:])
         )
         return jnp.concatenate([first[0], rest], axis=0)
 
-    return np.asarray(run(params, jnp.asarray(ids)), np.float32)
+    return tuple(
+        np.asarray(run(params, jnp.asarray(ids), rounded), np.float32)
+        for rounded in (False, True))
 
 
 def test_a_state_carried_in_bf16_fails_the_comparison():
@@ -210,17 +193,13 @@ def test_a_state_carried_in_bf16_fails_the_comparison():
     its slow channels remember and reads 0.020 over 600 steps, while the
     float32 state reads under 0.001."""
     deep = jamba_tiny(VOCAB, 28, 14, 7)
-    z = jnp.zeros((1, 8), jnp.int32)
-    params = nn.unbox(_model(deep, param_dtype=BF16).init(
-        jax.random.PRNGKey(0), z, z, z
-    ))["params"]
+    params = _params(BF16, cfg=deep)
     ids = np.asarray(_ids((1, 600), seed=3))
     model = _model(deep, jnp.float32, dml=600, param_dtype=BF16)
     want = correct.reference_logits(
         reference, {"params": params}, build.hf_view(deep), ids
     )[0]
-    kept = _decode_with_state_in(model, params, ids, jnp.float32)
-    rounded = _decode_with_state_in(model, params, ids, BF16)
+    kept, rounded = _decode_with_state_in(model, params, ids)
     assert correct.rel_rms(kept, want) <= BF16_REL_RMS / 5
     assert correct.rel_rms(rounded, want) > BF16_REL_RMS
 
@@ -240,8 +219,8 @@ def test_the_fused_loss_and_its_gradients_are_the_references():
     def want_loss(p):
         return reference.loss(p, HF, tokens, labels)
 
-    got, grads = jax.value_and_grad(loss)(params)
-    want, want_grads = jax.value_and_grad(want_loss)(params)
+    got, grads = jax.jit(jax.value_and_grad(loss))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(want_loss))(params)
     assert abs(float(got) - float(want)) <= 1e-5
     got_flat, want_flat = flatten_dict(grads), flatten_dict(want_grads)
     assert got_flat.keys() == want_flat.keys()
@@ -283,40 +262,12 @@ def test_tied_embeddings_need_one_stage():
         first.init(jax.random.PRNGKey(0), z, z)
 
 
-class _Provider(ModelProvider):
-    def build_module(self, stage):
-        return JambaCausalLM(
-            config=jamba_tiny(VOCAB), sdpa=eager_sdpa, stage=stage,
-            dtype=jnp.float32,
-        )
-
-    def build_plan(self, ctx):
-        return replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
-
-
-class _Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(0)
-        batch = {"input_ids": rng.randint(0, VOCAB, size=(4, 17))}
-        while True:
-            yield batch
-
-
 def test_gradient_steps_through_trainer_lower_the_loss():
-    trainer = Trainer(
-        ctx=MeshParameters().build(jax.devices()[:1]),
-        config=TrainerConfig(
-            global_batch_size=4, microbatch_size=4, seq_len=16,
-            total_steps=4, log_every=1, prefetch_batches=0,
-            learning_rate=1e-2, telemetry_console=False,
-        ),
-        model_provider=_Provider(), dataset_provider=_Data(),
-        task=CausalLMTask(),
-        optimizer_provider=AdamWProvider(weight_decay=0.0),
+    trainer = tiny.trainer(
+        lambda stage: JambaCausalLM(
+            config=jamba_tiny(VOCAB), sdpa=eager_sdpa, stage=stage,
+            dtype=jnp.float32),
+        total_steps=4, one_batch=True,
     )
     table = lambda p: np.asarray(  # noqa: E731
         nn.unbox(p)["params"]["model"]["embed_tokens"]["embedding_default"]

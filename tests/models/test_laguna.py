@@ -36,13 +36,14 @@ from d9d_tpu.ops import RopeScalingNone
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.ops.attention.pallas_decode import window_pages
 from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
+from tests.models import tiny
+from tests.models.tiny import F32_REL_RMS, VOCAB, count
+from tests.models.tiny import ids as _ids
 
-VOCAB = 64
 CFG = laguna_tiny(VOCAB)
 # what the benchmark hands the reference at the tiny size: none of the
 # family's keys, so the reference reads the tree and its tiny constants
 HF = build.hf_view(CFG)
-F32_REL_RMS = 1e-5  # float32 against float32: the order of sums
 SEQ = 4 * TINY_WINDOW
 PAGE = 4
 RING = window_pages(TINY_WINDOW, PAGE) * PAGE  # 20 positions a row
@@ -56,24 +57,12 @@ def _model(cfg=CFG, dml=0, sdpa=eager_sdpa):
 
 
 def _params(cfg=CFG, seed=0):
-    z = jnp.zeros((2, 8), jnp.int32)
-    return nn.unbox(jax.jit(
-        lambda key: _model(cfg).init(key, z, z, z)["params"]
-    )(jax.random.PRNGKey(seed)))
+    return tiny.seeded_params(_model(cfg), seed)
 
 
 @pytest.fixture(scope="module")
 def params():
     return _params()
-
-
-def _ids(shape, seed=1):
-    return jnp.asarray(
-        np.random.RandomState(seed).randint(0, VOCAB, shape), jnp.int32)
-
-
-def count(tree) -> int:
-    return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
 
 
 def _window_kind(**changes) -> tuple:
@@ -219,9 +208,9 @@ def test_a_planted_fault_in_a_kind_specific_piece_fails(params, fault):
     leaves."""
     sample = np.asarray(_ids((1, SEQ + 1), seed=3))
     wrong = _model(dataclasses.replace(CFG, **fault))
-    got = correct.training_system(wrong, {"params": params}, sample)
-    want = correct.training_reference(
-        reference, {"params": params}, HF, sample)
+    got = tiny.loss_and_grads(wrong, params, sample, grads=False)
+    want = tiny.reference_loss_and_grads(
+        reference, params, HF, sample, grads=False)
     assert correct.rel_rms(got["logits"], want["logits"]) > 1e-3
 
 
@@ -298,9 +287,11 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
                 for k, v in params["grouped_experts"].items()
             },
         }
-        return jax.jit(layer.apply)({"params": cut}, x)
+        return layer.apply({"params": cut}, x)
 
-    shares = [share(first) for first in range(0, 32, CFG.num_experts)]
+    # one program: un-jitted, every share's ``lax.switch`` is a compile
+    shares = jax.jit(lambda: [
+        share(first) for first in range(0, 32, CFG.num_experts)])()
     assert len(shares) == 8
     np.testing.assert_allclose(
         sum(shares) - 7 * shared, want, rtol=1e-4, atol=1e-6)
@@ -336,15 +327,8 @@ def test_the_paged_batcher_decodes_as_the_reference_forward(params):
         assert leaf.shape == (2 * RING // PAGE, 2, PAGE, CFG.head_dim)
     assert len(prompts[1]) + n_new > RING > TINY_WINDOW
 
-    full = jax.jit(lambda p, t: reference.logits(p, HF, t))
-    for prompt, rid in zip(prompts, rids):
-        ids = list(prompt)
-        for _ in range(n_new):
-            padded = np.zeros((1, width), np.int32)  # causal: the rest unseen
-            padded[0, :len(ids)] = ids
-            row = full(params, jnp.asarray(padded))[0, len(ids) - 1]
-            ids.append(int(np.argmax(row)))
-        assert outputs[rid] == ids[len(prompt):]
+    assert [outputs[rid] for rid in rids] == tiny.greedy_oracle(
+        lambda p, t: reference.logits(p, HF, t), params, prompts, n_new, width)
 
 
 def test_the_flash_wrapper_counts_what_its_grid_visits_and_computes():

@@ -20,15 +20,6 @@ from flax.traverse_util import flatten_dict
 
 from benchmarks.harness import build, correct
 from benchmarks.references import mimo_v2_flash as reference
-from d9d_tpu.core import MeshParameters
-from d9d_tpu.loop import (
-    AdamWProvider,
-    CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
-    Trainer,
-    TrainerConfig,
-)
 from d9d_tpu.loop.generate import generate
 from d9d_tpu.loop.serve import ContinuousBatcher
 from d9d_tpu.models.deepseek import deepseek_v2_tiny
@@ -44,14 +35,14 @@ from d9d_tpu.models.qwen3.moe import AttentionKind, Qwen3MoeConfig
 from d9d_tpu.nn.decode_flags import recurrent_leaves, window_leaves
 from d9d_tpu.ops.attention.pallas_decode import window_pages
 from d9d_tpu.ops.attention.eager import eager_sdpa
-from d9d_tpu.parallel import replicate_plan
+from tests.models import tiny
+from tests.models.tiny import F32_REL_RMS, VOCAB, count
+from tests.models.tiny import ids as _ids
 
-VOCAB = 64
 CFG = mimo_v2_flash_tiny(VOCAB)
 # what the benchmark hands the reference at the tiny size: none of the
 # family's keys, so the reference reads the tree and its tiny constants
 HF = build.hf_view(CFG)
-F32_REL_RMS = 1e-5  # float32 against float32: the order of sums
 PAGE = 4
 RING = window_pages(TINY_WINDOW, PAGE) * PAGE  # 12 positions a row
 
@@ -63,15 +54,9 @@ def _model(cfg=CFG, dtype=jnp.float32, dml=0, sdpa=eager_sdpa):
     )
 
 
-def _params(cfg=CFG, seed=0):
-    """Seeded weights with a selection bias in every router and a sink
-    logit on every window layer's heads that are not zero."""
-    z = jnp.zeros((2, 8), jnp.int32)
-    params = nn.unbox(
-        _model(cfg).init(jax.random.PRNGKey(seed), z, z, z)["params"]
-    )
-    rng = np.random.RandomState(seed)
-    for i in range(cfg.num_layers):
+def _bias_and_sinks(params, rng):
+    layers = sum(name.startswith("layers_") for name in params["model"])
+    for i in range(layers):
         layer = params["model"][f"layers_{i}"]
         if "router" in layer["mlp"]:
             router = layer["mlp"]["router"]
@@ -81,23 +66,20 @@ def _params(cfg=CFG, seed=0):
             )
         if "sinks" in layer["self_attn"]:
             layer["self_attn"]["sinks"] = jnp.asarray(
-                rng.uniform(-1.0, 1.0, cfg.num_heads), jnp.float32
+                rng.uniform(-1.0, 1.0, layer["self_attn"]["sinks"].shape),
+                jnp.float32,
             )
-    return params
+
+
+def _params(cfg=CFG, seed=0):
+    """Seeded weights with a selection bias in every router and a sink
+    logit on every window layer's heads that are not zero."""
+    return tiny.seeded_params(_model(cfg), seed, _bias_and_sinks)
 
 
 @pytest.fixture(scope="module")
 def params():
     return _params()
-
-
-def _ids(shape, seed=1):
-    return jnp.asarray(
-        np.random.RandomState(seed).randint(0, VOCAB, shape), jnp.int32)
-
-
-def count(tree) -> int:
-    return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
 
 
 def test_presets_hold_the_published_sizes():
@@ -176,9 +158,9 @@ def test_the_comparison_catches_each_family_constant(params, wrong):
     base on a window layer, twelve rotated numbers for eight: each reads
     far outside what float32 rounding leaves."""
     sample = np.asarray(_ids((1, 17), seed=3))
-    got = correct.training_system(_model(), {"params": params}, sample)
-    off = correct.training_reference(
-        reference, {"params": params}, {**HF, **wrong}, sample)
+    got = tiny.loss_and_grads(_model(), params, sample, grads=False)
+    off = tiny.reference_loss_and_grads(
+        reference, params, {**HF, **wrong}, sample, grads=False)
     assert correct.rel_rms(got["logits"], off["logits"]) > 1e-3
 
 
@@ -226,8 +208,9 @@ def test_gradients_through_the_flash_kernel_match_the_eager_backend(params):
             {"params": p}, tokens, pos, labels).mean()
 
     flash = make_pallas_flash_sdpa(block_q=8, block_kv=8)
-    want, want_g = jax.value_and_grad(loss)(params, eager_sdpa)
-    got, got_g = jax.value_and_grad(loss)(params, flash)
+    run = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+    want, want_g = run(params, eager_sdpa)
+    got, got_g = run(params, flash)
     assert abs(float(got) - float(want)) <= 1e-5
     for path, g in flatten_dict(got_g).items():
         w = flatten_dict(want_g)[path]
@@ -267,23 +250,13 @@ def test_generate_and_the_batcher_serve_past_the_window_and_the_ring(
     streams."""
     model, prompts, _, streams = served
     n_new = 20
-
-    full = jax.jit(lambda p, t: reference.logits(p, HF, t))
-
-    def oracle(prompt):
-        ids = list(prompt)
-        for _ in range(n_new):
-            padded = np.zeros((1, 32), np.int32)  # causal: the rest is unseen
-            padded[0, :len(ids)] = ids
-            row = full(params, jnp.asarray(padded))[0, len(ids) - 1]
-            ids.append(int(np.argmax(row)))
-        return ids[len(prompt):]
-
     assert max(len(p) for p in prompts) + n_new > RING > TINY_WINDOW
     got = correct.generate_streams(
         model, params, prompts, n_new, max(len(p) for p in prompts)
     ).tolist()
-    assert got[0] == oracle(prompts[0])
+    assert [got[0]] == tiny.greedy_oracle(
+        lambda p, t: reference.logits(p, HF, t), params, prompts[:1], n_new,
+        32)
     assert streams == got
 
 
@@ -411,7 +384,9 @@ def test_the_sixteen_shares_add_up_to_the_uncut_reference():
         }
         return layer.apply({"params": cut}, x)
 
-    shares = [share(first) for first in range(0, 64, CFG.num_experts)]
+    # one program: un-jitted, every share's ``lax.switch`` is a compile
+    shares = jax.jit(lambda: [
+        share(first) for first in range(0, 64, CFG.num_experts)])()
     assert len(shares) == 16
     np.testing.assert_allclose(sum(shares), want, rtol=1e-4, atol=1e-6)
     # and the reference, told a share, leaves out what the others add
@@ -463,38 +438,11 @@ def test_a_pattern_is_held_to_the_kinds_that_exist():
             CFG, attention_kinds=(("mla", AttentionKind()),))
 
 
-class _Provider(ModelProvider):
-    def build_module(self, stage):
-        return MimoCausalLM(
-            config=CFG, sdpa=eager_sdpa, stage=stage, dtype=jnp.float32)
-
-    def build_plan(self, ctx):
-        return replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
-
-
-class _Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(0)
-        batch = {"input_ids": rng.randint(0, VOCAB, size=(4, 17))}
-        while True:
-            yield batch
-
-
 def test_gradient_steps_through_trainer_lower_the_loss():
-    trainer = Trainer(
-        ctx=MeshParameters().build(jax.devices()[:1]),
-        config=TrainerConfig(
-            global_batch_size=4, microbatch_size=4, seq_len=16,
-            total_steps=4, log_every=1, prefetch_batches=0,
-            learning_rate=1e-2, telemetry_console=False,
-        ),
-        model_provider=_Provider(), dataset_provider=_Data(),
-        task=CausalLMTask(),
-        optimizer_provider=AdamWProvider(weight_decay=0.0),
+    trainer = tiny.trainer(
+        lambda stage: MimoCausalLM(
+            config=CFG, sdpa=eager_sdpa, stage=stage, dtype=jnp.float32),
+        total_steps=4, one_batch=True,
     )
     history = trainer.train()
     losses = [row["loss"] for row in history]

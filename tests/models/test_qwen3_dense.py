@@ -8,6 +8,7 @@ pytestmark = pytest.mark.e2e  # slow tier: heavy kernel/e2e parity
 from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM, Qwen3DenseConfig
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.pipelining import PipelineStageInfo
+from tests.models import tiny
 
 
 @pytest.fixture(scope="module")
@@ -26,8 +27,8 @@ def test_forward_loss_shape(tiny_cfg):
     tokens = jnp.arange(24).reshape(2, 12) % 128
     positions = jnp.broadcast_to(jnp.arange(12), (2, 12))
     labels = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(0), tokens, positions, labels)
-    loss = model.apply(params, tokens, positions, labels)
+    params = {"params": tiny.seeded_params(model)}
+    loss = jax.jit(model.apply)(params, tokens, positions, labels)
     assert loss.shape == (2, 12)
     assert np.isfinite(np.asarray(loss)).all()
 
@@ -56,8 +57,8 @@ def test_pipeline_stage_split_matches_full(tiny_cfg):
     tokens = jnp.arange(16).reshape(2, 8) % 128
     positions = jnp.broadcast_to(jnp.arange(8), (2, 8))
     labels = jnp.roll(tokens, -1, axis=1)
-    params = full.init(jax.random.PRNGKey(0), tokens, positions, labels)
-    full_loss = full.apply(params, tokens, positions, labels)
+    params = {"params": tiny.seeded_params(full)}
+    full_loss = jax.jit(full.apply)(params, tokens, positions, labels)
 
     s0 = make_model(tiny_cfg, PipelineStageInfo(0, 2))
     s1 = make_model(tiny_cfg, PipelineStageInfo(1, 2))
@@ -70,9 +71,9 @@ def test_pipeline_stage_split_matches_full(tiny_cfg):
         "model": {"layers_1": p["model"]["layers_1"], "norm": p["model"]["norm"]},
         "lm_head": p["lm_head"],
     }}
-    h = s0.apply(p0, tokens, positions)
+    h = jax.jit(s0.apply)(p0, tokens, positions)
     assert h.shape == (2, 8, tiny_cfg.hidden_size)
-    loss = s1.apply(p1, h, positions, labels)
+    loss = jax.jit(s1.apply)(p1, h, positions, labels)
     np.testing.assert_allclose(np.asarray(loss), np.asarray(full_loss), rtol=1e-5)
 
 
@@ -139,9 +140,9 @@ def test_hf_parity(tiny_cfg):
     model = make_model(cfg)
     tokens_np = np.arange(20).reshape(2, 10) % cfg.vocab_size
     positions = jnp.broadcast_to(jnp.arange(10), (2, 10))
-    ours = model.apply(
-        params, jnp.asarray(tokens_np), positions, method=model.logits
-    )
+    ours = jax.jit(lambda p, t: model.apply(
+        p, t, positions, method=model.logits
+    ))(params, jnp.asarray(tokens_np))
     with torch.no_grad():
         theirs = hf(torch.tensor(tokens_np)).logits.numpy()
     np.testing.assert_allclose(np.asarray(ours), theirs, rtol=2e-4, atol=2e-4)

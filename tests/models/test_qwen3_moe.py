@@ -22,6 +22,8 @@ from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.nn.moe import MoELayer
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
+from tests.jaxpr_tools import count, equations
+from tests.models import tiny
 
 B, T = 4, 16
 
@@ -46,22 +48,37 @@ def _inputs(vocab=256):
     return tokens, positions
 
 
-def test_forward_loss_shape(ctx):
-    model = _model()
+def _variables(model):
+    """Seeded weights of ``model`` as ``apply`` takes them."""
+    return {"params": tiny.seeded_params(model)}
+
+
+@pytest.fixture(scope="module")
+def local_path():
+    """The tiny model without ``ep_axes`` on ``_inputs()``: its weights,
+    loss and gradient from one jitted program, on the host so that every
+    mesh of this module can read them; the three EP comparisons' oracle."""
     tokens, positions = _inputs()
-    variables = model.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-    params = {"params": variables["params"]}
-    loss = model.apply(params, tokens, positions, tokens)
+    local = _model()
+
+    def loss(p):
+        out = local.apply(p, tokens, positions, tokens)
+        return out.sum(), out
+
+    (_, loss_local), g_local = jax.jit(
+        jax.value_and_grad(loss, has_aux=True))(_variables(local))
+    return jax.tree.map(np.asarray, (_variables(local), loss_local, g_local))
+
+
+def test_forward_loss_shape(ctx, local_path):
+    _, loss, _ = local_path
     assert loss.shape == (B, T)
     assert np.isfinite(np.asarray(loss)).all()
 
 
-def test_ep_matches_local(ctx):
+def test_ep_matches_local(ctx, local_path):
     tokens, positions = _inputs()
-    local = _model()
-    variables = local.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-    params = {"params": variables["params"]}
-    loss_local = local.apply(params, tokens, positions, tokens)
+    params, loss_local, g_local = local_path
 
     ep = _model(ep_axes=ctx.ep_shard_axes)
     loss_ep = jax.jit(ep.apply)(params, tokens, positions, tokens)
@@ -69,9 +86,6 @@ def test_ep_matches_local(ctx):
         np.asarray(loss_ep), np.asarray(loss_local), rtol=2e-4, atol=2e-5
     )
 
-    g_local = jax.grad(
-        lambda p: local.apply(p, tokens, positions, tokens).sum()
-    )(params)
     g_ep = jax.jit(
         jax.grad(lambda p: ep.apply(p, tokens, positions, tokens).sum())
     )(params)
@@ -101,7 +115,8 @@ def test_mlp_only_layers_are_dense(ctx):
     )
     model = Qwen3MoeCausalLM(config=cfg, sdpa=eager_sdpa, dtype=jnp.float32)
     tokens, positions = _inputs(vocab=64)
-    variables = model.init(jax.random.PRNGKey(0), tokens, positions, tokens)
+    variables = jax.eval_shape(  # the tree's names: nothing compiles
+        lambda: model.init(jax.random.PRNGKey(0), tokens, positions, tokens))
     layers = variables["params"]["model"]
     assert "gate_proj" in layers["layers_0"]["mlp"]  # dense SwiGLU
     assert "router" in layers["layers_1"]["mlp"]  # MoE
@@ -116,21 +131,13 @@ def test_moe_layer_tokens_per_expert_stats(ctx):
         dtype=jnp.float32,
     )
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16))
-    variables = layer.init(jax.random.PRNGKey(0), x)
-    _, stats = layer.apply(
-        {"params": variables["params"]}, x, mutable=["moe_stats"]
-    )
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
+    _, stats = jax.jit(lambda p: layer.apply(
+        {"params": p}, x, mutable=["moe_stats"]
+    ))(variables["params"])
     tpe = stats["moe_stats"]["tokens_per_expert"]
     tpe = tpe[0] if isinstance(tpe, tuple) else tpe
     assert int(np.asarray(tpe).sum()) == 2 * 8 * 2
-
-
-def _equations(jaxpr):
-    """Every equation of a jaxpr, sub-jaxprs included."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(sub)
 
 
 def test_local_path_has_no_buffer_choice(ctx):
@@ -144,14 +151,14 @@ def test_local_path_has_no_buffer_choice(ctx):
     )
     # 256 rows: a call of 128 or fewer takes the all-expert products
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 16))
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
 
     def loss(p, x):
         out, stats = layer.apply({"params": p}, x, mutable=["moe_stats"])
         return (out ** 2).sum(), stats
 
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(params, x)
-    eqns = list(_equations(jaxpr.jaxpr))
+    eqns = list(equations(jaxpr.jaxpr))
     names = {eqn.primitive.name for eqn in eqns}
     assert "ragged_dot_general" in names  # the scan reaches the experts
     assert not names & {"cond", "custom_vjp_call_jaxpr"}
@@ -162,7 +169,7 @@ def test_local_path_has_no_buffer_choice(ctx):
         if eqn.primitive.name == "custom_vjp_call"
     } <= {"_permute_rows_bwd", "_spread_to_pairs_bwd", "_combine_pairs_bwd"}
     assert not names & {"all_gather", "ragged_all_to_all", "shard_map"}
-    _, stats = loss(params, x)
+    _, stats = jax.eval_shape(loss, params, x)
     assert set(stats["moe_stats"]) == {"tokens_per_expert"}
 
 
@@ -188,7 +195,7 @@ def test_ep_layer_sows_buffer_use(ctx, token_layout, routing):
         **kw,
     )
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16))
-    params = local.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(local.init)(jax.random.PRNGKey(0), x)["params"]
     if routing == "one_shard":
         # the selection bias sends every token to experts 0 and 1
         bias = jnp.zeros((16,)).at[:2].set(10.0)
@@ -200,7 +207,7 @@ def test_ep_layer_sows_buffer_use(ctx, token_layout, routing):
         x = jax.device_put(
             x, NamedSharding(ctx.mesh, P(ctx.batch_axes, ctx.sequence_axes))
         )
-    want = local.apply({"params": params}, x)
+    want = jax.jit(local.apply)({"params": params}, x)
     got, stats = jax.jit(
         lambda p, x: ep.apply({"params": p}, x, mutable=["moe_stats"])
     )(params, x)
@@ -231,7 +238,7 @@ def test_ep_layer_sows_buffer_use(ctx, token_layout, routing):
     ],
     ids=["dp_tp", "dp_cp_tp"],
 )
-def test_ep_token_layout_matches_local(mesh_kw):
+def test_ep_token_layout_matches_local(mesh_kw, local_path):
     """The token-layout EP flow (shard_map riding the residual
     [B@dp, T@cp, D] sharding, non-token ep axes subdividing ownership)
     computes the same loss/grads as the local path."""
@@ -239,10 +246,7 @@ def test_ep_token_layout_matches_local(mesh_kw):
 
     ctx = MeshParameters(**mesh_kw).build(jax.devices())
     tokens, positions = _inputs()
-    local = _model()
-    variables = local.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-    params = {"params": variables["params"]}
-    loss_local = local.apply(params, tokens, positions, tokens)
+    params, loss_local, g_local = local_path
 
     import dataclasses
 
@@ -260,9 +264,6 @@ def test_ep_token_layout_matches_local(mesh_kw):
         np.asarray(loss_ep), np.asarray(loss_local), rtol=2e-4, atol=2e-5
     )
 
-    g_local = jax.grad(
-        lambda p: local.apply(p, tokens, positions, tokens).sum()
-    )(params)
     g_ep = jax.jit(
         jax.grad(lambda p: ep.apply(p, sharded_tokens, positions, tokens).sum())
     )(params)
@@ -284,14 +285,13 @@ class TestHybridLinearAttention:
             dtype=jnp.float32,
         )
         tokens, positions = _inputs()
-        variables = model.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-        layers = variables["params"]["model"]
+        params = _variables(model)
+        layers = params["params"]["model"]
         for i in (0, 1, 2):
             assert "linear_attn" in layers[f"layers_{i}"], i
             assert "self_attn" not in layers[f"layers_{i}"], i
         assert "self_attn" in layers["layers_3"]
-        params = {"params": variables["params"]}
-        loss = model.apply(params, tokens, positions, tokens)
+        loss = jax.jit(model.apply)(params, tokens, positions, tokens)
         assert loss.shape == (B, T)
         assert np.isfinite(np.asarray(loss)).all()
 
@@ -304,8 +304,7 @@ class TestHybridLinearAttention:
             dtype=jnp.float32,
         )
         tokens, positions = _inputs()
-        variables = model.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-        params = {"params": variables["params"]}
+        params = _variables(model)
         opt = optax.adam(3e-3)
         state = opt.init(params)
 
@@ -334,8 +333,7 @@ def test_hybrid_padding_mask_blocks_contamination(ctx):
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, 256, (1, 12)), jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(12, dtype=jnp.int32), (1, 12))
-    variables = model.init(jax.random.PRNGKey(0), tokens, positions, tokens)
-    params = {"params": variables["params"]}
+    params = _variables(model)
 
     # garbage in the first 4 (padded) positions must not change outputs at
     # the real positions when both masks exclude them: padding_mask zeroes
@@ -344,22 +342,18 @@ def test_hybrid_padding_mask_blocks_contamination(ctx):
     pad_mask = jnp.asarray([[0, 0, 0, 0] + [1] * 8], jnp.int32)
     attn_mask = pad_mask[:, None, None, :].astype(bool)
     corrupted = tokens.at[:, :4].set(7)
-    out_a = model.apply(
-        params, tokens, positions, method=model.logits,
-        mask=attn_mask, padding_mask=pad_mask,
-    )
-    out_b = model.apply(
-        params, corrupted, positions, method=model.logits,
-        mask=attn_mask, padding_mask=pad_mask,
-    )
+    logits = jax.jit(lambda t, padding_mask: model.apply(
+        params, t, positions, method=model.logits,
+        mask=attn_mask, padding_mask=padding_mask,
+    ))
+    out_a = logits(tokens, pad_mask)
+    out_b = logits(corrupted, pad_mask)
     np.testing.assert_allclose(
         np.asarray(out_a[:, 4:]), np.asarray(out_b[:, 4:]), atol=1e-5
     )
     # sdpa mask alone is NOT enough — without padding_mask the pad tokens
     # still flow through the GDN conv/recurrence (the bug being pinned)
-    out_c = model.apply(
-        params, corrupted, positions, method=model.logits, mask=attn_mask
-    )
+    out_c = logits(corrupted, None)
     assert not np.allclose(np.asarray(out_a[:, 4:]), np.asarray(out_c[:, 4:]),
                            atol=1e-5)
 
@@ -388,7 +382,7 @@ class TestRematPolicies:
                 sdpa=make_pallas_flash_sdpa(block_q=16, block_kv=16),
                 dtype=jnp.float32,
             )
-            variables = m.init(jax.random.PRNGKey(0), toks, pos, toks)
+            variables = jax.jit(m.init)(jax.random.PRNGKey(0), toks, pos, toks)
             params = variables["params"]
             rest = {k: v for k, v in variables.items() if k != "params"}
 
@@ -403,7 +397,9 @@ class TestRematPolicies:
                 )
 
             traced = jax.jit(jax.grad(loss)).trace(params)
-            assert str(traced.jaxpr).count("pallas_call") == 3 * 2, policy
+            assert count(
+                traced.jaxpr.jaxpr,
+                lambda eqn: eqn.primitive.name == "pallas_call") == 3 * 2, policy
             grads[policy] = traced.lower().compile()(params)
 
         ref = jax.tree.leaves(grads["full"])

@@ -24,6 +24,7 @@ from d9d_tpu.models.deepseek import DeepseekCausalLM, xing4_0_tiny
 from d9d_tpu.models.laguna import LagunaCausalLM, laguna_tiny
 from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
+from tests.jaxpr_tools import count
 
 VOCAB = 64
 SEQ = 32
@@ -42,17 +43,6 @@ STACKS = {
         LAGUNA, num_layers=2, layer_kinds=LAGUNA.layer_kinds[:2])),
 }
 ATTENTION_LAYERS = 2
-
-
-def count(jaxpr, wanted) -> int:
-    """Equations of ``jaxpr``, and of the jaxprs inside it, that
-    ``wanted`` accepts."""
-    return sum(
-        bool(wanted(eqn))
-        + sum(count(sub, wanted)
-              for sub in jax.core.jaxprs_in_params(eqn.params))
-        for eqn in jaxpr.eqns
-    )
 
 
 def pallas_call(eqn) -> bool:

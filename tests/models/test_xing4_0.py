@@ -18,15 +18,6 @@ import pytest
 from benchmarks.harness import build, correct
 from benchmarks.references import xing4_0 as reference
 from d9d_tpu.core import MeshParameters
-from d9d_tpu.loop import (
-    AdamWProvider,
-    CausalLMTask,
-    DatasetProvider,
-    ModelProvider,
-    Trainer,
-    TrainerConfig,
-)
-from d9d_tpu.loop.generate import generate
 from d9d_tpu.loop.serve import ContinuousBatcher
 from d9d_tpu.models.deepseek import (
     DeepseekCausalLM,
@@ -39,11 +30,12 @@ from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu import parallel
 from d9d_tpu.parallel.plan import logical_to_mesh_sharding
 from d9d_tpu.pipelining import PipelineStageInfo
+from tests.models.tiny import F32_REL_RMS, VOCAB, count
+from tests.models.tiny import ids as _ids
+from tests.models import tiny
 
-VOCAB = 64
 CFG = xing4_0_tiny(VOCAB)
 HF = build.hf_view(CFG)
-F32_REL_RMS = 1e-5  # see tests/models/test_glm4_moe_lite.py
 
 
 def _model(cfg=CFG, dtype=jnp.float32, dml=0, **extra):
@@ -53,13 +45,7 @@ def _model(cfg=CFG, dtype=jnp.float32, dml=0, **extra):
     )
 
 
-def _params(cfg=CFG, dtype=jnp.float32, seed=0):
-    """Seeded weights with a non-zero selection bias in every router."""
-    z = jnp.zeros((2, 8), jnp.int32)
-    params = nn.unbox(
-        _model(cfg, dtype).init(jax.random.PRNGKey(seed), z, z, z)["params"]
-    )
-    rng = np.random.RandomState(seed)
+def _bias_every_router(params, rng):
     blocks = [params["model"]["layers_1"]]
     if "mtp" in params:
         blocks.append(params["mtp"]["block"])
@@ -69,16 +55,22 @@ def _params(cfg=CFG, dtype=jnp.float32, seed=0):
             rng.uniform(-0.3, 0.3, router["gate"]["kernel"].shape[1]),
             jnp.float32,
         )
-    return params
 
 
-def _ids(shape, seed=1):
-    return jnp.asarray(
-        np.random.RandomState(seed).randint(0, VOCAB, shape), jnp.int32)
+def _params(cfg=CFG, dtype=jnp.float32, seed=0):
+    """Seeded weights with a non-zero selection bias in every router."""
+    return tiny.seeded_params(_model(cfg, dtype), seed, _bias_every_router)
 
 
-def count(tree) -> int:
-    return sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))
+@pytest.fixture(scope="module")
+def trained():
+    """The (2, 17) sample through the Trainer's task and through the
+    reference, logits, loss, metrics and gradients: one compiled program
+    each, read by the three tests below."""
+    model, params = _model(), _params()
+    sample = np.asarray(_ids((2, 17)))
+    return (tiny.loss_and_grads(model, params, sample),
+            tiny.reference_loss_and_grads(reference, params, HF, sample))
 
 
 def test_presets_hold_the_published_sizes():
@@ -117,14 +109,10 @@ def test_presets_hold_the_published_sizes():
     assert round(count(shapes) / 1e6, 1) == 913.5
 
 
-def test_training_mode_matches_the_reference():
+def test_training_mode_matches_the_reference(trained):
     """Main-head logits and the whole loss (next token + 0.3 x the
     module's), through the Trainer's task as the benchmark compares them."""
-    model, params = _model(), _params()
-    sample = np.asarray(_ids((2, 17)))
-    system = correct.training_system(model, {"params": params}, sample)
-    want = correct.training_reference(
-        reference, {"params": params}, HF, sample)
+    system, want = trained
     checks = correct.compare_training(system, want)
     assert checks["logits_rel_rms"] <= F32_REL_RMS, checks
     assert checks["loss_gap"] <= 1e-5, checks
@@ -134,26 +122,22 @@ def test_the_modules_logits_match_the_reference():
     model, params = _model(), _params()
     tokens = _ids((2, 16))
     pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
-    got = model.apply({"params": params}, tokens, pos, method="mtp_logits")
+    got, main = jax.jit(lambda p: tuple(
+        model.apply({"params": p}, tokens, pos, method=method)
+        for method in ("mtp_logits", "logits")))(params)
     with jax.default_matmul_precision("highest"):
-        want = reference.mtp_logits(params, HF, tokens)
+        want = jax.jit(lambda p: reference.mtp_logits(p, HF, tokens))(params)
     assert want.shape == (2, 15, VOCAB)
     # the program's last position was given the first token: of no use
     assert correct.rel_rms(got[:, :-1], want) <= F32_REL_RMS
     # and the module is not the main head at another name
-    main = model.apply({"params": params}, tokens, pos, method="logits")
     assert correct.rel_rms(main[:, :-1], want) > 0.1
 
 
-def test_the_loss_sees_the_module_and_its_terms_reach_the_metrics():
+def test_the_loss_sees_the_module_and_its_terms_reach_the_metrics(trained):
     model, params = _model(), _params()
-    task = CausalLMTask()
-    mb = task.prepare_batch({"input_ids": np.asarray(_ids((2, 17)))})
-    loss_sum, weight, metrics = task.loss_fn(
-        model, {"params": params}, mb, jax.random.PRNGKey(0))
-    host = task.metrics_postprocess({
-        f"task/{k}": np.asarray(v) for k, v in metrics.items()})
-    total = float(loss_sum) / float(weight)
+    system, want = trained
+    host, total, mb = system["metrics"], system["loss"], system["mb"]
     assert host["loss/next_token"] + 0.3 * host["loss/mtp"] == pytest.approx(
         total, rel=1e-6)
     # near ln(64) each at seeded init; the module's 15 of 16 positions
@@ -165,32 +149,22 @@ def test_the_loss_sees_the_module_and_its_terms_reach_the_metrics():
     with jax.default_matmul_precision("highest"):
         without = dict(params)
         without.pop("mtp")
-        plain = float(reference.loss(without, HF, mb["tokens"], mb["labels"]))
-        whole = float(reference.loss(params, HF, mb["tokens"], mb["labels"]))
+        plain = float(jax.jit(lambda p: reference.loss(
+            p, HF, mb["tokens"], mb["labels"]))(without))
+    whole = want["loss"]
     assert plain == pytest.approx(host["loss/next_token"], abs=1e-5)
     assert whole == pytest.approx(total, abs=1e-5)
-    # ignored labels are masked in both terms
-    mb["labels"] = np.where(np.arange(16) % 3 == 0, -100, mb["labels"])
-    masked, weight, _ = task.loss_fn(
-        model, {"params": params}, mb, jax.random.PRNGKey(0))
-    assert float(weight) == 2 * 10 and np.isfinite(float(masked))
+    # ignored labels are masked in both terms (the same compiled program)
+    masked = tiny.loss_and_grads(
+        model, params, np.asarray(_ids((2, 17))),
+        labels=np.where(np.arange(16) % 3 == 0, -100, mb["labels"]))
+    assert masked["weight"] == 2 * 10 and np.isfinite(masked["loss"])
 
 
-def test_gradients_match_the_reference():
-    model, params = _model(), _params()
-    task = CausalLMTask()
-    mb = task.prepare_batch({"input_ids": np.asarray(_ids((2, 17)))})
-
-    def program(p):
-        loss_sum, weight, _ = task.loss_fn(
-            model, {"params": p}, mb, jax.random.PRNGKey(0))
-        return loss_sum / weight
-
-    got = jax.grad(program)(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(
-            lambda p: reference.loss(p, HF, mb["tokens"], mb["labels"])
-        )(params)
+def test_gradients_match_the_reference(trained):
+    """Of ``loss_sum / weight`` through the task, against the gradient of
+    the reference's whole loss."""
+    got, want = trained[0]["grads"], trained[1]["grads"]
     scale = max(float(jnp.abs(g).max()) for g in jax.tree.leaves(want))
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
     for path, w in jax.tree_util.tree_leaves_with_path(want):
@@ -213,29 +187,27 @@ def test_gradients_match_the_reference():
     assert not np.asarray(bias).any()
 
 
-def system_logits(model, params, sample):
-    return correct.training_system(model, {"params": params}, sample)["logits"]
-
-
 @pytest.mark.parametrize("first", [0, 4, 12])
 def test_each_share_matches_the_reference_given_the_same_share(first):
     """The reference is told the held range (``first_held_expert``) and
     leaves out what the absent experts would add, as the program does."""
     cfg = dataclasses.replace(CFG, first_held_expert=first)
-    model, params = _model(cfg), _params(cfg)
+    # the range is no part of what ``init`` draws: one tree for the three
+    model, params = _model(cfg), _params()
     hf = dict(build.hf_view(cfg), first_held_expert=first)
     sample = np.asarray(_ids((1, 13), seed=first))
+    system = tiny.loss_and_grads(model, params, sample, grads=False)
     checks = correct.compare_training(
-        correct.training_system(model, {"params": params}, sample),
-        correct.training_reference(reference, {"params": params}, hf, sample),
+        system,
+        tiny.reference_loss_and_grads(
+            reference, params, hf, sample, grads=False),
     )
     assert checks["logits_rel_rms"] <= F32_REL_RMS, checks
     assert checks["loss_gap"] <= 1e-5, checks
     if first:
-        wrong = correct.training_reference(
-            reference, {"params": params}, HF, sample)
-        assert correct.rel_rms(
-            system_logits(model, params, sample), wrong["logits"]) > 1e-3
+        wrong = tiny.reference_loss_and_grads(
+            reference, params, HF, sample, grads=False)
+        assert correct.rel_rms(system["logits"], wrong["logits"]) > 1e-3
 
 
 def test_the_shares_of_the_model_add_up_to_the_uncut_reference():
@@ -270,7 +242,9 @@ def test_the_shares_of_the_model_add_up_to_the_uncut_reference():
         }
         return layer.apply({"params": cut}, x)
 
-    routed = sum(share(first) for first in range(0, 16, CFG.num_experts))
+    # one program: un-jitted, every share's ``lax.switch`` is a compile
+    routed = jax.jit(lambda: sum(
+        share(first) for first in range(0, 16, CFG.num_experts)))()
     np.testing.assert_allclose(routed + shared, want, rtol=1e-4, atol=1e-6)
 
 
@@ -288,25 +262,14 @@ def test_generate_and_the_batcher_serve_the_model():
     same streams; the module's parameters ride along unused."""
     model, params = _model(dml=32), _params()
     prompts = [np.asarray(_ids((n,), seed=n)).tolist() for n in (3, 6, 4)]
-    n_new = 5
+    n_new, width = 5, 16
     plain = _model()
-
-    def oracle(prompt):
-        ids = list(prompt)
-        for _ in range(n_new):
-            t = jnp.asarray([ids], jnp.int32)
-            pos = jnp.arange(len(ids), dtype=jnp.int32)[None]
-            row = plain.apply({"params": params}, t, pos, method="logits")
-            ids.append(int(jnp.argmax(row[0, -1])))
-        return ids[len(prompt):]
-
-    want = [oracle(p) for p in prompts]
-    got = [
-        np.asarray(generate(
-            model, params, jnp.asarray([p], jnp.int32), max_new_tokens=n_new
-        ))[0].tolist()
-        for p in prompts
-    ]
+    pos = jnp.arange(width, dtype=jnp.int32)[None]
+    want = tiny.greedy_oracle(
+        lambda p, t: plain.apply({"params": p}, t, pos, method="logits"),
+        params, prompts, n_new, width)
+    got = correct.generate_streams(
+        model, params, prompts, n_new, max(len(p) for p in prompts)).tolist()
     assert got == want
     batcher = ContinuousBatcher(model, params, batch_size=2, page_size=8)
     rids = [batcher.submit(p, max_new_tokens=n_new) for p in prompts]
@@ -323,10 +286,8 @@ def test_one_stream_no_range_and_no_module_is_the_model_it_was():
         old, hc_mult=1, num_routed_experts=old.num_experts,
         num_mtp_modules=0, hc_sinkhorn_iters=7, mtp_loss_weight=0.9,
     )
-    z = jnp.zeros((2, 8), jnp.int32)
     trees = [
-        jax.tree.map(np.asarray, nn.unbox(
-            _model(cfg).init(jax.random.PRNGKey(0), z, z, z)["params"]))
+        jax.tree.map(np.asarray, tiny.seeded_params(_model(cfg)))
         for cfg in (old, same)
     ]
     assert jax.tree.structure(trees[0]) == jax.tree.structure(trees[1])
@@ -353,11 +314,16 @@ def test_the_module_needs_embedding_and_head_on_one_stage(stage, fails):
     model = _model(stage=stage)
     carry = z if stage.is_first else jnp.zeros(
         (1, 8, CFG.hc_mult, CFG.hidden_size))
+
+    def init():  # shapes are enough: nothing compiles
+        return jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), carry, z, z))["params"]
+
     if fails:
         with pytest.raises(ValueError, match="one pipeline stage"):
-            model.init(jax.random.PRNGKey(0), carry, z, z)
+            init()
     else:
-        assert "mtp" in model.init(jax.random.PRNGKey(0), carry, z, z)["params"]
+        assert "mtp" in init()
 
 
 def test_between_pipeline_stages_the_carry_is_the_stream():
@@ -367,7 +333,7 @@ def test_between_pipeline_stages_the_carry_is_the_stream():
     params = _params(cfg)
     tokens = _ids((2, 8))
     pos = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32), (2, 8))
-    whole = _model(cfg).apply({"params": params}, tokens, pos, tokens)
+    whole = jax.jit(_model(cfg).apply)({"params": params}, tokens, pos, tokens)
 
     def stage_params(keep):
         model = {k: v for k, v in params["model"].items() if k in keep}
@@ -378,10 +344,10 @@ def test_between_pipeline_stages_the_carry_is_the_stream():
 
     first = _model(cfg, stage=PipelineStageInfo(0, 2))
     last = _model(cfg, stage=PipelineStageInfo(1, 2))
-    carry = first.apply(
+    carry = jax.jit(first.apply)(
         {"params": stage_params({"embed_tokens", "layers_0"})}, tokens, pos)
     assert carry.shape == (2, 8, cfg.hc_mult, cfg.hidden_size)
-    loss = last.apply(
+    loss = jax.jit(last.apply)(
         {"params": stage_params({"layers_1", "norm"})}, carry, pos, tokens)
     np.testing.assert_allclose(loss, whole, rtol=1e-5, atol=1e-6)
 
@@ -416,41 +382,14 @@ def test_every_new_parameter_gets_a_spec_and_the_stream_is_pinned(plan):
     assert out.shape == (2, 8) and np.isfinite(np.asarray(out)).all()
 
 
-class _Provider(ModelProvider):
-    def build_module(self, stage):
-        return DeepseekCausalLM(
-            config=dataclasses.replace(CFG, remat=True), sdpa=eager_sdpa,
-            stage=stage, dtype=jnp.float32,
-        )
-
-    def build_plan(self, ctx):
-        return parallel.replicate_plan(ctx)
-
-    def sample_inputs(self, batch_size, seq_len):
-        z = jnp.zeros((batch_size, seq_len), jnp.int32)
-        return (z, z, z)
-
-
-class _Data(DatasetProvider):
-    def build(self):
-        rng = np.random.RandomState(0)
-        while True:
-            yield {"input_ids": rng.randint(0, VOCAB, size=(4, 17))}
-
-
 def test_steps_through_trainer_under_remat_log_both_terms():
     from d9d_tpu.telemetry import get_telemetry
 
-    trainer = Trainer(
-        ctx=MeshParameters().build(jax.devices()[:1]),
-        config=TrainerConfig(
-            global_batch_size=4, microbatch_size=4, seq_len=16,
-            total_steps=3, log_every=1, prefetch_batches=0,
-            learning_rate=1e-2, telemetry_console=False,
-        ),
-        model_provider=_Provider(), dataset_provider=_Data(),
-        task=CausalLMTask(),
-        optimizer_provider=AdamWProvider(weight_decay=0.0),
+    trainer = tiny.trainer(
+        lambda stage: DeepseekCausalLM(
+            config=dataclasses.replace(CFG, remat=True), sdpa=eager_sdpa,
+            stage=stage, dtype=jnp.float32),
+        total_steps=3, one_batch=False,
     )
     before = jax.tree.map(np.asarray, nn.unbox(trainer.params)["params"])
     history = trainer.train()

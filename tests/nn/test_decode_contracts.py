@@ -40,7 +40,7 @@ def gqa_setup():
     b = 1
     x4 = jax.random.normal(jax.random.PRNGKey(0), (b, 4, 32))
     cos, sin = _rope(b, 4, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x4, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x4, cos, sin)
     # init ran a forward, so its cache is warm — tests start from zeros
     fresh = jax.tree.map(jnp.zeros_like, variables["cache"])
     return blk, x4, cos, sin, {"params": variables["params"],

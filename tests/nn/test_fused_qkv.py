@@ -40,8 +40,8 @@ def test_fused_qkv_matches_unfused_params_and_outputs():
                                dtype=jnp.float32)
     m_fused = Qwen3DenseCausalLM(config=_cfg(True), sdpa=eager_sdpa,
                                  dtype=jnp.float32)
-    p_ref = m_ref.init(jax.random.PRNGKey(0), tokens, pos, labels)
-    p_fused = m_fused.init(jax.random.PRNGKey(0), tokens, pos, labels)
+    p_ref = jax.jit(m_ref.init)(jax.random.PRNGKey(0), tokens, pos, labels)
+    p_fused = jax.jit(m_fused.init)(jax.random.PRNGKey(0), tokens, pos, labels)
 
     # identical parameter pytree: same paths, shapes, and init values
     ref_leaves = jax.tree_util.tree_leaves_with_path(p_ref)
@@ -79,7 +79,7 @@ def test_fused_qkv_rejects_tp_mesh():
     m = Qwen3DenseCausalLM(config=_cfg(True), sdpa=eager_sdpa,
                            dtype=jnp.float32)
     with pytest.raises(ValueError, match="fused_qkv"):
-        m.init(jax.random.PRNGKey(0), tokens, pos, labels)
+        jax.jit(m.init)(jax.random.PRNGKey(0), tokens, pos, labels)
 
 
 @pytest.fixture

@@ -4,6 +4,8 @@ leaves the state untouched; the cache leaves lead with the batch
 dimension; in a bf16 mixer only the projections' operands and the conv
 tail are bf16, the state and everything between the projections float32."""
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -22,9 +24,19 @@ def _mixer(dtype=jnp.float32, **kwargs):
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _apply(dtype=jnp.float32, decode=False):
+    """The mixer's ``apply``, one compiled program a shape (un-jitted it
+    is a compile an operation a shape, a step at a time)."""
+    return jax.jit(functools.partial(
+        _mixer(dtype, decode=decode).apply,
+        mutable=["cache"] if decode else False,
+    ))
+
+
 def _setup(seed=0):
     u = jax.random.normal(jax.random.PRNGKey(seed), (B, T, E))
-    params = nn.unbox(_mixer().init(jax.random.PRNGKey(1), u)["params"])
+    params = nn.unbox(jax.jit(_mixer().init)(jax.random.PRNGKey(1), u)["params"])
     # the norms' weights off one, so that a forgotten norm shows
     params = jax.tree.map(
         lambda p: p * 1.1 if p.ndim == 1 and p.shape[0] in (4, 8) else p,
@@ -35,15 +47,13 @@ def _setup(seed=0):
 
 def _decode(params, pieces, masks=None):
     """The pieces through one decode-mode cache, outputs joined."""
-    mixer, cache, outs = _mixer(decode=True), None, []
+    cache, outs = None, []
     for i, piece in enumerate(pieces):
         variables = {"params": params}
         if cache is not None:
             variables["cache"] = cache
-        out, state = mixer.apply(
-            variables, piece, None if masks is None else masks[i],
-            mutable=["cache"],
-        )
+        out, state = _apply(decode=True)(
+            variables, piece, None if masks is None else masks[i])
         cache = state["cache"]
         outs.append(out)
     return jnp.concatenate(outs, axis=1), cache
@@ -52,7 +62,7 @@ def _decode(params, pieces, masks=None):
 @pytest.mark.parametrize("prefill", [1, 5, 20])
 def test_prefill_then_one_token_a_step_is_the_full_forward(prefill):
     u, params = _setup()
-    full = _mixer().apply({"params": params}, u)
+    full = _apply()({"params": params}, u)
     pieces = [u[:, :prefill]] + [u[:, t:t + 1] for t in range(prefill, T)]
     out, _ = _decode(params, pieces)
     np.testing.assert_allclose(out, full, rtol=2e-5, atol=2e-5)
@@ -61,7 +71,7 @@ def test_prefill_then_one_token_a_step_is_the_full_forward(prefill):
 @pytest.mark.parametrize("sizes", [(8, 8, 5), (3, 17, 1), (10, 11)])
 def test_chunked_prefill_carries_state_and_tail(sizes):
     u, params = _setup(1)
-    full = _mixer().apply({"params": params}, u)
+    full = _apply()({"params": params}, u)
     cuts = np.cumsum((0,) + sizes)
     out, _ = _decode(params, [u[:, a:b] for a, b in zip(cuts, cuts[1:])])
     np.testing.assert_allclose(out, full, rtol=2e-5, atol=2e-5)
@@ -93,8 +103,8 @@ def test_left_padding_leaves_the_state_untouched():
     real = u[:1, pad:]
     mask = (jnp.arange(T) >= pad)[None]
     garbage = u[:1].at[:, :pad].multiply(50.0)
-    full = _mixer().apply({"params": params}, real)
-    padded = _mixer().apply({"params": params}, garbage, mask)
+    full = _apply()({"params": params}, real)
+    padded = _apply()({"params": params}, garbage, mask)
     np.testing.assert_allclose(padded[:, pad:], full, rtol=2e-5, atol=2e-5)
     # decode mode: the padding alone, then the real tokens one at a time
     _, after_pad = _decode(params, [garbage[:, :pad]], [mask[:, :pad]])
@@ -110,9 +120,8 @@ def test_left_padding_leaves_the_state_untouched():
 
 def test_initialisation_is_the_familys():
     _, params = _setup()
-    fresh = nn.unbox(
-        _mixer().init(jax.random.PRNGKey(7), jnp.zeros((1, 4, E)))["params"]
-    )
+    fresh = nn.unbox(jax.jit(_mixer().init)(
+        jax.random.PRNGKey(7), jnp.zeros((1, 4, E)))["params"])
     a = -np.exp(np.asarray(fresh["A_log"]))
     np.testing.assert_allclose(
         a, -np.tile(np.arange(1.0, 9.0), (2 * E, 1)), rtol=1e-6
@@ -131,10 +140,9 @@ def test_a_bf16_mixer_keeps_its_state_in_float32():
     u, params = _setup(4)
     half = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
     back = jax.tree.map(lambda p: p.astype(jnp.float32), half)
-    want = _mixer().apply({"params": back}, u)
-    got, state = _mixer(jnp.bfloat16, decode=True).apply(
-        {"params": half}, u.astype(jnp.bfloat16), mutable=["cache"]
-    )
+    want = _apply()({"params": back}, u)
+    got, state = _apply(jnp.bfloat16, decode=True)(
+        {"params": half}, u.astype(jnp.bfloat16))
     assert got.dtype == jnp.bfloat16
     assert state["cache"]["ssm_state"].dtype == jnp.float32
     assert state["cache"]["conv_tail"].dtype == jnp.bfloat16
@@ -149,13 +157,10 @@ def test_a_bf16_step_follows_a_bf16_prefill():
     u, params = _setup(5)
     half = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
     u = u.astype(jnp.bfloat16)
-    mixer = _mixer(jnp.bfloat16, decode=True)
-    whole, _ = mixer.apply({"params": half}, u, mutable=["cache"])
-    _, state = mixer.apply({"params": half}, u[:, :-1], mutable=["cache"])
-    last, _ = mixer.apply(
-        {"params": half, "cache": state["cache"]}, u[:, -1:],
-        mutable=["cache"],
-    )
+    mixer = _apply(jnp.bfloat16, decode=True)
+    whole, _ = mixer({"params": half}, u)
+    _, state = mixer({"params": half}, u[:, :-1])
+    last, _ = mixer({"params": half, "cache": state["cache"]}, u[:, -1:])
     np.testing.assert_allclose(
         np.asarray(last[:, 0], np.float32),
         np.asarray(whole[:, -1], np.float32), rtol=0.03, atol=0.03,

@@ -1,5 +1,7 @@
 """MLA + GatedDeltaNet block tests: shapes, causality, grads, variants."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,13 +49,14 @@ class TestMLA:
         b, t = 2, 10
         x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
         cos, sin = _rope(b, t, 8)
-        params = blk.init(jax.random.PRNGKey(1), x, cos, sin)
-        out = blk.apply(params, x, cos, sin)
+        params = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
+        out = jax.jit(blk.apply)(params, x, cos, sin)
         assert out.shape == (b, t, 64)
         if q_lora is not None:
             assert "down_proj" in params["params"]["q_proj"]
 
-        g = jax.grad(lambda p: jnp.sum(blk.apply(p, x, cos, sin) ** 2))(params)
+        g = jax.jit(jax.grad(
+            lambda p: jnp.sum(blk.apply(p, x, cos, sin) ** 2)))(params)
         assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
 
     def test_causality(self):
@@ -61,10 +64,11 @@ class TestMLA:
         b, t = 1, 8
         x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
         cos, sin = _rope(b, t, 8)
-        params = blk.init(jax.random.PRNGKey(1), x, cos, sin)
-        out1 = blk.apply(params, x, cos, sin)
+        params = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
+        apply = jax.jit(blk.apply)
+        out1 = apply(params, x, cos, sin)
         x2 = x.at[:, -1].set(99.0)  # perturb the future
-        out2 = blk.apply(params, x2, cos, sin)
+        out2 = apply(params, x2, cos, sin)
         np.testing.assert_allclose(
             np.asarray(out1[:, :-1]), np.asarray(out2[:, :-1]), atol=1e-5
         )
@@ -92,22 +96,21 @@ class TestMLA:
         )
         x = jax.random.normal(jax.random.PRNGKey(3), (b, t, 64))
         cos, sin = _rope(b, t, 8)
-        params = full.init(jax.random.PRNGKey(1), x, cos, sin)
-        want = full.apply(params, x, cos, sin)
+        params = jax.jit(full.init)(jax.random.PRNGKey(1), x, cos, sin)
+        want = jax.jit(full.apply)(params, x, cos, sin)
 
-        got_pre, state = dec.apply(
-            params, x[:, :p], cos[:, :p], sin[:, :p], mutable=["cache"]
-        )
+        # prefill and the single-token step: one compiled program each
+        decode = jax.jit(functools.partial(dec.apply, mutable=["cache"]))
+        got_pre, state = decode(params, x[:, :p], cos[:, :p], sin[:, :p])
         np.testing.assert_allclose(
             np.asarray(got_pre), np.asarray(want[:, :p]),
             rtol=2e-5, atol=2e-5,
         )
         cache = state["cache"]
         for i in range(p, t):
-            got_i, state = dec.apply(
+            got_i, state = decode(
                 {**params, "cache": cache},
                 x[:, i : i + 1], cos[:, i : i + 1], sin[:, i : i + 1],
-                mutable=["cache"],
             )
             cache = state["cache"]
             np.testing.assert_allclose(
@@ -143,20 +146,21 @@ class TestGatedDeltaNet:
         blk = self._block(gate, hqk, hv)
         b, t = 2, 24
         x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
-        params = blk.init(jax.random.PRNGKey(1), x)
-        out = blk.apply(params, x)
+        params = jax.jit(blk.init)(jax.random.PRNGKey(1), x)
+        out = jax.jit(blk.apply)(params, x)
         assert out.shape == (b, t, 64)
-        g = jax.grad(lambda p: jnp.sum(blk.apply(p, x) ** 2))(params)
+        g = jax.jit(jax.grad(lambda p: jnp.sum(blk.apply(p, x) ** 2)))(params)
         assert all(np.isfinite(np.asarray(l)).all() for l in jax.tree.leaves(g))
 
     def test_causality(self):
         blk = self._block()
         b, t = 1, 16
         x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
-        params = blk.init(jax.random.PRNGKey(1), x)
-        out1 = blk.apply(params, x)
+        params = jax.jit(blk.init)(jax.random.PRNGKey(1), x)
+        apply = jax.jit(blk.apply)
+        out1 = apply(params, x)
         x2 = x.at[:, -1].set(7.0)
-        out2 = blk.apply(params, x2)
+        out2 = apply(params, x2)
         np.testing.assert_allclose(
             np.asarray(out1[:, :-1]), np.asarray(out2[:, :-1]), atol=1e-5
         )
@@ -165,11 +169,12 @@ class TestGatedDeltaNet:
         blk = self._block()
         b, t = 1, 12
         x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
-        params = blk.init(jax.random.PRNGKey(1), x)
+        params = jax.jit(blk.init)(jax.random.PRNGKey(1), x)
         mask = jnp.ones((b, t)).at[:, 6:].set(0.0)
-        out_masked = blk.apply(params, x, mask)
+        apply = jax.jit(blk.apply)
+        out_masked = apply(params, x, mask)
         x_zeroed = x * mask[..., None]
-        out_zeroed = blk.apply(params, x_zeroed, mask)
+        out_zeroed = apply(params, x_zeroed, mask)
         np.testing.assert_allclose(
             np.asarray(out_masked[:, :6]), np.asarray(out_zeroed[:, :6]), atol=1e-5
         )
@@ -179,7 +184,7 @@ class TestGatedDeltaNet:
         x = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 64))
         import flax.linen as nn
 
-        params = nn.unbox(blk.init(jax.random.PRNGKey(1), x))
+        params = nn.unbox(jax.jit(blk.init)(jax.random.PRNGKey(1), x))
         dt_bias = params["params"]["decay_gate"]["dt_bias"]
         dt = np.asarray(jax.nn.softplus(dt_bias))
         assert (dt >= 1e-4 - 1e-9).all() and (dt <= 0.2).all()
@@ -215,7 +220,7 @@ def test_mla_with_ring_attention_matches_eager(devices):
     b, t = 2, 32
     x = jax.random.normal(jax.random.PRNGKey(0), (b, t, 64))
     cos, sin = _rope(b, t, 8)
-    params = block(eager_sdpa).init(jax.random.PRNGKey(1), x, cos, sin)
+    params = jax.jit(block(eager_sdpa).init)(jax.random.PRNGKey(1), x, cos, sin)
 
     def loss_eager(p, x):
         return jnp.sum(jnp.sin(block(eager_sdpa).apply(p, x, cos, sin)))
@@ -227,7 +232,7 @@ def test_mla_with_ring_attention_matches_eager(devices):
     def loss_ring(p, x):
         return jnp.sum(jnp.sin(block(ring).apply(p, x, cos, sin)))
 
-    l_e, g_e = jax.value_and_grad(loss_eager)(params, x)
+    l_e, g_e = jax.jit(jax.value_and_grad(loss_eager))(params, x)
     l_r, g_r = jax.jit(jax.value_and_grad(loss_ring))(params, x_sharded)
     np.testing.assert_allclose(float(l_r), float(l_e), rtol=1e-4, atol=1e-4)
     jax.tree.map(

@@ -23,6 +23,7 @@ from d9d_tpu.ops.moe import (
     sort_tokens_by_expert,
     unpermute_combine,
 )
+from tests.jaxpr_tools import scoped_equations
 
 D, F = 48, 24  # unlike any E, K or N below: a width names its array
 
@@ -111,13 +112,16 @@ def test_float32_outputs_and_gradients_match_the_grouped_form(n, k, e):
                          ids=lambda v: str(v))
 def test_bf16_forms_are_as_close_to_float32_as_each_other(n, k, e):
     x, ids, probs, weights = drawn(n, k, e, seed=3)
-    exact = grouped_form(x, ids, probs, *weights, jnp.float32)
+    def run(form, dtype):
+        return jax.jit(lambda x, weights: form(
+            x.astype(dtype), ids, probs,
+            *(w.astype(dtype) for w in weights), dtype,
+        ))(x, weights)
+
+    exact = run(grouped_form, jnp.float32)
 
     def distance(form):
-        out = form(
-            x.astype(jnp.bfloat16), ids, probs,
-            *(w.astype(jnp.bfloat16) for w in weights), jnp.bfloat16,
-        )
+        out = run(form, jnp.bfloat16)
         gap = out.astype(jnp.float32) - exact
         return float(jnp.sqrt((gap ** 2).mean() / (exact ** 2).mean()))
 
@@ -175,16 +179,6 @@ def test_uneven_routing_matches_the_grouped_form(case):
 # -- what the program holds -----------------------------------------------------
 
 
-def equations(jaxpr, scope=""):
-    """``(equation, scope)`` of ``jaxpr`` and every jaxpr nested in it; the
-    scope is the name stack the lowering joins into an op's ``op_name``."""
-    for eqn in jaxpr.eqns:
-        inner = f"{scope}/{eqn.source_info.name_stack}"
-        yield eqn, inner
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from equations(sub, inner)
-
-
 def layer_program(rows, k, e, **extra):
     layer = MoELayer(
         hidden_dim=D, intermediate_dim_grouped=F, num_grouped_experts=e,
@@ -193,7 +187,7 @@ def layer_program(rows, k, e, **extra):
     x = jax.ShapeDtypeStruct((rows, 1, D), jnp.bfloat16)
     params = jax.eval_shape(
         lambda x: layer.init(jax.random.PRNGKey(0), x)["params"], x)
-    return list(equations(
+    return list(scoped_equations(
         jax.make_jaxpr(lambda p, x: layer.apply({"params": p}, x))(
             params, x).jaxpr
     ))

@@ -17,6 +17,7 @@ from d9d_tpu.nn.moe import (
     held_ladder,
 )
 from d9d_tpu.ops.moe import fold_held, sort_held_pairs, spread_held
+from tests.jaxpr_tools import equations
 
 D, F = 32, 16
 SHARED = SharedExpertParameters(intermediate_size=16, enable_gate=False)
@@ -34,14 +35,25 @@ def layer(routed: int, held: int, first: int, top_k: int, **extra):
 
 
 def whole_layer_params(routed: int, top_k: int, x, shared=None, seed=1):
-    params = nn.meta.unbox(
-        layer(routed, routed, 0, top_k, shared_expert=shared)
-        .init(jax.random.PRNGKey(seed), x)["params"]
-    )
+    params = nn.meta.unbox(jax.jit(
+        layer(routed, routed, 0, top_k, shared_expert=shared).init
+    )(jax.random.PRNGKey(seed), x)["params"])
     params["router"]["e_score_correction_bias"] = jnp.asarray(
         np.random.RandomState(seed).uniform(-0.3, 0.3, routed), jnp.float32
     )
     return params
+
+
+def probed(module, probe):
+    """A layer's output, what it sows into ``moe_stats``, and the
+    gradients of ``(out * probe).sum()`` to the parameters and the input,
+    from one traversal: ``((_, (out, stats)), (d_params, d_x))``."""
+
+    def run(p, x):
+        out, stats = module.apply({"params": p}, x, mutable=["moe_stats"])
+        return (out * probe).sum(), (out, stats)
+
+    return jax.value_and_grad(run, argnums=(0, 1), has_aux=True)
 
 
 def share_of(params, first: int, held: int):
@@ -73,29 +85,30 @@ def test_the_shares_add_up_to_the_uncut_layer(
     params = whole_layer_params(routed, top_k, x, SHARED)
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
 
-    def uncut(p, x):
-        return (whole.apply({"params": p}, x) * probe).sum()
+    def uncut_and_shares(params, x):
+        results = [probed(whole, probe)(params, x)]
+        for first in range(0, routed, held):
+            # the shared expert rides with the first share only
+            shared = SHARED if first == 0 else None
+            part = layer(routed, held, first, top_k, shared_expert=shared)
+            p = share_of(params, first, held)
+            if shared is None:
+                p.pop("shared_expert_module")
+            results.append(probed(part, probe)(p, x))
+        return results
 
-    want = whole.apply({"params": params}, x)
-    want_dp, want_dx = jax.grad(uncut, argnums=(0, 1))(params, x)
+    # One compiled program a case: un-jitted, a share is three traversals
+    # of a compile an operation each, and a ladder's ``lax.switch``
+    # compiles its branches anew every call.
+    ((_, (want, _)), (want_dp, want_dx)), *shares = jax.jit(
+        uncut_and_shares)(params, x)
 
     total = 0.0
     total_dx = 0.0
     gate_grad = 0.0
     held_rows = 0.0
-    for first in range(0, routed, held):
-        # the shared expert rides with the first share only
-        shared = SHARED if first == 0 else None
-        part = layer(routed, held, first, top_k, shared_expert=shared)
-        p = share_of(params, first, held)
-        if shared is None:
-            p.pop("shared_expert_module")
-
-        def cut(p, x, part=part):
-            return (part.apply({"params": p}, x) * probe).sum()
-
-        out, stats = part.apply({"params": p}, x, mutable=["moe_stats"])
-        dp, dx = jax.grad(cut, argnums=(0, 1))(p, x)
+    for first, ((_, (out, stats)), (dp, dx)) in zip(
+            range(0, routed, held), shares):
         total, total_dx = total + out, total_dx + dx
         gate_grad = gate_grad + dp["router"]["gate"]["kernel"]
         held_rows += float(stats["moe_stats"]["rows_held"])
@@ -139,11 +152,13 @@ def test_no_pair_is_dropped_however_uneven_the_routing(skew):
     assert buffers == (40, 56, 64, 80, 104) and passes == 2
     assert skew != "even" or 20 < (ids < held).sum() <= 40
 
+    @jax.jit
     def run(x, probs, weights):
         return held_experts_apply(
             x, local, probs, weights, num_routed=routed, dtype=jnp.float32
         )
 
+    @jax.jit
     def dense(x, probs, weights):
         gate, up, down = weights
         out = jnp.zeros_like(x)
@@ -159,10 +174,12 @@ def test_no_pair_is_dropped_however_uneven_the_routing(skew):
         run(x, probs, weights), dense(x, probs, weights), rtol=1e-4, atol=1e-6
     )
     probe = jnp.asarray(rng.normal(size=(n, D)), jnp.float32)
-    got = jax.grad(lambda *a: (run(*a) * probe).sum(), argnums=(0, 1, 2))(
-        x, probs, weights)
-    want = jax.grad(lambda *a: (dense(*a) * probe).sum(), argnums=(0, 1, 2))(
-        x, probs, weights)
+    got = jax.jit(jax.grad(
+        lambda *a: (run(*a) * probe).sum(), argnums=(0, 1, 2)
+    ))(x, probs, weights)
+    want = jax.jit(jax.grad(
+        lambda *a: (dense(*a) * probe).sum(), argnums=(0, 1, 2)
+    ))(x, probs, weights)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
     if skew == "none_here":
@@ -212,17 +229,15 @@ def test_the_fold_past_its_unrolled_limit_adds_in_the_same_order(monkeypatch):
     np.testing.assert_allclose(unrolled, want, atol=1e-5)
 
 
-def wide_rows(jaxpr, rows: int, width: int, found=None):
+def wide_rows(jaxpr, rows: int, width: int):
     """Equations anywhere in ``jaxpr`` with an operand or result of
     ``rows`` rows and ``width`` or more columns, by primitive."""
-    found = {} if found is None else found
-    for eqn in jaxpr.eqns:
+    found = {}
+    for eqn in equations(jaxpr):
         for var in (*eqn.invars, *eqn.outvars):
             shape = getattr(getattr(var, "aval", None), "shape", ())
             if len(shape) >= 2 and shape[-2] == rows and shape[-1] >= width:
                 found[eqn.primitive.name] = found.get(eqn.primitive.name, 0) + 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            wide_rows(sub, rows, width, found)
     return found
 
 

@@ -15,6 +15,7 @@ from d9d_tpu.core import MeshParameters
 from d9d_tpu.models.deepseek import DeepseekCausalLM, deepseek_v2_tiny
 from d9d_tpu.models.qwen3 import Qwen3MoeCausalLM, Qwen3MoeConfig
 from d9d_tpu.ops.attention.eager import eager_sdpa
+from tests.jaxpr_tools import scoped_equations
 
 # 256 rows a call: past ``ops/moe.py FEW_ROWS_LIMIT``, so the local path is
 # the grouped one these tests read (tests/nn/test_moe_few_rows.py has the other)
@@ -44,9 +45,9 @@ def traced_train_step(family, ep_axes=None):
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, VOCAB, (B, T)), jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    params = model.clone().init(
-        jax.random.PRNGKey(0), tokens, positions, tokens
-    )["params"]
+    params = jax.eval_shape(  # the tree's shapes: a trace needs no numbers
+        lambda: model.clone().init(
+            jax.random.PRNGKey(0), tokens, positions, tokens)["params"])
 
     def loss(p):
         return model.apply({"params": p}, tokens, positions, tokens).sum()
@@ -120,16 +121,12 @@ def test_permute_and_combine_are_scoped_too(ep_axes):
             assert f"{mark}/" in text, mark
 
 
-def row_ops(jaxpr, primitives, scope=""):
+def row_ops(jaxpr, primitives):
     """``(primitive, scope, operand aval)`` of every equation of
-    ``primitives`` in ``jaxpr`` and the jaxprs nested in it; the scope is
-    the name stack the lowering joins into the op's ``op_name``."""
-    for eqn in jaxpr.eqns:
-        inner = f"{scope}/{eqn.source_info.name_stack}"
+    ``primitives`` in ``jaxpr`` and the jaxprs nested in it."""
+    for eqn, scope in scoped_equations(jaxpr):
         if eqn.primitive.name in primitives:
-            yield eqn.primitive.name, inner, eqn.invars[0].aval
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from row_ops(sub, primitives, inner)
+            yield eqn.primitive.name, scope, eqn.invars[0].aval
 
 
 @pytest.mark.parametrize("family,path", [

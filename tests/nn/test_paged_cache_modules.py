@@ -106,7 +106,7 @@ def test_gqa_paged_bitwise_matches_dense():
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 32))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
     zero = jax.tree.map(jnp.zeros_like, variables["cache"])
     want = _drive(blk, variables["params"], _per_row_cache(zero), 8)
     got = _drive(blk, variables["params"], _paged_cache(zero), 8)
@@ -123,7 +123,7 @@ def test_mla_paged_bitwise_matches_dense(absorbed):
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 64))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
     zero = jax.tree.map(jnp.zeros_like, variables["cache"])
     want = _drive(blk, variables["params"], _per_row_cache(zero), 8)
     got = _drive(blk, variables["params"], _paged_cache(zero), 8)
@@ -142,7 +142,7 @@ def test_gqa_paged_quant_drift_bounded(monkeypatch):
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 32))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
     zero = jax.tree.map(jnp.zeros_like, variables["cache"])
     want = _drive(blk, variables["params"], _per_row_cache(zero), 8)
     monkeypatch.setenv("D9D_TPU_DECODE_ATTN", "eager")
@@ -175,7 +175,7 @@ def test_mla_paged_quant_drift_bounded(absorbed):
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 64))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
     zero = jax.tree.map(jnp.zeros_like, variables["cache"])
     want = _drive(blk, variables["params"], _per_row_cache(zero), 8)
     got = _drive(
@@ -196,7 +196,7 @@ def test_paged_quant_pools_stay_int8():
     )
     x = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 32))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x, cos, sin)
     cache = _paged_cache(
         jax.tree.map(jnp.zeros_like, variables["cache"]), quant=True
     )
@@ -220,7 +220,7 @@ def test_paged_contracts_fail_loudly():
     )
     x1 = jax.random.normal(jax.random.PRNGKey(0), (B, 1, 32))
     cos, sin = _rope(B, 0, 1, 8)
-    variables = blk.init(jax.random.PRNGKey(1), x1, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x1, cos, sin)
     paged = _paged_cache(jax.tree.map(jnp.zeros_like, variables["cache"]))
     # multi-token calls never reach a paged cache (the serving loop
     # teacher-forces prompts token-by-token)

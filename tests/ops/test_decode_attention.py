@@ -400,7 +400,7 @@ def test_gqa_module_routes_pallas(monkeypatch):
 
     x4 = jax.random.normal(jax.random.PRNGKey(0), (b, 4, 32))
     cos, sin = rope(0, 4)
-    variables = blk.init(jax.random.PRNGKey(1), x4, cos, sin)
+    variables = jax.jit(blk.init)(jax.random.PRNGKey(1), x4, cos, sin)
     params = variables["params"]
     fresh = jax.tree.map(jnp.zeros_like, variables["cache"])
 

@@ -185,7 +185,7 @@ def test_dispatch_is_differentiable(devices):
         return (out ** 2).sum()
 
     with jax.set_mesh(mesh):
-        gx, gp = jax.grad(loss, argnums=(0, 1))(
+        gx, gp = jax.jit(jax.grad(loss, argnums=(0, 1)))(
             jnp.asarray(x), jnp.asarray(probs)
         )
 
@@ -194,7 +194,7 @@ def test_dispatch_is_differentiable(devices):
         out = (x[:, None, :] * scale[..., None] * probs[..., None]).sum(axis=1)
         return (out ** 2).sum()
 
-    egx, egp = jax.grad(oracle_loss, argnums=(0, 1))(
+    egx, egp = jax.jit(jax.grad(oracle_loss, argnums=(0, 1)))(
         jnp.asarray(x), jnp.asarray(probs)
     )
     np.testing.assert_allclose(gx, egx, rtol=1e-4, atol=1e-4)

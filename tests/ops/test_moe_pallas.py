@@ -429,7 +429,7 @@ class TestLayerIntegration:
             top_k=2,
             dtype=jnp.float32,
         )
-        params = layer.init(jax.random.PRNGKey(0), x)
+        params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
         base = layer.apply(params, x)
         monkeypatch.setenv("D9D_TPU_MOE_FFN", "pallas")
         fused = layer.apply(params, x)

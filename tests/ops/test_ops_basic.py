@@ -14,6 +14,7 @@ from d9d_tpu.ops import (
     rms_norm,
     silu_mul,
 )
+from tests.jaxpr_tools import count, equations
 
 
 def rng(*shape, seed=0, dtype=jnp.float32):
@@ -241,19 +242,11 @@ class TestLinearCrossEntropy:
         assert np.isfinite(np.asarray(out)).all()
 
 
-def _all_eqns(jaxpr):
-    """Every equation of a jaxpr, through scans, remats and calls."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            yield from _all_eqns(inner)
-
-
 def _loop_carries(jaxpr):
     """Shape and dtype of every scan's loop state (its carry, not what
     it scans)."""
     shapes = []
-    for eqn in _all_eqns(jaxpr):
+    for eqn in equations(jaxpr):
         if eqn.primitive.name == "scan":
             consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
             shapes += [
@@ -266,7 +259,7 @@ def _loop_carries(jaxpr):
 def _product_shapes(jaxpr):
     return [
         eqn.outvars[0].aval.shape
-        for eqn in _all_eqns(jaxpr)
+        for eqn in equations(jaxpr)
         if eqn.primitive.name == "dot_general"
     ]
 
@@ -340,7 +333,7 @@ class TestLinearCrossEntropyVocabBlocks:
                 )
                 assert loss.shape == (n,) and loss.dtype == jnp.float32
                 return (loss * cot).sum()
-            return jax.value_and_grad(total, argnums=(0, 1))(h, w)
+            return jax.jit(jax.value_and_grad(total, argnums=(0, 1)))(h, w)
 
         from unittest import mock
 
@@ -359,9 +352,9 @@ class TestLinearCrossEntropyVocabBlocks:
             blocks.reset_mock()
             chunked, chunked_grads = fused(16)
             assert chunks.called and not blocks.called
-        dense, dense_grads = jax.value_and_grad(self._dense, argnums=(0, 1))(
-            h, w, labels, cot, softcap
-        )
+        dense, dense_grads = jax.jit(
+            jax.value_and_grad(self._dense, argnums=(0, 1)), static_argnums=4
+        )(h, w, labels, cot, softcap)
 
         # bf16: the logits carry the operands' rounding (the policy), the
         # gradients one rounding to the parameter's dtype on top
@@ -400,14 +393,15 @@ class TestLinearCrossEntropyVocabBlocks:
         h, w, labels, cot = self._inputs(n, v, dtype)
 
         def grads(chunk):
-            return jax.grad(
+            return jax.jit(jax.grad(
                 lambda h, w: (
                     linear_cross_entropy(h, w, labels, chunk_size=chunk) * cot
                 ).sum(),
                 argnums=(0, 1),
-            )(h, w)
+            ))(h, w)
 
-        dense = jax.grad(self._dense, argnums=(0, 1))(h, w, labels, cot, None)
+        dense = jax.jit(jax.grad(self._dense, argnums=(0, 1)), static_argnums=4)(
+            h, w, labels, cot, None)
         err = lambda got, ref: float(
             jnp.abs(got.astype(jnp.float32) - ref).mean()
         )
@@ -472,12 +466,12 @@ class TestLinearCrossEntropyVocabBlocks:
         assert not whole & {shape for shape, _ in carries}
         assert all(
             var.aval.shape != (n, v) and var.aval.shape != (v, n)
-            for eqn in _all_eqns(blocks) for var in eqn.outvars
+            for eqn in equations(blocks) for var in eqn.outvars
         )
         # every block of the weight's gradient is one product over all
         # the tokens: its contraction is n long
         block_products = [
-            eqn for eqn in _all_eqns(blocks)
+            eqn for eqn in equations(blocks)
             if eqn.primitive.name == "dot_general"
             and eqn.outvars[0].aval.shape[-1] == d
             and eqn.outvars[0].aval.shape != (n, d)
@@ -738,9 +732,10 @@ class TestRowMovementTransposes:
             y = jnp.tanh(rows) * row_probs[:, None]
             return (moe_ops.unpermute_combine(y, sort, self.N) ** 2).sum()
 
-        jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, probs))
-        assert "scatter" not in jaxpr
-        assert jaxpr.count("gather") >= 6  # three forward, three transposed
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, probs).jaxpr
+        assert not count(jaxpr, lambda eqn: "scatter" in eqn.primitive.name)
+        # three forward, three transposed
+        assert count(jaxpr, lambda eqn: eqn.primitive.name == "gather") >= 6
 
     def test_forward_only_program_is_the_plain_take(self, monkeypatch):
         """Without a gradient the given transposes leave no mark: serving

@@ -14,6 +14,7 @@ pytestmark = pytest.mark.e2e  # slow tier: heavy kernel/e2e parity
 
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.ops.attention.pallas_flash import make_pallas_flash_sdpa
+from tests.jaxpr_tools import equations
 
 
 def rng(*shape, seed=0):
@@ -24,8 +25,8 @@ flash = make_pallas_flash_sdpa(block_q=16, block_kv=16)
 
 
 def check(q, k, v, rtol=2e-3, atol=2e-3, **kw):
-    out_f = flash(q, k, v, **kw)
-    out_e = eager_sdpa(q, k, v, **kw)
+    out_f = jax.jit(lambda q, k, v: flash(q, k, v, **kw))(q, k, v)
+    out_e = jax.jit(lambda q, k, v: eager_sdpa(q, k, v, **kw))(q, k, v)
     np.testing.assert_allclose(out_f, out_e, rtol=rtol, atol=atol)
 
 
@@ -101,8 +102,8 @@ class TestBackward:
             return (eager_sdpa(q, k, v, sinks=s, **kw) ** 2).sum()
 
         argnums = (0, 1, 2, 3) if sinks is not None else (0, 1, 2)
-        gf = jax.grad(loss_flash, argnums=argnums)(q, k, v, sinks)
-        ge = jax.grad(loss_eager, argnums=argnums)(q, k, v, sinks)
+        gf = jax.jit(jax.grad(loss_flash, argnums=argnums))(q, k, v, sinks)
+        ge = jax.jit(jax.grad(loss_eager, argnums=argnums))(q, k, v, sinks)
         for a, b in zip(gf, ge):
             np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
 
@@ -165,8 +166,8 @@ class TestSegments:
                                kv_segments=seg, **kw) ** 2).sum()
 
         argnums = (0, 1, 2, 3) if sinks is not None else (0, 1, 2)
-        gf = jax.grad(loss_flash, argnums=argnums)(q, k, v, sinks)
-        ge = jax.grad(loss_eager, argnums=argnums)(q, k, v, sinks)
+        gf = jax.jit(jax.grad(loss_flash, argnums=argnums))(q, k, v, sinks)
+        ge = jax.jit(jax.grad(loss_eager, argnums=argnums))(q, k, v, sinks)
         for a, b in zip(gf, ge):
             np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
 
@@ -220,8 +221,8 @@ class TestAttentionBlock:
             return (eager_sdpa(q, k, v, q_segments=seg,
                                kv_segments=seg, **kw) ** 2).sum()
 
-        lc, gc = jax.value_and_grad(loss_chunked, (0, 1, 2))(q, k, v)
-        le, ge = jax.value_and_grad(loss_full, (0, 1, 2))(q, k, v)
+        lc, gc = jax.jit(jax.value_and_grad(loss_chunked, (0, 1, 2)))(q, k, v)
+        le, ge = jax.jit(jax.value_and_grad(loss_full, (0, 1, 2)))(q, k, v)
         np.testing.assert_allclose(lc, le, rtol=2e-3, atol=2e-3)
         for a, b_ in zip(gc, ge):
             np.testing.assert_allclose(a, b_, rtol=5e-3, atol=5e-3)
@@ -278,8 +279,10 @@ class TestFusedBackward:
                       kv_segments=seg, **kw) ** 2).sum()
 
         argnums = (0, 1, 2, 3) if sinks is not None else (0, 1, 2)
-        gf = jax.grad(lambda *a: loss(fused, *a), argnums)(q, k, v, sinks)
-        gs = jax.grad(lambda *a: loss(split, *a), argnums)(q, k, v, sinks)
+        gf = jax.jit(
+            jax.grad(lambda *a: loss(fused, *a), argnums))(q, k, v, sinks)
+        gs = jax.jit(
+            jax.grad(lambda *a: loss(split, *a), argnums))(q, k, v, sinks)
         for a, b in zip(gf, gs):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -300,25 +303,20 @@ class TestFusedBackward:
             )
             return (o.astype(jnp.float32) ** 2).sum() + lse.sum()
 
-        g_split = jax.grad(loss, (0, 1, 2))(q, k, v, False)
-        g_fused = jax.grad(loss, (0, 1, 2))(q, k, v, True)
+        grads = jax.jit(jax.grad(loss, (0, 1, 2)), static_argnums=3)
+        g_split = grads(q, k, v, False)
+        g_fused = grads(q, k, v, True)
         for a, b in zip(g_fused, g_split):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 def _pallas_grids(fn, *args):
     """The grids of the Pallas calls ``fn`` traces to, in program order."""
-    grids = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                grids.append(tuple(eqn.params["grid_mapping"].grid))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return grids
+    return [
+        tuple(eqn.params["grid_mapping"].grid)
+        for eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    ]
 
 
 class TestWindowBand:
@@ -368,8 +366,8 @@ class TestWindowBand:
                 return (o ** 2).sum(), o
 
             argnums = (0, 1, 2, 3) if sinks is not None else (0, 1, 2)
-            (_, o), grads = jax.value_and_grad(
-                loss, argnums=argnums, has_aux=True)(q, k, v, sinks)
+            (_, o), grads = jax.jit(jax.value_and_grad(
+                loss, argnums=argnums, has_aux=True))(q, k, v, sinks)
             return o, grads
 
         o_f, g_f = out_and_grads(sdpa)

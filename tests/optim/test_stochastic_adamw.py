@@ -23,6 +23,7 @@ from d9d_tpu.ops.stochastic import (
 )
 from d9d_tpu.optim import StochasticAdamW
 from d9d_tpu.telemetry.audit_capture import _collective_census
+from tests.jaxpr_tools import equations
 
 
 class TestStochasticRounding:
@@ -180,13 +181,6 @@ class TestRoundingWithField:
         assert np.isposinf(o[0]) and np.isneginf(o[1]) and np.isnan(o[2])
 
 
-def _eqn_names(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqn_names(sub)
-
-
 def _ups(a):
     """1 where a two-valued array holds its upper value."""
     a = np.asarray(a.astype(jnp.float32))
@@ -227,9 +221,9 @@ class TestOneBlockPerElement:
         }
         grads = jax.tree.map(lambda p: jnp.ones(p.shape, jnp.float32), params)
         opt = StochasticAdamW(1e-2, moment_dtype=moment_dtype)
-        names = list(_eqn_names(
+        names = [eqn.primitive.name for eqn in equations(
             jax.make_jaxpr(opt.update)(grads, opt.init(params), params).jaxpr
-        ))
+        )]
         draws = [n for n in names if n in ("threefry2x32", "random_bits")]
         # the fp32 leaf with fp32 moments rounds nothing, but its block is
         # in the jaxpr until XLA drops it: one per leaf, never three
