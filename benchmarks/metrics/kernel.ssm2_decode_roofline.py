@@ -1,0 +1,50 @@
+"""The Mamba-2 mixers' decode step as a share of its roofline, over the
+traced chunks: the least time the chip could take for the state (a
+matrix a head, float32, read and written), the convolution tails, the
+step's operands and the mixers' projections of every Mamba-2 layer
+(``ssm2_decode_cost``: all slots, every traced step) over the device
+time of the ops under the mixers' module scope (``mamba``), the scope
+``model.decode_ssm_device_pct`` takes.
+
+The whole mixer and not the ops under ``mamba/conv`` and
+``mamba/state_update`` alone, as ``kernel.ssm_decode_roofline`` is built
+and for the reason its docstring gives: the compiler brings the state
+into VMEM under the neighbouring projections, and the time of the two
+scopes alone would leave out part of the work.
+
+The traced chunks are counted from their ``serve/step`` spans, which
+carry ``recurrent_state_bytes`` in a program that has such layers; a
+program whose spans carry none, a configuration without the family's
+keys (``mamba_n_heads``: Mamba-1's files have none), or a trace with no
+op under that scope, gives nothing to read."""
+
+from benchmarks.harness import costs, layers
+from benchmarks.harness import trace as tr
+from benchmarks.metrics import ssm2_decode_cost
+
+MIXER = r"/mamba/"
+
+
+def read(run):
+    traced = getattr(run.observed, "traced", None)
+    if run.trace is None or not run.trace["devices"] or not traced:
+        return None
+    if "mamba_n_heads" not in run.hf:
+        return None
+    chunks = [
+        s for s in layers.spans_between(
+            layers.program_spans(), *traced, names={"serve/step"}
+        ) if s.meta and s.meta.get("recurrent_state_bytes")
+    ]
+    seconds = layers.scope_seconds(run.trace, run.scopes, MIXER)
+    if not chunks or not seconds:
+        return None
+    work = ssm2_decode_cost.ssm2_decode_work(
+        run.hf, slots=run.observed.slots,
+        steps=len(chunks) * run.observed.chunk_k,
+    )
+    least, bound = costs.roofline_seconds(work, run.peak)
+    run.notes["ssm2_decode.bound"] = bound
+    run.notes["ssm2_decode.traced_chunks"] = len(chunks)
+    run.notes["ssm2_decode.device_s"] = seconds
+    return 100.0 * tr.roofline_share(least, seconds)

@@ -63,6 +63,35 @@ class MLAParameters:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2Parameters:
+    """Geometry of the ``"mamba2"`` kind's mixer (``nn/mamba.py
+    Mamba2Mixer``): ``num_heads`` heads of ``head_dim`` channels whose
+    decay, skip and step bias are one number a head, a state of
+    ``d_state`` numbers a channel, B and C shared by the heads of each of
+    ``n_groups`` groups, ``d_conv`` taps, and the prefill scan's chunk."""
+
+    num_heads: int
+    head_dim: int
+    d_state: int = 128
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk_size: int = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Constants on the stack's path (the Granite family's four): on the
+    embedding table's output, on both residual branches of every layer, a
+    divisor on the head's logits (in the fused loss too), and the softmax
+    scale of the GQA kinds where it is not ``head_dim ** -0.5``."""
+
+    embedding: float = 1.0
+    residual: float = 1.0
+    logits_divisor: float = 1.0
+    attention_softmax_scale: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     """One more grouped-query attention kind of a stack that mixes them
     (``Qwen3MoeConfig.attention_kinds``), by what it changes from the
@@ -88,8 +117,10 @@ class AttentionKind:
 
 # the token-mixer kinds a layer can be without an entry in
 # ``attention_kinds``: grouped-query attention with the plain fields,
-# latent attention (``mla``), a Mamba-1 mixer, a GatedDeltaNet block
-LAYER_KINDS = ("attention", "mla", "mamba", "gdn")
+# latent attention (``mla``), a Mamba-1 mixer, a Mamba-2 mixer, a
+# GatedDeltaNet block
+LAYER_KINDS = ("attention", "mla", "mamba", "mamba2", "gdn")
+STATE_SPACE_KINDS = ("mamba", "mamba2")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,6 +211,10 @@ class Qwen3MoeConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # the ``"mamba2"`` kind's block (models/granite/) and the stack's
+    # constant multipliers: one block a mechanism, see the two classes
+    mamba2: Optional[Mamba2Parameters] = None
+    multipliers: Multipliers = Multipliers()
     # the output head reads the embedding table (no head parameters);
     # the table is then drawn at embedding_init_std, the family's
     # initializer_range, so that logits are of order 1 at init
@@ -246,6 +281,13 @@ class Qwen3MoeConfig:
         if layer_idx in self.linear_attention_layers:
             return "gdn"
         return "attention" if self.mla is None else "mla"
+
+    @property
+    def has_state_space_layers(self) -> bool:
+        return any(
+            self.layer_kind(i) in STATE_SPACE_KINDS
+            for i in range(self.num_layers)
+        )
 
     def attention_kind(self, kind: str) -> AttentionKind:
         """The settings of GQA kind ``kind``, every field filled in."""
@@ -398,6 +440,18 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="mamba",
             )(normed, padding_mask)
+        elif kind == "mamba2":
+            from d9d_tpu.nn.mamba import Mamba2Mixer
+
+            attn_out = Mamba2Mixer(
+                hidden_size=cfg.hidden_size,
+                **dataclasses.asdict(cfg.mamba2),
+                norm_eps=cfg.norm_eps,
+                decode=self.decode_max_length > 0,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                name="mamba",
+            )(normed, padding_mask)
         elif kind == "gdn":
             from d9d_tpu.nn.linear_attention import GatedDeltaNet
 
@@ -463,11 +517,16 @@ class Qwen3MoeDecoderLayer(nn.Module):
                     rope_fraction=own.rope_fraction,
                     window_size=own.window_size,
                     use_sinks=own.use_sinks,
+                    softmax_scale=cfg.multipliers.attention_softmax_scale,
                     decode_max_length=self.decode_max_length,
                     dtype=self.dtype,
                     param_dtype=self.param_dtype,
                     name="self_attn",
                 )(normed, cos, sin, mask)
+        branch = cfg.multipliers.residual
+        if branch != 1.0:
+            # on the branch in the stream's type, before the addition
+            attn_out = branch * attn_out.astype(x.dtype)
         if hc:
             x = attn_hc.write(x, attn_out, attn_mix)
             mlp_hc = hc("mlp_mhc")
@@ -508,6 +567,8 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="mlp",
             )(h)
+        if branch != 1.0:
+            mlp_out = branch * mlp_out.astype(x.dtype)
         if hc:
             return mlp_hc.write(x, mlp_out, mlp_mix)
         return x + mlp_out
@@ -599,7 +660,9 @@ class Qwen3MoeBackbone(nn.Module):
         stage ``with_prenorm`` also returns what the final norm was given
         (the multi-token-prediction module's input)."""
         cfg = self.config
-        stream = jnp.float32 if cfg.mamba_layers else self.dtype
+        stream = (
+            jnp.float32 if cfg.has_state_space_layers else self.dtype
+        )
         if self.stage.is_first:
             x = TokenEmbedding(
                 vocab_ranges=cfg.vocab_ranges,
@@ -609,6 +672,8 @@ class Qwen3MoeBackbone(nn.Module):
                 param_dtype=self.param_dtype,
                 name="embed_tokens",
             )(x)
+            if cfg.multipliers.embedding != 1.0:
+                x = cfg.multipliers.embedding * x
             if cfg.hc_mult > 1:
                 x = expand_streams(x, cfg.hc_mult)
         else:
@@ -773,6 +838,7 @@ class Qwen3MoeCausalLM(nn.Module):
                 vocab_ranges=self.config.vocab_ranges,
                 hidden_size=self.config.hidden_size,
                 ce_chunk_size=self.ce_chunk_size,
+                logits_divisor=self.config.multipliers.logits_divisor,
                 tied=tied,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,

@@ -20,7 +20,7 @@ from d9d_tpu.nn.linear_attention import (
     LogSigmoidDecayGate,
     MambaDecayGate,
 )
-from d9d_tpu.nn.mamba import MambaMixer
+from d9d_tpu.nn.mamba import Mamba2Mixer, MambaMixer
 from d9d_tpu.nn.mlp import SwiGLU
 from d9d_tpu.nn.moe import (
     GroupedSwiGLU,
@@ -50,6 +50,7 @@ __all__ = [
     "GatedDeltaNet",
     "LogSigmoidDecayGate",
     "MambaDecayGate",
+    "Mamba2Mixer",
     "MambaMixer",
     "SwiGLU",
     "GroupedSwiGLU",

@@ -35,6 +35,9 @@ class LanguageModellingHead(nn.Module):
     hidden_size: int
     ce_chunk_size: "int | str" = "auto"
     logit_softcap: float | None = None
+    # the logits are the products over this constant (Granite's
+    # ``logits_scaling``), in the loss and in ``logits`` alike
+    logits_divisor: float = 1.0
     tied: bool = False
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
@@ -64,6 +67,9 @@ class LanguageModellingHead(nn.Module):
         """hidden [B,T,D], labels [B,T] → per-token loss [B,T] (fp32)."""
         w = self._weight(table)
         b, t, d = hidden.shape
+        if self.logits_divisor != 1.0:
+            # (h / s) W^T = (h W^T) / s: the fused loss never holds logits
+            hidden = hidden.astype(jnp.float32) / self.logits_divisor
         # CE matmul policy follows the activation dtype (linear_ce default):
         # bf16 models take the full-rate MXU path, fp32 models stay exact
         loss = linear_cross_entropy(
@@ -77,7 +83,10 @@ class LanguageModellingHead(nn.Module):
 
     def logits(self, hidden: Array, table: Optional[Array] = None) -> Array:
         w = self._weight(table)
-        return hidden.astype(jnp.float32) @ w.astype(jnp.float32).T
+        logits = hidden.astype(jnp.float32) @ w.astype(jnp.float32).T
+        if self.logits_divisor != 1.0:
+            logits = logits / self.logits_divisor
+        return logits
 
 
 class ClassificationHead(nn.Module):

@@ -315,8 +315,10 @@ def held_ladder(
     ``passes`` chunks of tokens, the fewest whose every pair fits that last
     buffer: dropless whatever the routing, and never ``num_tokens * top_k``
     rows in one buffer. A call of so few tokens (a decode step) that the
-    smallest rung would hold every pair gets one buffer of all its pairs
-    and ``passes`` 1.
+    smallest rung would hold every pair, or that one token's ``top_k``
+    pairs alone overflow the last rung (a one-row ``generate`` step at
+    top-10 of a four-way share), gets one buffer of all its pairs and
+    ``passes`` 1.
     """
     share = num_routed // num_held
     pairs = num_tokens * top_k
@@ -328,7 +330,7 @@ def held_ladder(
             break
         buffers.append(rows)
         factor *= _HELD_RUNG_RATIO
-    if not buffers:
+    if not buffers or top_k > buffers[-1]:
         return (pairs,), 1
     passes = next(
         p for p in range(2, num_tokens + 1)

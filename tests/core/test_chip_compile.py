@@ -417,6 +417,41 @@ def test_mamba_step_at_jamba2_3b_widths(topo):
     assert ma.temp_size_in_bytes < state_bytes / 2
 
 
+def test_mamba2_step_at_granite_4_0_h_small_widths(topo):
+    """The Mamba-2 mixer's one-token step for 128 rows at the published
+    widths (128 heads of 64, state 128, one group): the float32 state (a
+    matrix a head, 537 MB) and the conv tail are updated in place when
+    the cache is donated, and the step's temporaries stay a fraction of
+    the state."""
+    import flax.linen as nn
+
+    from d9d_tpu.nn.mamba import Mamba2Mixer
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    mixer = Mamba2Mixer(
+        hidden_size=4096, num_heads=128, head_dim=64, d_state=128,
+        decode=True, dtype=BF16, param_dtype=BF16,
+    )
+    abstract = nn.unbox(jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros((128, 1, 4096), BF16))
+    ))
+    variables = jax.tree.map(lambda a: sds(a.shape, a.dtype), abstract)
+    assert variables["cache"]["ssm_state"].shape == (128, 128, 64, 128)
+    assert variables["cache"]["conv_tail"].shape == (128, 3, 8448)
+    state_bytes = 128 * 128 * 64 * 128 * 4
+    compiled = jax.jit(
+        lambda cache, params, u: mixer.apply(
+            {"params": params, "cache": cache}, u, mutable=["cache"]
+        ),
+        donate_argnums=0,
+    ).lower(
+        variables["cache"], variables["params"], sds((128, 1, 4096), BF16)
+    ).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= state_bytes  # updated in place
+    assert ma.temp_size_in_bytes < state_bytes / 2
+
+
 # -- the output head's fused cross-entropy -----------------------------------
 
 

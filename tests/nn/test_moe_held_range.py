@@ -311,3 +311,17 @@ def test_a_range_that_is_no_share_is_refused(kwargs, why):
     module = layer(top_k=2, **kwargs)
     with pytest.raises(ValueError, match=why):
         module.init(jax.random.PRNGKey(0), x)
+
+
+@pytest.mark.parametrize("n,k,held,routed,want", [
+    (1, 10, 18, 72, ((10,), 1)),  # a one-row generate step at top-10
+    (1, 4, 4, 16, ((4,), 1)),
+    (128, 10, 18, 72, ((400, 504, 632, 784, 984), 2)),
+])
+def test_one_tokens_pairs_never_overflow_the_last_rung(n, k, held, routed, want):
+    """A call so small that one token's own pairs pass the last one-pass
+    buffer cannot be cut into chunks of tokens: it gets one buffer of all
+    its pairs (the ladder used to find no chunking and raise)."""
+    assert held_ladder(n, k, held, routed) == want
+    buffers, passes = want
+    assert n // passes * k <= max(buffers[-1], n * k)
