@@ -29,6 +29,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from d9d_tpu.core.types import Array
 from d9d_tpu.ops.swiglu import silu_mul
@@ -409,7 +411,8 @@ class HeldSort(NamedTuple):
 
     Two orders over the same pairs: *rows* are expert-sorted (what the
     grouped matmul wants), *slots* are token-major (a token's pairs are
-    neighbours, so folding them is a few shifted adds and no scatter).
+    neighbours and a block of tokens owns one run of slots, so folding
+    them is one pass over the runs and no scatter: :func:`fold_held`).
     Rows and slots from ``rows_held`` on are padding.
 
     pair_of_row: [M] flattened (token-major) pair of each row.
@@ -497,51 +500,140 @@ def _spread_held_bwd(top_k, residuals, g):
 spread_held.defvjp(_spread_held_fwd, _spread_held_bwd)
 
 
-# The compiler keeps every shifted float32 copy of :func:`fold_held`'s K - 1
-# adds alive at once (3.48 GB of temporaries for K = 8 over a 50,000-row
-# buffer of 2,048, compiled for a described v5e: 8.5 buffers; 0.82 GB as a
-# loop). Up to this many bytes of such copies they are written out, as every
-# program before PR 44 has them (K = 4 over 12,500 rows of 3,584: 0.54 GB);
-# past it the adds run in a loop
-_FOLD_UNROLLED_LIMIT = 1 << 30
+# A fold's tile: this many tokens' sums accumulate over chunks of this many
+# slots, this many bytes of a row at a time. At 256 x 256 a chunk's DMA
+# (1 MB of bf16 at 2,048 columns) and its product on the MXU take about as
+# long; the buffers (two chunks, two output blocks, the float32 sums and
+# the product beside them) stay under Mosaic's default 16 MiB of scoped VMEM
+# for bf16 and float32 rows alike
+_FOLD_TOKENS = 256
+_FOLD_SLOTS = 256
+_FOLD_ROW_BYTES = 4096
+
+
+def _round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
+def _fold_kernel(block_ref, chunk_ref, items_ref, token_ref, rows_ref,
+                 out_ref, sum_ref):
+    """Work item ``w``: add chunk ``chunk_ref[w]``'s rows to the sums of
+    token block ``block_ref[w]``. A block's items are neighbours; items
+    from ``items_ref[0]`` on are padding that repeats the last one."""
+    w = pl.program_id(1)
+    last = items_ref[0] - 1
+    block = block_ref[w]
+    tokens, slots = sum_ref.shape[0], rows_ref.shape[0]
+
+    @pl.when((w == 0) | (block_ref[jnp.maximum(w - 1, 0)] != block))
+    def _():
+        sum_ref[...] = jnp.zeros_like(sum_ref)
+
+    @pl.when(w <= last)
+    def _():
+        token = block * tokens + lax.broadcasted_iota(
+            jnp.int32, (tokens, slots), 0
+        )
+        # 0/1 times a row is the row: the products are exact, the sums
+        # float32. A float32 row needs every pass of the MXU for that
+        exact = rows_ref.dtype.itemsize >= 4
+        sum_ref[...] += jnp.dot(
+            (token_ref[...] == token).astype(rows_ref.dtype), rows_ref[...],
+            preferred_element_type=jnp.float32,
+            precision=lax.Precision.HIGHEST if exact else None,
+        )
+
+    following = block_ref[jnp.minimum(w + 1, pl.num_programs(1) - 1)]
+
+    @pl.when((w == last) | (following != block))
+    def _():
+        out_ref[...] = sum_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def fold_held(y: Array, held: HeldSort, num_tokens: int, top_k: int) -> Array:
     """Fold the held pairs' buffer rows back to their owning tokens.
 
-    y: [M, D] expert-sorted rows → [N, D]; a token with no held pair gets
-    zeros. One gather brings the rows into slot order, where a token's (at
-    most K) pairs are neighbours: K - 1 shifted adds leave each token's
-    sum in its first slot, and one N-row gather picks those up. No
-    scatter-add, and never ``N*K`` rows. Its transpose is
-    :func:`spread_held`.
+    y: [M, D] expert-sorted rows → [N, D]: a token's float32 sum of its
+    live rows, rounded once to ``y.dtype``; a token with no held pair gets
+    zeros. One gather brings the rows into slot order, in ``y``'s own
+    dtype, where a token's (at most K) pairs are neighbours and a block of
+    tokens owns one contiguous run of slots. A kernel then reads each
+    chunk of slots that a block's run touches and adds it to the block's
+    sums as a 0/1 membership matrix ``[tokens, slots]`` times the chunk:
+    every live row is read once (twice where a chunk straddles two blocks)
+    and every token's row written once, so the cost follows the rows held.
+    No scatter-add, no float32 copy of the buffer, and never ``N*K`` rows.
+    A row that is not finite reaches its chunk's other tokens as NaN
+    (``0 * inf``). Its transpose is :func:`spread_held`. Off the TPU the
+    kernel runs interpreted.
     """
     m, d = y.shape
-    live = jnp.arange(m) < held.rows_held
-    by_slot = jnp.where(
-        live[:, None], jnp.take(y, held.row_of_slot, axis=0), 0
-    ).astype(jnp.float32)
-    token = jnp.where(live, held.token_of_slot, -1)
-    if (top_k - 1) * m * d * 4 <= _FOLD_UNROLLED_LIMIT:
-        total = by_slot
-        for j in range(1, top_k):
-            same = jnp.pad(token[j:], (0, j), constant_values=-2) == token
-            later = jnp.pad(by_slot[j:], ((0, j), (0, 0)))
-            total = total + jnp.where(same[:, None], later, 0)
-    else:
-        # the same adds in the same order, one shifted copy alive at a time
-        padded = jnp.pad(by_slot, ((0, top_k - 1), (0, 0)))
-        tokens = jnp.pad(token, (0, top_k - 1), constant_values=-2)
+    tokens = min(_FOLD_TOKENS, _round_up(num_tokens, 16))
+    slots = min(_FOLD_SLOTS, _round_up(m, 128))
+    columns = max(
+        (c for c in range(128, d + 1, 128)
+         if d % c == 0 and c * y.dtype.itemsize <= _FOLD_ROW_BYTES),
+        default=d,
+    )
+    num_blocks = -(-num_tokens // tokens)
+    num_chunks = -(-m // slots)
+    pad = num_chunks * slots - m
 
-        def add_shifted(j, total):
-            same = lax.dynamic_slice_in_dim(tokens, j, m) == token
-            later = lax.dynamic_slice_in_dim(padded, j, m)
-            return total + jnp.where(same[:, None], later, 0)
+    live = jnp.minimum(held.rows_held, m)
+    # every index is a row of the buffer: clipping them is free where the
+    # default's fill of rows out of range doubles the gather's time
+    by_slot = jnp.take(
+        y, jnp.pad(held.row_of_slot, (0, pad)), axis=0, mode="clip"
+    )
+    token_of_slot = jnp.where(
+        jnp.arange(m + pad) < live, jnp.pad(held.token_of_slot, (0, pad)), -1
+    ).reshape(num_chunks, 1, slots)
 
-        total = lax.fori_loop(1, top_k, add_shifted, by_slot)
-    out = jnp.take(total, jnp.minimum(held.slot_start, m - 1), axis=0)
-    return jnp.where((held.slot_count > 0)[:, None], out, 0).astype(y.dtype)
+    # the work items: for each block of tokens the chunks its run of slots
+    # touches, one (of zeros to add) where it has none. Runs tile
+    # [0, live), so two neighbours share at most one chunk and the items
+    # number at most ``num_chunks - 1 + num_blocks``
+    start = jnp.minimum(held.slot_start[::tokens], live)
+    end = jnp.append(start[1:], live)
+    first_chunk = jnp.minimum(start // slots, num_chunks - 1)
+    last_chunk = jnp.clip((end - 1) // slots, first_chunk, num_chunks - 1)
+    count = last_chunk - first_chunk + 1
+    first_item = jnp.cumsum(count) - count
+    item = jnp.arange(num_chunks - 1 + num_blocks, dtype=jnp.int32)
+    block = jnp.sum(
+        first_item[None, :] <= item[:, None], axis=1, dtype=jnp.int32
+    ) - 1
+    chunk = jnp.minimum(
+        first_chunk[block] + item - first_item[block], last_chunk[block]
+    )
+
+    out = pl.pallas_call(
+        _fold_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(d // columns, item.shape[0]),
+            in_specs=[
+                pl.BlockSpec(
+                    (None, 1, slots), lambda c, w, blk, chk, n: (chk[w], 0, 0)
+                ),
+                pl.BlockSpec(
+                    (slots, columns), lambda c, w, blk, chk, n: (chk[w], c)
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (tokens, columns), lambda c, w, blk, chk, n: (blk[w], c)
+            ),
+            scratch_shapes=[pltpu.VMEM((tokens, columns), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((num_blocks * tokens, d), y.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=jax.default_backend() != "tpu",
+        name="fold_held",
+    )(block, chunk, count.sum().reshape(1), token_of_slot, by_slot)
+    return out[:num_tokens]
 
 
 def _fold_held_fwd(y, held, num_tokens, top_k):

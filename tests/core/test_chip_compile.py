@@ -648,6 +648,36 @@ def test_ep_dispatch_combine_on_four_chips(topo, as_tpu, mode):
     assert n >= (2 if mode == "forward" else 3), n
 
 
+@pytest.mark.parametrize("n,k,held,rows,width,dtype", [
+    (16384, 8, 32, 20480, 2048, BF16),  # the window-layer cell's first rung
+    (8192, 4, 8, 5120, 3584, BF16),  # Xing4.0's
+    (1, 8, 16, 8, 4096, BF16),  # a one-row generate step of MiMo's share
+    (1, 10, 18, 10, 4096, BF16),  # and of Granite's
+    (4096, 8, 32, 5120, 2048, jnp.float32),  # every pass of the MXU
+], ids=["laguna", "xing4.0", "generate-top-8", "generate-top-10", "float32"])
+def test_fold_held_at_the_share_cells_shapes(
+        topo, as_tpu, n, k, held, rows, width, dtype):
+    """A held range's fold (the backward's is the same call on the
+    cotangent's buffer): one Pallas call whatever ``top_k``, the buffer
+    and the dtype, inside Mosaic's default scoped VMEM, and among the
+    program's temporaries the buffer once over in slot order, in its own
+    dtype, never a float32 copy of it."""
+    from d9d_tpu.ops.moe import fold_held, sort_held_pairs
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+
+    def fold(y, local):
+        return fold_held(y, sort_held_pairs(local, held, rows), n, k)
+
+    compiled = jax.jit(fold).lower(
+        sds((rows, width), dtype), sds((n, k), jnp.int32)).compile()
+    assert _pallas_calls(compiled) == 1
+    row_bytes = width * jnp.dtype(dtype).itemsize
+    in_slot_order = -(-rows // 256) * 256 * row_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        in_slot_order + rows * row_bytes // 2)
+
+
 # -- the env-selected fused expert FFN (default stays ``xla``) ---------------
 
 # What the v5e compiler says to the gather variants once the ``unroll=8``

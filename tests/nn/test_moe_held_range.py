@@ -206,27 +206,128 @@ def test_spread_and_fold_are_each_others_transpose():
     np.testing.assert_allclose(fold_held(y, sort, n, k), want, atol=1e-5)
 
 
-def test_the_fold_past_its_unrolled_limit_adds_in_the_same_order(monkeypatch):
-    """Past ``_FOLD_UNROLLED_LIMIT`` bytes of shifted copies the K - 1
-    adds run in a loop, one copy alive at a time: the same adds in the
-    same order, so the same float32 sums to the bit, and no scatter."""
-    from d9d_tpu.ops import moe as moe_ops
+def held_ids(rng, n, k, held, how):
+    """``[n, k]`` local ids, ``held`` for a pair that lands elsewhere."""
+    if how == "all":
+        return rng.randint(0, held, size=(n, k))
+    if how == "none":
+        return np.full((n, k), held)
+    if how == "mixed":
+        # a third of the tokens hold every pair, a third none
+        ids = rng.randint(0, 3 * held, size=(n, k))
+        ids[: n // 3] = rng.randint(0, held, size=(n // 3, k))
+        ids[n // 3: 2 * (n // 3)] = held
+        return ids.clip(max=held)
+    return rng.randint(0, 4 * held, size=(n, k)).clip(max=held)  # a quarter
 
-    n, k, held, buf = 40, 8, 4, 96
-    rng = np.random.RandomState(2)
-    local = jnp.asarray(rng.randint(0, held + 12, size=(n, k)).clip(max=held))
+
+@pytest.mark.parametrize("n,k,how,buf,dtype", [
+    # every top_k the models route by, a quarter of the pairs held
+    (40, 1, "quarter", 24, jnp.float32),
+    (40, 3, "quarter", 56, jnp.float32),
+    (40, 4, "quarter", 72, jnp.float32),
+    (40, 8, "quarter", 136, jnp.float32),
+    (40, 10, "quarter", 160, jnp.bfloat16),
+    # a one-row generate step: all of one token's pairs, and none of them
+    (1, 8, "all", 8, jnp.bfloat16),
+    (1, 10, "all", 10, jnp.float32),
+    (1, 8, "none", 8, jnp.float32),
+    # nothing held, every row held
+    (48, 4, "none", 80, jnp.float32),
+    (48, 4, "all", 192, jnp.bfloat16),
+    # tokens with every pair and with no pair beside each other
+    (96, 8, "mixed", 400, jnp.float32),
+    # the chunked fallback: room for every pair of its tokens
+    (32, 4, "quarter", 128, jnp.float32),
+    (32, 4, "all", 128, jnp.float32),
+    # several blocks of tokens and chunks of slots, the last chunk part
+    # padding, runs that straddle chunks
+    (600, 3, "quarter", 700, jnp.bfloat16),
+    (600, 3, "mixed", 1000, jnp.float32),
+    (520, 8, "all", 4160, jnp.bfloat16),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_the_fold_is_the_float32_sum_of_a_tokens_live_rows(
+    n, k, how, buf, dtype
+):
+    """Against the plain scatter-add of the live rows in float32, rounded
+    once: one body for every ``top_k``, buffer and dtype."""
+    held = 4
+    rng = np.random.RandomState(n + k)
+    local = jnp.asarray(held_ids(rng, n, k, held, how), jnp.int32)
+    here = np.asarray(local) < held
+    # rows past the live ones must add nothing whatever they hold
+    y = jnp.asarray(rng.normal(size=(buf, D)), dtype)
+
+    @jax.jit
+    def folded_and_scattered(y):
+        sort = sort_held_pairs(local, held, buf)
+        live = (jnp.arange(buf) < sort.rows_held)[:, None]
+        want = jnp.zeros((n, D), jnp.float32).at[sort.token_of_row].add(
+            jnp.where(live, y.astype(jnp.float32), 0))
+        return fold_held(y, sort, n, k), want, sort.rows_held
+
+    got, want, rows = folded_and_scattered(y)
+    assert int(rows) == here.sum() <= buf
+    assert {"all": rows == n * k, "none": rows == 0}.get(how, 0 < rows < n * k)
+    assert got.shape == (n, D) and got.dtype == dtype
+    if dtype == jnp.bfloat16:
+        np.testing.assert_array_equal(
+            got.astype(jnp.float32), want.astype(dtype).astype(jnp.float32))
+    else:
+        np.testing.assert_allclose(got, want, atol=1e-5)
+    assert not np.asarray(got, np.float32)[~here.any(axis=1)].any()
+
+
+def test_bf16_rows_are_summed_in_float32_and_rounded_once():
+    """Eight bf16 rows of a token whose running bf16 sum would lose every
+    addend (256 + 1 rounds back to 256): the fold gives the float32 sum,
+    263, rounded to bf16 once, 264."""
+    n, k, held = 16, 8, 8
+    local = jnp.tile(jnp.arange(k, dtype=jnp.int32), (n, 1))
+    sort = sort_held_pairs(local, held, n * k)
+    ones = jnp.ones((n, k)).at[:, 0].set(256.0)  # by (token, choice)
+    y = jnp.zeros((n * k, D), jnp.bfloat16).at[
+        jnp.argsort(sort.pair_of_row)].set(
+        jnp.broadcast_to(ones.reshape(n * k, 1), (n * k, D)).astype(jnp.bfloat16))
+    running = jnp.zeros((n, D), jnp.bfloat16)
+    for j in range(k):
+        running = running + ones[:, j:j + 1].astype(jnp.bfloat16)
+    assert float(running[0, 0]) == 256.0
+    got = jax.jit(lambda y: fold_held(y, sort, n, k))(y)
+    np.testing.assert_array_equal(got.astype(jnp.float32), 264.0)
+
+
+@pytest.mark.parametrize("k", [4, 8])
+def test_the_fold_is_one_kernel_over_rows_of_their_own_dtype(k):
+    """One body whatever ``top_k``: a gather into slot order in the rows'
+    own dtype and one Pallas call. No float32 array as long as the buffer
+    and as wide as a row, no scatter, no loop of shifted adds outside the
+    kernel."""
+    n, held, buf = 64, 4, 40 * k
+    rng = np.random.RandomState(k)
+    local = jnp.asarray(held_ids(rng, n, k, held, "quarter"), jnp.int32)
     sort = sort_held_pairs(local, held, buf)
-    y = jnp.asarray(rng.normal(size=(buf, D)), jnp.float32)
-    unrolled = fold_held(y, sort, n, k)
-    assert "scan" not in str(jax.make_jaxpr(
-        lambda y: fold_held(y, sort, n, k))(y))
-    monkeypatch.setattr(moe_ops, "_FOLD_UNROLLED_LIMIT", 0)
-    looped = jax.make_jaxpr(lambda y: fold_held(y, sort, n, k))(y)
-    assert "scan" in str(looped) and "scatter" not in str(looped)
-    np.testing.assert_array_equal(fold_held(y, sort, n, k), unrolled)
-    rows = int(sort.rows_held)
-    want = jnp.zeros((n, D)).at[sort.token_of_row[:rows]].add(y[:rows])
-    np.testing.assert_allclose(unrolled, want, atol=1e-5)
+    y = jnp.asarray(rng.normal(size=(buf, D)), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda y: fold_held(y, sort, n, k))(y).jaxpr
+    names = [eqn.primitive.name for eqn in equations(jaxpr)]
+    assert names.count("pallas_call") == 1
+    assert not [name for name in names if "scatter" in name]
+    assert not {"scan", "while"} & {
+        eqn.primitive.name for eqn in jaxpr.eqns}
+    long_float32 = [
+        (eqn.primitive.name, var.aval.shape)
+        for eqn in equations(jaxpr) for var in (*eqn.invars, *eqn.outvars)
+        if getattr(getattr(var, "aval", None), "dtype", None) == jnp.float32
+        and len(var.aval.shape) >= 2 and var.aval.shape[-1] >= D
+        and var.aval.shape[-2] >= buf
+    ]
+    assert long_float32 == []
+    gathered = [
+        eqn.outvars[0].aval for eqn in equations(jaxpr)
+        if eqn.primitive.name == "gather" and eqn.outvars[0].aval.ndim == 2
+    ]
+    assert [(a.shape[1], a.dtype) for a in gathered] == [(D, jnp.bfloat16)]
+    assert buf <= gathered[0].shape[0] < buf + 256  # padded to whole chunks
 
 
 def wide_rows(jaxpr, rows: int, width: int):
