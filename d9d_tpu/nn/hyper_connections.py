@@ -20,15 +20,33 @@ nor shrinks their sum. The stream starts as ``n`` copies of the embedding
 and is read out by the sum over the streams (:func:`expand_streams`,
 :func:`sum_streams`).
 
-The coefficients and the Sinkhorn rounds are float32 with the tokens on
-the minor axis (``[n, n, B, T]``: sixteen numbers a token would waste a
-vector register's tile each); the stream stays in the model's dtype. One
-matmul forms all ``n n + 2 n`` projections, and the norm's ``rsqrt`` is
-applied to its result, so forming the coefficients reads the stream once.
-The ops carry ``mhc/{norm,coef,sinkhorn,pre,post}`` scopes (and the
-stream's two ends ``mhc/{expand,readout}``): a trace tells the stream's
-passes from the sublayers'. The path holds no state, so
-decode mode runs it as it stands.
+The stream's passes are Pallas calls (``ops/mhc.py``), one a pass, each
+holding a tile of tokens' ``n C`` numbers in VMEM, so the stream crosses
+HBM once a pass in the model's dtype and no float32 array of its width
+exists. :meth:`HyperConnection.read` is one call: the norm's float32
+square sum, the ``n n + 2 n`` projections (one product, its operands in
+the module's dtype, float32 accumulation, the norm's ``rsqrt`` applied to
+its result), ``H_pre`` and the sublayer's input. :meth:`HyperConnection
+.write` is one call: ``n (n + 1)`` float32 multiply-adds an element,
+rounded once. Their transposes are given, one call each: the update's
+reads the next stream's cotangent, ``x`` and the sublayer's output and
+gives ``H_res^T d``, the output's cotangent and the per-token sums that
+are ``d H_res`` and ``d H_post``; the read's gives ``d x``, the maps'
+gradient summed over the token tiles and ``d H_pre``'s logits. The two
+cotangents of ``x`` are summed by autodiff, one more pass. Under a mesh
+of several devices the calls run in a ``shard_map`` over the batch and
+sequence axes (a Mosaic call cannot be partitioned by the compiler), the
+maps whole, their gradients summed over those axes.
+
+What stays float32 XLA: ``H_post``, ``H_res`` and the Sinkhorn rounds, on
+the ``n n + 2 n`` numbers a token (0.8 MB at the Xing4.0 cell's sizes: not
+the stream's bytes), with the tokens on the minor axis (``[n, n, B, T]``:
+sixteen numbers a token would waste a vector register's tile each). The
+ops carry ``mhc/{coef,sinkhorn,pre,post}`` scopes (the read's call, which
+fuses the norm, the product and the mix, ``mhc/pre``; the update's
+``mhc/post``) and the stream's two ends ``mhc/{expand,readout}``: a trace
+tells the stream's passes from the sublayers'. The path holds no state, so
+decode mode runs it as it stands (a one-token step is a padded tile).
 
 Initialisers (the papers give none this repo could copy; the benchmark's
 configuration file lists them as assumed): ``phi_*`` normal at
@@ -43,8 +61,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
+from d9d_tpu.core.compat import get_abstract_mesh
+from d9d_tpu.core.mesh import AXIS_CP_SHARD, AXIS_DP_REPLICATE, AXIS_DP_SHARD
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn import logical_axes as la
+from d9d_tpu.ops import mhc
 
 A_INIT = 0.5
 B_RES_DIAGONAL = 2.0
@@ -85,6 +108,58 @@ def sinkhorn(log_weights: Array, iters: int, eps: float) -> Array:
         unroll=min(SINKHORN_UNROLL, iters),
     )
     return m
+
+
+def _axes_dividing(mesh, axes: tuple[str, ...], size: int) -> tuple[str, ...]:
+    """Those of ``axes`` the mesh gives several devices, while their
+    product still divides ``size``."""
+    kept, product = [], 1
+    for axis in axes:
+        devices = mesh.shape.get(axis, 1)
+        if devices > 1 and size % (product * devices) == 0:
+            kept.append(axis)
+            product *= devices
+    return tuple(kept)
+
+
+def _streams_first(x: Array) -> Array:
+    """``[B, T, n, C]`` ↔ ``[B, n, T, C]``, what the calls take: the
+    compiler keeps the stream in the layout that makes this no copy."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _over_tokens(fn, per_token: tuple[Array, ...], whole: tuple[Array, ...]):
+    """``fn(*per_token, *whole)`` on arrays that start ``[B, T]``, its
+    results starting so too. A one-token step's batch goes as the tokens
+    of one row (a tile is of tokens). Under a mesh of
+    several devices each shard of the batch and sequence axes runs ``fn``
+    on its own tokens (a Mosaic kernel cannot be partitioned by the
+    compiler: ``ops/attention/pallas_flash.py _shard_over_mesh``), ``whole``
+    whole on every device and its cotangents summed over those axes."""
+    count = len(per_token)
+
+    def shard(*args):
+        b, t = args[0].shape[:2]
+        if t == 1:
+            args = [
+                a.reshape(1, b, *a.shape[2:]) if i < count else a
+                for i, a in enumerate(args)
+            ]
+        return tuple(o.reshape(b, t, *o.shape[2:]) for o in fn(*args))
+
+    mesh = get_abstract_mesh()
+    if mesh.size <= 1:
+        return shard(*per_token, *whole)
+    b, t = per_token[0].shape[:2]
+    tokens = P(
+        _axes_dividing(mesh, (AXIS_DP_REPLICATE, AXIS_DP_SHARD), b) or None,
+        _axes_dividing(mesh, (AXIS_CP_SHARD,), t) or None,
+    )
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(tokens,) * count + (P(),) * len(whole),
+        out_specs=tokens, check_vma=False,
+    )(*per_token, *whole)
 
 
 class HyperConnection(nn.Module):
@@ -138,66 +213,54 @@ class HyperConnection(nn.Module):
             (n, n),
         )
 
-    def _coefficients(self, x: Array) -> tuple[Array, Array, Array]:
-        """``x [B, T, n, C]`` → ``H_pre [n, B, T]``, ``H_post [n, B, T]``,
-        ``H_res [n, n, B, T]``, float32."""
-        b, t, n, c = x.shape
-        flat = x.reshape(b, t, n * c)
-        with jax.named_scope("mhc/norm"):
-            square = jnp.square(flat.astype(jnp.float32)).mean(axis=-1)
-            inv_rms = jax.lax.rsqrt(square + self.norm_eps)  # [B, T]
+    def read(self, x: Array) -> tuple[Array, tuple[Array, Array]]:
+        """``x [B, T, n, C]`` → the sublayer's input ``H_pre x [B, T, C]``
+        and what :meth:`write` needs: ``H_post [n, B, T]`` and ``H_res
+        [n, n, B, T]``, float32."""
+        b, t, n, _ = x.shape
         with jax.named_scope("mhc/coef"):
-            phi = jnp.concatenate(
+            phi_t = jnp.concatenate(
                 [self.phi_pre, self.phi_post, self.phi_res], axis=1
-            ).astype(self.dtype)
-            raw = jnp.einsum(
-                "btk,kj->btj", flat.astype(self.dtype), phi,
-                preferred_element_type=jnp.float32,
+            ).astype(self.dtype).T
+            phi_t = jnp.pad(
+                phi_t, ((0, mhc.phi_rows(n) - phi_t.shape[0]), (0, 0)))
+        # one call: the norm's square sum, the coefficients' product,
+        # H_pre and the input mix (``mhc/norm``, ``mhc/coef``, ``mhc/pre``)
+        with jax.named_scope("mhc/pre"):
+            mixed, proj = _over_tokens(
+                lambda x, phi_t, a_pre, b_pre: mhc.read(
+                    _streams_first(x), phi_t, a_pre, b_pre, self.norm_eps),
+                (x,), (phi_t, self.a_pre, self.b_pre),
             )
-            raw = jnp.moveaxis(raw, -1, 0) * inv_rms  # [n n + 2 n, B, T]
-            pre, post, res = raw[:n], raw[n:2 * n], raw[2 * n:]
-            h_pre = jax.nn.sigmoid(
-                self.a_pre * pre + self.b_pre[:, None, None]
-            )
+            # the call hands the input over in float32: the rounding is
+            # here, where the compiler may fuse it into the input's reader
+            mixed = mixed.astype(x.dtype)
+        with jax.named_scope("mhc/coef"):
+            proj = jnp.moveaxis(proj, -1, 0)  # [n n + 2 n, B, T]
             h_post = 2.0 * jax.nn.sigmoid(
-                self.a_post * post + self.b_post[:, None, None]
+                self.a_post * proj[n:2 * n] + self.b_post[:, None, None]
             )
             res = (
-                self.a_res * res.reshape(n, n, b, t)
+                self.a_res * proj[2 * n:].reshape(n, n, b, t)
                 + self.b_res[:, :, None, None]
             )
         with jax.named_scope("mhc/sinkhorn"):
             h_res = sinkhorn(
                 jnp.clip(res, *self.res_clamp), self.sinkhorn_iters, self.eps
             )
-        return h_pre, h_post, h_res
-
-    def read(self, x: Array) -> tuple[Array, tuple[Array, Array]]:
-        """``x [B, T, n, C]`` → the sublayer's input ``H_pre x [B, T, C]``
-        and what :meth:`write` needs."""
-        h_pre, h_post, h_res = self._coefficients(x)
-        with jax.named_scope("mhc/pre"):
-            mixed = sum(
-                h_pre[j][:, :, None] * x[:, :, j].astype(jnp.float32)
-                for j in range(self.streams)
-            ).astype(x.dtype)
         return mixed, (h_post, h_res)
 
     def write(self, x: Array, out: Array, mix: tuple[Array, Array]) -> Array:
         """``H_res x + H_post^T out``: the next stream ``[B, T, n, C]``."""
         h_post, h_res = mix
-        n = self.streams
+        b, t, n, _ = x.shape
         with jax.named_scope("mhc/post"):
-            # stream by stream: n (n + 1) fused multiply-adds an element and
-            # no array wider than the stream (a broadcast product summed
-            # over j held [B, T, n, n, C] in float32: 4.6 GB more claimed at
-            # the Xing4.0 sizes, described-v5e compile, PR 35)
-            wide = out.astype(jnp.float32)
-            rows = [
-                (sum(
-                    h_res[i, j][:, :, None] * x[:, :, j].astype(jnp.float32)
-                    for j in range(n)
-                ) + h_post[i][:, :, None] * wide).astype(x.dtype)
-                for i in range(n)
-            ]
-            return jnp.stack(rows, axis=2)
+            # a token's coefficients a row: H_res row by row, then H_post
+            h = jnp.moveaxis(
+                jnp.concatenate([h_res.reshape(n * n, b, t), h_post]), 0, -1)
+            (new,) = _over_tokens(
+                lambda x, out, h: (
+                    _streams_first(mhc.write(_streams_first(x), out, h)),),
+                (x, out.astype(x.dtype), h), (),
+            )
+        return new

@@ -678,6 +678,72 @@ def test_fold_held_at_the_share_cells_shapes(
         in_slot_order + rows * row_bytes // 2)
 
 
+@pytest.mark.parametrize("call", ["read", "write", "write_bwd", "read_bwd"])
+def test_mhc_calls_at_the_xing4_0_cells_shapes(topo, as_tpu, call):
+    """The n-stream path's four passes (``ops/mhc.py``) at the Xing4.0
+    cell's shapes, 2 x 4,096 tokens of 4 x 3,584 in bf16: each one Pallas call
+    that fits the VMEM it asks for, and the program around it holds no
+    temporary as large as the stream (no float32 copy, no padded one)."""
+    from d9d_tpu.ops import mhc
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    batch, tokens, n, c = 2, 4096, 4, 3584
+    stream = sds((batch, n, tokens, c), BF16)
+    row = sds((batch, tokens, c), BF16)
+    lanes = sds((batch, tokens, mhc.LANES), jnp.float32)
+    phi_t = sds((mhc.phi_rows(n), n * c), BF16)
+    a, b = sds((), jnp.float32), sds((n,), jnp.float32)
+    fn, args = {
+        "read": (lambda x, p, a, b: mhc._read_call(
+            x, p, a, b, norm_eps=1e-6), (stream, phi_t, a, b)),
+        "write": (mhc._write_call, (stream, row, lanes)),
+        "write_bwd": (mhc._write_bwd_call, (stream, stream, row, lanes)),
+        "read_bwd": (mhc._read_bwd_call,
+                     (stream, phi_t, a, b, lanes, row, lanes)),
+    }[call]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _pallas_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        batch * tokens * n * c)
+
+
+def test_mhc_shards_itself_over_a_mesh(topo, as_tpu):
+    """On a mesh of four chips ``HyperConnection`` runs its calls in a
+    ``shard_map`` over the batch axis (a Mosaic kernel cannot be
+    partitioned by the compiler): a read and a write with their
+    gradient compile, the four calls in the program, the maps' gradient
+    reduced over the axis."""
+    import flax.linen as nn
+
+    from d9d_tpu.nn.hyper_connections import HyperConnection
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("dp_s", "tp"))
+    n, c = 4, 3584
+    mod = HyperConnection(hidden_size=c, streams=n, dtype=BF16,
+                          param_dtype=BF16)
+    stream = jax.ShapeDtypeStruct(
+        (4, 512, n, c), BF16, sharding=NamedSharding(mesh, P("dp_s")))
+
+    def loss(params, x):
+        u, mix = mod.apply({"params": params}, x, method="read")
+        new = mod.apply({"params": params}, x, jnp.tanh(u), mix,
+                        method="write")
+        return new.astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        abstract = nn.unbox(jax.eval_shape(
+            lambda: mod.init(jax.random.PRNGKey(0), stream, method="read")
+        ))["params"]
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
+            abstract)
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            params, stream).compile()
+    assert _pallas_calls(compiled, "mhc/") == 4
+    assert " all-reduce" in compiled.as_text()
+
+
 # -- the env-selected fused expert FFN (default stays ``xla``) ---------------
 
 # What the v5e compiler says to the gather variants once the ``unroll=8``

@@ -2,7 +2,14 @@
 matrix is doubly stochastic and stays finite at the clamp's ends, the
 module computes the papers' update (against the plain reference), a
 stream of copies read out by the sum carries the plain residual path's
-numbers, and every parameter gets a spec under the three plans."""
+numbers, and every parameter gets a spec under the three plans. The
+stream's passes are Pallas calls (``ops/mhc.py``): values and every
+gradient against the plain reference over streams, widths, token counts
+and dtypes; what a block traces (two calls a sublayer, two in its
+transpose, no float32 array of the stream's shape); four devices against
+one."""
+
+import dataclasses
 
 import flax.linen as nn
 import jax
@@ -20,6 +27,7 @@ from d9d_tpu.nn.hyper_connections import (
 )
 from d9d_tpu import parallel
 from d9d_tpu.parallel.plan import logical_to_mesh_sharding
+from tests.jaxpr_tools import equations
 
 C, N = 32, 4
 
@@ -157,3 +165,200 @@ def test_every_parameter_gets_a_spec_under_the_plan(plan):
         # the maps shard on their n C rows like any embed dimension
         assert any(a is not None for a in sharding.spec) == wide, (
             name, sharding.spec)
+
+
+# -- the stream's passes as Pallas calls --------------------------------------
+
+
+@pytest.fixture
+def one_device():
+    """``MeshParameters.build`` leaves its mesh ambient: the tests of one
+    device's program pin the one-device mesh they are about."""
+    MeshParameters().build(jax.devices()[:1])
+
+
+def seeded_for(mod, n, c, seed):
+    x = jnp.zeros((1, 2, n, c))
+    params = nn.meta.unbox(
+        mod.init(jax.random.PRNGKey(seed), x, method="read")["params"])
+    rng = np.random.RandomState(seed)
+    for name, value in params.items():
+        if name[0] in "ab":
+            params[name] = jnp.asarray(
+                rng.normal(size=value.shape), jnp.float32)
+    return params
+
+
+def around_one_sublayer(mod, probe):
+    """``(params, x, w, extra) -> (probe . x_next, x_next)``: the module
+    around a sublayer that is not linear, ``extra`` added to the
+    sublayer's output so that its gradient is the output's."""
+
+    def sublayer(u, w, extra):
+        return jnp.tanh(u.astype(jnp.float32) @ w) * 3.0 + extra
+
+    def program(p, x, w, extra):
+        u, mix = mod.apply({"params": p}, x, method="read")
+        y = mod.apply(
+            {"params": p}, x, sublayer(u, w, extra).astype(x.dtype), mix,
+            method="write")
+        return (y.astype(jnp.float32) * probe).sum(), y
+
+    def plain(p, x, w, extra):
+        with jax.default_matmul_precision("highest"):
+            y = reference.around(
+                x.astype(jnp.float32), p, {"rms_norm_eps": 1e-6},
+                lambda u: sublayer(u, w, extra))
+        return (y * probe).sum(), y
+
+    return program, plain
+
+
+def rel_rms(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(
+        np.sqrt(np.mean((got - want) ** 2))
+        / (np.sqrt(np.mean(want ** 2)) + 1e-30))
+
+
+@pytest.mark.parametrize("n,c,b,t,dtype", [
+    (4, 128, 1, 1, jnp.float32),  # a generate step: one token in a tile
+    (4, 128, 1, 127, jnp.bfloat16),  # one short of two row blocks
+    (4, 256, 2, 300, jnp.float32),  # several tiles, the last one padded
+    (4, 768, 1, 127, jnp.float32),  # a stream of two column chunks
+    (4, 32, 3, 5, jnp.bfloat16),  # a width that is no lane tile
+    (2, 128, 1, 1, jnp.bfloat16),
+    (2, 256, 1, 255, jnp.float32),  # one short of a tile
+    (2, 128, 3, 200, jnp.bfloat16),
+], ids=lambda v: getattr(v, "__name__", None) or str(v))
+def test_the_calls_and_their_transposes_are_the_plain_forms(
+        one_device, n, c, b, t, dtype):
+    """Values and the gradients with respect to the stream, the
+    sublayer's output and every parameter against the plain reference
+    (float32 at ``highest`` on the same numbers). Float32 inputs agree to
+    rounding; bf16 inputs to what rounding the stream, the product's
+    operands and each output once costs."""
+    mod = HyperConnection(
+        hidden_size=c, streams=n, dtype=dtype, param_dtype=jnp.float32)
+    params = seeded_for(mod, n, c, seed=n + c + t)
+    keys = jax.random.split(jax.random.PRNGKey(t), 4)
+    x = jax.random.normal(keys[0], (b, t, n, c)).astype(dtype)
+    w = jax.random.normal(keys[1], (c, c)) / c ** 0.5
+    extra = jax.random.normal(keys[2], (b, t, c))
+    probe = jax.random.normal(keys[3], (b, t, n, c))
+    program, plain = around_one_sublayer(mod, probe)
+
+    def grad(f):
+        return jax.jit(jax.value_and_grad(
+            f, argnums=(0, 1, 2, 3), has_aux=True))
+
+    (_, got), got_grads = grad(program)(params, x, w, extra)
+    (_, want), want_grads = grad(plain)(params, x, w, extra)
+    assert got.dtype == dtype and got.shape == x.shape
+    assert got_grads[1].dtype == dtype
+    exact = dtype == jnp.float32
+    assert rel_rms(got, want) < (1e-5 if exact else 6e-3)
+    names = ("params", "stream", "w", "output")
+    for name, g, wanted in zip(names, got_grads, want_grads):
+        leaves = (
+            {name: (g, wanted)} if name != "params"
+            else {k: (g[k], wanted[k]) for k in wanted})
+        for leaf, (a, z) in leaves.items():
+            assert np.isfinite(np.asarray(a, np.float32)).all(), leaf
+            # a handful of tokens leave a scalar's gradient a few numbers'
+            # difference: compare those to the gradient's own scale
+            assert rel_rms(a, z) < (2e-4 if exact else 3e-2), (
+                leaf, rel_rms(a, z))
+
+
+def test_a_block_traces_two_calls_a_sublayer_and_two_in_its_transpose(
+        one_device):
+    """The gradient of one tiny Xing4.0 block: under each ``mhc`` module
+    one read and one write forward, the same two replayed by the layer's
+    remat (but the block's last write, whose result nothing in the
+    block's backward reads), one transposed read and one transposed write;
+    outside the kernels no float32 array of the stream's shape, flat or
+    not."""
+    from d9d_tpu.models.deepseek import DeepseekCausalLM, xing4_0_tiny
+    from d9d_tpu.ops.attention.eager import eager_sdpa
+
+    cfg = dataclasses.replace(
+        xing4_0_tiny(64), num_layers=1, num_mtp_modules=0, remat=True)
+    model = DeepseekCausalLM(
+        config=cfg, sdpa=eager_sdpa, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    ids = jnp.zeros((2, 8), jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(8), (2, 8))
+    params = jax.eval_shape(lambda: nn.meta.unbox(
+        model.init(jax.random.PRNGKey(0), ids, positions)))
+
+    def loss(p):
+        return model.apply(p, ids, positions).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    calls = {}
+    for eqn in equations(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            calls[name] = calls.get(name, 0) + 1
+    sublayers = 2
+    assert calls == {
+        "mhc_read": 2 * sublayers, "mhc_write": 2 * sublayers - 1,
+        "mhc_read_bwd": sublayers, "mhc_write_bwd": sublayers,
+    }
+    n, c = cfg.hc_mult, cfg.hidden_size
+    stream = {(2, 8, n, c), (2, 8, n * c), (16, n * c)}
+
+    def outside_kernels(jaxpr, scope=""):
+        for eqn in jaxpr.eqns:
+            inner = f"{scope}/{eqn.source_info.name_stack}"
+            yield eqn, inner
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from outside_kernels(sub, inner)
+
+    # around the sublayers; the stream's read-out (``mhc/readout``) sums
+    # the streams in float32 inside one fusion and is not a pass of theirs
+    wide = [
+        (eqn.primitive.name, var.aval.shape)
+        for eqn, scope in outside_kernels(jaxpr) if "_mhc" in scope
+        for var in (*eqn.invars, *eqn.outvars)
+        if getattr(getattr(var, "aval", None), "dtype", None) == jnp.float32
+        and var.aval.shape in stream
+    ]
+    assert [s for _, s in outside_kernels(jaxpr) if "_mhc" in s]
+    assert wide == []
+
+
+def test_four_devices_give_one_devices_values_and_gradients(one_device):
+    """Under a mesh of four devices the calls run per shard of the batch
+    axes, the maps whole: the stream, the sublayer's input and ``d phi``
+    (summed over the shards) are the one-device run's."""
+    n, c, b, t = 4, 128, 4, 24
+    mod = HyperConnection(
+        hidden_size=c, streams=n, dtype=jnp.float32, param_dtype=jnp.float32)
+    params = seeded_for(mod, n, c, seed=3)
+    x = jax.random.normal(jax.random.PRNGKey(1), (b, t, n, c))
+    probe = jax.random.normal(jax.random.PRNGKey(2), (b, t, n, c))
+    program, _ = around_one_sublayer(mod, probe)
+    w, extra = jnp.eye(c), jnp.zeros((b, t, c))
+    grad = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)
+    (_, want), want_grads = jax.jit(grad)(params, x, w, extra)
+    # off the one device, so that the four-device program may place them
+    params, x, w, extra = jax.tree.map(np.asarray, (params, x, w, extra))
+    ctx = MeshParameters(dp_replicate=2, dp_shard=2).build(jax.devices()[:4])
+    try:
+        traced = jax.make_jaxpr(grad)(params, x, w, extra)
+        assert "shard_map" in {
+            eqn.primitive.name for eqn in equations(traced.jaxpr)}
+        sharded = jax.device_put(x, ctx.batch_sharding())
+        (_, got), got_grads = jax.jit(grad)(params, sharded, w, extra)
+    finally:
+        MeshParameters().build(jax.devices()[:1])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        got_grads[1], want_grads[1], rtol=1e-4, atol=1e-5)
+    for name in want_grads[0]:
+        np.testing.assert_allclose(
+            got_grads[0][name], want_grads[0][name], rtol=2e-4, atol=1e-5,
+            err_msg=name)
