@@ -79,6 +79,22 @@ class Mamba2Parameters:
 
 
 @dataclasses.dataclass(frozen=True)
+class KdaParameters:
+    """Geometry of the ``"kda"`` kind's mixer (``nn/linear_attention.py
+    KimiDeltaAttention``): ``num_heads`` heads of ``head_dim`` key and
+    value channels, a decay a key channel, ``conv_size`` taps, the decay
+    and output gates' rank (0 = ``head_dim``), a write strength to 2
+    under ``allow_neg_eigval``, and the chunked form's chunk."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    gate_rank: int = 0
+    allow_neg_eigval: bool = False
+    chunk_size: int = 64
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """Constants on the stack's path (the Granite family's four): on the
     embedding table's output, on both residual branches of every layer, a
@@ -118,9 +134,16 @@ class AttentionKind:
 # the token-mixer kinds a layer can be without an entry in
 # ``attention_kinds``: grouped-query attention with the plain fields,
 # latent attention (``mla``), a Mamba-1 mixer, a Mamba-2 mixer, a
-# GatedDeltaNet block
-LAYER_KINDS = ("attention", "mla", "mamba", "mamba2", "gdn")
-STATE_SPACE_KINDS = ("mamba", "mamba2")
+# GatedDeltaNet block, a Kimi delta attention block
+LAYER_KINDS = ("attention", "mla", "mamba", "mamba2", "gdn", "kda")
+# a stack with a layer of one of these adds its residual stream in
+# float32: the state-space mixers (Mamba's ``residual_in_fp32``) and Kimi
+# delta attention, whose float32 state remembers a prompt's roundings for
+# as long as it remembers the prompt (on the chip the share cell's bf16
+# stream stood 0.0082 from the float32 reference and its served streams
+# left ``generate``'s at reference gaps up to 0.012 of the 0.02 allowed;
+# with this 0.0057 and 0.0036, for 0.5 % of the rate: PERF.md, PR 51)
+FLOAT32_STREAM_KINDS = ("mamba", "mamba2", "kda")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,6 +237,8 @@ class Qwen3MoeConfig:
     # the ``"mamba2"`` kind's block (models/granite/) and the stack's
     # constant multipliers: one block a mechanism, see the two classes
     mamba2: Optional[Mamba2Parameters] = None
+    # the ``"kda"`` kind's block (models/solar/)
+    kda: Optional[KdaParameters] = None
     multipliers: Multipliers = Multipliers()
     # the output head reads the embedding table (no head parameters);
     # the table is then drawn at embedding_init_std, the family's
@@ -283,9 +308,9 @@ class Qwen3MoeConfig:
         return "attention" if self.mla is None else "mla"
 
     @property
-    def has_state_space_layers(self) -> bool:
+    def float32_stream(self) -> bool:
         return any(
-            self.layer_kind(i) in STATE_SPACE_KINDS
+            self.layer_kind(i) in FLOAT32_STREAM_KINDS
             for i in range(self.num_layers)
         )
 
@@ -471,6 +496,20 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name="linear_attn",
+            )(normed, padding_mask)
+        elif kind == "kda":
+            from d9d_tpu.nn.linear_attention import KimiDeltaAttention
+
+            # named ``kda``: its ops fall under ``/kda/`` in a trace and
+            # not under the attention layers' ``/self_attn/``
+            attn_out = KimiDeltaAttention(
+                hidden_size=cfg.hidden_size,
+                **dataclasses.asdict(cfg.kda),
+                norm_eps=cfg.norm_eps,
+                decode=self.decode_max_length > 0,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                name="kda",
             )(normed, padding_mask)
         elif kind == "mla":
             from d9d_tpu.nn.attention import MultiHeadLatentAttention
@@ -661,7 +700,7 @@ class Qwen3MoeBackbone(nn.Module):
         (the multi-token-prediction module's input)."""
         cfg = self.config
         stream = (
-            jnp.float32 if cfg.has_state_space_layers else self.dtype
+            jnp.float32 if cfg.float32_stream else self.dtype
         )
         if self.stage.is_first:
             x = TokenEmbedding(

@@ -17,6 +17,7 @@ from d9d_tpu.nn.linear_attention import (
     CausalShortConv1d,
     DecayGateKind,
     GatedDeltaNet,
+    KimiDeltaAttention,
     LogSigmoidDecayGate,
     MambaDecayGate,
 )
@@ -48,6 +49,7 @@ __all__ = [
     "CausalShortConv1d",
     "DecayGateKind",
     "GatedDeltaNet",
+    "KimiDeltaAttention",
     "LogSigmoidDecayGate",
     "MambaDecayGate",
     "Mamba2Mixer",

@@ -49,7 +49,11 @@ from jax import lax
 
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn import logical_axes as la
-from d9d_tpu.nn.linear_attention import CausalShortConv1d, _dt_bias_init
+from d9d_tpu.nn.linear_attention import (
+    _a_log_uniform,
+    _dt_bias_init,
+    conv_with_tail,
+)
 from d9d_tpu.nn.norm import RMSNorm
 from d9d_tpu.ops.ssd import ssd_chunked, ssd_step
 from d9d_tpu.ops.selective_scan import (
@@ -68,11 +72,6 @@ def _a_log_init(key, shape, dtype):
     ).astype(dtype)
 
 
-def _a_log_uniform(key, shape, dtype):
-    """``A`` uniform in [1, 16] a head (Mamba-2's default), as its log."""
-    return jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0)).astype(dtype)
-
-
 def _proj(mixer, features, name, axes, dot_general=None):
     """A projection without bias in the mixer's activation type."""
     return nn.Dense(
@@ -87,33 +86,13 @@ def _proj(mixer, features, name, axes, dot_general=None):
 
 def _conv_with_tail(mixer, xs: Array, channels: int, keep) -> Array:
     """``silu(conv1d_causal(xs) + bias)`` in float32 under ``mamba/conv``,
-    for either mixer (inside its ``@nn.compact`` call). In decode mode the
-    convolution's previous ``d_conv - 1`` inputs are the ``conv_tail``
-    cache leaf ``[B, K-1, channels]`` in the activation type, read as the
-    left context and shifted by the new inputs. ``keep [B, T, 1]`` zeroes
-    padded positions again: the bias would otherwise leak into them."""
-    batch, t, _ = xs.shape
-    with jax.named_scope("mamba/conv"):
-        conv = CausalShortConv1d(
-            channels=channels, kernel_size=mixer.d_conv, use_bias=True,
-            name="conv1d", param_dtype=mixer.param_dtype,
-        )
-        context = None
-        if mixer.decode and mixer.d_conv > 1:
-            tail = mixer.variable(
-                "cache", "conv_tail",
-                lambda: jnp.zeros(
-                    (batch, mixer.d_conv - 1, channels), mixer.dtype
-                ),
-            )
-            context = tail.value
-            tail.value = jnp.concatenate(
-                [context, xs.astype(mixer.dtype)], axis=1
-            )[:, t:]
-        xs = conv(xs.astype(F32), context)
-        if keep is not None:
-            xs = xs * keep.astype(F32)
-    return xs
+    for either mixer: the helper every mixer with a short convolution
+    shares (``nn/linear_attention.py conv_with_tail``, which says what
+    the ``conv_tail`` leaf holds), with the state-space family's bias."""
+    return conv_with_tail(
+        mixer, xs, channels, taps=mixer.d_conv, name="conv1d",
+        scope="mamba/conv", use_bias=True, keep=keep,
+    )
 
 
 class MambaMixer(nn.Module):

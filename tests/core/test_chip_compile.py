@@ -452,6 +452,59 @@ def test_mamba2_step_at_granite_4_0_h_small_widths(topo):
     assert ma.temp_size_in_bytes < state_bytes / 2
 
 
+def test_fused_decode_steps_at_the_solar_open2_share8_cell(topo, as_tpu):
+    """Eight scanned decode steps of the whole share (one period: gated
+    NoPE GQA and three Kimi delta attention mixers at the published
+    widths, 40 held experts and a shared one a layer) for the cell's 256
+    rows, the cache donated: the three ``kda_step`` calls are in the
+    program, each mixer's 1.07 GB float32 state is updated in place (no
+    copy of it is made), and weights, cache and temporaries fit the chip
+    with the room the cell's 71 % states. The body of the serving loop's
+    fused chunk with an unpaged cache, not the chunk itself."""
+    from d9d_tpu.models.solar import SolarCausalLM, solar_open2_250b_share8
+    from d9d_tpu.nn.sdpa import build_sdpa_backend
+
+    slots, state_bytes = 256, 256 * 64 * 128 * 128 * 4
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    model = SolarCausalLM(
+        config=solar_open2_250b_share8(), sdpa=build_sdpa_backend(),
+        dtype=BF16, param_dtype=BF16, decode_max_length=1152,
+    )
+    z = jnp.zeros((slots, 1), jnp.int32)
+    abstract = nn.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), z, z, z)))
+    variables = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        {k: abstract[k] for k in ("params", "cache")})
+
+    def chunk(cache, params, tok, pos):
+        def body(carry, _):
+            cache, tok, pos = carry
+            logits, new = model.apply(
+                {"params": params, "cache": cache}, tok, pos,
+                method="logits", mutable=["cache"])
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (new["cache"], tok[:, None], pos + 1), tok
+
+        (cache, _, _), toks = jax.lax.scan(
+            body, (cache, tok, pos), None, length=8)
+        return cache, toks
+
+    compiled = jax.jit(chunk, donate_argnums=0).lower(
+        variables["cache"], variables["params"],
+        sds(z.shape, z.dtype), sds(z.shape, z.dtype),
+    ).compile()
+    assert _pallas_calls(compiled, "kda_step") == 3
+    text = compiled.as_text()
+    assert not re.search(r"= f32\[256,64,128,128\]\S* copy\(", text)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 3 * state_bytes  # updated in place
+    assert ma.temp_size_in_bytes < 2 * state_bytes
+    claimed = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+               - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert claimed < 14e9  # of the chip's 15.75 GB
+
+
 # -- the output head's fused cross-entropy -----------------------------------
 
 

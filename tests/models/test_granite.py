@@ -101,7 +101,7 @@ def test_presets_hold_the_published_sizes():
         attention_softmax_scale=0.0078125)
     assert full.multipliers == published
     assert full.vocab_size == 100_352 and full.tie_word_embeddings
-    assert full.has_state_space_layers and full.norm_eps == 1e-5
+    assert full.float32_stream and full.norm_eps == 1e-5
     # the tiny twin keeps every mechanism on, at the published constants
     assert CFG.multipliers == published
     assert CFG.layer_kinds == ("mamba2", "attention", "mamba2")

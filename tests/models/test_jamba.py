@@ -147,16 +147,17 @@ def test_a_state_space_stack_adds_its_residual_stream_in_float32():
     assert hidden.dtype == BF16
 
 
-def _decode_with_state_in(model, params, ids):
+def _decode_with_state_in(model, params, ids, leaf="ssm_state"):
     """Logits of every position, one token a step through the cache, with
-    the SSM state kept in float32 and with it rounded to bf16 after every
-    step: one compiled program, which of the two is an argument."""
+    the recurrent state (the cache leaves named ``leaf``) kept in float32
+    and with it rounded to bf16 after every step: one compiled program,
+    which of the two is an argument."""
 
     def carried(cache, rounded):
         flat = flatten_dict(cache)
         return unflatten_dict({
             p: jnp.where(rounded, v.astype(BF16).astype(v.dtype), v)
-            if p[-1] == "ssm_state" else v
+            if p[-1] == leaf else v
             for p, v in flat.items()
         })
 
