@@ -57,11 +57,14 @@ starts it, so whatever an idle or dead row wrote there (it keeps
 stepping on token 0 under static shapes) cannot reach the next
 request; a model with such leaves serves with the prefix cache off
 (its state summarizes the whole prefix and cannot be rebuilt from
-shared KV pages) and ``prefix_cache=True`` raises. The zeroing is a
-masked pass over every per-row leaf, so its cost follows
-``ServeStats.recurrent_state_bytes`` (on the ``serve/step`` span and
-the ``serve/recurrent_state_bytes`` gauge too) and ``rows_reset``
-counts the rows it cleared.
+shared KV pages) and ``prefix_cache=True`` raises. The zeroing
+(``nn/decode_flags.zero_rows``, the ops under ``serve/reset_rows``)
+writes a zero row into each per-row leaf at each admitted index, in
+place: the device pays for the admitted rows' bytes and a launch a leaf
+a row, never for the state (``ServeStats.recurrent_state_bytes``, on
+the ``serve/step`` span and the ``serve/recurrent_state_bytes`` gauge
+too). ``rows_reset`` counts the rows it cleared and
+``rows_reset_device_bytes`` the bytes it wrote.
 
 A third kind under paging: an attention layer that reads a window of
 positions keeps a ring of pages a row (``nn/attention.py
@@ -162,6 +165,7 @@ from d9d_tpu.core.tracing import annotate
 from d9d_tpu.core.tree_sharding import replicate_uncommitted
 from d9d_tpu.core.types import Array
 from d9d_tpu.loop.quantize import dequantize_params, is_quantized_tree
+from d9d_tpu.nn.decode_flags import zero_rows
 from d9d_tpu.telemetry import get_telemetry, tracked_jit
 
 # slot-occupancy fraction per chunk/step: 20 linear bins over [0, 1]
@@ -396,7 +400,9 @@ class ServeStats:
     paging. ``recurrent_state_bytes`` is a level, not a sum: the bytes
     of the per-row recurrent leaves the batcher's cache holds (0 for an
     attention-only model), as of the last chunk; ``rows_reset`` counts
-    the rows whose per-row leaves an admission zeroed.
+    the rows whose per-row leaves an admission zeroed and
+    ``rows_reset_device_bytes`` the bytes the device wrote to do it
+    (rows times a row's share of every per-row leaf).
     ``window_cache_bytes`` is a level too: the bytes of the window
     layers' rings of pages (0 without such layers, or unpaged), and
     ``window_positions_attended`` is ``positions_attended`` for those
@@ -424,6 +430,7 @@ class ServeStats:
     pool_pages_peak: int = 0
     recurrent_state_bytes: int = 0
     rows_reset: int = 0
+    rows_reset_device_bytes: int = 0
     window_cache_bytes: int = 0
     window_positions_attended: int = 0
     moe_rows_held: int = 0
@@ -450,17 +457,6 @@ class ServeStats:
         if self.slot_steps_total == 0:
             return 0.0
         return self.slot_steps_busy / self.slot_steps_total
-
-
-def _zero_row(cache, row_mask: Array):
-    """Zero every cache leaf's ``row_mask``-selected batch rows (all
-    decode cache leaves — KV/latent caches, GDN state, conv tails,
-    per-row cache_index — lead with the batch dim)."""
-    def z(x):
-        m = row_mask.reshape((-1,) + (1,) * (x.ndim - 1))
-        return jnp.where(m, jnp.zeros_like(x), x)
-
-    return jax.tree.map(z, cache)
 
 
 def _positions_under(row_spans, window: int) -> int:
@@ -736,17 +732,14 @@ class ContinuousBatcher:
         self._step = None
         self._fused: dict[tuple[int, bool], object] = {}  # (k, with_admit)
         if self._paged:
-            from d9d_tpu.nn.decode_flags import (
-                map_cache_index,
-                zero_rows_skip_paged,
-            )
+            from d9d_tpu.nn.decode_flags import map_cache_index
 
             def _reset_rows_paged(cache, row_mask, admit_pos):
                 # page pools are shared (never row-zeroed — stale page
                 # bytes are unreachable behind the slot mask) and table
                 # rows come from the host mirror; per-row leaves reset,
                 # write indices jump to the first un-cached position
-                cache = zero_rows_skip_paged(cache, row_mask)
+                cache = zero_rows(cache, row_mask)
                 return map_cache_index(
                     cache,
                     lambda idx: jnp.where(row_mask, admit_pos, idx),
@@ -758,7 +751,7 @@ class ContinuousBatcher:
             )
         else:
             self._reset = tracked_jit(
-                _zero_row, name="serve/reset_row", donate_argnums=0
+                zero_rows, name="serve/reset_row", donate_argnums=0
             )
         self._cache = self._init_cache()
         # static per-batcher fact: what of the cache is per-row recurrent
@@ -946,6 +939,7 @@ class ContinuousBatcher:
             PAGE_TABLE_LEAF,
             PAGED_CACHE_LEAVES,
             PAGED_SCALE_SUFFIX,
+            per_row_leaves,
             recurrent_leaves,
             ring_caches,
             window_leaves,
@@ -988,8 +982,8 @@ class ContinuousBatcher:
         # per-row cache leaves that are NOT pageable (GDN and Mamba
         # recurrent state, conv tails, toy memories): paging leaves them
         # per-row; their presence auto-disables the prefix cache (their
-        # state can't be rebuilt from shared KV pages). Their bytes are
-        # what every admission's row reset passes over.
+        # state can't be rebuilt from shared KV pages). An admission
+        # zeroes the admitted rows of them (decode_flags.zero_rows).
         recurrent = recurrent_leaves(shapes["cache"])
         rings = window_leaves(shapes["cache"])
         if rings and self._kv_quant is not None:
@@ -1043,7 +1037,12 @@ class ContinuousBatcher:
                 self._page_bytes += pool.nbytes // self._num_pages
             else:
                 out[p] = jnp.zeros(s.shape, s.dtype)
-        return unflatten_dict(out)
+        cache = unflatten_dict(out)
+        # what an admission writes: a zero row into each per-row leaf
+        self._row_reset_bytes = (
+            nbytes(per_row_leaves(cache).values()) // self._b
+        )
+        return cache
 
     # ------------------------------------------------------------------
     # jitted executables
@@ -1127,11 +1126,13 @@ class ContinuousBatcher:
     def _build_fused(self, k: int, with_admit: bool):
         """Compile one fused K-step executable. ``with_admit`` variants
         open with the admitted rows' cache zeroing + carry resets fused
-        into the same dispatch; the no-admit variant (the steady state:
-        every follow-up chunk, all speculative chunks) skips them — the
-        masked zero is a full-capacity read+write of every cache leaf,
-        exactly the O(s_max) traffic class the fused loop exists to
-        avoid paying per chunk.
+        into the same dispatch; the no-admit variant (every follow-up
+        chunk, all speculative chunks) traces none of it. The zeroing
+        (``decode_flags.zero_rows``) is a loop over the admitted rows,
+        as many trips as the mask has rows set, counted on the device:
+        each trip writes one zero row into each per-row leaf in place,
+        the donated cache passes through it into the steps' scan without
+        a copy, and one program serves any number of admitted rows.
 
         Everything the host decided for the chunk arrives as ONE int32
         array, ``packed`` (:func:`_chunk_columns`; built and staged by
@@ -1145,8 +1146,9 @@ class ContinuousBatcher:
         samples from the second half and hands the first back.
 
         Paged mode differences, same dispatch structure: admitted rows
-        reset only their PER-ROW leaves (pools are shared; stale page
-        bytes sit behind the slot mask) and jump their write index /
+        reset only their PER-ROW leaves (``decode_flags.per_row_leaves``:
+        pools are shared; stale page bytes sit behind the slot mask)
+        and jump their write index /
         position to ``admit_pos`` — the first token past their prefix-
         cache hit; the host's table is written into every ``page_table``
         leaf and pinned by the device's own ``live`` (after the
@@ -1165,7 +1167,6 @@ class ContinuousBatcher:
             from d9d_tpu.nn.decode_flags import (
                 map_cache_index,
                 map_page_table,
-                zero_rows_skip_paged,
             )
 
         def fused_fn(params, cache, tok, pos, live, rem, key, packed):
@@ -1178,16 +1179,15 @@ class ContinuousBatcher:
                 admit_budget = packed[:, cols.admit_budget]
                 # boundary work, fused into the same dispatch: zero
                 # admitted rows' cache and reset their carries
+                cache = zero_rows(cache, admit_mask)
                 if paged:
                     admit_pos = packed[:, cols.admit_pos]
-                    cache = zero_rows_skip_paged(cache, admit_mask)
                     cache = map_cache_index(
                         cache,
                         lambda idx: jnp.where(admit_mask, admit_pos, idx),
                     )
                     pos = jnp.where(admit_mask, admit_pos, pos)
                 else:
-                    cache = _zero_row(cache, admit_mask)
                     pos = jnp.where(admit_mask, 0, pos)
                 live = jnp.where(admit_mask, True, live)
                 rem = jnp.where(admit_mask, admit_budget, rem)
@@ -2004,6 +2004,7 @@ class ContinuousBatcher:
                 admit_pos[i] = start_pos
                 self._note_admit(req.rid)
                 self.stats.rows_reset += 1
+                self.stats.rows_reset_device_bytes += self._row_reset_bytes
             if reset_mask.any():
                 if self._paged:
                     self._cache = self._reset(
@@ -2254,6 +2255,8 @@ class ContinuousBatcher:
         self.stats.device_steps += k
         rows_reset = int(admit_mask.sum())
         self.stats.rows_reset += rows_reset
+        reset_bytes = rows_reset * self._row_reset_bytes
+        self.stats.rows_reset_device_bytes += reset_bytes
         self.stats.recurrent_state_bytes = self._recurrent_state_bytes
         self.stats.window_cache_bytes = self._window_cache_bytes
         # what the wrapper's own Python and the enqueue cost this chunk
@@ -2263,6 +2266,7 @@ class ContinuousBatcher:
             recurrent_state_bytes=self._recurrent_state_bytes,
             window_cache_bytes=self._window_cache_bytes,
             rows_reset=rows_reset,
+            rows_reset_device_bytes=reset_bytes,
             dispatch_key_s=cost.key_s,
             dispatch_enqueue_s=cost.enqueue_s,
             dispatch_arg_leaves=cost.arg_leaves,

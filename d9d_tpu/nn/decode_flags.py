@@ -208,20 +208,66 @@ def map_page_table(cache, fn):
     return unflatten_dict(flat) if hit else cache
 
 
-def zero_rows_skip_paged(cache, row_mask):
-    """Zero ``row_mask``-selected batch rows of every PER-ROW cache leaf,
-    skipping page pools, page tables and rings of pages (which have no
-    batch-leading dim — pools are shared across rows, admitted rows'
-    table rows are written by the host allocator, not zeroed, and what a
-    ring still holds is behind the position masks). The paged-mode
-    sibling of ``loop/serve.py``'s ``_zero_row``; trace-safe."""
+def per_row_leaves(cache) -> dict:
+    """``{path: leaf}`` of the cache leaves that lead with the batch
+    dimension: everything but a paged cache's pools, tables and rings of
+    pages. A pool shares its name with the dense leaf it replaces, and
+    is told from it by the ``page_table`` the serving loop seeds beside
+    it, as the attention modules tell them. Works on arrays and on
+    ``jax.eval_shape`` shapes alike."""
+    from flax.traverse_util import flatten_dict
+
+    flat = flatten_dict(cache)
+
+    def shared(path) -> bool:
+        name = path[-1]
+        if name == PAGE_TABLE_LEAF or name in RING_CACHE_LEAVES:
+            return True
+        pooled = name in PAGED_CACHE_LEAVES or name in PAGED_SCALE_LEAVES
+        return pooled and path[:-1] + (PAGE_TABLE_LEAF,) in flat
+
+    return {path: x for path, x in flat.items() if not shared(path)}
+
+
+def zero_rows(cache, row_mask):
+    """Zero the ``row_mask``-selected batch rows of every per-row cache
+    leaf (:func:`per_row_leaves`: pools are shared across rows, admitted
+    rows' table rows are written by the host allocator, and what a ring
+    still holds is behind the position masks). The one reset of the
+    serving loop, paged or not; trace-safe.
+
+    The device pays for the rows it clears, not for the state: the
+    mask's set indices and their count are taken on the device
+    (``[B]``-sized work), and a loop of that many trips writes one zero
+    row into each leaf in place, the leaves as its carry, so donated
+    buffers pass through it without a copy. A ``[B]``-sized leaf (a
+    ``cache_index``) is masked, which costs nothing. The ops stand
+    under the scope ``serve/reset_rows``."""
+    import jax
     import jax.numpy as jnp
     from flax.traverse_util import flatten_dict, unflatten_dict
 
     flat = flatten_dict(cache)
-    for path, x in list(flat.items()):
-        if path[-1] in _SHARED_LEAVES:
-            continue
-        m = row_mask.reshape((-1,) + (1,) * (x.ndim - 1))
-        flat[path] = jnp.where(m, jnp.zeros_like(x), x)
+    per_row = per_row_leaves(cache)
+    wide = [path for path, x in per_row.items() if x.ndim > 1]
+    with jax.named_scope("serve/reset_rows"):
+        for path, x in per_row.items():
+            if x.ndim == 1:
+                flat[path] = jnp.where(row_mask, jnp.zeros_like(x), x)
+        if wide:
+            (rows,) = jnp.nonzero(row_mask, size=row_mask.shape[0])
+
+            def clear(i, leaves):
+                return [
+                    jax.lax.dynamic_update_slice_in_dim(
+                        x, jnp.zeros((1,) + x.shape[1:], x.dtype),
+                        rows[i], axis=0,
+                    )
+                    for x in leaves
+                ]
+
+            flat.update(zip(wide, jax.lax.fori_loop(
+                0, row_mask.sum(dtype=jnp.int32), clear,
+                [flat[path] for path in wide],
+            )))
     return unflatten_dict(flat)

@@ -452,6 +452,44 @@ def test_mamba2_step_at_granite_4_0_h_small_widths(topo):
     assert ma.temp_size_in_bytes < state_bytes / 2
 
 
+def test_an_admission_clears_its_rows_in_place_at_granite_widths(topo):
+    """The serving loop's reset (``decode_flags.zero_rows``) ahead of a
+    scan of steps, on three Mamba-2 layers' per-row leaves for the
+    Granite cell's 128 rows, the cache donated: the reset is a loop of
+    in-place row writes, no ``select`` or copy of a state's whole shape
+    stands between the argument and the scan, and the program holds no
+    second copy of the state (the 6.5 MB tails the compiler is free to
+    stage in fast memory, as it does in the cell's own step)."""
+    from d9d_tpu.nn.decode_flags import zero_rows
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    state, tail = (128, 128, 64, 128), (128, 3, 8448)
+    cache = {
+        f"layers_{i}": {"mamba": {
+            "ssm_state": sds(state, jnp.float32), "conv_tail": sds(tail, BF16),
+        }} for i in range(3)
+    }
+    state_bytes = 3 * int(np.prod(state)) * 4
+
+    def admit_then_step(cache, admit_mask):
+        cache = zero_rows(cache, admit_mask)
+        halve = lambda x: (x * 0.5).astype(x.dtype)  # noqa: E731
+        cache, _ = jax.lax.scan(
+            lambda c, _: (jax.tree.map(halve, c), None), cache, None, length=8)
+        return cache
+
+    compiled = jax.jit(admit_then_step, donate_argnums=0).lower(
+        cache, sds((128,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert not re.search(
+        r"= f32\[128,128,64,128\]\S* (select|copy|copy-start|copy-done)\(",
+        text)
+    assert "serve/reset_rows/while" in text
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= state_bytes  # cleared in place
+    assert ma.temp_size_in_bytes < state_bytes / 3
+
+
 def test_fused_decode_steps_at_the_solar_open2_share8_cell(topo, as_tpu):
     """Eight scanned decode steps of the whole share (one period: gated
     NoPE GQA and three Kimi delta attention mixers at the published
