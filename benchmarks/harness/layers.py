@@ -1,8 +1,8 @@
 """What the per-layer readers of PR 25 share: the program's span timeline
 inside a window, registry spans placed on a trace by the clock anchor,
-device time by scope, one scope for the expert matmuls' custom calls,
-and which events of a trace are a kernel's: each by its own instruction
-in the compiled program that ran it.
+and device time by scope, a kernel's events and the breakdown's
+labels: each event by its own instruction in the compiled program that
+ran it.
 
 Arithmetic on plain data, like ``trace.py``: the program is only asked
 for its span timeline and for its own reading of the clock anchor, so
@@ -102,66 +102,19 @@ def phase_spans_on_trace(trace: dict, lo: float, hi: float):
     ])
 
 
-# -- device time by scope -----------------------------------------------------
-
-
-def scope_seconds(trace: dict, scopes: dict, pattern: str,
-                  name_pattern=None, window=None) -> float:
-    """Self time of the device ops whose scope (the ``op_name`` of their
-    instruction) matches ``pattern``, or whose instruction name matches
-    ``name_pattern``, averaged over devices. Only the instruction's own
-    name and scope are looked at, never its operands."""
-    rx = re.compile(pattern)
-    lo, hi = window or tr.window_of(trace)
-    per_device = []
-    for lanes in trace["devices"].values():
-        inside = [e for e in lanes["ops"] if lo <= e[1] < hi]
-        total = 0.0
-        for text, seconds in tr.self_times(inside):
-            name = tr.parse_op(text)[0]
-            if rx.search((scopes or {}).get(name, "")) or (
-                name_pattern is not None and name_pattern.match(name)
-            ):
-                total += seconds
-        per_device.append(total)
-    return sum(per_device) / max(len(per_device), 1)
-
-
-def scope_share(run, pattern: str, name_pattern=None):
-    """Device time under a scope as a share of busy time, in percent."""
-    if run.trace is None or not run.trace["devices"]:
-        return None
-    busy, _ = tr.busy_and_window(run.trace)
-    if not busy:
-        return None
-    seconds = scope_seconds(run.trace, run.scopes, pattern, name_pattern)
-    return 100.0 * seconds / busy
-
-
-# -- a scope for the expert matmuls' custom calls ------------------------------
-
-
-def with_expert_matmuls(scopes: dict, hlo_texts) -> dict:
-    """``scopes`` with ``moe/experts/ragged_dot`` for every ``ragged-dot``
-    custom call of the compiled programs: the TPU compiler makes them
-    from ``lax.ragged_dot`` and from nothing else, and leaves them no
-    ``op_name`` but their own, so the breakdown would print them
-    unscoped. Which of the layer's matmuls a call is (gate|up or down,
-    forward or a transpose) is not told apart here."""
-    calls = re.findall(
-        r"^\s*(?:ROOT )?%?(ragged-dot[\w.\-]*) = ", "\n".join(hlo_texts), re.M
-    )
-    return {**scopes, **dict.fromkeys(calls, RAGGED_SCOPE)}
-
-
-# -- a kernel's events, each by its own instruction -----------------------------
+# -- an event by its own instruction --------------------------------------------
 
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?(%?[\w.\-]+ = .*)")
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
 RESULT_LIMIT = 160  # of a result type: a trace cuts an event's text short
+# what the compiler makes to bring an instruction's operand to it or take
+# its result away (a copy, a slice or a copy it runs beside the compute
+# and waits for) and gives no ``op_name``
+STAGING = ("copy", "-start", "-done")
 
 
 class Program(typing.NamedTuple):
@@ -171,8 +124,34 @@ class Program(typing.NamedTuple):
 
     module: str  # the HloModule's name: ``jit_step``
     results: dict  # instruction -> its result type, cut to RESULT_LIMIT
-    scopes: dict  # instruction -> the ``op_name`` of its metadata
+    scopes: dict  # instruction -> its ``op_name``, or the one it stages for
     products: dict  # instruction -> scopes of the matrix products it holds
+
+
+def _operands(line: str, opcode: str) -> list:
+    """Names of the instructions one reads: between its opcode's
+    parentheses."""
+    rest, depth = line.partition(f" {opcode}(")[2], 1
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return _OPERAND.findall(rest, 0, i)
+    return []
+
+
+def _nearest(name: str, scopes: dict, near: dict):
+    """The ``op_name`` of the nearest instruction that has one, going
+    from this one by ``near`` through those that have none (a bitcast,
+    a tuple's element, a concatenation the compiler made of slices)."""
+    todo, seen = [name], {name}
+    while todo:
+        for other in near.get(todo.pop(0), ()):
+            if other in scopes:
+                return scopes[other]
+            if other not in seen:
+                seen.add(other)
+                todo.append(other)
+    return None
 
 
 def compiled_program(text: str) -> Program:
@@ -182,9 +161,13 @@ def compiled_program(text: str) -> Program:
     each such product's ``op_name`` (a fusion's own is its root's, which
     may be an activation fused in behind the product, or one of three
     projections fused together) or else its own. A copy, a concatenation
-    or any other fusion is not one."""
+    or any other fusion is not one. A ``STAGING`` instruction with no
+    ``op_name`` is its reader's work, or (a loop's carried state, copied
+    out) its operand's: it takes the scope of the first instruction that
+    reads it, else of the one it reads."""
     module, results, scopes = "", {}, {}
     inside: dict[str, list] = {}  # computation -> (name, opcode, scope, calls)
+    reads, readers, staging = {}, {}, set()
     body = None
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
@@ -199,8 +182,13 @@ def compiled_program(text: str) -> Program:
         name, result, opcode = tr.instruction(found[1])
         scope, calls = _OP_NAME.search(line), _CALLS.search(line)
         results[name] = result[:RESULT_LIMIT]
+        reads[name] = _operands(found[1], opcode)
+        for read in reads[name]:
+            readers.setdefault(read, []).append(name)
         if scope:
             scopes[name] = scope[1]
+        elif opcode.endswith(STAGING):
+            staging.add(name)
         if opcode == "fusion" or opcode in MATRIX_PRODUCTS:
             body.append((name, opcode, scope[1] if scope else "",
                          calls[1] if calls else None))
@@ -221,19 +209,24 @@ def compiled_program(text: str) -> Program:
             held = products_of(*rest)
             if held:
                 products[name] = held
+    staged = {
+        name: _nearest(name, scopes, readers) or _nearest(name, scopes, reads)
+        for name in staging
+    }
+    scopes.update((name, scope) for name, scope in staged.items() if scope)
     return Program(module, results, scopes, products)
 
 
-def programs_that_ran(trace: dict, programs) -> dict[str, list]:
+def programs_that_ran(ops: tr.Ops, programs) -> dict[str, list]:
     """Module of the trace (``jit_step(1444..)``: a name with its
     fingerprint) -> the compiled programs it may be: those of that name
-    in which most of the module's events are found, each by its
-    instruction's name and result type. Two programs made from one
-    function (the serving chunk with and without admission) share a name
-    and number their fusions apart, so the name alone does not say which
-    ran; the events do, unless the two agree wherever it matters."""
+    in which most of the module's instructions are found, each by its
+    name and result type. Two programs made from one function (the
+    serving chunk with and without admission) share a name and number
+    their fusions apart, so the name alone does not say which ran; the
+    events do, unless the two agree wherever it matters."""
     seen: dict[str, set] = {}
-    for _, module, text, _ in tr.events_in_modules(trace):
+    for module, text in ops.by_instruction:
         if module is not None:
             name, result, _ = tr.instruction(text)
             seen.setdefault(module, set()).add((name, result[:RESULT_LIMIT]))
@@ -286,3 +279,74 @@ def own_instruction(ran: dict, module_pattern: str, call=None, scope=None,
         return said == {True}
 
     return take
+
+
+def own_scope(ran: dict):
+    """``scope_of(text, module)`` for ``trace.top_ops``: the scope of an
+    event's own instruction in the program that ran its module, one
+    scope for the expert matmuls' custom calls (the compiler leaves them
+    no ``op_name`` but their own), and ``None``, which leaves the label
+    the instruction's name, where the program gives none or two
+    candidates disagree."""
+
+    def scope_of(text: str, module):
+        name = tr.parse_op(text)[0]
+        if RAGGED_CALL.match(name):
+            return RAGGED_SCOPE
+        said = {p.scopes.get(name) for p in ran.get(module, ())}
+        return said.pop() if len(said) == 1 else None
+
+    return scope_of
+
+
+# -- device time by scope -----------------------------------------------------
+
+
+def own_seconds(run, module_pattern: str = "", call=None, scope=None,
+                product_scope=None):
+    """``{"seconds", "events"}`` of a run's traced device ops that
+    ``own_instruction`` takes, each asked of the program that ran it
+    (``run.ran``), averaged over devices. ``None`` with no trace, and,
+    with the note ``<metric>.ambiguous``, where two programs the trace
+    cannot tell apart disagree on an instruction."""
+    if run.trace is None or not run.trace["devices"]:
+        return None
+    take = own_instruction(run.ran, module_pattern, call, scope, product_scope)
+    try:
+        return tr.event_seconds(run.ops, take)
+    except Ambiguous as which:
+        run.note("ambiguous", str(which))
+        return None
+
+
+def scope_share(run, pattern: str, name_pattern=None,
+                module_pattern: str = ""):
+    """Device time of the ops whose scope (the ``op_name`` of their own
+    instruction) matches ``pattern``, or whose instruction's name matches
+    ``name_pattern``, in the modules matching ``module_pattern``, as a
+    share of busy time in percent; the seconds stay in the note
+    ``<metric>.device_s``."""
+    taken = own_seconds(run, module_pattern, call=name_pattern, scope=pattern)
+    if taken is None:
+        return None
+    busy, _ = tr.busy_and_window(run.trace)
+    if not busy:
+        return None
+    run.note("device_s", taken["seconds"])
+    return 100.0 * taken["seconds"] / busy
+
+
+def unread_seconds(ops: tr.Ops, ran: dict) -> dict:
+    """Device seconds that no scope's pattern can take, averaged over
+    devices: ``unplaced``, the ops outside any execution or in a module
+    the run holds no compiled text of, and ``unscoped``, the ops whose
+    own instruction has no scope in the program that ran it (or has two,
+    by two candidates)."""
+    scope_of = own_scope(ran)
+    out = {"unplaced": 0.0, "unscoped": 0.0}
+    for (module, text), (own, _) in ops.by_instruction.items():
+        if not ran.get(module):
+            out["unplaced"] += own
+        elif scope_of(text, module) is None:
+            out["unscoped"] += own
+    return {key: seconds / ops.devices for key, seconds in out.items()}

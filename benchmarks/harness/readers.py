@@ -6,7 +6,9 @@ which loads ``benchmarks/metrics/<name>.py`` and calls its
 ``read(run)``. A reader that finds nothing to read returns ``None`` and
 the harness leaves the metric out of the line. Readers never touch the
 program: they see what the cell observed (``run.observed``), the
-normalised trace of a traced run (``run.trace``) and the cell's files.
+normalised trace of a traced run (``run.trace``) with its device ops
+grouped by instruction (``run.ops``) and the compiled programs that
+ran them (``run.ran``), and the cell's files.
 """
 
 import dataclasses
@@ -27,9 +29,42 @@ class Run:
     inventory: tuple
     device_kind: str
     trace: dict | None = None
-    scopes: dict | None = None
     programs: tuple = ()  # layers.compiled_program of each program traced
     notes: dict = dataclasses.field(default_factory=dict)
+    reading: str = "scope"  # the metric being read: what its notes go under
+    _grouped: tuple | None = dataclasses.field(default=None, repr=False)
+
+    def note(self, key: str, value) -> None:
+        """Beside the metric being read: ``<metric>.<key>``."""
+        self.notes[f"{self.reading}.{key}"] = value
+
+    def _once(self) -> tuple:
+        """The traced device ops grouped by instruction (``trace.grouped``)
+        and the compiled programs each module of the trace may be
+        (``layers.programs_that_ran``), worked out once a trace and not
+        once a metric: the Jamba cell's 4 s hold 540,000 events and the
+        Laguna step's text is 18 MB. Device time no scope's pattern can
+        take (``layers.unread_seconds``) is noted as ``scope.unplaced_ms``
+        and ``scope.unscoped_ms`` where it is not 0, so that a share that
+        leaves work out says so."""
+        held = self._grouped
+        if (held is None or held[0] is not self.trace
+                or held[1] is not self.programs):
+            ops = tr.grouped(self.trace)
+            ran = layers.programs_that_ran(ops, self.programs)
+            held = self._grouped = (self.trace, self.programs, ops, ran)
+            for key, seconds in layers.unread_seconds(ops, ran).items():
+                if seconds:
+                    self.notes[f"scope.{key}_ms"] = 1e3 * seconds
+        return held
+
+    @property
+    def ops(self) -> tr.Ops:
+        return self._once()[2]
+
+    @property
+    def ran(self) -> dict:
+        return self._once()[3]
 
     @property
     def hf(self) -> dict:
@@ -45,6 +80,7 @@ class Run:
 def read(run: Run, name: str):
     own = manifest.metric_file(name)
     reader = own["reader"]
+    run.reading = name
     if reader.get("file"):
         path = manifest.BENCH_DIR / "metrics" / f"{name}.py"
         spec = importlib.util.spec_from_file_location(
@@ -189,18 +225,11 @@ def kernel_roofline(run: Run, cost: str, module_pattern: str, call=None,
     metric's file says which events of the step program are the
     kernel's, each taken by its own instruction
     (``layers.own_instruction``): named like ``call``, under ``scope``,
-    or a matrix product under ``product_scope``. Nothing where two
-    programs the trace cannot tell apart disagree on one."""
-    if not _traced(run):
-        return None
-    take = layers.own_instruction(
-        layers.programs_that_ran(run.trace, run.programs),
-        module_pattern, call, scope, product_scope,
-    )
-    try:
-        measured = tr.event_seconds(run.trace, take)
-    except layers.Ambiguous as which:
-        run.notes[f"{cost}.ambiguous"] = str(which)
+    or a matrix product under ``product_scope`` (``layers.own_seconds``:
+    nothing where two programs the trace cannot tell apart disagree)."""
+    measured = layers.own_seconds(
+        run, module_pattern, call, scope, product_scope)
+    if measured is None:
         return None
     executions = len(tr.module_seconds(run.trace, module_pattern))
     executions /= max(len(run.trace["devices"]), 1)
@@ -209,7 +238,8 @@ def kernel_roofline(run: Run, cost: str, module_pattern: str, call=None,
     work = KERNEL_COSTS[cost](run)
     least, bound = costs.roofline_seconds(work, run.peak)
     share = tr.roofline_share(least * executions, measured["seconds"])
-    run.notes[f"{cost}.bound"] = bound
+    run.note("bound", bound)
+    run.note("device_s", measured["seconds"])
     return 100.0 * share
 
 

@@ -21,9 +21,11 @@ import bisect
 import glob
 import os
 import re
+import typing
 
 TEXT_LIMIT = 400
 LABEL_LIMIT = 96
+LEDGER_KEEPS = 64  # characters of a label
 
 COLLECTIVE_OPCODES = (
     "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -146,31 +148,31 @@ def parse_op(text: str) -> tuple[str, str]:
     return name, opcode
 
 
-def scopes_from_hlo(hlo_texts) -> dict[str, str]:
-    """Instruction name -> the ``op_name`` its metadata carries (the
-    ``jax.named_scope`` / module path), from compiled HLO text."""
-    scopes = {}
-    pattern = re.compile(r"^\s*(?:ROOT )?%?(\S+) = .*op_name=\"([^\"]*)\"")
-    for text in hlo_texts:
-        for line in text.splitlines():
-            m = pattern.match(line)
-            if m:
-                scopes.setdefault(m[1], m[2])
-    return scopes
+# what every op of a fused serving chunk carries ahead of its own scope:
+# the chunk's loop and the model's class and entry method
+_WRAPPERS = re.compile(r"^(?:jit\([^)]*\)/)+")
+_CHUNK_HEAD = re.compile(r"^while/body/closed_call/[A-Z]\w*\.\w+/")
 
 
-def label(text: str, scopes: dict[str, str] | None = None) -> str:
-    """``opcode:scope-or-name`` as the breakdown prints it."""
+def label(text: str, scope: str | None = None) -> str:
+    """``opcode:scope-or-name`` as the breakdown prints it. ``scope`` is
+    the ``op_name`` of the event's own instruction (``layers.own_scope``):
+    the leading ``jit(...)/`` wrappers go from it and the layers of a
+    stack add up under one label. A label over ``LABEL_LIMIT`` keeps both
+    ends, the scope's first 36 characters and the op. A serving chunk's
+    also loses the head that all its ops share, and where it is still
+    over what the ledger keeps, keeps its tail alone: the tail names the
+    op."""
     name, opcode = parse_op(text)
-    scope = (scopes or {}).get(name)
+    chunk = 0
     if scope:
-        # drop the leading jit(...)/ wrappers, keep the module path; the
-        # layers of a stack add up under one label
-        scope = re.sub(r"^(?:jit\([^)]*\)/)+", "", scope)
-        scope = re.sub(r"layers_\d+", "layers_*", scope)
-    full = f"{opcode or 'op'}:{scope or name}"
-    if len(full) > LABEL_LIMIT:  # keep both ends: the scope and the op
-        full = full[:36] + ".." + full[-(LABEL_LIMIT - 38):]
+        scope = re.sub(r"layers_\d+", "layers_*", _WRAPPERS.sub("", scope))
+        scope, chunk = _CHUNK_HEAD.subn("", scope)
+    head = f"{opcode or 'op'}:"
+    full = head + (scope or name)
+    kept, limit = (len(head), LEDGER_KEEPS) if chunk else (36, LABEL_LIMIT)
+    if len(full) > limit:
+        full = full[:kept] + ".." + full[kept + 2 - limit:]
     return full
 
 
@@ -281,11 +283,6 @@ def self_times_at(events) -> list[tuple[str, float, float]]:
     return out
 
 
-def self_times(events) -> list[tuple[str, float]]:
-    """``(text, self seconds)`` per event of one device lane."""
-    return [(text, seconds) for text, _, seconds in self_times_at(events)]
-
-
 def events_in_modules(trace: dict, window=None):
     """``(device, module, text, self seconds)`` for every device op of
     the window. ``module`` is the execution the op started in, as the
@@ -304,31 +301,47 @@ def events_in_modules(trace: dict, window=None):
             yield ordinal, runs[i][2] if covered else None, text, seconds
 
 
-def event_seconds(trace: dict, take, window=None) -> dict:
-    """Self time of the device ops that ``take(text, module)`` accepts
-    (``events_in_modules``), averaged over devices; with the event count
-    per device."""
-    seconds, events = 0.0, 0
+class Ops(typing.NamedTuple):
+    """A window's device ops, grouped once a trace: what every reader of
+    device time by instruction sums over."""
+
+    devices: int
+    by_instruction: dict  # (module, text) -> [self seconds, events], summed
+
+
+def grouped(trace: dict, window=None) -> Ops:
+    """``events_in_modules`` by the execution's module and the op's text:
+    one instruction of one program, however often it ran."""
+    out: dict = {}
     for _, module, text, own in events_in_modules(trace, window):
+        row = out.setdefault((module, text), [0.0, 0])
+        row[0] += own
+        row[1] += 1
+    return Ops(max(len(trace["devices"]), 1), out)
+
+
+def event_seconds(ops: Ops, take) -> dict:
+    """Self time of the device ops that ``take(text, module)`` accepts,
+    averaged over devices; with the event count per device."""
+    seconds, events = 0.0, 0
+    for (module, text), (own, count) in ops.by_instruction.items():
         if take(text, module):
-            seconds, events = seconds + own, events + 1
-    n_dev = max(len(trace["devices"]), 1)
-    return {"seconds": seconds / n_dev, "events": events / n_dev}
+            seconds, events = seconds + own, events + count
+    return {"seconds": seconds / ops.devices, "events": events / ops.devices}
 
 
-def top_ops(trace: dict, scopes=None, n: int = 10, window=None):
+def top_ops(ops: Ops, scope_of=None, n: int = 10):
     """The ``n`` device operations with most self time, by label, summed
-    over the window and averaged over devices."""
-    lo, hi = window or window_of(trace)
+    over the window and averaged over devices. ``scope_of(text, module)``
+    gives an event's scope (``layers.own_scope``: from its own
+    instruction in the program that ran its module); without it, or where
+    it gives none, the label is the instruction's name."""
     totals: dict[str, float] = {}
-    for lanes in trace["devices"].values():
-        inside = [e for e in lanes["ops"] if lo <= e[1] < hi]
-        for text, seconds in self_times(inside):
-            key = label(text, scopes)
-            totals[key] = totals.get(key, 0.0) + seconds
-    n_dev = max(len(trace["devices"]), 1)
+    for (module, text), (seconds, _) in ops.by_instruction.items():
+        key = label(text, scope_of(text, module) if scope_of else None)
+        totals[key] = totals.get(key, 0.0) + seconds
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, v / n_dev] for k, v in ranked]
+    return [[k, v / ops.devices] for k, v in ranked]
 
 
 def module_seconds(trace: dict, pattern: str, window=None) -> list[float]:

@@ -1,12 +1,10 @@
 """Operations and bytes the Kimi delta attention mixer's decode step
 needs, from the configuration file's Hugging Face keys (``solar_open2``'s),
 the serving slots and the number of decode steps; nothing is taken from
-the program. It is what a ``kernel.kda_decode_roofline`` divides by: that
-metric and ``model.decode_kda_device_pct`` are not listed yet, because an
-entry can only be appended to ``per_layer`` and a test of PR 48 pins the
-list's last place (PERF.md section 7, ROADMAP B3(c)). Mamba-2's count
-(``ssm2_decode_cost``) is another recurrence under other keys and is not
-borrowed.
+the program. It is what ``kernel.kda_decode_roofline`` divides by
+(listed for the Solar cell since PR 53, beside
+``model.decode_kda_device_pct``). Mamba-2's count (``ssm2_decode_cost``)
+is another recurrence under other keys and is not borrowed.
 
 One decode step of one KDA layer processes all ``slots`` rows, busy or
 not (the shapes are static), with ``H = linear_attn_config.num_heads``

@@ -16,17 +16,9 @@ RERUN = r"rematted_computation.*" + FLASH
 
 
 def read(run):
-    if run.trace is None or not run.trace["devices"]:
-        return None
-    ran = layers.programs_that_ran(run.trace, run.programs)
-    try:
-        flash, rerun = (
-            tr.event_seconds(
-                run.trace, layers.own_instruction(ran, STEP, scope=scope))
-            for scope in (FLASH, RERUN)
-        )
-    except layers.Ambiguous as which:
-        run.notes["flash_rerun.ambiguous"] = str(which)
+    flash = layers.own_seconds(run, STEP, scope=FLASH)
+    rerun = layers.own_seconds(run, STEP, scope=RERUN)
+    if not flash or not rerun:
         return None
     steps = len(tr.module_seconds(run.trace, STEP))
     steps /= len(run.trace["devices"])

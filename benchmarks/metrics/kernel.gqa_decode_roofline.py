@@ -20,7 +20,7 @@ KERNEL = r"paged_decode_p\d+/pallas_call"
 
 def read(run):
     traced = getattr(run.observed, "traced", None)
-    if run.trace is None or not run.trace["devices"] or not traced:
+    if not traced:
         return None
     chunks = [
         s.meta for s in layers.spans_between(
@@ -30,16 +30,8 @@ def read(run):
     ]
     if not chunks:
         return None
-    take = layers.own_instruction(
-        layers.programs_that_ran(run.trace, run.programs),
-        "fused", scope=KERNEL,
-    )
-    try:
-        measured = tr.event_seconds(run.trace, take)
-    except layers.Ambiguous as which:
-        run.notes["gqa_decode.ambiguous"] = str(which)
-        return None
-    if not measured["events"]:
+    measured = layers.own_seconds(run, "fused", scope=KERNEL)
+    if not measured or not measured["events"]:
         return None
     work = gqa_decode_cost.gqa_decode_work(
         run.hf,
@@ -49,7 +41,7 @@ def read(run):
         ),
     )
     least, bound = costs.roofline_seconds(work, run.peak)
-    run.notes["gqa_decode.bound"] = bound
-    run.notes["gqa_decode.traced_chunks"] = len(chunks)
-    run.notes["gqa_decode.device_s"] = measured["seconds"]
+    run.note("bound", bound)
+    run.note("traced_chunks", len(chunks))
+    run.note("device_s", measured["seconds"])
     return 100.0 * tr.roofline_share(least, measured["seconds"])

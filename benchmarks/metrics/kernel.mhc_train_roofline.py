@@ -23,8 +23,8 @@ def read(run):
         return None
     if mhc_train_cost.streams(run.hf) < 2:
         return None
-    seconds = layers.scope_seconds(
-        run.trace, run.scopes, mhc_train_cost.SCOPE)
+    taken = layers.own_seconds(run, scope=mhc_train_cost.SCOPE)
+    seconds = taken and taken["seconds"]
     steps = len(tr.module_seconds(run.trace, STEP))
     steps /= max(len(run.trace["devices"]), 1)
     if not seconds or not steps:
@@ -32,7 +32,7 @@ def read(run):
     o = run.observed
     work = mhc_train_cost.mhc_train_work(run.hf, o.tokens_per_step // o.chips)
     least, bound = costs.roofline_seconds(work, run.peak)
-    run.notes["mhc_train.bound"] = bound
-    run.notes["mhc_train.traced_steps"] = steps
-    run.notes["mhc_train.device_s"] = seconds
+    run.note("bound", bound)
+    run.note("traced_steps", steps)
+    run.note("device_s", seconds)
     return 100.0 * tr.roofline_share(least * steps, seconds)

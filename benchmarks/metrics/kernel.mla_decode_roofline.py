@@ -28,7 +28,8 @@ def read(run):
             layers.program_spans(), *traced, names={"serve/step"}
         ) if s.meta and "positions_attended" in s.meta
     ]
-    seconds = layers.scope_seconds(run.trace, run.scopes, STAGES)
+    taken = layers.own_seconds(run, scope=STAGES)
+    seconds = taken and taken["seconds"]
     if not chunks or not seconds:
         return None
     work = mla_decode_cost.mla_decode_work(
@@ -38,7 +39,7 @@ def read(run):
         steps=len(chunks) * run.observed.chunk_k,
     )
     least, bound = costs.roofline_seconds(work, run.peak)
-    run.notes["mla_decode.bound"] = bound
-    run.notes["mla_decode.traced_chunks"] = len(chunks)
-    run.notes["mla_decode.device_s"] = seconds
+    run.note("bound", bound)
+    run.note("traced_chunks", len(chunks))
+    run.note("device_s", seconds)
     return 100.0 * tr.roofline_share(least, seconds)

@@ -36,7 +36,8 @@ def read(run):
             layers.program_spans(), *traced, names={"serve/step"}
         ) if s.meta and s.meta.get("recurrent_state_bytes")
     ]
-    seconds = layers.scope_seconds(run.trace, run.scopes, MIXER)
+    taken = layers.own_seconds(run, scope=MIXER)
+    seconds = taken and taken["seconds"]
     if not chunks or not seconds:
         return None
     work = ssm2_decode_cost.ssm2_decode_work(
@@ -44,7 +45,7 @@ def read(run):
         steps=len(chunks) * run.observed.chunk_k,
     )
     least, bound = costs.roofline_seconds(work, run.peak)
-    run.notes["ssm2_decode.bound"] = bound
-    run.notes["ssm2_decode.traced_chunks"] = len(chunks)
-    run.notes["ssm2_decode.device_s"] = seconds
+    run.note("bound", bound)
+    run.note("traced_chunks", len(chunks))
+    run.note("device_s", seconds)
     return 100.0 * tr.roofline_share(least, seconds)

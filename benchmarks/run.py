@@ -108,10 +108,6 @@ def main(argv=None) -> int:
         )
         if trace_dir is not None and platform == "tpu":
             run.trace = tr.load_xplane(tr.newest_xplane(trace_dir))
-            # the expert matmuls' custom calls carry no scope of their own
-            run.scopes = layers.with_expert_matmuls(
-                tr.scopes_from_hlo(observed.hlo_texts), observed.hlo_texts
-            )
             run.programs = tuple(
                 map(layers.compiled_program, observed.hlo_texts)
             )
@@ -150,7 +146,8 @@ def main(argv=None) -> int:
         device.update(busy_s=busy, window_s=window)
         spans = tr.program_spans(run.trace)
         line["breakdown"] = {
-            "device_ops": tr.top_ops(run.trace, run.scopes, n=10),
+            "device_ops": tr.top_ops(
+                run.ops, layers.own_scope(run.ran), n=10),
             "idle_gaps": tr.idle_gaps(run.trace, spans, n=10),
         }
         if args.trace == 2:

@@ -264,11 +264,11 @@ def test_each_kinds_roofline_takes_its_own_calls():
     got = readers.read(run, "kernel.flash_window_train_roofline")
     assert got == pytest.approx(
         100 * costs.roofline_seconds(window, PEAK)[0] / 42e-3)
-    assert run.notes["flash_window_train.bound"] == "compute"
+    assert run.notes["kernel.flash_window_train_roofline.bound"] == "compute"
     got = readers.read(run, "kernel.flash_full_train_roofline")
     assert got == pytest.approx(
         100 * costs.roofline_seconds(full, PEAK)[0] / 35e-3)
-    assert run.notes["flash_full_train.bound"] == "compute"
+    assert run.notes["kernel.flash_full_train_roofline.bound"] == "compute"
     # a program whose layers are all of the plain kind: the window's reads
     # nothing, the full kind's every call
     plain = {k: v.replace("attn_window/", "") for k, v in SCOPES.items()}

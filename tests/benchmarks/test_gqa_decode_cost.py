@@ -206,8 +206,8 @@ def test_the_roofline_takes_the_kernels_calls_and_the_chunks_counts(
     # both kernels' calls and nothing else under the attention scopes
     assert got == pytest.approx(100 * least / 16e-3)
     assert 0 < got < 100
-    assert run.notes["gqa_decode.bound"] == "memory"
-    assert run.notes["gqa_decode.traced_chunks"] == 1
+    assert run.notes["kernel.gqa_decode_roofline.bound"] == "memory"
+    assert run.notes["kernel.gqa_decode_roofline.traced_chunks"] == 1
     # spans without the window layers' count: the parent's program
     bare = traced_run(monkeypatch, SCOPES, seconds,
                       {"positions_attended": 1, "slot_steps_busy": 1})

@@ -13,6 +13,7 @@ import pytest
 
 from benchmarks.harness import layers, readers
 from benchmarks.harness import trace as tr
+from tests.benchmarks.hand_made import STEP, program, trace_of
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -23,10 +24,10 @@ def recorded():
         return json.load(f)
 
 
-def a_run(trace=None, scopes=None, observed=None, inventory=()):
+def a_run(trace=None, programs=(), observed=None, inventory=()):
     return readers.Run(
         cell=None, observed=observed, setup_s=0.0, inventory=inventory,
-        device_kind="TPU v5 lite", trace=trace, scopes=scopes,
+        device_kind="TPU v5 lite", trace=trace, programs=tuple(programs),
     )
 
 
@@ -34,7 +35,8 @@ def a_run(trace=None, scopes=None, observed=None, inventory=()):
 
 
 def test_model_shares_on_the_recorded_trace(recorded):
-    run = a_run(recorded["trace"], recorded["scopes"])
+    step = program(recorded["scopes"], STEP)
+    run = a_run(recorded["trace"], [step])
     share = {
         layer: readers.read(run, f"model.train_{layer}_device_pct")
         for layer in ("experts", "attention", "head_loss", "optimizer")
@@ -52,29 +54,37 @@ def test_model_shares_on_the_recorded_trace(recorded):
     # a pattern on the whole text also takes the fusions that read a
     # ragged-dot's result (0.1549 s: what kernel.expert_mm_train_roofline
     # summed until PR 34), this takes the calls alone (0.0906 s, 58 %)
-    calls = layers.scope_seconds(
-        recorded["trace"], {}, r"never", layers.RAGGED_CALL
-    )
+    calls = layers.own_seconds(
+        run, call=layers.RAGGED_CALL.pattern, scope=r"never")["seconds"]
     busy = recorded["expected"]["busy_s"]
     assert 100 * calls / busy == pytest.approx(share["experts"])
     assert calls < recorded["expected"]["ragged_dot_s"]
     # and so do the expert rooflines: 6 calls and 2 of metadata a step
-    program = layers.Program("jit_step", {}, recorded["scopes"], {})
-    ran = layers.programs_that_ran(recorded["trace"], [program])
-    assert ran["jit_step(14448973072706519740)"] == [program]
+    ran = run.ran
+    assert ran["jit_step(14448973072706519740)"] == [step]
     assert ran["jit__threefry_fold_in(15899896716144297254)"] == []
-    taken = tr.event_seconds(recorded["trace"], layers.own_instruction(
+    # the ops no scope can be read for are in no share, and the run says
+    # how much they are: 12 of the program whose text the run does not
+    # hold, and 59 (5.3 ms of the 1.13 s) of an execution that had begun
+    # when the capture started; and, in the step itself, those whose
+    # instruction carries no scope (copies, the loop's own time)
+    unread = layers.unread_seconds(run.ops, ran)
+    assert run.notes["scope.unplaced_ms"] == 1e3 * unread["unplaced"]
+    assert run.notes["scope.unscoped_ms"] == 1e3 * unread["unscoped"]
+    assert run.notes["scope.unplaced_ms"] == pytest.approx(5.305, abs=1e-3)
+    assert run.notes["scope.unscoped_ms"] == pytest.approx(6.852, abs=1e-3)
+    taken = tr.event_seconds(run.ops, layers.own_instruction(
         ran, "train_step|jit_step", call="ragged-dot",
         product_scope="moe/experts/(gate_up|down)/"))
     assert taken == {"seconds": pytest.approx(calls), "events": 16}
     assert calls == pytest.approx(0.0906, abs=1e-4)
     # the flash kernels are the custom calls under a self_attn scope
-    flash = tr.event_seconds(recorded["trace"], layers.own_instruction(
+    flash = tr.event_seconds(run.ops, layers.own_instruction(
         ran, "train_step|jit_step", scope="self_attn.*pallas_call"))
     assert flash["events"] == recorded["expected"]["flash_events"]
     # a kernel's events are those of the step program alone
     other = layers.own_instruction(ran, "no_such_module", call="ragged-dot")
-    assert tr.event_seconds(recorded["trace"], other)["events"] == 0
+    assert tr.event_seconds(run.ops, other)["events"] == 0
 
 
 def test_model_shares_need_a_trace():
@@ -127,22 +137,32 @@ HLO = """
 
 
 def test_every_ragged_dot_call_gets_the_experts_scope():
-    plain = tr.scopes_from_hlo([HLO])
-    assert plain["ragged-dot-none.3"] == "ragged-dot-none"  # what the compiler left
-    scopes = layers.with_expert_matmuls(plain, [HLO])
-    assert {k: v for k, v in scopes.items() if k.startswith("ragged-dot")} == {
+    plain = layers.compiled_program(
+        "HloModule jit_step\n\nENTRY %main (x: bf16[8]) -> bf16[8] {" + HLO
+        + "}\n")
+    assert plain.scopes["ragged-dot-none.3"] == "ragged-dot-none"  # what the compiler left
+    scope_of = layers.own_scope({"jit_step(1)": [plain]})
+    event = lambda name: f"%{name} = bf16[8]{{0}} custom-call(bf16[8]{{0}} %p)"  # noqa: E731
+    assert {name: scope_of(event(name), "jit_step(1)")
+            for name in plain.results if name.startswith("ragged-dot")} == {
         "ragged-dot-metadata": layers.RAGGED_SCOPE,
         "ragged-dot-none.3": layers.RAGGED_SCOPE,
         "ragged-dot-none": layers.RAGGED_SCOPE,  # no metadata at all
     }
     # every other instruction keeps the scope it had
-    assert {k: v for k, v in scopes.items()
-            if not k.startswith("ragged-dot")} == {
-        k: v for k, v in plain.items() if not k.startswith("ragged-dot")}
+    assert {name: scope_of(event(name), "jit_step(1)")
+            for name in ("concat.1", "act.1")} == {
+        name: plain.scopes[name] for name in ("concat.1", "act.1")}
     text = ('%ragged-dot-none.3 = bf16[512,256]{1,0} custom-call(s32[9]{0} '
             '%meta.0), custom_call_target="tpu_custom_call"')
-    assert tr.label(text, plain) == "custom-call:ragged-dot-none"
-    assert tr.label(text, scopes) == "custom-call:moe/experts/ragged_dot"
+    assert tr.label(text, plain.scopes["ragged-dot-none.3"]) == \
+        "custom-call:ragged-dot-none"
+    assert tr.label(text, scope_of(text, "jit_step(1)")) == \
+        "custom-call:moe/experts/ragged_dot"
+    # in a module the run holds no text of, a call is still the experts'
+    # and any other event keeps its instruction's name
+    assert scope_of(text, "jit_other(2)") == layers.RAGGED_SCOPE
+    assert scope_of(event("act.1"), "jit_other(2)") is None
     # the accepted roofline readers find these calls by name, not by scope
     assert not layers.RAGGED_CALL.search(layers.RAGGED_SCOPE)
 
@@ -340,26 +360,231 @@ def test_an_instruction_is_looked_up_in_the_program_that_ran_it():
         ["jit_fused_fn(11)", 0.0, 1.0, 1], ["jit_step_fn(22)", 2.0, 1.0, 2],
         ["jit_fused_fn(33)", 4.0, 3.0, 3],
     ]}}, "host": []}
-    ran = layers.programs_that_ran(trace, [chunk, step, admit])
+    ops = tr.grouped(trace)
+    ran = layers.programs_that_ran(ops, [chunk, step, admit])
     # the name says which function, the events which of its two programs
     assert ran == {"jit_fused_fn(11)": [chunk], "jit_step_fn(22)": [step],
                    "jit_fused_fn(33)": [admit]}
     take = layers.own_instruction(ran, "fused", product_scope=EXPERTS)
-    assert tr.event_seconds(trace, take) == {"seconds": 2.5, "events": 2}
+    assert tr.event_seconds(ops, take) == {"seconds": 2.5, "events": 2}
     # with the step program asked for too, its fusion.9 is still attention
     both = layers.own_instruction(ran, "fused|step", product_scope=EXPERTS)
-    assert tr.event_seconds(trace, both) == {"seconds": 2.5, "events": 2}
+    assert tr.event_seconds(ops, both) == {"seconds": 2.5, "events": 2}
     # where the events do not tell two programs of one name apart and the
     # two disagree on an instruction, nothing is read rather than a guess
     blind = [p._replace(results={}) for p in (chunk, step, admit)]
-    unsure = layers.programs_that_ran(trace, blind)
+    unsure = layers.programs_that_ran(ops, blind)
     assert unsure["jit_fused_fn(11)"] == [blind[0], blind[2]]
     with pytest.raises(layers.Ambiguous, match="fusion.9"):
-        tr.event_seconds(trace, layers.own_instruction(
+        tr.event_seconds(ops, layers.own_instruction(
             unsure, "fused", product_scope=EXPERTS))
     # where they agree (a call the compiler names itself), it is read
     calls = layers.own_instruction(unsure, "fused", call="fusion.12")
-    assert tr.event_seconds(trace, calls) == {"seconds": 1.5, "events": 1}
+    assert tr.event_seconds(ops, calls) == {"seconds": 1.5, "events": 1}
+
+
+# -- device time by scope, each event by its own program -----------------------
+
+HEAD = "jit(fused_fn)/while/body/closed_call/Qwen3MoeCausalLM.logits_last/"
+DOWN_SCOPE = HEAD + "model/layers_0/mlp/moe/experts/down/all_experts/dot_general"
+Q_SCOPE = HEAD + "model/layers_0/self_attn/q_proj/dot_general"
+RESET_SCOPE = "jit(fused_fn)/serve/reset_rows/dynamic_update_slice"
+WIDE, NARROW, ROWS = "bf16[64,4096]{1,0}", "bf16[64,2048]{1,0}", "s32[64]{0}"
+
+
+def chunk_text(*instructions) -> str:
+    """A compiled text of the serving chunk: ``(name, result, scope)``."""
+    return (
+        "HloModule jit_fused_fn, is_scheduled=true\n\n"
+        "ENTRY %main (x: bf16[64,2048]) -> bf16[64,2048] {\n" + "".join(
+            f"  %{name} = {result} fusion(%x), kind=kLoop, "
+            f'calls=%fused_computation.{i}, metadata={{op_name="{scope}"}}\n'
+            for i, (name, result, scope) in enumerate(instructions)) + "}\n")
+
+
+# the chunk as the batcher compiles it twice, with admission and without:
+# one function, one module name, and ``fusion.7`` is the experts' down
+# product in one text and the attention's query projection in the other
+PLAIN_TEXT = chunk_text(("fusion.7", NARROW, DOWN_SCOPE),
+                        ("fusion.8", WIDE, Q_SCOPE))
+ADMIT_TEXT = chunk_text(("fusion.7", WIDE, Q_SCOPE),
+                        ("fusion.8", ROWS, RESET_SCOPE),
+                        ("fusion.9", NARROW, DOWN_SCOPE))
+
+
+def two_chunks_trace():
+    """Two executions of the plain chunk and one of the admitting one."""
+    event = lambda name, result: f"%{name} = {result} fusion(bf16[64,2048]{{1,0}} %x), kind=kLoop"  # noqa: E731
+    ops, modules, t = [], [], 0.0
+    for module, events in (
+        ("jit_fused_fn(11)", [("fusion.7", NARROW, 1.0), ("fusion.8", WIDE, 0.25)]),
+        ("jit_fused_fn(22)", [("fusion.7", WIDE, 0.5), ("fusion.8", ROWS, 0.125),
+                              ("fusion.9", NARROW, 1.5)]),
+        ("jit_fused_fn(11)", [("fusion.7", NARROW, 1.0), ("fusion.8", WIDE, 0.25)]),
+    ):
+        start = t
+        for name, result, seconds in events:
+            ops.append([event(name, result), t, seconds])
+            t += seconds
+        modules.append([module, start, t - start, len(modules) + 1])
+        t += 0.5  # the device idles between two chunks
+    return {"devices": {"0": {"ops": ops, "async": [], "modules": modules}},
+            "host": []}
+
+
+EXPERTS_PCT = "model.decode_experts_device_pct"
+ATTENTION_PCT = "model.decode_attention_device_pct"
+
+
+@pytest.mark.parametrize("texts", [(PLAIN_TEXT, ADMIT_TEXT),
+                                   (ADMIT_TEXT, PLAIN_TEXT)],
+                         ids=["plain_first", "admitting_first"])
+def test_shares_and_labels_ask_the_program_that_ran_the_event(texts):
+    """Until PR 53 an instruction's name alone gave its scope and the
+    first text won: the admitting chunk's ``fusion.7`` (0.5 s of query
+    projections) counted as the experts', or the plain chunk's two
+    seconds of down products as attention, by the order of the texts."""
+    run = a_run(two_chunks_trace(), map(layers.compiled_program, texts))
+    busy = 2 * 1.25 + 2.125
+    assert readers.read(run, EXPERTS_PCT) == pytest.approx(100 * 3.5 / busy)
+    assert readers.read(run, ATTENTION_PCT) == pytest.approx(100 * 1.0 / busy)
+    assert run.notes[EXPERTS_PCT + ".device_s"] == pytest.approx(3.5)
+    assert run.notes[ATTENTION_PCT + ".device_s"] == pytest.approx(1.0)
+    assert not {"scope.unplaced_ms", "scope.unscoped_ms"} & set(run.notes)
+    assert [len(run.ran[m]) for m in ("jit_fused_fn(11)", "jit_fused_fn(22)")] \
+        == [1, 1]
+    # a scope only the admitting chunk has, over one module or all
+    assert layers.own_seconds(run, scope="serve/reset_rows") == {
+        "seconds": 0.125, "events": 1}
+    assert layers.own_seconds(run, "step", scope="serve/reset_rows") == {
+        "seconds": 0.0, "events": 0}
+    # the breakdown names each event by its own program's scope
+    assert tr.top_ops(run.ops, layers.own_scope(run.ran)) == [
+        ["fusion:..l/layers_*/mlp/moe/experts/down/all_experts/dot_general",
+         pytest.approx(3.5)],
+        ["fusion:model/layers_*/self_attn/q_proj/dot_general",
+         pytest.approx(1.0)],
+        ["fusion:serve/reset_rows/dynamic_update_slice", 0.125],
+    ]
+
+
+def test_two_candidates_that_disagree_give_nothing_and_a_note():
+    """Events that do not tell the two chunks apart (a trace that cut
+    their result types away): a share that would be a guess is not read,
+    and the breakdown falls back on the instruction's name."""
+    blind = [layers.compiled_program(t)._replace(results={})
+             for t in (PLAIN_TEXT, ADMIT_TEXT)]
+    run = a_run(two_chunks_trace(), blind)
+    assert [len(c) for c in run.ran.values()] == [2, 2]
+    assert readers.read(run, EXPERTS_PCT) is None
+    assert readers.read(run, ATTENTION_PCT) is None
+    assert "fusion.7 of jit_fused_fn" in run.notes[EXPERTS_PCT + ".ambiguous"]
+    assert ATTENTION_PCT + ".ambiguous" in run.notes
+    assert EXPERTS_PCT + ".device_s" not in run.notes
+    top = dict(tr.top_ops(run.ops, layers.own_scope(run.ran)))
+    assert top["fusion:fusion.7"] == pytest.approx(2.5)
+    assert top["fusion:fusion.8"] == pytest.approx(0.625)
+    # fusion.9 is the admitting chunk's alone; the plain one does not
+    # have it, so the two candidates say two things of it as well
+    assert top["fusion:fusion.9"] == pytest.approx(1.5)
+    # a kernel roofline's files leave the same kind of note
+    assert readers.read(run, "model.decode_window_attention_device_pct") is None
+
+
+STATE_SCOPE = HEAD + "model/layers_0/mamba/state_update/mul"
+STAGED_TEXT = (
+    "HloModule jit_fused_fn, is_scheduled=true\n\n"
+    "%body (p: (f32[64,16], bf16[64,2048])) -> (f32[64,16], bf16[64,2048]) {\n"
+    "  %p = (f32[64,16]{1,0}, bf16[64,2048]{1,0}) parameter(0)\n"
+    "  %get-tuple-element.1 = f32[64,16]{1,0} get-tuple-element(%p), index=0\n"
+    "  %get-tuple-element.2 = bf16[64,2048]{1,0} get-tuple-element(%p), index=1\n"
+    # a weight brought into fast memory for the product that reads it
+    "  %copy-start.1 = (bf16[64,2048]{1,0:S(1)}, bf16[64,2048]{1,0}, u32[]) "
+    "copy-start(%get-tuple-element.2)\n"
+    "  %copy-done.1 = bf16[64,2048]{1,0:S(1)} copy-done(%copy-start.1)\n"
+    "  %slice-start.1 = ((f32[64,16]{1,0}), f32[64,8]{1,0:S(1)}, s32[]) "
+    "async-start(%get-tuple-element.1), calls=%async_slice\n"
+    "  %slice-done.1 = f32[64,8]{1,0:S(1)} async-done(%slice-start.1)\n"
+    "  %copy.4 = bf16[64,2048]{0,1} copy(%copy-done.1)\n"
+    f"  %fusion.7 = {NARROW} fusion(%copy.4, %slice-done.1), kind=kOutput, "
+    f'calls=%fused_computation.1, metadata={{op_name="{DOWN_SCOPE}"}}\n'
+    f"  %fusion.8 = f32[64,16]{{1,0}} fusion(%fusion.7), kind=kLoop, "
+    f'calls=%fused_computation.2, metadata={{op_name="{STATE_SCOPE}"}}\n'
+    # the loop's carried state copied out: nothing with a scope reads it
+    "  %copy-start.2 = (f32[64,16]{1,0}, f32[64,16]{1,0}, u32[]) "
+    "copy-start(%fusion.8)\n"
+    "  %copy-done.2 = f32[64,16]{1,0} copy-done(%copy-start.2)\n"
+    # what the compiler made of nothing the program names
+    "  %copy.5 = bf16[64,2048]{0,1} copy(%get-tuple-element.2)\n"
+    "  %bitcast.6 = bf16[64,2048]{1,0} bitcast(%get-tuple-element.2)\n"
+    "  ROOT %tuple.9 = (f32[64,16]{1,0}, bf16[64,2048]{1,0}) "
+    "tuple(%copy-done.2, %copy.5)\n}\n")
+
+
+def test_a_staging_instruction_takes_the_scope_it_stages_for():
+    """The compiler brings an instruction's operands to it by copies and
+    slices of its own, which it runs beside the compute, waits for under
+    a ``-done`` and gives no ``op_name``: 19 % of the Jamba chunk's device
+    time, which no share took and ``kernel.ssm_decode_roofline`` read
+    89.9 without (my chip run, PR 53). Such an instruction is its
+    reader's work, through other such instructions; a carried state's
+    copy, which only the loop's tuple reads, is its operand's."""
+    staged = layers.compiled_program(STAGED_TEXT)
+    for name in ("copy-start.1", "copy-done.1", "copy.4", "slice-start.1",
+                 "slice-done.1"):
+        assert staged.scopes[name] == DOWN_SCOPE, name
+    assert staged.scopes["copy-start.2"] == STATE_SCOPE
+    assert staged.scopes["copy-done.2"] == STATE_SCOPE
+    # a copy that neither reads nor feeds a scope, and an instruction
+    # that is no staging, have none
+    assert "copy.5" not in staged.scopes and "bitcast.6" not in staged.scopes
+    event = lambda name, result, opcode: f"%{name} = {result} {opcode}(%x)"  # noqa: E731
+    ops = [[event("copy-done.1", "bf16[64,2048]{1,0:S(1)}", "copy-done"), 0.0, 0.25],
+           [event("slice-done.1", "f32[64,8]{1,0:S(1)}", "async-done"), 0.25, 0.125],
+           [event("fusion.7", NARROW, "fusion"), 0.375, 1.0],
+           [event("fusion.8", "f32[64,16]{1,0}", "fusion"), 1.375, 0.5],
+           [event("copy-done.2", "f32[64,16]{1,0}", "copy-done"), 1.875, 0.0625],
+           [event("copy.5", "bf16[64,2048]{0,1}", "copy"), 1.9375, 0.0625]]
+    run = a_run(trace_of(ops), [staged])
+    assert readers.read(run, EXPERTS_PCT) == pytest.approx(100 * 1.375 / 2.0)
+    assert readers.read(run, "model.decode_ssm_device_pct") == \
+        pytest.approx(100 * 0.5625 / 2.0)
+    assert run.notes["scope.unscoped_ms"] == pytest.approx(62.5)
+    top = dict(tr.top_ops(run.ops, layers.own_scope(run.ran)))
+    assert top["copy-done:..ayers_*/mlp/moe/experts/down/all_experts/"
+               "dot_general"] == 0.25
+    assert top["copy:copy.5"] == 0.0625
+
+
+def test_device_time_no_scope_can_be_read_for_is_said():
+    """An op of a module whose compiled text the run does not hold (a
+    sampling program beside the chunk), an op outside any execution and
+    an op whose instruction carries no scope in its program (an
+    asynchronous copy of an operand into fast memory) are in no share;
+    two notes say how much they are."""
+    trace = two_chunks_trace()
+    lanes = trace["devices"]["0"]
+    lanes["ops"] += [
+        ["%copy.1 = bf16[8]{0} copy(bf16[8]{0} %y)", 7.0, 0.25],
+        ["%fusion.7 = u32[64]{0} fusion(u32[2]{0} %key)", 8.0, 0.125],
+        ["%copy-done.3 = bf16[8,64]{1,0} copy-done((bf16[8,64]{1,0}) %c)",
+         9.0, 0.0625]]
+    lanes["modules"] += [["jit__threefry_fold_in(5)", 8.0, 0.125, 4],
+                         ["jit_fused_fn(11)", 9.0, 0.0625, 5]]
+    run = a_run(trace, map(layers.compiled_program, (PLAIN_TEXT, ADMIT_TEXT)))
+    assert run.ran["jit__threefry_fold_in(5)"] == []
+    assert readers.read(run, EXPERTS_PCT) == pytest.approx(
+        100 * 3.5 / (4.625 + 0.375 + 0.0625))
+    assert run.notes["scope.unplaced_ms"] == pytest.approx(375.0)
+    assert run.notes["scope.unscoped_ms"] == pytest.approx(62.5)
+    # the other program's fusion.7 keeps its name in the breakdown
+    top = dict(tr.top_ops(run.ops, layers.own_scope(run.ran)))
+    assert top["fusion:fusion.7"] == 0.125 and top["copy:copy.1"] == 0.25
+    assert top["copy-done:copy-done.3"] == 0.0625
+    none_held = a_run(two_chunks_trace(), [program({}, STEP)])
+    assert readers.read(none_held, EXPERTS_PCT) == 0.0
+    assert none_held.notes["scope.unplaced_ms"] == pytest.approx(4625.0)
+    assert "scope.unscoped_ms" not in none_held.notes
 
 
 # -- the serving loop's phase readers ------------------------------------------

@@ -1,9 +1,12 @@
 """``BENCHMARK.json`` against the contract, and against the files it
 names. Nothing here needs a device."""
 
+import contextlib
 import functools
+import inspect
 import json
 import re
+import subprocess
 from pathlib import Path
 
 import pytest
@@ -481,15 +484,90 @@ def with_an_appended_entry(tmp_path, cell: str):
 @functools.cache
 def the_other_files_checks() -> dict:
     """The test files that check the manifest's or a cell's list of
-    metrics, loaded once: each has its check as a function of the root."""
+    metrics, loaded once: the cost functions', the tiny runs' and the
+    single readers' files, and the dispatch split's, which has its check
+    as a function of the root."""
     from tests.conftest import load_repo_module
 
+    here = Path(__file__).parent
+    stems = sorted({
+        p.stem for pattern in ("test_*cost*.py", "test_run_tiny_*.py",
+                               "test_*reader.py")
+        for p in here.glob(pattern)
+    } | {"test_dispatch_split_readers"})
     return {
         stem: load_repo_module(f"bench_{stem}", f"tests/benchmarks/{stem}.py")
-        for stem in ("test_run_tiny_glm", "test_run_tiny_jamba",
-                     "test_run_tiny_mimo", "test_mhc_train_cost",
-                     "test_dispatch_split_readers")
+        for stem in stems
     }
+
+
+class NeedsASubprocess(Exception):
+    """A test asked for a run of ``benchmarks/run.py``."""
+
+
+def needs_a_subprocess(*args, **kwargs):
+    raise NeedsASubprocess
+
+
+@contextlib.contextmanager
+def the_manifest_under(root: Path):
+    """While it holds, ``manifest.manifest``, ``cell`` and
+    ``metric_file`` read the manifest under ``root`` where a caller names
+    none (each has ``root`` as its one default), and nothing starts a
+    process."""
+    readers_of = (manifest.manifest, manifest.metric_file, manifest.cell)
+    plain, run = [f.__defaults__ for f in readers_of], subprocess.run
+    try:
+        for f in readers_of:
+            f.__defaults__ = (root,)
+        subprocess.run = needs_a_subprocess
+        yield
+    finally:
+        subprocess.run = run
+        for f, defaults in zip(readers_of, plain):
+            f.__defaults__ = defaults
+
+
+def the_cases_of(test) -> list[dict]:
+    """The calls pytest makes of a test that takes no fixture: one, or
+    one a case of its ``parametrize`` marks; none of a test that takes a
+    fixture."""
+    cases = [{}]
+    for mark in getattr(test, "pytestmark", ()):
+        if mark.name != "parametrize":
+            continue
+        names, values = mark.args[:2]
+        if isinstance(names, str):
+            names = [n.strip() for n in names.split(",")]
+        rows = [getattr(v, "values", v) for v in values]
+        if len(names) == 1:
+            rows = [r if hasattr(v, "values") else (r,)
+                    for r, v in zip(rows, values)]
+        cases = [dict(c, **dict(zip(names, r))) for c in cases for r in rows]
+    asked = set(inspect.signature(test).parameters)
+    return cases if asked <= set(cases[0]) else []
+
+
+def check_the_other_files_tests(root: Path) -> int:
+    """Every test of the files ``the_other_files_checks`` loads that
+    needs no fixture and no run of ``benchmarks/run.py``, against the
+    manifest under ``root``: the tests of a cell's list among them, by
+    whatever name (PR 48's two pins of the list's last place stood in
+    such tests and stopped every appended entry until PR 53). Returns how
+    many calls it made."""
+    made = 0
+    with the_manifest_under(root):
+        for module in the_other_files_checks().values():
+            for name, test in vars(module).items():
+                if not name.startswith("test_") or not inspect.isfunction(test):
+                    continue
+                for case in the_cases_of(test):
+                    try:
+                        test(**case)
+                    except NeedsASubprocess:
+                        continue
+                    made += 1
+    return made
 
 
 def check_the_whole_manifest(root: Path) -> None:
@@ -510,9 +588,9 @@ def check_the_whole_manifest(root: Path) -> None:
     check_files_and_entries(bench, root)
     assert len(bench["per_layer"]) <= 128
     others = the_other_files_checks()
-    for stem, module in others.items():
-        if stem != "test_dispatch_split_readers":
-            module.check_the_manifest_gives_the_cell_its_metrics(root)
+    for stem in ("test_run_tiny_glm", "test_run_tiny_jamba",
+                 "test_run_tiny_mimo", "test_mhc_train_cost"):
+        others[stem].check_the_manifest_gives_the_cell_its_metrics(root)
     mimo = others["test_run_tiny_mimo"]
     split = others["test_dispatch_split_readers"]
     for own in mimo.OWN_FILES:
@@ -522,6 +600,7 @@ def check_the_whole_manifest(root: Path) -> None:
             name, root)
     split.check_the_manifest_lists_the_exchanges_counters_fill_before_fallback(
         root)
+    assert check_the_other_files_tests(root) >= 80
 
 
 @pytest.mark.parametrize("cell", CELLS)

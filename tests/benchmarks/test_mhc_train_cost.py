@@ -20,6 +20,7 @@ import pytest
 
 from benchmarks.harness import costs, peaks, readers
 from benchmarks.metrics import mhc_train_cost
+from tests.benchmarks.hand_made import STEP, program, ran_by
 from tests.conftest import load_repo_module
 
 in_order = load_repo_module(
@@ -127,10 +128,9 @@ def with_timeline(monkeypatch, spans):
     monkeypatch.setattr(layers, "program_spans", lambda: list(spans))
 
 
-def traced(ops, steps=2):
-    modules = [["jit_step(123)", 30.0 + 0.07 * i, 0.07] for i in range(steps)]
-    return {"devices": {0: {"ops": [list(op) for op in ops],
-                            "async": [], "modules": modules}}, "host": []}
+def traced(run, ops=None, steps=2):
+    """``run`` as ``steps`` executions of the step program ran ``OPS``."""
+    return ran_by(run, ops or OPS, SCOPES, STEP, executions=steps)
 
 
 OPS = [
@@ -152,45 +152,41 @@ BUSY = sum(op[2] for op in OPS)
 
 
 def test_residual_mix_share_is_the_mhc_scopes_over_busy_time():
-    run = run_of()
-    run.trace, run.scopes = traced(OPS), SCOPES
+    run = traced(run_of())
     assert readers.read(run, "model.train_residual_mix_device_pct") == \
         pytest.approx(100.0 * 0.068 / BUSY)
     # a program without the path, or no trace: nothing
-    run.scopes = {"fusion.4": SCOPES["fusion.4"]}
+    run.programs = (program({"fusion.4": SCOPES["fusion.4"]}, STEP),)
     assert readers.read(run, "model.train_residual_mix_device_pct") is None
     assert readers.read(run_of(), "model.train_residual_mix_device_pct") is None
 
 
 def test_mtp_share_is_the_modules_scope_and_overlaps_the_others():
-    run = run_of()
-    run.trace, run.scopes = traced(OPS), SCOPES
+    run = traced(run_of())
     # the module's stream mix and its attention projection, both
     assert readers.read(run, "model.train_mtp_device_pct") == \
         pytest.approx(100.0 * 0.060 / BUSY)
-    run.scopes = {"fusion.1": SCOPES["fusion.1"]}
+    run.programs = (program({"fusion.1": SCOPES["fusion.1"]}, STEP),)
     assert readers.read(run, "model.train_mtp_device_pct") is None
     assert readers.read(run_of(), "model.train_mtp_device_pct") is None
 
 
 def test_roofline_share_from_the_traced_steps():
-    run = run_of()
-    run.trace, run.scopes = traced(OPS, steps=2), SCOPES
+    run = traced(run_of(), steps=2)
     least, _ = costs.roofline_seconds(
         mhc_train_cost.mhc_train_work(XING, TOKENS), PEAK)
     got = readers.read(run, "kernel.mhc_train_roofline")
     assert got == pytest.approx(100.0 * 2 * least / 0.068)
-    assert run.notes["mhc_train.bound"] == "memory"
-    assert run.notes["mhc_train.traced_steps"] == 2
+    assert run.notes["kernel.mhc_train_roofline.bound"] == "memory"
+    assert run.notes["kernel.mhc_train_roofline.traced_steps"] == 2
     # no trace, no op under the scope, no step program, or a
     # configuration with one stream: nothing
     assert readers.read(run_of(), "kernel.mhc_train_roofline") is None
-    run.scopes = {"fusion.4": SCOPES["fusion.4"]}
+    run.programs = (program({"fusion.4": SCOPES["fusion.4"]}, STEP),)
     assert readers.read(run, "kernel.mhc_train_roofline") is None
-    run.scopes, run.trace = SCOPES, traced(OPS, steps=0)
-    assert readers.read(run, "kernel.mhc_train_roofline") is None
-    plain = run_of(config=dict(XING, hc_mult=1))
-    plain.trace, plain.scopes = traced(OPS), SCOPES
+    assert readers.read(
+        traced(run, steps=0), "kernel.mhc_train_roofline") is None
+    plain = traced(run_of(config=dict(XING, hc_mult=1)))
     assert readers.read(plain, "kernel.mhc_train_roofline") is None
 
 

@@ -11,6 +11,7 @@ import pytest
 
 from benchmarks.harness import costs, peaks, readers
 from benchmarks.metrics import mla_decode_cost
+from tests.benchmarks.hand_made import program, ran_by
 
 ROOT = Path(__file__).resolve().parents[2]
 GLM = json.loads(
@@ -112,13 +113,6 @@ def test_pool_use_is_the_windows_peak_over_the_pool(monkeypatch):
     assert readers.read(run, "serve.latent_pool_used_pct") is None
 
 
-def one_device_trace(ops):
-    """A normalised trace (harness/trace.py) of one device: ``ops`` are
-    (hlo text, start, seconds)."""
-    return {"devices": {0: {"ops": [list(op) for op in ops],
-                            "async": [], "modules": []}}, "host": []}
-
-
 def test_roofline_share_from_the_traced_chunks_own_counts(monkeypatch):
     from benchmarks.harness import trace as tr
 
@@ -141,8 +135,7 @@ def test_roofline_share_from_the_traced_chunks_own_counts(monkeypatch):
         span("serve/step", 30.0, 0.2, 9, chunk),   # inside the capture
         span("serve/step", 30.3, 0.2, 10, chunk),
     ])
-    run = run_of({}, traced=(29.9, 31.0))
-    run.trace, run.scopes = one_device_trace(ops), scopes
+    run = ran_by(run_of({}, traced=(29.9, 31.0)), ops, scopes)
     want = mla_decode_cost.mla_decode_work(
         GLM, positions_attended=2 * 8 * 64 * 352, slot_steps=2 * 8 * 64,
         steps=16,
@@ -150,14 +143,14 @@ def test_roofline_share_from_the_traced_chunks_own_counts(monkeypatch):
     least, _ = costs.roofline_seconds(want, run.peak)
     got = readers.read(run, "kernel.mla_decode_roofline")
     assert got == pytest.approx(100.0 * least / 0.009)
-    assert run.notes["mla_decode.bound"] == "memory"
-    assert run.notes["mla_decode.traced_chunks"] == 2
+    assert run.notes["kernel.mla_decode_roofline.bound"] == "memory"
+    assert run.notes["kernel.mla_decode_roofline.traced_chunks"] == 2
     assert tr.parse_op(ops[0][0])[0] == "fusion.1"
     # no capture, no counts on the spans, or no op under the scopes: nothing
     plain = run_of({}, traced=None)
     assert readers.read(plain, "kernel.mla_decode_roofline") is None
-    run.scopes = {}
+    run.programs = (program({}),)
     assert readers.read(run, "kernel.mla_decode_roofline") is None
     with_timeline(monkeypatch, [span("serve/step", 30.0, 0.2, 9)])
-    run.scopes = scopes
+    run.programs = (program(scopes),)
     assert readers.read(run, "kernel.mla_decode_roofline") is None

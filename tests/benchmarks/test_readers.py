@@ -6,6 +6,7 @@ import types
 import pytest
 
 from benchmarks.harness import costs, layers, readers
+from tests.benchmarks.hand_made import program, trace_of
 
 PHASES = ["data_wait", "host_dispatch", "metric_flush"]
 
@@ -115,16 +116,13 @@ EXPERT_TRACES = {
 def kernel_run(ops, module: str, hf=QWEN, scopes=SCOPES, products=PRODUCTS):
     """A traced run of one program: its executable is ``module`` with a
     fingerprint, as a trace names it, and covers all the ops."""
-    trace = {"devices": {"0": {
-        "ops": [list(op) for op in ops], "async": [],
-        "modules": [[module + "(17)", 0.0, 6.0, 1]],
-    }}, "host": []}
     seen = types.SimpleNamespace(
         tokens_per_step=16_384, seq_len=4_096, chips=1, slots=64, chunk_k=8)
     return readers.Run(
         cell=types.SimpleNamespace(config=hf), observed=seen, setup_s=0.0,
-        inventory=(), device_kind="TPU v5 lite", trace=trace, scopes=scopes,
-        programs=(layers.Program(module, {}, scopes, products),),
+        inventory=(), device_kind="TPU v5 lite",
+        trace=trace_of(ops, module, devices=("0",)),
+        programs=(program(scopes, module, products=products),),
     )
 
 
@@ -146,7 +144,7 @@ def test_expert_rooflines_take_an_event_by_its_own_instruction(
         return
     least, bound = costs.roofline_seconds(getattr(readers, cost)(run), run.peak)
     assert share == pytest.approx(100.0 * least / seconds)
-    assert run.notes[cost.lstrip("_") + ".bound"] == bound
+    assert run.notes[metric + ".bound"] == bound
 
 
 @pytest.mark.parametrize("ops,seconds", [
@@ -184,7 +182,7 @@ def test_a_kernel_roofline_reads_nothing_where_two_programs_disagree():
     twin = layers.Program("jit_fused_fn", {}, SCOPES, {})
     run.programs = (*run.programs, twin)
     assert readers.read(run, "kernel.expert_mm_decode_roofline") is None
-    assert "jit_fused_fn(17)" in run.notes["expert_mm_decode.ambiguous"]
+    assert "jit_fused_fn(17)" in run.notes["kernel.expert_mm_decode_roofline.ambiguous"]
     # the twin told apart by what its instructions return: read again
     run.programs = (run.programs[0]._replace(
         results={"fusion.9": "bf16[512,1024]{1,0}"}), twin)

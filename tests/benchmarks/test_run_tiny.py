@@ -2,6 +2,7 @@
 process per run, as the driver starts it. It must print the contract's
 last line, and no device metric may be in it."""
 
+import fcntl
 import functools
 import json
 import os
@@ -10,6 +11,8 @@ import sys
 from pathlib import Path
 
 import pytest
+
+from tests.benchmarks.conftest import tiny_lines_dir
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -62,11 +65,32 @@ def last_line(proc) -> dict:
 
 @functools.lru_cache(maxsize=None)
 def tiny_line(workload: str, trace: int, devices: int) -> dict:
-    """The last line of one tiny run; a run is made once per module."""
-    return last_line(run_py(
-        "--workload", workload, "--seed", str(2**31 + 5), "--seconds", "1",
-        "--trace", str(trace), "--tiny", devices=devices,
-    ))
+    """The last line of one tiny run; a run is made once a test session:
+    once a process, and under xdist once for all the workers, which keep
+    the line as a file named by the three arguments in the session's
+    directory (``conftest.tiny_lines_dir``, which the last worker to end
+    removes), written whole (``os.replace``) under a lock a file, so that
+    a worker that asks for a run another is making waits for it and runs
+    of other cells go on beside it."""
+    def run() -> dict:
+        return last_line(run_py(
+            "--workload", workload, "--seed", str(2**31 + 5), "--seconds", "1",
+            "--trace", str(trace), "--tiny", devices=devices,
+        ))
+
+    uid = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+    if not uid:
+        return run()
+    shared = tiny_lines_dir(uid)
+    shared.mkdir(exist_ok=True)
+    held = shared / f"{workload}.{trace}.{devices}.json"
+    with open(f"{held}.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not held.exists():
+            fresh = Path(f"{held}.{os.getpid()}")
+            fresh.write_text(json.dumps(run()))
+            os.replace(fresh, held)
+        return json.loads(held.read_text())
 
 
 CASES = [

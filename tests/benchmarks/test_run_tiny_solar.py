@@ -15,8 +15,10 @@ COUNTERS, tiny_line, in_order = _tiny.COUNTERS, _tiny.tiny_line, _tiny.in_order
 CELL = "solar-open2-250b-share8-decode.serve-reason-closed"
 GRANITE = "granite-4.0-h-small-share4-decode.serve-reason-closed"
 JAMBA = "jamba2-3b-decode.serve-reason-closed"
-# the KDA mixers' share and roofline are not listed yet (PERF.md section 7)
-WAITING = {"kernel.kda_decode_roofline", "model.decode_kda_device_pct"}
+# the KDA mixers' roofline and share (listed since PR 53) and the
+# admission's reset, which the cells that keep recurrent state share
+KDA = ["kernel.kda_decode_roofline", "model.decode_kda_device_pct"]
+RESET = "serve.reset_rows_ms_per_admitting_chunk"
 
 
 def test_tiny_run_prints_the_contracts_last_line_and_its_counters():
@@ -45,7 +47,8 @@ def test_tiny_run_prints_the_contracts_last_line_and_its_counters():
     # the tiny table: prompts 3 and 6, outputs 10 and 20
     context = metrics["serve.mean_context_tokens"]
     assert 6.5 <= context["value"] <= 13.0
-    assert not WAITING & set(metrics)
+    # shares of device time and of a roofline come from a device trace
+    assert not {*KDA, RESET} & set(metrics)
 
 
 def test_the_manifest_gives_the_cell_its_metrics():
@@ -69,7 +72,11 @@ def test_the_manifest_gives_the_cell_its_metrics():
         ["model.decode_experts_device_pct", "serve.mean_context_tokens",
          "serve.recurrent_state_gb", "moe.decode_held_rows_pct"],
         names)
-    assert not WAITING & set(names)
+    assert in_order(["moe.decode_held_rows_pct", *KDA, RESET], names)
+    assert not set(KDA) & {
+        m["name"] for m in granite.per_layer + jamba.per_layer}
+    assert RESET in {m["name"] for m in granite.per_layer} \
+        & {m["name"] for m in jamba.per_layer}
     # Mamba's scope and counts, the window layers' and the latent pool's:
     # nothing to read here
     absent = {"model.decode_ssm_device_pct", "kernel.ssm_decode_roofline",

@@ -11,13 +11,13 @@ import pytest
 
 from benchmarks.harness import costs, peaks, readers
 from benchmarks.metrics import ssm2_decode_cost
+from tests.benchmarks.hand_made import program, ran_by
 from tests.conftest import load_repo_module
 
 # the hand-made spans and traces of the Mamba-1 reader's tests
 _ssm = load_repo_module(
     "bench_ssm_decode_cost", "tests/benchmarks/test_ssm_decode_cost.py")
-span, with_timeline, one_device_trace = (
-    _ssm.span, _ssm.with_timeline, _ssm.one_device_trace)
+span, with_timeline = _ssm.span, _ssm.with_timeline
 
 ROOT = Path(__file__).resolve().parents[2]
 GRANITE = json.loads((
@@ -140,28 +140,26 @@ def test_roofline_share_from_the_traced_steps(monkeypatch):
         span("serve/step", 30.0, 0.2, 9, STATE),   # inside the capture
         span("serve/step", 30.3, 0.2, 10, STATE),
     ])
-    run = run_of(traced=(29.9, 31.0))
-    run.trace, run.scopes = one_device_trace(OPS), SCOPES
+    run = ran_by(run_of(traced=(29.9, 31.0)), OPS, SCOPES)
     want = ssm2_decode_cost.ssm2_decode_work(GRANITE, SLOTS, steps=16)
     least, _ = costs.roofline_seconds(want, run.peak)
     got = readers.read(run, "kernel.ssm2_decode_roofline")
     # every op under a mixer's scope, its projections too (the state's
     # traffic hides under them), and not the attention layer's
     assert got == pytest.approx(100.0 * least / 0.220)
-    assert run.notes["ssm2_decode.bound"] == "memory"
-    assert run.notes["ssm2_decode.traced_chunks"] == 2
-    assert run.notes["ssm2_decode.device_s"] == pytest.approx(0.220)
+    assert run.notes["kernel.ssm2_decode_roofline.bound"] == "memory"
+    assert run.notes["kernel.ssm2_decode_roofline.traced_chunks"] == 2
+    assert run.notes["kernel.ssm2_decode_roofline.device_s"] == pytest.approx(0.220)
     # no capture, no op under the scopes, spans without the count, or a
     # configuration of another recurrence (Mamba-1's keys): nothing
     assert readers.read(
         run_of(traced=None), "kernel.ssm2_decode_roofline") is None
-    run.scopes = {}
+    run.programs = (program({}),)
     assert readers.read(run, "kernel.ssm2_decode_roofline") is None
-    run.scopes = SCOPES
+    run.programs = (program(SCOPES),)
     with_timeline(monkeypatch, [span("serve/step", 30.0, 0.2, 9)])
     assert readers.read(run, "kernel.ssm2_decode_roofline") is None
-    other = run_of(config=JAMBA, traced=(29.9, 31.0))
-    other.trace, other.scopes = one_device_trace(OPS), SCOPES
+    other = ran_by(run_of(config=JAMBA, traced=(29.9, 31.0)), OPS, SCOPES)
     assert readers.read(other, "kernel.ssm2_decode_roofline") is None
 
 
@@ -169,8 +167,8 @@ def test_the_metric_is_listed_for_its_cell_alone():
     from benchmarks.harness import manifest
 
     name = "kernel.ssm2_decode_roofline"
-    entry = manifest.manifest()["per_layer"][-1]
-    assert entry["name"] == name
+    entry, = (m for m in manifest.manifest()["per_layer"]
+              if m["name"] == name)
     assert entry["workloads"] == [
         "granite-4.0-h-small-share4-decode.serve-reason-closed"]
     own = manifest.metric_file(name)

@@ -12,6 +12,7 @@ import pytest
 
 from benchmarks.harness import costs, peaks, readers
 from benchmarks.metrics import ssm_decode_cost
+from tests.benchmarks.hand_made import program, ran_by
 
 ROOT = Path(__file__).resolve().parents[2]
 JAMBA = json.loads(
@@ -107,11 +108,6 @@ def with_timeline(monkeypatch, spans):
     monkeypatch.setattr(layers, "program_spans", lambda: list(spans))
 
 
-def one_device_trace(ops):
-    return {"devices": {0: {"ops": [list(op) for op in ops],
-                            "async": [], "modules": []}}, "host": []}
-
-
 STATE = {"recurrent_state_bytes": 2_589_982_720, "rows_reset": 3}
 OPS = [
     ("%fusion.1 = f32[256,16,5120] fusion(%a)", 30.00, 0.004),
@@ -145,12 +141,11 @@ def test_recurrent_state_gb_is_the_windows_count(monkeypatch):
 
 
 def test_ssm_share_is_the_mixers_scope_over_busy_time():
-    run = run_of()
-    run.trace, run.scopes = one_device_trace(OPS), SCOPES
+    run = ran_by(run_of(), OPS, SCOPES)
     assert readers.read(run, "model.decode_ssm_device_pct") == \
         pytest.approx(100.0 * 0.016 / 0.019)
     # a program with no op under a mixer's scope, or no trace: nothing
-    run.scopes = {"fusion.4": SCOPES["fusion.4"]}
+    run.programs = (program({"fusion.4": SCOPES["fusion.4"]}),)
     assert readers.read(run, "model.decode_ssm_device_pct") is None
     assert readers.read(run_of(), "model.decode_ssm_device_pct") is None
 
@@ -161,24 +156,23 @@ def test_roofline_share_from_the_traced_steps(monkeypatch):
         span("serve/step", 30.0, 0.2, 9, STATE),   # inside the capture
         span("serve/step", 30.3, 0.2, 10, STATE),
     ])
-    run = run_of(traced=(29.9, 31.0))
-    run.trace, run.scopes = one_device_trace(OPS), SCOPES
+    run = ran_by(run_of(traced=(29.9, 31.0)), OPS, SCOPES)
     want = ssm_decode_cost.ssm_decode_work(JAMBA, SLOTS, steps=16)
     least, _ = costs.roofline_seconds(want, run.peak)
     got = readers.read(run, "kernel.ssm_decode_roofline")
     # every op under a mixer's scope, its projections too (the state's
     # traffic hides under them), and not the attention layer's
     assert got == pytest.approx(100.0 * least / 0.016)
-    assert run.notes["ssm_decode.bound"] == "memory"
-    assert run.notes["ssm_decode.traced_chunks"] == 2
+    assert run.notes["kernel.ssm_decode_roofline.bound"] == "memory"
+    assert run.notes["kernel.ssm_decode_roofline.traced_chunks"] == 2
     # no capture, no op under the scopes, spans without the count, or a
     # configuration without the family's keys: nothing
     assert readers.read(run_of(traced=None), "kernel.ssm_decode_roofline") is None
-    run.scopes = {}
+    run.programs = (program({}),)
     assert readers.read(run, "kernel.ssm_decode_roofline") is None
-    run.scopes = SCOPES
+    run.programs = (program(SCOPES),)
     with_timeline(monkeypatch, [span("serve/step", 30.0, 0.2, 9)])
     assert readers.read(run, "kernel.ssm_decode_roofline") is None
-    other = run_of(config={"hidden_size": 2048}, traced=(29.9, 31.0))
-    other.trace, other.scopes = one_device_trace(OPS), SCOPES
+    other = ran_by(
+        run_of(config={"hidden_size": 2048}, traced=(29.9, 31.0)), OPS, SCOPES)
     assert readers.read(other, "kernel.ssm_decode_roofline") is None

@@ -10,8 +10,9 @@ from pathlib import Path
 
 import pytest
 
-from benchmarks.harness import costs, peaks
+from benchmarks.harness import costs, layers, peaks
 from benchmarks.harness import trace as tr
+from tests.benchmarks.hand_made import STEP, program
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -42,12 +43,76 @@ def test_parse_op_names_and_opcodes():
     # a text the trace cut inside its result: all of it, and no opcode
     assert tr.instruction(WHILE[:20]) == ("while.3", "(s32[], b", "")
     assert tr.label(RAGGED) == "custom-call:ragged-dot-none"
-    scopes = tr.scopes_from_hlo([
-        '  %fusion.1 = bf16[8,128]{1,0} fusion(%p0), kind=kLoop, '
-        'metadata={op_name="jit(step)/jit(main)/train/optimizer/mul"}',
-    ])
-    assert scopes == {"fusion.1": "jit(step)/jit(main)/train/optimizer/mul"}
-    assert tr.label(FUSION, scopes) == "fusion:train/optimizer/mul"
+    # the scope is the event's own instruction's, in the program that ran
+    # it (``layers.own_scope``, tests/benchmarks/test_layers.py)
+    assert tr.label(FUSION, "jit(step)/jit(main)/train/optimizer/mul") == \
+        "fusion:train/optimizer/mul"
+
+
+CHUNK = "jit(fused_fn)/while/body/closed_call/Qwen3MoeCausalLM.logits_last/"
+GRAD = ("jit(step)/while/body/closed_call/train/microbatch_grad/"
+        "transpose(jvp(Qwen3MoeCausalLM))/model/train/microbatch_grad/"
+        "jvp(Qwen3MoeCausalLM)/model/")
+
+
+def test_a_label_says_what_differs_in_what_the_ledger_keeps():
+    """The ledger keeps 64 characters of a label. Until PR 53 the first
+    62 of every serving label were the chunk's loop and the model's entry
+    method (``fusion:while/body/closed_call/Qwen3M..salLM.logits_last/
+    model/la``: three of the Jamba cell's ten read so)."""
+    assert (tr.LABEL_LIMIT, tr.LEDGER_KEEPS) == (96, 64)
+    serving = [
+        "model/layers_3/mlp/down_proj/dot_general",
+        "model/layers_3/mlp/gate_proj/dot_general",
+        "model/layers_5/mamba/mamba/in_proj/in_proj/slice",
+        "model/layers_5/mamba/mamba/state_update/reduce_sum",
+        "model/layers_5/mamba/mamba/out_proj/out_proj/dot_general",
+        "model/layers_7/self_attn/self_attn._decode_attend/"
+        "jit(_paged_decode_call)/paged_decode_p8/pallas_call",
+        "model/layers_7/attn_window/self_attn/self_attn._decode_attend/"
+        "jit(_paged_decode_call)/paged_decode_p3/pallas_call",
+        "model/layers_1/mlp/mlp._forward_held/mlp._all_experts/moe/experts/"
+        "gate_up/all_experts/dot_general",
+        "model/layers_1/mlp/mlp._forward_held/mlp._all_experts/moe/experts/"
+        "down/all_experts/dot_general",
+        "lm_head.logits/dot_general",
+    ]
+    labels = [tr.label(FUSION, CHUNK + scope) for scope in serving]
+    assert len({label[:64] for label in labels}) == len(serving)
+    assert all(len(label) <= 64 for label in labels)
+    # the head every op of the chunk shares goes, and the layers of a
+    # stack add up under one label
+    assert labels[0] == "fusion:model/layers_*/mlp/down_proj/dot_general"
+    assert labels[3] == \
+        "fusion:model/layers_*/mamba/mamba/state_update/reduce_sum"
+    assert labels[-1] == "fusion:lm_head.logits/dot_general"
+    # where it must be cut, the tail stays: 64 characters with the opcode
+    assert labels[5] == "fusion:.." + serving[5][-55:] == (
+        "fusion:..end/jit(_paged_decode_call)/paged_decode_p8/pallas_call")
+    assert labels[7].endswith("moe/experts/gate_up/all_experts/dot_general")
+    assert labels[8].endswith("/moe/experts/down/all_experts/dot_general")
+    # an op of the chunk outside the model's entry method keeps the loop
+    assert tr.label(FUSION, CHUNK.rpartition("Qwen3")[0] + "jit(_where)/"
+                    "select_n") == \
+        "fusion:while/body/closed_call/jit(_where)/select_n"
+    # a training step's labels (``jit_step``, no such head) are what they
+    # were: whole up to 96 characters, and past that the first 36, two
+    # dots and the last 58, of which the ledger's 64 keep 26
+    assert tr.label(FUSION, "jit(step)/train/optimizer/convert_element_type") \
+        == "fusion:train/optimizer/convert_element_type"
+    assert tr.label(RAGGED, "moe/experts/ragged_dot") == \
+        "custom-call:moe/experts/ragged_dot"
+    backward = GRAD + "checkpoint/layers_2/attn_window/self_attn/"
+    whole = "custom-call:" + backward.partition("jit(step)/")[2].replace(
+        "layers_2", "layers_*") + "self_attn._sdpa_padded/pallas_call"
+    assert tr.label(RAGGED, backward + "self_attn._sdpa_padded/pallas_call") \
+        == whole[:36] + ".." + whole[-58:]
+    assert whole[:36] == "custom-call:while/body/closed_call/t"
+    assert len(whole[:36] + ".." + whole[-58:]) == 96
+    at_the_limit = "x" * 30 + "/" + "y" * 58
+    assert tr.label(FUSION, at_the_limit) == "fusion:" + at_the_limit
+    assert tr.label(FUSION, "x" + at_the_limit) == \
+        "fusion:" + "x" * 29 + ".." + "y" * 58
 
 
 def test_union_subtract_and_clip():
@@ -68,11 +133,12 @@ def test_busy_union_counts_nested_ops_once_and_idle_share():
     assert tr.idle_share(trace) == pytest.approx(0.25)
     # self time: the while keeps what its children leave over
     self_t = dict()
-    for text, seconds in tr.self_times(trace["devices"]["0"]["ops"]):
+    for text, _, seconds in tr.self_times_at(trace["devices"]["0"]["ops"]):
         self_t[text] = self_t.get(text, 0) + seconds
     assert self_t[WHILE] == pytest.approx(2.0)
     assert self_t[FUSION] == pytest.approx(3.0)
-    assert tr.top_ops(trace, n=2) == [
+    ops = tr.grouped(trace)
+    assert tr.top_ops(ops, n=2) == [
         ["fusion:fusion.1", pytest.approx(3.0)],
         ["while:while.3", pytest.approx(2.0)],
     ]
@@ -81,10 +147,11 @@ def test_busy_union_counts_nested_ops_once_and_idle_share():
     assert [(m, t) for _, m, t, _ in tr.events_in_modules(trace)] == [
         ("jit_step(7)", FUSION), ("jit_step(7)", RAGGED),
         ("jit_step(7)", WHILE), (None, FUSION)]
+    ops = tr.grouped(trace)
     ragged = lambda text, module: "ragged-dot" in text  # noqa: E731
-    assert tr.event_seconds(trace, ragged) == {"seconds": 1.0, "events": 1}
+    assert tr.event_seconds(ops, ragged) == {"seconds": 1.0, "events": 1}
     inside = lambda text, module: module == "jit_step(7)"  # noqa: E731
-    assert tr.event_seconds(trace, inside) == {"seconds": 4.0, "events": 3}
+    assert tr.event_seconds(ops, inside) == {"seconds": 4.0, "events": 3}
 
 
 def test_busy_is_averaged_over_devices():
@@ -192,12 +259,18 @@ def test_recorded_train_trace_reduces(train_trace):
     assert len(steps) == expected["steps"]
     # the whole text of an event names its operands too: what the expert
     # roofline summed until PR 34, the calls and the fusions that read them
-    ragged = tr.event_seconds(trace, lambda text, _: "ragged-dot" in text)
+    ops = tr.grouped(trace)
+    ragged = tr.event_seconds(ops, lambda text, _: "ragged-dot" in text)
     assert ragged["events"] > 0
     assert ragged["seconds"] == pytest.approx(expected["ragged_dot_s"])
-    top = tr.top_ops(trace, scopes, n=10)
+    step = program(scopes, STEP)
+    scope_of = layers.own_scope(layers.programs_that_ran(ops, [step]))
+    top = tr.top_ops(ops, scope_of, n=10)
     assert len(top) == 10 and top[0][1] >= top[-1][1] > 0
     assert sum(s for _, s in top) <= busy * (1 + 1e-9)
+    assert "custom-call:moe/experts/ragged_dot" in dict(top)
+    # without the programs every label is its instruction's name
+    assert all("/" not in label for label, _ in tr.top_ops(ops, n=10))
     spans = tr.program_spans(trace)
     gaps = tr.idle_gaps(trace, spans)
     assert sum(s for _, s in gaps) == pytest.approx(window - busy, rel=1e-6)
