@@ -16,7 +16,9 @@ Usage shape (see docs/design/observability.md):
 Metric namespace (enforced by convention, documented in the design doc):
 ``train/*`` trainer loop, ``pp/*`` pipeline executor, ``serve/*``
 continuous batching, ``io/*`` checkpoint + data IO, ``host/*`` the
-process itself (``host/gc``: the cyclic collector's pauses).
+process itself (``host/gc``: the cyclic collector's pauses;
+``host/hiccup``, ``host/probe``: the late wake-ups of a thread that only
+sleeps, and the witness that it ran).
 """
 
 import collections
@@ -59,6 +61,7 @@ __all__ = [
     "PhaseTimeline",
     "Span",
     "Telemetry",
+    "HiccupProbe",
     "TelemetrySink",
     "JsonlSink",
     "TrackerBridge",
@@ -91,6 +94,71 @@ __all__ = [
 ]
 
 
+class HiccupProbe:
+    """The loop of the ``d9d-host-hiccup`` thread: sleep ``interval_s``,
+    read the clock, and ``late = now - due`` is how long a thread with
+    nothing to wait for but the operating system was kept from running.
+    No loop's own clock can say that: it runs on the thread that is
+    itself frozen or blocked. A wake-up is also late while another
+    thread holds the interpreter (a collection, a long C call that keeps
+    the GIL); a reader tells those apart by what the hiccup overlaps.
+
+    It appends ``(name, t0, dur_s, meta)`` to ``out`` and takes no lock
+    but the deque's own. The clock and the wait are arguments so that
+    tests drive :meth:`run` with a fake clock and no sleeping; ``wait``
+    returns true when the probe is to stop, as ``Event.wait`` does."""
+
+    WITNESS_S = 1.0
+    THREAD_NAME = "d9d-host-hiccup"
+
+    def __init__(self, out: collections.deque, *, interval_s: float,
+                 span_min_s: float, clock=_time.perf_counter, wait=None):
+        self._out = out
+        self.interval_s = interval_s
+        self.span_min_s = span_min_s
+        self._clock = clock
+        self._stop = threading.Event()
+        self._wait = wait if wait is not None else self._stop.wait
+        self._thread: threading.Thread | None = None
+
+    @property
+    def alive(self) -> bool:
+        return self._thread is not None and self._thread.is_alive()
+
+    def start(self) -> None:
+        self._thread = threading.Thread(
+            target=self.run, name=self.THREAD_NAME, daemon=True)
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        if self.alive and self._thread is not threading.current_thread():
+            self._thread.join(timeout=1.0)
+
+    def run(self) -> None:
+        clock, out = self._clock, self._out
+        since, wakes, late_sum, late_max = clock(), 0, 0.0, 0.0
+        while True:
+            due = clock() + self.interval_s
+            stopped = self._wait(self.interval_s)
+            now = clock()
+            if not stopped:
+                late = max(now - due, 0.0)
+                wakes += 1
+                late_sum += late
+                late_max = max(late_max, late)
+                if late >= self.span_min_s:
+                    out.append(("host/hiccup", due, late, None))
+            if wakes and (stopped or now - since >= self.WITNESS_S):
+                out.append(("host/probe", since, now - since, {
+                    "wakes": wakes, "late_sum_s": late_sum,
+                    "late_max_s": late_max,
+                }))
+                since, wakes, late_sum, late_max = now, 0, 0.0, 0.0
+            if stopped:
+                return
+
+
 class Telemetry:
     """One registry + its attached sinks.
 
@@ -117,8 +185,10 @@ class Telemetry:
         self.last_numerics = None
         self._slo_eval_warned_t = -float("inf")
         self._gc_t0: float | None = None
-        # pauses the collector's hook has seen and no span records yet
-        self._gc_pauses: collections.deque = collections.deque()
+        # (name, t0, dur_s, meta) of the pauses the collector's hook and
+        # the hiccup probe have seen and no span records yet
+        self._host_pauses: collections.deque = collections.deque()
+        self._hiccups: HiccupProbe | None = None
 
     # -- instrument passthrough (the API components actually use) ------
 
@@ -176,8 +246,8 @@ class Telemetry:
             return tuple(self._sinks)
 
     def _on_span(self, span: Span) -> None:
-        if self._gc_pauses and span.name != "host/gc":
-            self._record_gc_pauses()
+        if self._host_pauses and not span.name.startswith("host/"):
+            self._record_host_pauses()
         for sink in self.sinks:
             sink.on_span(span)
 
@@ -218,7 +288,7 @@ class Telemetry:
         Each flush also (a) evaluates the attached SLO monitor first, so
         slo/* instruments in the snapshot are current, and (b) appends
         the snapshot to the registry's flight-recorder ring."""
-        self._record_gc_pauses()
+        self._record_host_pauses()
         if self.slo_monitor is not None:
             try:
                 self.slo_monitor.evaluate()
@@ -311,24 +381,55 @@ class Telemetry:
             return
         generation = info.get("generation")
         if generation == 2 or now - t0 > self.GC_SPAN_MIN_S:
-            self._gc_pauses.append(
-                (t0, now - t0, generation, info.get("collected"))
-            )
+            self._host_pauses.append((
+                "host/gc", t0, now - t0,
+                {"generation": generation, "collected": info.get("collected")},
+            ))
 
-    def _record_gc_pauses(self) -> None:
-        while self._gc_pauses:
+    def _record_host_pauses(self) -> None:
+        while self._host_pauses:
             try:
-                t0, dur_s, generation, collected = self._gc_pauses.popleft()
+                name, t0, dur_s, meta = self._host_pauses.popleft()
             except IndexError:  # another thread took the last one
                 return
-            self.registry.record_span(
-                "host/gc", t0, dur_s,
-                meta={"generation": generation, "collected": collected},
-            )
+            self.registry.record_span(name, t0, dur_s, meta=meta)
+
+    # -- the host's own pauses -----------------------------------------
+
+    HICCUP_INTERVAL_S = 10e-3
+    # twice the interpreter's 5 ms switch interval: a main thread that
+    # merely runs Python does not read as a pause
+    HICCUP_SPAN_MIN_S = 10e-3
+
+    def watch_hiccups(self) -> None:
+        """Start the ``d9d-host-hiccup`` thread (:class:`HiccupProbe`): a
+        ``host/hiccup`` span for every wake-up of a thread that only
+        sleeps ``HICCUP_INTERVAL_S`` that came ``HICCUP_SPAN_MIN_S`` or
+        more late (a host that stood still: a descheduled VM, a stopped
+        process, a thread that kept the interpreter), and a
+        ``host/probe`` span a second (meta: ``wakes``, ``late_sum_s``,
+        ``late_max_s``), the witness that the probe ran. Started for the
+        process hub beside the collector's hook and stopped by
+        :meth:`close`; a second call while the thread lives is a no-op,
+        one after a fork (the thread is not the child's) starts it anew.
+        Like the hook, the thread only reads the clock and appends to a
+        deque: the spans are recorded with the next span of any kind and
+        at every flush."""
+        if self._hiccups is None or not self._hiccups.alive:
+            self._hiccups = HiccupProbe(
+                self._host_pauses, interval_s=self.HICCUP_INTERVAL_S,
+                span_min_s=self.HICCUP_SPAN_MIN_S)
+            self._hiccups.start()
+
+    def unwatch_hiccups(self) -> None:
+        probe, self._hiccups = self._hiccups, None
+        if probe is not None:
+            probe.stop()
 
     def close(self) -> None:
         self.unwatch_gc()
-        self._record_gc_pauses()
+        self.unwatch_hiccups()
+        self._record_host_pauses()
         for sink in self.sinks:
             self.remove_sink(sink)
 
@@ -345,6 +446,7 @@ def get_telemetry() -> Telemetry:
             if _default is None:
                 _default = Telemetry()
                 _default.watch_gc()
+                _default.watch_hiccups()
     return _default
 
 
@@ -354,8 +456,10 @@ def set_telemetry(hub: Telemetry) -> Telemetry:
     with _default_lock:
         if _default is not None and _default is not hub:
             _default.unwatch_gc()  # one hook per process
+            _default.unwatch_hiccups()  # and one probe
         _default = hub
         hub.watch_gc()
+        hub.watch_hiccups()
     return hub
 
 

@@ -91,7 +91,8 @@ def test_the_wrapper_emits_no_span_per_call(_fresh_hub):
     for _ in range(3):
         f(jnp.ones((2,)))
     names = [
-        s.name for s in _fresh_hub.registry.spans if s.name != "host/gc"
+        s.name for s in _fresh_hub.registry.spans
+        if not s.name.startswith("host/")
     ]
     assert names == ["compile/unit/quiet"]  # the caller owns the call's span
 
