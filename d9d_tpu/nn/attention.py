@@ -215,7 +215,17 @@ def _scatter_head_rows(pool, page, off, rows):
     gives the scatter a layout of its own (heads inside positions) and
     copies the WHOLE pool into it and back to the decode kernel's every
     step: 3.9 ms a layer a step against 0.56 at 256 slots x 18 pages x 4
-    heads x (256 + 128) numbers (my chip run, PR 41; PERF.md §6)."""
+    heads x (256 + 128) numbers (my chip run, PR 41; PERF.md §6).
+
+    Where it still runs (PR 55): under the ``eager`` decode backend
+    (every CPU run that is not an interpret-mode kernel test), for int8
+    pools and their scale pools under both backends, and for a module
+    that appends to one pool alone (:func:`_decode_cache_append_heads_major`
+    with a ``page_table``). Under the ``pallas`` backend a GQA layer's
+    two pools take ``pallas_decode.paged_append``
+    (:func:`_paged_append_kv`), which is tested against this: the chip
+    runs the scatter as a loop over rows, 65 to 80 ns each (ledger,
+    PR 54)."""
     p, h, ps, d = pool.shape
     flat = (
         (page[:, None] * h + jnp.arange(h, dtype=page.dtype)[None, :]) * ps
@@ -225,6 +235,62 @@ def _scatter_head_rows(pool, page, off, rows):
         pool.reshape(p * h * ps, d).at[flat].set(rows.reshape(-1, d))
         .reshape(pool.shape)
     )
+
+
+def _paged_scatter_append(module: nn.Module, ref, name: str, page, off,
+                          value):
+    """One heads-major pool ``ref`` (leaf ``name``) with ``value
+    [B, 1, H, D]`` scattered to ``(page[b], h, off[b])``; returns the
+    pool."""
+    sref = _paged_scale_var(module, name)
+    if sref is not None:
+        # int8 pool (kv_quant): per-(row, head) scales land in the
+        # [P, H, ps] scale pool at the same (page, offset); readers
+        # (the flash kernel's scale BlockSpec / the quantized eager
+        # gather) dequantize — the raw int8 pool is returned
+        qv, sc = _quantize_rows(value[:, 0])  # [B,H,D] i8, [B,H] f32
+        ref.value = _scatter_head_rows(ref.value, page, off, qv)
+        sref.value = sref.value.at[page, :, off].set(sc)
+        return ref.value
+    ref.value = _scatter_head_rows(ref.value, page, off, value[:, 0])
+    return ref.value
+
+
+def _paged_append_kv(module: nn.Module, k, v, names, start, page_table):
+    """A decode step's new ``k [B, 1, H, Dk]`` and ``v [B, 1, H, Dv]``
+    into the heads-major page pools ``names`` (shared pools or a window
+    layer's ring of pages: both are ``[P, H, ps, D]`` behind a table);
+    returns the two pools.
+
+    The append follows the switch the attend follows: under the
+    ``pallas`` decode backend one ``pallas_decode.paged_append`` call
+    writes both pools, held in place; under ``eager`` each pool takes
+    :func:`_scatter_head_rows`, the reference the kernel is tested
+    against. int8 pools (a scale pool beside them) keep the scatter for
+    rows and scales alike: a scale pool has no tile to cut."""
+    from d9d_tpu.nn.decode_flags import PAGED_SCALE_SUFFIX
+    from d9d_tpu.ops.attention.pallas_decode import (
+        decode_attention_backend,
+        paged_append,
+    )
+
+    refs = [module.variable("cache", name, lambda: None) for name in names]
+    page, off = _paged_slot(page_table, start, refs[0].value.shape[2])
+    with jax.named_scope("cache_append"):
+        if (
+            decode_attention_backend() == "pallas"
+            and not module.has_variable("cache", names[0] + PAGED_SCALE_SUFFIX)
+        ):
+            pools = paged_append(
+                refs[0].value, refs[1].value, page, off, k[:, 0], v[:, 0]
+            )
+            for ref, pool in zip(refs, pools):
+                ref.value = pool
+            return pools
+        return [
+            _paged_scatter_append(module, ref, name, page, off, value)
+            for ref, name, value in zip(refs, names, (k, v))
+        ]
 
 
 def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
@@ -252,21 +318,8 @@ def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
     b, _, h, d = value.shape
     if page_table is not None:
         ref = module.variable("cache", name, lambda: None)
-        pool = ref.value  # [P, H, ps, D]
-        ps = pool.shape[2]
-        page, off = _paged_slot(page_table, start, ps)
-        sref = _paged_scale_var(module, name)
-        if sref is not None:
-            # int8 pool (kv_quant): per-(row, head) scales land in the
-            # [P, H, ps] scale pool at the same (page, offset); readers
-            # (the flash kernel's scale BlockSpec / the quantized eager
-            # gather) dequantize — the raw int8 pool is returned
-            qv, sc = _quantize_rows(value[:, 0])  # [B,H,D] i8, [B,H] f32
-            ref.value = _scatter_head_rows(pool, page, off, qv)
-            sref.value = sref.value.at[page, :, off].set(sc)
-            return ref.value
-        ref.value = _scatter_head_rows(pool, page, off, value[:, 0])
-        return ref.value
+        page, off = _paged_slot(page_table, start, ref.value.shape[2])
+        return _paged_scatter_append(module, ref, name, page, off, value)
     ref = module.variable(
         "cache", name,
         lambda: jnp.zeros((b, h, s_max, d), value.dtype),
@@ -713,13 +766,9 @@ class GroupedQueryAttention(nn.Module):
                 # still the scalar it seeds per row
                 start = jnp.broadcast_to(start, (b,))
             _paged_write_checks(start, t, mask)
-            k_pool = _decode_cache_append_heads_major(
-                self, k.astype(self.dtype), key_leaf, s_max, start,
-                page_table=page_table,
-            )
-            v_pool = _decode_cache_append_heads_major(
-                self, v.astype(self.dtype), value_leaf, s_max, start,
-                page_table=page_table,
+            k_pool, v_pool = _paged_append_kv(
+                self, k.astype(self.dtype), v.astype(self.dtype),
+                (key_leaf, value_leaf), start, page_table,
             )
             idx.value = index + t
             # kv_quant mode (loop/serve.py): the appends above wrote

@@ -475,6 +475,144 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     return o, lse[..., 0]
 
 
+# Paged append. A decode step's new key and value rows, one a batch row
+# and kv head, written into the heads-major pools from one call that
+# holds both pools in HBM, aliased to its outputs. A row's write is the
+# SUBLANE TILE it lies in: a 16-bit pool packs two positions to a sublane
+# word, so a transfer cannot be cut at one position; the kernel copies
+# ``pool[page, :, tile, :]`` (all kv heads in one strided transfer) into
+# VMEM, replaces the row's position by a select, and copies the tile
+# back. XLA's scatter of the same rows (``nn/attention.py
+# _scatter_head_rows``) runs as a loop over rows x heads at 65 to 80 ns a
+# row on the chip (ledger, PR 54).
+APPEND_ROWS_IN_FLIGHT = 8
+
+
+def append_tile(page_size: int, dtype) -> int:
+    """Positions in a pool's sublane tile, from the dtype's packing: 8
+    sublanes of 32-bit words, ``4 // itemsize`` positions a word (8 for
+    float32, 16 for bfloat16). A page that is not whole tiles (toy pages
+    on the CPU rig) is its own tile: a whole dimension is always a legal
+    transfer."""
+    tile = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return tile if page_size % tile == 0 else page_size
+
+
+def _paged_append_kernel(page_ref, off_ref, k_rows_ref, v_rows_ref,
+                         k_in, v_in, k_pool, v_pool, k_buf, v_buf, sems,
+                         *, tile: int, depth: int):
+    """Row ``r`` of the batch: fetch the tile of ``(page[r], off[r])``
+    from both pools, put the new rows at ``off[r] % tile``, write the
+    tiles back. ``depth`` rows' buffers: while a row's tiles are written
+    out, the next rows' are on their way in. Rows are independent: a live
+    row owns the page it writes; dead rows share the garbage page (or
+    scribble in their own ring), where a race writes garbage over
+    garbage."""
+    del k_in, v_in  # the pools themselves: aliased to k_pool / v_pool
+    n_rows = k_rows_ref.shape[0]
+    pools, bufs = (k_pool, v_pool), (k_buf, v_buf)
+    new_rows = (k_rows_ref, v_rows_ref)
+
+    def tile_copy(row, i, out: bool):
+        first = pl.multiple_of(off_ref[row] // tile * tile, tile)
+        hbm = pools[i].at[page_ref[row], :, pl.ds(first, tile), :]
+        slot = row % depth
+        vmem = bufs[i].at[slot]
+        src, dst = (vmem, hbm) if out else (hbm, vmem)
+        return pltpu.make_async_copy(src, dst, sems.at[int(out), slot, i])
+
+    def fetch(row, carry=None):
+        for i in range(2):
+            tile_copy(row, i, out=False).start()
+
+    def wait_written(row):
+        for i in range(2):
+            tile_copy(row, i, out=True).wait()
+
+    jax.lax.fori_loop(0, min(depth, n_rows), fetch, None)
+
+    def one_row(row, carry):
+        at = off_ref[row] % tile
+        for i in range(2):
+            tile_copy(row, i, out=False).wait()
+            buf = bufs[i].at[row % depth]
+            held = buf[...]  # [H, tile, D]
+            position = jax.lax.broadcasted_iota(jnp.int32, held.shape, 1)
+            buf[...] = jnp.where(
+                position == at, new_rows[i][row][:, None, :], held)
+            tile_copy(row, i, out=True).start()
+
+        # the row before has had this row's work to land: its buffers
+        # take the row ``depth`` after it
+        @pl.when(row > 0)
+        def _reuse():
+            wait_written(row - 1)
+
+            @pl.when(row - 1 + depth < n_rows)
+            def _next():
+                fetch(row - 1 + depth)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_rows, one_row, None)
+    wait_written(n_rows - 1)
+
+
+def paged_append(k_pool, v_pool, page, off, k_rows, v_rows, *,
+                 interpret: bool | None = None):
+    """``k_pool [P, H, ps, Dk]`` and ``v_pool [P, H, ps, Dv]`` with
+    ``k_rows [B, H, Dk]`` and ``v_rows [B, H, Dv]`` written at
+    ``(page[b], h, off[b])``: the bits ``nn/attention.py
+    _scatter_head_rows`` writes (the reference this is tested against),
+    every other position untouched. One call serves both pools; they stay
+    in HBM and are the call's outputs, so a caller that donates them (the
+    serving chunk's carried cache) has them updated in place. Rows whose
+    ``(page, tile)`` coincide (dead rows on the garbage page) leave that
+    tile holding one of their writes or a mix of them."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, dk = k_rows.shape
+    dv = v_rows.shape[-1]
+    tile = append_tile(k_pool.shape[2], k_pool.dtype)
+    depth = min(APPEND_ROWS_IN_FLIGHT, b)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    pool = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # a row's page and its position in it
+        grid=(),
+        in_specs=[whole, whole, pool, pool],
+        out_specs=[pool, pool],
+        scratch_shapes=[
+            pltpu.VMEM((depth, h, tile, dk), k_pool.dtype),
+            pltpu.VMEM((depth, h, tile, dv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, depth, 2)),  # in / out, buffer, pool
+        ],
+    )
+    pools = pl.pallas_call(
+        functools.partial(_paged_append_kernel, tile=tile, depth=depth),
+        grid_spec=grid_spec,
+        # HBM by name, which the aliased operands inherit: a pool that
+        # fits the compiler's fast memory (Qwen3's 37.8 MB, S(1) in the
+        # compiled text) is otherwise staged there whole around the call
+        # and copied back out, every layer every step
+        out_shape=[
+            pltpu.HBM(k_pool.shape, k_pool.dtype),
+            pltpu.HBM(v_pool.shape, v_pool.dtype),
+        ],
+        # operands count the scalar prefetch: page, off, rows, rows, pools
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+        name="paged_append",
+    )(page.astype(jnp.int32), off.astype(jnp.int32),
+      k_rows.astype(k_pool.dtype), v_rows.astype(v_pool.dtype),
+      k_pool, v_pool)
+    # where the pools are a program's own donated arguments and results (a
+    # step outside any loop) the compiler's verifier refuses the named
+    # outputs as aliases of unnamed parameters; behind a barrier it takes
+    # them (tests/core/test_chip_compile.py)
+    return jax.lax.optimization_barrier(tuple(pools))
+
+
 # d9d-lint: disable=D9D001 — standalone-use decorator; serving traces this inside the tracked serve/step program (a TrackedJit cannot be called under a trace)
 @functools.partial(
     jax.jit,

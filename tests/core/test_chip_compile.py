@@ -384,6 +384,88 @@ def test_a_scanned_cache_append_copies_no_pool(topo):
     assert not pool_copies
 
 
+@pytest.mark.parametrize(
+    "slots,hkv,dk,dv,row_pages,window",
+    [(256, 8, 256, 128, 3, 128), (64, 4, 128, 128, 9, None)],
+    ids=["mimo-window-ring-256x8", "qwen3-64x4"],
+)
+def test_a_scanned_paged_append_holds_the_pools_in_place(
+        topo, slots, hkv, dk, dv, row_pages, window):
+    """The same eight-step scan with ``paged_append`` in the scatter's
+    place, at the MiMo cell's window layers (256 slots, 8 kv heads, rings
+    of 3 pages, 256 / 128) and at the Qwen3 cell's (64 slots, 4 heads,
+    128 / 128): Mosaic takes the tile transfers for the described v5e
+    (a refused transfer shape shows here, before chip time is spent),
+    the compiled program holds no scatter and no copy of a pool, and
+    both pools go through the call aliased."""
+    from d9d_tpu.ops.attention.pallas_decode import (
+        flash_decode_attention,
+        paged_append,
+    )
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    hq, pages = 8 * hkv, slots * row_pages + 1
+
+    def chunk(kpool, vpool, table, start, q, knew, vnew):
+        def step(carry, _):
+            kpool, vpool, start = carry
+            page = jnp.take_along_axis(
+                table, (start // PAGE)[:, None], axis=1)[:, 0]
+            kpool, vpool = paged_append(
+                kpool, vpool, page, start % PAGE, knew, vnew,
+                interpret=False)
+            out = flash_decode_attention(
+                q, kpool, vpool, start=start, page_table=table,
+                window_size=window, interpret=False,
+            )
+            return (kpool, vpool, start + 1), out.astype(jnp.float32).sum()
+
+        (kpool, vpool, _), outs = jax.lax.scan(
+            step, (kpool, vpool, start), None, length=8)
+        return kpool, vpool, outs
+
+    text = jax.jit(chunk, donate_argnums=(0, 1)).lower(
+        sds((pages, hkv, PAGE, dk), BF16), sds((pages, hkv, PAGE, dv), BF16),
+        sds((slots, 18), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, 1, hq, dk), BF16), sds((slots, hkv, dk), BF16),
+        sds((slots, hkv, dv), BF16),
+    ).compile().as_text()
+    assert "scatter(" not in text
+    pool = rf"bf16\[{pages},{hkv},{PAGE},(?:{dk}|{dv})\]"
+    assert not re.findall(rf"= {pool}[^=\n]*\bcopy(?:-start)?\(", text)
+    (append,) = [
+        line for line in text.splitlines()
+        if "paged_append/pallas_call" in line and "custom-call(" in line
+    ]
+    # operands: page, off, the new rows, then the two pools
+    assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in append
+    # held in HBM by name: a pool that fits the compiler's fast memory is
+    # otherwise staged there whole around the call (the Qwen3 cell's body
+    # of six layers: 13 copies of a pool a step, none with this)
+    assert '"output_memory_colors":["0","0"]' in append
+
+
+def test_a_donated_paged_append_compiles_outside_a_loop(topo):
+    """The pools as a program's own donated arguments and results, no
+    loop around the call (a serving step that is not a fused chunk): the
+    verifier refused the call's HBM-named outputs as aliases of the
+    unnamed parameters (`Different aliasing shapes`) until
+    ``paged_append`` returned them behind a barrier."""
+    from d9d_tpu.ops.attention.pallas_decode import paged_append
+
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    slots, pages = 64, 64 * 9 + 1
+    text = jax.jit(
+        lambda *a: paged_append(*a, interpret=False), donate_argnums=(0, 1),
+    ).lower(
+        sds((pages, HKV, PAGE, D), BF16), sds((pages, HKV, PAGE, D), BF16),
+        sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots, HKV, D), BF16), sds((slots, HKV, D), BF16),
+    ).compile().as_text()
+    assert "paged_append/pallas_call" in text
+    assert not re.findall(r"\bcopy(?:-start)?\(", text)  # in place
+
+
 def test_mamba_step_at_jamba2_3b_widths(topo):
     """The Mamba-1 mixer's one-token step for 256 rows at the published
     widths (d_inner 5,120, d_state 16, dt_rank 160): the float32 state

@@ -14,11 +14,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from d9d_tpu.nn.attention import _decode_slot_mask
+from d9d_tpu.nn.attention import (
+    _decode_slot_mask,
+    _paged_slot,
+    _scatter_head_rows,
+)
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.ops.attention import pallas_decode
 from d9d_tpu.ops.attention.pallas_decode import (
+    append_tile,
     flash_decode_attention,
+    paged_append,
     paged_decode_geometry,
 )
 
@@ -321,6 +327,59 @@ def test_paged_dead_tail_on_the_garbage_page():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+@pytest.mark.parametrize("table", ["shared", "ring"])
+@pytest.mark.parametrize("ps", [16, 64])
+@pytest.mark.parametrize("dk,dv", [(128, 128), (256, 128)])
+@pytest.mark.parametrize("heads", [1, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_append_writes_the_scatters_bits(dtype, heads, dk, dv, ps, table):
+    """``paged_append`` against ``_scatter_head_rows`` on both pools, bit
+    for bit: rows at a tile's first, last and middle position and on a
+    page's last row, through the allocator's table (two dead rows on the
+    garbage page) and through a ring of pages (a wrapped row, a dead row
+    in its own ring); every other position of every pool unchanged."""
+    rng = np.random.RandomState(heads * ps + dk)
+    tile = append_tile(ps, dtype)
+    assert tile == (16 if dtype == jnp.bfloat16 else 8)
+    per_row = 3
+    starts = [0, tile - 1, tile + tile // 2, ps - 1, ps + tile, 0, 0]
+    b = len(starts)
+    if table == "shared":
+        pages = 1 + b * per_row
+        pt = 1 + rng.permutation(pages - 1).reshape(b, per_row)
+        pt[-2:] = 0  # dead rows: table row and write index pinned to 0
+    else:
+        pages = b * per_row
+        # nn/attention.py _ring_page_table's rule, five logical pages
+        pt = np.arange(b)[:, None] * per_row + np.arange(5)[None, :] % per_row
+        starts[4] = 4 * ps + 1  # logical page 4 lives in ring page 1
+    page, off = _paged_slot(
+        jnp.asarray(pt, jnp.int32), jnp.asarray(starts, jnp.int32), ps)
+
+    def draw(*shape):  # bits, so that a NaN pattern is a pattern like another
+        return jnp.asarray(rng.randn(*shape), dtype)
+
+    k_pool, v_pool = draw(pages, heads, ps, dk), draw(pages, heads, ps, dv)
+    k_rows, v_rows = draw(b, heads, dk), draw(b, heads, dv)
+    got = jax.jit(
+        lambda *a: paged_append(*a, interpret=True)
+    )(k_pool, v_pool, page, off, k_rows, v_rows)
+    for pool, rows, new in zip((k_pool, v_pool), (k_rows, v_rows), got):
+        want = np.array(_scatter_head_rows(pool, page, off, rows))
+        new = np.array(new)
+        assert new.dtype == want.dtype and new.shape == want.shape
+        if table == "shared":
+            # position 0 of the garbage page holds one dead row's write
+            # or the other's; the rest of that page is as it was
+            new[0, :, 0], want[0, :, 0] = 0, 0
+        np.testing.assert_array_equal(new, want)
+        untouched = np.ones(pool.shape[:3], bool)
+        untouched[np.asarray(page), :, np.asarray(off)] = False
+        np.testing.assert_array_equal(
+            new[untouched], np.asarray(pool)[untouched])
 
 
 @pytest.mark.parametrize(
