@@ -168,6 +168,11 @@ from d9d_tpu.loop.quantize import dequantize_params, is_quantized_tree
 from d9d_tpu.nn.decode_flags import zero_rows
 from d9d_tpu.telemetry import get_telemetry, tracked_jit
 
+# what the expert layers that hold a range of their router's experts sow
+# into ``moe_stats`` a step (nn/moe.py), in the order the fused chunk
+# carries their sums out: ``ServeStats.moe_<name>``
+_MOE_ROW_COUNTS = ("rows_held", "rows_routed", "rows_skipped")
+
 # slot-occupancy fraction per chunk/step: 20 linear bins over [0, 1]
 _UTIL_EDGES = tuple(i / 20 for i in range(21))
 
@@ -414,7 +419,9 @@ class ServeStats:
     routed (token, expert) pairs that landed on the held ones and all of
     them, dead rows' included (they step on token 0): the layers' own
     counts, carried out in the chunk's one token readback; 0 for a model
-    whose layers hold every expert.
+    whose layers hold every expert. ``moe_rows_skipped`` is, of those
+    routed pairs, the ones a router with a skip sent to it (ZAYA's
+    mixture-of-depths; 0 for a router without one).
     """
 
     host_dispatches: int = 0
@@ -435,6 +442,7 @@ class ServeStats:
     window_positions_attended: int = 0
     moe_rows_held: int = 0
     moe_rows_routed: int = 0
+    moe_rows_skipped: int = 0
     # degraded-mode counters: submits rejected by the bounded queue,
     # requests expired by their deadline (queued or running), requests
     # shed by the autopilot's burn-driven admission tiering
@@ -1091,8 +1099,8 @@ class ContinuousBatcher:
 
         sown = flatten_dict(state["moe_stats"])
         step_rows = jnp.stack([
-            sum(v for p, v in sown.items() if p[-1] == name)
-            for name in ("rows_held", "rows_routed")
+            jnp.asarray(sum(v for p, v in sown.items() if p[-1] == name))
+            for name in _MOE_ROW_COUNTS
         ]).astype(jnp.int32)
         return state["cache"], row_logits, held_rows + step_rows
 
@@ -1231,7 +1239,8 @@ class ContinuousBatcher:
                     cache = _pin_page_table(cache, live)
                 return (cache, tok, pos, live, rem, *held_rows), out
 
-            counts = (jnp.zeros((2,), jnp.int32),) if counted else ()
+            n_counts = len(_MOE_ROW_COUNTS)
+            counts = (jnp.zeros((n_counts,), jnp.int32),) if counted else ()
             (cache, tok, pos, live, rem, *counts), toks = jax.lax.scan(
                 body, (cache, tok, pos, live, rem, *counts),
                 (jnp.arange(k, dtype=jnp.int32), keys, forced_t),
@@ -1240,11 +1249,13 @@ class ContinuousBatcher:
             # host fetches in ONE readback per chunk
             toks = jnp.moveaxis(toks, 0, 1)
             if counted:
-                # the held-rows counts ride the same buffer: two more
-                # rows, each count in its first column
+                # the held-rows counts ride the same buffer: a row
+                # more for each, the count in its first column
                 toks = jnp.concatenate([
                     toks,
-                    jnp.zeros((2, k), jnp.int32).at[:, 0].set(counts[0]),
+                    jnp.zeros((n_counts, k), jnp.int32).at[:, 0].set(
+                        counts[0]
+                    ),
                 ])
             return cache, tok, pos, live, rem, key, toks
 
@@ -2296,10 +2307,12 @@ class ContinuousBatcher:
         clock.mark("readback")
         with annotate("serve.commit"):
             if self._counts_held_rows:
-                # the two rows below the slots' (``_build_fused``)
-                held, routed = (int(n) for n in toks[self._b:, 0])
+                # the rows below the slots' (``_build_fused``), in
+                # ``_MOE_ROW_COUNTS``' order
+                held, routed, skipped = (int(n) for n in toks[self._b:, 0])
                 self.stats.moe_rows_held += held
                 self.stats.moe_rows_routed += routed
+                self.stats.moe_rows_skipped += skipped
                 toks = toks[:self._b]
             now = time.perf_counter()
             self._progress_t = now
@@ -2369,13 +2382,14 @@ class ContinuousBatcher:
             clock.meta.update(
                 slot_steps_busy=chunk_busy, positions_attended=chunk_positions
             )
-            if self._ring_windows:
-                chunk_window = sum(
-                    layers * _positions_under(row_spans, window)
-                    for window, layers in self._ring_windows.items()
-                )
-                self.stats.window_positions_attended += chunk_window
-                clock.meta.update(window_positions_attended=chunk_window)
+            # 0 without window layers: a reader of the attention layers'
+            # work (the paged decode kernel's roofline) is told so
+            chunk_window = sum(
+                layers * _positions_under(row_spans, window)
+                for window, layers in self._ring_windows.items()
+            )
+            self.stats.window_positions_attended += chunk_window
+            clock.meta.update(window_positions_attended=chunk_window)
             self._observe(
                 "serve/slot_util", chunk_busy / (self._b * plan.k),
                 _UTIL_EDGES,

@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding
 
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn.attention import GroupedQueryAttention
+from d9d_tpu.nn.cca import near
 from d9d_tpu.nn.embedding import TokenEmbedding
 from d9d_tpu.nn.hyper_connections import expand_streams, sum_streams
 from d9d_tpu.nn import logical_axes as la
@@ -95,6 +96,21 @@ class KdaParameters:
 
 
 @dataclasses.dataclass(frozen=True)
+class CcaParameters:
+    """The ``"cca"`` kind's block (``nn/cca.py CompressedConvAttention``,
+    on the config's ``num_heads``, ``num_kv_heads`` and ``head_dim``):
+    the taps of its two convolutions and the readings its published
+    configuration leaves open, each a switch of the module."""
+
+    time0: int = 2
+    time1: int = 2
+    conv1_grouped: bool = True
+    qk_mean: bool = True
+    value_shift: bool = True
+    key_temperature: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
 class Multipliers:
     """Constants on the stack's path (the Granite family's four): on the
     embedding table's output, on both residual branches of every layer, a
@@ -134,16 +150,23 @@ class AttentionKind:
 # the token-mixer kinds a layer can be without an entry in
 # ``attention_kinds``: grouped-query attention with the plain fields,
 # latent attention (``mla``), a Mamba-1 mixer, a Mamba-2 mixer, a
-# GatedDeltaNet block, a Kimi delta attention block
-LAYER_KINDS = ("attention", "mla", "mamba", "mamba2", "gdn", "kda")
+# GatedDeltaNet block, a Kimi delta attention block, compressed
+# convolutional attention
+LAYER_KINDS = ("attention", "mla", "mamba", "mamba2", "gdn", "kda", "cca")
 # a stack with a layer of one of these adds its residual stream in
 # float32: the state-space mixers (Mamba's ``residual_in_fp32``) and Kimi
 # delta attention, whose float32 state remembers a prompt's roundings for
 # as long as it remembers the prompt (on the chip the share cell's bf16
 # stream stood 0.0082 from the float32 reference and its served streams
 # left ``generate``'s at reference gaps up to 0.012 of the 0.02 allowed;
-# with this 0.0057 and 0.0036, for 0.5 % of the rate: PERF.md, PR 51)
-FLOAT32_STREAM_KINDS = ("mamba", "mamba2", "kda")
+# with this 0.0057 and 0.0036, for 0.5 % of the rate: PERF.md, PR 51),
+# and compressed convolutional attention, whose unit queries and keys
+# make a softmax at logits up to sqrt(head_dim) out of whatever the
+# stream's rounding left (twelve layers' bf16 stream stood 0.0073 to
+# 0.0080 from the reference with served streams leaving ``generate``'s at
+# gaps up to 0.0185 of the 0.02; with this 0.0037 to 0.0047 and 0.0104,
+# for 1.0 % of the rate: PERF.md, PR 56)
+FLOAT32_STREAM_KINDS = ("mamba", "mamba2", "kda", "cca")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,6 +263,24 @@ class Qwen3MoeConfig:
     # the ``"kda"`` kind's block (models/solar/)
     kda: Optional[KdaParameters] = None
     multipliers: Multipliers = Multipliers()
+    # the ``"cca"`` kind's block (models/zaya/)
+    cca: Optional[CcaParameters] = None
+    # the ZAYA block's other parts. The router is an MLP
+    # ``router_hidden_size`` wide (0 = one matrix; nn/moe.py TopKRouter)
+    # whose state, with ``router_carry``, goes from each layer to the
+    # next: the layer loop then carries it beside the stream.
+    # ``router_skip``: the router's last id is a skip
+    # (``num_routed_experts`` is one more than the experts held).
+    # ``residual_scaling``: both residual additions are ``s_r * (x + b_r)
+    # + s_h * (branch + b_h)`` with four learned vectors each
+    # (``ScaledResidual``). ``init_jitter``: noise on the ones and zeros
+    # these vectors, the router's biases and the attention's temperature
+    # start at, for seeded weights that are to exercise them
+    router_hidden_size: int = 0
+    router_carry: bool = False
+    router_skip: bool = False
+    residual_scaling: bool = False
+    init_jitter: float = 0.0
     # the output head reads the embedding table (no head parameters);
     # the table is then drawn at embedding_init_std, the family's
     # initializer_range, so that logits are of order 1 at init
@@ -289,6 +330,13 @@ class Qwen3MoeConfig:
             raise ValueError(
                 f"{len(self.layer_kinds)} layer_kinds for {self.num_layers} "
                 "layers"
+            )
+        if self.router_carry and not self.router_hidden_size:
+            raise ValueError("router_carry needs the router's MLP form")
+        if self.residual_scaling and self.hc_mult > 1:
+            raise ValueError(
+                "residual_scaling and hc_mult both replace the residual "
+                "addition"
             )
 
     @property
@@ -415,6 +463,36 @@ class Qwen3MoeConfig:
         )
 
 
+class ScaledResidual(nn.Module):
+    """``s_r * (x + b_r) + s_h * (branch + b_h)``: a residual addition
+    with a learned scale and bias a channel on each side (the ZAYA
+    block's; ones and zeros at the published init). Float32 sums, the
+    result in the stream's type."""
+
+    hidden_size: int
+    init_jitter: float = 0.0
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: Array, branch: Array) -> Array:
+        def vector(name, value):
+            return self.param(
+                name,
+                nn.with_logical_partitioning(
+                    near(value, self.init_jitter), (None,)
+                ),
+                (self.hidden_size,), self.param_dtype,
+            ).astype(jnp.float32)
+
+        with jax.named_scope("residual_scale"):
+            out = vector("stream_scale", 1.0) * (
+                x.astype(jnp.float32) + vector("stream_bias", 0.0)
+            ) + vector("branch_scale", 1.0) * (
+                branch.astype(jnp.float32) + vector("branch_bias", 0.0)
+            )
+            return out.astype(x.dtype)
+
+
 class Qwen3MoeDecoderLayer(nn.Module):
     config: Qwen3MoeConfig
     sdpa: SdpaBackend
@@ -432,7 +510,11 @@ class Qwen3MoeDecoderLayer(nn.Module):
         sin: Optional[Array],
         mask: Optional[Array] = None,
         padding_mask: Optional[Array] = None,
-    ) -> Array:
+        router_state: Optional[Array] = None,
+    ):
+        """The stream after the layer; with ``router_carry`` ``(stream,
+        the router's state)``, ``router_state`` being the layer before's
+        (None for the first)."""
         cfg = self.config
         zc = cfg.zero_centered_norms
         # hc_mult > 1: ``x`` is the n-stream ``[B, T, n, C]`` and each
@@ -511,6 +593,26 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 param_dtype=self.param_dtype,
                 name="kda",
             )(normed, padding_mask)
+        elif kind == "cca":
+            from d9d_tpu.nn.cca import CompressedConvAttention
+
+            # named ``self_attn``: a trace counts the whole sublayer with
+            # the attention layers
+            attn_out = CompressedConvAttention(
+                hidden_size=cfg.hidden_size,
+                num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim,
+                **dataclasses.asdict(cfg.cca),
+                rope_fraction=cfg.rope_fraction,
+                init_jitter=cfg.init_jitter,
+                softmax_scale=cfg.multipliers.attention_softmax_scale,
+                sdpa=self.sdpa,
+                decode_max_length=self.decode_max_length,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                name="self_attn",
+            )(normed, cos, sin, mask, padding_mask)
         elif kind == "mla":
             from d9d_tpu.nn.attention import MultiHeadLatentAttention
 
@@ -570,6 +672,8 @@ class Qwen3MoeDecoderLayer(nn.Module):
             x = attn_hc.write(x, attn_out, attn_mix)
             mlp_hc = hc("mlp_mhc")
             mlp_in, mlp_mix = mlp_hc.read(x)
+        elif cfg.residual_scaling:
+            x = mlp_in = self._scaled_residual("attn_residual")(x, attn_out)
         else:
             x = mlp_in = x + attn_out
         h = RMSNorm(
@@ -602,15 +706,34 @@ class Qwen3MoeDecoderLayer(nn.Module):
                 router_topk_group=cfg.router_topk_group,
                 num_routed_experts=cfg.num_routed_experts,
                 first_held_expert=cfg.first_held_expert,
+                router_mlp_hidden=cfg.router_hidden_size,
+                router_carry=cfg.router_carry,
+                router_norm_eps=cfg.norm_eps,
+                router_init_jitter=cfg.init_jitter,
+                router_skip=cfg.router_skip,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name="mlp",
-            )(h)
+            )(h, router_state)
+            if cfg.router_hidden_size:
+                mlp_out, router_state = mlp_out
         if branch != 1.0:
             mlp_out = branch * mlp_out.astype(x.dtype)
         if hc:
-            return mlp_hc.write(x, mlp_out, mlp_mix)
-        return x + mlp_out
+            x = mlp_hc.write(x, mlp_out, mlp_mix)
+        elif cfg.residual_scaling:
+            x = self._scaled_residual("mlp_residual")(x, mlp_out)
+        else:
+            x = x + mlp_out
+        return (x, router_state) if cfg.router_carry else x
+
+    def _scaled_residual(self, name: str):
+        return ScaledResidual(
+            hidden_size=self.config.hidden_size,
+            init_jitter=self.config.init_jitter,
+            param_dtype=self.param_dtype,
+            name=name,
+        )
 
     def _hyper_connection(self, name: str):
         from d9d_tpu.nn.hyper_connections import HyperConnection
@@ -723,6 +846,15 @@ class Qwen3MoeBackbone(nn.Module):
         # stack of one kind builds one
         rope: dict = {}
         layer_cls = decoder_layer_class(cfg, self.decode_max_length)
+        if cfg.router_carry and self.stage.num_stages > 1:
+            raise NotImplementedError(
+                "router_carry: the router's state goes from layer to layer "
+                "beside the stream, and a pipeline stage hands on the "
+                "stream alone"
+            )
+        # the loop's second carry (router_carry): layer i's router state,
+        # read by layer i + 1's
+        router_state = None
 
         for gid in distribute_layers_for_pipeline_stage(cfg.num_layers, self.stage):
             kind = cfg.layer_kind(gid)
@@ -739,7 +871,9 @@ class Qwen3MoeBackbone(nn.Module):
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 name=f"layers_{gid}",
-            )(x, cos, sin, mask, padding_mask)
+            )(x, cos, sin, mask, padding_mask, router_state)
+            if cfg.router_carry:
+                x, router_state = x
             x = self._pin(x)
             # numerics plane (telemetry/numerics.py): tap each layer's
             # residual-stream output HERE — outside the (possible)

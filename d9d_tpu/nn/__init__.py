@@ -3,6 +3,7 @@ from d9d_tpu.nn.attention import (
     LowRankProjection,
     MultiHeadLatentAttention,
 )
+from d9d_tpu.nn.cca import CompressedConvAttention
 from d9d_tpu.nn.decoder import DecoderLayer
 from d9d_tpu.nn.embedding import TokenEmbedding
 from d9d_tpu.nn.heads import ClassificationHead, EmbeddingHead, LanguageModellingHead
@@ -36,6 +37,7 @@ __all__ = [
     "GroupedQueryAttention",
     "LowRankProjection",
     "MultiHeadLatentAttention",
+    "CompressedConvAttention",
     "DecoderLayer",
     "TokenEmbedding",
     "ClassificationHead",

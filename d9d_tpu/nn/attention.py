@@ -487,6 +487,19 @@ def _prefill_segments(mask, t: int, s_max: int) -> dict:
     return {"q_segments": seg, "kv_segments": seg}
 
 
+def rotate_leading(u, cos, sin, rot: int, style: RopeStyle):
+    """``u [..., D]`` with its first ``rot`` numbers rotated by the first
+    ``rot // 2`` frequencies of ``cos`` / ``sin``, the rest passed
+    through."""
+    cos_r, sin_r = cos[..., : rot // 2], sin[..., : rot // 2]
+    if rot == u.shape[-1]:
+        return apply_rope(u, cos_r, sin_r, style)
+    return jnp.concatenate(
+        [apply_rope(u[..., :rot], cos_r, sin_r, style), u[..., rot:]],
+        axis=-1,
+    )
+
+
 class _ProjKernel(nn.Module):
     """Declare a Dense-compatible kernel (``<name>/kernel``, shape
     ``[in, features]``, lecun-normal, logical axes) and return it raw —
@@ -643,19 +656,11 @@ class GroupedQueryAttention(nn.Module):
                 f"rope_fraction={self.rope_fraction} gives {rot}"
             )
         if rot:
-            cos_r, sin_r = cos[..., : rot // 2], sin[..., : rot // 2]
-
-            def rotated(u):
-                if rot == d:
-                    return apply_rope(u, cos_r, sin_r, self.rope_style)
-                return jnp.concatenate(
-                    [apply_rope(u[..., :rot], cos_r, sin_r, self.rope_style),
-                     u[..., rot:]],
-                    axis=-1,
-                )
-
             with jax.named_scope("rope"):
-                q, k = rotated(q), rotated(k)
+                q, k = (
+                    rotate_leading(u, cos, sin, rot, self.rope_style)
+                    for u in (q, k)
+                )
 
         sinks = None
         if self.use_sinks:
@@ -693,174 +698,128 @@ class GroupedQueryAttention(nn.Module):
             out = out * nn.sigmoid(gate)
         return proj(self.hidden_size, "o_proj", (la.HEADS, la.EMBED))(out)
 
+    # methods, so that the ops keep ``self_attn._sdpa_padded`` and
+    # ``self_attn._decode_attend`` in their scopes (the benchmark's
+    # readers find the flash and paged-decode calls by them); the bodies
+    # are functions of the module, shared with ``nn/cca.py``
     def _sdpa_padded(self, q, k, v, **kwargs):
-        """The SDPA backend on value heads zero-padded to the query/key
-        width, the padding cut off its output: every backend (flash and
-        ring included) takes one head width."""
-        pad = q.shape[-1] - v.shape[-1]
-        if not pad:
-            return self.sdpa(q, k, v, **kwargs)
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        return self.sdpa(q, k, v, **kwargs)[..., : v.shape[-1] - pad]
+        return sdpa_padded(self, q, k, v, **kwargs)
 
     def _decode_attend(self, q, k, v, sinks, mask, b, t):
-        """KV-cache attention: write the new k/v at the cache index, then
-        attend against the full static-length cache.
+        return decode_attend(self, q, k, v, sinks, mask, b, t)
 
-        Per-step attention is cache-bandwidth-bound; on TPU it runs the
-        Pallas flash-decode kernel (ops/attention/pallas_decode.py):
-        streams each (batch, kv-head) cache slice from HBM exactly once
-        with the GQA group as the matmul M dim, skips slots past the
-        write index, and never materializes [B,H,T,S] logits — the
-        eager oracle remains the fallback (non-TPU, or masks beyond the
-        key-validity form) and the parity reference. Cache mechanics +
-        capacity/mask contracts: the module-level ``_decode_cache_append``
-        / ``_decode_slot_mask`` helpers.
-        """
-        from d9d_tpu.nn.decode_flags import in_continuation_chunk
-        from d9d_tpu.ops.attention.eager import eager_sdpa
-        from d9d_tpu.ops.attention.pallas_decode import (
-            MAX_DECODE_ROWS,
-            decode_attention_backend,
-            flash_decode_attention,
-        )
 
-        s_max = self.decode_max_length
-        idx = _decode_cache_index(self)
-        start = idx.value
-        _decode_contract_checks(start, t, s_max)
-        # a cached key row ends on a lane tile's edge (_cache_row_pad):
-        # new keys are zero-padded on their way into a cache and so are
-        # the queries that meet cached keys (zeros add nothing to a
-        # score; the scale stays the head's own). The prefill fast path
-        # below attends the new tokens as they are.
-        scale = (
-            self.softmax_scale if self.softmax_scale is not None
-            else q.shape[-1] ** -0.5
-        )
-        k_new, q_new = k, q
-        pad = _cache_row_pad(q.shape[-1])
-        if pad:
-            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        page_table = _decode_page_table(self)
-        key_leaf, value_leaf = "cached_key", "cached_value"
-        ring_table = _ring_page_table(
-            self, b, s_max, self.window_size,
-            (k.shape[2:], v.shape[2:]), self.dtype,
-        )
-        if ring_table is not None:
-            # a window layer under the paged serving loop: the paged
-            # path below on the row's own ring of pages
-            from d9d_tpu.nn.decode_flags import RING_CACHE_LEAVES
+def sdpa_padded(module, q, k, v, **kwargs):
+    """The SDPA backend on value heads zero-padded to the query/key
+    width, the padding cut off its output: every backend (flash and
+    ring included) takes one head width."""
+    pad = q.shape[-1] - v.shape[-1]
+    if not pad:
+        return module.sdpa(q, k, v, **kwargs)
+    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    return module.sdpa(q, k, v, **kwargs)[..., : v.shape[-1] - pad]
 
-            page_table, (key_leaf, value_leaf) = ring_table, RING_CACHE_LEAVES
-        if page_table is not None:
-            # paged serving mode (loop/serve.py): one token per row per
-            # step through page pools; the flash path streams the pool
-            # through the gathering block index map, the eager oracle
-            # gathers a contiguous per-row view
-            index = start
-            if ring_table is not None and self.is_initializing():
-                # the serving loop's shape-only init: the index is
-                # still the scalar it seeds per row
-                start = jnp.broadcast_to(start, (b,))
-            _paged_write_checks(start, t, mask)
-            k_pool, v_pool = _paged_append_kv(
-                self, k.astype(self.dtype), v.astype(self.dtype),
-                (key_leaf, value_leaf), start, page_table,
-            )
-            idx.value = index + t
-            # kv_quant mode (loop/serve.py): the appends above wrote
-            # int8 + per-slot scales; both read paths dequantize
-            k_scale = v_scale = None
-            if self.has_variable("cache", "cached_key_scale"):
-                k_scale = self.get_variable("cache", "cached_key_scale")
-                v_scale = self.get_variable("cache", "cached_value_scale")
-            rows = (self.num_heads // self.num_kv_heads) * t
-            if (
-                decode_attention_backend() == "pallas"
-                and rows <= MAX_DECODE_ROWS
-            ):
-                return flash_decode_attention(
-                    q, k_pool, v_pool,
-                    start=start,
-                    softmax_scale=scale,
-                    window_size=self.window_size,
-                    sinks=sinks,
-                    page_table=page_table,
-                    k_scale=k_scale,
-                    v_scale=v_scale,
-                )
-            if k_scale is not None:
-                keys = _gather_pages_heads_major_quant(
-                    k_pool, k_scale, page_table, self.dtype
-                )
-                values = _gather_pages_heads_major_quant(
-                    v_pool, v_scale, page_table, self.dtype
-                )
-            else:
-                keys = _gather_pages_heads_major(k_pool, page_table)
-                values = _gather_pages_heads_major(v_pool, page_table)
-            s_virt = keys.shape[2]
-            return eager_sdpa(
-                q,
-                jnp.transpose(keys, (0, 2, 1, 3)),
-                jnp.transpose(values, (0, 2, 1, 3)),
-                causal=False,
-                softmax_scale=scale,
-                sinks=sinks,
-                mask=_decode_slot_mask(
-                    start, t, s_virt, self.window_size, None
-                ),
-            )
-        # heads-major [B, Hkv, s_max, D]: the flash-decode kernel's
-        # streaming layout, written in place (no per-step cache relayout)
-        keys = _decode_cache_append_heads_major(
-            self, k.astype(self.dtype), "cached_key", s_max, start
+
+def decode_attend(module, q, k, v, sinks, mask, b, t):
+    """KV-cache attention: write the new k/v at the cache index, then
+    attend against the full static-length cache.
+
+    Per-step attention is cache-bandwidth-bound; on TPU it runs the
+    Pallas flash-decode kernel (ops/attention/pallas_decode.py):
+    streams each (batch, kv-head) cache slice from HBM exactly once
+    with the GQA group as the matmul M dim, skips slots past the
+    write index, and never materializes [B,H,T,S] logits — the
+    eager oracle remains the fallback (non-TPU, or masks beyond the
+    key-validity form) and the parity reference. Cache mechanics +
+    capacity/mask contracts: the module-level ``_decode_cache_append``
+    / ``_decode_slot_mask`` helpers.
+    """
+    from d9d_tpu.nn.decode_flags import in_continuation_chunk
+    from d9d_tpu.ops.attention.eager import eager_sdpa
+    from d9d_tpu.ops.attention.pallas_decode import (
+        MAX_DECODE_ROWS,
+        decode_attention_backend,
+        flash_decode_attention,
+    )
+
+    s_max = module.decode_max_length
+    idx = _decode_cache_index(module)
+    start = idx.value
+    _decode_contract_checks(start, t, s_max)
+    # a cached key row ends on a lane tile's edge (_cache_row_pad):
+    # new keys are zero-padded on their way into a cache and so are
+    # the queries that meet cached keys (zeros add nothing to a
+    # score; the scale stays the head's own). The prefill fast path
+    # below attends the new tokens as they are.
+    scale = (
+        module.softmax_scale if module.softmax_scale is not None
+        else q.shape[-1] ** -0.5
+    )
+    k_new, q_new = k, q
+    pad = _cache_row_pad(q.shape[-1])
+    if pad:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    page_table = _decode_page_table(module)
+    key_leaf, value_leaf = "cached_key", "cached_value"
+    ring_table = _ring_page_table(
+        module, b, s_max, module.window_size,
+        (k.shape[2:], v.shape[2:]), module.dtype,
+    )
+    if ring_table is not None:
+        # a window layer under the paged serving loop: the paged
+        # path below on the row's own ring of pages
+        from d9d_tpu.nn.decode_flags import RING_CACHE_LEAVES
+
+        page_table, (key_leaf, value_leaf) = ring_table, RING_CACHE_LEAVES
+    if page_table is not None:
+        # paged serving mode (loop/serve.py): one token per row per
+        # step through page pools; the flash path streams the pool
+        # through the gathering block index map, the eager oracle
+        # gathers a contiguous per-row view
+        index = start
+        if ring_table is not None and module.is_initializing():
+            # the serving loop's shape-only init: the index is
+            # still the scalar it seeds per row
+            start = jnp.broadcast_to(start, (b,))
+        _paged_write_checks(start, t, mask)
+        k_pool, v_pool = _paged_append_kv(
+            module, k.astype(module.dtype), v.astype(module.dtype),
+            (key_leaf, value_leaf), start, page_table,
         )
-        values = _decode_cache_append_heads_major(
-            self, v.astype(self.dtype), "cached_value", s_max, start
-        )
-        idx.value = start + t
-        if t > 1 and not in_continuation_chunk():
-            # PREFILL fast path: attend the new tokens against themselves
-            # through the training SDPA (flash on TPU) — the eager slot
-            # path would materialize [t, s_max] logits, which explodes
-            # for long prompts. Valid only when the cache was empty
-            # (start == 0), which is exactly how loop/generate.py issues
-            # its first (or only) multi-token call; start is traced, so
-            # the contract is asserted via checkify
-            # (_decode_contract_checks) and enforced statically by
-            # generate(). Continuation prefill chunks (chunked prefill,
-            # loop/generate.py prefill_chunk_size) fall through to the
-            # slot-cache path below, which is valid at any cache index.
-            return self._sdpa_padded(
-                q_new, k_new, v,
-                causal=True,
-                softmax_scale=self.softmax_scale,
-                window_size=self.window_size,
-                sinks=sinks,
-                **_prefill_segments(mask, t, s_max),
-            )
-        key_validity_mask = mask is None or (
-            mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1
-        )
-        rows = (self.num_heads // self.num_kv_heads) * t
+        idx.value = index + t
+        # kv_quant mode (loop/serve.py): the appends above wrote
+        # int8 + per-slot scales; both read paths dequantize
+        k_scale = v_scale = None
+        if module.has_variable("cache", "cached_key_scale"):
+            k_scale = module.get_variable("cache", "cached_key_scale")
+            v_scale = module.get_variable("cache", "cached_value_scale")
+        rows = (module.num_heads // module.num_kv_heads) * t
         if (
             decode_attention_backend() == "pallas"
-            and key_validity_mask
             and rows <= MAX_DECODE_ROWS
         ):
-            _check_slot_mask(mask, s_max)
             return flash_decode_attention(
-                q, keys, values,
+                q, k_pool, v_pool,
                 start=start,
                 softmax_scale=scale,
-                window_size=self.window_size,
+                window_size=module.window_size,
                 sinks=sinks,
-                kv_valid=None if mask is None else mask[:, 0, 0, :],
+                page_table=page_table,
+                k_scale=k_scale,
+                v_scale=v_scale,
             )
+        if k_scale is not None:
+            keys = _gather_pages_heads_major_quant(
+                k_pool, k_scale, page_table, module.dtype
+            )
+            values = _gather_pages_heads_major_quant(
+                v_pool, v_scale, page_table, module.dtype
+            )
+        else:
+            keys = _gather_pages_heads_major(k_pool, page_table)
+            values = _gather_pages_heads_major(v_pool, page_table)
+        s_virt = keys.shape[2]
         return eager_sdpa(
             q,
             jnp.transpose(keys, (0, 2, 1, 3)),
@@ -868,8 +827,66 @@ class GroupedQueryAttention(nn.Module):
             causal=False,
             softmax_scale=scale,
             sinks=sinks,
-            mask=_decode_slot_mask(start, t, s_max, self.window_size, mask),
+            mask=_decode_slot_mask(
+                start, t, s_virt, module.window_size, None
+            ),
         )
+    # heads-major [B, Hkv, s_max, D]: the flash-decode kernel's
+    # streaming layout, written in place (no per-step cache relayout)
+    keys = _decode_cache_append_heads_major(
+        module, k.astype(module.dtype), "cached_key", s_max, start
+    )
+    values = _decode_cache_append_heads_major(
+        module, v.astype(module.dtype), "cached_value", s_max, start
+    )
+    idx.value = start + t
+    if t > 1 and not in_continuation_chunk():
+        # PREFILL fast path: attend the new tokens against themselves
+        # through the training SDPA (flash on TPU) — the eager slot
+        # path would materialize [t, s_max] logits, which explodes
+        # for long prompts. Valid only when the cache was empty
+        # (start == 0), which is exactly how loop/generate.py issues
+        # its first (or only) multi-token call; start is traced, so
+        # the contract is asserted via checkify
+        # (_decode_contract_checks) and enforced statically by
+        # generate(). Continuation prefill chunks (chunked prefill,
+        # loop/generate.py prefill_chunk_size) fall through to the
+        # slot-cache path below, which is valid at any cache index.
+        return module._sdpa_padded(
+            q_new, k_new, v,
+            causal=True,
+            softmax_scale=module.softmax_scale,
+            window_size=module.window_size,
+            sinks=sinks,
+            **_prefill_segments(mask, t, s_max),
+        )
+    key_validity_mask = mask is None or (
+        mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1
+    )
+    rows = (module.num_heads // module.num_kv_heads) * t
+    if (
+        decode_attention_backend() == "pallas"
+        and key_validity_mask
+        and rows <= MAX_DECODE_ROWS
+    ):
+        _check_slot_mask(mask, s_max)
+        return flash_decode_attention(
+            q, keys, values,
+            start=start,
+            softmax_scale=scale,
+            window_size=module.window_size,
+            sinks=sinks,
+            kv_valid=None if mask is None else mask[:, 0, 0, :],
+        )
+    return eager_sdpa(
+        q,
+        jnp.transpose(keys, (0, 2, 1, 3)),
+        jnp.transpose(values, (0, 2, 1, 3)),
+        causal=False,
+        softmax_scale=scale,
+        sinks=sinks,
+        mask=_decode_slot_mask(start, t, s_max, module.window_size, mask),
+    )
 
 
 def _decompress_kv(c, k_rope, w, num_heads: int, d_nope: int, dtype):

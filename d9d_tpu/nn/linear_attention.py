@@ -44,11 +44,14 @@ class CausalShortConv1d(nn.Module):
     causal_conv1d). Weight [channels, kernel]; ``use_bias`` adds a
     per-channel bias before the SiLU (Mamba's conv1d). ``left_context``
     ``[B, K-1, C]`` stands in for the zero left pad: the true previous
-    inputs of a decode-mode call."""
+    inputs of a decode-mode call. ``activation`` off gives the plain
+    filter in float32 (compressed convolutional attention's first
+    convolution, ``nn/cca.py``)."""
 
     channels: int
     kernel_size: int
     use_bias: bool = False
+    activation: bool = True
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -80,6 +83,8 @@ class CausalShortConv1d(nn.Module):
                 self.param_dtype,
             )
             out = out + bias.astype(jnp.float32)
+        if not self.activation:
+            return out
         return jax.nn.silu(out).astype(x.dtype)
 
 
@@ -99,34 +104,45 @@ def _depthwise_causal_conv(xp: Array, w: Array) -> Array:
     return out
 
 
+def shift_tail(mixer: nn.Module, leaf: str, xs: Array, rows: int) -> Array:
+    """The per-row cache leaf ``leaf [B, rows, C]`` of ``mixer``: the
+    ``rows`` inputs before ``xs [B, T, C]``, zeros for a fresh row. It is
+    returned as it stood and left holding the last ``rows`` of itself
+    followed by ``xs``, in the mixer's activation type."""
+    batch, t, channels = xs.shape
+    tail = mixer.variable(
+        "cache", leaf, lambda: jnp.zeros((batch, rows, channels), mixer.dtype)
+    )
+    context = tail.value
+    tail.value = jnp.concatenate(
+        [context, xs.astype(mixer.dtype)], axis=1
+    )[:, t:]
+    return context
+
+
 def conv_with_tail(
     mixer: nn.Module, xs: Array, channels: int, *, taps: int, name: str,
     scope: str, use_bias: bool = False, keep: Optional[Array] = None,
+    activation: bool = True, leaf: str = "conv_tail",
 ) -> Array:
     """``silu(conv1d_causal(xs) [+ bias])`` in float32 under the scope
     ``scope``, for any mixer with a short convolution (inside its
     ``@nn.compact`` call; ``mixer.decode``, ``.dtype`` and
     ``.param_dtype`` are read). In decode mode the convolution's previous
     ``taps - 1`` inputs are the ``conv_tail`` cache leaf ``[B, K-1,
-    channels]`` in the activation type, read as the left context and
-    shifted by the new inputs. ``keep [B, T, 1]`` zeroes padded positions
-    again: a bias would otherwise leak into them."""
-    batch, t, _ = xs.shape
+    channels]`` in the activation type (``leaf`` names it where a mixer
+    keeps more than one), read as the left context and shifted by the
+    new inputs (:func:`shift_tail`). ``keep [B, T, 1]`` zeroes padded
+    positions again: a bias would otherwise leak into them.
+    ``activation`` off leaves the SiLU out."""
     with jax.named_scope(scope):
         conv = CausalShortConv1d(
             channels=channels, kernel_size=taps, use_bias=use_bias,
-            name=name, param_dtype=mixer.param_dtype,
+            activation=activation, name=name, param_dtype=mixer.param_dtype,
         )
         context = None
         if mixer.decode and taps > 1:
-            tail = mixer.variable(
-                "cache", "conv_tail",
-                lambda: jnp.zeros((batch, taps - 1, channels), mixer.dtype),
-            )
-            context = tail.value
-            tail.value = jnp.concatenate(
-                [context, xs.astype(mixer.dtype)], axis=1
-            )[:, t:]
+            context = shift_tail(mixer, leaf, xs, taps - 1)
         xs = conv(xs.astype(jnp.float32), context)
         if keep is not None:
             xs = xs * keep.astype(jnp.float32)

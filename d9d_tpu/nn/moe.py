@@ -33,7 +33,9 @@ from jax.sharding import PartitionSpec as P
 from d9d_tpu.core import compat
 from d9d_tpu.core.types import Array
 from d9d_tpu.nn import logical_axes as la
+from d9d_tpu.nn.cca import near
 from d9d_tpu.nn.mlp import SwiGLU
+from d9d_tpu.nn.norm import RMSNorm
 from d9d_tpu.ops.ep_dispatch import (
     ep_buffer_rows,
     ep_dispatch_compute_combine,
@@ -53,6 +55,8 @@ from d9d_tpu.ops.moe import (
 )
 from d9d_tpu.ops.moe_pallas import fused_moe_ffn_apply, moe_ffn_backend
 from d9d_tpu.ops.swiglu import silu_mul
+
+F32 = jnp.float32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +81,19 @@ class TopKRouter(nn.Module):
     (``Trainer``, ``generate``, ``ContinuousBatcher``, a checkpoint)
     carries it, its gradient is exactly zero, and a balancing controller
     writes it outside the gradient path.
+
+    ``mlp_hidden`` > 0 is the ZAYA form (arXiv:2511.17127): the gate is
+    a small MLP, not one matrix. ``z = h W_D + b_D`` (``mlp_hidden``
+    wide); with ``carry`` the state the previous layer's router handed
+    on joins it, ``z += gamma * r_prev`` (a learned vector; the first
+    layer is given none and has no ``gamma``), and ``z`` is what this
+    layer hands on; the scores are ``W_3 gelu(W_2 gelu(W_1 RMSNorm(z) +
+    b_1) + b_2)``. The call then returns ``z`` beside indices and
+    weights. The down-projection takes its operands in the activation
+    type and sums in float32; everything after it is float32 (a router
+    of a few hundred numbers a token: top-1 selection is what its
+    rounding would move). Scopes ``moe/router/{down, eda, mlp, score,
+    select}``.
     """
 
     dim: int
@@ -91,26 +108,72 @@ class TopKRouter(nn.Module):
     # global top-k. n_group == 1 is plain top-k.
     n_group: int = 1
     topk_group: int = 1
+    # the MLP form: its width (0 = one matrix), whether it carries state
+    # from layer to layer, RMSNorm's eps, and noise on the init of its
+    # biases and ``gamma`` (``nn/cca.py near``)
+    mlp_hidden: int = 0
+    carry: bool = False
+    norm_eps: float = 1e-6
+    init_jitter: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, hidden: Array) -> tuple[Array, Array]:
-        """hidden [..., D] → (indices [..., K] int32, probs [..., K] fp32)."""
+    def __call__(self, hidden: Array, carried: Optional[Array] = None):
+        """hidden [..., D] → (indices [..., K] int32, probs [..., K] fp32),
+        and the router's state [..., mlp_hidden] as a third in the MLP
+        form (``carried``: the previous layer's)."""
         if self.score_function not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"score_function {self.score_function!r}: softmax or sigmoid"
             )
-        with jax.named_scope("moe/router/score"):
-            scores = nn.Dense(
-                self.num_experts,
-                use_bias=False,
-                name="gate",
-                dtype=self.dtype,
+
+        def dense(features, name, axes=(None, None), dtype=F32, **kw):
+            return nn.Dense(
+                features, name=name, dtype=dtype,
                 param_dtype=self.param_dtype,
                 kernel_init=nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(), (la.EMBED, None)
+                    nn.initializers.lecun_normal(), axes
                 ),
+                bias_init=nn.with_logical_partitioning(
+                    near(0.0, self.init_jitter), (None,)
+                ),
+                **kw,
+            )
+
+        state = None
+        if self.mlp_hidden:
+            with jax.named_scope("moe/router/down"):
+                state = dense(
+                    self.mlp_hidden, "down", (la.EMBED, None), self.dtype,
+                    dot_general=functools.partial(
+                        lax.dot_general, preferred_element_type=F32
+                    ),
+                )(hidden).astype(F32)
+            if self.carry and carried is not None:
+                with jax.named_scope("moe/router/eda"):
+                    gamma = self.param(
+                        "carry_scale",
+                        nn.with_logical_partitioning(
+                            near(1.0, self.init_jitter), (None,)
+                        ),
+                        (self.mlp_hidden,), self.param_dtype,
+                    )
+                    state = state + gamma.astype(F32) * carried.astype(F32)
+            with jax.named_scope("moe/router/mlp"):
+                hidden = RMSNorm(
+                    self.mlp_hidden, eps=self.norm_eps, name="norm",
+                    param_dtype=self.param_dtype,
+                )(state)
+                for name in ("fc1", "fc2"):
+                    hidden = jax.nn.gelu(
+                        dense(self.mlp_hidden, name)(hidden),
+                        approximate=False,
+                    )
+        with jax.named_scope("moe/router/score"):
+            scores = dense(
+                self.num_experts, "gate", (la.EMBED, None),
+                F32 if self.mlp_hidden else self.dtype, use_bias=False,
             )(hidden)
             if self.score_function == "softmax":
                 probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
@@ -158,7 +221,8 @@ class TopKRouter(nn.Module):
                 selected_probs = selected_probs / (
                     selected_probs.sum(axis=-1, keepdims=True) + 1e-20
                 )
-            return selected_idx.astype(jnp.int32), selected_probs
+            routed = selected_idx.astype(jnp.int32), selected_probs
+            return routed if state is None else (*routed, state)
 
 
 class GroupedSwiGLU(nn.Module):
@@ -559,6 +623,19 @@ class MoELayer(nn.Module):
     # to compute: nothing here stands in for them.
     num_routed_experts: int = 0
     first_held_expert: int = 0
+    # the router as an MLP that may carry state from layer to layer
+    # (``TopKRouter.mlp_hidden``, ``.carry``): the call then takes the
+    # previous layer's state and returns this layer's beside its output
+    router_mlp_hidden: int = 0
+    router_carry: bool = False
+    router_norm_eps: float = 1e-6
+    router_init_jitter: float = 0.0
+    # the router's last id is no expert but a skip (ZAYA's
+    # mixture-of-depths): a token routed there gets nothing from this
+    # layer. It is an id outside the held range like any other
+    # (``num_routed_experts`` one more than the experts), so nothing
+    # masks it; the flag has the layer count its rows, ``rows_skipped``
+    router_skip: bool = False
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
@@ -569,18 +646,23 @@ class MoELayer(nn.Module):
     def setup(self) -> None:
         held, routed = self.num_grouped_experts, self.router_width
         if routed != held:
-            if routed % held or not (
-                0 <= self.first_held_expert <= routed - held
-            ):
+            if not 0 <= self.first_held_expert <= routed - held:
                 raise ValueError(
-                    f"{held} experts from {self.first_held_expert} on are "
-                    f"no share of {routed} routed experts"
+                    f"{held} experts from {self.first_held_expert} on do "
+                    f"not lie inside {routed} routed experts"
                 )
             if self.ep_axes is not None:
                 raise ValueError(
                     "a held range is one chip's view of an expert-parallel "
                     "layer; with ep_axes the mesh holds every expert"
                 )
+        if self.router_skip and (
+            self.first_held_expert + held >= routed
+        ):
+            raise ValueError(
+                f"a skip is the router's last id, {routed - 1}, outside "
+                f"the experts held ({held} from {self.first_held_expert})"
+            )
         self.router = TopKRouter(
             dim=self.hidden_dim,
             num_experts=routed,
@@ -590,6 +672,10 @@ class MoELayer(nn.Module):
             score_function=self.router_score_function,
             n_group=self.router_n_group,
             topk_group=self.router_topk_group,
+            mlp_hidden=self.router_mlp_hidden,
+            carry=self.router_carry,
+            norm_eps=self.router_norm_eps,
+            init_jitter=self.router_init_jitter,
             dtype=self.dtype,
             param_dtype=self.param_dtype,
         )
@@ -608,8 +694,10 @@ class MoELayer(nn.Module):
                 param_dtype=self.param_dtype,
             )
 
-    def __call__(self, hidden: Array) -> Array:
-        """[B, T, D] → [B, T, D]."""
+    def __call__(self, hidden: Array, router_state: Optional[Array] = None):
+        """[B, T, D] → [B, T, D]; with an MLP router ``(output, the
+        router's state [B, T, router_mlp_hidden])``, ``router_state`` being
+        the previous layer's."""
         orig_shape = hidden.shape
 
         # router + shared expert run on the 3D layout: flattening first
@@ -619,17 +707,23 @@ class MoELayer(nn.Module):
         if self.shared_expert is not None:
             shared = self.shared_expert_module(hidden)
 
-        topk_ids, topk_probs = self.router(hidden)  # [B, T, K]
+        # [B, T, K] twice, and the MLP form's state
+        topk_ids, topk_probs, *state = self.router(hidden, router_state)
 
         # load-balancing stats (reference tokens_per_expert buffer):
         # collected when callers apply with mutable=["moe_stats"]
+        per_expert = jnp.bincount(
+            topk_ids.reshape(-1), length=self.router_width
+        )
         self.sow(
             "moe_stats",
             "tokens_per_expert",
-            jnp.bincount(topk_ids.reshape(-1), length=self.router_width),
+            per_expert,
             reduce_fn=lambda a, b: a + b,
             init_fn=lambda: jnp.zeros((self.router_width,), jnp.int32),
         )
+        if self.router_skip:
+            self._sow_count("rows_skipped", per_expert[-1])
 
         k = topk_ids.shape[-1]
         if self.router_width != self.num_grouped_experts:
@@ -653,7 +747,15 @@ class MoELayer(nn.Module):
             out = out * jnp.asarray(self.routed_scaling, out.dtype)
         if shared is not None:
             out = out + shared
-        return out
+        return (out, *state) if state else out
+
+    def _sow_count(self, name: str, value) -> None:
+        """A scalar of ``moe_stats`` that sums over layers and calls."""
+        self.sow(
+            "moe_stats", name, jnp.asarray(value, jnp.float32),
+            reduce_fn=lambda a, b: a + b,
+            init_fn=lambda: jnp.zeros((), jnp.float32),
+        )
 
     # --- local permute path (reference communications/naive.py) ----------
 
@@ -705,14 +807,8 @@ class MoELayer(nn.Module):
             local = jnp.where((local >= 0) & (local < held), local, held)
         # routed pairs that landed here, and all of them: the share says
         # how far the rows computed are from an even router's
-        for name, value in (
-            ("rows_held", (local < held).sum()), ("rows_routed", local.size),
-        ):
-            self.sow(
-                "moe_stats", name, jnp.asarray(value, jnp.float32),
-                reduce_fn=lambda a, b: a + b,
-                init_fn=lambda: jnp.zeros((), jnp.float32),
-            )
+        self._sow_count("rows_held", (local < held).sum())
+        self._sow_count("rows_routed", local.size)
         if moe_ffn_backend() == "xla" and few_rows_touch_all_experts(
             *local.shape, self.router_width, HELD_FEW_ROWS_LIMIT
         ):
@@ -866,10 +962,4 @@ class MoELayer(nn.Module):
         drifted out of the snug rung says so in its metrics."""
         values = (*use.mean(axis=0), jnp.ones((), jnp.float32))
         for name, value in zip(EP_BUFFER_STATS, values):
-            self.sow(
-                "moe_stats",
-                name,
-                value,
-                reduce_fn=lambda a, b: a + b,
-                init_fn=lambda: jnp.zeros((), jnp.float32),
-            )
+            self._sow_count(name, value)

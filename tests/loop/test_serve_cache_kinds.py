@@ -82,9 +82,10 @@ def test_a_model_with_window_layers_adds_rings_and_counts():
         == {(slots, 8)}
     assert dict(batcher._ring_windows) == {6: 2}
     assert batcher._kv.prefix_cache_enabled is False
-    # the chunk's one output carries the held-rows counts below the slots
+    # the chunk's one output carries the held-rows counts below the slots:
+    # held, routed and (0 for a router without a skip) skipped
     out = chunk_output(batcher, slots)
-    assert out[-1].shape == (slots + 2, 8)
+    assert out[-1].shape == (slots + 3, 8)
     batcher.close()
 
 

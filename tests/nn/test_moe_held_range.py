@@ -403,9 +403,11 @@ def test_without_a_range_the_layer_is_the_layer_it_was():
 
 
 @pytest.mark.parametrize("kwargs,why", [
-    (dict(routed=16, held=5, first=0), "no share"),
-    (dict(routed=16, held=4, first=13), "no share"),
+    (dict(routed=17, held=16, first=2), "lie inside"),
+    (dict(routed=16, held=4, first=13), "lie inside"),
     (dict(routed=16, held=4, first=0, ep_axes=("ep",)), "ep_axes"),
+    # a skip is the router's last id: the held range may not reach it
+    (dict(routed=17, held=16, first=1, router_skip=True), "skip"),
 ])
 def test_a_range_that_is_no_share_is_refused(kwargs, why):
     x = jnp.zeros((1, 8, D))
@@ -414,10 +416,32 @@ def test_a_range_that_is_no_share_is_refused(kwargs, why):
         module.init(jax.random.PRNGKey(0), x)
 
 
+def test_a_range_need_not_divide_the_routers_width():
+    """16 experts held of a router 17 wide, the last id a skip (ZAYA's):
+    the range lies inside the width, which is all a range has to do. The
+    layer counts the skip's rows from the last entry of
+    ``tokens_per_expert``, and what is neither held nor skipped is
+    nothing here."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, D))
+    module = layer(routed=17, held=16, first=0, top_k=1, router_skip=True)
+    params = jax.jit(module.init)(jax.random.PRNGKey(1), x)
+    _, sown = jax.jit(lambda p, x: module.apply(
+        p, x, mutable=["moe_stats"]))({"params": params["params"]}, x)
+    stats = sown["moe_stats"]
+    per_expert = np.asarray(stats["tokens_per_expert"])
+    assert per_expert.shape == (17,) and per_expert.sum() == 48
+    assert float(stats["rows_skipped"]) == per_expert[-1]
+    assert float(stats["rows_routed"]) == 48
+    assert float(stats["rows_held"]) == 48 - per_expert[-1]
+
+
 @pytest.mark.parametrize("n,k,held,routed,want", [
     (1, 10, 18, 72, ((10,), 1)),  # a one-row generate step at top-10
     (1, 4, 4, 16, ((4,), 1)),
     (128, 10, 18, 72, ((400, 504, 632, 784, 984), 2)),
+    # top-1 over 16 held of 17 routed: a one-row step, a batcher's two rows
+    (1, 1, 16, 17, ((1,), 1)),
+    (2, 1, 16, 17, ((2,), 1)),
 ])
 def test_one_tokens_pairs_never_overflow_the_last_rung(n, k, held, routed, want):
     """A call so small that one token's own pairs pass the last one-pass
