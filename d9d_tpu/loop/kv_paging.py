@@ -5,7 +5,8 @@ The device side of paging is deliberately dumb: a pool of fixed-size
 pages per cache leaf plus a static-shape ``[B, max_pages]`` int32 page
 table that the jitted decode step indexes through
 (``nn/attention.py`` write/gather; ``ops/attention/pallas_decode.py``
-copies a row's live pages a block of pages a grid step). Everything with policy in it — allocation, free
+copies a row's live pages a block of pages at a time, a group of rows a
+grid step). Everything with policy in it — allocation, free
 lists, reference counting, content-hashed prefix reuse, LRU eviction —
 lives HERE, on the host, and only ever runs at the serving loop's
 existing chunk boundaries (admit/retire), so the one-dispatch /

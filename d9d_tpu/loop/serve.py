@@ -126,8 +126,8 @@ admission already owns; the page table is a traced cache leaf like
 ``tracked_jit`` fingerprints are untouched (``tools/bench_compare.py``
 gates the paged leg's dispatch/readback/compile counts against the
 contiguous leg's). The flash-decode kernel gathers a row's live pages
-through the page table, a block of pages a grid step
-(``ops/attention/pallas_decode.py paged_decode_geometry``);
+through the page table, a block of pages of each of a group of rows a
+grid step (``ops/attention/pallas_decode.py paged_decode_geometry``);
 the eager path gathers a contiguous per-row view and remains the
 bitwise exactness reference — greedy paged serving is token-identical
 to the contiguous layout, prefix hit or cold.

@@ -27,10 +27,11 @@ never materializes logits. Two layout decisions follow:
 
 Paged mode (the serving loop's page pools, ``_paged_decode_call``) is
 the same arithmetic with the cache gathered through a page table: one
-grid step a batch row, the row's live pages copied a block of pages at
-a time into a double-buffered VMEM block (all kv heads of a page in one
-copy), one online-softmax update a block; pages past the row's last
-query are neither copied nor attended.
+grid step a group of batch rows, each row's live pages copied a block
+of pages at a time into a double-buffered VMEM block (all kv heads of a
+page in one copy), one online-softmax update for the group's blocks,
+the (row, kv head) pairs as the batch of its two products; pages past a
+row's last query are neither copied nor attended.
 
 Slot semantics ride positions: the cache write index ``start`` enters
 as a traced SMEM scalar, queries sit at global positions
@@ -96,8 +97,10 @@ class _DecodeConfig:
     # mode only; never combines with has_valid — the serving loop's
     # paged rows are never left-padded)
     quant: bool = False
-    # paged mode: pages a copied block holds (paged_decode_geometry)
+    # paged mode: pages a copied block holds, and batch rows a grid step
+    # attends (paged_decode_geometry)
     pages_per_step: int = 1
+    rows_per_step: int = 1
 
 
 def _decode_kernel(*refs, cfg: _DecodeConfig):
@@ -184,17 +187,38 @@ def _pad_to(n: int, m: int) -> int:
     return (-n) % m
 
 
-# Paged mode. A grid step is one batch row; inside it the row's LIVE
-# pages are copied from the pools (left in HBM) into a double-buffered
-# VMEM block of ``pages_per_step`` pages, and a block meets the online
-# softmax at once. On the chip (PERF.md, PR 33) a page operand of the
-# BlockSpec pipeline costs 0.05 us whether its page is live or not, a
-# grid step 0.2 us, and one online-softmax update 0.45 us whatever its
-# width: a step of one 16 KB page was bound by those, not by its bytes.
-# The contiguous path streams 512 positions a step too.
+# Paged mode. A grid step is a group of batch rows; inside it each row's
+# LIVE pages are copied from the pools (left in HBM) into a
+# double-buffered VMEM block of ``pages_per_step`` pages a row, and the
+# group's blocks meet the online softmax at once. On the chip (PERF.md,
+# PR 33) a page operand of the BlockSpec pipeline costs 0.05 us whether
+# its page is live or not: a step of one 16 KB page was bound by that,
+# not by its bytes. The contiguous path streams 512 positions a step too.
 PAGED_STEP_POSITIONS = 512
-# both buffers of the K and V blocks
-PAGED_VMEM_BUDGET = 4 * 1024 * 1024
+# A grid step and its one online-softmax update (a chain of dependent
+# operations: product, row maximum, exponential, row sum, product,
+# rescale) cost 0.54 us together however many rows they serve, and a row
+# 0.30 us of its own with one live page (two transfers started and waited
+# for, its scalars): 256 rows of 2 kv heads of 128 + 128 with one live
+# page each take 0.84 / 0.55 / 0.42 / 0.36 us a row at 1 / 2 / 4 / 8 rows
+# a step, and at a serving table's contexts (mean 351 positions) 1.22 /
+# 0.96 / 0.83 / 0.80 us where the copied bytes are 0.48 (PERF.md, PR 57:
+# the calls timed alone). A group is the largest power of two up to
+# ``PAGED_STEP_ROWS`` that meets two bounds, both from that timing.
+# Bytes: both buffers of the group's K and V blocks within
+# ``PAGED_VMEM_BUDGET``; every shape timed past it was slower than at
+# half the group (8 kv heads of 128 + 128: 637 us a call at 2 rows and
+# 8 MiB, 662 at 4 and 16 MiB; 4 of 256 + 128: 510 at 6 MiB, 520 at 12),
+# and the call stays inside the 16 MiB of VMEM a kernel has unasked (it
+# claims the buffers and a twentieth more: the float32 casts stay in
+# registers). Width: ``PAGED_STEP_WIDTH`` score rows an update (group x
+# kv heads x padded query rows); the gain is all there at 128 (two kv
+# heads of 16 rows: 224 us at 4 rows a step and at 8; one of 64: 266 /
+# 264 / 267 at 128 / 256 / 512), nothing timed lost by 512, and at 256
+# every shape timed has its best group (one kv head of 24 rows takes 8).
+PAGED_VMEM_BUDGET = 8 * 1024 * 1024
+PAGED_STEP_ROWS = 8
+PAGED_STEP_WIDTH = 256
 
 
 def window_pages(window: int, page_size: int) -> int:
@@ -210,27 +234,36 @@ def window_pages(window: int, page_size: int) -> int:
 class PagedDecodeGeometry:
     """The tiling :func:`_paged_decode_call` runs for given shapes."""
 
-    pages_per_step: int      # pages a copied block holds
-    grid: tuple[int, ...]    # one step a batch row
+    pages_per_step: int      # pages a copied block of one row holds
+    rows_per_step: int       # batch rows a grid step attends
+    grid: tuple[int, ...]    # one step a group of rows
     vmem_bytes: int          # both buffers of the K and V blocks
 
 
 def paged_decode_geometry(
     *, batch: int, kv_heads: int, n_pages: int, page_size: int,
     head_dim: int, kv_itemsize: int, v_head_dim: int | None = None,
-    window: int | None = None,
+    window: int | None = None, query_rows: int = 1,
 ) -> PagedDecodeGeometry:
-    """``pages_per_step`` and the grid, from the shapes alone.
+    """``pages_per_step``, ``rows_per_step`` and the grid, from the
+    shapes alone.
 
     A copied page holds ALL kv heads (the pool is ``[P, Hkv, page, D]``:
     a page's heads are one contiguous slab), so the grid has no kv head
-    dimension, and a row's blocks are a loop inside its one grid step,
-    over the live ones only. A block takes enough pages to cover
+    dimension, and a row's blocks are a loop inside its grid step, over
+    the live ones only. A block takes enough pages to cover
     ``PAGED_STEP_POSITIONS`` key positions (8 pages of 64), at most the
-    row's pages, and fewer until both buffers of the K and V blocks fit
-    ``PAGED_VMEM_BUDGET`` (one page of every kv head is the least).
-    Under a ``window`` a row never has more live pages than the window
-    and the query's own page span, and a block takes no more.
+    row's pages, and fewer until both buffers of the K and V blocks of
+    one row fit ``PAGED_VMEM_BUDGET`` (one page of every kv head is the
+    least). Under a ``window`` a row never has more live pages than the
+    window and the query's own page span, and a block takes no more.
+
+    A grid step attends the largest group of ``PAGED_STEP_ROWS`` rows, a
+    power of two, whose buffers fit the same budget and whose update is
+    no wider than ``PAGED_STEP_WIDTH`` score rows (``query_rows``, the
+    ``g x T`` queries a kv head, padded to sublanes, times the kv heads,
+    times the group); a batch it does not divide is padded with dead
+    rows.
     """
     width = head_dim + (head_dim if v_head_dim is None else v_head_dim)
     page_bytes = kv_heads * page_size * width * kv_itemsize  # K and V
@@ -239,30 +272,44 @@ def paged_decode_geometry(
         pps = min(pps, window_pages(window, page_size))
     while pps > 1 and 2 * pps * page_bytes > PAGED_VMEM_BUDGET:
         pps -= 1
+    row_bytes = 2 * pps * page_bytes
+    row_width = kv_heads * (query_rows + _pad_to(query_rows, 8))
+    group = PAGED_STEP_ROWS
+    while group > 1 and (group * row_bytes > PAGED_VMEM_BUDGET
+                         or group * row_width > PAGED_STEP_WIDTH):
+        group //= 2
     return PagedDecodeGeometry(
-        pages_per_step=pps, grid=(batch,), vmem_bytes=2 * pps * page_bytes,
+        pages_per_step=pps, rows_per_step=group, grid=(-(-batch // group),),
+        vmem_bytes=group * row_bytes,
     )
 
 
 def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
                          cfg: _DecodeConfig, n_pages: int):
-    """Grid step ``bi`` attends row ``bi``: all kv heads, the row's live
-    pages only (from the window's floor to the last query's page), a
-    block of ``cfg.pages_per_step`` pages at a time. While a block is
-    attended the next one (the row's, or the next row's first) is on
-    its way into the other buffer. Same arithmetic as
-    :func:`_decode_kernel`: one online-softmax update a block of keys,
-    in position order; a block's pages that were not copied are past
-    the last query and masked by position."""
+    """Grid step ``gi`` attends rows ``gi * R .. gi * R + R - 1``
+    (``R = cfg.rows_per_step``): all kv heads, each row's live pages only
+    (from the window's floor to the last query's page), a block of
+    ``cfg.pages_per_step`` pages a row at a time. Iteration ``ib`` waits
+    for block ``ib`` of every row that has one and runs ONE online-softmax
+    update over the ``R x Hkv`` (row, head) pairs as the batch dimension
+    of the two products; while it runs, iteration ``ib + 1`` of the same
+    rows (or block 0 of the next group's rows) is on its way into the
+    other buffer. A row whose live pages ended before ``ib`` sits the
+    update out by the position mask: its block's first key lies past its
+    last query, so ``p = 0``, ``m`` stays and ``alpha = 1``, whatever
+    (finite) rows its buffer still holds. Same arithmetic as
+    :func:`_decode_kernel`: one update a block of keys, in position
+    order; a block's pages that were not copied are past the last query
+    and masked by position."""
     ks_ref = vs_ref = None
     if cfg.quant:
         (ks_ref, vs_ref), refs = refs[:2], refs[2:]
     pools, (o_ref, lse_ref), bufs = refs[:2], refs[2:4], refs[4:6]
     sems, slot_ref, m_ref, l_ref, acc_ref = refs[6:]
-    page, pps = cfg.block_kv, cfg.pages_per_step
+    page, pps, group = cfg.block_kv, cfg.pages_per_step, cfg.rows_per_step
     block = pps * page
-    bi = pl.program_id(0)
-    n_rows = pl.num_programs(0)
+    gi = pl.program_id(0)
+    n_groups = pl.num_programs(0)
 
     def live_pages(row):
         """(first live page, live pages) of a row: pages wholly past the
@@ -278,20 +325,20 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
             first = floor // block * pps if cfg.quant else floor // page
         return first, last - first + 1
 
-    def page_copy(pool, buf, pid, j, slot, i):
+    def page_copy(pool, buf, pid, j, slot, r, i):
         return pltpu.make_async_copy(
             pool.at[pid],
-            buf.at[slot, :, pl.ds(pl.multiple_of(j * page, page), page), :],
+            buf.at[slot, r, :, pl.ds(pl.multiple_of(j * page, page), page), :],
             sems.at[slot, i],
         )
 
-    def start_copies(row, first_page, count, slot):
-        """``count`` pages of ``row`` from ``first_page`` on, into
-        buffer ``slot``."""
+    def start_copies(row, r, first_page, count, slot):
+        """``count`` pages of ``row`` from ``first_page`` on, into row
+        ``r`` of buffer ``slot``."""
         def one_page(j, carry):
             pid = pt_ref[row * n_pages + first_page + j]
             for i, (pool, buf) in enumerate(zip(pools, bufs)):
-                page_copy(pool, buf, pid, j, slot, i).start()
+                page_copy(pool, buf, pid, j, slot, r, i).start()
             return carry
 
         jax.lax.fori_loop(0, count, one_page, None)
@@ -299,99 +346,128 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
     def wait_copies(count, slot):
         def one_page(j, carry):
             for i, (pool, buf) in enumerate(zip(pools, bufs)):
-                # a wait counts a page's bytes, whichever page they were
-                page_copy(pool, buf, 0, 0, slot, i).wait()
+                # a wait counts a page's bytes, whichever page and row
+                # they were: the group's copies share a buffer's semaphore
+                page_copy(pool, buf, 0, 0, slot, 0, i).wait()
             return carry
 
         jax.lax.fori_loop(0, count, one_page, None)
 
-    first, n_live = live_pages(bi)
-    n_blocks = pl.cdiv(n_live, pps)
-    nxt_row = jnp.minimum(bi + 1, n_rows - 1)
-    nxt_row_first, nxt_row_live = live_pages(nxt_row)
+    def block_pages(live, ib):
+        """Pages of a row's block ``ib``: none past its live pages."""
+        return jnp.clip(live - ib * pps, 0, pps)
 
-    @pl.when(bi == 0)
-    def _first_row():
+    rows = [gi * group + r for r in range(group)]
+    firsts, lives = zip(*(live_pages(row) for row in rows))
+    n_iter = functools.reduce(
+        jnp.maximum, [pl.cdiv(live, pps) for live in lives])
+    # the next group's rows; after the last group none, with no pages
+    nxt_rows = [jnp.minimum(gi + 1, n_groups - 1) * group + r
+                for r in range(group)]
+    nxt_firsts, nxt_lives = zip(*(live_pages(row) for row in nxt_rows))
+    nxt_lives = [jnp.where(gi + 1 < n_groups, live, 0) for live in nxt_lives]
+
+    @pl.when(gi == 0)
+    def _first_group():
         slot_ref[0] = 0
-        # a buffer's unwritten tail is masked by position, but 0 x NaN
-        # in the value dot is NaN: no buffer starts with arbitrary bits
+        # a buffer's unwritten tail, and the whole buffer of a row that
+        # sits an update out, is masked by position, but 0 x NaN in the
+        # value dot is NaN: no buffer starts with arbitrary bits
         bufs[1][...] = jnp.zeros_like(bufs[1])
-        start_copies(0, first, jnp.minimum(n_live, pps), 0)
+        for r in range(group):
+            start_copies(rows[r], r, firsts[r], block_pages(lives[r], 0), 0)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_BIG)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    start = offs_ref[bi]
+    starts = [offs_ref[row] for row in rows]
+    hkv, rp = q_ref.shape[1], q_ref.shape[2]
 
-    def attend(slot, k_lo):
-        q = q_ref[0].astype(jnp.float32)       # [Hkv, rows_pad, D]
-        k = bufs[0][slot].astype(jnp.float32)  # [Hkv, block, D]
-        v = bufs[1][slot].astype(jnp.float32)
+    def heads_as_batch(x):  # [R, Hkv, a, b] -> [R x Hkv, a, b]
+        return x.reshape(group * hkv, *x.shape[2:])
+
+    def scale_rows(ref, ib):
+        # a sat-out row's block index may lie past its gathered rows:
+        # any row of finite scales does for scores that are masked
+        last = ref.shape[2] - 1
+        return jnp.stack([
+            ref[r, :, pl.ds(jnp.minimum(firsts[r] // pps + ib, last), 1), :]
+            for r in range(group)
+        ])  # [R, Hkv, 1, block]
+
+    def attend(slot, ib):
+        q = heads_as_batch(q_ref[...].astype(jnp.float32))   # [., rows_pad, D]
+        k = heads_as_batch(bufs[0][slot].astype(jnp.float32))  # [., block, D]
+        v = heads_as_batch(bufs[1][slot].astype(jnp.float32))
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * cfg.scale  # [Hkv, rows_pad, block]
+        ) * cfg.scale  # [R x Hkv, rows_pad, block]
+        s = s.reshape(group, hkv, rp, block)
         if cfg.quant:
             # int8 keys: a slot's scale multiplies its column of
             # scores (row [Hkv, 1, block] of the row's gathered scales),
             # in float32 like the rest; the values' scales meet p below
-            s = s * ks_ref[0, :, pl.ds(k_lo // block, 1), :]
+            s = s * scale_rows(ks_ref, ib)
 
-        rp = s.shape[1]
         row = jax.lax.broadcasted_iota(jnp.int32, (rp, block), 0)
-        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (rp, block), 1)
+        key = jax.lax.broadcasted_iota(jnp.int32, (rp, block), 1)
         # row r = (head-in-group, token i) flattened as ig·T + i
-        q_pos = start + jax.lax.rem(row, cfg.t)
-        mask = (k_pos < cfg.s_len) & (k_pos <= q_pos) & (row < cfg.rows)
-        if cfg.window is not None:
-            mask &= k_pos > q_pos - cfg.window
-        mask = mask[None]
+        token = jax.lax.rem(row, cfg.t)
+        masks = []
+        for r in range(group):
+            k_pos = (firsts[r] + ib * pps) * page + key
+            q_pos = starts[r] + token
+            mask = (k_pos < cfg.s_len) & (k_pos <= q_pos) & (row < cfg.rows)
+            if cfg.window is not None:
+                mask &= k_pos > q_pos - cfg.window
+            masks.append(mask)
+        mask = jnp.stack(masks)[:, None]  # [R, 1, rows_pad, block]
         s = jnp.where(mask, s, NEG_BIG)
 
-        m_prev = m_ref[:, :, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+        m_prev = m_ref[..., :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=3, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         # gated by the mask, not just the sentinel (see _decode_kernel):
         # fully masked rows keep l at 0 and finalize to exact zeros
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_ref[:, :, :1] + p.sum(axis=2, keepdims=True)
+        l_new = alpha * l_ref[..., :1] + p.sum(axis=3, keepdims=True)
         if cfg.quant:
-            p = p * vs_ref[0, :, pl.ds(k_lo // block, 1), :]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
+            p = p * scale_rows(vs_ref, ib)
+        pv = jax.lax.dot_general(
+            heads_as_batch(p), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
+        acc_ref[...] = acc_ref[...] * alpha + pv.reshape(acc_ref.shape)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    def attend_block(ib, carry):
+    def attend_blocks(ib, carry):
         slot = slot_ref[0]
-        block_first = first + ib * pps
-        count = jnp.minimum(n_live - ib * pps, pps)
-        # the block after this one: the row's next, else the next row's first
-        in_row = ib + 1 < n_blocks
-        nxt_first = jnp.where(in_row, block_first + pps, nxt_row_first)
-        nxt_count = jnp.minimum(
-            jnp.where(in_row, n_live - (ib + 1) * pps, nxt_row_live), pps
-        )
+        # the iteration after this one: the group's next blocks, else
+        # the next group's first
+        in_group = ib + 1 < n_iter
+        for r in range(group):
+            start_copies(
+                jnp.where(in_group, rows[r], nxt_rows[r]), r,
+                jnp.where(in_group, firsts[r] + (ib + 1) * pps, nxt_firsts[r]),
+                jnp.where(in_group, block_pages(lives[r], ib + 1),
+                          block_pages(nxt_lives[r], 0)),
+                1 - slot,
+            )
 
-        @pl.when(in_row | (bi + 1 < n_rows))
-        def _prefetch():
-            start_copies(jnp.where(in_row, bi, nxt_row), nxt_first,
-                         nxt_count, 1 - slot)
+        wait_copies(sum(block_pages(live, ib) for live in lives), slot)
 
-        wait_copies(count, slot)
-
-        attend(slot, block_first * page)
+        attend(slot, ib)
         slot_ref[0] = 1 - slot
         return carry
 
-    jax.lax.fori_loop(0, n_blocks, attend_block, None)
+    jax.lax.fori_loop(0, n_iter, attend_blocks, None)
 
-    m = m_ref[:, :, :1]
-    l = l_ref[:, :, :1]
-    o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
+    m = m_ref[..., :1]
+    l = l_ref[..., :1]
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    lse_ref[...] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 # d9d-lint: disable=D9D001 — standalone-use decorator; serving traces this inside the tracked serve/step program (a TrackedJit cannot be called under a trace)
@@ -403,10 +479,12 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     ``page_table [B, n_pages]`` → same outputs as :func:`_decode_call`
     on the contiguous equivalent: a different INDEX, not a different
     algorithm. The pools stay in HBM; the kernel copies a row's live
-    pages itself, ``cfg.pages_per_step`` a block
+    pages itself, ``cfg.pages_per_step`` a block and
+    ``cfg.rows_per_step`` rows a grid step
     (:func:`paged_decode_geometry`), so a page costs a copy only while
-    it is live and a grid step moves a block of pages, not one.
-    ``cfg.block_kv`` is the page size.
+    it is live, a grid step moves a block of pages of several rows, and
+    one online-softmax update serves them all. ``cfg.block_kv`` is the
+    page size.
 
     ``cfg.quant``: k/v pools are int8 and ``k/v_scale [P, Hkv, ps]``
     carry the per-slot dequantization scales. A row's scales are
@@ -416,63 +494,74 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     b, hkv, rp, d = q_rows.shape
     dv = v_pool.shape[-1]
     n_pages = page_table.shape[1]
-    block = cfg.pages_per_step * cfg.block_kv
+    pps, group = cfg.pages_per_step, cfg.rows_per_step
+    block = pps * cfg.block_kv
+    dead = _pad_to(b, group)
+    if dead:
+        # dead rows fill the last group: position 0 of table row 0's
+        # first page, the garbage page, as idle slots are
+        q_rows = jnp.pad(q_rows, ((0, dead), (0, 0), (0, 0), (0, 0)))
+        offsets = jnp.pad(offsets, (0, dead))
+        page_table = jnp.pad(page_table, ((0, dead), (0, 0)))
+    rows = b + dead
 
     scale_specs, scale_rows = [], ()
     if cfg.quant:
         # a row's scales, gathered here: [B, Hkv, blocks, block], one
         # lane-dense row a block of keys (3 % of the int8 bytes)
-        pps = cfg.pages_per_step
         n_blocks = pl.cdiv(n_pages, pps)
         table = jnp.pad(page_table, ((0, 0), (0, n_blocks * pps - n_pages)))
 
-        def rows(scale):  # [P, Hkv, page] -> [B, Hkv, blocks, block]
-            g = scale[table].reshape(b, n_blocks, pps, hkv, cfg.block_kv)
-            return g.transpose(0, 3, 1, 2, 4).reshape(b, hkv, n_blocks, block)
+        def gathered(scale):  # [P, Hkv, page] -> [B, Hkv, blocks, block]
+            g = scale[table].reshape(rows, n_blocks, pps, hkv, cfg.block_kv)
+            return g.transpose(0, 3, 1, 2, 4).reshape(
+                rows, hkv, n_blocks, block)
 
-        scale_rows = (rows(k_scale), rows(v_scale))
+        scale_rows = (gathered(k_scale), gathered(v_scale))
         scale_specs = [pl.BlockSpec(
-            (1, hkv, n_blocks, block), lambda bi, offs, pt: (bi, 0, 0, 0),
+            (group, hkv, n_blocks, block), lambda gi, offs, pt: (gi, 0, 0, 0),
         )] * 2
 
-    def row_spec(width):
-        return pl.BlockSpec((1, hkv, rp, width),
-                            lambda bi, offs, pt: (bi, 0, 0, 0))
+    def group_spec(width):
+        return pl.BlockSpec((group, hkv, rp, width),
+                            lambda gi, offs, pt: (gi, 0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # offsets, the page table (flat: SMEM pads rows)
-        grid=(b,),
-        in_specs=[row_spec(d), *scale_specs]
+        grid=(rows // group,),
+        in_specs=[group_spec(d), *scale_specs]
         + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
-        out_specs=[row_spec(dv), row_spec(1)],
+        out_specs=[group_spec(dv), group_spec(1)],
         scratch_shapes=[
-            pltpu.VMEM((2, hkv, block, d), k_pool.dtype),
-            pltpu.VMEM((2, hkv, block, dv), v_pool.dtype),
+            pltpu.VMEM((2, group, hkv, block, d), k_pool.dtype),
+            pltpu.VMEM((2, group, hkv, block, dv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),  # the buffer the next block waits on
-            pltpu.VMEM((hkv, rp, LANES), jnp.float32),
-            pltpu.VMEM((hkv, rp, LANES), jnp.float32),
-            pltpu.VMEM((hkv, rp, dv), jnp.float32),
+            pltpu.VMEM((group, hkv, rp, LANES), jnp.float32),
+            pltpu.VMEM((group, hkv, rp, LANES), jnp.float32),
+            pltpu.VMEM((group, hkv, rp, dv), jnp.float32),
         ],
     )
-    o, lse = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, cfg=cfg, n_pages=n_pages),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rp, dv), q_rows.dtype),
-            jax.ShapeDtypeStruct((b, hkv, rp, 1), jnp.float32),
-        ],
-        # rows in order: a row starts the next row's first copies
-        compiler_params=(
-            None if cfg.interpret else pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)
-            )
-        ),
-        interpret=cfg.interpret,
-        # the name holds the geometry: a trace says which tiling ran
-        name=f"paged_decode_p{cfg.pages_per_step}",
-    )(offsets, page_table.reshape(-1), q_rows, *scale_rows, k_pool, v_pool)
-    return o, lse[..., 0]
+    # the scope holds the rows a grid step attends and the name the pages
+    # a block: a trace says which tiling ran
+    with jax.named_scope(f"paged_decode_r{group}"):
+        o, lse = pl.pallas_call(
+            functools.partial(_paged_decode_kernel, cfg=cfg, n_pages=n_pages),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((rows, hkv, rp, dv), q_rows.dtype),
+                jax.ShapeDtypeStruct((rows, hkv, rp, 1), jnp.float32),
+            ],
+            # groups in order: a group starts the next group's first copies
+            compiler_params=(
+                None if cfg.interpret else pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",)
+                )
+            ),
+            interpret=cfg.interpret,
+            name=f"paged_decode_p{pps}",
+        )(offsets, page_table.reshape(-1), q_rows, *scale_rows, k_pool, v_pool)
+    return o[:b], lse[:b, ..., 0]
 
 
 # Paged append. A decode step's new key and value rows, one a batch row
@@ -710,10 +799,12 @@ def flash_decode_attention(
 
     PAGED mode (``page_table [B, n_pages]`` set): ``k/v`` are page
     POOLS ``[P, Hkv, page_size, D]`` and row ``b``'s logical page ``p``
-    lives in pool page ``page_table[b, p]``. A grid step is one row:
-    it copies the row's live pages, a block of pages at a time
-    (:func:`paged_decode_geometry`; ``block_kv`` does not apply), all
-    kv heads of a page in one copy, one online-softmax update a block.
+    lives in pool page ``page_table[b, p]``. A grid step is a group of
+    rows (:func:`paged_decode_geometry`: 8, 4, 2 or 1 by the shapes;
+    ``block_kv`` does not apply): it copies each row's live pages, a
+    block of pages at a time, all kv heads of a page in one copy, and
+    runs one online-softmax update for the group's blocks; a row's
+    result is what it is with one row a step.
     Everything else — per-row ``start``, pages past the row's last
     query never touched, windows, sinks, the online softmax — is
     unchanged. ``kv_valid`` does not compose with paging (the serving
@@ -767,7 +858,7 @@ def flash_decode_attention(
         geo = paged_decode_geometry(
             batch=b, kv_heads=hkv, n_pages=n_pages, page_size=page_size,
             head_dim=d, kv_itemsize=k_cache.dtype.itemsize,
-            v_head_dim=dv, window=window_size,
+            v_head_dim=dv, window=window_size, query_rows=rows,
         )
         cfg = _DecodeConfig(
             scale=softmax_scale if softmax_scale is not None else d**-0.5,
@@ -781,6 +872,7 @@ def flash_decode_attention(
             interpret=interpret,
             quant=k_scale is not None,
             pages_per_step=geo.pages_per_step,
+            rows_per_step=geo.rows_per_step,
         )
         o, lse = _paged_decode_call(
             cfg, q_rows, k_cache, v_cache, offsets,

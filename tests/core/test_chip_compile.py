@@ -248,19 +248,53 @@ def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
+def _assert_grouped_paged_call(compiled, geo, pool: str):
+    """The one paged decode call of ``compiled``: under the scope that
+    holds the rows a grid step attends and the name that holds the pages
+    a block (which ``kernel.gqa_decode_roofline`` reads it by), within
+    the VMEM a kernel has without asking, and with no copy of a ``pool``
+    (an HLO shape pattern) anywhere in the program."""
+    assert _pallas_calls(compiled) == 1
+    text = compiled.as_text()
+    (call,) = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "pallas_call" in line
+    ]
+    assert (f"paged_decode_r{geo.rows_per_step}/"
+            f"paged_decode_p{geo.pages_per_step}/pallas_call") in call
+    assert '"scoped_memory_configs":[]' in call  # no vmem_limit_bytes set
+    (used,) = re.findall(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call)
+    # the two buffers of the K and V blocks, the group's queries, results
+    # and softmax state (the float32 casts stay in registers), within the
+    # 16 MiB a kernel on this chip has unasked
+    assert geo.vmem_bytes <= int(used) <= 1.1 * geo.vmem_bytes + 2**20
+    assert int(used) <= 16 * 2**20
+    assert not re.findall(rf"= {pool}[^=\n]*\bcopy(?:-start)?\(", text)
+
+
 @pytest.mark.parametrize("pools", ["bf16", "int8"])
 @pytest.mark.parametrize(
-    "slots,hq,hkv,s",
-    [(64, HQ, HKV, 576), (256, 20, 1, 1152)],
-    ids=["qwen3-64x4x9-group8", "jamba-256x1x18-group20"],
+    "slots,hq,hkv,s,rows_per_step",
+    # rows a grid step: with bf16 pools, with int8 pools (half the bytes)
+    [(64, HQ, HKV, 576, (4, 8)), (256, 20, 1, 1152, (8, 8)),
+     (128, 32, 8, 1152, (2, 4)), (256, 64, 8, 1152, (2, 4)),
+     (256, 8, 2, 1152, (8, 8))],
+    ids=["qwen3-64x4x9-group8", "jamba-256x1x18-group20",
+         "granite-128x8x18-group4", "solar-256x8x18-group8",
+         "zaya1-256x2x18-group4"],
 )
-def test_paged_decode_at_the_serving_cells(topo, slots, hq, hkv, s, pools):
-    """The paged decode kernel at the two serving cells' geometries:
-    Qwen3-30B-A3B (64 slots, 32 query heads on 4, 9 pages of 64 a row)
-    and Jamba2-3B's attention layers (256 slots, 20 query heads on ONE
-    key/value head, 24 padded rows, 18 pages a row). One grid step a
-    row attends blocks of several pages, all kv heads of a page in one
-    copy."""
+def test_paged_decode_at_the_serving_cells(
+        topo, slots, hq, hkv, s, rows_per_step, pools):
+    """The paged decode kernel at the serving cells' geometries:
+    Qwen3-30B-A3B (64 slots, 32 query heads on 4, 9 pages of 64 a row),
+    Jamba2-3B's attention layers (256 slots, 20 query heads on ONE
+    key/value head, 24 padded rows, 18 pages a row),
+    granite-4.0-h-small's (128 slots, 32 on 8), Solar-Open2's (256
+    slots, 64 on 8) and ZAYA1-8B's latent pool (256 slots, 8 on 2). One
+    grid step a group of rows attends blocks of several pages of each,
+    all kv heads of a page in one copy."""
     from d9d_tpu.ops.attention.pallas_decode import (
         flash_decode_attention,
         paged_decode_geometry,
@@ -271,13 +305,13 @@ def test_paged_decode_at_the_serving_cells(topo, slots, hq, hkv, s, pools):
     n_pages = s // PAGE
     geo = paged_decode_geometry(
         batch=slots, kv_heads=hkv, n_pages=n_pages, page_size=PAGE,
-        head_dim=D, kv_itemsize=1 if quant else 2,
+        head_dim=D, kv_itemsize=1 if quant else 2, query_rows=hq // hkv,
     )
     assert geo.pages_per_step > 1
-    assert int(np.prod(geo.grid)) <= slots * hkv * -(
-        -n_pages // geo.pages_per_step
-    )
-    pool = sds((slots * n_pages + 1, hkv, PAGE, D), jnp.int8 if quant else BF16)
+    assert geo.rows_per_step == rows_per_step[quant]
+    assert geo.grid == (slots // geo.rows_per_step,)
+    dtype = jnp.int8 if quant else BF16
+    pool = sds((slots * n_pages + 1, hkv, PAGE, D), dtype)
     kwargs = {"page_table": sds((slots, n_pages), jnp.int32)}
     if quant:
         scale = sds((slots * n_pages + 1, hkv, PAGE), jnp.float32)
@@ -290,17 +324,21 @@ def test_paged_decode_at_the_serving_cells(topo, slots, hq, hkv, s, pools):
         sds((slots, 1, hq, D), BF16), pool, pool, sds((slots,), jnp.int32),
         **kwargs,
     ).compile()
-    assert _pallas_calls(compiled) == 1
-    # the kernel's name holds the geometry, for the traces
-    assert f"paged_decode_p{geo.pages_per_step}/pallas_call" in compiled.as_text()
-    # the int8 row scales are gathered, not the whole scale pool relaid
-    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
+    _assert_grouped_paged_call(
+        compiled, geo,
+        rf"{'s8' if quant else 'bf16'}\[{slots * n_pages + 1},{hkv},{PAGE},{D}\]",
+    )
+    # the queries' and the result's relayouts; the int8 row scales are
+    # gathered, not the whole scale pool relaid
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * slots * hq * D * 2 + 4e6)
 
 
 @pytest.mark.parametrize(
-    "hkv,window", [(4, None), (8, 128)], ids=["full-4x192", "window-8x192"]
+    "hkv,window,rows_per_step", [(4, None, 2), (8, 128, 2)],
+    ids=["full-4x192", "window-8x192"],
 )
-def test_paged_decode_at_mimo_v2_flash_widths(topo, hkv, window):
+def test_paged_decode_at_mimo_v2_flash_widths(topo, hkv, window, rows_per_step):
     """The paged decode kernel at the MiMo-V2-Flash cell's two kinds of
     layer (256 slots, 64 query heads, pages of 64): key rows of 192
     cached as 256 (whole lane tiles, so the kernel's own page copies end
@@ -321,9 +359,11 @@ def test_paged_decode_at_mimo_v2_flash_widths(topo, hkv, window):
     geo = paged_decode_geometry(
         batch=slots, kv_heads=hkv, n_pages=n_pages, page_size=PAGE,
         head_dim=dk, kv_itemsize=2, v_head_dim=dv, window=window,
+        query_rows=hq // hkv,
     )
     pages = slots * (window_pages(window, PAGE) if window else n_pages) + 1
     assert geo.pages_per_step == (3 if window else 8)
+    assert geo.rows_per_step == rows_per_step
     compiled = jax.jit(
         lambda q, k, v, start, table: flash_decode_attention(
             q, k, v, start=start, page_table=table, window_size=window,
@@ -334,8 +374,8 @@ def test_paged_decode_at_mimo_v2_flash_widths(topo, hkv, window):
         sds((pages, hkv, PAGE, dv), BF16), sds((slots,), jnp.int32),
         sds((slots, n_pages), jnp.int32),
     ).compile()
-    assert _pallas_calls(compiled) == 1
-    assert f"paged_decode_p{geo.pages_per_step}/pallas_call" in compiled.as_text()
+    _assert_grouped_paged_call(
+        compiled, geo, rf"bf16\[{pages},{hkv},{PAGE},(?:{dk}|{dv})\]")
     # the queries' relayout to [B, Hkv, g, 256] (8.4 MB) and the output's:
     # no pool is copied or relaid
     assert compiled.memory_analysis().temp_size_in_bytes < 20e6

@@ -112,19 +112,21 @@ def test_a_ring_is_the_window_and_the_page_being_written():
     assert [_cache_row_pad(d) for d in (16, 64, 128, 192, 256, 320)] \
         == [0, 0, 0, 64, 0, 64]
     # the kernel's block: a window's pages at most, the keys' and the
-    # values' own widths in the buffers
+    # values' own widths in the buffers of the rows a grid step attends
     shapes = dict(batch=256, n_pages=18, page_size=64, head_dim=256,
                   kv_itemsize=2, v_head_dim=128)
     full = paged_decode_geometry(kv_heads=4, **shapes)
     ring = paged_decode_geometry(kv_heads=8, window=128, **shapes)
     assert (full.pages_per_step, ring.pages_per_step) == (8, 3)
-    assert full.vmem_bytes == 2 * 8 * 4 * 64 * (256 + 128) * 2
-    assert ring.vmem_bytes == 2 * 3 * 8 * 64 * (256 + 128) * 2
+    assert (full.rows_per_step, ring.rows_per_step) == (2, 2)
+    assert full.vmem_bytes == 2 * 2 * 8 * 4 * 64 * (256 + 128) * 2
+    assert ring.vmem_bytes == 2 * 2 * 3 * 8 * 64 * (256 + 128) * 2
     # one head width and no window: what it was
     old = paged_decode_geometry(
         batch=64, kv_heads=4, n_pages=9, page_size=64, head_dim=128,
         kv_itemsize=2)
-    assert (old.pages_per_step, old.vmem_bytes) == (8, 2 * 8 * 2 * 4 * 64 * 128 * 2)
+    assert (old.pages_per_step, old.rows_per_step, old.vmem_bytes) == (
+        8, 4, 4 * 2 * 8 * 2 * 4 * 64 * 128 * 2)
 
 
 def test_window_positions_are_the_context_or_the_window():

@@ -215,71 +215,135 @@ _BLOCK_GEOMETRIES = {
     "one-block": ((16, 4, 512), 4),     # every page in one block
     "qwen3-9x64": ((64, 9, 512), 8),    # the Qwen3 serving cell: 8 + 1
     "jamba-18x64": ((64, 18, 512), 8),  # the Jamba serving cell: 8 + 8 + 2
+    "groups-4+4+3": ((8, 11, 32), 4),   # rows of 1, 2 and 3 blocks a group
 }
-_GROUPS = {"g8-on-4": (32, 4), "g20-on-1": (20, 1)}
+_GROUPS = {"g8-on-4": (32, 4), "g20-on-1": (20, 1),
+           "g8-on-2": (8, 2), "g8-on-8": (8, 8)}
+
+
+def _with_rows_per_step(cases, grouped):
+    """The cases a test had, one row a grid step, and ``grouped``: cases
+    of the geometry ``groups-4+4+3`` that end in the rows a grid step
+    attends."""
+    old = [g for g in _BLOCK_GEOMETRIES if g != "groups-4+4+3"]
+    return [(g, *rest, 1) for g in old for rest in cases] + [
+        ("groups-4+4+3", *case) for case in grouped
+    ]
 
 
 def _block_case(monkeypatch, geometry, heads, t, d=16, seed=0):
-    """q, a contiguous cache and five rows' starts that meet every edge
+    """q, a contiguous cache and the rows' starts that meet every edge
     of the block geometry: position 0, a row that ends inside a page
     with fewer live pages than one block, the last position of block 0,
-    the first of block 1 (a block's edge), and every page live."""
+    the first of block 1 (a block's edge), and every page live. The
+    geometry ``groups-4+4+3`` has seven rows (no multiple of a group)
+    in an order that puts rows of one, two and three live blocks, and a
+    row at position 0, into one grid step at 2, 4 and 8 rows a step;
+    its row 3 is the one a caller makes a dead row."""
     (ps, n_pages, block), want = _BLOCK_GEOMETRIES[geometry]
     monkeypatch.setattr(pallas_decode, "PAGED_STEP_POSITIONS", block)
     hq, hkv = _GROUPS[heads]
     s = ps * n_pages
-    geo = paged_decode_geometry(
-        batch=5, kv_heads=hkv, n_pages=n_pages, page_size=ps, head_dim=d,
-        kv_itemsize=4,
-    )
-    pps = geo.pages_per_step
-    assert pps == want
-    assert geo.grid == (5,)
+    pps = want
     edge = min(pps * ps, s - t)
-    starts = jnp.asarray(
-        [0, ps + 3, max(edge - t, 0), edge, s - t], jnp.int32
+    if geometry == "groups-4+4+3":
+        starts = [0, edge + 5, 2 * edge + 3, 0, ps + 3, s - t, 2 * edge - t]
+    else:
+        starts = [0, ps + 3, max(edge - t, 0), edge, s - t]
+    geo = paged_decode_geometry(
+        batch=len(starts), kv_heads=hkv, n_pages=n_pages, page_size=ps,
+        head_dim=d, kv_itemsize=4,
     )
-    q, k, v = _mk(5, t, hq, hkv, d, s, seed=seed)
-    return q, k, v, starts, ps
+    assert geo.pages_per_step == want
+    assert geo.grid == (-(-len(starts) // geo.rows_per_step),)
+    q, k, v = _mk(len(starts), t, hq, hkv, d, s, seed=seed)
+    return q, k, v, jnp.asarray(starts, jnp.int32), ps
 
 
-def _assert_paged_equals_contiguous(q, k, v, starts, ps, **kwargs):
-    want = flash_decode_attention(
-        q, k, v, start=starts, interpret=True, block_kv=ps, **kwargs
-    )
-    pool_k, pool_v, pt = _paginate(k, v, ps, seed=4)
-    got = flash_decode_attention(
-        q, pool_k, pool_v, start=starts, page_table=pt, interpret=True,
-        **kwargs,
-    )
+_DEAD_ROW = 3  # of the geometry "groups-4+4+3"
+
+
+def _assert_paged_equals_contiguous(monkeypatch, rows_per_step, q, pools,
+                                    want, starts, **kwargs):
+    """``pools = (pool_k, pool_v, table)`` attended ``rows_per_step``
+    rows a grid step against ``want``, the contiguous call's result on
+    the gathered view; a group of several rows also bit for bit against
+    one row a step, and with row ``_DEAD_ROW`` dead: at position 0 of a
+    table row of zeros, the garbage page, which holds finite numbers no
+    live row may see."""
+    pool_k, pool_v, pt = pools
+    live = np.ones(q.shape[0], bool)
+    if rows_per_step > 1:
+        live[_DEAD_ROW] = False
+        pt = pt.at[_DEAD_ROW].set(0)
+        pool_k = pool_k.at[0].set(jnp.asarray(3, pool_k.dtype))
+        pool_v = pool_v.at[0].set(jnp.asarray(-5, pool_v.dtype))
+
+    def paged(rows):
+        monkeypatch.setattr(pallas_decode, "PAGED_STEP_ROWS", rows)
+        return np.asarray(flash_decode_attention(
+            q, pool_k, pool_v, start=starts, page_table=pt, interpret=True,
+            **kwargs,
+        ))
+
+    got = paged(rows_per_step)
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+        got[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5
+    )
+    if rows_per_step > 1:
+        np.testing.assert_array_equal(got, paged(1))
+
+
+@pytest.mark.parametrize(
+    "geometry,heads,window,t,rows_per_step",
+    _with_rows_per_step(
+        [(h, w, t) for h in ("g8-on-4", "g20-on-1") for w in (None, 7)
+         for t in (1, 3)],
+        [("g8-on-2", None, 1, 4), ("g8-on-2", 7, 3, 2),
+         ("g20-on-1", None, 3, 8), ("g20-on-1", 7, 1, 4),
+         ("g8-on-8", None, 1, 2), ("g8-on-8", 7, 3, 8)],
+    ),
+)
+def test_paged_block_parity(monkeypatch, geometry, heads, window, t,
+                            rows_per_step):
+    """A grid step attends a block of pages of each row of a group: the
+    paged result equals the contiguous call's on the gathered view, over
+    the geometries the rule produces and the rows that meet their edges,
+    and a group's rows read what they read alone."""
+    q, k, v, starts, ps = _block_case(monkeypatch, geometry, heads, t)
+    want = flash_decode_attention(
+        q, k, v, start=starts, interpret=True, block_kv=ps,
+        window_size=window,
+    )
+    _assert_paged_equals_contiguous(
+        monkeypatch, rows_per_step, q, _paginate(k, v, ps, seed=4), want,
+        starts, window_size=window,
     )
 
 
-@pytest.mark.parametrize("t", [1, 3])
-@pytest.mark.parametrize("window", [None, 7])
-@pytest.mark.parametrize("heads", list(_GROUPS))
-@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
-def test_paged_block_parity(monkeypatch, geometry, heads, window, t):
-    """A grid step attends a block of pages: the paged result equals the
-    contiguous call's on the gathered view, over the geometries the rule
-    produces and the rows that meet their edges."""
-    q, k, v, starts, ps = _block_case(monkeypatch, geometry, heads, t)
-    _assert_paged_equals_contiguous(q, k, v, starts, ps, window_size=window)
-
-
-@pytest.mark.parametrize("heads", list(_GROUPS))
-@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
-def test_paged_block_parity_sinks(monkeypatch, geometry, heads):
+@pytest.mark.parametrize(
+    "geometry,heads,rows_per_step",
+    _with_rows_per_step(
+        [("g8-on-4",), ("g20-on-1",)], [("g8-on-2", 4)],
+    ),
+)
+def test_paged_block_parity_sinks(monkeypatch, geometry, heads, rows_per_step):
     q, k, v, starts, ps = _block_case(monkeypatch, geometry, heads, 1, seed=2)
     sinks = jnp.asarray(np.random.RandomState(5).randn(q.shape[2]), jnp.float32)
-    _assert_paged_equals_contiguous(q, k, v, starts, ps, sinks=sinks)
+    want = flash_decode_attention(
+        q, k, v, start=starts, interpret=True, block_kv=ps, sinks=sinks,
+    )
+    _assert_paged_equals_contiguous(
+        monkeypatch, rows_per_step, q, _paginate(k, v, ps, seed=4), want,
+        starts, sinks=sinks,
+    )
 
 
-@pytest.mark.parametrize("window", [None, 7])
-@pytest.mark.parametrize("geometry", list(_BLOCK_GEOMETRIES))
-def test_paged_block_parity_int8(monkeypatch, geometry, window):
+@pytest.mark.parametrize(
+    "geometry,window,rows_per_step",
+    _with_rows_per_step([(None,), (7,)], [(None, 2), (7, 8)]),
+)
+def test_paged_block_parity_int8(monkeypatch, geometry, window, rows_per_step):
     """int8 pools with scale pages gathered by the same rule: the
     kernel's in-VMEM ``int8 * scale`` equals the contiguous call on the
     widened view."""
@@ -298,13 +362,10 @@ def test_paged_block_parity_int8(monkeypatch, geometry, window):
     pool_k, pool_v, pt = _paginate(k8, v8, ps, seed=8)
     # a scale pool is a pool of [Hkv, page, 1] pages under the same table
     pool_ks, pool_vs, _ = _paginate(ks[..., None], vs[..., None], ps, seed=8)
-    got = flash_decode_attention(
-        q, pool_k.astype(jnp.int8), pool_v.astype(jnp.int8), start=starts,
-        window_size=window, page_table=pt, k_scale=pool_ks[..., 0],
-        v_scale=pool_vs[..., 0], interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+    _assert_paged_equals_contiguous(
+        monkeypatch, rows_per_step, q,
+        (pool_k.astype(jnp.int8), pool_v.astype(jnp.int8), pt), want, starts,
+        window_size=window, k_scale=pool_ks[..., 0], v_scale=pool_vs[..., 0],
     )
 
 
@@ -383,37 +444,57 @@ def test_paged_append_writes_the_scatters_bits(dtype, heads, dk, dv, ps, table):
 
 
 @pytest.mark.parametrize(
-    "shapes,pages_per_step",
+    "shapes,pages_per_step,rows_per_step",
     [
-        # the two serving cells: blocks of 8 pages of 64
-        (dict(batch=64, kv_heads=4, n_pages=9, page_size=64), 8),
-        (dict(batch=256, kv_heads=1, n_pages=18, page_size=64), 8),
         # tiny pages still cover 512 positions a block
-        (dict(batch=4, kv_heads=2, n_pages=6, page_size=8), 6),
-        (dict(batch=4, kv_heads=2, n_pages=100, page_size=16), 32),
-        # a long row; 8 kv heads are 4 MiB exactly
-        (dict(batch=8, kv_heads=4, n_pages=512, page_size=64), 8),
-        (dict(batch=8, kv_heads=8, n_pages=512, page_size=64), 8),
-        # float32 pools: the VMEM budget cuts the block
+        (dict(batch=4, kv_heads=2, n_pages=6, page_size=8), 6, 8),
+        (dict(batch=4, kv_heads=2, n_pages=100, page_size=16), 32, 8),
+        # a long row; 8 kv heads are 4 MiB a row, two rows the budget
+        (dict(batch=8, kv_heads=4, n_pages=512, page_size=64), 8, 4),
+        (dict(batch=8, kv_heads=8, n_pages=512, page_size=64), 8, 2),
+        # float32 pools: 8 kv heads are the budget, 32 cut the block
         (dict(batch=8, kv_heads=8, n_pages=64, page_size=64,
-              kv_itemsize=4), 4),
+              kv_itemsize=4), 8, 1),
         (dict(batch=8, kv_heads=32, n_pages=64, page_size=64,
-              kv_itemsize=4), 1),
+              kv_itemsize=4), 2, 1),
         # pages of 256: two a block
-        (dict(batch=8, kv_heads=2, n_pages=16, page_size=256), 2),
+        (dict(batch=8, kv_heads=2, n_pages=16, page_size=256), 2, 8),
+        # a batch the group does not divide: its last group has dead rows
+        (dict(batch=13, kv_heads=2, n_pages=18, page_size=64), 8, 8),
+        # the serving cells. Qwen3-30B-A3B: 32 query heads on 4
+        (dict(batch=64, kv_heads=4, n_pages=9, page_size=64,
+              query_rows=8), 8, 4),
+        # Jamba2-3B: 20 on ONE kv head, 24 padded rows
+        (dict(batch=256, kv_heads=1, n_pages=18, page_size=64,
+              query_rows=20), 8, 8),
+        # MiMo-V2-Flash: 64 on 4 full, 64 on 8 under a window of 128,
+        # key rows of 256 and value rows of 128
+        (dict(batch=256, kv_heads=4, n_pages=18, page_size=64, head_dim=256,
+              v_head_dim=128, query_rows=16), 8, 2),
+        (dict(batch=256, kv_heads=8, n_pages=18, page_size=64, head_dim=256,
+              v_head_dim=128, window=128, query_rows=8), 3, 2),
+        # granite-4.0-h-small: 32 on 8; Solar-Open2: 64 on 8
+        (dict(batch=128, kv_heads=8, n_pages=18, page_size=64,
+              query_rows=4), 8, 2),
+        (dict(batch=256, kv_heads=8, n_pages=18, page_size=64,
+              query_rows=8), 8, 2),
+        # ZAYA1-8B's latent pool: 8 on 2
+        (dict(batch=256, kv_heads=2, n_pages=18, page_size=64,
+              query_rows=4), 8, 8),
     ],
 )
-def test_paged_geometry_from_shapes(shapes, pages_per_step):
+def test_paged_geometry_from_shapes(shapes, pages_per_step, rows_per_step):
     shapes = {"head_dim": 128, "kv_itemsize": 2, **shapes}
     geo = paged_decode_geometry(**shapes)
     assert geo.pages_per_step == pages_per_step
-    # one grid step a row, whatever its pages: never more than a step a
-    # block of every kv head
-    assert geo.grid == (shapes["batch"],)
-    assert math.prod(geo.grid) <= shapes["batch"] * shapes["kv_heads"] * -(
-        -shapes["n_pages"] // geo.pages_per_step
-    )
+    assert geo.rows_per_step == rows_per_step
+    # one grid step a group of rows, whatever their pages
+    assert geo.grid == (-(-shapes["batch"] // rows_per_step),)
     assert geo.vmem_bytes <= pallas_decode.PAGED_VMEM_BUDGET
+    # the update's width: score rows a grid step
+    rows_pad = -(-shapes.get("query_rows", 1) // 8) * 8
+    assert (rows_per_step == 1 or rows_per_step * shapes["kv_heads"] * rows_pad
+            <= pallas_decode.PAGED_STEP_WIDTH)
 
 
 def test_parity_under_jit_traced_start():
