@@ -163,9 +163,16 @@ def _decode_cache_append(module: nn.Module, value, name: str, s_max: int,
     With ``page_table [B, n_pages]`` the buffer is a page POOL
     ``[P, page_size, ...]`` (seeded by the serving loop); the one new
     token scatters to ``(page_table[b, start // ps], start % ps)`` and
-    the CONTIGUOUS PER-ROW VIEW ``[B, n_pages·ps, ...]`` is returned —
-    gathered once per step, the same traffic class as attending the
-    cache at all (MLA's decode paths consume the full buffer anyway).
+    the CONTIGUOUS PER-ROW VIEW ``[B, n_pages·ps, ...]`` is returned:
+    every page of every row, live or not, written out and read back each
+    step (85 MB a layer at 64 slots x 1,152 positions of 576 numbers:
+    2.7 times the device time of the attend it fed; ledger, PR 57). Who
+    still takes that gather: MLA's single-token steps under the ``eager``
+    decode backend (every CPU run that is not an interpret-mode kernel
+    test), over int8 pools, and with ``decode_absorbed=False`` (the
+    oracle). Under the ``pallas`` backend the absorbed step appends
+    through :func:`_paged_append_latent` and reads the pools through the
+    page table itself, live pages only.
     """
     from jax import lax
 
@@ -291,6 +298,29 @@ def _paged_append_kv(module: nn.Module, k, v, names, start, page_table):
             _paged_scatter_append(module, ref, name, page, off, value)
             for ref, name, value in zip(refs, names, (k, v))
         ]
+
+
+def _paged_append_latent(module: nn.Module, c, k_rope, start, page_table):
+    """A decode step's new latent row ``c [B, 1, r]`` and rotary key row
+    ``k_rope [B, 1, d]`` into the pools ``cached_latent`` and
+    ``cached_rope_key`` (``[P, ps, .]``); returns the two pools, no
+    gathered view. One ``pallas_decode.paged_append`` call that holds
+    both in HBM, the pools seen as one kv head (with one token a row the
+    token axis of the new rows stands for it): behind XLA's scatter the
+    compiler stages a 75.6 MB latent pool in its fast memory and copies
+    it back out for the kernel that reads it, a layer a step
+    (tests/core/test_chip_compile.py; PERF.md, PR 60)."""
+    from d9d_tpu.ops.attention.pallas_decode import paged_append
+
+    refs = [module.variable("cache", name, lambda: None)
+            for name in ("cached_latent", "cached_rope_key")]
+    page, off = _paged_slot(page_table, start, refs[0].value.shape[1])
+    pools = paged_append(
+        refs[0].value[:, None], refs[1].value[:, None], page, off, c, k_rope
+    )
+    for ref, pool in zip(refs, pools):
+        ref.value = pool[:, 0]
+    return [ref.value for ref in refs]
 
 
 def _decode_cache_append_heads_major(module: nn.Module, value, name: str,
@@ -1072,29 +1102,56 @@ class MultiHeadLatentAttention(nn.Module):
             start = idx.value
             _decode_contract_checks(start, t, s_max)
             page_table = _decode_page_table(self)
+            through_table = False
             if page_table is not None:
                 # paged serving mode: the latent/rope-key pools scatter
-                # the one new token and hand back the gathered per-row
-                # view — both decode paths below consume the full
-                # buffer anyway, so they run unchanged on it (masks are
-                # built over the gathered length)
+                # the one new token. Under the ``pallas`` decode backend
+                # the absorbed attend reads the pools through the page
+                # table, live pages only; every other case is handed the
+                # gathered per-row view, on which both decode paths
+                # below run unchanged (masks are built over the gathered
+                # length)
                 _paged_write_checks(start, t, mask)
-            # the scatter of the new token and, paged, the gather of the
-            # row's view: where a step reads its cache rows from HBM
+                through_table = _latent_reads_through_table(self)
+                _note_latent_decode(self, through_table)
+            # zeros that fill a cached rotary key row to a lane tile, for
+            # the pool the paged kernel cuts pages from; they never meet a
+            # query elsewhere
+            rope_pad = _rope_key_pad(self, d_rope)
+
+            def new_rope_row():
+                row = k_rope.astype(self.dtype)
+                if rope_pad:
+                    row = jnp.pad(row, ((0, 0), (0, 0), (0, rope_pad)))
+                return row
+
+            # the write of the new token and, where the view is
+            # gathered, the gather: where a step reads its cache rows
+            # from HBM
             with jax.named_scope("mla/cache_append"):
-                cached_c = _decode_cache_append(
-                    self, c_kv.astype(self.dtype), "cached_latent", s_max,
-                    start, page_table=page_table,
-                )
-                cached_r = _decode_cache_append(
-                    self, k_rope.astype(self.dtype), "cached_rope_key",
-                    s_max, start, page_table=page_table,
-                )
+                new_c = c_kv.astype(self.dtype)
+                if through_table:
+                    cached_c, cached_r = _paged_append_latent(
+                        self, new_c, new_rope_row(), start, page_table
+                    )
+                else:
+                    cached_c = _decode_cache_append(
+                        self, new_c, "cached_latent", s_max, start,
+                        page_table=page_table,
+                    )
+                    cached_r = _decode_cache_append(
+                        self, new_rope_row(), "cached_rope_key", s_max,
+                        start, page_table=page_table,
+                    )
+                    if rope_pad:
+                        cached_r = cached_r[..., :d_rope]
             idx.value = start + t
             from d9d_tpu.nn.decode_flags import in_continuation_chunk
 
             if t == 1 or in_continuation_chunk():
-                dec_mask = _decode_slot_mask(
+                # (through the table a row sees the positions up to its
+                # own: no mask over a view that was never gathered)
+                dec_mask = None if through_table else _decode_slot_mask(
                     start, t, cached_c.shape[1], None, mask
                 )
                 if t == 1 and self.decode_absorbed:
@@ -1107,6 +1164,7 @@ class MultiHeadLatentAttention(nn.Module):
                     out = self._absorbed_attend(
                         q_nope, q_rope, cached_c, cached_r, kv_up_w,
                         dec_mask, d_qk, d_nope, d_v,
+                        paged=(page_table, start) if through_table else None,
                     )
                 else:
                     # decompressed slot attention: the single-step
@@ -1173,13 +1231,19 @@ class MultiHeadLatentAttention(nn.Module):
         )
 
     def _absorbed_attend(self, q_nope, q_rope, c, k_rope, w, dec_mask,
-                         d_qk, d_nope, d_v):
+                         d_qk, d_nope, d_v, paged=None):
         """Rank-space attention against the latent cache (fp32).
 
         scores = (W_k^T q_nope)^T c + q_rope^T k_rope; the value side
         stays latent until one final fold through W_v. Per step this
         costs O(t·h·r·(d_nope+d_v)) absorption + O(t·h·s·r) attention
         instead of decompressing all s_max slots through kv_up.
+
+        ``c`` and ``k_rope`` are each row's cache ``[B, S, .]`` under
+        ``dec_mask``, or with ``paged = (page_table, start)`` the page
+        pools ``[P, page_size, .]``, attended through the table by
+        ``pallas_decode.latent_decode_attention`` (one Pallas call under
+        the same scope; no mask: a row sees positions up to its own).
         """
         h = self.num_heads
         r = self.kv_lora_rank
@@ -1197,21 +1261,114 @@ class MultiHeadLatentAttention(nn.Module):
             qr = q_rope.astype(jnp.float32)
             q_abs = jnp.einsum("bthd,rhd->bthr", qn, wk)
         with jax.named_scope("mla/latent_attend"):
-            cf = c.astype(jnp.float32)
-            rf = k_rope.astype(jnp.float32)
-            scores = (
-                jnp.einsum("bthr,bsr->bhts", q_abs, cf)
-                + jnp.einsum("bthd,bsd->bhts", qr, rf)
-            ) * scale
-            neg_big = jnp.asarray(-1e30, scores.dtype)
-            scores = jnp.where(dec_mask, scores, neg_big)
-            # finite mask sentinel (not -inf): a fully-masked row must
-            # produce zeros like eager_sdpa's guarded softmax, not NaN
-            p = jax.nn.softmax(scores, axis=-1)
-            p = jnp.where(
-                jnp.any(dec_mask, axis=-1, keepdims=True), p, 0.0
-            )
-            out_lat = jnp.einsum("bhts,bsr->bthr", p, cf)
+            if paged is not None:
+                from d9d_tpu.ops.attention.pallas_decode import (
+                    latent_decode_attention,
+                )
+
+                page_table, start = paged
+                out_lat = latent_decode_attention(
+                    q_abs, qr, c, k_rope, start=start,
+                    page_table=page_table, softmax_scale=scale,
+                )
+            else:
+                out_lat = latent_attend(q_abs, qr, c, k_rope, dec_mask, scale)
         with jax.named_scope("mla/fold_v"):
             out = jnp.einsum("bthr,rhd->bthd", out_lat, wv)
             return out.astype(self.dtype)
+
+
+def latent_attend(q_abs, q_rope, c, k_rope, dec_mask, scale):
+    """The absorbed attend on each row's whole cache: float32 queries
+    ``q_abs [B, T, H, r]`` and ``q_rope [B, T, H, d_rope]`` against
+    ``c [B, S, r]`` and ``k_rope [B, S, d_rope]`` under ``dec_mask`` →
+    the weighted sum of the latent rows ``[B, T, H, r]``. The reference
+    the paged kernel's latent configuration is tested against."""
+    cf = c.astype(jnp.float32)
+    rf = k_rope.astype(jnp.float32)
+    scores = (
+        jnp.einsum("bthr,bsr->bhts", q_abs, cf)
+        + jnp.einsum("bthd,bsd->bhts", q_rope, rf)
+    ) * scale
+    neg_big = jnp.asarray(-1e30, scores.dtype)
+    scores = jnp.where(dec_mask, scores, neg_big)
+    # finite mask sentinel (not -inf): a fully-masked row must
+    # produce zeros like eager_sdpa's guarded softmax, not NaN
+    p = jax.nn.softmax(scores, axis=-1)
+    p = jnp.where(
+        jnp.any(dec_mask, axis=-1, keepdims=True), p, 0.0
+    )
+    return jnp.einsum("bhts,bsr->bthr", p, cf)
+
+
+# A paged latent layer's last traced decode step, by the module's path:
+# whether it read its pools through the page table
+_LATENT_DECODE_PATHS: dict[tuple, bool] = {}
+
+
+def _latent_kernel_wanted(module: "MultiHeadLatentAttention") -> bool:
+    """The absorbed form under the ``pallas`` decode backend: the switch
+    the GQA layers' paged append and attend follow."""
+    from d9d_tpu.ops.attention.pallas_decode import decode_attention_backend
+
+    return module.decode_absorbed and decode_attention_backend() == "pallas"
+
+
+def _rope_key_pad(module: "MultiHeadLatentAttention", d_rope: int) -> int:
+    """Numbers added to a cached rotary key row. Mosaic cannot cut a page
+    out of a pool whose rows are narrower than a 128-lane tile (the chip
+    stores 64 numbers in a tile of 128 anyway; the compiler for a
+    described v5e refuses the slice, PERF.md, PR 60), so where the paged
+    kernel will read the pool, the serving loop's paged shape-only init
+    (``decode_flags.ring_caches``) under :func:`_latent_kernel_wanted`,
+    the leaf is declared a whole tile wide and every later step follows
+    the leaf it is handed. ``generate``'s contiguous cache, the ``eager``
+    backend and the oracle keep the rows as they are."""
+    from d9d_tpu.nn.decode_flags import ring_page_size
+    from d9d_tpu.ops.attention.pallas_decode import LANES
+
+    if module.has_variable("cache", "cached_rope_key"):
+        leaf = module.get_variable("cache", "cached_rope_key")
+        return leaf.shape[-1] - d_rope
+    if ring_page_size() is not None and _latent_kernel_wanted(module):
+        return (-d_rope) % LANES
+    return 0
+
+
+def _latent_reads_through_table(module: "MultiHeadLatentAttention") -> bool:
+    """Whether a paged single-token step of ``module`` attends its pools
+    through the page table (``pallas_decode.latent_decode_attention``) or
+    is handed the gathered view: the first under
+    :func:`_latent_kernel_wanted`, over pools in the module's dtype (an
+    int8 pool's scales have no reader there) whose rotary key rows are
+    whole lane tiles (:func:`_rope_key_pad`: a pool seeded under another
+    backend is not). From what the step sees."""
+    from d9d_tpu.nn.decode_flags import PAGED_SCALE_SUFFIX
+    from d9d_tpu.ops.attention.pallas_decode import LANES
+
+    return (
+        _latent_kernel_wanted(module)
+        and not module.has_variable(
+            "cache", "cached_latent" + PAGED_SCALE_SUFFIX)
+        and module.get_variable(
+            "cache", "cached_rope_key").shape[-1] % LANES == 0
+    )
+
+
+def _note_latent_decode(module: nn.Module, through_table: bool) -> None:
+    """Record which way a paged latent layer's step was traced, as gauges
+    of the process's telemetry registry: ``decode/latent/paged_layers``
+    (layers whose last traced step reads the pools through the page
+    table) and ``decode/latent/gathered_layers`` (layers handed the
+    gathered view). The path is chosen where the step is traced, from
+    static facts, so it is a count of layers and not of requests, as
+    ``pallas_flash.py _note_grid`` notes its grids."""
+    from d9d_tpu.telemetry import get_telemetry
+
+    _LATENT_DECODE_PATHS[module.path] = through_table
+    paged = sum(_LATENT_DECODE_PATHS.values())
+    tele = get_telemetry()
+    tele.gauge("decode/latent/paged_layers").set(paged)
+    tele.gauge("decode/latent/gathered_layers").set(
+        len(_LATENT_DECODE_PATHS) - paged
+    )

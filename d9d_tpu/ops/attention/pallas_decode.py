@@ -31,7 +31,12 @@ grid step a group of batch rows, each row's live pages copied a block
 of pages at a time into a double-buffered VMEM block (all kv heads of a
 page in one copy), one online-softmax update for the group's blocks,
 the (row, kv head) pairs as the batch of its two products; pages past a
-row's last query are neither copied nor attended.
+row's last query are neither copied nor attended. The absorbed latent
+decode (``latent_decode_attention``, ``_DecodeConfig.latent``) is a
+configuration of the same kernel: its two pools are the latent rows and
+the shared rotary key rows seen as one kv head, a score is the sum of a
+query row's two products with them, and the values are the latent rows
+already in the block.
 
 Slot semantics ride positions: the cache write index ``start`` enters
 as a traced SMEM scalar, queries sit at global positions
@@ -101,6 +106,12 @@ class _DecodeConfig:
     # attends (paged_decode_geometry)
     pages_per_step: int = 1
     rows_per_step: int = 1
+    # paged mode, the absorbed latent decode: the pools are the latent
+    # rows ``[P, 1, page, r]`` and the shared rotary key rows
+    # ``[P, 1, page, d_rope]``, a query row is ``[q_abs | q_rope]``, a
+    # score the sum of its two products, and THE VALUES ARE THE FIRST
+    # POOL'S ROWS: the block that was copied for the scores
+    latent: bool = False
 
 
 def _decode_kernel(*refs, cfg: _DecodeConfig):
@@ -308,6 +319,7 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
     sems, slot_ref, m_ref, l_ref, acc_ref = refs[6:]
     page, pps, group = cfg.block_kv, cfg.pages_per_step, cfg.rows_per_step
     block = pps * page
+    value_buf = bufs[0] if cfg.latent else bufs[1]
     gi = pl.program_id(0)
     n_groups = pl.num_programs(0)
 
@@ -373,7 +385,7 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
         # a buffer's unwritten tail, and the whole buffer of a row that
         # sits an update out, is masked by position, but 0 x NaN in the
         # value dot is NaN: no buffer starts with arbitrary bits
-        bufs[1][...] = jnp.zeros_like(bufs[1])
+        value_buf[...] = jnp.zeros_like(value_buf)
         for r in range(group):
             start_copies(rows[r], r, firsts[r], block_pages(lives[r], 0), 0)
 
@@ -396,14 +408,25 @@ def _paged_decode_kernel(offs_ref, pt_ref, q_ref, *refs,
         ])  # [R, Hkv, 1, block]
 
     def attend(slot, ib):
+        def scores(q, k):  # [., rows_pad, D] x [., block, D]
+            return jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+
         q = heads_as_batch(q_ref[...].astype(jnp.float32))   # [., rows_pad, D]
         k = heads_as_batch(bufs[0][slot].astype(jnp.float32))  # [., block, D]
-        v = heads_as_batch(bufs[1][slot].astype(jnp.float32))
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * cfg.scale  # [R x Hkv, rows_pad, block]
-        s = s.reshape(group, hkv, rp, block)
+        if cfg.latent:
+            # q = [q_abs | q_rope]: the latent rows meet the first, the
+            # rotary key rows the second, and the latent rows are the values
+            r = k.shape[-1]
+            k_rope = heads_as_batch(bufs[1][slot].astype(jnp.float32))
+            v = k
+            s = scores(q[..., :r], k) + scores(q[..., r:], k_rope)
+        else:
+            v = heads_as_batch(bufs[1][slot].astype(jnp.float32))
+            s = scores(q, k)
+        s = (s * cfg.scale).reshape(group, hkv, rp, block)
         if cfg.quant:
             # int8 keys: a slot's scale multiplies its column of
             # scores (row [Hkv, 1, block] of the row's gathered scales),
@@ -490,9 +513,15 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
     carry the per-slot dequantization scales. A row's scales are
     gathered here through the same table into lane-dense rows, one a
     block of keys, and meet the scores and the probabilities in the
-    kernel's float32 math."""
+    kernel's float32 math.
+
+    ``cfg.latent``: ``k_pool [P, 1, page_size, r]`` holds the latent rows,
+    which are the values too, ``v_pool [P, 1, page_size, d_rope]`` the
+    shared rotary key rows, and ``q_rows`` is ``[B, 1, rows_pad,
+    r + d_rope]``; the output is ``r`` wide."""
     b, hkv, rp, d = q_rows.shape
-    dv = v_pool.shape[-1]
+    dk = k_pool.shape[-1]  # d, but for the latent rows' r
+    dv = dk if cfg.latent else v_pool.shape[-1]
     n_pages = page_table.shape[1]
     pps, group = cfg.pages_per_step, cfg.rows_per_step
     block = pps * cfg.block_kv
@@ -533,8 +562,8 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
         + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=[group_spec(dv), group_spec(1)],
         scratch_shapes=[
-            pltpu.VMEM((2, group, hkv, block, d), k_pool.dtype),
-            pltpu.VMEM((2, group, hkv, block, dv), v_pool.dtype),
+            pltpu.VMEM((2, group, hkv, block, dk), k_pool.dtype),
+            pltpu.VMEM((2, group, hkv, block, v_pool.shape[-1]), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),  # the buffer the next block waits on
             pltpu.VMEM((group, hkv, rp, LANES), jnp.float32),
@@ -543,8 +572,9 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
         ],
     )
     # the scope holds the rows a grid step attends and the name the pages
-    # a block: a trace says which tiling ran
-    with jax.named_scope(f"paged_decode_r{group}"):
+    # a block: a trace says which tiling ran, of which configuration
+    kind = "latent_decode" if cfg.latent else "paged_decode"
+    with jax.named_scope(f"{kind}_r{group}"):
         o, lse = pl.pallas_call(
             functools.partial(_paged_decode_kernel, cfg=cfg, n_pages=n_pages),
             grid_spec=grid_spec,
@@ -559,7 +589,7 @@ def _paged_decode_call(cfg: _DecodeConfig, q_rows, k_pool, v_pool,
                 )
             ),
             interpret=cfg.interpret,
-            name=f"paged_decode_p{pps}",
+            name=f"{kind}_p{pps}",
         )(offsets, page_table.reshape(-1), q_rows, *scale_rows, k_pool, v_pool)
     return o[:b], lse[:b, ..., 0]
 
@@ -925,3 +955,76 @@ def flash_decode_attention(
         .transpose(0, 3, 1, 2, 4)
         .reshape(b, t, hq, dv)
     )
+
+
+def latent_decode_attention(
+    q_abs: Array,
+    q_rope: Array,
+    latent_pool: Array,
+    rope_pool: Array,
+    *,
+    start: Array,
+    page_table: Array,
+    softmax_scale: float,
+    interpret: bool | None = None,
+) -> Array:
+    """One token's absorbed latent attention through the page table:
+    ``q_abs [B, 1, H, r]`` (the query with the key up-projection folded
+    in) and ``q_rope [B, 1, H, d_rope]`` against the pools
+    ``latent_pool [P, page_size, r]`` and ``rope_pool [P, page_size,
+    d_rope or more]`` (on the chip a whole lane tile, its rows filled
+    with zeros: Mosaic cuts no page out of a pool of narrower rows), row
+    ``b``'s logical page ``p`` in pool page ``page_table[b, p]`` and its
+    query at position ``start[b]`` → ``[B, 1, H, r]``, the softmax's
+    weighted sum of the latent rows, which the caller folds through the
+    value up-projection.
+
+    The paged kernel of :func:`flash_decode_attention` with the pools
+    seen as one kv head (a free view) and ``_DecodeConfig.latent`` set: a
+    score is ``(q_abs . c + q_rope . k_rope) * softmax_scale``, the values
+    are the copied latent rows, and only a row's live pages leave HBM,
+    where ``nn/attention.py latent_attend`` multiplies the gathered view
+    of every page of every row under a mask. The same arithmetic in
+    float32 (pool rows are cast before each product; the queries come and
+    the result leaves in the queries' dtype, float32 from the module) with
+    the softmax taken a block of keys at a time in position order; a row
+    with no visible position gives exact zeros."""
+    b, t, h, r = q_abs.shape
+    if t != 1:
+        raise NotImplementedError(
+            f"the paged latent decode attends one token a row; got t={t}"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    page_size, rope_width = rope_pool.shape[1:]
+    n_pages = page_table.shape[1]
+    geo = paged_decode_geometry(
+        batch=b, kv_heads=1, n_pages=n_pages, page_size=page_size,
+        head_dim=r, v_head_dim=rope_width,
+        kv_itemsize=latent_pool.dtype.itemsize, query_rows=h,
+    )
+    rp = h + _pad_to(h, 8)
+    cfg = _DecodeConfig(
+        scale=softmax_scale,
+        window=None,
+        t=1,
+        rows=h,
+        rows_pad=rp,
+        s_len=n_pages * page_size,
+        block_kv=page_size,
+        has_valid=False,
+        interpret=interpret,
+        pages_per_step=geo.pages_per_step,
+        rows_per_step=geo.rows_per_step,
+        latent=True,
+    )
+    # [B, 1, H, r + d_rope] is [B, one kv head, H query rows, .] as it is;
+    # zeros meet the zeros that fill a rotary key row to a lane tile
+    q_rows = jnp.concatenate([q_abs, q_rope.astype(q_abs.dtype)], axis=-1)
+    q_rows = jnp.pad(q_rows, (
+        (0, 0), (0, 0), (0, rp - h), (0, rope_width - q_rope.shape[-1])))
+    o, _ = _paged_decode_call(
+        cfg, q_rows, latent_pool[:, None], rope_pool[:, None],
+        jnp.asarray(start, jnp.int32), page_table.astype(jnp.int32),
+    )
+    return o[:, :, :h]

@@ -197,11 +197,14 @@ def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
     value dims, 20 heads): the decode-mode prefill takes d_qk = d_v = 256
     through the Pallas flash kernel, and the absorbed single-token step
     compiles against paged latent and rope-key pools of 64 slots x 1,152
-    positions (the benchmark's ``glm-4.7-flash-decode`` cell)."""
+    positions (the benchmark's ``glm-4.7-flash-decode`` cell): since
+    PR 60 the append and the attend through the page table, two calls,
+    the rotary key rows seeded a whole lane tile wide (Mosaic refuses to
+    cut a page out of a pool of 64-number rows)."""
     import flax.linen as nn
 
     from d9d_tpu.nn.attention import MultiHeadLatentAttention
-    from d9d_tpu.nn.decode_flags import PAGE_TABLE_LEAF
+    from d9d_tpu.nn.decode_flags import PAGE_TABLE_LEAF, ring_caches
     from d9d_tpu.nn.sdpa import build_sdpa_backend
 
     sds = _on(SingleDeviceSharding(topo.devices[0]))
@@ -231,21 +234,37 @@ def test_mla_at_glm_4_7_flash_widths(topo, as_tpu, phase):
             "cached_rope_key": sds((b, s_max, d_rope), BF16),
         }
     else:
+        # the leaves as the serving loop's paged init has them declared
+        with ring_caches(PAGE):
+            declared = jax.eval_shape(
+                lambda: module.init(
+                    jax.random.PRNGKey(0), jnp.zeros((b, 1, H), BF16),
+                    jnp.zeros((b, 1, d_rope // 2)),
+                    jnp.zeros((b, 1, d_rope // 2)),
+                )["cache"]
+            )
+        assert declared["cached_rope_key"].shape == (b, s_max, 128)
         pages = b * (s_max // PAGE) + 1
         cache = {
             "cache_index": sds((b,), jnp.int32),
             "cached_latent": sds((pages, PAGE, rank), BF16),
-            "cached_rope_key": sds((pages, PAGE, d_rope), BF16),
+            "cached_rope_key": sds((pages, PAGE, 128), BF16),
             PAGE_TABLE_LEAF: sds((b, s_max // PAGE), jnp.int32),
         }
     compiled = jax.jit(
         lambda p, c, x, cos, sin: module.apply(
             {"params": p, "cache": c}, x, cos, sin, mutable=["cache"]
-        )
+        ),
+        donate_argnums=1,
     ).lower(params, cache, x, rope, rope).compile()
-    assert _pallas_calls(compiled) == (1 if phase == "prefill" else 0)
-    # the gathered view and its float32 copy are the step's temporaries
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    if phase == "prefill":
+        assert _pallas_calls(compiled) == 1
+        return
+    assert _pallas_calls(compiled, "mla/cache_append/paged_append") == 1
+    assert _pallas_calls(compiled, "latent_decode_r4/latent_decode_p8") == 1
+    # no view of every page of every row (151 MB with its float32 copy):
+    # the queries, the result and their relayouts
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6
 
 
 def _assert_grouped_paged_call(compiled, geo, pool: str):
@@ -612,6 +631,25 @@ def test_an_admission_clears_its_rows_in_place_at_granite_widths(topo):
     assert ma.temp_size_in_bytes < state_bytes / 3
 
 
+def _decode_chunk(model, steps: int = 8):
+    """``steps`` scanned greedy decode steps of ``model`` on a carried
+    cache: the body of the serving loop's fused chunk."""
+    def chunk(cache, params, tok, pos):
+        def body(carry, _):
+            cache, tok, pos = carry
+            logits, new = model.apply(
+                {"params": params, "cache": cache}, tok, pos,
+                method="logits", mutable=["cache"])
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (new["cache"], tok[:, None], pos + 1), tok
+
+        (cache, _, _), toks = jax.lax.scan(
+            body, (cache, tok, pos), None, length=steps)
+        return cache, toks
+
+    return chunk
+
+
 def test_fused_decode_steps_at_the_solar_open2_share8_cell(topo, as_tpu):
     """Eight scanned decode steps of the whole share (one period: gated
     NoPE GQA and three Kimi delta attention mixers at the published
@@ -637,20 +675,7 @@ def test_fused_decode_steps_at_the_solar_open2_share8_cell(topo, as_tpu):
         lambda a: sds(a.shape, a.dtype),
         {k: abstract[k] for k in ("params", "cache")})
 
-    def chunk(cache, params, tok, pos):
-        def body(carry, _):
-            cache, tok, pos = carry
-            logits, new = model.apply(
-                {"params": params, "cache": cache}, tok, pos,
-                method="logits", mutable=["cache"])
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return (new["cache"], tok[:, None], pos + 1), tok
-
-        (cache, _, _), toks = jax.lax.scan(
-            body, (cache, tok, pos), None, length=8)
-        return cache, toks
-
-    compiled = jax.jit(chunk, donate_argnums=0).lower(
+    compiled = jax.jit(_decode_chunk(model), donate_argnums=0).lower(
         variables["cache"], variables["params"],
         sds(z.shape, z.dtype), sds(z.shape, z.dtype),
     ).compile()
@@ -663,6 +688,71 @@ def test_fused_decode_steps_at_the_solar_open2_share8_cell(topo, as_tpu):
     claimed = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert claimed < 14e9  # of the chip's 15.75 GB
+
+
+def test_fused_decode_steps_at_the_glm_4_7_flash_cell(topo, as_tpu):
+    """Eight scanned decode steps of the GLM cell's six layers for its 64
+    rows, the paged cache donated (the body of the serving loop's fused
+    chunk): a layer holds the append and the latent call through the page
+    table, nothing gathers a pool, nothing scatters into one, and no copy
+    of a whole pool is made around the calls. Behind XLA's scatter the
+    compiler staged the 75.6 MB latent pool in its fast memory and copied
+    it back out for the kernel, in four of the six layers (PR 60)."""
+    from flax.traverse_util import flatten_dict, unflatten_dict
+
+    from d9d_tpu.models.deepseek import DeepseekCausalLM, glm_4_7_flash
+    from d9d_tpu.nn.decode_flags import PAGE_TABLE_LEAF, ring_caches
+    from d9d_tpu.nn.sdpa import build_sdpa_backend
+    from d9d_tpu.ops.attention.pallas_decode import paged_decode_geometry
+
+    slots, n_pages, layers = 64, 18, 6
+    pages = slots * n_pages + 1
+    sds = _on(SingleDeviceSharding(topo.devices[0]))
+    model = DeepseekCausalLM(
+        config=dataclasses.replace(glm_4_7_flash(), num_layers=layers),
+        sdpa=build_sdpa_backend(), dtype=BF16, param_dtype=BF16,
+        decode_max_length=n_pages * PAGE,
+    )
+    z = jnp.zeros((slots, 1), jnp.int32)
+    with ring_caches(PAGE):  # the serving loop's paged shape-only init
+        abstract = nn.unbox(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), z, z, z)))
+    cache = {}
+    for path, leaf in flatten_dict(abstract["cache"]).items():
+        if path[-1] == "cache_index":
+            cache[path] = sds((slots,), jnp.int32)
+        else:  # a pool, and the table the loop seeds beside it
+            assert path[-1] in ("cached_latent", "cached_rope_key")
+            cache[path] = sds((pages, PAGE, leaf.shape[-1]), leaf.dtype)
+            cache[path[:-1] + (PAGE_TABLE_LEAF,)] = sds(
+                (slots, n_pages), jnp.int32)
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), abstract["params"])
+
+    compiled = jax.jit(_decode_chunk(model), donate_argnums=0).lower(
+        unflatten_dict(cache), params, sds(z.shape, z.dtype),
+        sds(z.shape, z.dtype),
+    ).compile()
+    geo = paged_decode_geometry(
+        batch=slots, kv_heads=1, n_pages=n_pages, page_size=PAGE,
+        head_dim=512, v_head_dim=128, kv_itemsize=2, query_rows=20,
+    )
+    assert (geo.pages_per_step, geo.rows_per_step) == (8, 4)
+    assert _pallas_calls(compiled, "mla/cache_append/paged_append") == layers
+    assert _pallas_calls(
+        compiled, "mla/latent_attend/jit(_paged_decode_call)/"
+        "latent_decode_r4/latent_decode_p8/pallas_call") == layers
+    text = compiled.as_text()
+    pool = rf"bf16\[{pages},(?:1,)?{PAGE},(?:512|128)\]"
+    assert not re.findall(rf"= {pool}[^=\n]*\bcopy(?:-start)?\(", text)
+    # the parent gathered bf16[64,18,64,512] a layer and read it as
+    # [64,1152,512]: no row's view of its pages, of either pool
+    assert f"[{slots},{n_pages},{PAGE}," not in text
+    assert f"[{slots},{n_pages * PAGE}," not in text
+    assert "scatter(" not in text
+    ma = compiled.memory_analysis()
+    pool_bytes = layers * pages * PAGE * (512 + 128) * 2
+    assert ma.alias_size_in_bytes >= pool_bytes  # the pools, in place
+    assert ma.temp_size_in_bytes < 0.25 * pool_bytes  # no row's view
 
 
 # -- the output head's fused cross-entropy -----------------------------------

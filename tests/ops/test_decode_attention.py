@@ -18,12 +18,14 @@ from d9d_tpu.nn.attention import (
     _decode_slot_mask,
     _paged_slot,
     _scatter_head_rows,
+    latent_attend,
 )
 from d9d_tpu.ops.attention.eager import eager_sdpa
 from d9d_tpu.ops.attention import pallas_decode
 from d9d_tpu.ops.attention.pallas_decode import (
     append_tile,
     flash_decode_attention,
+    latent_decode_attention,
     paged_append,
     paged_decode_geometry,
 )
@@ -481,6 +483,11 @@ def test_paged_append_writes_the_scatters_bits(dtype, heads, dk, dv, ps, table):
         # ZAYA1-8B's latent pool: 8 on 2
         (dict(batch=256, kv_heads=2, n_pages=18, page_size=64,
               query_rows=4), 8, 8),
+        # GLM-4.7-Flash's absorbed latent decode: 20 query heads on the
+        # latent rows of 512 and rotary key rows stored as 128, one "kv
+        # head": a page is 80 KB, 4 rows a step fill 5 of the 8 MiB
+        (dict(batch=64, kv_heads=1, n_pages=18, page_size=64, head_dim=512,
+              v_head_dim=128, query_rows=20), 8, 4),
     ],
 )
 def test_paged_geometry_from_shapes(shapes, pages_per_step, rows_per_step):
@@ -495,6 +502,118 @@ def test_paged_geometry_from_shapes(shapes, pages_per_step, rows_per_step):
     rows_pad = -(-shapes.get("query_rows", 1) // 8) * 8
     assert (rows_per_step == 1 or rows_per_step * shapes["kv_heads"] * rows_pad
             <= pallas_decode.PAGED_STEP_WIDTH)
+
+
+# -- the absorbed latent decode through the page table ----------------------
+
+_LATENT = dict(h=5, r=32, d_rope=8, ps=8, n_pages=6)
+# name -> each row's write index (its query's position); None: the last
+_LATENT_ROWS = {
+    "one-position": [0, 0, 0],
+    "ends-on-a-page-edge": [7, 15, 47],
+    "one-past-a-page-edge": [8, 16, 40],
+    "full-context": [None, None, 3],
+    "idle-slot-on-the-garbage-page": [13, "idle", 30],
+    "fully-masked-row": [20, -1, 0],
+    "batch-the-group-does-not-divide": [0, 9, 17, 26, 33, 41, None],
+    "shuffled-table": [5, 44, 23, 12],
+}
+
+
+def _latent_case(starts, *, h, r, d_rope, ps, n_pages, rope_width=None,
+                 seed=0):
+    """Float32 queries, bf16 pools whose pages lie shuffled through the
+    pool, the rows' table and write indices, and ``latent_attend``'s
+    result on the gathered view. An ``"idle"`` row is what the serving
+    loop makes of a free slot: write index 0, its table row on page 0,
+    the garbage page, which holds finite numbers."""
+    rng = np.random.RandomState(seed)
+    b = len(starts)
+    pool_n = b * n_pages + 1
+    latent = rng.randn(pool_n, ps, r)
+    rope = np.zeros((pool_n, ps, rope_width or d_rope))
+    rope[..., :d_rope] = rng.randn(pool_n, ps, d_rope)
+    table = 1 + rng.permutation(pool_n - 1).reshape(b, n_pages)
+    for i, start in enumerate(starts):
+        if start == "idle":
+            table[i] = 0
+    start = jnp.asarray(
+        [0 if s == "idle" else n_pages * ps - 1 if s is None else s
+         for s in starts], jnp.int32)
+    latent = jnp.asarray(latent, jnp.bfloat16)
+    rope = jnp.asarray(rope, jnp.bfloat16)
+    table = jnp.asarray(table, jnp.int32)
+    q_abs = jnp.asarray(rng.randn(b, 1, h, r), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(b, 1, h, d_rope), jnp.float32)
+    want = latent_attend(
+        q_abs, q_rope,
+        latent[table].reshape(b, n_pages * ps, r),
+        rope[table].reshape(b, n_pages * ps, -1)[..., :d_rope],
+        _decode_slot_mask(start, 1, n_pages * ps, None, None), 0.25,
+    )
+    return (q_abs, q_rope, latent, rope), dict(
+        start=start, page_table=table, softmax_scale=0.25, interpret=True
+    ), np.asarray(want)
+
+
+@pytest.mark.parametrize("rows", list(_LATENT_ROWS))
+def test_latent_paged_parity(rows):
+    """The paged kernel's latent configuration against ``latent_attend``
+    on the gathered view of every page of every row: float32 agreement,
+    exact zeros for a row that sees no position."""
+    args, kwargs, want = _latent_case(_LATENT_ROWS[rows], **_LATENT)
+    got = np.asarray(latent_decode_attention(*args, **kwargs))
+    assert got.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for i, start in enumerate(_LATENT_ROWS[rows]):
+        if start == -1:
+            np.testing.assert_array_equal(want[i], 0)
+            np.testing.assert_array_equal(got[i], 0)
+
+
+@pytest.mark.parametrize("rows_per_step", [1, 2, 8])
+def test_latent_paged_groups_read_what_rows_read_alone(
+        monkeypatch, rows_per_step):
+    """Blocks of two pages (three a row) and groups of 1, 2 and 8 rows a
+    grid step, rows of one, two and three live blocks in one group: the
+    reference's numbers, and bit for bit what one row a step gives."""
+    monkeypatch.setattr(pallas_decode, "PAGED_STEP_POSITIONS", 16)
+    starts = _LATENT_ROWS["batch-the-group-does-not-divide"]
+    args, kwargs, want = _latent_case(starts, **_LATENT, seed=1)
+
+    def call(rows):
+        monkeypatch.setattr(pallas_decode, "PAGED_STEP_ROWS", rows)
+        return np.asarray(latent_decode_attention(*args, **kwargs))
+
+    got = call(rows_per_step)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(got, call(1))
+
+
+def test_latent_paged_parity_at_the_glm_cells_pages():
+    """Pages of 64, 18 a row, 20 query heads and rotary key rows stored
+    a whole lane tile wide, as the serving loop seeds them: the full
+    1,152 positions, a page's edge and one past it, blocks of 8 pages."""
+    shape = dict(h=20, r=128, d_rope=64, ps=64, n_pages=18)
+    starts = [None, 63, 64, 511, 512, 0, 700]
+    args, kwargs, want = _latent_case(starts, **shape, rope_width=128)
+    got = np.asarray(latent_decode_attention(*args, **kwargs))
+    # sums of 1,152 float32 terms in another order: 1e-5 of a value's size
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # the zeros that fill a rotary key row meet zeros: the bits of the
+    # rows as they are
+    q_abs, q_rope, latent, rope = args
+    np.testing.assert_array_equal(got, np.asarray(latent_decode_attention(
+        q_abs, q_rope, latent, rope[..., :64], **kwargs)))
+
+
+def test_latent_paged_call_refuses_several_tokens():
+    args, kwargs, _ = _latent_case([3], **_LATENT)
+    q_abs, q_rope, latent, rope = args
+    with pytest.raises(NotImplementedError, match="one token"):
+        latent_decode_attention(
+            jnp.tile(q_abs, (1, 2, 1, 1)), jnp.tile(q_rope, (1, 2, 1, 1)),
+            latent, rope, **kwargs)
 
 
 def test_parity_under_jit_traced_start():
