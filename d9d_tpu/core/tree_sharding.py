@@ -178,3 +178,18 @@ def replicate_uncommitted(tree: PyTree, mesh) -> PyTree:
         return x
 
     return jax.tree.map(fix, tree)
+
+
+def normalize_params(params: PyTree) -> PyTree:
+    """Pin uncommitted leaves of a handed-over param tree to the
+    mesh-replicated placement of its committed leaves
+    (:func:`replicate_uncommitted`); identity for trees with no
+    committed NamedSharding to normalize against. What the serving loop
+    does to every tree it is given, at construction and at a publish."""
+    from jax.sharding import NamedSharding
+
+    for leaf in jax.tree.leaves(params):
+        sh = getattr(leaf, "sharding", None)
+        if isinstance(sh, NamedSharding):
+            return replicate_uncommitted(params, sh.mesh)
+    return params

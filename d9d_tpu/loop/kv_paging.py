@@ -14,7 +14,7 @@ one-readback-per-K-tokens contract is untouched.
 
 Page identity contract: page 0 is the reserved GARBAGE page — never
 allocated, never freed. Idle/dead device rows have their page-table
-rows pinned to 0 in-device (``loop/serve.py`` ``_pin_page_table``), so
+rows pinned to 0 in-device (``loop/serve_cache.py`` ``pin_idle_rows``), so
 a row that dies mid-chunk scribbles into the garbage page instead of a
 page the allocator may have handed to someone else (or, worse, a
 shared prefix page).

@@ -193,10 +193,9 @@ def recurrent_leaves(cache) -> dict:
 def map_page_table(cache, fn):
     """Apply ``fn`` to every ``page_table`` leaf of a cache pytree (the
     paged counterpart of :func:`map_cache_index`; the serving loop uses
-    it to write the host allocator's table mirror into every leaf, inside
-    the fused chunk or from the host on the single-step path, and to pin
-    dead rows' tables to the garbage page in-device). No-op on unpaged
-    caches."""
+    it to write the host allocator's table mirror into every leaf inside
+    the fused chunk, and to pin dead rows' tables to the garbage page
+    in-device). No-op on unpaged caches."""
     from flax.traverse_util import flatten_dict, unflatten_dict
 
     flat = flatten_dict(cache)

@@ -710,7 +710,7 @@ class FleetAutopilot:
                 self._tele.gauge("autopilot/canary_pending").set(0.0)
                 return
             b = pending.target()
-            label = getattr(b, "_replica_label", None) if b else None
+            label = getattr(b, "replica_label", None) if b else None
             if label is None:
                 # unlabeled / dead target: nothing to compare against —
                 # roll straight back rather than promote blind

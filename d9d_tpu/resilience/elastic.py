@@ -71,6 +71,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from d9d_tpu.core.tree_sharding import normalize_params
 from d9d_tpu.core.types import PyTree
 from d9d_tpu.telemetry import get_telemetry
 
@@ -370,21 +371,6 @@ def bounded_restore_shardings(
     return jax.tree.map(plan, target_tree)
 
 
-def normalize_published_params(params: PyTree) -> PyTree:
-    """Pin uncommitted leaves of a to-be-published param tree to a
-    mesh-replicated placement — the same latent-placement class as the
-    PR 5 resume bug: params coming out of a restored checkpoint (or a
-    fresh ``jit(init)``) can carry uncommitted scalars whose placement
-    conflicts with the batcher's mesh-placed cache at the first
-    post-publish dispatch. No-op when the tree has no committed mesh to
-    normalize against. Delegates to the batcher's own helper so the
-    two can never drift; ``install_weights`` re-running it on an
-    already-normalized tree is a pure traversal (no transfers)."""
-    from d9d_tpu.loop.serve import _normalize_params
-
-    return _normalize_params(params)
-
-
 # ---------------------------------------------------------------------------
 # live train→serve weight publish
 
@@ -455,7 +441,12 @@ class WeightPublisher:
         to hold the swap until its in-flight requests finish. A pending
         canary is superseded: the fleet converges on THIS generation
         and the autopilot abandons the stale decision."""
-        params = normalize_published_params(params)
+        # the latent-placement class of the PR 5 resume bug: params out
+        # of a restored checkpoint (or a fresh ``jit(init)``) can carry
+        # uncommitted scalars whose placement conflicts with a batcher's
+        # mesh-placed cache at the first post-publish dispatch;
+        # ``install_weights`` re-running it is a pure traversal
+        params = normalize_params(params)
         self.version += 1
         self.latest_version = self.version
         self.latest_params = params
@@ -509,7 +500,7 @@ class WeightPublisher:
                 "publish_canary needs a prior fleet-wide publish: the "
                 "retained tree is the rollback target"
             )
-        params = normalize_published_params(params)
+        params = normalize_params(params)
         if batcher is None:
             live = self._live_targets()
             if not live:
@@ -599,6 +590,13 @@ class _FleetRequest:
     # "decode" (post-handoff or post-fallback: the continuation runs
     # out the remaining budget on a decode-capable replica)
     stage: str = "direct"
+
+
+def _allocator(batcher):
+    """A paged replica's host page allocator (``loop/kv_paging.py``):
+    the fleet's prefix directory and capacity ranking read it. None for
+    an unpaged replica."""
+    return batcher._cache_mgr.allocator
 
 
 class ServingFleet:
@@ -730,7 +728,7 @@ class ServingFleet:
 
     def _fleet_rate(self) -> float:
         return float(sum(
-            self._replicas[i]._live_rate() for i in self._live
+            self._replicas[i].live_rate() for i in self._live
         ))
 
     def _kv_pages(self, attr: str) -> float:
@@ -739,7 +737,7 @@ class ServingFleet:
         — absence of paging is not an empty pool)."""
         total, any_paged = 0.0, False
         for i in self._live:
-            kv = getattr(self._replicas[i], "_kv", None)
+            kv = _allocator(self._replicas[i])
             if kv is not None:
                 any_paged = True
                 total += float(getattr(kv, attr))
@@ -835,7 +833,7 @@ class ServingFleet:
         # replica's serve instruments get a fleet-assigned namespace
         # (serve/r{i}/...) unless the embedder labeled it already
         if (
-            getattr(batcher, "_replica_label", None) is None
+            getattr(batcher, "replica_label", None) is None
             and hasattr(batcher, "set_replica_label")
         ):
             batcher.set_replica_label(f"r{idx}")
@@ -955,7 +953,7 @@ class ServingFleet:
         Contiguous replicas are never short (admission is slot-bounded
         there); prefix hits and LRU eviction could only help, so this
         is a conservative RANKING signal, not an admission gate."""
-        kv = getattr(self._replicas[i], "_kv", None)
+        kv = _allocator(self._replicas[i])
         if kv is None:
             return False
         return kv.pages_needed(total_tokens) > kv.pages_free_after_flush()
@@ -1054,7 +1052,7 @@ class ServingFleet:
         mid-chunk owner, version skew, checksum, pool pressure) counts
         a miss and degrades to a local prefill — never an error."""
         tb = self._replicas[target]
-        kv = getattr(tb, "_kv", None)
+        kv = _allocator(tb)
         if kv is None or not kv.prefix_cache_enabled or not self._prefix_dir:
             return
         ps = kv.page_size
@@ -1273,7 +1271,7 @@ class ServingFleet:
                 return
         dir_: dict[bytes, int] = {}
         for i in sorted(self._live):
-            kv = getattr(self._replicas[i], "_kv", None)
+            kv = _allocator(self._replicas[i])
             if kv is None or not kv.prefix_cache_enabled:
                 continue
             for key, e in kv._entries.items():
@@ -1326,8 +1324,8 @@ class ServingFleet:
         ship = None
         src_b = self._replicas.get(src)
         if target is not None and src in self._live and src_b is not None:
-            tkv = getattr(self._replicas[target], "_kv", None)
-            if tkv is not None and getattr(src_b, "_kv", None) is not None:
+            tkv = _allocator(self._replicas[target])
+            if tkv is not None and _allocator(src_b) is not None:
                 cap = (len(prompt) - 1) // tkv.page_size
                 if cap > 0:
                     ship = src_b.export_kv_pages(

@@ -62,7 +62,7 @@ __all__ = [
 logger = logging.getLogger("d9d_tpu.telemetry")
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_]")
-# any path-free replica label (ContinuousBatcher._validate_label's
+# any path-free replica label (loop/serve_accounting.py _validate_label's
 # contract), not just the fleet's r{i} — a custom "east1" label must
 # fold into the same metric family as everyone else, or fleet PromQL
 # aggregations silently exclude that replica

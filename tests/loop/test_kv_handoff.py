@@ -14,6 +14,11 @@ import pytest
 from d9d_tpu.loop.kv_paging import PagedKVAllocator
 
 
+def _kv(batcher):
+    """The batcher's host page allocator (``loop/kv_paging.py``)."""
+    return batcher._cache_mgr.allocator
+
+
 def _alloc(**kw):
     kw.setdefault("num_pages", 9)       # 8 allocatable + garbage
     kw.setdefault("page_size", 4)
@@ -150,14 +155,14 @@ def test_shipment_round_trips_pool_payloads(paged_toy_factory, kv_quant):
         # int8 pools ship WITH their sibling scale pages
         assert any(n.endswith("_scale") for n in ship.payload)
     # payload rows are the exact device pool pages, in chain order
-    pool = {n: np.asarray(leaf) for n, leaf in src._pool_leaves().items()}
-    pages = src._kv.export_prefix(prompt)
+    pool = {n: np.asarray(leaf) for n, leaf in src._cache_mgr.pool_leaves(src._cache).items()}
+    pages = _kv(src).export_prefix(prompt)
     for name, arr in ship.payload.items():
         np.testing.assert_array_equal(arr, pool[name][np.asarray(pages)])
     assert dst.import_kv_pages(ship)
-    dst._kv.check_invariants()
-    dpool = {n: np.asarray(leaf) for n, leaf in dst._pool_leaves().items()}
-    dpages = dst._kv.export_prefix(prompt)
+    _kv(dst).check_invariants()
+    dpool = {n: np.asarray(leaf) for n, leaf in dst._cache_mgr.pool_leaves(dst._cache).items()}
+    dpages = _kv(dst).export_prefix(prompt)
     assert len(dpages) == 2
     for name, arr in ship.payload.items():
         np.testing.assert_array_equal(
@@ -167,8 +172,8 @@ def test_shipment_round_trips_pool_payloads(paged_toy_factory, kv_quant):
     rid2 = dst.submit(prompt, max_new_tokens=3)
     out2 = dst.drain()
     assert out2[rid2] == paged_toy_expected(prompt, 3)
-    assert dst._kv.prefix_hits == 1
-    dst._kv.check_invariants()
+    assert _kv(dst).prefix_hits == 1
+    _kv(dst).check_invariants()
 
 
 @pytest.mark.e2e
@@ -188,13 +193,13 @@ def test_shipment_checksum_catches_corruption(paged_toy_factory):
     raw = ship.payload[name].copy()
     raw.view(np.uint8).flat[0] ^= 0xFF
     ship.payload[name] = raw
-    before = {n: np.asarray(v) for n, v in dst._pool_leaves().items()}
+    before = {n: np.asarray(v) for n, v in dst._cache_mgr.pool_leaves(dst._cache).items()}
     assert not dst.import_kv_pages(ship)
     # refused WHOLESALE: no entries registered, no pool bytes written
-    assert len(dst._kv._entries) == 0
-    for n, v in dst._pool_leaves().items():
+    assert len(_kv(dst)._entries) == 0
+    for n, v in dst._cache_mgr.pool_leaves(dst._cache).items():
         np.testing.assert_array_equal(np.asarray(v), before[n])
-    dst._kv.check_invariants()
+    _kv(dst).check_invariants()
 
 
 @pytest.mark.e2e
@@ -211,8 +216,8 @@ def test_shipment_version_mismatch_refused(paged_toy_factory):
     # prefix invalidation)
     ship.weights_version = ship.weights_version + 1
     assert not dst.import_kv_pages(ship)
-    assert len(dst._kv._entries) == 0
-    dst._kv.check_invariants()
+    assert len(_kv(dst)._entries) == 0
+    _kv(dst).check_invariants()
 
 
 @pytest.mark.e2e
@@ -225,8 +230,8 @@ def test_shipment_quant_mode_mismatch_refused(paged_toy_factory):
     ship = src.export_kv_pages(prompt)
     assert ship is not None
     assert not dst.import_kv_pages(ship)  # f32 pages into int8 pools
-    assert len(dst._kv._entries) == 0
-    dst._kv.check_invariants()
+    assert len(_kv(dst)._entries) == 0
+    _kv(dst).check_invariants()
 
 
 @pytest.mark.e2e
@@ -237,7 +242,7 @@ def test_export_respects_transfer_budget_chunks(paged_toy_factory):
     src.drain()
     # a budget of one page's bytes forces one chunk per page
     ship = src.export_kv_pages(
-        prompt, transfer_budget_bytes=src._page_bytes
+        prompt, transfer_budget_bytes=src._cache_mgr.page_bytes
     )
     assert ship is not None and ship.n_pages == 3
     assert ship.chunks == 3

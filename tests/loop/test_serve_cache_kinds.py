@@ -45,7 +45,7 @@ def chunk_output(batcher, slots: int, k: int = 8):
     """The abstract result of the fused chunk without admission."""
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     # the host-made argument: plan, admission and page table, packed
-    packed = i32(slots, k + 5 + batcher._pages_per_row)
+    packed = i32(slots, k + 5 + batcher._cache_mgr.pages_per_row)
     args = (
         batcher._params, batcher._cache, i32(slots), i32(slots),
         jnp.zeros((slots,), bool), i32(slots), jax.random.PRNGKey(0), packed,
@@ -61,8 +61,8 @@ def test_a_single_kind_model_keeps_its_cache_and_its_chunk(cell):
     assert len(flat) == leaves and {p[-1] for p in flat} == names
     assert {v.shape for p, v in flat.items() if p[-1] == "page_table"} \
         == {table}
-    assert batcher._window_cache_bytes == 0 and not batcher._ring_windows
-    assert not batcher._counts_held_rows
+    assert batcher._cache_mgr.window_cache_bytes == 0 and not batcher._cache_mgr.ring_windows
+    assert not batcher._cache_mgr.counts_held_rows
     out = chunk_output(batcher, serving["slots"])
     assert out[-1].shape == (serving["slots"], 8)
     assert jax.tree.structure(out[0]) == jax.tree.structure(batcher._cache)
@@ -80,8 +80,8 @@ def test_a_model_with_window_layers_adds_rings_and_counts():
     assert len(flat) == 2 * 4 + 2 * 3
     assert {v.shape for p, v in flat.items() if p[-1] == "page_table"} \
         == {(slots, 8)}
-    assert dict(batcher._ring_windows) == {6: 2}
-    assert batcher._kv.prefix_cache_enabled is False
+    assert dict(batcher._cache_mgr.ring_windows) == {6: 2}
+    assert batcher._cache_mgr.allocator.prefix_cache_enabled is False
     # the chunk's one output carries the held-rows counts below the slots:
     # held, routed and (0 for a router without a skip) skipped
     out = chunk_output(batcher, slots)
@@ -130,7 +130,7 @@ def test_a_ring_is_the_window_and_the_page_being_written():
 
 
 def test_window_positions_are_the_context_or_the_window():
-    from d9d_tpu.loop.serve import _positions_under
+    from d9d_tpu.loop.serve_accounting import positions_under
 
     spans = [(0, 8), (3, 8), (5, 1), (6, 8), (120, 8), (124, 8), (1100, 5)]
     for window in (6, 128):
@@ -138,5 +138,5 @@ def test_window_positions_are_the_context_or_the_window():
             min(pos + j, window)
             for pos, steps in spans for j in range(1, steps + 1)
         )
-        assert _positions_under(spans, window) == want
-    assert _positions_under([], 128) == 0
+        assert positions_under(spans, window) == want
+    assert positions_under([], 128) == 0

@@ -24,6 +24,11 @@ from d9d_tpu.loop.serve import ContinuousBatcher
 from d9d_tpu.nn.decode_flags import PAGE_TABLE_LEAF
 from d9d_tpu.telemetry import Telemetry, introspect
 
+def _kv(batcher):
+    """The batcher's host page allocator (``loop/kv_paging.py``)."""
+    return batcher._cache_mgr.allocator
+
+
 K = 4
 PAGE = 8  # decode_max_length=24 → 3 pages per row
 SEED = 20260930
@@ -49,24 +54,23 @@ def a_batcher(model_and_params, *, paged, chunk=K, temperature=0.0, **kw):
     )
 
 
-def mixed_run(b):
+def mixed_run(b, advance=None):
     """Short and long requests, a prompt served twice (with pages its
     second serving starts past two shared pages), a death followed by
     chunks that admit nothing, and a drain that sends a chunk out while
     the one a row died in is still in flight. Returns the requests as
     ``(prompt, budget, stream)`` in submission order and every dispatch
-    as ``(admit, chunks in flight)``."""
-    fused = b._k is not None
-    advance = b.step_chunk if fused else b.step
+    as ``(admit, chunks in flight)``. ``advance`` steps the waves
+    (``step_chunk`` unless given)."""
+    advance = advance or b.step_chunk
     dispatches = []
-    if fused:
-        inner = b._dispatch_chunk
+    inner = b._dispatch_chunk
 
-        def recorded(k, admit):
-            dispatches.append((admit, len(b._pending)))
-            inner(k, admit)
+    def recorded(k, admit):
+        dispatches.append((admit, len(b._pending)))
+        inner(k, admit)
 
-        b._dispatch_chunk = recorded
+    b._dispatch_chunk = recorded
     shared = _prompts(42, 1, lo=18, hi=19)[0]  # two full pages and a tail
     short, long_, tail, longer_tail = _prompts(7, 4, lo=2, hi=5)
     asked = []
@@ -101,15 +105,16 @@ def test_greedy_streams_are_generates_and_the_single_step_paths(
     got, dispatches = mixed_run(b)
     for prompt, n, stream in got:
         assert stream == _oracle(model, params, prompt, n)
-    stepped, _ = mixed_run(a_batcher(model_and_params, paged=paged,
-                                     chunk=None))
+    # the single-token surface: ``step()`` waves, a K = 1 drain
+    single = a_batcher(model_and_params, paged=paged, chunk=1)
+    stepped, _ = mixed_run(single, single.step)
     assert got == stepped
     # the run held what it was written for: a prefix hit, so a first
     # position past 0; chunks without admission after a death; follow-up
     # chunks dispatched with one in flight
     if paged:
-        assert b._kv.prefix_hits == 1
-        assert b._kv.prefix_hit_tokens == 2 * PAGE
+        assert _kv(b).prefix_hits == 1
+        assert _kv(b).prefix_hit_tokens == 2 * PAGE
     steps = [s for s in hub.registry.spans if s.name == "serve/step"]
     assert {s.meta["rows_reset"] > 0 for s in steps} == {True, False}
     assert any(not admit and flying for admit, flying in dispatches)
@@ -177,7 +182,7 @@ def test_the_callers_key_outlives_the_donated_carry(model_and_params):
 
 
 def pools(b) -> dict:
-    return {p: np.asarray(v) for p, v in b._pool_leaves().items()}
+    return {p: np.asarray(v) for p, v in b._cache_mgr.pool_leaves(b._cache).items()}
 
 
 def device_tables(b) -> list:
@@ -198,12 +203,12 @@ def test_a_row_that_died_unread_writes_into_the_garbage_page(
     b.submit(short, max_new_tokens=1)
     b.submit(long_, max_new_tokens=15)
     b._dispatch_chunk(K, admit=True)
-    mine = b._kv.table[0].copy()
+    mine = _kv(b).table[0].copy()
     assert mine[0] > 0
     held = pools(b)  # waits for the chunk, reads nothing back of it
     b._dispatch_chunk(K, admit=False)
     assert len(b._pending) == 2
-    assert np.array_equal(b._kv.table[0], mine)  # the death is unread
+    assert np.array_equal(_kv(b).table[0], mine)  # the death is unread
     for table in device_tables(b):
         assert not table[0].any() and table[1].any()
     after = pools(b)
@@ -216,7 +221,7 @@ def test_a_row_that_died_unread_writes_into_the_garbage_page(
     out = b.drain()
     model, params = model_and_params
     assert out[1] == _oracle(model, params, long_, 15)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     b.close()
 
 
@@ -233,12 +238,12 @@ def test_a_row_the_host_zeroed_is_rerouted_and_its_pages_wait(
     kept = b.submit(long_, max_new_tokens=15)
     b.step_chunk()
     b._dispatch_chunk(K, admit=False)  # in flight
-    mine = b._kv.table[0].copy()
+    mine = _kv(b).table[0].copy()
     time.sleep(0.05)
     b._expire_running(time.perf_counter())
     assert b.failed[doomed] == "deadline"
-    assert b._kv._deferred and not b._kv.table[0].any()
-    in_use = b._kv.pages_in_use
+    assert _kv(b)._deferred and not _kv(b).table[0].any()
+    in_use = _kv(b).pages_in_use
     held = pools(b)
     b._dispatch_chunk(K, admit=False)  # the zombie steps on, rerouted
     for table in device_tables(b):
@@ -247,12 +252,12 @@ def test_a_row_the_host_zeroed_is_rerouted_and_its_pages_wait(
     for path, pool in held.items():
         for page in mine[mine > 0]:
             assert np.array_equal(after[path][page], pool[page])
-    assert b._kv._deferred and b._kv.pages_in_use == in_use
+    assert _kv(b)._deferred and _kv(b).pages_in_use == in_use
     fresh = b.submit(doomed_prompt, max_new_tokens=3)
     out = b.drain()  # a clean boundary on its way: the pages free
-    assert not b._kv._deferred
+    assert not _kv(b)._deferred
     model, params = model_and_params
     assert out[kept] == _oracle(model, params, long_, 15)
     assert out[fresh] == _oracle(model, params, doomed_prompt, 3)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     b.close()

@@ -20,6 +20,11 @@ from tests.loop.test_serve import _dense, _oracle, _params, _prompts
 
 from d9d_tpu.loop.serve import ContinuousBatcher
 
+def _kv(batcher):
+    """The batcher's host page allocator (``loop/kv_paging.py``)."""
+    return batcher._cache_mgr.allocator
+
+
 PAGE = 8  # decode_max_length=24 → 3 pages per row
 
 
@@ -43,9 +48,9 @@ def _staggered_run(model, params, prompts, *, n, paged, chunk, **kw):
     rids += [b.submit(p, max_new_tokens=n) for p in prompts[1:]]
     outputs = b.drain()
     if paged:
-        b._kv.check_invariants()
-        assert b._kv.pages_in_use == (
-            len(b._kv._entries)  # only cached prefix pages stay mapped
+        _kv(b).check_invariants()
+        assert _kv(b).pages_in_use == (
+            len(_kv(b)._entries)  # only cached prefix pages stay mapped
         )
     return [outputs[r] for r in rids], b
 
@@ -74,18 +79,6 @@ def test_paged_token_identical_to_contiguous(k):
     del pb
 
 
-@pytest.mark.slow  # second compile of the legacy per-token step
-def test_paged_legacy_path_token_identical():
-    model = _dense()
-    params = _params(model)
-    prompts = _prompts(11, 3)
-    want, _ = _staggered_run(model, params, prompts, n=5, paged=False,
-                             chunk=None)
-    got, _ = _staggered_run(model, params, prompts, n=5, paged=True,
-                            chunk=None)
-    assert got == want
-
-
 def test_prefix_hit_token_identical_and_counted():
     """A shared prompt's second serving must hit the prefix cache
     (skipping its full pages) and still emit EXACTLY the cold-prefill
@@ -98,21 +91,21 @@ def test_prefix_hit_token_identical_and_counted():
     r1 = b.submit(prompt, max_new_tokens=5)
     cold = b.drain()[r1]
     assert cold == oracle
-    assert b._kv.prefix_hits == 0 and b._kv.prefix_misses == 1
+    assert _kv(b).prefix_hits == 0 and _kv(b).prefix_misses == 1
     # second serving: 2 pages (16 tokens) come from the cache
     r2 = b.submit(prompt, max_new_tokens=5)
     hit = b.drain()[r2]
     assert hit == oracle
-    assert b._kv.prefix_hits == 1 and b._kv.prefix_hit_tokens == 2 * PAGE
+    assert _kv(b).prefix_hits == 1 and _kv(b).prefix_hit_tokens == 2 * PAGE
     assert b.prefix_hit_rate() == 0.5
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     # BOTH rows sharing at once: two fresh hits decode concurrently
     r3 = b.submit(prompt, max_new_tokens=5)
     r4 = b.submit(prompt, max_new_tokens=5)
     out = b.drain()
     assert out[r3] == oracle and out[r4] == oracle
-    assert b._kv.prefix_hits == 3
-    b._kv.check_invariants()
+    assert _kv(b).prefix_hits == 3
+    _kv(b).check_invariants()
 
 
 def test_paged_admission_bounded_by_free_pages():
@@ -131,11 +124,11 @@ def test_paged_admission_bounded_by_free_pages():
     b.step_chunk()
     # only one row could be mapped: the other is still queued
     assert sum(1 for s in b._slots if s.rid >= 0) == 1
-    assert b._kv.pages_free == 0
+    assert _kv(b).pages_free == 0
     out = b.drain()
     assert out[r1] == _oracle(model, params, prompts[0], 8)
     assert out[r2] == _oracle(model, params, prompts[1], 8)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     # a request that could NEVER fit fails fast at submit
     with pytest.raises(ValueError, match="could never be admitted"):
         b.submit(list(range(10)), max_new_tokens=12)
@@ -156,11 +149,11 @@ def test_paged_deadline_eviction_recycles_pages_exactly():
     time.sleep(0.1)
     b.step_chunk()  # boundary: expire + release
     assert b.failed[doomed] == "deadline"
-    assert b._kv.pages_in_use == 0
-    b._kv.check_invariants()
+    assert _kv(b).pages_in_use == 0
+    _kv(b).check_invariants()
     fresh = b.submit(prompts[1], max_new_tokens=6)
     assert b.drain()[fresh] == _oracle(model, params, prompts[1], 6)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
 
 
 def test_paged_pallas_backend_matches_eager(monkeypatch):
@@ -211,7 +204,7 @@ def test_paged_gauges_and_structural_counts():
     assert tele.registry.gauge("serve/kv_pages_in_use").value == 0
     assert (
         tele.registry.gauge("serve/kv_pages_free").value
-        == paged._kv.num_pages - 1
+        == _kv(paged).num_pages - 1
     )
 
 
@@ -245,8 +238,8 @@ def test_paged_hybrid_gdn_token_identical_and_prefix_auto_disabled():
     got, pb = _staggered_run(model, params, prompts, n=5, paged=True,
                              chunk=4)
     assert got == want
-    assert pb._kv.prefix_cache_enabled is False
-    assert pb._unpageable_leaves == ["conv_tail", "delta_state"]
+    assert _kv(pb).prefix_cache_enabled is False
+    assert pb._cache_mgr.unpageable_leaves == ["conv_tail", "delta_state"]
     with pytest.raises(ValueError, match="unsound"):
         ContinuousBatcher(model, params, batch_size=2, chunk_size=4,
                           page_size=PAGE, prefix_cache=True)
@@ -264,17 +257,17 @@ def test_weight_publish_invalidates_prefix_cache():
     b = _batcher(model, params, paged=True)
     r1 = b.submit(prompt, max_new_tokens=5)
     assert b.drain()[r1] == _oracle(model, params, prompt, 5)
-    assert b._kv._entries  # the prefix is cached (old weights)
+    assert _kv(b)._entries  # the prefix is cached (old weights)
     b.install_weights(params2)
     r2 = b.submit(prompt, max_new_tokens=5)
     out = b.drain()[r2]
-    assert b._kv.prefix_hits == 0  # invalidated: no stale hit
+    assert _kv(b).prefix_hits == 0  # invalidated: no stale hit
     assert out == _oracle(model, params2, prompt, 5)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     # and the prompt re-cached under the new generation: now it hits
     r3 = b.submit(prompt, max_new_tokens=5)
     assert b.drain()[r3] == out
-    assert b._kv.prefix_hits == 1
+    assert _kv(b).prefix_hits == 1
 
 
 def test_quant_kv_serving_exact_on_toy():
@@ -294,7 +287,7 @@ def test_quant_kv_serving_exact_on_toy():
     out = b.drain()
     assert out[r1] == toy_expected([3], 6)
     assert out[r2] == toy_expected([7], 6)
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     # and the mode is misuse-proof: int8 pools need a page table
     with pytest.raises(ValueError, match="paged"):
         ContinuousBatcher(model, params, batch_size=2, chunk_size=4,
@@ -328,18 +321,18 @@ def test_quant_prefix_hit_shares_scale_pages_token_identical():
         assert pool.dtype == jnp.int8
     r1 = b.submit(prompt, max_new_tokens=5)
     cold = b.drain()[r1]
-    assert b._kv.prefix_hits == 0 and b._kv.prefix_misses == 1
+    assert _kv(b).prefix_hits == 0 and _kv(b).prefix_misses == 1
     r2 = b.submit(prompt, max_new_tokens=5)
     assert b.drain()[r2] == cold
-    assert b._kv.prefix_hits == 1 and b._kv.prefix_hit_tokens == 2 * PAGE
-    b._kv.check_invariants()
+    assert _kv(b).prefix_hits == 1 and _kv(b).prefix_hit_tokens == 2 * PAGE
+    _kv(b).check_invariants()
     # two rows sharing the quantized prefix concurrently
     r3 = b.submit(prompt, max_new_tokens=5)
     r4 = b.submit(prompt, max_new_tokens=5)
     out = b.drain()
     assert out[r3] == cold and out[r4] == cold
-    assert b._kv.prefix_hits == 3
-    b._kv.check_invariants()
+    assert _kv(b).prefix_hits == 3
+    _kv(b).check_invariants()
 
 
 def test_canary_rollback_invalidation_stamp_distinct_from_publish():
@@ -367,7 +360,7 @@ def test_canary_rollback_invalidation_stamp_distinct_from_publish():
     assert b.weights_version == 1
     gauge = tele.registry.gauge("serve/prefix_cache_invalidated_version")
     assert gauge.value == 1
-    assert b._kv._entries  # the prefix is cached under generation 1
+    assert _kv(b)._entries  # the prefix is cached under generation 1
     # canary publish: the apply at the next boundary must invalidate
     # and stamp with the canary's generation
     assert pub.publish_canary(bad) == 2
@@ -375,7 +368,7 @@ def test_canary_rollback_invalidation_stamp_distinct_from_publish():
     b.drain()
     assert b.weights_version == 2
     assert gauge.value == 2
-    assert b._kv.prefix_hits == 0  # no stale hit under the canary
+    assert _kv(b).prefix_hits == 0  # no stale hit under the canary
     # rollback: a FRESH generation, and a FRESH invalidation stamp —
     # the re-invalidation is auditable as the rollback, not a replay
     # of the publish
@@ -385,7 +378,7 @@ def test_canary_rollback_invalidation_stamp_distinct_from_publish():
     assert b.weights_version == 3
     assert gauge.value == 3
     assert out == oracle  # back on the retained tree, exactly
-    b._kv.check_invariants()
+    _kv(b).check_invariants()
     del r2
 
 
@@ -438,11 +431,11 @@ def test_paged_deferred_release_flushes_at_next_boundary():
     time.sleep(0.05)
     b._expire_running(time.perf_counter())
     assert b.failed[doomed] == "deadline"
-    assert b._kv._deferred and b._kv.pages_in_use > 0  # held for zombie
-    b._kv.check_invariants()
+    assert _kv(b)._deferred and _kv(b).pages_in_use > 0  # held for zombie
+    _kv(b).check_invariants()
     b.drain()  # harvests the in-flight chunk
     fresh = b.submit([7], max_new_tokens=3)  # admit boundary: flush
     out = b.drain()
     assert out[fresh] == toy_expected([7], 3)
-    assert not b._kv._deferred and b._kv.pages_in_use == 0
-    b._kv.check_invariants()
+    assert not _kv(b)._deferred and _kv(b).pages_in_use == 0
+    _kv(b).check_invariants()

@@ -87,7 +87,7 @@ def test_the_admitting_chunk_selects_over_no_recurrent_leaf():
     args = (
         b._params, b._cache, i32(slots), i32(slots),
         jnp.zeros((slots,), bool), i32(slots), jax.random.PRNGKey(0),
-        i32(slots, K + 5 + b._pages_per_row),
+        i32(slots, K + 5 + b._cache_mgr.pages_per_row),
     )
     whole = {x.shape for x in recurrent_leaves(b._cache).values()}
     assert whole and all(len(s) > 1 for s in whole)

@@ -294,7 +294,7 @@ def test_the_batcher_holds_two_kinds_of_cache_and_counts_both(served):
 def test_a_model_with_window_layers_serves_without_the_prefix_cache(
         params, served):
     model, _, batcher, _ = served
-    assert batcher._kv.prefix_cache_enabled is False
+    assert batcher._cache_mgr.allocator.prefix_cache_enabled is False
     with pytest.raises(ValueError, match="ring"):
         ContinuousBatcher(
             model, params, batch_size=2, page_size=PAGE, prefix_cache=True)

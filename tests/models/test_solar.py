@@ -332,7 +332,7 @@ def test_recurrent_leaves_and_a_held_range_share_one_chunk(served):
     assert stats.moe_rows_routed == 4 * 4 * slots * stats.device_steps
     assert 0 < stats.moe_rows_held < stats.moe_rows_routed
     assert stats.readbacks == stats.chunks
-    assert batcher._kv.prefix_cache_enabled is False
+    assert batcher._cache_mgr.allocator.prefix_cache_enabled is False
 
 
 def test_the_eight_shares_add_up_to_the_uncut_reference():

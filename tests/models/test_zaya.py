@@ -291,7 +291,7 @@ def test_a_recycled_slot_serves_as_a_fresh_one(served):
     assert stats.moe_rows_skipped > 0
     assert stats.moe_rows_held == stats.moe_rows_routed - stats.moe_rows_skipped
     assert stats.readbacks == stats.chunks
-    assert batcher._kv.prefix_cache_enabled is False
+    assert batcher._cache_mgr.allocator.prefix_cache_enabled is False
 
 
 def test_a_carrying_router_refuses_pipeline_stages():

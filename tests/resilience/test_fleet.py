@@ -20,6 +20,11 @@ from d9d_tpu.resilience.chaos import kill_replica_mid_drain, shrink_at_step
 from d9d_tpu.telemetry import get_telemetry
 
 
+def _kv(batcher):
+    """The batcher's host page allocator (``loop/kv_paging.py``)."""
+    return batcher._cache_mgr.allocator
+
+
 def _make_batcher(params=None, **kwargs):
     model = ToyDecodeLM()
     if params is None:
@@ -141,10 +146,10 @@ def test_kill_mid_drain_recovers_paged_requests_token_identically():
     for frid, p in zip(frids, prompts):
         assert out[frid] == toy_expected(p, 10), frid
     survivor = fleet._replicas[1]
-    survivor._kv.check_invariants()
-    assert survivor._kv.pages_in_use == 0  # everything retired cleanly
+    _kv(survivor).check_invariants()
+    assert _kv(survivor).pages_in_use == 0  # everything retired cleanly
     # the fleet-level page rollup reflects the one live paged replica
-    assert fleet._kv_pages("pages_free") == survivor._kv.pages_free
+    assert fleet._kv_pages("pages_free") == _kv(survivor).pages_free
 
 
 def test_kill_mid_drain_quantized_fleet_token_identical():
@@ -165,9 +170,9 @@ def test_kill_mid_drain_quantized_fleet_token_identical():
     for frid, p in zip(frids, prompts):
         assert out[frid] == toy_expected(p, 10), frid
     survivor = fleet._replicas[1]
-    survivor._kv.check_invariants()
-    assert survivor._kv.pages_in_use == 0
-    assert fleet._kv_pages("pages_free") == survivor._kv.pages_free
+    _kv(survivor).check_invariants()
+    assert _kv(survivor).pages_in_use == 0
+    assert fleet._kv_pages("pages_free") == _kv(survivor).pages_free
 
 
 def test_submit_validation_error_leaves_no_ghost():

@@ -42,6 +42,11 @@ from d9d_tpu.telemetry import (
 )
 
 
+def _kv(batcher):
+    """The batcher's host page allocator (``loop/kv_paging.py``)."""
+    return batcher._cache_mgr.allocator
+
+
 @pytest.fixture(autouse=True)
 def _fresh_hub():
     old = get_telemetry()
@@ -89,7 +94,7 @@ def _assert_no_leaks(fleet):
     entries may hold pages after a full drain, and the refcount audit
     must balance exactly."""
     for i in fleet.live_replicas:
-        kv = fleet._replicas[i]._kv
+        kv = _kv(fleet._replicas[i])
         kv.check_invariants()
         assert kv.pages_in_use == len(kv._entries), (
             f"replica {i} leaked pages: {kv.pages_in_use} in use, "
@@ -285,7 +290,7 @@ def test_shared_prompt_prefills_once_per_fleet():
     assert snap.get("serve/fleet_prefix_misses", 0) == 0
     # both allocators saw prefix hits: one locally, one via shipment
     assert all(
-        fleet._replicas[i]._kv.prefix_hits >= 1 for i in (0, 1)
+        _kv(fleet._replicas[i]).prefix_hits >= 1 for i in (0, 1)
     )
     _assert_no_leaks(fleet)
 
@@ -329,7 +334,7 @@ def test_placement_ranks_full_pool_behind_capacity():
     assert fleet._reqs[f0].replica == 0
     _drain(fleet, [f0])
     # fill replica 0's pool completely with pinned prefix chains
-    kv0 = fleet._replicas[0]._kv
+    kv0 = _kv(fleet._replicas[0])
     kv0.invalidate_prefix_cache()
     assert kv0.import_pages(list(range(16)), 4) is not None
     assert kv0.import_pages(list(range(100, 116)), 4) is not None

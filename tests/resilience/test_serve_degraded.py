@@ -83,7 +83,7 @@ def test_full_queue_expiry_credit_is_page_bounded_when_paged(
     out = b.drain()
     assert out[alive] == toy_expected([3], 12)
     assert out[head] == toy_expected([5], 16)
-    b._kv.check_invariants()
+    b._cache_mgr.allocator.check_invariants()
 
 
 def test_queued_request_past_deadline_expires_cleanly(toy_batcher_factory):
@@ -144,8 +144,8 @@ def test_drain_stall_watchdog_converts_hang_to_error(toy_batcher_factory):
     assert b._tele.registry.counter("serve/stalls").value >= 1
 
 
-def test_legacy_per_token_path_honors_deadlines(toy_batcher_factory):
-    b = toy_batcher_factory(chunk_size=None)
+def test_single_token_steps_honor_deadlines(toy_batcher_factory):
+    b = toy_batcher_factory(chunk_size=1)
     rid = b.submit([3], max_new_tokens=20, deadline_s=0.05)
     for _ in range(3):
         b.step()

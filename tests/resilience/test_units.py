@@ -290,7 +290,7 @@ def test_backoff_is_capped(monkeypatch):
 # -- serve knob validation ------------------------------------------------
 
 def test_serve_stats_reset_covers_degraded_counters():
-    from d9d_tpu.loop.serve import ServeStats
+    from d9d_tpu.loop.serve_accounting import ServeStats
 
     s = ServeStats()
     s.rejected = 3

@@ -97,7 +97,7 @@ def test_publish_applies_at_chunk_boundary_not_mid_chunk():
     """Install mid-request: tokens already harvested (old chunks) keep
     the old step; emissions from chunks dispatched after the boundary
     switch to the new step — exactly the chunk-boundary contract."""
-    b = _batcher(_params(1.0), chunk_size=4, overlap=False)
+    b = _batcher(_params(1.0), chunk_size=4)
     rid = b.submit([5], max_new_tokens=8)
     first = b.step_chunk()  # one K=4 chunk, all on the old weights
     assert first[rid] == _expected([5], 4, 1)
@@ -110,7 +110,7 @@ def test_publish_applies_at_chunk_boundary_not_mid_chunk():
 
 
 def test_defer_to_idle_finishes_inflight_on_old_weights():
-    b = _batcher(_params(1.0), chunk_size=2, overlap=False)
+    b = _batcher(_params(1.0), chunk_size=2)
     rid = b.submit([7], max_new_tokens=6)
     b.step_chunk()  # request now mid-flight
     b.install_weights(_params(2.0), defer_to_idle=True)
@@ -138,13 +138,20 @@ def test_publish_causes_zero_steady_state_recompiles():
     assert not new_records, [r.name for r in new_records]
 
 
-def test_legacy_per_token_path_publishes_too():
-    b = _batcher(_params(1.0), chunk_size=None)
+def _step_until_idle(b):
+    while b.active:
+        b.step()
+
+
+def test_single_token_steps_publish_too():
+    """``step()``, the single-token surface: the swap lands at the next
+    step's boundary."""
+    b = _batcher(_params(1.0), chunk_size=1)
     r1 = b.submit([4], max_new_tokens=3)
-    b.drain()
+    _step_until_idle(b)
     b.install_weights(_params(2.0))
     r2 = b.submit([4], max_new_tokens=3)
-    b.drain()
+    _step_until_idle(b)
     assert b.outputs[r1] == _expected([4], 3, 1)
     assert b.outputs[r2] == _expected([4], 3, 2)
     assert b.request_stats[r2].weights_version == 1

@@ -130,7 +130,7 @@ def test_slot_steps_prompt_is_exact(model_and_params, paged):
     b.drain()
     cached = 16 if paged else 0
     if paged:
-        assert b._kv.prefix_hits == 1 and b._kv.prefix_hit_tokens == cached
+        assert b._cache_mgr.allocator.prefix_hits == 1 and b._cache_mgr.allocator.prefix_hit_tokens == cached
     assert b.stats.slot_steps_prompt - before == len(long) - cached - 1
     assert (
         b.stats.slot_steps_prompt + b.stats.emitted_tokens
@@ -138,15 +138,17 @@ def test_slot_steps_prompt_is_exact(model_and_params, paged):
     )
 
 
-def test_slot_steps_prompt_on_the_per_token_path(model_and_params):
+def test_slot_steps_prompt_a_token_a_step(model_and_params):
+    """The same count through ``step()``, the single-token surface."""
     model, params = model_and_params
     b = ContinuousBatcher(
-        model, params, batch_size=2, chunk_size=None, telemetry=Telemetry()
+        model, params, batch_size=2, chunk_size=1, telemetry=Telemetry()
     )
     table = [(p, 3) for p in _prompts(8, 3)]
     for prompt, n in table:
         b.submit(prompt, max_new_tokens=n)
-    b.drain()
+    while b.active:
+        b.step()
     assert b.stats.slot_steps_prompt == sum(len(p) - 1 for p, _ in table)
     assert (
         b.stats.slot_steps_prompt + b.stats.emitted_tokens

@@ -1,7 +1,7 @@
-"""Serving telemetry: TTFT/TPOT/queue-wait stats must agree between the
-per-token (chunk_size=None) and fused (K=8) paths on identical
-requests, and deriving them must add ZERO device readbacks to the fused
-path's one-readback-per-chunk contract."""
+"""Serving telemetry: TTFT/TPOT/queue-wait stats must agree between a
+token a dispatch (K=1) and eight (K=8) on identical requests, and
+deriving them must add ZERO device readbacks to the
+one-readback-per-chunk contract."""
 
 import jax
 import jax.numpy as jnp
@@ -61,15 +61,16 @@ def _serve(model, params, prompts, *, chunk, n=6, hub=None):
 
 
 def test_ttft_tpot_agree_across_paths(model_and_params):
-    """Same requests through both stepping modes: identical tokens (the
-    existing parity contract) AND identical telemetry *shape* — every
-    request gets one queue-wait, one TTFT, and (multi-token) one TPOT
-    sample, with finite positive-or-zero values, in both modes."""
+    """Same requests a token a dispatch (K=1) and eight (K=8): identical
+    tokens (the existing parity contract) AND identical telemetry
+    *shape* — every request gets one queue-wait, one TTFT, and
+    (multi-token) one TPOT sample, with finite positive-or-zero values,
+    at both chunk sizes."""
     model, params = model_and_params
     prompts = _prompts(0, 4)
 
     results = {}
-    for label, chunk in (("per_token", None), ("fused", 8)):
+    for label, chunk in (("per_token", 1), ("fused", 8)):
         batcher, rids, hub = _serve(model, params, prompts, chunk=chunk)
         snap = hub.registry.snapshot()
         results[label] = (batcher, rids, snap)

@@ -5,8 +5,8 @@ This is the standing certification harness the tier-1 gate
 (tests/tools/test_audit_clean.py) and the ``d9d-audit`` CLI both run:
 every executable shape the repo dispatches in production is compiled
 here once, at tiny config, with artifact capture on — non-PP train
-step, ZeRO dp_replicate>1 train step, the serving fused-K and legacy
-step paths, the disaggregated prefill->decode fleet (whose handoff
+step, ZeRO dp_replicate>1 train step, the serving fused-K path, the
+disaggregated prefill->decode fleet (whose handoff
 must add zero executables), the speculative-decode round, the
 PipelinedOptimizer per-stage update programs, and the fused MPMD
 pipeline runs
@@ -139,41 +139,16 @@ def leg_train_zero() -> None:
 
 
 def leg_serve() -> None:
-    """The fused-K serving path (fused_k4[_admit] + row reset) and the
-    legacy per-token ``serve/step`` — the legacy leg runs the tiny
-    model in bf16 so the gate exercises the bf16_compute dtype policy
-    on a real decode program."""
-    import jax
-    import jax.numpy as jnp
-
+    """The fused-K serving path (fused_k4[_admit], the row reset inside
+    the admitting program)."""
     from tools.bench_serve import build_model
 
     from d9d_tpu.loop.serve import ContinuousBatcher
-    from d9d_tpu.models.qwen3 import Qwen3DenseCausalLM
 
     model, params, cfg = build_model()
-    prompt = [1, 2, 3]
-    fused = ContinuousBatcher(
-        model, params, batch_size=2, chunk_size=4, overlap=True
-    )
-    fused.submit(prompt, max_new_tokens=10)
+    fused = ContinuousBatcher(model, params, batch_size=2, chunk_size=4)
+    fused.submit([1, 2, 3], max_new_tokens=10)
     fused.drain()
-
-    bf16_model = Qwen3DenseCausalLM(
-        config=model.config, sdpa=model.sdpa, dtype=jnp.bfloat16,
-        decode_max_length=model.decode_max_length,
-    )
-    bf16_params = jax.tree.map(
-        lambda x: (
-            x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
-        ),
-        params,
-    )
-    legacy = ContinuousBatcher(
-        bf16_model, bf16_params, batch_size=2, chunk_size=None
-    )
-    legacy.submit(prompt, max_new_tokens=4)
-    legacy.drain()
 
 
 def leg_serve_quant() -> None:
@@ -193,21 +168,10 @@ def leg_serve_quant() -> None:
     qparams = quantize_for_serving(params)
     fused = ContinuousBatcher(
         model, qparams, batch_size=2, chunk_size=4,
-        overlap=True, page_size=4, num_pages=33, kv_quant="int8",
+        page_size=4, num_pages=33, kv_quant="int8",
     )
     fused.submit([1, 2, 3], max_new_tokens=10)
     fused.drain()
-
-    # the legacy per-token paged path is the only one that dispatches
-    # the standalone row-reset program (the fused path folds the reset
-    # into fused_k*_paged_admit) — run it so serve/reset_row_paged and
-    # the legacy quantized decode step are certified too
-    legacy = ContinuousBatcher(
-        model, qparams, batch_size=2, chunk_size=None,
-        page_size=4, num_pages=33, kv_quant="int8",
-    )
-    legacy.submit([1, 2, 3], max_new_tokens=4)
-    legacy.drain()
 
 
 def leg_serve_disagg() -> None:

@@ -188,7 +188,7 @@ def run_micro() -> dict:
     mark_bench = len(introspect.inventory())
     batcher = ContinuousBatcher(
         model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True,
+        chunk_size=k,
     )
     # warmup compiles both fused variants (admit + steady-state) before
     # the measurement window, like the real serving benches
@@ -207,7 +207,7 @@ def run_micro() -> dict:
     # -- exporter-enabled leg (monitoring-plane overhead contract) -----
     exp = ContinuousBatcher(
         model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True, replica_label="r0",
+        chunk_size=k, replica_label="r0",
     )
     monitor = SloMonitor([
         SloPolicy(name="bench_ttft_p99", metric="serve/ttft_s",
@@ -248,7 +248,7 @@ def run_micro() -> dict:
     # what it says
     pg = ContinuousBatcher(
         model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True, page_size=16, prefix_cache=False,
+        chunk_size=k, page_size=16, prefix_cache=False,
     )
     pg.submit(workload[0][1], max_new_tokens=2 * k + 2)
     pg.drain()
@@ -273,7 +273,7 @@ def run_micro() -> dict:
     qparams = quantize_for_serving(params)
     qt = ContinuousBatcher(
         model, qparams, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True, page_size=16, prefix_cache=False,
+        chunk_size=k, page_size=16, prefix_cache=False,
         kv_quant="int8",
     )
     qt.submit(workload[0][1], max_new_tokens=2 * k + 2)
@@ -292,7 +292,7 @@ def run_micro() -> dict:
     )
     px = ContinuousBatcher(
         model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True, page_size=16,
+        chunk_size=k, page_size=16,
     )
     # warmup ALSO primes the prefix cache (deliberate: the measured
     # window then shows the steady-state hit rate a shared system
@@ -304,7 +304,7 @@ def run_micro() -> dict:
     _drive_micro(px, shared, params, publish=False)
     px_window_records = introspect.inventory()[mark_px:]
     # dense-layout bytes the same concurrency would have pinned
-    px_dense_equiv = px._kv_bytes_static / max(1, px._peak_running)
+    px_dense_equiv = px._cache_mgr.kv_bytes_static / max(1, px._cache_mgr.peak_running)
 
     # -- autopilot leg: same workload through a 1-replica fleet with the
     # FULL control loop attached (SLO monitor + FleetAutopilot polled
@@ -329,7 +329,7 @@ def run_micro() -> dict:
     ap_fleet = ServingFleet(publisher=ap_pub)
     ap_b = ContinuousBatcher(
         model, params, batch_size=MICRO["batch_size"],
-        chunk_size=k, overlap=True,
+        chunk_size=k,
     )
     ap_fleet.add_replica(ap_b)
     ap_pub.publish(params)
@@ -439,7 +439,7 @@ def run_micro() -> dict:
             ),
             # requests a fixed HBM pool budget holds, vs wide pages
             "serve_micro.quant_kv_capacity_x": round(
-                pg._page_bytes / qt._page_bytes, 2
+                pg._cache_mgr.page_bytes / qt._cache_mgr.page_bytes, 2
             ),
             # prefix leg: the shared-system-prompt economics, all
             # deterministic accounting (exact thresholds)

@@ -112,7 +112,7 @@ def run_fleet(model, params, workload, *, roles, batch_size, chunk_size,
     outputs = {i: fleet.outputs(f) for i, f in frids.items()}
     snap = get_telemetry().registry.snapshot()["counters"]
     for i in fleet.live_replicas:
-        fleet._replicas[i]._kv.check_invariants()
+        fleet._replicas[i]._cache_mgr.allocator.check_invariants()
     fleet.close()
     return {
         "tokens": sum(len(t) for t in outputs.values()),

@@ -26,13 +26,15 @@ HOT_JIT_MODULES: tuple[str, ...] = (
 # host does no synchronous device work beyond its accounted readbacks;
 # each accounted readback carries an inline suppression naming itself.
 HOT_SYNC_SCOPES: tuple[tuple[str, str], ...] = (
-    # serve chunk loop: dispatch + harvest + the legacy per-token path
+    # serve chunk loop: dispatch + harvest, and what they call every
+    # chunk in the cache manager and the accounting
     ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\._dispatch_chunk"),
     ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\._harvest_one"),
-    ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\._step_legacy"),
-    ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\._admit_legacy"),
     ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\.step_chunk"),
     ("d9d_tpu/loop/serve.py", r"ContinuousBatcher\._drain_impl"),
+    ("d9d_tpu/loop/serve_cache.py",
+     r"CacheManager\.(admit|mark_filled|release_row|flush_deferred)"),
+    ("d9d_tpu/loop/serve_accounting.py", r"ServeAccounting\.note_.*"),
     # speculative decode round (one dispatch/readback per round)
     ("d9d_tpu/loop/speculative.py", r".*"),
     # train step builders: everything in the module is traced or
@@ -137,7 +139,7 @@ NONDETERMINISM_CALLS: tuple[str, ...] = (
 
 # -- D9D006: telemetry namespace discipline -----------------------------
 # attribute names whose first argument is a metric/span name literal;
-# includes ContinuousBatcher's replica-label-aware wrappers
+# includes ServeAccounting's replica-label-aware wrappers
 INSTRUMENT_CALL_ATTRS: tuple[str, ...] = (
     "counter",
     "gauge",
@@ -147,9 +149,8 @@ INSTRUMENT_CALL_ATTRS: tuple[str, ...] = (
     "record_value",
     "span",
     "record_span",
-    "_count",
-    "_observe",
-    "_gauge_set",
+    "counter_add",
+    "gauge_set",
 )
 # receivers that are NOT the telemetry hub despite sharing attr names
 INSTRUMENT_RECEIVER_DENYLIST: tuple[str, ...] = (
