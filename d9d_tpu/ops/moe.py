@@ -130,9 +130,11 @@ def permute_rows(x: Array, idx: Array, inv_idx: Array) -> Array:
     permutation is the gather by its inverse: no row collides and nothing
     is added. Autodiff cannot know ``idx`` is a permutation and would write
     a general scatter-add, several times slower than a gather on TPU, so
-    the transpose is given here.
+    the transpose is given here. Both are permutations of ``range(len(x))``,
+    so every index is a row: ``mode="clip"`` leaves the gather alone where
+    the default's fill writes a select over the gathered rows after it.
     """
-    return jnp.take(x, idx, axis=0)
+    return jnp.take(x, idx, axis=0, mode="clip")
 
 
 def _permute_rows_fwd(x, idx, inv_idx):
@@ -154,7 +156,8 @@ def spread_to_pairs(x: Array, token_idx: Array, dest: Array) -> Array:
     ``token_idx = sort_idx // K`` and ``dest`` the inverse of ``sort_idx``
     (:func:`stable_expert_order`). The mirror of :func:`combine_pairs`,
     which is its transpose: autodiff's own would be a scatter-add
-    colliding K ways on every token.
+    colliding K ways on every token. ``sort_idx`` permutes ``range(N*K)``,
+    so ``sort_idx // K`` is a token's row: the gather clips, it never fills.
     """
     return _spread_to_pairs(x, token_idx, dest, x.shape[0])
 
@@ -162,7 +165,7 @@ def spread_to_pairs(x: Array, token_idx: Array, dest: Array) -> Array:
 # N is static beside the operands: the transpose needs it and sees only g
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _spread_to_pairs(x, token_idx, dest, num_tokens):
-    return jnp.take(x, token_idx, axis=0)
+    return jnp.take(x, token_idx, axis=0, mode="clip")
 
 
 def _spread_to_pairs_fwd(x, token_idx, dest, num_tokens):
@@ -223,11 +226,12 @@ def combine_pairs(
     ``dest`` is a permutation, so the gather is collision-free on TPU. Its
     transpose is :func:`spread_to_pairs`, a gather by ``token_idx`` (the
     K-fold broadcast and the inverse permutation in one), where autodiff
-    would scatter at ``dest``. Shared by the local MoE path and the EP
-    shard_map combine.
+    would scatter at ``dest``. ``dest`` permutes ``range(N*K)``, so every
+    index is a row of ``y``: the gather clips, it never fills. Shared by
+    the local MoE path and the EP shard_map combine.
     """
     k = dest.shape[0] // num_tokens
-    pair_y = jnp.take(y, dest, axis=0)  # token-major pair rows
+    pair_y = jnp.take(y, dest, axis=0, mode="clip")  # token-major pair rows
     return pair_y.reshape(num_tokens, k, y.shape[-1]).sum(axis=1)
 
 
@@ -444,7 +448,9 @@ def sort_held_pairs(
     local_ids: [N, K] int32 local expert of each pair, ``num_held`` for a
     pair routed to an expert held elsewhere. The caller picks ``buf_rows``
     at or above the number of held pairs. Only index vectors are made
-    here, ``N*K`` integers at most: no row of hidden width moves.
+    here, ``N*K`` integers at most: no row of hidden width moves. A
+    slot's pair is one of the ``N*K`` (0 where the slot is empty), so the
+    gather of ``dest`` clips, it never fills.
     """
     n, k = local_ids.shape
     flat = local_ids.reshape(n * k)
@@ -467,7 +473,7 @@ def sort_held_pairs(
     return HeldSort(
         pair_of_row=pair_of_row,
         token_of_row=pair_of_row // k,
-        row_of_slot=jnp.take(dest, pair_of_slot),
+        row_of_slot=jnp.take(dest, pair_of_slot, mode="clip"),
         token_of_slot=pair_of_slot // k,
         slot_start=jnp.cumsum(count) - count,
         slot_count=count,
@@ -482,8 +488,10 @@ def spread_held(x: Array, held: HeldSort, top_k: int) -> Array:
 
     x: [N, D] → [M, D], row r a copy of token ``held.token_of_row[r]``,
     zeros from ``held.rows_held`` on. Its transpose is :func:`fold_held`.
+    ``token_of_row`` is ``sort_idx[:M] // K``, a token's row, so the gather
+    clips, it never fills: the one select over the rows is the live mask.
     """
-    rows = jnp.take(x, held.token_of_row, axis=0)
+    rows = jnp.take(x, held.token_of_row, axis=0, mode="clip")
     live = jnp.arange(rows.shape[0]) < held.rows_held
     return jnp.where(live[:, None], rows, jnp.zeros((), rows.dtype))
 

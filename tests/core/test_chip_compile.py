@@ -77,6 +77,20 @@ def _pallas_calls(compiled, scope: str = "") -> int:
     return _smoke_pallas_calls([compiled.as_text()], scope)
 
 
+def _fill_selects_of_row_gathers(compiled) -> list[str]:
+    """Result shapes of the ``select`` over a ``[rows, H]`` buffer that
+    ``jnp.take``'s default ``mode="fill"`` writes after a row gather under
+    ``moe/permute`` or ``moe/combine`` (a read and a write of every row, to
+    put NaN where an index was out of range): none where the gather says
+    its indices are rows. (An exchange's own backward names the scope
+    ``transpose(jvp(moe/permute))``.)"""
+    return re.findall(
+        rf"= (\w+\[\d+,{H}\])\S* select\([^\n]*"
+        r'op_name="[^"]*moe/(?:permute|combine)\)*/jit\(_take\)/select_n"',
+        compiled.as_text(),
+    )
+
+
 def _on(sharding):
     return lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=sharding
@@ -898,6 +912,8 @@ def test_one_layer_30b_a3b_value_and_grad(topo, as_tpu):
     assert _pallas_calls(compiled, "self_attn") >= 3, (
         "flash forward/backward kernels not in the HLO"
     )
+    # the local path's permute and combine, forward and transposed
+    assert _fill_selects_of_row_gathers(compiled) == []
     ma = compiled.memory_analysis()
     # bf16 params in, bf16 grads out: 2.5 GB each; the step fits one chip
     assert ma.argument_size_in_bytes + ma.output_size_in_bytes < 6e9
@@ -906,7 +922,8 @@ def test_one_layer_30b_a3b_value_and_grad(topo, as_tpu):
 @pytest.mark.parametrize("mode", ["forward", "grad"])
 def test_ep_dispatch_combine_on_four_chips(topo, as_tpu, mode):
     """The EP flow at 30B-A3B expert shapes over four described chips:
-    ``lax.ragged_all_to_all`` itself, not the CPU emulation under it."""
+    ``lax.ragged_all_to_all`` itself, not the CPU emulation under it, and
+    row gathers with no fill pass behind them."""
     from d9d_tpu.nn.moe import grouped_swiglu_apply
     from d9d_tpu.ops.ep_dispatch import ep_dispatch_compute_combine
 
@@ -949,6 +966,8 @@ def test_ep_dispatch_combine_on_four_chips(topo, as_tpu, mode):
     n = compiled.as_text().count(" ragged-all-to-all(")
     # dispatch + combine, and the transposes of both in the backward
     assert n >= (2 if mode == "forward" else 3), n
+    # at every rung, forward, recomputed and transposed
+    assert _fill_selects_of_row_gathers(compiled) == []
 
 
 @pytest.mark.parametrize("n,k,held,rows,width,dtype", [

@@ -288,9 +288,10 @@ def test_ladder_matches_the_worst_case_buffer(
     devices, monkeypatch, world, kind, rung
 ):
     """Whatever rung the counts select (the snug one, the middle one eight
-    shards have, the worst case), outputs are bit-equal to the single
-    ``m·W`` buffer's and every gradient equal; the rung and the rows
-    needed are reported identically by every shard."""
+    shards have, the worst case), outputs and every gradient are bit-equal
+    to the ``m·W`` buffer's through the same switch, and every gradient
+    equal to plain autodiff's through the single ``m·W`` buffer; the rung
+    and the rows needed are reported identically by every shard."""
     rng = np.random.RandomState(11)
     n = world * N_LOC
     x = rng.randn(n, D).astype(np.float32)
@@ -308,9 +309,11 @@ def test_ladder_matches_the_worst_case_buffer(
         ladder[rung], needed, int(rung == len(ladder) - 1)
     ]
 
-    # the parent's dropless path: one worst-case buffer, plain autodiff
+    # the worst-case buffer on both rungs: the same switch around the
+    # exchange, so XLA:CPU cuts its fusions at the same places and every
+    # rounding is the same one
     monkeypatch.setattr(
-        ep_dispatch, "ep_buffer_ladder", lambda rows, w: (rows * w,)
+        ep_dispatch, "ep_buffer_ladder", lambda rows, w: (rows * w,) * 2
     )
     ref_out, ref_grads, ref_use = _loss_grads_and_use(
         devices, world, x, ids, probs, weights
@@ -318,9 +321,24 @@ def test_ladder_matches_the_worst_case_buffer(
     assert ref_use[0].tolist() == [M * world, needed, 0]
     np.testing.assert_array_equal(out, ref_out)
     assert np.abs(out).max() > 0
-    for name, g, ref in zip(
-        ("x", "probs", "gate", "up", "down"), grads, ref_grads
-    ):
+    names = ("x", "probs", "gate", "up", "down")
+    for name, g, ref in zip(names, grads, ref_grads):
+        np.testing.assert_array_equal(g, ref, err_msg=name)
+
+    # the parent's dropless path: one worst-case buffer, plain autodiff.
+    # With no switch the probabilities' multiply, the gather and the k-row
+    # sum are one CPU fusion, where the multiply-add contracts (one
+    # rounding; a fill's select stood between them until PR 62): the
+    # output is this one's to a last bit, not bit for bit
+    monkeypatch.setattr(
+        ep_dispatch, "ep_buffer_ladder", lambda rows, w: (rows * w,)
+    )
+    plain_out, plain_grads, plain_use = _loss_grads_and_use(
+        devices, world, x, ids, probs, weights
+    )
+    assert plain_use[0].tolist() == [M * world, needed, 0]
+    np.testing.assert_allclose(out, plain_out, rtol=1e-6, atol=1e-6)
+    for name, g, ref in zip(names, grads, plain_grads):
         np.testing.assert_allclose(g, ref, rtol=1e-6, atol=1e-6, err_msg=name)
         assert np.abs(ref).max() > 0, name
 
@@ -374,13 +392,14 @@ def test_capacity_factor_drops_the_same_tail_rows(devices, capacity_factor):
 
 def _plain_autodiff_rows(monkeypatch):
     """The three row movements as the plain ``jnp.take`` they were, whose
-    transposes autodiff writes as scatter-adds."""
+    transposes autodiff writes as scatter-adds (the forward gathers clip
+    as ``ops/moe.py``'s do, so the forward is one program either way)."""
 
     def take_rows(x, idx, *unused):
-        return jnp.take(x, idx, axis=0)
+        return jnp.take(x, idx, axis=0, mode="clip")
 
     def take_and_fold(y, token_idx, dest, num_tokens):
-        pair_y = jnp.take(y, dest, axis=0)
+        pair_y = jnp.take(y, dest, axis=0, mode="clip")
         return pair_y.reshape(num_tokens, -1, y.shape[-1]).sum(axis=1)
 
     monkeypatch.setattr(ep_dispatch, "permute_rows", take_rows)

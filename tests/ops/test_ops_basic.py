@@ -1,7 +1,10 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.lax import GatherScatterMode
 
 from d9d_tpu.ops import (
     LM_IGNORE_INDEX,
@@ -636,14 +639,14 @@ def test_stable_expert_order_argsort_fallback_matches(monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _take_rows(x, idx, *unused):
+def _take_rows(x, idx, *unused, mode=None):
     """``permute_rows`` and ``spread_to_pairs`` as the plain gather."""
-    return jnp.take(x, idx, axis=0)
+    return jnp.take(x, idx, axis=0, mode=mode)
 
 
-def _take_and_fold(y, token_idx, dest, num_tokens):
+def _take_and_fold(y, token_idx, dest, num_tokens, mode=None):
     """``combine_pairs`` as the plain gather and k-row sum."""
-    return jnp.take(y, dest, axis=0).reshape(
+    return jnp.take(y, dest, axis=0, mode=mode).reshape(
         num_tokens, -1, y.shape[-1]
     ).sum(axis=1)
 
@@ -757,5 +760,128 @@ class TestRowMovementTransposes:
         given = lowered(
             moe_ops.spread_to_pairs, moe_ops.permute_rows, moe_ops.combine_pairs
         )
-        plain = lowered(_take_rows, _take_rows, _take_and_fold)
+        clipped = functools.partial(_take_rows, mode="clip")
+        plain = lowered(
+            clipped, clipped, functools.partial(_take_and_fold, mode="clip")
+        )
         assert "gather" in plain and given == plain
+
+    @pytest.mark.parametrize(
+        "name", ["permute_rows", "spread_to_pairs", "combine_pairs", "spread_held"]
+    )
+    def test_a_row_gather_leaves_no_fill_pass(self, monkeypatch, name):
+        """Every index of the four row movements is a row by construction
+        and the gather says so: neither the value nor the given transpose
+        traces a gather of ``[rows, D]`` rows in ``jnp.take``'s default
+        mode, which lowers to the gather and a ``select`` over all it
+        gathered (NaN where an index was out of range), and values and
+        gradients are, bit for bit, what the same movement through the
+        fill gives."""
+        moe_ops, sort = self._sort(monkeypatch, "one_hot", "random")
+        n, k, d = self.N, self.K, self.D
+        pairs, tokens = rng(n * k, d, seed=1), rng(n, d, seed=2)
+        held_experts, buf_rows = 3, 96
+        held = moe_ops.sort_held_pairs(
+            jnp.minimum(
+                jnp.asarray(
+                    np.random.RandomState(5).randint(0, self.E, (n, k)),
+                    jnp.int32,
+                ),
+                held_experts,
+            ),
+            held_experts, buf_rows,
+        )
+        assert 0 < int(held.rows_held) < buf_rows  # live rows and padding
+        live = (jnp.arange(buf_rows) < held.rows_held)[:, None]
+        # (the movement, its operand, the value and the transpose through
+        # the fill)
+        fn, operand, filled, filled_transpose = {
+            "permute_rows": (
+                lambda v: moe_ops.permute_rows(v, sort.sort_idx, sort.dest),
+                pairs,
+                lambda v: _take_rows(v, sort.sort_idx),
+                lambda g: _take_rows(g, sort.dest)),
+            "spread_to_pairs": (
+                lambda v: moe_ops.spread_to_pairs(v, sort.token_idx, sort.dest),
+                tokens,
+                lambda v: _take_rows(v, sort.token_idx),
+                lambda g: _take_and_fold(g, sort.token_idx, sort.dest, n)),
+            "combine_pairs": (
+                lambda v: moe_ops.combine_pairs(
+                    v, sort.token_idx, sort.dest, n),
+                pairs,
+                lambda v: _take_and_fold(v, sort.token_idx, sort.dest, n),
+                lambda g: _take_rows(g, sort.token_idx)),
+            "spread_held": (
+                lambda v: moe_ops.spread_held(v, held, k),
+                tokens,
+                lambda v: jnp.where(
+                    live, _take_rows(v, held.token_of_row), 0.0),
+                # the fold has clipped since PR 49: its own reference
+                lambda g: moe_ops.fold_held(g, held, n, k)),
+        }[name]
+        cotangent = rng(*jax.eval_shape(fn, operand).shape, seed=9)
+
+        def pull_back(v, g):
+            return jax.vjp(fn, v)[1](g)[0]
+
+        def filling_row_gathers(program, *args):
+            return count(
+                jax.make_jaxpr(program)(*args).jaxpr,
+                lambda eqn: eqn.primitive.name == "gather"
+                and eqn.params["mode"] == GatherScatterMode.FILL_OR_DROP
+                and eqn.invars[0].aval.shape[1:] == (d,),
+            )
+
+        assert filling_row_gathers(filled, operand) == 1  # the fill is seen
+        assert filling_row_gathers(fn, operand) == 0
+        # (the pull-back's trace holds the forward too)
+        assert filling_row_gathers(pull_back, operand, cotangent) == 0
+        value, grad = fn(operand), pull_back(operand, cotangent)
+        assert np.abs(value).max() > 0 and np.abs(grad).max() > 0
+        np.testing.assert_array_equal(value, filled(operand))
+        np.testing.assert_array_equal(grad, filled_transpose(cotangent))
+
+    @pytest.mark.parametrize("held_experts", [0, 3], ids=["none_held", "held"])
+    @pytest.mark.parametrize("grouping", ["one_hot", "argsort"])
+    def test_every_gathered_index_is_a_row(
+        self, monkeypatch, grouping, held_experts
+    ):
+        """What the clipping gathers promise, on both branches of
+        ``stable_expert_order``: ``sort_idx`` and ``dest`` permute the
+        pair rows, ``sort_idx // K`` is a token, and with padding behind
+        the held rows (or nothing held at all) every row's token, every
+        slot's pair and every slot's row still is one. A clip would read
+        row 0 or the last row for an index that was not, and say nothing."""
+        moe_ops, sort = self._sort(monkeypatch, grouping, "random")
+        n, k = self.N, self.K
+        pair_rows = np.arange(n * k)
+        np.testing.assert_array_equal(np.sort(sort.sort_idx), pair_rows)
+        np.testing.assert_array_equal(np.sort(sort.dest), pair_rows)
+        np.testing.assert_array_equal(sort.token_idx, np.asarray(sort.sort_idx) // k)
+
+        def within(values, size):
+            values = np.asarray(values)
+            return values.min() >= 0 and values.max() < size
+
+        assert within(sort.token_idx, n)
+        buf_rows = 96
+        ids = np.random.RandomState(5).randint(0, self.E, (n, k))
+        # a pair routed elsewhere carries the label ``num_held``; with no
+        # expert held (one stands in for the group sizes) that is all of them
+        local = np.minimum(ids, held_experts) if held_experts else ids * 0 + 1
+        held = moe_ops.sort_held_pairs(
+            jnp.asarray(local, jnp.int32), max(held_experts, 1), buf_rows
+        )
+        rows_held = int(held.rows_held)
+        assert rows_held == (local < max(held_experts, 1)).sum() < buf_rows
+        assert (rows_held > 0) == bool(held_experts)
+        assert within(held.pair_of_row, n * k) and within(held.token_of_row, n)
+        # token_of_slot is pair_of_slot // K: in range only if the pair is
+        assert within(held.token_of_slot, n) and within(held.row_of_slot, n * k)
+        # the live slots are the held pairs in pair order, and each finds
+        # the row that holds it
+        np.testing.assert_array_equal(
+            np.asarray(held.pair_of_row)[np.asarray(held.row_of_slot)[:rows_held]],
+            np.flatnonzero(local.reshape(-1) < max(held_experts, 1)),
+        )
